@@ -258,7 +258,7 @@ class CacheConfig:
 
     block_size: int = 16  # tokens per block
     num_blocks: Optional[int] = None  # None -> sized from HBM fraction
-    hbm_utilization: float = 0.90  # fraction of free HBM for weights+KV
+    hbm_utilization: float = 0.90  # fraction of HBM for weights + KV pool
     # stackcheck: allow=SC401 reason=prefix caching has been the default-on contract since the seed; the safe rollback is the explicit opt-out (--no-prefix-caching), and the KV-transfer plane auto-disables itself when this is off
     enable_prefix_caching: bool = True
     # Host-DRAM offload tier (the reference's LMCache CPU-offload analogue,

@@ -61,7 +61,9 @@ from production_stack_tpu.engine.kv.offload import HostOffloadManager, OffloadSt
 from production_stack_tpu.engine.kv.prefetch import PrefetchedChain, PrefetchManager
 from production_stack_tpu.engine.models import get_model
 from production_stack_tpu.engine.models.weights import load_params
+from production_stack_tpu.engine.ops import attention as attn_ops
 from production_stack_tpu.obs.engine import EngineObs
+from production_stack_tpu.utils.compile_cache import compile_cache_report
 from production_stack_tpu.obs.histogram import Histogram
 from production_stack_tpu.engine.parallel import shardings as shardings_lib
 from production_stack_tpu.engine.parallel.mesh import AXES, build_mesh
@@ -187,14 +189,14 @@ class LLMEngine:
                     "ring shards the prefix block table over sp)"
                 )
         self.mesh = build_mesh(par)
+        self._report_device_and_kernels()
 
         logger.info("Loading params for %s ...", cfg.name)
-        self.params = load_params(cfg, config.weights_path, seed=config.seed)
-        if cfg.quantization is not None:
-            logger.info("Quantizing projections to %s ...", cfg.quantization)
-            self.params = self.model.quantize_params(self.params, cfg)
-        self.params = jax.device_put(
-            self.params, shardings_lib.param_shardings(cfg, self.mesh)
+        # Created (or read), quantized and placed tensor by tensor in the
+        # final sharding: the whole model never sits on one device.
+        self.params = load_params(
+            cfg, config.weights_path, seed=config.seed,
+            shardings=shardings_lib.param_shardings(cfg, self.mesh),
         )
 
         # Draft model for in-scan speculative decoding
@@ -233,11 +235,10 @@ class LLMEngine:
                 self.draft_model = get_model(draft_cfg.name)
                 logger.info("Loading draft params for %s ...", draft_cfg.name)
                 self.draft_params = load_params(
-                    draft_cfg, config.draft_weights_path, seed=config.seed
-                )
-                self.draft_params = jax.device_put(
-                    self.draft_params,
-                    shardings_lib.param_shardings(draft_cfg, self.mesh),
+                    draft_cfg, config.draft_weights_path, seed=config.seed,
+                    shardings=shardings_lib.param_shardings(
+                        draft_cfg, self.mesh
+                    ),
                 )
 
         num_blocks = self._decide_num_blocks()
@@ -1509,28 +1510,98 @@ class LLMEngine:
             )
         return num_blocks * self.config.cache.block_size * per_token * cfg.num_layers
 
+    def device_report(self) -> Dict:
+        """The devices this process sees, as JAX reports them (logged at
+        boot, served under "device" in GET /debug/compiles)."""
+        first = jax.devices()[0]
+        memory = []
+        for device in self.mesh.local_devices:
+            stats = device.memory_stats() or {}
+            memory.append({
+                "id": device.id,
+                "bytes_in_use": stats.get("bytes_in_use"),
+                "bytes_limit": stats.get("bytes_limit"),
+            })
+        return {
+            "platform": first.platform,
+            "kind": first.device_kind,
+            "count": len(jax.devices()),
+            "mesh": {axis: int(n) for axis, n in self.mesh.shape.items()},
+            "memory": memory,
+        }
+
+    def _report_device_and_kernels(self) -> None:
+        """Say at boot which device is held and which attention path each
+        step takes there; refuse what cannot run on it."""
+        cfg, par = self.config.model, self.config.parallel
+        report = self.device_report()
+        logger.info(
+            "Device: platform=%s kind=%s count=%d mesh=%s",
+            report["platform"], report["kind"], report["count"],
+            report["mesh"],
+        )
+        if attn_ops.pallas_disabled():
+            logger.warning(
+                "PSTPU_DISABLE_PALLAS is set: both Pallas attention kernels "
+                "are switched off, every step takes the XLA gather/dense path"
+            )
+        decode_kernel = attn_ops.use_pallas_decode(
+            cfg.num_kv_heads // par.tensor_parallel, cfg.head_dim
+        )
+        prefill_kernel = self.mesh.size == 1 and attn_ops.use_pallas_prefill(
+            cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
+            self.config.scheduler.prefill_buckets[0],
+        )
+        logger.info(
+            "Attention: decode=%s prefill=%s",
+            "pallas" if decode_kernel else "xla-gather",
+            "pallas-flash" if prefill_kernel else "xla-dense",
+        )
+        if (
+            report["platform"] == "tpu"
+            and self.config.cache.kv_cache_dtype == "int8"
+        ):
+            # Mosaic refuses the int8-KV decode kernel: the [N, bs, K]
+            # fp32 scale planes have K (8, or 2 under tp=4) as their minor
+            # dimension, which is neither a 128-lane DMA slice nor dense
+            # in HBM (ROADMAP S10).  Refused here rather than at the first
+            # request or by quietly taking the gather path.
+            raise ValueError(
+                "--kv-cache-dtype int8 cannot run on a TPU yet: the int8-KV "
+                "decode kernel does not compile for it (scale-plane layout, "
+                "ROADMAP S10); serve with the default bf16 KV cache"
+            )
+
     def _decide_num_blocks(self) -> int:
         cache = self.config.cache
         if cache.num_blocks is not None:
             return cache.num_blocks
-        device = jax.local_devices()[0]
-        stats = {}
-        try:
-            stats = device.memory_stats() or {}
-        except Exception:
-            pass
-        limit = stats.get("bytes_limit")
-        in_use = stats.get("bytes_in_use", 0)
-        if limit:
-            free = (limit - in_use) * cache.hbm_utilization
-            # KV heads are sharded over tp, so each device holds 1/tp of a
-            # block; size the pool against per-device free HBM.
-            per_block = self._kv_bytes(1) / self.config.parallel.tensor_parallel
-            blocks = max(int(free // per_block), 16)
-        else:
-            # CPU / unknown backend: enough for tests and smoke serving.
-            blocks = 512
-        # Cap the block-table width implied by max_model_len.
+        report = self.device_report()
+        if report["platform"] == "cpu":
+            # The CPU reports no memory limit: enough for tests and smoke
+            # serving.
+            return 512
+        # An accelerator that reports no limit is an error, not a default:
+        # a silent 512-block pool would serve 8k tokens from a 16 GB chip.
+        # hbm_utilization bounds weights + KV together: what is left of the
+        # device is the step programs' workspace (about 1 GB for a
+        # 2048-token prefill of a 7B model).
+        budget = []
+        for mem in report["memory"]:
+            if not mem["bytes_limit"]:
+                raise RuntimeError(
+                    f"device {mem['id']} reports no bytes_limit in "
+                    "memory_stats(); cannot size the KV pool — pass "
+                    "--num-blocks"
+                )
+            budget.append(
+                mem["bytes_limit"] * cache.hbm_utilization
+                - (mem["bytes_in_use"] or 0)
+            )
+        # KV heads are sharded over tp, so each device holds 1/tp of a
+        # block; size the pool against the fullest device.
+        per_block = self._kv_bytes(1) / self.config.parallel.tensor_parallel
+        blocks = max(int(min(budget) // per_block), 16)
         return blocks
 
     def _allocate_kv(self, num_blocks: int):
@@ -4540,8 +4611,10 @@ class LLMEngine:
         """Batched embeddings: ONE [B, T]-bucketed llama.encode_batch
         dispatch for up to encode_batch_buckets[-1] texts (B pads to an
         encode-batch bucket, T to a prefill bucket), replacing B serial
-        ``embed`` round-trips.  Vectors are identical to per-text
-        ``embed`` output up to float addition order.  STEP-THREAD-only
+        ``embed`` round-trips.  Vectors agree with per-text ``embed``
+        output to float32 rounding, not bit for bit: the vmap-batched
+        program sums in another order (one ulp apart on the installed
+        XLA).  STEP-THREAD-only
         caller in production (the EncodeBatcher) — this touches the
         device."""
         if not hasattr(self.model, "encode_batch"):
@@ -4689,6 +4762,8 @@ class LLMEngine:
         }
         return {
             "enabled": self.obs.enabled,
+            "device": self.device_report(),
+            "persistent_cache": compile_cache_report(),
             "compiled_shapes": self.obs.compile_tracker.compiled_shapes(),
             "compile_seconds": round(
                 self.obs.compile_tracker.compile_seconds(), 6

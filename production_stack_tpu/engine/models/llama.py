@@ -21,7 +21,7 @@ from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from production_stack_tpu.engine.parallel.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from production_stack_tpu.engine.config import ModelConfig
@@ -53,48 +53,104 @@ def param_dtype(cfg: ModelConfig):
     return jnp.dtype(cfg.dtype)
 
 
-def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
-    """Random init with HF-compatible tree structure."""
+def quantize_weight(w: jax.Array) -> Params:
+    """Per-out-channel symmetric int8 form of one [in, out] projection."""
+    w32 = w.astype(jnp.float32)
+    amax = jnp.max(jnp.abs(w32), axis=0)
+    s = jnp.where(amax > 0, amax / 127.0, 1.0).astype(jnp.float32)
+    q = jnp.clip(jnp.round(w32 / s), -127, 127).astype(jnp.int8)
+    return {"q": q, "s": s}
+
+
+def place_weight(w, sharding=None):
+    """One host or device tensor into its final sharding.  A {"q", "s"}
+    sharding pair (parallel/shardings.py under cfg.quantization) asks for
+    the int8 form: the tensor is placed sharded first and quantized there."""
+    if sharding is None:
+        return jnp.asarray(w)
+    if isinstance(sharding, dict):
+        return jax.jit(quantize_weight, out_shardings=sharding)(
+            jax.device_put(w, sharding["q"])
+        )
+    return jax.device_put(w, sharding)
+
+
+def init_params(cfg: ModelConfig, key: jax.Array, shardings=None) -> Params:
+    """Random init with HF-compatible tree structure.
+
+    With ``shardings`` (parallel/shardings.py param_shardings) every
+    tensor is created by a jitted initializer directly in its final
+    sharding and, where that is a {"q", "s"} pair (cfg.quantization),
+    quantized there: neither the unsharded nor the unquantized model ever
+    sits on one device (int8 mistral-7b fits a 16 GB chip; its bf16 form
+    does not).  Without it: plain cfg.dtype arrays on the default device.
+    """
     dtype = param_dtype(cfg)
     h, hd = cfg.hidden_size, cfg.head_dim
     H, K, I = cfg.num_heads, cfg.num_kv_heads, cfg.intermediate_size
+    makers = {}  # (shape, sharding) -> jitted initializer
 
-    def dense(key, shape, scale=0.02):
-        return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dtype)
+    def dense(key, shape, sharding=None):
+        quantized = isinstance(sharding, dict)
+        maker_key = (shape, tuple(sharding.values()) if quantized else sharding)
+        if maker_key not in makers:
 
+            def make(key):
+                w = jax.random.normal(key, shape, jnp.float32) * 0.02
+                w = w.astype(dtype)
+                return quantize_weight(w) if quantized else w
+
+            makers[maker_key] = jax.jit(make, out_shardings=sharding)
+        return makers[maker_key](key)
+
+    def const(fill, shape, sharding=None):
+        return place_weight(jnp.full(shape, fill, dtype), sharding)
+
+    top = shardings or {}
     keys = jax.random.split(key, cfg.num_layers + 3)
     params: Params = {
-        "embed_tokens": dense(keys[0], (cfg.vocab_size, h)),
-        "norm": jnp.ones((h,), dtype),
+        "embed_tokens": dense(
+            keys[0], (cfg.vocab_size, h), top.get("embed_tokens")
+        ),
+        "norm": const(1, (h,), top.get("norm")),
         "layers": [],
     }
     if not cfg.tie_word_embeddings:
-        params["lm_head"] = dense(keys[1], (h, cfg.vocab_size))
+        params["lm_head"] = dense(
+            keys[1], (h, cfg.vocab_size), top.get("lm_head")
+        )
     for i in range(cfg.num_layers):
         lk = jax.random.split(keys[i + 3], 8)
+        sh = shardings["layers"][i] if shardings else {}
         layer = {
-            "input_layernorm": jnp.ones((h,), dtype),
-            "post_attention_layernorm": jnp.ones((h,), dtype),
-            "q_proj": dense(lk[0], (h, H * hd)),
-            "k_proj": dense(lk[1], (h, K * hd)),
-            "v_proj": dense(lk[2], (h, K * hd)),
-            "o_proj": dense(lk[3], (H * hd, h)),
+            "input_layernorm": const(1, (h,), sh.get("input_layernorm")),
+            "post_attention_layernorm": const(
+                1, (h,), sh.get("post_attention_layernorm")
+            ),
+            "q_proj": dense(lk[0], (h, H * hd), sh.get("q_proj")),
+            "k_proj": dense(lk[1], (h, K * hd), sh.get("k_proj")),
+            "v_proj": dense(lk[2], (h, K * hd), sh.get("v_proj")),
+            "o_proj": dense(lk[3], (H * hd, h), sh.get("o_proj")),
         }
         if cfg.num_experts:
             E = cfg.num_experts
-            layer["gate"] = dense(lk[7], (h, E))
-            layer["experts_gate"] = dense(lk[4], (E, h, I))
-            layer["experts_up"] = dense(lk[5], (E, h, I))
-            layer["experts_down"] = dense(lk[6], (E, I, h))
+            layer["gate"] = dense(lk[7], (h, E), sh.get("gate"))
+            layer["experts_gate"] = dense(
+                lk[4], (E, h, I), sh.get("experts_gate")
+            )
+            layer["experts_up"] = dense(lk[5], (E, h, I), sh.get("experts_up"))
+            layer["experts_down"] = dense(
+                lk[6], (E, I, h), sh.get("experts_down")
+            )
         else:
-            layer["gate_proj"] = dense(lk[4], (h, I))
-            layer["up_proj"] = dense(lk[5], (h, I))
-            layer["down_proj"] = dense(lk[6], (I, h))
+            layer["gate_proj"] = dense(lk[4], (h, I), sh.get("gate_proj"))
+            layer["up_proj"] = dense(lk[5], (h, I), sh.get("up_proj"))
+            layer["down_proj"] = dense(lk[6], (I, h), sh.get("down_proj"))
         if cfg.attention_bias:
             # Qwen2-style QKV biases (o_proj stays bias-free there).
-            layer["q_bias"] = jnp.zeros((H * hd,), dtype)
-            layer["k_bias"] = jnp.zeros((K * hd,), dtype)
-            layer["v_bias"] = jnp.zeros((K * hd,), dtype)
+            layer["q_bias"] = const(0, (H * hd,), sh.get("q_bias"))
+            layer["k_bias"] = const(0, (K * hd,), sh.get("k_bias"))
+            layer["v_bias"] = const(0, (K * hd,), sh.get("v_bias"))
         params["layers"].append(layer)
     return params
 
@@ -143,23 +199,16 @@ def quantize_params(params: Params, cfg: ModelConfig) -> Params:
     if cfg.quantization is None:
         return params
 
-    def qw(w):
-        w32 = w.astype(jnp.float32)
-        amax = jnp.max(jnp.abs(w32), axis=0)
-        s = jnp.where(amax > 0, amax / 127.0, 1.0).astype(jnp.float32)
-        q = jnp.clip(jnp.round(w32 / s), -127, 127).astype(jnp.int8)
-        return {"q": q, "s": s}
-
     out = dict(params)
     out["layers"] = []
     for layer in params["layers"]:
         new = dict(layer)
         for name in _QUANT_TARGETS:
             if name in layer:
-                new[name] = qw(layer[name])
+                new[name] = quantize_weight(layer[name])
         out["layers"].append(new)
     if "lm_head" in params:
-        out["lm_head"] = qw(params["lm_head"])
+        out["lm_head"] = quantize_weight(params["lm_head"])
     return out
 
 

@@ -1,8 +1,9 @@
 """Checkpoint loading: HF safetensors -> our functional param trees.
 
-Zero-egress friendly: if no checkpoint directory is given (or it is
-missing), models fall back to deterministic random init — throughput
-benchmarking and scale testing need correct shapes, not trained weights.
+Zero-egress friendly: with no checkpoint path, models get a deterministic
+random init from the seed — throughput benchmarking and scale testing need
+correct shapes, not trained weights.  A path that was given and cannot be
+loaded is an error: the server never answers from weights nobody asked for.
 """
 
 from __future__ import annotations
@@ -26,19 +27,21 @@ def load_params(
     weights_path: Optional[str],
     *,
     seed: int = 0,
+    shardings=None,
 ):
-    """Load HF-layout safetensors if available, else random init."""
+    """HF-layout safetensors from ``weights_path``, or random init from
+    ``seed`` when no path is given.  ``shardings`` (parallel/shardings.py
+    param_shardings) places, and under cfg.quantization quantizes, each
+    tensor directly in its final sharding (models/llama.py place_weight)."""
     from production_stack_tpu.engine.models import llama
 
-    if weights_path and os.path.isdir(weights_path):
-        try:
-            return load_hf_safetensors(cfg, weights_path)
-        except Exception:
-            logger.exception(
-                "Failed to load weights from %s; falling back to random init",
-                weights_path,
+    if weights_path:
+        if not os.path.isdir(weights_path):
+            raise FileNotFoundError(
+                f"weights path {weights_path!r} is not a directory"
             )
-    return llama.init_params(cfg, jax.random.PRNGKey(seed))
+        return load_hf_safetensors(cfg, weights_path, shardings)
+    return llama.init_params(cfg, jax.random.PRNGKey(seed), shardings)
 
 
 def _open_safetensors(weights_path: str) -> Dict[str, np.ndarray]:
@@ -62,20 +65,23 @@ def _open_safetensors(weights_path: str) -> Dict[str, np.ndarray]:
     return tensors
 
 
-def load_hf_safetensors(cfg: ModelConfig, weights_path: str):
+def load_hf_safetensors(cfg: ModelConfig, weights_path: str, shardings=None):
     """Map HF LlamaForCausalLM tensor names into our layout.
 
     torch Linear stores [out, in]; we store [in, out], hence the transposes
-    (see models/llama.py docstring).
+    (see models/llama.py docstring).  Tensors stay on the host until
+    ``place_weight`` puts each one in its final sharding.
     """
+    from production_stack_tpu.engine.models.llama import place_weight
+
     sd = _open_safetensors(weights_path)
     dtype = jnp.dtype(cfg.dtype)
 
-    def take(name: str, transpose: bool = False) -> jax.Array:
+    def take(name: str, transpose: bool = False) -> np.ndarray:
         arr = sd[name]
         if transpose:
             arr = arr.T
-        return jnp.asarray(arr, dtype)
+        return np.asarray(arr).astype(dtype)
 
     params = {
         "embed_tokens": take("model.embed_tokens.weight"),
@@ -101,15 +107,15 @@ def load_hf_safetensors(cfg: ModelConfig, weights_path: str):
             # (gate/up/down), stacked into [E, ...] arrays.
             moe = p + "block_sparse_moe."
             layer["gate"] = take(moe + "gate.weight", transpose=True)
-            layer["experts_gate"] = jnp.stack([
+            layer["experts_gate"] = np.stack([
                 take(moe + f"experts.{e}.w1.weight", transpose=True)
                 for e in range(cfg.num_experts)
             ])
-            layer["experts_up"] = jnp.stack([
+            layer["experts_up"] = np.stack([
                 take(moe + f"experts.{e}.w3.weight", transpose=True)
                 for e in range(cfg.num_experts)
             ])
-            layer["experts_down"] = jnp.stack([
+            layer["experts_down"] = np.stack([
                 take(moe + f"experts.{e}.w2.weight", transpose=True)
                 for e in range(cfg.num_experts)
             ])
@@ -125,4 +131,8 @@ def load_hf_safetensors(cfg: ModelConfig, weights_path: str):
             layer["v_bias"] = take(p + "self_attn.v_proj.bias")
         params["layers"].append(layer)
     logger.info("Loaded %d tensors from %s", len(sd), weights_path)
-    return params
+    if shardings is None:
+        return jax.tree_util.tree_map(place_weight, params)
+    # tree_map flattens ``shardings`` only as deep as ``params``: a
+    # quantized leaf's {"q", "s"} pair reaches place_weight whole.
+    return jax.tree_util.tree_map(place_weight, params, shardings)

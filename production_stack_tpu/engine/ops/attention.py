@@ -28,13 +28,19 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from production_stack_tpu.engine.parallel.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 NEG_INF = -1e30  # large-but-finite: keeps masked softmax rows NaN-free
 
 # Quantized (int8) cache sides are (data, scale) tuples — kv/quant.py.
 from production_stack_tpu.engine.kv import quant as kv_quant
+
+
+def pallas_disabled() -> bool:
+    """The A/B switch that sends every step down the XLA gather/dense
+    path; the engine says at boot when it is set."""
+    return bool(os.environ.get("PSTPU_DISABLE_PALLAS"))
 
 
 def use_pallas_decode(num_kv_heads: int = 128, head_dim: int = 128) -> bool:
@@ -45,7 +51,7 @@ def use_pallas_decode(num_kv_heads: int = 128, head_dim: int = 128) -> bool:
     shape cast when head_dim is a multiple of the 128-lane tile.  Covers
     llama-3-8b / llama-3.2-3b / mistral-7b (D=128); head_dim-64 models
     (llama-3.2-1b) and the tiny test models use the gather path."""
-    if os.environ.get("PSTPU_DISABLE_PALLAS"):
+    if pallas_disabled():
         return False
     if num_kv_heads < 1 or head_dim % 128:
         return False
@@ -116,11 +122,7 @@ def use_pallas_prefill(num_heads: int, num_kv_heads: int, head_dim: int,
     """Trace-time dispatch check for the flash prefill kernel: real TPU,
     128-lane-aligned head_dim, GQA-divisible heads, and a power-of-two-ish
     token bucket the q tiling divides (engine buckets are powers of two)."""
-    if os.environ.get("PSTPU_DISABLE_PALLAS") or os.environ.get(
-        "PSTPU_DISABLE_FLASH_PREFILL"
-    ):
-        # The second gate exists so bench.py's stage watchdog can re-exec
-        # with only the prefill kernel disabled if it ever stalls a chip.
+    if pallas_disabled():
         return False
     if head_dim % 128 or num_heads % max(num_kv_heads, 1):
         return False

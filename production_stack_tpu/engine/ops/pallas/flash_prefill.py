@@ -229,11 +229,9 @@ def flash_prefill_attention(
         # online-softmax scratch exceed the compiler's default 16 MB scoped
         # VMEM at serving tile sizes; v5e/v6e have 128 MB, so raise the cap
         # rather than shrink tiles below MXU-efficient shapes.
-        # CompilerParams is the jax>=0.5 name; 0.4.x calls it
-        # TPUCompilerParams.
-        compiler_params=getattr(
-            pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-        )(vmem_limit_bytes=96 * 1024 * 1024),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=96 * 1024 * 1024
+        ),
         interpret=interpret,
     )(
         jnp.asarray(cached_len, jnp.int32).reshape(1),

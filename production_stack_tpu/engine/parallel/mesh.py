@@ -45,11 +45,12 @@ def build_mesh(parallel: ParallelConfig, devices: Optional[list] = None) -> Mesh
             f"Mesh {shape} needs {needed} devices; only {len(devices)} available"
         )
     devices = devices[:needed]
-    try:
-        device_array = mesh_utils.create_device_mesh(shape, devices=devices)
-    except Exception:
-        # Fallback (CPU virtual devices have no topology info).
+    if devices[0].platform == "cpu":
+        # CPU virtual devices have no topology to order by.  On an
+        # accelerator a topology create_device_mesh refuses is an error.
         device_array = np.asarray(devices).reshape(shape)
+    else:
+        device_array = mesh_utils.create_device_mesh(shape, devices=devices)
     return Mesh(device_array, (AXES.DP, AXES.TP, AXES.SP))
 
 

@@ -217,7 +217,7 @@ def ring_prefill_with_prefix(
 
 def ring_prefill_attention(mesh, q, k, v, *, scale: float, valid_len=None):
     """Convenience wrapper: shard T over the sp axis and run the ring."""
-    from production_stack_tpu.engine.parallel.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from production_stack_tpu.engine.parallel.mesh import AXES

@@ -13,9 +13,15 @@ entirely) that compile.  Events are keyed by a compact
 gauge; the engine drains pending events after each dispatch to tag the
 owning windows/requests ``compile=true``.
 
-jax-free by construction (duck-typed ``_cache_size`` / shape probing), so
-the module imports in the bare router/CI venv; when a wrapped callable
-lacks ``_cache_size`` the proxy degrades to pass-through.
+On a TPU each event also carries ``kernels``: how many Pallas kernels
+(``tpu_custom_call``) the lowered text of the program just compiled holds —
+the evidence that a step took the kernel path and not the XLA gather/dense
+path (``chip_smoke.py`` asserts on it through GET /debug/compiles).
+
+jax-free at import (duck-typed ``_cache_size`` / shape probing; jax is
+imported only after a wrapped jit callable has compiled), so the module
+imports in the bare router/CI venv; when a wrapped callable lacks
+``_cache_size`` the proxy degrades to pass-through.
 
 Thread-safety: wrapped callables fire on the engine step thread; the HTTP
 server reads snapshots from the event loop — every mutation of the shared
@@ -25,9 +31,12 @@ so the fast path keeps bare jit functions (byte-identical dispatch).
 
 from __future__ import annotations
 
+import logging
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional
+
+logger = logging.getLogger(__name__)
 
 _SIG_MAX_CHARS = 96  # keep executable label cardinality readable
 
@@ -64,6 +73,43 @@ def arg_signature(args: tuple, kwargs: dict) -> str:
     return sig
 
 
+def count_kernels(fn: Callable, args: tuple, kwargs: dict) -> Optional[int]:
+    """Pallas kernels in the program ``fn`` compiles for these arguments:
+    occurrences of ``tpu_custom_call`` in its lowered text.  Lowers from
+    shapes, so arguments the call has donated (and deleted) are fine.
+    None where the callable cannot be lowered again — an observability
+    sink never fails the step that fed it."""
+    import jax
+
+    def spec(x):
+        if isinstance(x, jax.Array):
+            # Only a committed array pins its devices: an uncommitted
+            # scalar beside mesh-sharded params must stay free to follow.
+            return jax.ShapeDtypeStruct(
+                x.shape, x.dtype,
+                sharding=x.sharding if x.committed else None,
+            )
+        return x
+
+    try:
+        shaped_args, shaped_kwargs = jax.tree_util.tree_map(
+            spec, (args, kwargs)
+        )
+        text = fn.lower(*shaped_args, **shaped_kwargs).as_text()
+    except Exception:
+        logger.exception("count_kernels: cannot lower %r again", fn)
+        return None
+    return text.count("tpu_custom_call")
+
+
+def _on_tpu() -> bool:
+    try:
+        import jax
+    except ImportError:  # bare router venv: a duck-typed callable compiled
+        return False
+    return jax.default_backend() == "tpu"
+
+
 class _TrackedJit:
     """Pass-through proxy for one jit callable; detects compiles via the
     executable-cache-size delta around each call."""
@@ -89,8 +135,13 @@ class _TrackedJit:
         except Exception:
             grew = False
         if grew:
+            seconds = time.time() - t0
+            # The kernels are dispatched on the TPU only: elsewhere the
+            # second trace would buy a certain zero.
+            kernels = count_kernels(fn, args, kwargs) if _on_tpu() else None
             self._tracker.record(
-                self._name, arg_signature(args, kwargs), time.time() - t0
+                self._name, arg_signature(args, kwargs), seconds,
+                kernels=kernels,
             )
         return out
 
@@ -105,7 +156,7 @@ class CompileTracker:
     def __init__(self, enabled: bool = True):
         self.enabled = bool(enabled)
         self._lock = threading.Lock()
-        # executable key -> [count, seconds]
+        # executable key -> [count, seconds, kernels]
         self._by_executable: Dict[str, list] = {}
         # events since the engine last drained (tag owning windows/spans)
         self._events: List[Dict] = []
@@ -117,12 +168,17 @@ class CompileTracker:
             return fn
         return _TrackedJit(self, name, fn)
 
-    def record(self, name: str, signature: str, seconds: float) -> None:
+    def record(
+        self, name: str, signature: str, seconds: float,
+        kernels: Optional[int] = None,
+    ) -> None:
         key = f"{name}[{signature}]"
         with self._lock:
-            ent = self._by_executable.setdefault(key, [0, 0.0])
+            ent = self._by_executable.setdefault(key, [0, 0.0, None])
             ent[0] += 1
             ent[1] += float(seconds)
+            if kernels is not None:
+                ent[2] = kernels
             self._events.append({"executable": key, "seconds": float(seconds)})
 
     def drain_events(self) -> List[Dict]:
@@ -157,7 +213,7 @@ class CompileTracker:
         with self._lock:
             rows = [
                 {"executable": k, "count": ent[0],
-                 "seconds": round(ent[1], 6)}
+                 "seconds": round(ent[1], 6), "kernels": ent[2]}
                 for k, ent in self._by_executable.items()
             ]
         rows.sort(key=lambda r: -r["seconds"])

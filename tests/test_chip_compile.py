@@ -1,0 +1,206 @@
+"""The main path's Pallas kernels, compiled for a described TPU v5e.
+
+No chip is attached: the TPU's compiler is installed here and compiles for
+a topology that is described (``v5e:2x2``), about two seconds a kernel.
+Interpret mode (every other kernel test) cannot see what Mosaic refuses —
+a slice not aligned to the tiling, too much VMEM — so these compiles guard
+each kernel variant the engine can dispatch for mistral-7b, at its
+published widths and at its tp=4 shard shapes, against every later PR.
+A compile that passes is not a chip run: nothing executes here.
+
+The topology is described inside a module-scoped fixture (never at import,
+in a ``skipif`` or in ``parametrize``): only the xdist worker that is
+given this file loads the TPU library.  All of these tests stay in this
+one file for the same reason.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from production_stack_tpu.engine.config import PRESETS
+from production_stack_tpu.engine.ops.pallas.flash_prefill import (
+    flash_prefill_attention,
+)
+from production_stack_tpu.engine.ops.pallas.paged_attention import (
+    paged_decode_attention_pallas,
+)
+
+MISTRAL = PRESETS["mistral-7b"]
+D = MISTRAL.head_dim  # 128
+SCALE = D**-0.5
+WINDOW = MISTRAL.sliding_window  # 4096: mistral sets one, so every variant does
+BS = 16
+MAX_LEN = 8192  # chip_smoke.py's --max-model-len
+# (H, K): published widths on one chip, and one shard of --tensor-parallel 4.
+ONE_CHIP = (MISTRAL.num_heads, MISTRAL.num_kv_heads)
+TP4_SHARD = (MISTRAL.num_heads // 4, MISTRAL.num_kv_heads // 4)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def sds(one_chip):
+    """Shape of one argument, on the described chip."""
+    def make(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    return make
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without the chip (the next one warns and
+    compiles again): keep it out of these tests."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+@pytest.mark.parametrize("heads", [ONE_CHIP, TP4_SHARD], ids=["H32K8", "tp4-H8K2"])
+def test_decode_kernel_bf16_compiles(sds, no_persistent_cache, heads):
+    H, K = heads
+    S, N, bmax = 8, 1024, MAX_LEN // BS
+
+    cache = sds((N, BS, K, D), jnp.bfloat16)
+    _compile(
+        lambda q, k, v, bt, cl: paged_decode_attention_pallas(
+            q, k, v, bt, cl, scale=SCALE, sliding_window=WINDOW
+        ),
+        sds((S, H, D), jnp.bfloat16), cache, cache,
+        sds((S, bmax), jnp.int32), sds((S,), jnp.int32),
+    )
+
+
+# The engine always gathers max_model_len of prefix slots (masked by
+# cached_len), so C = MAX_LEN is the shape every served prefill compiles;
+# C = 0 is the embeddings path (models/llama.py encode).
+@pytest.mark.parametrize(
+    "heads,T,C",
+    [
+        (ONE_CHIP, 256, MAX_LEN),
+        (ONE_CHIP, 2048, MAX_LEN),
+        (ONE_CHIP, 2048, 0),
+        (TP4_SHARD, 2048, MAX_LEN),
+    ],
+    ids=["H32K8-T256-C8192", "H32K8-T2048-C8192", "H32K8-T2048-C0",
+         "tp4-H8K2-T2048-C8192"],
+)
+def test_flash_prefill_kernel_compiles(sds, no_persistent_cache, heads, T, C):
+    H, K = heads
+
+    new, prefix = sds((T, K, D), jnp.bfloat16), sds((C, K, D), jnp.bfloat16)
+    _compile(
+        lambda q, k, v, kp, vp, cached, valid: flash_prefill_attention(
+            q, k, v, kp, vp, cached, valid,
+            scale=SCALE, sliding_window=WINDOW,
+        ),
+        sds((T, H, D), jnp.bfloat16), new, new, prefix, prefix,
+        sds((), jnp.int32), sds((), jnp.int32),
+    )
+
+
+def test_int8_kv_decode_kernel_is_still_refused(sds, no_persistent_cache):
+    """ROADMAP S10: Mosaic refuses the int8-KV decode kernel (the
+    [N, bs, K] fp32 scale planes are no 128-lane DMA slice), which is why
+    the engine refuses ``--kv-cache-dtype int8`` on a TPU at boot
+    (test_engine_refuses_int8_kv_on_tpu_at_boot).  The day this test fails
+    the kernel compiles: lift the refusal and compile it above instead."""
+    H, K = ONE_CHIP
+    S, N, bmax = 8, 1024, MAX_LEN // BS
+
+    cache = (sds((N, BS, K, D), jnp.int8), sds((N, BS, K), jnp.float32))
+    with pytest.raises(Exception, match="aligned to tiling"):
+        _compile(
+            lambda q, k, v, bt, cl: paged_decode_attention_pallas(
+                q, k, v, bt, cl, scale=SCALE, sliding_window=WINDOW
+            ),
+            sds((S, H, D), jnp.bfloat16), cache, cache,
+            sds((S, bmax), jnp.int32), sds((S,), jnp.int32),
+        )
+
+
+def test_engine_refuses_int8_kv_on_tpu_at_boot():
+    """Not at the first request, and not by quietly taking the gather
+    path: the boot-time report raises on a TPU (steered here by the
+    device report, the way the on-chip guide says a test steers code that
+    asks JAX for its backend)."""
+    from production_stack_tpu.engine.config import config_from_preset
+    from production_stack_tpu.engine.core.engine import LLMEngine
+    from production_stack_tpu.engine.parallel.mesh import single_device_mesh
+
+    class Boot:
+        config = config_from_preset(
+            "tiny-llama", **{"cache.kv_cache_dtype": "int8"}
+        )
+        mesh = single_device_mesh()
+
+        def device_report(self):
+            return {"platform": "tpu", "kind": "TPU v5 lite", "count": 1,
+                    "mesh": {"dp": 1, "tp": 1, "sp": 1}}
+
+    with pytest.raises(ValueError, match="kv-cache-dtype int8"):
+        LLMEngine._report_device_and_kernels(Boot())
+    Boot.config.cache.kv_cache_dtype = "auto"
+    LLMEngine._report_device_and_kernels(Boot())  # bf16 KV boots
+
+
+def test_kv_pool_is_sized_from_every_device_and_never_guessed_on_a_tpu():
+    """On the CPU 512 blocks; on an accelerator weights + KV stay inside
+    hbm_utilization of the fullest device, and a device that reports no
+    memory limit is an error, not a silent 512-block (8k-token) pool."""
+    from production_stack_tpu.engine.config import config_from_preset
+    from production_stack_tpu.engine.core.engine import LLMEngine
+
+    GB = 10**9
+
+    class Boot:
+        config = config_from_preset("tiny-llama")
+        _kv_bytes = LLMEngine._kv_bytes
+        memory = []
+
+        def device_report(self):
+            return {"platform": "tpu", "memory": self.memory}
+
+    boot = Boot()
+    per_block = boot._kv_bytes(1)
+    boot.memory = [
+        {"id": 0, "bytes_limit": 16 * GB, "bytes_in_use": 4 * GB},
+        {"id": 1, "bytes_limit": 16 * GB, "bytes_in_use": 8 * GB},  # fullest
+    ]
+    assert LLMEngine._decide_num_blocks(boot) == int(
+        (0.9 * 16 * GB - 8 * GB) // per_block
+    )
+    boot.memory = [{"id": 0, "bytes_limit": None, "bytes_in_use": None}]
+    with pytest.raises(RuntimeError, match="no bytes_limit"):
+        LLMEngine._decide_num_blocks(boot)
+    boot.device_report = lambda: {"platform": "cpu", "memory": boot.memory}
+    assert LLMEngine._decide_num_blocks(boot) == 512

@@ -1,8 +1,8 @@
 """The engine's batched encode lane (server/encode_batcher.py;
 docs/engine.md "The encode lane") on the CPU tiny-llama preset:
 
-* one [B, T] forward serves a multi-text request, bit-identical to the
-  serial per-text path (the --no-encode-lane fallback);
+* one [B, T] forward serves a multi-text request, equal to the serial
+  per-text path (the --no-encode-lane fallback) within float32 rounding;
 * REGRESSION PIN: encode work never touches the device off the step
   thread — every encode_batch dispatch runs on "engine-step-loop";
 * the PR-5 overload contract on the encode surface: structured 429 +
@@ -54,18 +54,26 @@ async def _server(**overrides):
     return server, engine
 
 
-# -- one forward, bit-identical to serial ------------------------------------
+# -- one forward, equal to serial within float32 rounding --------------------
 
 
-def test_encode_batch_matches_serial_embed_bitexact():
+def test_encode_batch_matches_serial_embed():
     eng = tiny_engine()
     texts = ["the cat sat on the mat", "quarterly revenue grew 8%", "hi"]
     ids = [eng.tokenizer.encode(t) for t in texts]
     batched = eng.encode_batch(ids)
     for vec, token_ids in zip(batched, ids):
         # Same forward, different batching: vmap over the single-text
-        # encode, so the lane's ON/OFF answers are indistinguishable.
-        assert np.array_equal(np.asarray(vec), np.asarray(eng.embed(token_ids)))
+        # encode.  XLA compiles the [B, T] program with another summation
+        # order than the [T] one, so the float32 vectors agree to a few
+        # ulps (observed: one), not bit for bit.  Components of an
+        # L2-normalized vector are at most 1, where one float32 ulp is
+        # 6e-8: atol 1e-6 is ~16 of them, and a wrong row or a padding
+        # leak is off by 1e-2 or more.
+        np.testing.assert_allclose(
+            np.asarray(vec), np.asarray(eng.embed(token_ids)),
+            rtol=0, atol=1e-6,
+        )
     # Only batched texts count (the serial embed path predates the
     # counter and bench's serial leg must read as zero lane traffic).
     assert eng.stats()["encode_texts_total"] == len(texts)

@@ -30,6 +30,20 @@ from production_stack_tpu.testing.fleet import FleetHarness
 from tests.test_router_e2e import start_fake_engine, start_router
 
 
+async def _encode_cache_stored(app, entries: int = 1) -> None:
+    """The store runs as a background task after the response: wait for
+    its outcome (the cache holds the entry), not for a length of time
+    that a loaded machine overruns."""
+    from production_stack_tpu.router.encode_cache import ENCODE_CACHE_SERVICE
+
+    cache = app["registry"].get(ENCODE_CACHE_SERVICE)
+    for _ in range(2000):
+        if cache.size >= entries:
+            return
+        await asyncio.sleep(0.01)
+    raise AssertionError(f"encode cache never stored {entries} entries")
+
+
 def eps(*urls, roles=None):
     return [
         EndpointInfo(url=u, model_names=["m"], role=(roles[i] if roles else None))
@@ -135,8 +149,7 @@ async def test_repeat_embeddings_served_from_cache_byte_identical():
             assert "x-encode-cache" not in first.headers
             first_bytes = await first.read()
             assert state.encode_texts_total == 2
-            # The store runs as a background task after the response.
-            await asyncio.sleep(0.05)
+            await _encode_cache_stored(app)
             second = await client.post("/v1/embeddings", json=body)
             assert second.status == 200
             assert second.headers.get("x-encode-cache") == "hit"
@@ -169,7 +182,7 @@ async def test_cache_hits_are_engine_independent():
             body = {"model": "m", "input": "fleet-stable doc"}
             r1 = await client.post("/v1/embeddings", json=body)
             b1 = await r1.read()
-            await asyncio.sleep(0.05)
+            await _encode_cache_stored(app)
             r2 = await client.post("/v1/embeddings", json=body)
             b2 = await r2.read()
             assert r2.headers.get("x-encode-cache") == "hit"
@@ -215,7 +228,7 @@ async def test_rerank_similarity_tier_e2e():
             assert r.status == 200
             stored_bytes = await r.read()
             # Background store vectorizes the query through the engine.
-            await asyncio.sleep(0.1)
+            await _encode_cache_stored(app)
             base_texts = state.encode_texts_total
             r = await client.post("/v1/rerank", json={
                 "model": "m", "query": q_near, "documents": docs,

@@ -142,6 +142,33 @@ def test_tracker_wrap_detects_cache_growth_and_keys_executables():
     assert all(r["count"] == 1 and r["seconds"] >= 0.0 for r in rows)
 
 
+def test_count_kernels_lowers_again_from_shapes():
+    """The TPU-only leg of the tracker (how many Pallas kernels the
+    program just compiled holds), on the two argument kinds that broke it
+    on the chip: a donated (deleted) buffer, and an uncommitted scalar
+    beside mesh-sharded arrays.  No kernels on the CPU: zero, not None."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from production_stack_tpu.obs.compile_tracker import count_kernels
+
+    donating = jax.jit(lambda x, n: x * n, donate_argnums=(0,))
+    x = jnp.ones((8, 8))
+    donating(x, jnp.int32(2))
+    assert x.is_deleted()
+    assert count_kernels(donating, (x, jnp.int32(2)), {}) == 0
+
+    mesh = Mesh(np.asarray(jax.devices()[:4]), ("tp",))
+    w = jax.device_put(jnp.ones((8, 8)), NamedSharding(mesh, P("tp", None)))
+    sharded = jax.jit(lambda w, n: w * n)
+    sharded(w, jnp.int32(3))  # the scalar is uncommitted, on device 0
+    assert count_kernels(sharded, (w,), {"n": jnp.int32(3)}) == 0
+    # A callable that cannot be lowered again is None, never an exception.
+    assert count_kernels(_FakeJit(), (4,), {}) is None
+
+
 def test_tracker_disabled_wrap_is_identity():
     tracker = CompileTracker(enabled=False)
     fn = _FakeJit()

@@ -3,8 +3,8 @@
 
 Covers every file in engine/parallel/: mesh construction, sharding specs
 applied through a real engine, ring attention vs the dense reference, and
-full engine generation parity across (dp, tp, sp) layouts — the in-process
-counterpart of the driver's ``__graft_entry__.dryrun_multichip``.
+full engine generation parity across (dp, tp, sp) layouts.  The same tp
+path on four real chips: ``python chip_smoke.py --chips 4``.
 """
 
 from functools import partial
@@ -13,7 +13,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from production_stack_tpu.engine.parallel.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from production_stack_tpu.engine.config import (

@@ -119,3 +119,117 @@ def test_embed_works_quantized():
 def test_unknown_quantization_rejected():
     with pytest.raises(ValueError, match="quantization"):
         ModelConfig(quantization="fp4")
+
+
+# -- parameters made, quantized and placed in their final sharding ----------
+#
+# Bring-up on the chip: the whole bf16 model used to be built on the default
+# device, quantized there and only then put on the mesh, which cannot fit a
+# 7B model on one 16 GB chip and piles a tp=4 model on device 0.  Now each
+# tensor is created (or read), quantized and placed by itself.
+
+
+def _tp2(quantization=None, dtype="float32"):
+    from production_stack_tpu.engine.parallel import shardings as sh
+    from production_stack_tpu.engine.parallel.mesh import build_mesh
+
+    cfg = ModelConfig(dtype=dtype, quantization=quantization)
+    mesh = build_mesh(ParallelConfig(tensor_parallel=2))
+    return cfg, sh.param_shardings(cfg, mesh)
+
+
+def _assert_trees_equal(got, want):
+    """Bit for bit, but for the int8 form: a scale computed inside one
+    jitted program and one computed op by op differ by a float32 ulp
+    (amax / 127 fuses differently), which can move a weight that sits on
+    a rounding boundary by one int8 step."""
+    got_leaves, got_def = jax.tree_util.tree_flatten(got)
+    want_leaves, want_def = jax.tree_util.tree_flatten(want)
+    assert got_def == want_def
+    quantized = any(x.dtype == jnp.int8 for x in want_leaves)
+    for g, w in zip(got_leaves, want_leaves):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        g, w = np.asarray(g), np.asarray(w)
+        if not quantized:
+            np.testing.assert_array_equal(g, w)
+        elif w.dtype == np.int8:
+            assert np.abs(g.astype(np.int32) - w.astype(np.int32)).max() <= 1
+        else:
+            np.testing.assert_allclose(g, w, rtol=1e-6, atol=0)
+
+
+@pytest.mark.parametrize("quantization", [None, "int8"])
+def test_init_params_in_final_sharding_matches_whole_model_path(quantization):
+    cfg, shardings = _tp2(quantization)
+    want = llama.quantize_params(
+        llama.init_params(cfg, jax.random.PRNGKey(3)), cfg
+    )
+    got = llama.init_params(cfg, jax.random.PRNGKey(3), shardings)
+    _assert_trees_equal(got, want)
+    # Every leaf landed where the sharding tree says; a projection spans
+    # both devices of the tp axis.
+    for x, want_sharding in zip(
+        jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(shardings)
+    ):
+        assert x.sharding.is_equivalent_to(want_sharding, x.ndim), x.shape
+    q_proj = jax.tree_util.tree_leaves(got["layers"][0]["q_proj"])[0]
+    assert len(q_proj.sharding.device_set) == 2
+
+
+def _save_hf_checkpoint(params, path):
+    from safetensors.numpy import save_file
+
+    tensors = {
+        "model.embed_tokens.weight": params["embed_tokens"],
+        "model.norm.weight": params["norm"],
+        "lm_head.weight": params["lm_head"].T,
+    }
+    names = {
+        "q_proj": "self_attn.q_proj", "k_proj": "self_attn.k_proj",
+        "v_proj": "self_attn.v_proj", "o_proj": "self_attn.o_proj",
+        "gate_proj": "mlp.gate_proj", "up_proj": "mlp.up_proj",
+        "down_proj": "mlp.down_proj",
+    }
+    for i, layer in enumerate(params["layers"]):
+        p = f"model.layers.{i}."
+        tensors[p + "input_layernorm.weight"] = layer["input_layernorm"]
+        tensors[p + "post_attention_layernorm.weight"] = layer[
+            "post_attention_layernorm"
+        ]
+        for ours, theirs in names.items():
+            tensors[p + theirs + ".weight"] = layer[ours].T  # torch [out, in]
+    save_file(
+        {k: np.ascontiguousarray(np.asarray(v)) for k, v in tensors.items()},
+        str(path / "model.safetensors"),
+    )
+
+
+@pytest.mark.parametrize("quantization", [None, "int8"])
+def test_load_params_reads_quantizes_and_places_a_checkpoint(
+    tmp_path, quantization
+):
+    from production_stack_tpu.engine.models.weights import load_params
+
+    cfg, shardings = _tp2(quantization)
+    params = llama.init_params(cfg, jax.random.PRNGKey(5))
+    _save_hf_checkpoint(params, tmp_path)
+    want = llama.quantize_params(params, cfg)
+    _assert_trees_equal(load_params(cfg, str(tmp_path), shardings=shardings), want)
+    _assert_trees_equal(load_params(cfg, str(tmp_path)), params)
+
+
+def test_load_params_raises_on_a_path_it_cannot_load(tmp_path):
+    """A --weights-path that fails to load used to log and serve random
+    weights.  It raises: nobody asked for those."""
+    from production_stack_tpu.engine.models.weights import load_params
+
+    cfg = ModelConfig(dtype="float32")
+    with pytest.raises(FileNotFoundError):
+        load_params(cfg, str(tmp_path / "missing"))
+    with pytest.raises(KeyError):  # a directory with no tensors in it
+        load_params(cfg, str(tmp_path))
+    # No path at all is the seeded random init, as before.
+    _assert_trees_equal(
+        load_params(cfg, None, seed=7),
+        llama.init_params(cfg, jax.random.PRNGKey(7)),
+    )
