@@ -1,0 +1,317 @@
+import json
+import os
+import sys
+import types
+
+import pytest
+
+from conftest import BENCH
+from generators import open_loop, sessions
+from harness import layers
+from harness.client import Record
+from reduce import stats, xplane
+
+DATA = os.path.join(BENCH, "tests", "data")
+
+
+def traffic(name):
+    with open(os.path.join(BENCH, "traffic", name + ".json")) as f:
+        return json.load(f)
+
+
+# -- the arrival schedule and the lengths are a pure function of the seed --
+
+
+def test_open_loop_schedule_is_a_pure_function_of_the_seed():
+    t = traffic("chat-steady")
+    a = open_loop.schedule(t, 4.0, 45, seed=3_000_000_001)
+    b = open_loop.schedule(t, 4.0, 45, seed=3_000_000_001)
+    c = open_loop.schedule(t, 4.0, 45, seed=7)
+    assert a == b and a != c
+    assert len(a) == 180 and a[0]["at"] == 0.0 and a[-1]["at"] < 45
+    # Every seed: the same arrivals and sizes; only the bytes differ.
+    strip = lambda plan: [{k: v for k, v in p.items() if k != "text"}
+                          for p in plan]
+    assert strip(a) == strip(c)
+    assert all(32 <= p["prompt_tokens"] <= 2048
+               and 16 <= p["output_tokens"] <= 512 for p in a)
+    # No two prompts share a 16-byte block at the start.
+    assert len({p["text"][:16] for p in a}) == len(a)
+
+
+def test_sessions_population_is_a_pure_function_of_the_seed():
+    t = traffic("sessions-prefix")
+    a = sessions.population(t, 11, "u")
+    b = sessions.population(t, 11, "u")
+    c = sessions.population(t, 12, "u")
+    msgs = lambda pop: [s.messages for s in pop["sessions"]]
+    assert msgs(a) == msgs(b) and msgs(a) != msgs(c)
+    assert a["system"] == c["system"] and len(a["system"]) == 1000
+    assert [s.round for s in a["sessions"]] == [i % 8 for i in range(12)]
+    # User 3 starts at round 3: system, history + question... 3 answers in.
+    roles = [m["role"] for m in a["sessions"][3].messages]
+    assert roles == ["system", "user", "assistant", "user", "assistant",
+                     "user", "assistant"]
+    # A round's prompt is a prefix of the next round's.
+    s = a["sessions"][0]
+    s.ask()
+    before = json.dumps(s.messages)[:-1]
+    s.answer()
+    s.ask()
+    assert json.dumps(s.messages).startswith(before)
+    thinks = sessions.think_times(t, a["rngs"][0])
+    assert sum(thinks) / len(thinks) == pytest.approx(0.5, rel=0.05)
+
+
+# -- percentile, tpot and failure arithmetic on a hand-made record set -----
+
+
+def rec(due, first=None, last=None, asked=11, got=11, status=200, done=True,
+        ended=None, error=None, reason="length"):
+    return Record(phase="measure", due=due, asked=asked, sent=due + 0.001,
+                  first=first, last=last,
+                  ended=ended if ended is not None else last,
+                  status=status, error=error, finish_reason=reason,
+                  prompt_tokens=100, completion_tokens=got, done=done)
+
+
+def test_percentile_and_supported_percentile():
+    assert stats.percentile([1, 2, 3, 4, 5], 50) == 3
+    assert stats.percentile(list(range(101)), 95) == 95
+    assert stats.percentile([10, 20], 95) == pytest.approx(19.5)
+    assert stats.highest_supported_percentile(200) == 95.0
+    assert stats.highest_supported_percentile(100) == 90.0
+    assert stats.highest_supported_percentile(8) == 0.0
+
+
+def test_summary_counts_a_429_a_short_answer_and_an_undrained_request():
+    t0, seconds, drain = 100.0, 10.0, 2.0
+    records = [
+        rec(101.0, first=101.2, last=102.2),              # ok: tpot 100 ms
+        rec(102.0, first=102.4, last=104.4),              # ok: tpot 200 ms
+        rec(103.0, status=429, done=False, ended=103.01),  # shed
+        rec(104.0, first=104.1, last=104.5, got=7),       # short answer
+        rec(109.5, first=110.5, last=113.0, ended=113.0),  # past the drain
+        rec(109.0, first=109.2, last=None, done=False, ended=None),  # open
+        rec(99.0, first=99.5, last=100.5),    # pre-roll: due before t0
+        rec(110.5, first=110.6, last=110.9),  # due after the window
+    ]
+    records[5].ended = 200.0
+    s = stats.summarize(records, t0, seconds, drain)
+    assert s["attempted"] == 6 and s["failed"] == 4
+    assert s["failure_reasons"] == {
+        "status 429": 1, "short answer": 1, "not drained": 2}
+    assert s["metrics"]["ttft_p50_ms"] == pytest.approx(300.0)
+    assert s["metrics"]["ttft_mean_ms"] == pytest.approx(300.0)
+    assert s["metrics"]["tpot_p95_ms"] == pytest.approx(195.0)
+    # Finished inside [100, 110): records 0, 1, the short one and the
+    # pre-roll one -> 11 + 11 + 7 + 11 tokens over 10 s.
+    assert s["metrics"]["out_tok_s"] == pytest.approx(4.0)
+    assert s["samples"] == {"ttft": 2, "tpot": 2, "finished_inside": 4}
+
+
+def test_client_ttft_leaves_out_what_the_trace_stalled():
+    from readers import client_ttft
+
+    records = [rec(100.0 + i, first=100.1 + i, last=100.5 + i)
+               for i in range(8)]
+    records += [rec(108.0, first=130.0, last=131.0),   # due under the trace
+                rec(109.0, first=130.0, last=131.0)]
+    got = {"t0": 100.0, "seconds": 10.0, "drain_s": 75.0, "wall_t0": 5000.0}
+    ctx = types.SimpleNamespace(records=records, got=got)
+    assert client_ttft.read(ctx, {"percentile": 95}) > 20000.0
+    got["trace_wall"] = [5007.5, 5009.0]
+    assert client_ttft.read(ctx, {"percentile": 95}) == pytest.approx(100.0)
+    assert client_ttft.read(
+        types.SimpleNamespace(records=[], got=got), {"percentile": 95}) is None
+
+
+# -- the trace reduction on a small recorded trace -------------------------
+
+
+def test_union_and_reduce_on_hand_made_planes():
+    assert xplane.union([(0, 2), (1, 3), (5, 6), (6, 7), (9, 9.5)]) == [
+        (0, 3), (5, 7), (9, 9.5)]
+    planes = [
+        {"name": "/host:CPU", "lines": [{"name": "python", "events": [
+            ("x", 0.0, 1e9)]}]},
+        {"name": "/device:TPU:0", "lines": [
+            {"name": "XLA Modules", "events": [
+                ("jit_window_fn(1)", 1e9, 3e8), ("jit_prefill_fn(2)", 2e9, 1e8),
+                ("jit_window_fn(1)", 3e9, 3e8)]},
+            {"name": "XLA Ops", "events": [
+                ("fusion.1", 1e9, 2e8), ("paged.2", 1.1e9, 2e8),
+                ("fusion.1", 2e9, 1e8), ("fusion.1", 3e9, 3e8)]}]},
+    ]
+    out = xplane.reduce(planes, "tpu")
+    assert out["busy_s"] == pytest.approx(0.7)
+    assert out["window_s"] == pytest.approx(2.3)
+    assert out["programs"][0] == ["window_fn", pytest.approx(0.6), 2]
+    assert out["ops"][0][:2] == ["fusion", pytest.approx(0.6)]
+    assert out["gaps"][0][0] == pytest.approx(0.9)
+    assert [m[3] for m in out["modules"]] == [
+        {"fusion": 1, "paged": 1}, {"fusion": 1}, {"fusion": 1}]
+    assert xplane.reduce(planes, "cpu")["busy_s"] is None
+
+
+def test_reduce_gives_the_known_busy_share_of_the_recorded_trace():
+    path = os.path.join(DATA, "trace_small.json")
+    sys.path.insert(0, os.path.join(BENCH, "tools"))
+    try:
+        from slice_trace import expand
+    finally:
+        sys.path.pop(0)
+    with open(path) as f:
+        planes = expand(json.load(f))
+    with open(os.path.join(DATA, "trace_small.expected.json")) as f:
+        want = json.load(f)
+    out = xplane.reduce(planes, "tpu")
+    # An independent count: paint every operation onto a 0.1 us grid.
+    ops = [ln for ln in planes[0]["lines"] if ln["name"] == "XLA Ops"][0]
+    lo = min(s for _n, s, _d in ops["events"])
+    hi = max(s + d for _n, s, d in ops["events"])
+    grid = bytearray(int((hi - lo) / 1e2) + 2)
+    for _n, s, d in ops["events"]:
+        a, b = int((s - lo) / 1e2), int((s + d - lo) / 1e2)
+        grid[a:b + 1] = b"\x01" * (b + 1 - a)
+    assert out["busy_s"] == pytest.approx(sum(grid) / 1e7, rel=0.05)
+    assert out["busy_s"] == pytest.approx(want["busy_s"], rel=1e-9)
+    assert out["window_s"] == pytest.approx(want["window_s"], rel=1e-9)
+    assert out["programs"][0][0] == want["top_program"]
+    assert 0 < out["busy_s"] < out["window_s"]
+    assert len(out["modules"]) >= 3 and out["gaps"]
+
+
+# -- new files, no edit -----------------------------------------------------
+
+
+def test_a_dropped_in_config_traffic_generator_and_metric_are_found(tmp_path):
+    """A later PR's cell: a benchmark entry plus new files, none edited."""
+    import run
+
+    with open(os.path.join(BENCH, "..", "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    bench["configs"].append({
+        "name": "new-model", "source": "https://example.org/new",
+        "file": "bench/configs/new-model.json", "reduced": [], "why": "new"})
+    bench["workloads"].append({
+        "name": "new-model.bursts", "config": "new-model",
+        "traffic": "bursts", "chips": 1, "why": "new"})
+    bench["per_layer"].append({
+        "name": "answer_42", "unit": "x", "better": "higher",
+        "source": "program_counter", "layer": "Scheduler",
+        "moves": "out_tok_s", "workloads": ["new-model.bursts"]})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    root = tmp_path / "bench"
+    for d in ("configs", "traffic", "cells", "layer_metrics", "generators",
+              "readers"):
+        (root / d).mkdir(parents=True)
+    (root / "configs" / "new-model.json").write_text(
+        json.dumps({"model": "tiny-llama", "engine_argv": []}))
+    (root / "traffic" / "bursts.json").write_text(
+        json.dumps({"generator": "bursty", "burst": 3}))
+    (root / "cells" / "new-model.bursts.json").write_text(
+        json.dumps({"rate_rps": 9.0}))
+    (root / "generators" / "bursty.py").write_text(
+        "def plan(traffic, cell):\n"
+        "    return [cell['rate_rps']] * traffic['burst']\n")
+    (root / "layer_metrics" / "answer_42.json").write_text(
+        json.dumps({"reader": "fortytwo", "args": {"times": 2}}))
+    (root / "readers" / "fortytwo.py").write_text(
+        "def read(ctx, args):\n    return 21.0 * args['times']\n")
+
+    import importlib
+
+    _bench, cell, config, tr, cell_params, dirs = run.resolve(
+        str(tmp_path / "BENCHMARK.json"), "new-model.bursts")
+    try:
+        assert config["model"] == "tiny-llama" and cell_params["rate_rps"] == 9.0
+        gen = importlib.import_module("generators." + tr["generator"])
+        assert gen.plan(tr, cell_params) == [9.0, 9.0, 9.0]
+        # The new metric by its new reader, an old one by a reader that is
+        # there; a reader that finds nothing gives None.
+        got = layers.read_all(
+            types.SimpleNamespace(late_ms=[], delta=lambda family: None,
+                                  dirs=dirs),
+            ["answer_42", "gen_late_p95_ms", "prefix_hit_share"])
+        assert got == {"answer_42": 42.0, "gen_late_p95_ms": None,
+                       "prefix_hit_share": None}
+        # The cells that are there resolve as before.
+        assert run.resolve(str(tmp_path / "BENCHMARK.json"),
+                           "m7b-int8.chat-steady")[2]["model"] == "mistral-7b"
+    finally:
+        sys.path.remove(str(root))
+        for name in ("generators.bursty", "readers.fortytwo"):
+            sys.modules.pop(name, None)
+
+
+def test_every_name_in_the_benchmark_file_has_its_files():
+    import run
+
+    path = os.path.join(BENCH, "..", "BENCHMARK.json")
+    with open(path) as f:
+        bench = json.load(f)
+    ends = {m["name"] for m in bench["end_to_end"]}
+    for cell in bench["workloads"]:
+        _b, _c, config, tr, _p, dirs = run.resolve(path, cell["name"])
+        assert os.path.exists(os.path.join(
+            BENCH, "generators", tr["generator"] + ".py"))
+        assert os.path.exists(os.path.join(
+            BENCH, "reference", config["compare"]["reference"] + ".py"))
+    for m in bench["per_layer"]:
+        spec = layers.spec_of(m["name"], [BENCH])
+        assert os.path.exists(os.path.join(
+            BENCH, "readers", spec["reader"] + ".py")), m["name"]
+        assert m["moves"] in ends
+    # What a per-layer metric moves is reported in every cell where it is.
+    cells = {w["name"] for w in bench["workloads"]}
+    where = {m["name"]: set(m.get("workloads", cells))
+             for m in bench["end_to_end"]}
+    for m in bench["per_layer"]:
+        assert set(m.get("workloads", cells)) <= where[m["moves"]], m["name"]
+
+
+def test_the_benchmark_file_keeps_inside_the_contract_limits():
+    import re
+
+    path = os.path.join(BENCH, "..", "BENCHMARK.json")
+    with open(path) as f:
+        raw = f.read()
+    bench = json.loads(raw)
+    assert len(raw) <= 64 * 1024
+    assert set(bench) == {"command", "paths", "run_seconds", "configs",
+                          "workloads", "end_to_end", "per_layer"}
+    name = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+    unit = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+    line = lambda s: 1 <= len(s) <= 200 and "\n" not in s and "\t" not in s
+    assert 1 <= bench["run_seconds"] <= 51
+    for c in bench["configs"]:
+        assert set(c) == {"name", "source", "file", "reduced", "why"}
+        assert name.match(c["name"]) and line(c["source"]) and line(c["why"])
+        assert c["file"].startswith(bench["paths"][0] + "/")
+        assert any(w["config"] == c["name"] for w in bench["workloads"])
+    pairs = set()
+    for w in bench["workloads"]:
+        assert set(w) == {"name", "config", "traffic", "chips", "why"}
+        assert name.match(w["name"]) and name.match(w["traffic"])
+        assert line(w["why"]) and w["chips"] in (1, 4)
+        assert (w["config"], w["traffic"]) not in pairs
+        pairs.add((w["config"], w["traffic"]))
+    ends = {m["name"] for m in bench["end_to_end"]}
+    assert "setup_s" in ends
+    for m in bench["end_to_end"]:
+        assert set(m) - {"workloads"} == {"name", "unit", "better", "bound",
+                                          "source"}
+        assert 0.01 <= m["bound"] <= 0.1
+        assert m["source"] in ("host_clock", "device_trace")
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        assert name.match(m["name"]) and unit.match(m["unit"])
+        assert m["better"] in ("lower", "higher")
+    for m in bench["per_layer"]:
+        assert set(m) - {"workloads"} == {"name", "unit", "better", "source",
+                                          "layer", "moves"}
+        assert m["moves"] in ends and line(m["layer"])
+        assert m["source"] in ("device_trace", "program_span",
+                               "program_counter", "host_clock")
+    names = [m["name"] for m in bench["end_to_end"] + bench["per_layer"]]
+    assert len(names) == len(set(names))
