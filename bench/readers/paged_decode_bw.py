@@ -1,0 +1,37 @@
+"""The paged decode kernel against the chip's memory bandwidth: the bytes of
+keys and values it must read for the traced programs that hold it
+(``reduce/kv_bytes.py``, from the context lengths their flight records
+carry) over the published bytes/s, over the kernel's seconds in the trace.
+K and V only, counted for the very programs in the trace: what the
+algorithm needs, so the share cannot pass 100 % unless the join is
+wrong."""
+
+from reduce import join
+from reduce.kv_bytes import decode_read_bytes, kv_shards
+
+
+def read(ctx, args):
+    got = join.joined(ctx)
+    if got is None:
+        return None
+    marker = args["marker"]
+    seconds = sum(s for name, s, _n in ctx.trace["ops"] if name == marker)
+    if not seconds:
+        return None
+    hp = ctx.config["published"]
+    records = ctx.got["windows"]["windows"]
+    matched = dict(got["pairs"])
+    shards = kv_shards(ctx.config)
+    total = 0.0
+    for j, (_name, _start, _dur, inside) in enumerate(ctx.trace["modules"]):
+        calls = inside.get(marker)
+        if not calls:
+            continue
+        if j not in matched:
+            return None   # a program with the kernel that no record owns
+        total += decode_read_bytes(
+            hp, records[matched[j]]["kv_tokens"],
+            calls / hp["num_hidden_layers"], shards,
+            args.get("kv_dtype_bytes", 2))
+    least_s = total / (ctx.peaks()["hbm_gbs"] * 1e9)
+    return 100.0 * least_s / seconds

@@ -25,6 +25,7 @@ the router expects.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import logging
 import threading
 import time
@@ -76,6 +77,20 @@ logger = logging.getLogger(__name__)
 
 def _dtype_size(dtype: str) -> int:
     return jnp.dtype(dtype).itemsize
+
+
+def _enclosed(span: str):
+    """Run an ``LLMEngine`` method under one of the two ENCLOSING phase
+    spans of obs/engine.py: ``dispatch`` (an asynchronous dispatch: its
+    build + launch) or ``mixed`` (a fused step, end to end) — the wall
+    time their ``tpu:step_<span>_seconds`` families have always timed."""
+    def decorate(fn):
+        @functools.wraps(fn)
+        def under_span(self, *args, **kwargs):
+            with self.obs.phase(span):
+                return fn(self, *args, **kwargs)
+        return under_span
+    return decorate
 
 
 @dataclasses.dataclass
@@ -400,9 +415,22 @@ class LLMEngine:
         self._bmax = config.scheduler.max_model_len // config.cache.block_size
         self._smax = config.scheduler.max_num_seqs
 
+        # Observability hub: request tracer + step-phase spans/histograms
+        # + window flight recorder + compile-event tracker (all hooks
+        # no-op when config.obs.tracing is off).  Made before the jitted
+        # step functions, which are named and tracked through it (_jit).
+        self.obs = EngineObs(
+            enabled=config.obs.tracing,
+            ring_size=config.obs.trace_ring_size,
+            ring_bytes=config.obs.trace_ring_bytes,
+            window_ring_size=config.obs.window_ring_size,
+            annotation=jax.profiler.TraceAnnotation,
+        )
+
         # Jitted step functions.  KV caches are donated so updates alias the
         # same HBM; cfg and mesh are closed over (static).
-        self._prefill_fn = jax.jit(
+        self._prefill_fn = self._jit(
+            "prefill_fn",
             partial(
                 self.model.prefill, cfg=cfg, mesh=self.mesh,
                 sp_mode=par.sequence_parallel_mode,
@@ -410,7 +438,8 @@ class LLMEngine:
             donate_argnames=("kv_caches",),
             static_argnames=("prompt_topk",),
         )
-        self._decode_fn = jax.jit(
+        self._decode_fn = self._jit(
+            "decode_fn",
             partial(self.model.decode, cfg=cfg, mesh=self.mesh),
             donate_argnames=("kv_caches",),
         )
@@ -419,7 +448,8 @@ class LLMEngine:
         # per shape, and both axes come from small bucket sets.
         self._mixed_fn = None
         if config.scheduler.mixed_enabled and hasattr(self.model, "mixed_step"):
-            self._mixed_fn = jax.jit(
+            self._mixed_fn = self._jit(
+                "mixed_fn",
                 partial(self.model.mixed_step, cfg=cfg, mesh=self.mesh),
                 donate_argnames=("kv_caches",),
             )
@@ -427,7 +457,7 @@ class LLMEngine:
             # Model without a fused entry point: fall back to alternating
             # plans rather than failing at the first mixed dispatch.
             config.scheduler.mixed_batch = False
-        self._sample_fn = jax.jit(sample_tokens)
+        self._sample_fn = self._jit("sample_fn", sample_tokens)
 
         # K-step device-resident decode windows (tentpole of the unified
         # StepPlan path; vLLM --num-scheduler-steps made the default):
@@ -571,7 +601,8 @@ class LLMEngine:
                 }
                 return emitted, state, kv_caches
 
-            self._window_fn = jax.jit(
+            self._window_fn = self._jit(
+                "window_fn",
                 multi_window,
                 static_argnames=("use_penalties", "use_min_floor"),
                 donate_argnames=("kv_caches",),
@@ -1017,7 +1048,8 @@ class LLMEngine:
                     )
                 return emitted, drafted, accepted, state, kv_caches
 
-            self._spec_window_fn = jax.jit(
+            self._spec_window_fn = self._jit(
+                "spec_window_fn",
                 spec_window,
                 static_argnames=(
                     "use_penalties", "use_min_floor", "do_prime",
@@ -1043,9 +1075,10 @@ class LLMEngine:
                     jnp.where(valid, vals, keep)
                 )
 
-            self._win_advance_fn = jax.jit(win_advance)
-            self._win_occurrence_fn = jax.jit(
-                partial(sampling_lib.occurrence_state, vocab_size=vocab)
+            self._win_advance_fn = self._jit("win_advance_fn", win_advance)
+            self._win_occurrence_fn = self._jit(
+                "win_occurrence_fn",
+                partial(sampling_lib.occurrence_state, vocab_size=vocab),
             )
 
         # MIXED K-step windows (the sustained-arrival fusion): a waiting
@@ -1230,16 +1263,20 @@ class LLMEngine:
                     state["hist"] = hist
                 return emitted, tails, state, kv_caches
 
-            self._mixed_window_fn = jax.jit(
+            self._mixed_window_fn = self._jit(
+                "mixed_window_fn",
                 mixed_window,
                 static_argnames=(
                     "n_steps", "use_penalties", "use_min_floor",
                 ),
                 donate_argnames=("kv_caches",),
             )
-        self._penalties_fn = jax.jit(sampling_lib.apply_penalties)
-        self._argmax_fn = jax.jit(
-            lambda logits: jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        self._penalties_fn = self._jit(
+            "penalties_fn", sampling_lib.apply_penalties
+        )
+        self._argmax_fn = self._jit(
+            "argmax_fn",
+            lambda logits: jnp.argmax(logits, axis=-1).astype(jnp.int32),
         )
         # Speculative decoding effectiveness counters (fed by the
         # legacy host-side n-gram path and the fused window path, both
@@ -1280,8 +1317,9 @@ class LLMEngine:
             self._draft_cost_fraction = (draft_rows * dft_n) / (
                 draft_rows * dft_n + (d_len + 1) * tgt_n
             )
-        self._logprobs_fn = jax.jit(
-            sampling_lib.top_logprobs_of, static_argnames=("k",)
+        self._logprobs_fn = self._jit(
+            "logprobs_fn", sampling_lib.top_logprobs_of,
+            static_argnames=("k",),
         )
 
         # Multi-LoRA slot arrays (engine/lora.py); None keeps the model's
@@ -1293,35 +1331,6 @@ class LLMEngine:
             self.lora_registry = AdapterRegistry(
                 cfg, config.lora, jnp.dtype(cfg.dtype)
             )
-
-        # Observability hub: request tracer + step-phase/latency histograms
-        # + window flight recorder + compile-event tracker (all hooks
-        # no-op when config.obs.tracing is off).
-        self.obs = EngineObs(
-            enabled=config.obs.tracing,
-            ring_size=config.obs.trace_ring_size,
-            ring_bytes=config.obs.trace_ring_bytes,
-            window_ring_size=config.obs.window_ring_size,
-        )
-        # Wrap every jit entry point in the compile tracker's cache-size
-        # probe so XLA compiles are counted/timed per executable shape key
-        # (tpu:compile_seconds_total{executable}, GET /debug/compiles).
-        # With tracing off wrap() is the identity, keeping bare jit
-        # callables — the untraced fast path is byte-identical.
-        for _jit_name in (
-            "_prefill_fn", "_decode_fn", "_mixed_fn", "_sample_fn",
-            "_window_fn", "_spec_window_fn", "_mixed_window_fn",
-            "_win_advance_fn", "_win_occurrence_fn", "_penalties_fn",
-            "_argmax_fn", "_logprobs_fn",
-        ):
-            _jit_fn = getattr(self, _jit_name, None)
-            if _jit_fn is not None:
-                setattr(
-                    self, _jit_name,
-                    self.obs.compile_tracker.wrap(
-                        _jit_name.lstrip("_"), _jit_fn
-                    ),
-                )
 
         self._step_counter = 0
         self._encode_fn = None  # lazily jitted /v1/embeddings path
@@ -1488,11 +1497,22 @@ class LLMEngine:
                 "tables": tables,
             }
 
-        self._pipe_unpack_fn = self.obs.compile_tracker.wrap(
-            "pipe_unpack_fn", jax.jit(_pipe_unpack)
-        )
-        self._pipe_advance_fn = self.obs.compile_tracker.wrap(
-            "pipe_advance_fn", jax.jit(_pipe_advance)
+        self._pipe_unpack_fn = self._jit("pipe_unpack_fn", _pipe_unpack)
+        self._pipe_advance_fn = self._jit("pipe_advance_fn", _pipe_advance)
+
+    def _jit(self, name: str, fn, **jit_kwargs):
+        """The ONE place a step function gets its name: jitted under
+        ``name`` (the profiler's ``XLA Modules`` line then reads
+        ``jit_<name>``, where a bare ``partial`` reads ``_unknown``) and
+        wrapped in the compile tracker's cache-size probe under the same
+        name, so XLA compiles are counted and timed per executable shape
+        key (tpu:compile_seconds_total{executable}, GET /debug/compiles)
+        and a flight record's ``programs`` name what the trace shows.
+        With tracing off ``wrap`` is the identity: bare jit callables."""
+        named = partial(fn)
+        named.__name__ = named.__qualname__ = name
+        return self.obs.compile_tracker.wrap(
+            name, jax.jit(named, **jit_kwargs)
         )
 
     # -- sizing ------------------------------------------------------------
@@ -1797,29 +1817,25 @@ class LLMEngine:
         if p.outputs is not None:
             outputs = p.outputs
         elif p.steps is not None:
-            # stackcheck: allow=SC201 reason=t0 only stamps the obs collect-phase histogram inside _collect_window; no plan state reads it
-            outputs = self._collect_window(p, t0)
+            outputs = self._collect_window(p)
         else:
-            arr = np.asarray(p.sampled)  # the ONE device sync point
-            if self.obs.enabled:
-                self.obs.step_phase("collect", time.time() - t0)
-            t_post = time.time()
-            live = [
-                (i, s) for i, s in enumerate(p.seqs) if not s.is_finished
-            ]
-            outputs = self._append_and_check(
-                [s for _, s in live],
-                [int(arr[i]) for i, _ in live],
-                first_token=False,
-            )
-            if self.obs.enabled:
-                self.obs.step_phase("sample", time.time() - t_post)
+            with self.obs.phase("collect", p.rec):
+                arr = np.asarray(p.sampled)  # the ONE device sync point
+            with self.obs.phase("sample", p.rec):
+                live = [
+                    (i, s) for i, s in enumerate(p.seqs) if not s.is_finished
+                ]
+                outputs = self._append_and_check(
+                    [s for _, s in live],
+                    [int(arr[i]) for i, _ in live],
+                    first_token=False,
+                )
             if p.rec is not None:
                 # Sample-side jits (penalties/argmax/logprobs) ran inside
                 # _append_and_check: drain any compiles onto this record,
                 # then complete it.  Rows whose sequence finished while
                 # the step flew sampled a discarded overrun token.
-                self._note_compiles([s.seq_id for s in p.seqs], p.rec)
+                self._note_compiles(p.rec, [s.seq_id for s in p.seqs])
                 self.obs.recorder.on_collect(
                     p.rec, host_s=p.host_s,
                     tokens_emitted=len(p.seqs),
@@ -1856,15 +1872,6 @@ class LLMEngine:
                         d.rec, host_s=d.host_s,
                         tokens_emitted=n, tokens_wasted=n,
                     )
-            if self.obs.enabled:
-                # Only pipelined steps have a pure-dispatch host_s: a
-                # synchronous step's host_s fuses array build, blocking
-                # device compute and sampling, and attributing THAT to
-                # "dispatch" would point slow-step debugging at H2D work
-                # when the time was device compute.  Sync steps feed only
-                # the schedule phase; the dispatch/collect/sample split
-                # covers the steady-state pipelined decode path.
-                self.obs.step_phase("dispatch", p.host_s)
         now = time.time()
         self._last_decode_end = now if p.is_decode else None
         busy = (now - t0) + p.host_s
@@ -1879,14 +1886,13 @@ class LLMEngine:
         """Dispatch with nothing in flight: full scheduler knowledge
         (admission, preemption, partial-prefill rollback) — the only
         place synchronous plans run."""
-        # Land completed remote-prefix prefetches in the prefix cache
-        # BEFORE planning, so this very schedule()'s match_prefix can
-        # serve them (copy-in is an async device dispatch, not a wait).
-        self._drain_prefetched()
-        t0 = time.time()
-        plan = self.scheduler.schedule()
-        if self.obs.enabled:
-            self.obs.step_phase("schedule", time.time() - t0)
+        with self.obs.phase("schedule"):
+            # Land completed remote-prefix prefetches in the prefix cache
+            # BEFORE planning, so this very schedule()'s match_prefix can
+            # serve them (copy-in is an async device dispatch, not a wait).
+            self._drain_prefetched()
+            t0 = time.time()
+            plan = self.scheduler.schedule()
         if plan.is_empty:
             # Nothing schedulable.  If that is because the async transfer
             # plane is mid-flight (a restore page-in or offload stage the
@@ -1895,8 +1901,9 @@ class LLMEngine:
             # faster than the worker threads can land the bytes.  The
             # device is idle here — this is backoff, not a data wait.
             if self._transfer_inflight():
-                # stackcheck: allow=SC101 reason=1ms idle backoff while async transfers land; the device is idle here by definition (nothing scheduled) so this is pacing, not a data wait
-                time.sleep(0.001)
+                with self.obs.phase("wait"):
+                    # stackcheck: allow=SC101 reason=1ms idle backoff while async transfers land; the device is idle here by definition (nothing scheduled) so this is pacing, not a data wait
+                    time.sleep(0.001)
             return False
         if plan.window_fallback:
             # A waiting head forced K=1 stepping (the mixed-window path
@@ -1906,19 +1913,19 @@ class LLMEngine:
                 self.multistep_fallback.get(plan.window_fallback, 0) + 1
             )
         if plan.decode is None:
-            outputs = self._run_prefill(plan.prefill_chunk)
+            cp = plan.prefill_chunk
+            # The synchronous paths open their record before the work, so
+            # that its spans, programs and launch stamps land on it.
+            rec = self._open_record(
+                "prefill", chunks=(cp,), fallback=plan.window_fallback,
+            )
+            self._stamp_record(rec, t0, gap=False)
+            outputs = self._run_prefill(cp, rec)
             self._step_counter += 1
             # stackcheck: allow=SC201 reason=host_s is a stats field (host-gap metric); no plan state reads it
             host_s = time.time() - t0
-            if self.obs.enabled:
-                cp = plan.prefill_chunk
-                rec = self.obs.recorder.on_dispatch(
-                    "prefill", k=1, rows=0, seq_ids=(cp.seq.seq_id,),
-                    chunk_prompts=1,
-                    chunk_tokens_planned=cp.num_new_tokens,
-                    fallback=plan.window_fallback, now=t0,
-                )
-                self._note_compiles((cp.seq.seq_id,), rec)
+            if rec is not None:
+                self._note_compiles(rec)
                 self.obs.recorder.on_collect(
                     rec, host_s=host_s,
                     tokens_emitted=len(outputs),
@@ -1942,23 +1949,18 @@ class LLMEngine:
             # admission/finalization needs collected state), so the
             # lookahead pipeline pauses for the step and resumes on the
             # next pure-decode plan.
-            gap = self._recorder_gap(t0) if self.obs.enabled else 0.0
-            outputs = self._run_mixed(plan)
+            cp = plan.prefill_chunk
+            rec = self._open_record(
+                "mixed", seqs=plan.decode.seqs, chunks=(cp,),
+                fallback=plan.window_fallback,
+            )
+            self._stamp_record(rec, t0)
+            outputs = self._run_mixed(plan, rec)
             self._step_counter += 1
             # stackcheck: allow=SC201 reason=host_s is a stats field (host-gap metric); no plan state reads it
             host_s = time.time() - t0
-            if self.obs.enabled:
-                cp = plan.prefill_chunk
-                sids = tuple(s.seq_id for s in plan.decode.seqs) + (
-                    cp.seq.seq_id,
-                )
-                rec = self.obs.recorder.on_dispatch(
-                    "mixed", k=1, rows=len(plan.decode.seqs),
-                    seq_ids=sids, chunk_prompts=1,
-                    chunk_tokens_planned=cp.num_new_tokens,
-                    fallback=plan.window_fallback, host_gap_s=gap, now=t0,
-                )
-                self._note_compiles(sids, rec)
+            if rec is not None:
+                self._note_compiles(rec)
                 self.obs.recorder.on_collect(
                     rec, host_s=host_s,
                     tokens_emitted=len(outputs),
@@ -1985,18 +1987,14 @@ class LLMEngine:
                 p.rec.fallback = decline
             self._pending.append(p)
         else:
-            gap = self._recorder_gap(t0) if self.obs.enabled else 0.0
-            outputs = self._run_decode(plan.decode)
+            rec = self._open_record("decode", seqs=seqs, fallback=decline)
+            self._stamp_record(rec, t0)
+            outputs = self._run_decode(plan.decode, rec)
             self._step_counter += 1
             # stackcheck: allow=SC201 reason=host_s is a stats field (host-gap metric); no plan state reads it
             host_s = time.time() - t0
-            if self.obs.enabled:
-                sids = tuple(s.seq_id for s in seqs)
-                rec = self.obs.recorder.on_dispatch(
-                    "decode", k=1, rows=len(seqs), seq_ids=sids,
-                    fallback=decline, host_gap_s=gap, now=t0,
-                )
-                self._note_compiles(sids, rec)
+            if rec is not None:
+                self._note_compiles(rec)
                 self.obs.recorder.on_collect(
                     rec, host_s=host_s,
                     tokens_emitted=len(seqs),
@@ -2021,12 +2019,10 @@ class LLMEngine:
         if prev.sampled is None:
             return False  # only pipelined decode steps chain
         if prev.win_state is not None:
-            t0 = time.time()
-            plan = self.scheduler.schedule_provisional_window(
-                prev.seqs, prev.steps
-            )
-            if self.obs.enabled:
-                self.obs.step_phase("schedule", time.time() - t0)
+            with self.obs.phase("schedule"):
+                plan = self.scheduler.schedule_provisional_window(
+                    prev.seqs, prev.steps
+                )
             if plan is None:
                 return False
             if plan.chunk_schedule is not None:
@@ -2041,10 +2037,8 @@ class LLMEngine:
             return True
         if not self._can_pipeline(prev.seqs):
             return False
-        t0 = time.time()
-        plan = self.scheduler.schedule_provisional(prev.seqs)
-        if self.obs.enabled:
-            self.obs.step_phase("schedule", time.time() - t0)
+        with self.obs.phase("schedule"):
+            plan = self.scheduler.schedule_provisional(prev.seqs)
         if plan is None:
             return False
         self._pending.append(
@@ -2116,23 +2110,76 @@ class LLMEngine:
             for s in seqs
         )
 
-    def _recorder_gap(self, t0: float) -> float:
-        """Host gap this dispatch inherited from the previous window
-        (device idle since the last decode retired), stamped onto the
-        flight record so a stalled window's timeline shows WHERE the
-        stall was.  Read before the launch bookkeeping clears it."""
+    def _stamp_record(self, rec, t0: float, gap: bool = True) -> None:
+        """``t0``: when this dispatch began, on the host's clock.  With
+        ``gap``, also the host gap it inherited from the previous window
+        (device idle since the last decode retired), so a stalled
+        window's timeline shows WHERE the stall was — read before the
+        launch bookkeeping clears it."""
+        if rec is None:
+            return
+        rec.dispatched_at = t0
         last = self._last_decode_end
-        return max(0.0, t0 - last) if last is not None else 0.0
+        if gap and last is not None:
+            rec.host_gap_s = max(0.0, t0 - last)
 
-    def _note_compiles(self, seq_ids, rec=None) -> None:
+    def _open_record(
+        self, kind: str, *, seqs=(), chunks=(), ahead=0,
+        bucket_tokens: Optional[int] = None, **fields,
+    ):
+        """Flight record for the dispatch that is about to run: ``seqs``
+        its decode rows, ``chunks`` the PrefillPlans riding it.  Opened
+        BEFORE the work so that the work's phase spans, program launches
+        and stamps land on it; None with tracing off.  ``ahead``: tokens
+        still in flight on the device, one number for every row or the
+        ``_PendingStep`` this dispatch chains from (a lookahead dispatch's
+        rows are that much longer than the host knows).
+        ``bucket_tokens``: the chunk program's token slots where they are
+        not the plans' buckets (a mixed window scans a power of two)."""
+        if not self.obs.enabled:
+            return None
+        bs = self.block_pool.block_size
+        window = self.config.model.sliding_window
+        if isinstance(ahead, _PendingStep):
+            budget = {
+                s.seq_id: n for s, n in zip(ahead.seqs, ahead.steps)
+            }
+            ahead = 0
+        else:
+            budget = {}
+        kv_tokens = 0
+        for s in seqs:
+            ctx = s.num_tokens + budget.get(s.seq_id, ahead)
+            if window is not None:
+                ctx = min(ctx, window)
+            kv_tokens += -(-ctx // bs) * bs
+        first = {}
+        for cp in chunks:
+            first.setdefault(cp.seq.seq_id, cp.cached_len)
+        if bucket_tokens is None:
+            bucket_tokens = sum(cp.bucket_len for cp in chunks)
+        new_tokens = sum(cp.num_new_tokens for cp in chunks)
+        return self.obs.recorder.on_dispatch(
+            kind, rows=len(seqs),
+            seq_ids=tuple(s.seq_id for s in seqs) + tuple(first),
+            chunk_prompts=len(first), chunk_tokens_planned=new_tokens,
+            kv_tokens=kv_tokens, new_tokens=new_tokens,
+            bucket_tokens=bucket_tokens,
+            cached_tokens=sum(first.values()), **fields,
+        )
+
+    def _note_compiles(self, rec, seq_ids=None) -> None:
         """Drain XLA compile events fired inside the jit calls this
         dispatch just made and attribute them: the window flight record
-        goes compile-tainted and every co-scheduled request's trace is
-        tagged compile=true (the compile-excluded-TTFT separator)."""
-        if not self.obs.enabled:
+        goes compile-tainted and every co-scheduled request's trace
+        (``seq_ids``; the record's own by default) is tagged compile=true
+        (the compile-excluded-TTFT separator).  No record: tracing is
+        off."""
+        if rec is None:
             return
         self.obs.on_compile(
-            seq_ids, self.obs.compile_tracker.drain_events(), rec
+            rec.seq_ids if seq_ids is None else seq_ids,
+            self.obs.compile_tracker.drain_events(), rec,
         )
 
     def _note_decode_launch(self) -> None:
@@ -2145,6 +2192,7 @@ class LLMEngine:
             self._gap_steps += 1
         self._last_decode_end = None
 
+    @_enclosed("dispatch")
     def _dispatch_decode_async(
         self, seqs: List[Sequence], lookahead: bool, prev_sampled=None
     ) -> _PendingStep:
@@ -2155,7 +2203,58 @@ class LLMEngine:
         steady "same batch, +1 token" path (one packed [4, S] delta,
         tokens chained from the in-flight sample)."""
         t0 = time.time()
-        gap = self._recorder_gap(t0) if self.obs.enabled else 0.0
+        rec = self._open_record(
+            "decode", seqs=seqs, provisional=lookahead,
+            ahead=1 if lookahead else 0,
+        )
+        self._stamp_record(rec, t0)
+        with self.obs.phase("build", rec):
+            st = self._pipe_state(seqs, lookahead, prev_sampled)
+        self._pipe_tables = st["tables"]
+
+        lora_kwargs = {}
+        if self.lora_registry is not None:
+            lora_kwargs = {
+                "lora": self.lora_registry.params,
+                "adapter_idx": self._pipe_adapter,
+            }
+        if lookahead:
+            self._gap_steps += 1  # device busy: zero gap by construction
+            self._last_decode_end = None
+        else:
+            self._note_decode_launch()
+        with self.obs.phase("launch", rec):
+            logits, self.kv_caches = self._decode_fn(
+                self.params,
+                tokens=st["tokens"],
+                positions=st["positions"],
+                block_tables=st["tables"],
+                ctx_lens=st["ctx_lens"],
+                slot_block_ids=st["slot_blocks"],
+                slot_offsets=st["slot_offsets"],
+                kv_caches=self.kv_caches,
+                **lora_kwargs,
+            )
+            temps, top_ps, top_ks, min_ps, seeds = self._pipe_sampling
+            step_key = jax.random.PRNGKey(
+                self.config.seed + self._step_counter
+            )
+            sampled = self._sample_fn(
+                logits, temps, top_ps, top_ks, step_key, seeds, min_p=min_ps,
+            )
+        self._step_counter += 1
+        self._note_compiles(rec)
+        # stackcheck: allow=SC201 reason=host_s is a stats field (host-gap metric); no plan state reads it
+        return _PendingStep(
+            seqs=list(seqs), sampled=sampled, is_decode=True,
+            host_s=time.time() - t0, rec=rec,
+        )
+
+    def _pipe_state(self, seqs: List[Sequence], lookahead: bool,
+                    prev_sampled=None) -> dict:
+        """Device-resident decode batch state for one pipelined step:
+        rebuilt from host bookkeeping, or (``lookahead``) advanced by one
+        token from the in-flight sample."""
         # Rebuilds pad to the decode batch-size bucket; lookahead steps
         # reuse the device-resident state, whose row count is by
         # construction the same bucket (identical running set).
@@ -2210,49 +2309,7 @@ class LLMEngine:
                 prev_sampled,
                 self._pipe_tables,
             )
-        self._pipe_tables = st["tables"]
-
-        lora_kwargs = {}
-        if self.lora_registry is not None:
-            lora_kwargs = {
-                "lora": self.lora_registry.params,
-                "adapter_idx": self._pipe_adapter,
-            }
-        if lookahead:
-            self._gap_steps += 1  # device busy: zero gap by construction
-            self._last_decode_end = None
-        else:
-            self._note_decode_launch()
-        logits, self.kv_caches = self._decode_fn(
-            self.params,
-            tokens=st["tokens"],
-            positions=st["positions"],
-            block_tables=st["tables"],
-            ctx_lens=st["ctx_lens"],
-            slot_block_ids=st["slot_blocks"],
-            slot_offsets=st["slot_offsets"],
-            kv_caches=self.kv_caches,
-            **lora_kwargs,
-        )
-        temps, top_ps, top_ks, min_ps, seeds = self._pipe_sampling
-        step_key = jax.random.PRNGKey(self.config.seed + self._step_counter)
-        sampled = self._sample_fn(
-            logits, temps, top_ps, top_ks, step_key, seeds, min_p=min_ps,
-        )
-        self._step_counter += 1
-        rec = None
-        if self.obs.enabled:
-            sids = tuple(s.seq_id for s in seqs)
-            rec = self.obs.recorder.on_dispatch(
-                "decode", k=1, rows=len(seqs), seq_ids=sids,
-                provisional=lookahead, host_gap_s=gap, now=t0,
-            )
-            self._note_compiles(sids, rec)
-        # stackcheck: allow=SC201 reason=host_s is a stats field (host-gap metric); no plan state reads it
-        return _PendingStep(
-            seqs=list(seqs), sampled=sampled, is_decode=True,
-            host_s=time.time() - t0, rec=rec,
-        )
+        return st
 
     # -- K-step device-resident decode windows -----------------------------
 
@@ -2475,6 +2532,7 @@ class LLMEngine:
         return state
 
     # stackcheck: root=step-thread
+    @_enclosed("dispatch")
     def _dispatch_window(self, plan, chain_from: Optional[_PendingStep] = None
                          ) -> _PendingStep:
         """Enqueue one K-step decode window on the device and return
@@ -2485,12 +2543,26 @@ class LLMEngine:
         t0 = time.time()
         decode = plan.decode
         seqs = decode.seqs
-        gap = self._recorder_gap(t0) if self.obs.enabled else 0.0
+        depth = 0
+        if chain_from is not None and chain_from.rec is not None:
+            depth = chain_from.rec.chain_depth + 1
+        # Opened as a plain decode window; the fused speculative path
+        # below renames it once it is known to be taken.
+        rec = self._open_record(
+            "decode", seqs=seqs, ahead=chain_from or 0,
+            k=self._window_steps, chain_depth=depth,
+            provisional=chain_from is not None,
+            fallback=plan.window_fallback,
+        )
+        self._stamp_record(rec, t0)
+        with self.obs.phase("build", rec):
+            if chain_from is None:
+                state = self._window_build(seqs, decode.steps)
+            else:
+                state = self._window_chain(chain_from, seqs, decode.steps)
         if chain_from is None:
-            state = self._window_build(seqs, decode.steps)
             self._note_decode_launch()
         else:
-            state = self._window_chain(chain_from, seqs, decode.steps)
             self._gap_steps += 1  # device busy: zero gap by construction
             self._last_decode_end = None
         lora_kwargs = {}
@@ -2546,28 +2618,29 @@ class LLMEngine:
                 }
             else:
                 spec_drafter = "ngram"
-            out = self._spec_window_fn(
-                self.params,
-                tokens=state["tokens"],
-                positions=state["positions"],
-                ctx_lens=state["ctx_lens"],
-                done=state["done"],
-                min_left=state["min_left"],
-                block_tables=state["tables"],
-                max_steps=state["max_steps"],
-                kv_caches=self.kv_caches,
-                stop_ids=state["stop_ids"],
-                counts=state["counts"],
-                seen=state["seen"],
-                hist=state["hist"],
-                presence=state["presence"],
-                frequency=state["frequency"],
-                repetition=state["repetition"],
-                use_penalties=state["use_penalties"],
-                use_min_floor=state["use_min_floor"],
-                **spec_kwargs,
-                **lora_kwargs,
-            )
+            with self.obs.phase("launch", rec):
+                out = self._spec_window_fn(
+                    self.params,
+                    tokens=state["tokens"],
+                    positions=state["positions"],
+                    ctx_lens=state["ctx_lens"],
+                    done=state["done"],
+                    min_left=state["min_left"],
+                    block_tables=state["tables"],
+                    max_steps=state["max_steps"],
+                    kv_caches=self.kv_caches,
+                    stop_ids=state["stop_ids"],
+                    counts=state["counts"],
+                    seen=state["seen"],
+                    hist=state["hist"],
+                    presence=state["presence"],
+                    frequency=state["frequency"],
+                    repetition=state["repetition"],
+                    use_penalties=state["use_penalties"],
+                    use_min_floor=state["use_min_floor"],
+                    **spec_kwargs,
+                    **lora_kwargs,
+                )
             if spec_drafter == "model":
                 (emitted, drafted, accepted, out_state, self.kv_caches,
                  self.draft_kv_caches) = out
@@ -2588,64 +2661,53 @@ class LLMEngine:
             # extending the draft KV: the chain is broken and the next
             # model-spec window must re-prime from `hist`.
             self._draft_primed = False
-            emitted, out_state, self.kv_caches = self._window_fn(
-                self.params,
-                tokens=state["tokens"],
-                positions=state["positions"],
-                ctx_lens=state["ctx_lens"],
-                done=state["done"],
-                min_left=state["min_left"],
-                block_tables=state["tables"],
-                max_steps=state["max_steps"],
-                kv_caches=self.kv_caches,
-                temps=state["temps"],
-                top_ps=state["top_ps"],
-                top_ks=state["top_ks"],
-                min_ps=state["min_ps"],
-                seq_seeds=state["seeds"],
-                stop_ids=state["stop_ids"],
-                # Masked to 31 bits: a long-lived engine's monotone step
-                # counter would otherwise overflow the host->int32 cast
-                # and kill the step thread.  Below 2**31 key ordinals
-                # (years of serving) the schedule is bit-identical to
-                # single-token stepping; past it, +t wraps in-graph,
-                # which PRNGKey treats as bits — still deterministic
-                # across lockstep replicas.
-                key_base=jnp.int32(
-                    (self.config.seed + self._step_counter) & 0x7FFFFFFF
-                ),
-                counts=state["counts"],
-                seen=state["seen"],
-                presence=state["presence"],
-                frequency=state["frequency"],
-                repetition=state["repetition"],
-                use_penalties=state["use_penalties"],
-                use_min_floor=state["use_min_floor"],
-                **lora_kwargs,
-            )
+            with self.obs.phase("launch", rec):
+                emitted, out_state, self.kv_caches = self._window_fn(
+                    self.params,
+                    tokens=state["tokens"],
+                    positions=state["positions"],
+                    ctx_lens=state["ctx_lens"],
+                    done=state["done"],
+                    min_left=state["min_left"],
+                    block_tables=state["tables"],
+                    max_steps=state["max_steps"],
+                    kv_caches=self.kv_caches,
+                    temps=state["temps"],
+                    top_ps=state["top_ps"],
+                    top_ks=state["top_ks"],
+                    min_ps=state["min_ps"],
+                    seq_seeds=state["seeds"],
+                    stop_ids=state["stop_ids"],
+                    # Masked to 31 bits: a long-lived engine's monotone step
+                    # counter would otherwise overflow the host->int32 cast
+                    # and kill the step thread.  Below 2**31 key ordinals
+                    # (years of serving) the schedule is bit-identical to
+                    # single-token stepping; past it, +t wraps in-graph,
+                    # which PRNGKey treats as bits — still deterministic
+                    # across lockstep replicas.
+                    key_base=jnp.int32(
+                        (self.config.seed + self._step_counter) & 0x7FFFFFFF
+                    ),
+                    counts=state["counts"],
+                    seen=state["seen"],
+                    presence=state["presence"],
+                    frequency=state["frequency"],
+                    repetition=state["repetition"],
+                    use_penalties=state["use_penalties"],
+                    use_min_floor=state["use_min_floor"],
+                    **lora_kwargs,
+                )
             # One key ordinal per iteration: single-token stepping would
             # have burned exactly these counter values for the same
             # tokens.
             self._step_counter += self._window_steps
         state.update(out_state)
-        rec = None
-        if self.obs.enabled:
-            depth = 0
-            if chain_from is not None and chain_from.rec is not None:
-                depth = chain_from.rec.chain_depth + 1
-            sids = tuple(s.seq_id for s in seqs)
-            rec = self.obs.recorder.on_dispatch(
-                "spec" if spec_stats is not None else "decode",
-                k=self._window_steps, rows=len(seqs), seq_ids=sids,
-                chain_depth=depth, provisional=chain_from is not None,
-                spec_width=(
-                    self.config.scheduler.spec_draft_len
-                    if spec_stats is not None else 0
-                ),
-                drafter=spec_drafter or "",
-                fallback=plan.window_fallback, host_gap_s=gap, now=t0,
-            )
-            self._note_compiles(sids, rec)
+        if rec is not None:
+            if spec_stats is not None:
+                rec.kind = "spec"
+                rec.spec_width = self.config.scheduler.spec_draft_len
+                rec.drafter = spec_drafter
+            self._note_compiles(rec)
         # stackcheck: allow=SC201 reason=host_s is a stats field (host-gap metric); no plan state reads it
         return _PendingStep(
             seqs=list(seqs), sampled=emitted, is_decode=True,
@@ -2655,6 +2717,7 @@ class LLMEngine:
         )
 
     # stackcheck: root=step-thread
+    @_enclosed("dispatch")
     def _dispatch_mixed_window(
         self, plan, chain_from: Optional[_PendingStep] = None
     ) -> _PendingStep:
@@ -2687,17 +2750,29 @@ class LLMEngine:
         sched = plan.chunk_schedule
         k_eff = len(sched)
         n_scan = self._pow2_bucket(k_eff, 1)
-        gap = self._recorder_gap(t0) if self.obs.enabled else 0.0
+        depth = 0
+        if chain_from is not None and chain_from.rec is not None:
+            depth = chain_from.rec.chain_depth + 1
+        rec = self._open_record(
+            "mixed", seqs=seqs, chunks=sched, ahead=chain_from or 0,
+            bucket_tokens=n_scan * sched[0].bucket_len,
+            k=k_eff, chain_depth=depth, provisional=chain_from is not None,
+            fallback=plan.window_fallback,
+        )
+        self._stamp_record(rec, t0)
         if self.obs.enabled:
             for cp in sched:
                 if cp.seq.first_scheduled_time is None:
                     cp.seq.first_scheduled_time = t0
                     self.obs.on_first_scheduled(cp.seq, t0)
+        with self.obs.phase("build", rec):
+            if chain_from is None:
+                state = self._window_build(seqs, decode.steps)
+            else:
+                state = self._window_chain(chain_from, seqs, decode.steps)
         if chain_from is None:
-            state = self._window_build(seqs, decode.steps)
             self._note_decode_launch()
         else:
-            state = self._window_chain(chain_from, seqs, decode.steps)
             self._gap_steps += 1  # device busy: zero gap by construction
             self._last_decode_end = None
         # Mixed windows keep `hist` warm but advance positions without
@@ -2706,6 +2781,84 @@ class LLMEngine:
         # the next model-spec window re-primes from the warm hist.
         self._draft_primed = False
 
+        lora_kwargs = {}
+        if self.lora_registry is not None:
+            lora_kwargs = {
+                "lora": self.lora_registry.params,
+                "adapter_idx": state["adapter"],
+            }
+        with self.obs.phase("build", rec):
+            pf_device, any_final, overlap_s = self._stage_chunks(
+                sched, n_scan, chained=chain_from is not None
+            )
+        with self.obs.phase("launch", rec):
+            emitted, tails, out_state, self.kv_caches = (
+                self._mixed_window_fn(
+                    self.params,
+                    tokens=state["tokens"],
+                    positions=state["positions"],
+                    ctx_lens=state["ctx_lens"],
+                    done=state["done"],
+                    min_left=state["min_left"],
+                    block_tables=state["tables"],
+                    max_steps=state["max_steps"],
+                    kv_caches=self.kv_caches,
+                    temps=state["temps"],
+                    top_ps=state["top_ps"],
+                    top_ks=state["top_ks"],
+                    min_ps=state["min_ps"],
+                    seq_seeds=state["seeds"],
+                    stop_ids=state["stop_ids"],
+                    # Same 31-bit masking rationale as _dispatch_window.
+                    key_base=jnp.int32(
+                        (self.config.seed + self._step_counter) & 0x7FFFFFFF
+                    ),
+                    counts=state["counts"],
+                    seen=state["seen"],
+                    presence=state["presence"],
+                    frequency=state["frequency"],
+                    repetition=state["repetition"],
+                    pf_tokens=pf_device["tokens"],
+                    pf_cached=pf_device["cached"],
+                    pf_valid=pf_device["valid"],
+                    pf_new_blocks=pf_device["new_blocks"],
+                    pf_prefix_ids=pf_device["prefix"],
+                    pf_adapter=pf_device["adapter"],
+                    n_steps=n_scan,
+                    use_penalties=state["use_penalties"],
+                    use_min_floor=state["use_min_floor"],
+                    hist=state.get("hist"),
+                    **lora_kwargs,
+                )
+            )
+        # chunk_ordinal is the window's BASE step counter: a final
+        # chunk at iteration f is K=1 step (base + f), and the
+        # collect-side first-token sample burns exactly that ordinal —
+        # per packed prompt.
+        chunk_ordinal = self._step_counter
+        # K_eff live iterations = K_eff single-step equivalents (dead
+        # pow-2 padding iterations burn no ordinal anywhere).
+        self._step_counter += k_eff
+        state.update(out_state)
+        if rec is not None:
+            rec.transfer_overlap_s = overlap_s
+            self._note_compiles(rec)
+        # stackcheck: allow=SC201 reason=host_s is a stats field (host-gap metric); no plan state reads it
+        return _PendingStep(
+            seqs=list(seqs), sampled=emitted, is_decode=True,
+            host_s=time.time() - t0, steps=list(decode.steps),
+            win_state=state,
+            chunk_sched=list(sched),
+            chunk_logits=tails if any_final else None,
+            chunk_ordinal=chunk_ordinal,
+            rec=rec,
+        )
+
+    def _stage_chunks(self, sched, n_scan: int, chained: bool):
+        """A mixed window's chunk schedule as device arrays: (the scan xs,
+        whether any chunk is its prompt's last, seconds of staging that ran
+        under the previous window's compute)."""
+        k_eff = len(sched)
         # Per-iteration chunk schedule (host-precomputed, rides as scan
         # xs).  All chunks share ONE bucket T (static scan shape); dead
         # pow-2 padding iterations carry valid_len 0, new blocks parked
@@ -2751,101 +2904,19 @@ class LLMEngine:
         buf["cached"][k_eff:] = end_cursor
         buf["prefix"][k_eff:] = buf["prefix"][k_eff - 1]
 
-        lora_kwargs = {}
-        if self.lora_registry is not None:
-            lora_kwargs = {
-                "lora": self.lora_registry.params,
-                "adapter_idx": state["adapter"],
-            }
         pf_device = {
             k: self._put(v, P()) for k, v in buf.items()
         }
         overlap_s = 0.0
-        if chain_from is not None:
+        if chained:
             # The previous window still occupies the device: every
             # second of this H2D staging ran UNDER its compute instead
             # of serializing after it.
             overlap_s = time.time() - t_stage
             self.window_transfer_overlap_s += overlap_s
-        emitted, tails, out_state, self.kv_caches = (
-            self._mixed_window_fn(
-                self.params,
-                tokens=state["tokens"],
-                positions=state["positions"],
-                ctx_lens=state["ctx_lens"],
-                done=state["done"],
-                min_left=state["min_left"],
-                block_tables=state["tables"],
-                max_steps=state["max_steps"],
-                kv_caches=self.kv_caches,
-                temps=state["temps"],
-                top_ps=state["top_ps"],
-                top_ks=state["top_ks"],
-                min_ps=state["min_ps"],
-                seq_seeds=state["seeds"],
-                stop_ids=state["stop_ids"],
-                # Same 31-bit masking rationale as _dispatch_window.
-                key_base=jnp.int32(
-                    (self.config.seed + self._step_counter) & 0x7FFFFFFF
-                ),
-                counts=state["counts"],
-                seen=state["seen"],
-                presence=state["presence"],
-                frequency=state["frequency"],
-                repetition=state["repetition"],
-                pf_tokens=pf_device["tokens"],
-                pf_cached=pf_device["cached"],
-                pf_valid=pf_device["valid"],
-                pf_new_blocks=pf_device["new_blocks"],
-                pf_prefix_ids=pf_device["prefix"],
-                pf_adapter=pf_device["adapter"],
-                n_steps=n_scan,
-                use_penalties=state["use_penalties"],
-                use_min_floor=state["use_min_floor"],
-                hist=state.get("hist"),
-                **lora_kwargs,
-            )
-        )
-        # chunk_ordinal is the window's BASE step counter: a final
-        # chunk at iteration f is K=1 step (base + f), and the
-        # collect-side first-token sample burns exactly that ordinal —
-        # per packed prompt.
-        chunk_ordinal = self._step_counter
-        # K_eff live iterations = K_eff single-step equivalents (dead
-        # pow-2 padding iterations burn no ordinal anywhere).
-        self._step_counter += k_eff
-        state.update(out_state)
-        rec = None
-        if self.obs.enabled:
-            depth = 0
-            if chain_from is not None and chain_from.rec is not None:
-                depth = chain_from.rec.chain_depth + 1
-            sids = tuple(s.seq_id for s in seqs) + tuple(
-                dict.fromkeys(cp.seq.seq_id for cp in sched)
-            )
-            rec = self.obs.recorder.on_dispatch(
-                "mixed", k=k_eff, rows=len(seqs), seq_ids=sids,
-                chain_depth=depth, provisional=chain_from is not None,
-                chunk_prompts=len({cp.seq.seq_id for cp in sched}),
-                chunk_tokens_planned=sum(
-                    cp.num_new_tokens for cp in sched
-                ),
-                fallback=plan.window_fallback, host_gap_s=gap,
-                transfer_overlap_s=overlap_s, now=t0,
-            )
-            self._note_compiles(sids, rec)
-        # stackcheck: allow=SC201 reason=host_s is a stats field (host-gap metric); no plan state reads it
-        return _PendingStep(
-            seqs=list(seqs), sampled=emitted, is_decode=True,
-            host_s=time.time() - t0, steps=list(decode.steps),
-            win_state=state,
-            chunk_sched=list(sched),
-            chunk_logits=tails if any_final else None,
-            chunk_ordinal=chunk_ordinal,
-            rec=rec,
-        )
+        return pf_device, any_final, overlap_s
 
-    def _collect_window(self, p: _PendingStep, t0: float) -> List[StepOutput]:
+    def _collect_window(self, p: _PendingStep) -> List[StepOutput]:
         """Read one window's emitted tokens back ([K, S] plain, or
         [K, W, S] from the fused speculative scan — flattened to the
         chronological [K*W, S] token order) and replay them through the
@@ -2856,14 +2927,36 @@ class LLMEngine:
         sequence aborted / finished out-of-band while the window flew)
         are counted as multistep waste.  Fused windows additionally
         account drafted / accepted / wasted speculation per window."""
-        arr = np.asarray(p.sampled)  # the ONE device sync point
-        sync_s = time.time() - t0
+        t0 = time.time()
+        with self.obs.phase("collect", p.rec):
+            arr = np.asarray(p.sampled)  # the ONE device sync point
+        if p.spec_drafter == "model":
+            # Scan seconds attributed to draft forwards
+            # (tpu:spec_draft_fraction_seconds): the measured collect
+            # sync wait times the static cost-model split computed at
+            # boot from real parameter counts (the n-gram drafter's
+            # lookup costs no forward, so it accrues nothing).
+            # Pipelined windows under-attribute — the host overlaps part
+            # of the scan — which keeps the counter a floor, never an
+            # overclaim.
+            self.spec_draft_fraction_s += (
+                self._draft_cost_fraction * (time.time() - t0)
+            )
+        with self.obs.phase("sample", p.rec):
+            outputs, counts = self._replay_window(p, arr)
+        if p.rec is not None:
+            # Sample-side jits ran inside the replay above: drain any
+            # compiles onto this record, then complete it.
+            self._note_compiles(p.rec, [s.seq_id for s in p.seqs])
+            self.obs.recorder.on_collect(p.rec, host_s=p.host_s, **counts)
+        return outputs
+
+    def _replay_window(self, p: _PendingStep, arr):
+        """The host half of a window's collect: (outputs, the token counts
+        its flight record is completed with)."""
         spec = p.spec_stats is not None
         if arr.ndim == 3:
             arr = arr.reshape(-1, arr.shape[-1])  # [K*W, S], in order
-        if self.obs.enabled:
-            self.obs.step_phase("collect", sync_s)
-        t_post = time.time()
         outputs: List[StepOutput] = []
         delivered = [0] * len(p.seqs)
         alive = [(i, s) for i, s in enumerate(p.seqs) if not s.is_finished]
@@ -2957,33 +3050,13 @@ class LLMEngine:
             self.spec_window_tokens["accepted"] += accepted
             self.spec_window_tokens["rejected"] += drafted - accepted
             self.spec_window_tokens["wasted"] += wasted
-            if p.spec_drafter == "model":
-                # Scan seconds attributed to draft forwards
-                # (tpu:spec_draft_fraction_seconds): the measured
-                # collect sync wait times the static cost-model split
-                # computed at boot from real parameter counts (the
-                # n-gram drafter's lookup costs no forward, so it
-                # accrues nothing).  Pipelined windows under-attribute —
-                # the host overlaps part of the scan — which keeps the
-                # counter a floor, never an overclaim.
-                self.spec_draft_fraction_s += (
-                    self._draft_cost_fraction * sync_s
-                )
-        if self.obs.enabled:
-            self.obs.step_phase("sample", time.time() - t_post)
-        if p.rec is not None:
-            # Sample-side jits ran inside the replay above: drain any
-            # compiles onto this record, then complete it.
-            self._note_compiles([s.seq_id for s in p.seqs], p.rec)
-            self.obs.recorder.on_collect(
-                p.rec, host_s=p.host_s,
-                tokens_emitted=emitted,
-                tokens_delivered=emitted - wasted,
-                tokens_wasted=wasted,
-                chunk_tokens_delivered=chunk_delivered,
-                drafted=drafted, accepted=accepted,
-            )
-        return outputs
+        return outputs, dict(
+            tokens_emitted=emitted,
+            tokens_delivered=emitted - wasted,
+            tokens_wasted=wasted,
+            chunk_tokens_delivered=chunk_delivered,
+            drafted=drafted, accepted=accepted,
+        )
 
     def restore_seq_blocks(self, seq: Sequence) -> str:
         """Scheduler restore_cb: page an offloaded sequence's KV snapshot
@@ -3628,11 +3701,42 @@ class LLMEngine:
         while len(self._exported_hashes) > 65536:
             self._exported_hashes.popitem(last=False)
 
-    def _run_prefill(self, plan: PrefillPlan) -> List[StepOutput]:
+    def _run_prefill(self, plan: PrefillPlan, rec=None) -> List[StepOutput]:
         seq = plan.seq
         if self.obs.enabled and seq.first_scheduled_time is None:
             seq.first_scheduled_time = time.time()
             self.obs.on_first_scheduled(seq, seq.first_scheduled_time)
+        with self.obs.phase("build", rec):
+            kwargs, want_plp = self._prefill_kwargs(plan)
+        with self.obs.phase("launch", rec):
+            out = self._prefill_fn(
+                self.params, kv_caches=self.kv_caches, **kwargs
+            )
+        if want_plp:
+            logits, self.kv_caches, plp = out
+            with self.obs.phase("collect", rec, family=False):
+                self._collect_prompt_logprobs(seq, plan, plp)
+        else:
+            logits, self.kv_caches = out
+        if not plan.is_final:
+            # Non-final chunk of a long prompt: KV is written, but the
+            # logits are mid-prompt — nothing to sample yet.
+            return []
+        outputs = self._finalize_final_prefill(seq, logits, rec=rec)
+        if want_plp and outputs and seq.prompt_lp is not None:
+            # Attach the assembled per-position entries to the request's
+            # FIRST token event (position 0 has no predictor -> None).
+            n = seq.echo_prompt_len
+            entries: List = [(None, None)]
+            for pos in range(1, n):
+                entries.append(seq.prompt_lp.get(pos, (None, None)))
+            outputs[0].prompt_logprobs = entries
+        return outputs
+
+    def _prefill_kwargs(self, plan: PrefillPlan):
+        """(the dedicated prefill executable's keyword arguments, on the
+        device; whether it also returns prompt logprobs)."""
+        seq = plan.seq
         T = plan.bucket_len
         tokens, new_block_ids, prefix_ids = self._prefill_plan_arrays(plan)
 
@@ -3668,36 +3772,15 @@ class LLMEngine:
                 "prompt_topk": 20,
             }
 
-        out = self._prefill_fn(
-            self.params,
+        return dict(
             tokens=self._put(tokens, P(AXES.SP)),
             cached_len=jnp.int32(plan.cached_len),
             prefix_block_ids=self._put(prefix_ids, P(AXES.SP)),
             new_block_ids=self._put(new_block_ids, P(AXES.SP)),
             valid_len=jnp.int32(plan.num_new_tokens),
-            kv_caches=self.kv_caches,
             **plp_kwargs,
             **lora_kwargs,
-        )
-        if want_plp:
-            logits, self.kv_caches, plp = out
-            self._collect_prompt_logprobs(seq, plan, plp)
-        else:
-            logits, self.kv_caches = out
-        if not plan.is_final:
-            # Non-final chunk of a long prompt: KV is written, but the
-            # logits are mid-prompt — nothing to sample yet.
-            return []
-        outputs = self._finalize_final_prefill(seq, logits)
-        if want_plp and outputs and seq.prompt_lp is not None:
-            # Attach the assembled per-position entries to the request's
-            # FIRST token event (position 0 has no predictor -> None).
-            n = seq.echo_prompt_len
-            entries: List = [(None, None)]
-            for pos in range(1, n):
-                entries.append(seq.prompt_lp.get(pos, (None, None)))
-            outputs[0].prompt_logprobs = entries
-        return outputs
+        ), want_plp
 
     def _collect_prompt_logprobs(self, seq, plan, plp) -> None:
         """Stitch one chunk's (target_lp, top_ids, top_lps) into the
@@ -3740,7 +3823,8 @@ class LLMEngine:
         return tokens, new_block_ids, prefix_ids
 
     def _finalize_final_prefill(
-        self, seq: Sequence, last_logits, step_ordinal: Optional[int] = None
+        self, seq: Sequence, last_logits, step_ordinal: Optional[int] = None,
+        rec=None,
     ) -> List[StepOutput]:
         """Shared tail of every FINAL prefill — dedicated executable,
         mixed-step chunk, or a mixed WINDOW's final chunk (which passes
@@ -3750,7 +3834,8 @@ class LLMEngine:
         sampling the request's first token from the last valid row's
         logits [V]."""
         if self._exports:
-            self._export_prefix_blocks(seq)
+            with self.obs.phase("sample", rec, family=False):
+                self._export_prefix_blocks(seq)
         if seq.sampling_params.max_tokens == 0:
             # Scoring-only request (echo+logprobs with max_tokens=0):
             # nothing to sample — finish at prefill with the text-free
@@ -3766,11 +3851,12 @@ class LLMEngine:
                 num_output_tokens=0,
             )]
         token_ids, logprob_info = self._sample_batch(
-            last_logits[None, :], [seq], step_ordinal=step_ordinal
+            last_logits[None, :], [seq], step_ordinal=step_ordinal, rec=rec
         )
-        return self._append_and_check(
-            [seq], token_ids, first_token=True, logprob_info=logprob_info
-        )
+        with self.obs.phase("sample", rec, family=False):
+            return self._append_and_check(
+                [seq], token_ids, first_token=True, logprob_info=logprob_info
+            )
 
     def _decode_batch_arrays(self, seqs: List[Sequence], S: int):
         """Padded decode-row host arrays ([S] x5 + [S, bmax] tables) for
@@ -3808,7 +3894,8 @@ class LLMEngine:
         return min(b, self._smax)
 
     # stackcheck: root=step-thread
-    def _run_mixed(self, step_plan) -> List[StepOutput]:
+    @_enclosed("mixed")
+    def _run_mixed(self, step_plan, rec=None) -> List[StepOutput]:
         """One fused step over the packed [decode bucket + chunk bucket]
         token batch (a StepPlan with both ``decode`` and
         ``prefill_chunk`` set): every running sequence decodes exactly
@@ -3825,6 +3912,35 @@ class LLMEngine:
         if self.obs.enabled and seq.first_scheduled_time is None:
             seq.first_scheduled_time = t_start
             self.obs.on_first_scheduled(seq, t_start)
+        with self.obs.phase("build", rec):
+            kwargs = self._mixed_kwargs(plan, seqs)
+        self._note_decode_launch()
+        with self.obs.phase("launch", rec):
+            logits, self.kv_caches = self._mixed_fn(
+                self.params, kv_caches=self.kv_caches, **kwargs
+            )
+        self.prefill_chunk_tokens += plan.num_new_tokens
+        # Decode rows first (logits rows 0..len(seqs)-1).
+        token_ids, logprob_info = self._sample_batch(
+            logits[: len(seqs)], seqs, rec=rec
+        )
+        with self.obs.phase("sample", rec, family=False):
+            outputs = self._append_and_check(
+                seqs, token_ids, first_token=False,
+                logprob_info=logprob_info,
+            )
+        if plan.is_final:
+            # Row -1 is the chunk's last valid token: the request's
+            # first sampled token (same finalize contract as the
+            # dedicated prefill executable).
+            outputs.extend(
+                self._finalize_final_prefill(seq, logits[-1], rec=rec)
+            )
+        return outputs
+
+    def _mixed_kwargs(self, plan: PrefillPlan, seqs: List[Sequence]) -> Dict:
+        """The fused mixed step's keyword arguments, on the device."""
+        seq = plan.seq
         S = self._decode_bucket(len(seqs))
         T = plan.bucket_len
         (tokens, positions, block_tables, ctx_lens, slot_blocks,
@@ -3847,9 +3963,7 @@ class LLMEngine:
                 "adapter_idx": self._put(adapter_idx, P()),
             }
 
-        self._note_decode_launch()
-        logits, self.kv_caches = self._mixed_fn(
-            self.params,
+        return dict(
             dec_tokens=self._put(tokens, batch_spec),
             dec_positions=self._put(positions, batch_spec),
             dec_block_tables=self._put(block_tables, P(AXES.DP, None)),
@@ -3861,25 +3975,10 @@ class LLMEngine:
             pf_prefix_block_ids=self._put(pf_prefix, P(AXES.SP)),
             pf_new_block_ids=self._put(pf_new_blocks, P(AXES.SP)),
             pf_valid_len=jnp.int32(plan.num_new_tokens),
-            kv_caches=self.kv_caches,
             **lora_kwargs,
         )
-        self.prefill_chunk_tokens += plan.num_new_tokens
-        # Decode rows first (logits rows 0..len(seqs)-1).
-        token_ids, logprob_info = self._sample_batch(logits[: len(seqs)], seqs)
-        outputs = self._append_and_check(
-            seqs, token_ids, first_token=False, logprob_info=logprob_info
-        )
-        if plan.is_final:
-            # Row -1 is the chunk's last valid token: the request's
-            # first sampled token (same finalize contract as the
-            # dedicated prefill executable).
-            outputs.extend(self._finalize_final_prefill(seq, logits[-1]))
-        if self.obs.enabled:
-            self.obs.step_phase("mixed", time.time() - t_start)
-        return outputs
 
-    def _run_decode(self, plan: DecodePlan) -> List[StepOutput]:
+    def _run_decode(self, plan: DecodePlan, rec=None) -> List[StepOutput]:
         seqs = plan.seqs
         S = self._decode_bucket(len(seqs))
 
@@ -3899,29 +3998,41 @@ class LLMEngine:
             and s.guide is None
             for s in seqs
         ):
-            return self._run_decode_speculative(plan, spec_k)
+            return self._run_decode_speculative(plan, spec_k, rec)
 
-        (tokens, positions, block_tables, ctx_lens, slot_blocks,
-         slot_offsets) = self._decode_batch_arrays(seqs, S)
-
-        batch_spec = shardings_lib.decode_batch_spec()
-        lora_kwargs = self._lora_kwargs(seqs, S, 1, batch_spec)
-
+        with self.obs.phase("build", rec):
+            batch_spec = shardings_lib.decode_batch_spec()
+            kwargs = self._decode_kwargs(
+                self._decode_batch_arrays(seqs, S), batch_spec,
+                self._lora_kwargs(seqs, S, 1, batch_spec),
+            )
         self._note_decode_launch()
-        logits, self.kv_caches = self._decode_fn(
-            self.params,
+        with self.obs.phase("launch", rec):
+            logits, self.kv_caches = self._decode_fn(
+                self.params, kv_caches=self.kv_caches, **kwargs
+            )
+        token_ids, logprob_info = self._sample_batch(
+            logits[: len(seqs)], seqs, rec=rec
+        )
+        with self.obs.phase("sample", rec, family=False):
+            return self._append_and_check(
+                seqs, token_ids, first_token=False,
+                logprob_info=logprob_info,
+            )
+
+    def _decode_kwargs(self, arrays, batch_spec, lora_kwargs: Dict) -> Dict:
+        """The decode executable's keyword arguments, on the device, from
+        ``_decode_batch_arrays``-shaped host arrays."""
+        (tokens, positions, block_tables, ctx_lens, slot_blocks,
+         slot_offsets) = arrays
+        return dict(
             tokens=self._put(tokens, batch_spec),
             positions=self._put(positions, batch_spec),
             block_tables=self._put(block_tables, P(AXES.DP, None)),
             ctx_lens=self._put(ctx_lens, batch_spec),
             slot_block_ids=self._put(slot_blocks, batch_spec),
             slot_offsets=self._put(slot_offsets, batch_spec),
-            kv_caches=self.kv_caches,
             **lora_kwargs,
-        )
-        token_ids, logprob_info = self._sample_batch(logits[: len(seqs)], seqs)
-        return self._append_and_check(
-            seqs, token_ids, first_token=False, logprob_info=logprob_info
         )
 
     def _lora_kwargs(self, seqs: List[Sequence], S: int, width: int,
@@ -3978,7 +4089,7 @@ class LLMEngine:
         return []
 
     def _run_decode_speculative(
-        self, plan: DecodePlan, k: int
+        self, plan: DecodePlan, k: int, rec=None
     ) -> List[StepOutput]:
         """Verify K n-gram-drafted tokens + sample one bonus token in ONE
         forward: each sequence occupies K+1 rows of an expanded decode
@@ -3994,6 +4105,28 @@ class LLMEngine:
         seqs = plan.seqs
         S = self._decode_bucket(len(seqs))
         W = k + 1  # rows per sequence
+        with self.obs.phase("build", rec):
+            arrays, drafts = self._speculative_arrays(plan, k, S)
+            batch_spec = shardings_lib.decode_batch_spec()
+            kwargs = self._decode_kwargs(
+                arrays, batch_spec, self._lora_kwargs(seqs, S, W, batch_spec)
+            )
+        self._note_decode_launch()
+        with self.obs.phase("launch", rec):
+            logits, self.kv_caches = self._decode_fn(
+                self.params, kv_caches=self.kv_caches, **kwargs
+            )
+            greedy = self._argmax_fn(logits)
+        with self.obs.phase("collect", rec, family=False):
+            greedy = np.asarray(greedy)  # [R] — one sync
+        with self.obs.phase("sample", rec, family=False):
+            return self._accept_drafts(seqs, drafts, greedy, W)
+
+    def _speculative_arrays(self, plan: DecodePlan, k: int, S: int):
+        """(``_decode_batch_arrays``-shaped host arrays of the expanded
+        [S * (k + 1)] batch, each sequence's draft)."""
+        seqs = plan.seqs
+        W = k + 1
         R = S * W
         bs = self.block_pool.block_size
 
@@ -4022,23 +4155,11 @@ class LLMEngine:
                 slot_blocks[r] = seq.block_table[(pos0 + j) // bs]
                 slot_offsets[r] = (pos0 + j) % bs
             # Rows past the chain stay inactive: null block 0, ctx 0.
+        return (tokens, positions, block_tables, ctx_lens, slot_blocks,
+                slot_offsets), drafts
 
-        batch_spec = shardings_lib.decode_batch_spec()
-        lora_kwargs = self._lora_kwargs(seqs, S, W, batch_spec)
-        self._note_decode_launch()
-        logits, self.kv_caches = self._decode_fn(
-            self.params,
-            tokens=self._put(tokens, batch_spec),
-            positions=self._put(positions, batch_spec),
-            block_tables=self._put(block_tables, P(AXES.DP, None)),
-            ctx_lens=self._put(ctx_lens, batch_spec),
-            slot_block_ids=self._put(slot_blocks, batch_spec),
-            slot_offsets=self._put(slot_offsets, batch_spec),
-            kv_caches=self.kv_caches,
-            **lora_kwargs,
-        )
-        greedy = np.asarray(self._argmax_fn(logits))  # [R] — one sync
-
+    def _accept_drafts(self, seqs: List[Sequence], drafts: List[List[int]],
+                       greedy, W: int) -> List[StepOutput]:
         # Greedy verification: accept the longest draft prefix the model
         # agrees with, then take the model's own token from the first
         # disagreeing (or final) row as the bonus.
@@ -4089,14 +4210,27 @@ class LLMEngine:
 
     def _sample_batch(
         self, logits: jax.Array, seqs: List[Sequence],
-        step_ordinal: Optional[int] = None,
+        step_ordinal: Optional[int] = None, rec=None,
     ):
         """Returns (token_ids, logprob_info) where logprob_info is a list of
         None or (chosen_logprob, [(token_id, logprob), ...]) per sequence.
         ``step_ordinal`` overrides the live step counter for the PRNG key
         (a mixed window's final-chunk first token samples with the
         ordinal of the iteration it landed in — the counter has already
-        advanced past the whole window by collect time)."""
+        advanced past the whole window by collect time).  ``rec``: the
+        flight record of the synchronous dispatch this sampling belongs
+        to, which its launch / read-back / post-processing spans join."""
+        with self.obs.phase("launch", rec):
+            logits, out = self._sample_launch(logits, seqs, step_ordinal)
+        with self.obs.phase("collect", rec, family=False):
+            token_ids = [int(t) for t in np.asarray(out[: len(seqs)])]
+        with self.obs.phase("sample", rec, family=False):
+            return self._sample_finish(logits, out, seqs, token_ids)
+
+    def _sample_launch(self, logits: jax.Array, seqs: List[Sequence],
+                       step_ordinal: Optional[int]):
+        """Penalties and biases applied and the sampler launched: (the
+        adjusted logits, the still-in-flight sampled ids [S])."""
         S = logits.shape[0]
         pad = S - len(seqs)
 
@@ -4206,7 +4340,7 @@ class LLMEngine:
             self._step_counter if step_ordinal is None else step_ordinal
         )
         step_key = jax.random.PRNGKey(self.config.seed + ordinal)
-        out = self._sample_fn(
+        return logits, self._sample_fn(
             logits,
             jnp.asarray(temps),
             jnp.asarray(top_ps),
@@ -4215,7 +4349,12 @@ class LLMEngine:
             jnp.asarray(seeds),
             min_p=jnp.asarray(min_ps),
         )
-        token_ids = [int(t) for t in np.asarray(out[: len(seqs)])]
+
+    def _sample_finish(self, logits: jax.Array, out, seqs: List[Sequence],
+                       token_ids: List[int]):
+        """Host post-processing of the read-back ids: guided overrides
+        and the logprobs gather."""
+        pad = logits.shape[0] - len(seqs)
         any_logprobs = any(s.sampling_params.logprobs for s in seqs)
         if any(s.guide is not None for s in seqs):
             token_ids = self._guided_override(logits, seqs, token_ids)
@@ -4584,12 +4723,10 @@ class LLMEngine:
         )
         ids = (list(prompt_token_ids) + [0] * bucket)[:bucket]
         if self._encode_fn is None:
-            self._encode_fn = self.obs.compile_tracker.wrap(
+            self._encode_fn = self._jit(
                 "encode_fn",
-                jax.jit(
-                    partial(self.model.encode, cfg=self.config.model,
-                            mesh=self.mesh)
-                ),
+                partial(self.model.encode, cfg=self.config.model,
+                        mesh=self.mesh),
             )
         out = self._encode_fn(
             self.params,
@@ -4655,12 +4792,10 @@ class LLMEngine:
         rows += [[0] * t_bucket] * (b_bucket - len(rows))
         valid = lens + [0] * (b_bucket - len(lens))
         if self._encode_batch_fn is None:
-            self._encode_batch_fn = self.obs.compile_tracker.wrap(
+            self._encode_batch_fn = self._jit(
                 "encode_batch_fn",
-                jax.jit(
-                    partial(self.model.encode_batch, cfg=self.config.model,
-                            mesh=self.mesh)
-                ),
+                partial(self.model.encode_batch, cfg=self.config.model,
+                        mesh=self.mesh),
             )
         t0 = time.time()
         out = self._encode_batch_fn(

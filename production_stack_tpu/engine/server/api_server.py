@@ -1827,8 +1827,10 @@ def build_engine_app(
 
     # On-demand device profiling (vLLM's /start_profile and /stop_profile,
     # TPU-native: jax.profiler traces, viewable in TensorBoard/XProf or
-    # Perfetto).  Serving continues while the trace records, so a
-    # production TTFT spike can be captured in situ.
+    # Perfetto).  Serving continues while the trace records AND while it is
+    # written, so a production TTFT spike can be captured in situ: the
+    # Python tracer is off (the host tracer keeps the step loop's pstpu.*
+    # spans), and stop_trace runs on a worker thread.
     profile_state = {"dir": None}
 
     async def start_profile(request: web.Request) -> web.Response:
@@ -1847,13 +1849,26 @@ def build_engine_app(
         trace_dir = body.get("trace_dir") or os.environ.get(
             "PSTPU_PROFILE_DIR", "/tmp/pstpu_profile"
         )
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        # The trace has a clock of its own.  Bracket the session's start on
+        # the host's, and make the first host event of the session say
+        # what time it was: the flight records' unix ns (GET
+        # /debug/windows) are laid against the trace from these.
+        before_ns = time.time_ns()
         try:
-            jax.profiler.start_trace(trace_dir)
+            jax.profiler.start_trace(trace_dir, profiler_options=options)
         except Exception as e:
             return web.json_response(
                 {"error": {"message": f"start_trace failed: {e}"}},
                 status=500,
             )
+        after_ns = time.time_ns()
+        with jax.profiler.TraceAnnotation(
+            "pstpu.anchor", unix_ns=time.time_ns()
+        ):
+            pass
+        engine.engine.obs.note_profile("start", before_ns, after_ns)
         profile_state["dir"] = trace_dir
         logger.info("profiling started -> %s", trace_dir)
         return web.json_response({"ok": True, "trace_dir": trace_dir})
@@ -1867,15 +1882,24 @@ def build_engine_app(
         import jax
 
         trace_dir, profile_state["dir"] = profile_state["dir"], None
+        before_ns = time.time_ns()
         try:
-            jax.profiler.stop_trace()
+            # Writing the trace takes seconds: off the event loop, so that
+            # no stream stalls behind it.
+            await asyncio.to_thread(jax.profiler.stop_trace)
         except Exception as e:
             return web.json_response(
                 {"error": {"message": f"stop_trace failed: {e}"}},
                 status=500,
             )
-        logger.info("profiling stopped; trace in %s", trace_dir)
-        return web.json_response({"ok": True, "trace_dir": trace_dir})
+        after_ns = time.time_ns()
+        engine.engine.obs.note_profile("stop", before_ns, after_ns)
+        stop_s = (after_ns - before_ns) / 1e9
+        logger.info("profiling stopped in %.3f s; trace in %s",
+                    stop_s, trace_dir)
+        return web.json_response(
+            {"ok": True, "trace_dir": trace_dir, "stop_s": stop_s}
+        )
 
     app.router.add_post("/start_profile", start_profile)
     app.router.add_post("/stop_profile", stop_profile)

@@ -423,6 +423,10 @@ class AsyncEngine:
                     aborts=list(aborts),
                 ))
                 last_publish = time.time()
+            for request_id in aborts:
+                self.engine.abort_request(request_id)
+            # Told only once the abort is applied: a client that sees the
+            # 504 finds the sequence gone from the queue.
             for request_id in expired:
                 self.engine.deadline_expired += 1
                 self._emit(
@@ -432,8 +436,6 @@ class AsyncEngine:
                         "queued; shed before occupying a batch slot"
                     ),
                 )
-            for request_id in aborts:
-                self.engine.abort_request(request_id)
             for request_id, token_ids, params, adapter in pending:
                 try:
                     self.engine.add_request(
@@ -452,7 +454,8 @@ class AsyncEngine:
                     and self.encode_batcher.run_pending(max_batches=0)
                 ):
                     continue
-                self._wakeup.wait(timeout=0.01)
+                with self.engine.obs.phase("wait"):
+                    self._wakeup.wait(timeout=0.01)
                 self._wakeup.clear()
                 continue
             try:
@@ -493,22 +496,9 @@ class AsyncEngine:
                 # stackcheck: allow=SC101 reason=error backoff after a failed step; the device produced nothing to wait for and hammering a failing dispatch would spin the log
                 time.sleep(0.1)
                 continue
-            for out in outputs:
-                # Drop events for requests whose client vanished.
-                if out.seq_id in self._queues:
-                    self._emit(
-                        out.seq_id,
-                        TokenEvent(
-                            token_id=out.new_token_id,
-                            finished=out.finished,
-                            finish_reason=out.finish_reason,
-                            num_prompt_tokens=out.num_prompt_tokens,
-                            num_output_tokens=out.num_output_tokens,
-                            logprob=out.logprob,
-                            top_logprobs=out.top_logprobs,
-                            prompt_logprobs=out.prompt_logprobs,
-                        ),
-                    )
+            if outputs:
+                with self.engine.obs.phase("emit"):
+                    self._emit_outputs(outputs)
             # Window boundary: at most ONE encode batch per iteration
             # while generation is live — an embed burst adds one
             # prefill-chunk-shaped pass between decode windows, never
@@ -524,6 +514,24 @@ class AsyncEngine:
 
             self._lockstep.publish(StepEvents(shutdown=True))
         logger.info("engine step loop exited")
+
+    def _emit_outputs(self, outputs) -> None:
+        for out in outputs:
+            # Drop events for requests whose client vanished.
+            if out.seq_id in self._queues:
+                self._emit(
+                    out.seq_id,
+                    TokenEvent(
+                        token_id=out.new_token_id,
+                        finished=out.finished,
+                        finish_reason=out.finish_reason,
+                        num_prompt_tokens=out.num_prompt_tokens,
+                        num_output_tokens=out.num_output_tokens,
+                        logprob=out.logprob,
+                        top_logprobs=out.top_logprobs,
+                        prompt_logprobs=out.prompt_logprobs,
+                    ),
+                )
 
     def _emit(self, request_id: str, event) -> None:
         queue = self._queues.get(request_id)

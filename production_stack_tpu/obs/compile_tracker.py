@@ -124,6 +124,9 @@ class _TrackedJit:
     # stackcheck: allow=SC201 reason=compile wall-time measurement is an observability sink; no plan state reads it (obs layer is plan-inert by contract)
     def __call__(self, *args, **kwargs):
         fn = self._fn
+        on_launch = self._tracker.on_launch
+        if on_launch is not None:
+            on_launch(self._name)
         try:
             before = fn._cache_size()
         except Exception:
@@ -160,6 +163,12 @@ class CompileTracker:
         self._by_executable: Dict[str, list] = {}
         # events since the engine last drained (tag owning windows/spans)
         self._events: List[Dict] = []
+        # Compile events since boot (step thread writes; a phase span
+        # compares it across its own extent to learn that it compiled).
+        self.events_total = 0
+        # Called with a wrapped callable's name just before each call
+        # (EngineObs stamps the launch onto the open flight record).
+        self.on_launch: Optional[Callable[[str], None]] = None
 
     def wrap(self, name: str, fn: Optional[Callable]) -> Optional[Callable]:
         """Instrument one jit entry point.  Identity when disabled or fn
@@ -180,6 +189,7 @@ class CompileTracker:
             if kernels is not None:
                 ent[2] = kernels
             self._events.append({"executable": key, "seconds": float(seconds)})
+            self.events_total += 1
 
     def drain_events(self) -> List[Dict]:
         """Events recorded since the last drain (engine step thread calls

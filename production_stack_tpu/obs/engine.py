@@ -12,11 +12,17 @@ allocations, no per-step bookkeeping — restoring the pre-tracing fast path.
 
 from __future__ import annotations
 
+import contextlib
+import threading
 import time
-from typing import Dict, Optional
+from collections import deque
+from typing import Callable, Deque, Dict, List, Optional
 
 from production_stack_tpu.obs.compile_tracker import CompileTracker
-from production_stack_tpu.obs.flight_recorder import FlightRecorder
+from production_stack_tpu.obs.flight_recorder import (
+    FlightRecorder,
+    WindowRecord,
+)
 from production_stack_tpu.obs.histogram import (
     Histogram,
     render_histogram,
@@ -36,11 +42,37 @@ from production_stack_tpu.obs.trace import Tracer
 #              rate(mixed_count)/rate(all step counts) is the fraction of
 #              steps where a prompt chunked alongside live decodes.
 # schedule covers every step; dispatch/collect/sample are the PIPELINED
-# decode split (the steady-state hot path) — synchronous steps (prefill,
-# host-state fallbacks) fuse those stages into one blocking call and
-# cannot be split without lying about where the time went.  Mixed steps
-# are synchronous by design and get their own family instead.
+# decode split (the steady-state hot path).  Synchronous steps (prefill,
+# host-state fallbacks) are cut into the finer PHASES spans below but stay
+# out of these families, whose sums the dashboard and bench.py read per
+# pipelined step.  Mixed steps are synchronous by design and get their own
+# family instead.
 STEP_PHASES = ("schedule", "dispatch", "collect", "sample", "mixed")
+
+# What the step thread is doing, as spans (EngineObs.phase): the closed set
+# a flight record's ``phases`` and the loose ring of GET /debug/windows
+# hold, each also a ``pstpu.<phase>`` TraceAnnotation on the profiler's
+# clock.  Finer than STEP_PHASES and covering every dispatch path, so that
+# device idle can be laid against them:
+#   schedule - scheduler planning, incl. landing completed prefetches
+#   build    - host arrays and H2D for the dispatch (incl. the small
+#              programs that unpack or advance device-resident state)
+#   launch   - the jitted step call returning (enqueue, not compute)
+#   collect  - the blocking device read-back
+#   sample   - host post-processing of the read-back (append, finish
+#              checks, guided decoding, a chunk's first token)
+#   emit     - fan-out of the step's outputs to the event loop
+#   wait     - nothing to do: asleep on the wake-up event or the 1 ms
+#              transfer back-off
+#   compile  - any of the above inside which a jit call traced and
+#              compiled (the span keeps its place, its name says why it
+#              was long)
+# ``dispatch`` and ``mixed`` of STEP_PHASES are ENCLOSING spans: they feed
+# their histogram family (and the profiler) and hold build/launch/... spans
+# inside, so they never land on a record themselves.
+PHASES = ("schedule", "build", "launch", "collect", "sample", "emit",
+          "wait", "compile")
+_ENCLOSING = frozenset(STEP_PHASES) - frozenset(PHASES)
 
 # Request-level engine histograms -> ``tpu:*_seconds`` families; one
 # observation per request, EXCEPT itl which observes every token gap (its
@@ -77,6 +109,64 @@ PHASE_SPAN_NAMES = (
 )
 
 
+class _PhaseSpan:
+    """One open ``EngineObs.phase``; see there."""
+
+    __slots__ = ("_obs", "_name", "_rec", "_family", "_ann", "_t0",
+                 "_compiles0", "_outer_rec", "_top")
+
+    def __init__(self, obs: "EngineObs", name: str,
+                 rec: Optional[WindowRecord], family: bool):
+        self._obs = obs
+        self._name = name
+        self._rec = rec
+        self._family = family
+        self._ann = None
+
+    # stackcheck: allow=SC201 reason=phase-span timestamps are observability sinks; no plan state reads them (obs layer is plan-inert by contract)
+    def __enter__(self) -> "_PhaseSpan":
+        obs = self._obs
+        self._outer_rec = obs._open_rec
+        if self._rec is None:
+            self._rec = self._outer_rec
+        else:
+            obs._open_rec = self._rec
+        if self._name in _ENCLOSING:
+            self._top = False
+        else:
+            self._top = obs._depth == 0
+            obs._depth += 1
+        self._compiles0 = obs.compile_tracker.events_total
+        if obs._annotation is not None:
+            kwargs = ({} if self._rec is None
+                      else {"window_id": self._rec.window_id})
+            self._ann = obs._annotation("pstpu." + self._name, **kwargs)
+            self._ann.__enter__()
+        self._t0 = time.time_ns()
+        return self
+
+    # stackcheck: allow=SC201 reason=phase-span timestamps are observability sinks; no plan state reads them (obs layer is plan-inert by contract)
+    def __exit__(self, *exc) -> bool:
+        t1 = time.time_ns()
+        obs = self._obs
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        obs._open_rec = self._outer_rec
+        if self._name not in _ENCLOSING:
+            obs._depth -= 1
+        if self._family:
+            obs.step_hists[self._name].observe((t1 - self._t0) / 1e9)
+        if self._top:
+            name = self._name
+            if obs.compile_tracker.events_total != self._compiles0:
+                name = "compile"
+            obs._keep_phase(self._rec, name, self._t0, t1)
+        return False
+
+
+_NULL_PHASE = contextlib.nullcontext()
+
+
 class EngineObs:
     def __init__(
         self,
@@ -84,6 +174,7 @@ class EngineObs:
         ring_size: int = 256,
         ring_bytes: int = 0,
         window_ring_size: int = 1024,
+        annotation: Optional[Callable] = None,
     ):
         self.enabled = bool(enabled)
         self.tracer = Tracer(
@@ -111,13 +202,84 @@ class EngineObs:
         self.kv_hists: Dict[str, Histogram] = {
             name: Histogram() for name in KV_PHASES
         }
+        # Phase spans (step thread only, like the recorder's write path).
+        # ``annotation``: jax.profiler.TraceAnnotation where the owner has
+        # JAX (the engine core passes it; the fake engine has none).
+        self._annotation = annotation if self.enabled else None
+        self._depth = 0            # open non-enclosing phases
+        self._open_rec: Optional[WindowRecord] = None
+        # Spans that belong to no dispatch (schedule, emit, wait): a ring
+        # covering about as much history as the window ring does (at most
+        # a schedule, an emit and a wait per dispatch), served as
+        # "phases" by GET /debug/windows.  The step thread appends, the
+        # event loop snapshots: both under ``_phase_lock``.
+        self._loose: Deque[list] = deque(maxlen=4 * max(1, window_ring_size))
+        self._phase_lock = threading.Lock()
+        # Last profiler session (/start_profile, /stop_profile): unix ns
+        # taken before and after start_trace / stop_trace.
+        self._profile: Dict[str, List[int]] = {}
+        if self.enabled:
+            self.compile_tracker.on_launch = self._on_launch
 
     # -- step phases (engine step thread) ----------------------------------
 
-    def step_phase(self, phase: str, seconds: float) -> None:
+    def phase(self, name: str, rec: Optional[WindowRecord] = None,
+              family: Optional[bool] = None):
+        """Span of the step thread's work, as a context manager with three
+        sinks: a ``pstpu.<name>`` TraceAnnotation (with the record's
+        ``window_id``) on the profiler's clock; ``[name, start_ns,
+        end_ns]`` (``time.time_ns()``) appended to ``rec.phases``, or with
+        no record to the loose ring of GET /debug/windows; and one
+        observation of ``tpu:step_<name>_seconds`` where ``name`` is a
+        STEP_PHASES family (``family=False``: not this time — the
+        synchronous paths' collect/sample splits stay out of the families
+        that time the pipelined path).
+
+        ``name`` is one of PHASES, or ``dispatch``/``mixed``, which
+        enclose other spans and reach only the profiler and their family.
+        A phase opened inside another (a first token sampled inside a
+        window's ``sample``) inherits its record, and reaches the profiler
+        and its family but not the record: a record's ``phases`` stay
+        ordered and disjoint.  With tracing off: one shared null context,
+        no state touched."""
         if not self.enabled:
+            return _NULL_PHASE
+        if family is None:
+            family = name in self.step_hists
+        return _PhaseSpan(self, name, rec, family)
+
+    def _keep_phase(self, rec: Optional[WindowRecord], name: str,
+                    t0: int, t1: int) -> None:
+        if rec is not None:
+            rec.phases.append([name, t0, t1])
+            if name == "collect":
+                rec.collected_ns = t1
             return
-        self.step_hists[phase].observe(seconds)
+        with self._phase_lock:
+            last = self._loose[-1] if self._loose else None
+            if name == "wait" and last is not None and last[0] == "wait":
+                # An idle engine wakes every 10 ms to look for work: one
+                # span for the whole sleep, not a ring full of them.
+                last[2] = t1
+            else:
+                self._loose.append([name, t0, t1])
+
+    # stackcheck: allow=SC201 reason=launch stamps are observability sinks; no plan state reads them (obs layer is plan-inert by contract)
+    def _on_launch(self, program: str) -> None:
+        """A tracked jit callable is about to be called (compile tracker
+        hook, step thread): stamp it onto the record whose phase is
+        open."""
+        rec = self._open_rec
+        if rec is None:
+            return
+        rec.programs.append(program)
+        rec.program_ns.append(time.time_ns())
+
+    def note_profile(self, edge: str, before_ns: int, after_ns: int) -> None:
+        """``edge`` = "start" | "stop": unix ns around the profiler call."""
+        if edge == "start":
+            self._profile = {}
+        self._profile[edge + "_unix_ns"] = [before_ns, after_ns]
 
     # -- KV transfer plane (prefetch/stager background threads) ------------
 
@@ -262,9 +424,16 @@ class EngineObs:
     def windows_payload(self, seq: Optional[str] = None) -> Dict:
         """GET /debug/windows (+?seq= filter): the flight-recorder ring,
         newest first."""
-        return {
+        payload = {
             "enabled": self.enabled,
             "windows": self.recorder.snapshot(seq=seq),
             "recorded": self.recorder.windows_recorded,
             "dropped": self.recorder.dropped,
+            "profile": dict(self._profile),
         }
+        if seq is None:
+            # The step thread's spans that belong to no dispatch, oldest
+            # first (one request's view leaves them out).
+            with self._phase_lock:
+                payload["phases"] = [list(p) for p in self._loose]
+        return payload

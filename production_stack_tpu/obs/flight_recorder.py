@@ -78,6 +78,33 @@ class WindowRecord:
     accepted: int = 0
     compile: bool = False          # an XLA compile fired inside this dispatch
     compile_s: float = 0.0
+    # The step loop on the profiler's clock (all unix ns, time.time_ns()).
+    # ``programs``/``program_ns``: every jitted program this dispatch
+    # launched (its compile-tracker name) and when, in launch order — the
+    # device runs them in that order, which is how a traced program finds
+    # its record; ``launch_ns`` (served too) is the first.  ``collected_ns``:
+    # the end of the last blocking read-back (None: nothing was read back).
+    # ``phases``: [name, start_ns, end_ns] spans of the step thread's work
+    # on this dispatch (EngineObs.phase), ordered and disjoint.
+    programs: List[str] = dataclasses.field(default_factory=list)
+    program_ns: List[int] = dataclasses.field(default_factory=list)
+    collected_ns: Optional[int] = None
+    phases: List[list] = dataclasses.field(default_factory=list)
+    # Token counters, known on the host at dispatch.  ``kv_tokens``: KV
+    # positions the decode rows attend, per row min(context, sliding
+    # window) rounded up to whole blocks (what the paged kernel must
+    # read on the first step).  ``new_tokens`` / ``bucket_tokens``: prompt
+    # tokens really computed / token slots of the prefill or chunk program
+    # that ran (the rest is padding).  ``cached_tokens``: tokens of those
+    # prompts already in the KV cache and skipped.
+    kv_tokens: int = 0
+    new_tokens: int = 0
+    bucket_tokens: int = 0
+    cached_tokens: int = 0
+
+    @property
+    def launch_ns(self) -> Optional[int]:
+        return self.program_ns[0] if self.program_ns else None
 
     def to_dict(self) -> Dict:
         d = {
@@ -97,7 +124,18 @@ class WindowRecord:
             "tokens_emitted": self.tokens_emitted,
             "tokens_delivered": self.tokens_delivered,
             "tokens_wasted": self.tokens_wasted,
+            "programs": list(self.programs),
+            "program_ns": list(self.program_ns),
+            "launch_ns": self.launch_ns,
+            "collected_ns": self.collected_ns,
+            "phases": [list(p) for p in self.phases],
         }
+        if self.rows:
+            d["kv_tokens"] = self.kv_tokens
+        if self.bucket_tokens:
+            d["new_tokens"] = self.new_tokens
+            d["bucket_tokens"] = self.bucket_tokens
+            d["cached_tokens"] = self.cached_tokens
         if self.spec_width:
             d["spec_width"] = self.spec_width
             d["drafter"] = self.drafter
@@ -153,6 +191,10 @@ class FlightRecorder:
         fallback: Optional[str] = None,
         host_gap_s: float = 0.0,
         transfer_overlap_s: float = 0.0,
+        kv_tokens: int = 0,
+        new_tokens: int = 0,
+        bucket_tokens: int = 0,
+        cached_tokens: int = 0,
         now: Optional[float] = None,
     ) -> Optional[WindowRecord]:
         """Stamp a new record at dispatch.  Returns None when disabled so
@@ -177,6 +219,10 @@ class FlightRecorder:
             fallback=fallback,
             host_gap_s=float(host_gap_s),
             transfer_overlap_s=float(transfer_overlap_s),
+            kv_tokens=int(kv_tokens),
+            new_tokens=int(new_tokens),
+            bucket_tokens=int(bucket_tokens),
+            cached_tokens=int(cached_tokens),
             dispatched_at=now if now is not None else time.time(),
         )
 

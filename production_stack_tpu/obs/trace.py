@@ -3,9 +3,7 @@
 No OpenTelemetry dependency — TPU serving images don't ship it, and the
 stack only needs (a) W3C ``traceparent`` propagation so router and engine
 timelines join under one trace id, and (b) a bounded in-memory ring of
-completed request timelines served at ``GET /debug/requests``.  ``to_otlp``
-emits OTLP-shaped JSON for anyone who wants to forward a timeline into a
-real collector.
+completed request timelines served at ``GET /debug/requests``.
 
 Thread-safety: the engine records spans from its step thread while the
 HTTP server reads from the event loop; every mutation holds the tracer
@@ -111,40 +109,6 @@ class RequestTrace:
             "duration_s": round(self.duration, 6),
             "attrs": dict(self.attrs),
             "spans": [s.to_dict() for s in sorted(self.spans, key=lambda s: s.start)],
-        }
-
-    def to_otlp(self) -> Dict:
-        """OTLP/JSON-shaped export of this timeline (one resourceSpans
-        entry; span/parent ids are freshly minted — only the trace id is
-        load-bearing for cross-component joins)."""
-
-        def nanos(t: float) -> str:
-            return str(int(t * 1e9))
-
-        return {
-            "resourceSpans": [{
-                "resource": {"attributes": [
-                    {"key": "service.name",
-                     "value": {"stringValue": f"tpu-{self.component}"}},
-                ]},
-                "scopeSpans": [{
-                    "scope": {"name": "production_stack_tpu.obs"},
-                    "spans": [
-                        {
-                            "traceId": self.trace_id,
-                            "spanId": new_span_id(),
-                            "name": span.name,
-                            "startTimeUnixNano": nanos(span.start),
-                            "endTimeUnixNano": nanos(span.end),
-                            "attributes": [
-                                {"key": str(k), "value": {"stringValue": str(v)}}
-                                for k, v in span.attrs.items()
-                            ],
-                        }
-                        for span in self.spans
-                    ],
-                }],
-            }]
         }
 
 

@@ -162,18 +162,6 @@ def test_disabled_tracer_is_noop():
     assert tracer.active_count() == 0
 
 
-def test_otlp_export_shape():
-    tracer = Tracer("engine")
-    tracer.start("r1", trace_id="ab" * 16)
-    tracer.add_span("r1", "engine.decode", 1.0, 2.0, tokens=5)
-    trace = tracer.finish("r1")
-    otlp = trace.to_otlp()
-    spans = otlp["resourceSpans"][0]["scopeSpans"][0]["spans"]
-    assert spans[0]["traceId"] == "ab" * 16
-    assert spans[0]["name"] == "engine.decode"
-    assert int(spans[0]["endTimeUnixNano"]) - int(spans[0]["startTimeUnixNano"]) == 10**9
-
-
 def test_join_timelines_phase_attribution():
     router = {
         "request_id": "r1", "trace_id": "t", "duration_s": 1.0,

@@ -461,6 +461,19 @@ def test_tracing_off_restores_fast_path():
     assert not isinstance(eng_off._prefill_fn, _TrackedJit)
     assert not isinstance(eng_off._decode_fn, _TrackedJit)
     assert isinstance(eng_on._prefill_fn, _TrackedJit)
+    # ... and the phase spans (PR 25): off, every phase() is ONE shared
+    # null context, nothing is annotated, kept or stamped, and no launch
+    # hook hangs on the tracker.
+    off = eng_off.obs
+    assert off.phase("build") is off.phase("collect", None, family=False)
+    assert off._annotation is None and off.compile_tracker.on_launch is None
+    assert off._depth == 0 and off._open_rec is None
+    assert off.windows_payload()["phases"] == []
+    assert off.windows_payload()["profile"] == {}
+    # On: spans were kept, on the records and beside them, and all closed.
+    on = eng_on.obs.windows_payload()
+    assert on["phases"] and all(w["phases"] for w in on["windows"])
+    assert eng_on.obs._depth == 0 and eng_on.obs._open_rec is None
 
 
 async def test_idle_router_renders_histogram_family_headers():
