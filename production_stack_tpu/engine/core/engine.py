@@ -1406,6 +1406,11 @@ class LLMEngine:
         # step-thread-only writers.
         self.multistep_fallback: Dict[str, int] = {}
         self.multistep_wasted_tokens = 0
+        # Kv tiles of the flash prefill kernel's grid that were computed /
+        # skipped by its liveness rule, per layer, summed over dispatched
+        # prefill chunks (tpu:prefill_attn_tiles_total{state}); host
+        # arithmetic in _count_kv_tiles, step-thread-only writer.
+        self.prefill_attn_tiles: Dict[str, int] = {"live": 0, "skipped": 0}
         # Last _can_window decline reason, stamped on the flight record
         # of the K=1 dispatch that replaced the declined window (step-
         # thread-only, overwritten every _can_window call).
@@ -2136,6 +2141,12 @@ class LLMEngine:
         rows are that much longer than the host knows).
         ``bucket_tokens``: the chunk program's token slots where they are
         not the plans' buckets (a mixed window scans a power of two)."""
+        if bucket_tokens is None:
+            bucket_tokens = sum(cp.bucket_len for cp in chunks)
+        # Counted with tracing off too: /metrics carries the totals.
+        kv_tiles_live, kv_tiles_grid = self._count_kv_tiles(
+            chunks, bucket_tokens
+        )
         if not self.obs.enabled:
             return None
         bs = self.block_pool.block_size
@@ -2156,8 +2167,6 @@ class LLMEngine:
         first = {}
         for cp in chunks:
             first.setdefault(cp.seq.seq_id, cp.cached_len)
-        if bucket_tokens is None:
-            bucket_tokens = sum(cp.bucket_len for cp in chunks)
         new_tokens = sum(cp.num_new_tokens for cp in chunks)
         return self.obs.recorder.on_dispatch(
             kind, rows=len(seqs),
@@ -2165,8 +2174,41 @@ class LLMEngine:
             chunk_prompts=len(first), chunk_tokens_planned=new_tokens,
             kv_tokens=kv_tokens, new_tokens=new_tokens,
             bucket_tokens=bucket_tokens,
-            cached_tokens=sum(first.values()), **fields,
+            cached_tokens=sum(first.values()),
+            kv_tiles_live=kv_tiles_live, kv_tiles_grid=kv_tiles_grid,
+            **fields,
         )
+
+    def _count_kv_tiles(self, chunks, bucket_tokens: int):
+        """(kv tiles the flash prefill kernel computes, kv tiles in its
+        grid) per layer for the PrefillPlans of one dispatch, by the
+        kernel's own liveness rule — host arithmetic, no device read; also
+        feeds ``tpu:prefill_attn_tiles_total``.  The counts describe the
+        kernel's grid whether or not it is the path that runs (under a tp
+        mesh prefill takes the dense path)."""
+        if not chunks:
+            return 0, 0
+        from production_stack_tpu.engine.ops.pallas.flash_prefill import (
+            count_kv_tiles,
+        )
+
+        C = max(self._bmax, 1) * self.block_pool.block_size
+        window = self.config.model.sliding_window
+        live = grid = slots = 0
+        for cp in chunks:
+            n_live, n_grid = count_kv_tiles(
+                cp.bucket_len, C, cp.cached_len, cp.num_new_tokens, window
+            )
+            live += n_live
+            grid += n_grid
+            slots += cp.bucket_len
+        # A mixed window's scan pads its schedule (one bucket) to a power
+        # of two: the padding iterations carry valid_len 0, so their
+        # tiles are in the grid and none is live.
+        grid += (bucket_tokens - slots) // cp.bucket_len * n_grid
+        self.prefill_attn_tiles["live"] += live
+        self.prefill_attn_tiles["skipped"] += grid - live
+        return live, grid
 
     def _note_compiles(self, rec, seq_ids=None) -> None:
         """Drain XLA compile events fired inside the jit calls this
@@ -4989,6 +5031,7 @@ class LLMEngine:
             # emitted-but-undeliverable window tokens.
             "multistep_fallback": dict(self.multistep_fallback),
             "multistep_wasted_tokens": self.multistep_wasted_tokens,
+            "prefill_attn_tiles": dict(self.prefill_attn_tiles),
             # Quantized KV tiering plane: bytes crossing each tier
             # boundary by wire format, and snapshot serde versions put
             # on the kvserver wire (tpu:kv_wire_bytes_total /

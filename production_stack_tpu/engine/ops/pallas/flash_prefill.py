@@ -12,11 +12,30 @@ scales with sequence length, so VMEM stays ~12 MB at any context
 (a previous revision kept the whole [S_k, K, D] KV row resident, which
 blew the 16 MB scoped-VMEM limit at 2k context on a 3B model).
 
-Causal skipping: kv tiles wholly above a query tile's frontier are
-skipped two ways — compute is fenced with ``pl.when``, and the kv
-index map clamps to the last visible tile so Mosaic's revisit-elision
-skips the DMA too (the block index doesn't change, so nothing is
-re-fetched).
+Tile skipping: the grid is static — (T/Tq, ceil((C+T)/Tk)), and the
+engine always gathers max_model_len prefix slots, so C is 8,192 whatever
+``cached_len`` is — but a kv tile is *visited* only if one of its scores
+survives the mask for one query of the query tile.  ``live_kv_tiles``
+states that rule once, per query tile, as two ranges of kv tiles:
+
+* the query tile has a valid row (``i*Tq < valid_len``), else nothing;
+* prefix tiles holding a slot below ``min(C, cached_len)``;
+* new-key tiles holding a key below ``valid_len`` and not above the
+  tile's causal frontier (its last query);
+* with a sliding window, only tiles whose newest valid key is still
+  inside the window of the tile's oldest query.
+
+A tile that straddles prefix and new keys (C need not be a multiple of
+Tk) is live if either part is.  The rule is used three times: the
+compute fence (``pl.when``), the kv index map — every dead step maps to
+the block of the nearest live tile already fetched (dead query tiles to
+the one tile the last live query tile ended on), so the block index
+repeats and Mosaic's revisit elision issues no DMA — and, on the host,
+``count_kv_tiles`` for the flight records' ``kv_tiles_live`` /
+``kv_tiles_grid``.  Skipping is exact: a fully masked tile's
+contribution is wiped by ``alpha = exp(NEG_INF - m) = 0`` at the first
+live tile, and a query tile that saw no live tile ends with ``l == 0``
+and emits zeros.
 
 Layout notes (Mosaic): blocks keep the (head, lane) dims whole — q tiles
 are [Tq, H, D], kv tiles [Tk, K, D] — because Mosaic requires the last
@@ -44,11 +63,89 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 LANES = 128  # scratch lane width: fp32 scratch must tile to (8, 128)
+Q_TILE = 128
+KV_TILE = 512
+
+
+def _tiling(T: int, C: int, q_tile: int, kv_tile: int):
+    """(Tq, Tk, query tiles, kv tiles) of the grid for T new tokens behind
+    C gathered prefix slots."""
+    Tq = min(q_tile, T)
+    Tk = min(kv_tile, C + T)
+    return Tq, Tk, T // Tq, -(-(C + T) // Tk)
+
+
+def live_kv_tiles(i, cached_len, valid_len, *, Tq, Tk, C, sliding_window,
+                  xp=jnp):
+    """The liveness rule (module docstring): which kv tiles query tile
+    ``i`` must visit, as ``(q_live, prefix_live, p_lo, p_hi, n_lo, n_hi)``
+    — kv tiles ``p_lo..p_hi`` hold its visible prefix slots (only if
+    ``prefix_live``), ``n_lo..n_hi`` its visible new keys, and nothing is
+    live unless ``q_live``.  Pure integer arithmetic on ``xp`` scalars or
+    arrays: jnp inside the kernel and its index map, numpy on the host."""
+    q0 = i * Tq  # the tile's oldest query, as a new-token index
+    q_live = q0 < valid_len
+    p_end = xp.minimum(cached_len, C)  # valid prefix slots [p_start, p_end)
+    n_end = xp.minimum(valid_len, q0 + Tq)  # valid causal keys [n_start, n_end)
+    if sliding_window is None:
+        p_start = n_start = 0
+    else:
+        # Oldest position the oldest query still sees; newer queries of
+        # the tile see no further back.
+        oldest = cached_len + q0 - sliding_window + 1
+        p_start = xp.maximum(oldest, 0)
+        n_start = xp.maximum(oldest - cached_len, 0)
+    prefix_live = q_live & (p_start < p_end)
+    return (
+        q_live, prefix_live,
+        p_start // Tk, (xp.maximum(p_end, 1) - 1) // Tk,
+        (C + n_start) // Tk, (C + xp.maximum(n_end, 1) - 1) // Tk,
+    )
+
+
+def _tile_is_live(j, ranges):
+    q_live, prefix_live, p_lo, p_hi, n_lo, n_hi = ranges
+    return q_live & (
+        (prefix_live & (p_lo <= j) & (j <= p_hi)) | ((n_lo <= j) & (j <= n_hi))
+    )
+
+
+def kv_block_index(i, j, cached_len, valid_len, *, Tq, Tk, C,
+                   sliding_window, xp=jnp):
+    """The kv block that grid step ``(i, j)`` holds.  A live step holds its
+    own tile ``j``; a dead step repeats the block of the nearest live tile
+    already fetched, so Mosaic's revisit elision skips its DMA: clamp into
+    [first live, last live], and park the gap between the prefix range and
+    the new-key range on the prefix range's end.  A dead query tile stays
+    where the last live query tile ended."""
+    q_live, prefix_live, p_lo, p_hi, n_lo, n_hi = live_kv_tiles(
+        i, cached_len, valid_len,
+        Tq=Tq, Tk=Tk, C=C, sliding_window=sliding_window, xp=xp,
+    )
+    idx = xp.clip(j, xp.where(prefix_live, p_lo, n_lo), n_hi)
+    idx = xp.where(prefix_live & (idx > p_hi) & (idx < n_lo), p_hi, idx)
+    parked = (C + xp.maximum(valid_len, 1) - 1) // Tk
+    return xp.where(q_live, idx, parked)
+
+
+def count_kv_tiles(T: int, C: int, cached_len: int, valid_len: int,
+                   sliding_window: Optional[int] = None, *,
+                   q_tile: int = Q_TILE, kv_tile: int = KV_TILE):
+    """(kv tiles the kernel computes, kv tiles in its grid) for one call,
+    per layer — the same rule on the host, arithmetic only."""
+    Tq, Tk, NQ, NKV = _tiling(T, C, q_tile, kv_tile)
+    ranges = live_kv_tiles(
+        np.arange(NQ), cached_len, valid_len,
+        Tq=Tq, Tk=Tk, C=C, sliding_window=sliding_window, xp=np,
+    )
+    live = _tile_is_live(np.arange(NKV)[:, None], ranges)
+    return int(live.sum()), NQ * NKV
 
 
 def _flash_prefill_kernel(
@@ -82,10 +179,9 @@ def _flash_prefill_kernel(
     valid = valid_len_ref[0]
     R = Tq * G  # query rows per kv head after GQA regrouping
 
-    # Last kv tile any query in this tile can see: the tile's last query
-    # sits at cached + (i+1)*Tq - 1 and sees prefix keys (flat < C) plus
-    # new keys with flat index < C + (i+1)*Tq.
-    last = (C + (i + 1) * Tq - 1) // Tk
+    live = _tile_is_live(j, live_kv_tiles(
+        i, cached, valid, Tq=Tq, Tk=Tk, C=C, sliding_window=sliding_window,
+    ))
 
     @pl.when(j == 0)
     def _init():
@@ -93,7 +189,7 @@ def _flash_prefill_kernel(
         l_ref[...] = jnp.zeros((K, R, LANES), jnp.float32)
         acc_ref[...] = jnp.zeros((K, R, D), jnp.float32)
 
-    @pl.when(j <= last)
+    @pl.when(live)
     def _compute():
         # [Tq, H, D] -> [K, Tq*G, D]: head h = k*G + g attends kv head k.
         q = q_ref[...].astype(jnp.float32) * scale
@@ -141,8 +237,8 @@ def _flash_prefill_kernel(
 
     @pl.when(j == NKV - 1)
     def _final():
-        # Rows past valid_len (padding) have every key masked -> l == 0;
-        # emit zeros, not NaNs (the caller slices them off).
+        # A query tile wholly past valid_len visited no kv tile -> l == 0;
+        # emit zeros, not NaNs (the caller slices padding rows off).
         l = jnp.max(l_ref[...], axis=-1, keepdims=True)
         l = jnp.where(l == 0.0, 1.0, l)
         out = (acc_ref[...] / l).reshape(K, Tq, G, D).swapaxes(0, 1)
@@ -164,8 +260,8 @@ def flash_prefill_attention(
     *,
     scale: float,
     sliding_window: Optional[int] = None,
-    q_tile: int = 128,
-    kv_tile: int = 512,
+    q_tile: int = Q_TILE,
+    kv_tile: int = KV_TILE,
     interpret: bool = False,
 ) -> jax.Array:
     """Flash causal prefill attention with prefix (Pallas TPU)."""
@@ -178,20 +274,16 @@ def flash_prefill_attention(
     if D % 128 and not interpret:
         raise ValueError(f"flash prefill requires head_dim%128==0, got {D}")
 
-    Tq = min(q_tile, T)
+    Tq, Tk, NQ, NKV = _tiling(T, C, q_tile, kv_tile)
     if T % Tq:
         raise ValueError(f"T={T} not a multiple of q_tile={Tq}")
 
     keys = jnp.concatenate([k_prefix, k_new], axis=0)  # [C+T, K, D]
     values = jnp.concatenate([v_prefix, v_new], axis=0)
-    S_raw = C + T
-    Tk = min(kv_tile, S_raw)
-    S_k = -(-S_raw // Tk) * Tk
-    if S_k != S_raw:
-        pad = [(0, S_k - S_raw), (0, 0), (0, 0)]
+    if NKV * Tk != C + T:
+        pad = [(0, NKV * Tk - (C + T)), (0, 0), (0, 0)]
         keys = jnp.pad(keys, pad)  # padded keys are masked (j-C >= valid)
         values = jnp.pad(values, pad)
-    NKV = S_k // Tk
 
     kernel = functools.partial(
         _flash_prefill_kernel,
@@ -199,16 +291,18 @@ def flash_prefill_attention(
         scale=scale, sliding_window=sliding_window,
     )
 
-    def kv_index(i, j, *_):
-        # Clamp to the tile's causal frontier: for skipped steps the block
-        # index repeats, so Mosaic's revisit-elision skips the DMA.
-        last = (C + (i + 1) * Tq - 1) // Tk
-        return (jnp.minimum(j, last), 0, 0)
+    def kv_index(i, j, cached_ref, valid_ref):
+        return (
+            kv_block_index(
+                i, j, cached_ref[0], valid_ref[0],
+                Tq=Tq, Tk=Tk, C=C, sliding_window=sliding_window,
+            ), 0, 0,
+        )
 
     R = Tq * G
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(T // Tq, NKV),
+        grid=(NQ, NKV),
         in_specs=[
             pl.BlockSpec((Tq, H, D), lambda i, j, *_: (i, 0, 0)),
             pl.BlockSpec((Tk, K, D), kv_index),

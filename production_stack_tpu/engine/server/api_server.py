@@ -409,6 +409,10 @@ def build_engine_app(
                     **s["multistep_fallback"],
                 },
             )
+            + vocab.render_labeled_counter(
+                vocab.TPU_PREFILL_ATTN_TILES, "state",
+                s["prefill_attn_tiles"],
+            )
             # Fused speculative windows: outcome x drafter (one engine
             # runs at most one proposal source, so the live counts land
             # on the configured drafter's series; all six cells pre-seed

@@ -96,11 +96,16 @@ class WindowRecord:
     # read on the first step).  ``new_tokens`` / ``bucket_tokens``: prompt
     # tokens really computed / token slots of the prefill or chunk program
     # that ran (the rest is padding).  ``cached_tokens``: tokens of those
-    # prompts already in the KV cache and skipped.
+    # prompts already in the KV cache and skipped.  ``kv_tiles_live`` /
+    # ``kv_tiles_grid``: kv tiles, per layer, the flash prefill kernel
+    # computes / its grid holds for those chunks (its liveness rule,
+    # evaluated on the host; the rest is skipped).
     kv_tokens: int = 0
     new_tokens: int = 0
     bucket_tokens: int = 0
     cached_tokens: int = 0
+    kv_tiles_live: int = 0
+    kv_tiles_grid: int = 0
 
     @property
     def launch_ns(self) -> Optional[int]:
@@ -136,6 +141,8 @@ class WindowRecord:
             d["new_tokens"] = self.new_tokens
             d["bucket_tokens"] = self.bucket_tokens
             d["cached_tokens"] = self.cached_tokens
+            d["kv_tiles_live"] = self.kv_tiles_live
+            d["kv_tiles_grid"] = self.kv_tiles_grid
         if self.spec_width:
             d["spec_width"] = self.spec_width
             d["drafter"] = self.drafter
@@ -195,6 +202,8 @@ class FlightRecorder:
         new_tokens: int = 0,
         bucket_tokens: int = 0,
         cached_tokens: int = 0,
+        kv_tiles_live: int = 0,
+        kv_tiles_grid: int = 0,
         now: Optional[float] = None,
     ) -> Optional[WindowRecord]:
         """Stamp a new record at dispatch.  Returns None when disabled so
@@ -223,6 +232,8 @@ class FlightRecorder:
             new_tokens=int(new_tokens),
             bucket_tokens=int(bucket_tokens),
             cached_tokens=int(cached_tokens),
+            kv_tiles_live=int(kv_tiles_live),
+            kv_tiles_grid=int(kv_tiles_grid),
             dispatched_at=now if now is not None else time.time(),
         )
 

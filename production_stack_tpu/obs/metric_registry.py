@@ -297,6 +297,16 @@ REGISTRY = {
                 "out-of-band finish mid-window; device stop-mask keeps "
                 "ordinary stops at zero waste)",
     },
+    "tpu:prefill_attn_tiles_total": {
+        "kind": "counter", "layer": "engine", "labels": ("state",),
+        "mirrors": ("fake_engine", "dashboard", "docs"),
+        "help": "Kv tiles of the flash prefill kernel's grid, per layer, "
+                "over dispatched prefill chunks (state: live — computed; "
+                "skipped — wholly masked, neither fetched nor computed: "
+                "gathered prefix slots past cached_len, new keys past "
+                "valid_len, padded query tiles); host arithmetic from "
+                "each plan, no device read",
+    },
     "tpu:kv_wire_bytes_total": {
         "kind": "counter", "layer": "engine", "labels": ("tier", "format"),
         "mirrors": ("fake_engine", "dashboard", "docs"),

@@ -195,6 +195,15 @@ TPU_MULTISTEP_FALLBACK_REASONS = (
     "bucket_mismatch", "pool_pressure", "draft_pool",
 )
 TPU_MULTISTEP_WASTED_TOKENS = "tpu:multistep_wasted_tokens_total"
+# The flash prefill kernel's kv tiles (ops/pallas/flash_prefill.py), per
+# layer, over dispatched prefill chunks: live — tiles with a score that
+# survives the mask, computed; skipped — tiles of the static grid (the
+# gathered max_model_len prefix slots past cached_len, new keys past
+# valid_len or the causal frontier or outside the sliding window, padded
+# query tiles) the kernel neither fetched nor computed.  Counted on the
+# host from each plan's (bucket, cached_len, new tokens).
+TPU_PREFILL_ATTN_TILES = "tpu:prefill_attn_tiles_total"
+TPU_PREFILL_ATTN_TILE_STATES = ("live", "skipped")
 # Mixed K-step windows (scheduler mixed_window): prompt tokens whose
 # prefill chunks rode the device-resident decode scan — the subset of
 # tpu:prefill_chunk_tokens that did NOT pay a per-chunk host

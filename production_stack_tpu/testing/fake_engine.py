@@ -552,6 +552,10 @@ def build_fake_engine_app(state: FakeEngineState | None = None) -> web.Applicati
         ) + vocab.render_labeled_counter(
             vocab.TPU_MULTISTEP_FALLBACK, "reason",
             dict.fromkeys(vocab.TPU_MULTISTEP_FALLBACK_REASONS, 0),
+        ) + vocab.render_labeled_counter(
+            # No prefill kernel in the fake: the family, at zero (SC303).
+            vocab.TPU_PREFILL_ATTN_TILES, "state",
+            dict.fromkeys(vocab.TPU_PREFILL_ATTN_TILE_STATES, 0),
         ) + vocab.render_labeled_counter2(
             # Fused speculative windows: no device, so no drafts — but
             # the family (all outcome x drafter cells) must exist for

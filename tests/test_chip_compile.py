@@ -100,9 +100,13 @@ def test_decode_kernel_bf16_compiles(sds, no_persistent_cache, heads):
     )
 
 
-# The engine always gathers max_model_len of prefix slots (masked by
-# cached_len), so C = MAX_LEN is the shape every served prefill compiles;
-# C = 0 is the embeddings path (models/llama.py encode).
+# The engine always gathers max_model_len of prefix slots, so C = MAX_LEN
+# is the shape every served prefill compiles; C = 0 is the embeddings path
+# (models/llama.py encode).  The grid is static, what it visits is not:
+# the kernel's fence and its kv index map (which reads the prefetched
+# cached_len / valid_len) skip every kv tile that holds no visible key --
+# prefix slots past cached_len, new keys past valid_len -- and every
+# query tile past valid_len (flash_prefill.py: live_kv_tiles).
 @pytest.mark.parametrize(
     "heads,T,C",
     [

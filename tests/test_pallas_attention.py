@@ -100,6 +100,7 @@ def test_pallas_decode_gqa_head_mapping():
 # -- flash prefill kernel ---------------------------------------------------
 
 from production_stack_tpu.engine.ops.attention import prefill_attention
+from production_stack_tpu.engine.ops.pallas import flash_prefill as fp
 from production_stack_tpu.engine.ops.pallas.flash_prefill import (
     flash_prefill_attention,
 )
@@ -124,6 +125,19 @@ def _prefill_case(seed, T, H, K, D, C, dtype=jnp.float32):
         (64, 6, 2, 32, 16, 16, 64, None),    # G=3 (llama-3.2-3b shape)
         (64, 4, 2, 32, 32, 32, 64, 24),      # sliding window
         (512, 4, 2, 32, 64, 48, 500, None),  # multi q-tile + multi kv-tile
+        # The served pattern, small: the engine gathers max_model_len
+        # prefix slots whatever cached_len is, and pads T to a bucket.
+        (64, 4, 2, 32, 512, 0, 64, None),     # C >> T, nothing cached
+        (64, 4, 2, 32, 256, 100, 64, None),   # cached_len inside a kv tile
+        (64, 4, 2, 32, 256, 128, 64, None),   # cached_len on a tile edge
+        (64, 4, 2, 32, 256, 256, 40, None),   # cached_len == C
+        (128, 4, 2, 32, 128, 70, 10, None),   # valid_len < Tq: a dead q tile
+        (128, 4, 2, 32, 256, 0, 128, None),   # valid_len == T, dead prefix
+        (128, 4, 2, 32, 200, 150, 100, None),  # C not a multiple of kv_tile
+        (64, 4, 2, 32, 256, 256, 64, 100),    # window cuts inside the prefix
+        (128, 4, 2, 32, 320, 200, 128, 150),  # window + dead prefix tiles
+        (128, 4, 2, 32, 64, 64, 128, 40),     # window cuts inside new keys
+        (64, 4, 2, 32, 128, 64, 0, None),     # valid_len 0: nothing live
     ],
 )
 def test_flash_prefill_matches_dense(T, H, K, D, C, cached, valid, window):
@@ -146,6 +160,115 @@ def test_flash_prefill_matches_dense(T, H, K, D, C, cached, valid, window):
         np.asarray(got)[live], np.asarray(want)[live], rtol=2e-5, atol=2e-5
     )
     assert np.all(np.isfinite(np.asarray(got)))
+
+
+def _brute_force_live_tiles(T, C, cached, valid, window, Tq, Tk):
+    """[query tiles, kv tiles] bool: does any score of the tile survive
+    the dense path's mask (ops/attention.py: prefill_attention) on a row
+    below valid_len?  Rows past it are padding nobody reads."""
+    key_pos = np.concatenate([np.arange(C), cached + np.arange(T)])
+    key_valid = np.concatenate([np.arange(C) < cached, np.arange(T) < valid])
+    q_pos = cached + np.arange(T)
+    mask = (key_pos[None, :] <= q_pos[:, None]) & key_valid[None, :]
+    if window is not None:
+        mask &= key_pos[None, :] > q_pos[:, None] - window
+    mask &= (np.arange(T) < valid)[:, None]
+    nkv = -(-(C + T) // Tk)
+    mask = np.pad(mask, [(0, 0), (0, nkv * Tk - (C + T))])
+    return mask.reshape(T // Tq, Tq, nkv, Tk).any(axis=(1, 3))
+
+
+@pytest.mark.parametrize(
+    "T,C,q_tile,kv_tile",
+    [
+        (64, 0, 16, 32),      # the encode lane: no prefix
+        (64, 128, 16, 32),    # C a multiple of the kv tile
+        (64, 100, 16, 32),    # a tile straddles prefix and new keys
+        (32, 96, 32, 48),     # one query tile
+        (256, 8192, 128, 512),   # the served buckets behind the 8,192
+        (2048, 8192, 128, 512),  # gathered prefix slots
+    ],
+)
+def test_liveness_rule_matches_the_mask_tile_by_tile(T, C, q_tile, kv_tile):
+    """The one rule behind the compute fence, the kv index map and the
+    host's counter, against brute force over (cached_len, valid_len,
+    window); and the index map fetches each live tile once and nothing
+    else."""
+    Tq, Tk, NQ, NKV = fp._tiling(T, C, q_tile, kv_tile)
+    i, j = np.arange(NQ)[:, None], np.arange(NKV)[None, :]
+    small = C + T <= 1024
+    for cached in sorted({0, 1, C // 3, Tk, C - 1, C} & set(range(C + 1))):
+        for valid in sorted({0, 1, Tq - 1, Tq, T // 2 + 3, T}):
+            for window in (None, 1, Tq + 5, C // 2 + 7, 4096):
+                if window == 4096 and small:
+                    continue
+                kw = dict(Tq=Tq, Tk=Tk, C=C, sliding_window=window, xp=np)
+                what = f"cached={cached} valid={valid} window={window}"
+                want = _brute_force_live_tiles(
+                    T, C, cached, valid, window, Tq, Tk)
+                got = fp._tile_is_live(
+                    j, fp.live_kv_tiles(i, cached, valid, **kw))
+                np.testing.assert_array_equal(got, want, err_msg=what)
+                assert fp.count_kv_tiles(
+                    T, C, cached, valid, window,
+                    q_tile=q_tile, kv_tile=kv_tile,
+                ) == (want.sum(), NQ * NKV), what
+                # A live step holds its own tile, a dead step of a live
+                # query tile one of that query tile's live tiles, a dead
+                # query tile whatever the step before it held: walking
+                # the grid in order fetches no more blocks than are live.
+                idx = fp.kv_block_index(i, j, cached, valid, **kw)
+                np.testing.assert_array_equal(
+                    idx[want], np.broadcast_to(j, idx.shape)[want], what)
+                assert idx.min() >= 0 and idx.max() < NKV, what
+                q_live = want.any(axis=1)
+                assert np.take_along_axis(want, idx, 1)[q_live].all(), what
+                flat = idx.ravel()
+                moved = np.flatnonzero(flat[1:] != flat[:-1]) + 1
+                assert q_live[moved // NKV].all(), what
+                assert len(moved) + 1 <= max(want.sum(), 1), what
+
+
+def test_flight_record_counts_the_tiles_the_kernel_visits():
+    """``kv_tiles_live`` / ``kv_tiles_grid`` on a prefill's flight record
+    are the rule's count for that plan, at the kernel's own tile sizes,
+    and /metrics' counter is their sum."""
+    from production_stack_tpu.engine.config import config_from_preset
+    from production_stack_tpu.engine.core.engine import LLMEngine
+    from production_stack_tpu.engine.core.sequence import SamplingParams
+
+    config = config_from_preset(
+        "tiny-llama",
+        **{"cache.num_blocks": 64, "scheduler.max_num_seqs": 2,
+           "scheduler.prefill_buckets": (16, 32),
+           "scheduler.mixed_batch": False},
+    )
+    eng = LLMEngine(config)
+    shared = list(range(3, 35))  # two whole 16-token blocks
+    for rid, ids in (("a", shared + [40, 41, 42]), ("b", shared + [50])):
+        eng.add_request(rid, prompt_token_ids=ids,
+                        sampling_params=SamplingParams(
+                            max_tokens=2, ignore_eos=True))
+        while eng.has_unfinished():
+            eng.step()
+    C = config.scheduler.max_model_len
+    window = config.model.sliding_window
+    prefills = [w for w in eng.obs.windows_payload()["windows"]
+                if w.get("bucket_tokens")]
+    assert {w["cached_tokens"] for w in prefills} >= {0, 32}
+    live = grid = 0
+    for w in prefills:
+        T = w["bucket_tokens"]
+        Tq, Tk, NQ, NKV = fp._tiling(T, C, fp.Q_TILE, fp.KV_TILE)
+        want = _brute_force_live_tiles(
+            T, C, w["cached_tokens"], w["new_tokens"], window, Tq, Tk)
+        assert (w["kv_tiles_live"], w["kv_tiles_grid"]) == (
+            want.sum(), NQ * NKV)
+        assert 0 < w["kv_tiles_live"] < w["kv_tiles_grid"]
+        live += w["kv_tiles_live"]
+        grid += w["kv_tiles_grid"]
+    assert eng.stats()["prefill_attn_tiles"] == {
+        "live": live, "skipped": grid - live}
 
 
 def test_flash_prefill_causality():

@@ -161,6 +161,12 @@ def test_every_dispatch_path_leaves_phases_programs_and_counts(runs, path):
                     assert end <= w["collected_ns"] or name == "launch"
         assert w["new_tokens"] <= w["bucket_tokens"] if w.get(
             "bucket_tokens") else "new_tokens" not in w
+        if w.get("bucket_tokens"):
+            # The flash prefill kernel's kv tiles for these chunks: some
+            # are live, and the static grid holds more than are.
+            assert 0 < w["kv_tiles_live"] < w["kv_tiles_grid"]
+        else:
+            assert "kv_tiles_live" not in w
         if w["rows"]:
             # Whole 16-token blocks, at least one a row.
             assert w["kv_tokens"] >= 16 * w["rows"]
