@@ -40,6 +40,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from production_stack_tpu.engine.config import PRESETS, EngineConfig
+from production_stack_tpu.engine.core import step_programs
 from production_stack_tpu.engine.core.scheduler import (
     DecodePlan,
     PrefillPlan,
@@ -438,10 +439,9 @@ class LLMEngine:
             donate_argnames=("kv_caches",),
             static_argnames=("prompt_topk",),
         )
+        model_decode = partial(self.model.decode, cfg=cfg, mesh=self.mesh)
         self._decode_fn = self._jit(
-            "decode_fn",
-            partial(self.model.decode, cfg=cfg, mesh=self.mesh),
-            donate_argnames=("kv_caches",),
+            "decode_fn", model_decode, donate_argnames=("kv_caches",),
         )
         # Fused mixed prefill+decode step (StepPlan decode+chunk): one
         # executable per (decode bucket, chunk bucket) pair — jit retraces
@@ -459,818 +459,80 @@ class LLMEngine:
             config.scheduler.mixed_batch = False
         self._sample_fn = self._jit("sample_fn", sample_tokens)
 
-        # K-step device-resident decode windows (tentpole of the unified
-        # StepPlan path; vLLM --num-scheduler-steps made the default):
-        # scan K decode+sample iterations on-device and return all K
-        # emitted tokens in one host round-trip.  Slot targeting moves
-        # on-device (the block-table lookup per iteration); penalties and
-        # the min_tokens EOS floor run INSIDE the scan from device-
-        # resident occurrence state, and a per-row stop-token match
-        # freezes the row (no further KV writes, position/ctx frozen, -1
-        # emitted) so stop conditions no longer waste up to K-1 tokens.
-        # The final carry is returned so window N+1 can chain from window
-        # N's still-in-flight state (pipelined windows).
+        # The step programs (engine/core/step_programs.py): what runs on
+        # the device between two host round trips.  Built there from the
+        # model's entry points and jitted here under the names the trace,
+        # the flight records and the compile tracker read.
         self._window_fn = None
+        self._spec_window_fn = None
+        self._mixed_window_fn = None
         self._window_steps = config.scheduler.window_steps
         # Per-window per-row token ceiling (max-acceptance growth under
         # the fused speculative window): sizes the chained-window
         # block-table delta and mirrors the scheduler's block budget.
         self._window_max_tokens = config.scheduler.window_max_tokens
         if self._window_steps > 1:
-            model_decode = partial(self.model.decode, cfg=cfg, mesh=self.mesh)
-            bs = config.cache.block_size
-            n_steps = self._window_steps
-            vocab = cfg.vocab_size
-
-            def multi_window(
-                params, tokens, positions, ctx_lens, done, min_left,
-                block_tables, max_steps, kv_caches,
-                temps, top_ps, top_ks, min_ps, seq_seeds,
-                stop_ids, key_base, counts, seen,
-                presence, frequency, repetition,
-                use_penalties, use_min_floor,
-                lora=None, adapter_idx=None,
-            ):
-                # Per-row stop set as an [S, V] mask: doubles as the
-                # min_tokens ban mask (the banned set IS the stop set —
-                # vLLM min_tokens semantics) and the freeze predicate.
-                stop_valid = stop_ids >= 0
-                stop_mask = None
-                if use_min_floor:
-                    stop_mask = jax.vmap(
-                        lambda ids, v: jnp.zeros(
-                            (vocab,), jnp.bool_
-                        ).at[jnp.where(v, ids, 0)].max(v)
-                    )(stop_ids, stop_valid)
-
-                def body(carry, t):
-                    (tokens, positions, ctx_lens, done, min_left,
-                     counts, seen, kv_caches) = carry
-                    active = jnp.logical_and(~done, t < max_steps)  # [S]
-                    blk = jnp.take_along_axis(
-                        block_tables, (positions // bs)[:, None], axis=1
-                    )[:, 0]
-                    extra = (
-                        {"lora": lora, "adapter_idx": adapter_idx}
-                        if lora is not None else {}
-                    )
-                    logits, kv_caches = model_decode(
-                        params,
-                        tokens=tokens,
-                        positions=positions,
-                        block_tables=block_tables,
-                        ctx_lens=ctx_lens,
-                        # Frozen/done rows park their KV write on null
-                        # block 0 — no cache slot past the stop position
-                        # is ever written.
-                        slot_block_ids=jnp.where(active, blk, 0),
-                        slot_offsets=positions % bs,
-                        kv_caches=kv_caches,
-                        **extra,
-                    )
-                    if use_penalties:
-                        logits = sampling_lib.apply_penalties_state(
-                            logits, counts, seen,
-                            presence, frequency, repetition,
-                        )
-                    if use_min_floor:
-                        # Same -1e9 additive bias as the host path's
-                        # logit_bias matrix, active while the row's
-                        # min_tokens floor is unmet (+0.0 elsewhere is
-                        # bit-exact identity).
-                        bias = (
-                            jnp.logical_and(
-                                stop_mask, (min_left > 0)[:, None]
-                            ).astype(jnp.float32) * -1e9
-                        )
-                        logits = logits + bias
-                    # Key schedule matches single-token stepping exactly:
-                    # iteration t of a window dispatched at step counter
-                    # c uses PRNGKey(seed + c + t), the key the classic
-                    # path would use for that token — seeded sampling is
-                    # bit-identical across window sizes.
-                    sampled = sample_tokens(
-                        logits, temps, top_ps, top_ks,
-                        jax.random.PRNGKey(key_base + t), seq_seeds,
-                        min_p=min_ps,
-                    )
-                    stop_hit = jnp.logical_and(
-                        active,
-                        jnp.any(
-                            jnp.logical_and(
-                                sampled[:, None] == stop_ids, stop_valid
-                            ),
-                            axis=1,
-                        ),
-                    )
-                    emitted = jnp.where(active, sampled, -1)
-                    appended = jnp.logical_and(active, ~stop_hit)
-                    if use_penalties:
-                        rows = jnp.arange(counts.shape[0])
-                        counts = counts.at[rows, sampled].add(
-                            appended.astype(jnp.int16)
-                        )
-                        seen = seen.at[rows, sampled].max(appended)
-                    step = active.astype(jnp.int32)
-                    return (
-                        jnp.where(active, sampled, tokens),
-                        positions + step,
-                        ctx_lens + step,
-                        jnp.logical_or(done, stop_hit),
-                        jnp.maximum(min_left - step, 0),
-                        counts, seen, kv_caches,
-                    ), emitted
-
-                carry, emitted = jax.lax.scan(
-                    body,
-                    (tokens, positions, ctx_lens, done, min_left,
-                     counts, seen, kv_caches),
-                    jnp.arange(n_steps),
-                )
-                (tokens, positions, ctx_lens, done, min_left,
-                 counts, seen, kv_caches) = carry
-                # (No device-side all-finished reduction: every stop is
-                # visible in the emitted [K, S] tokens the host reads
-                # back anyway, so collect() evaluates the all-finished
-                # predicate from host state for free and drops queued
-                # successor windows without any extra device sync.)
-                state = {
-                    "tokens": tokens, "positions": positions,
-                    "ctx_lens": ctx_lens, "done": done,
-                    "min_left": min_left, "counts": counts, "seen": seen,
-                }
-                return emitted, state, kv_caches
-
+            dims = dict(
+                block_size=config.cache.block_size, vocab=cfg.vocab_size,
+            )
             self._window_fn = self._jit(
                 "window_fn",
-                multi_window,
+                step_programs.window_program(
+                    model_decode, n_steps=self._window_steps, **dims
+                ),
                 static_argnames=("use_penalties", "use_min_floor"),
                 donate_argnames=("kv_caches",),
             )
-
-        # Fused speculation INSIDE the K-step window scan (the ROADMAP
-        # item-1 plan fusion): each scan iteration proposes up to
-        # `spec_draft_len` draft tokens on-device from ONE of two
-        # proposal sources behind a shared drafting interface — the
-        # n-gram drafter (prompt lookup: most recent earlier occurrence
-        # of the trailing bigram within a carried recent-history buffer)
-        # or the draft MODEL (scheduler.speculative_model: a tiny second
-        # model run autoregressively from its own compact device-
-        # resident KV cache, carried through the scan like the history
-        # buffer) — then verifies them in the SAME wide forward by
-        # scoring the draft positions alongside the committed token
-        # (W = draft_len+1 rows per sequence — the host speculative
-        # path's expanded-batch layout, now inside the scan), and folds
-        # acceptance into the carried state.  A rejected draft costs a
-        # scan iteration, never a host round-trip; accepted tokens
-        # advance the row's position/KV cursor inside the window.
-        # Greedy-only (acceptance compares the model's own argmax, so
-        # greedy streams are byte-identical by construction AND a pure
-        # function of weights + carried state — lockstep replicas cannot
-        # desync); penalties, the min_tokens floor and stop masking
-        # apply to EVERY accepted token sequentially through the same
-        # apply_penalties_state / stop-mask code the single-step path
-        # uses.
-        #
-        # Model-drafter cache layout: the draft KV uses COMPACT slots
-        # (0-based within the row's dedicated draft blocks) but TRUE
-        # sequence positions for RoPE — attention distances stay exact,
-        # so draft logits match full-context draft logits whenever the
-        # H-token history window covers the whole sequence, and degrade
-        # gracefully (history truncation, not corruption) past it.  The
-        # cache is (re)built by an in-graph causal PRIME (do_prime
-        # static arg): ONE wide draft forward over the S x (H-1) history
-        # tokens, write-then-attend + per-row ctx masking making row c
-        # attend exactly slots 0..c — the same trick the verify rows
-        # use.  Chained windows skip the prime (draft_pos rides the
-        # carry); the host re-primes on batch rebuilds, after any
-        # non-model-spec dispatch, and every _DRAFT_PRIME_CHAIN windows
-        # (the conservative capacity watermark).
-        self._spec_window_fn = None
-        if self._window_steps > 1 and config.scheduler.spec_window_enabled:
-            model_decode = partial(self.model.decode, cfg=cfg, mesh=self.mesh)
-            bs = config.cache.block_size
-            n_steps = self._window_steps
-            vocab = cfg.vocab_size
-            drafter = config.scheduler.spec_drafter
-            D = config.scheduler.spec_draft_len  # drafts per iteration
-            W = D + 1  # verify rows per sequence (committed + drafts)
-            H = self._SPEC_HIST_WINDOW
-            if drafter == "model":
-                draft_decode = partial(
-                    self.draft_model.decode, cfg=self.draft_cfg,
-                    mesh=self.mesh,
+            if config.scheduler.spec_window_enabled:
+                drafter = config.scheduler.spec_drafter
+                self._spec_window_fn = self._jit(
+                    "spec_window_fn",
+                    step_programs.spec_window_program(
+                        model_decode,
+                        partial(
+                            self.draft_model.decode, cfg=self.draft_cfg,
+                            mesh=self.mesh,
+                        ) if drafter == "model" else None,
+                        drafter=drafter,
+                        draft_len=config.scheduler.spec_draft_len,
+                        hist_window=self._SPEC_HIST_WINDOW,
+                        n_steps=self._window_steps, **dims,
+                    ),
+                    static_argnames=(
+                        "use_penalties", "use_min_floor", "do_prime",
+                    ),
+                    donate_argnames=(
+                        ("kv_caches", "draft_kv") if drafter == "model"
+                        else ("kv_caches",)
+                    ),
                 )
-
-            def spec_window(
-                params, tokens, positions, ctx_lens, done, min_left,
-                block_tables, max_steps, kv_caches,
-                stop_ids, counts, seen, hist,
-                presence, frequency, repetition,
-                use_penalties, use_min_floor,
-                draft_params=None, draft_tables=None, draft_pos=None,
-                draft_kv=None, do_prime=False,
-                lora=None, adapter_idx=None,
-            ):
-                stop_valid = stop_ids >= 0
-                stop_mask = None
-                if use_min_floor:
-                    stop_mask = jax.vmap(
-                        lambda ids, v: jnp.zeros(
-                            (vocab,), jnp.bool_
-                        ).at[jnp.where(v, ids, 0)].max(v)
-                    )(stop_ids, stop_valid)
-                bmax = block_tables.shape[1]
-                if lora is not None:
-                    wide_adapter = jnp.repeat(adapter_idx, W)
-                if drafter == "model":
-                    dbmax = draft_tables.shape[1]
-                if drafter == "model" and do_prime:
-                    # -- in-graph causal prime of the draft cache -------
-                    # One wide draft forward over every row's history-
-                    # window tokens EXCLUDING the committed last token
-                    # (the scan's first draft forward consumes that):
-                    # hist col c of a row with `live` valid entries maps
-                    # to compact slot c - (H - live) at TRUE position
-                    # positions + 1 - H + c; invalid (left-pad) rows
-                    # park on draft null block 0 at ctx 0.  Write-then-
-                    # attend + ctx = slot+1 masking gives exact causal
-                    # attention in the single call.
-                    Hm1 = H - 1
-                    live = jnp.minimum(positions + 1, H)
-                    colsp = jnp.arange(Hm1)[None, :]
-                    slots = colsp - (H - live)[:, None]
-                    pvalid = slots >= 0
-                    safe_slot = jnp.where(pvalid, slots, 0)
-                    rope = positions[:, None] + 1 - H + colsp
-                    pblk = jnp.take_along_axis(
-                        draft_tables,
-                        jnp.clip(safe_slot // bs, 0, dbmax - 1),
-                        axis=1,
-                    )
-                    _, draft_kv = draft_decode(
-                        draft_params,
-                        tokens=jnp.maximum(hist[:, :Hm1], 0).reshape(-1),
-                        positions=jnp.where(pvalid, rope, 0).reshape(-1),
-                        block_tables=jnp.repeat(draft_tables, Hm1, axis=0),
-                        ctx_lens=jnp.where(
-                            pvalid, slots + 1, 0
-                        ).reshape(-1),
-                        slot_block_ids=jnp.where(
-                            pvalid, pblk, 0
-                        ).reshape(-1),
-                        slot_offsets=(safe_slot % bs).reshape(-1),
-                        kv_caches=draft_kv,
-                    )
-                    # Invariant entering the scan: the draft cache holds
-                    # all context up to but EXCLUDING the committed
-                    # token, and draft_pos counts those compact slots.
-                    draft_pos = live - 1
-
-                def body(carry, t):
-                    if drafter == "model":
-                        (tokens, positions, ctx_lens, done, min_left,
-                         emitted_cnt, counts, seen, hist, draft_pos,
-                         kv_caches, draft_kv) = carry
-                    else:
-                        (tokens, positions, ctx_lens, done, min_left,
-                         emitted_cnt, counts, seen, hist, kv_caches) = carry
-                    # Budget gate is the TOKEN count, not the iteration
-                    # index: acceptance advances a row several tokens
-                    # per iteration and max_steps budgets the
-                    # max-acceptance growth the scheduler allocated
-                    # blocks for.
-                    active = jnp.logical_and(~done, emitted_cnt < max_steps)
-
-                    if drafter == "model":
-                        # -- in-scan draft-model proposal ---------------
-                        # D+1 sequential single-row draft forwards: d=0
-                        # consumes the committed token (writing its KV
-                        # at compact slot draft_pos, TRUE RoPE position
-                        # `positions`), each d < D argmaxes the next
-                        # proposal and feeds it forward; the final d=D
-                        # forward only writes the last draft's KV so the
-                        # cache invariant holds even at full acceptance.
-                        # The verify's rewind is free: draft_pos
-                        # advances by the ACCEPTED count + 1, landing
-                        # the next iteration's first write exactly on
-                        # the first stale (rejected-draft) slot — stale
-                        # slots are overwritten before any row's ctx
-                        # mask can attend them.  Inactive rows park
-                        # writes on draft null block 0.
-                        cur = tokens
-                        drafts = []
-                        # Penalty-aware proposals: the verifier scores
-                        # sub-step j with the carried penalty state plus
-                        # the tokens accepted at sub-steps < j, so the
-                        # drafter replays the SAME transform on a local
-                        # copy along its chain — otherwise every token
-                        # where penalties flip the target argmax is a
-                        # guaranteed rejection.  Acceptance stays a pure
-                        # function of weights + carried state.
-                        if use_penalties:
-                            dcounts, dseen = counts, seen
-                        if use_min_floor:
-                            dmin = min_left
-                        drows = jnp.arange(tokens.shape[0])
-                        for d in range(D + 1):
-                            dslot = draft_pos + d
-                            dblk = jnp.take_along_axis(
-                                draft_tables,
-                                jnp.clip(dslot // bs, 0, dbmax - 1)[:, None],
-                                axis=1,
-                            )[:, 0]
-                            dlogits, draft_kv = draft_decode(
-                                draft_params,
-                                tokens=cur,
-                                positions=positions + d,
-                                block_tables=draft_tables,
-                                ctx_lens=jnp.where(active, dslot + 1, 0),
-                                slot_block_ids=jnp.where(active, dblk, 0),
-                                slot_offsets=dslot % bs,
-                                kv_caches=draft_kv,
-                            )
-                            if d < D:
-                                if use_penalties:
-                                    dlogits = (
-                                        sampling_lib.apply_penalties_state(
-                                            dlogits, dcounts, dseen,
-                                            presence, frequency, repetition,
-                                        )
-                                    )
-                                if use_min_floor:
-                                    dlogits = dlogits + (
-                                        jnp.logical_and(
-                                            stop_mask, (dmin > 0)[:, None]
-                                        ).astype(jnp.float32) * -1e9
-                                    )
-                                cur = jnp.argmax(
-                                    dlogits, axis=-1
-                                ).astype(jnp.int32)
-                                drafts.append(cur)
-                                if use_penalties:
-                                    # Mirror the verifier's append gate:
-                                    # a proposed stop token is emitted
-                                    # but not counted, and the chain
-                                    # past it is dead anyway.
-                                    dstop = jnp.any(
-                                        jnp.logical_and(
-                                            cur[:, None] == stop_ids,
-                                            stop_valid,
-                                        ),
-                                        axis=1,
-                                    )
-                                    dapp = jnp.logical_and(active, ~dstop)
-                                    dcounts = dcounts.at[drows, cur].add(
-                                        dapp.astype(jnp.int16)
-                                    )
-                                    dseen = dseen.at[drows, cur].max(dapp)
-                                if use_min_floor:
-                                    dmin = jnp.maximum(
-                                        dmin - active.astype(jnp.int32), 0
-                                    )
-                        draft = jnp.stack(drafts, axis=1)  # [S, D]
-                        # Room for drafts: the bonus/correction token
-                        # always takes one budget slot, drafts fill the
-                        # rest (same budget gate as the n-gram source).
-                        room = jnp.maximum(max_steps - emitted_cnt - 1, 0)
-                        dvalid = jnp.logical_and(
-                            jnp.arange(D)[None, :] < room[:, None],
-                            active[:, None],
-                        )
-                    else:
-                        # -- on-device prompt-lookup draft --------------
-                        # Most recent earlier occurrence of the trailing
-                        # bigram within the carried [S, H] history (left
-                        # -1-padded, hist[:, -1] == the committed
-                        # token); the tokens that followed it are the
-                        # draft.  No bigram hit falls back to the most
-                        # recent UNIGRAM occurrence of the committed
-                        # token: the verify rows are computed either way
-                        # (static shapes), so a speculative proposal is
-                        # free and a rejected one costs nothing the
-                        # empty iteration didn't.
-                        key0 = hist[:, H - 2][:, None]
-                        key1 = hist[:, H - 1][:, None]
-                        starts = jnp.arange(H - 2)
-                        match2 = jnp.logical_and(
-                            jnp.logical_and(
-                                hist[:, : H - 2] == key0,
-                                hist[:, 1 : H - 1] == key1,
-                            ),
-                            hist[:, : H - 2] >= 0,
-                        )
-                        best2 = jnp.max(
-                            jnp.where(match2, starts[None, :], -1), axis=1
-                        )
-                        match1 = jnp.logical_and(
-                            hist[:, 1 : H - 1] == key1,
-                            hist[:, 1 : H - 1] >= 0,
-                        )
-                        best1 = jnp.max(
-                            jnp.where(match1, starts[None, :], -1), axis=1
-                        )
-                        best = jnp.where(best2 >= 0, best2, best1)
-                        dpos = best[:, None] + 2 + jnp.arange(D)[None, :]
-                        draft = jnp.take_along_axis(
-                            hist, jnp.clip(dpos, 0, H - 1), axis=1
-                        )
-                        # Room for drafts: the bonus/correction token
-                        # always takes one budget slot, drafts fill the
-                        # rest.
-                        room = jnp.maximum(max_steps - emitted_cnt - 1, 0)
-                        dvalid = (
-                            (best >= 0)[:, None]
-                            & (dpos < H)
-                            & (draft >= 0)
-                            & (jnp.arange(D)[None, :] < room[:, None])
-                            & active[:, None]
-                        )
-                    # Only a contiguous prefix is verifiable (already
-                    # contiguous for model proposals; shared so both
-                    # sources feed the identical verify machinery).
-                    dvalid = jnp.cumsum(
-                        jnp.where(dvalid, 0, 1), axis=1
-                    ) == 0
-                    draft = jnp.where(dvalid, draft, 0)
-                    nd = dvalid.sum(axis=1).astype(jnp.int32)
-
-                    # -- one wide verify forward ------------------------
-                    # Row j of sequence i consumes chain[j] at position
-                    # pos+j with ctx pos+j+1 — exactly the host
-                    # speculative layout, so the shared decode kernel's
-                    # write-then-attend order makes draft rows see their
-                    # predecessors' KV.  Dead rows park KV on null
-                    # block 0 (never corrupt a live slot).
-                    chain = jnp.concatenate([tokens[:, None], draft], axis=1)
-                    row_live = jnp.concatenate(
-                        [active[:, None], dvalid], axis=1
-                    )
-                    offs = jnp.arange(W)[None, :]
-                    wpos = positions[:, None] + offs
-                    wctx = ctx_lens[:, None] + offs
-                    blk = jnp.take_along_axis(
-                        block_tables,
-                        jnp.clip(wpos // bs, 0, bmax - 1),
-                        axis=1,
-                    )
-                    extra = (
-                        {"lora": lora, "adapter_idx": wide_adapter}
-                        if lora is not None else {}
-                    )
-                    logits, kv_caches = model_decode(
-                        params,
-                        tokens=chain.reshape(-1),
-                        positions=jnp.where(row_live, wpos, 0).reshape(-1),
-                        block_tables=jnp.repeat(block_tables, W, axis=0),
-                        ctx_lens=jnp.where(row_live, wctx, 0).reshape(-1),
-                        slot_block_ids=jnp.where(
-                            row_live, blk, 0
-                        ).reshape(-1),
-                        slot_offsets=(wpos % bs).reshape(-1),
-                        kv_caches=kv_caches,
-                        **extra,
-                    )
-                    # No dtype cast: the verify rows must see EXACTLY the
-                    # logits the single-row path would (lm_head already
-                    # emits fp32), or greedy parity could drift.
-                    logits = logits.reshape(tokens.shape[0], W, vocab)
-
-                    # -- sequential verify: penalties / min-floor / stop
-                    # applied to every accepted token in order, through
-                    # the SAME apply_penalties_state call site the
-                    # single-step path uses (the PR-8 one-call-site
-                    # rule), so streams are byte-identical.
-                    rows = jnp.arange(tokens.shape[0])
-                    alive = active
-                    last_tok = tokens
-                    adv = jnp.zeros_like(positions)
-                    acc_cnt = jnp.zeros_like(positions)
-                    new_done = done
-                    emits = []
-                    for j in range(W):
-                        lj = logits[:, j, :]
-                        if use_penalties:
-                            lj = sampling_lib.apply_penalties_state(
-                                lj, counts, seen,
-                                presence, frequency, repetition,
-                            )
-                        if use_min_floor:
-                            bias = (
-                                jnp.logical_and(
-                                    stop_mask, (min_left > 0)[:, None]
-                                ).astype(jnp.float32) * -1e9
-                            )
-                            lj = lj + bias
-                        tok_j = jnp.argmax(lj, axis=-1).astype(jnp.int32)
-                        stop_hit = jnp.logical_and(
-                            alive,
-                            jnp.any(
-                                jnp.logical_and(
-                                    tok_j[:, None] == stop_ids, stop_valid
-                                ),
-                                axis=1,
-                            ),
-                        )
-                        emits.append(jnp.where(alive, tok_j, -1))
-                        appended = jnp.logical_and(alive, ~stop_hit)
-                        if use_penalties:
-                            counts = counts.at[rows, tok_j].add(
-                                appended.astype(jnp.int16)
-                            )
-                            seen = seen.at[rows, tok_j].max(appended)
-                        step = alive.astype(jnp.int32)
-                        adv = adv + step
-                        min_left = jnp.maximum(min_left - step, 0)
-                        last_tok = jnp.where(alive, tok_j, last_tok)
-                        new_done = jnp.logical_or(new_done, stop_hit)
-                        if j < W - 1:
-                            agree = jnp.logical_and(
-                                dvalid[:, j], tok_j == draft[:, j]
-                            )
-                            acc = jnp.logical_and(appended, agree)
-                            acc_cnt = acc_cnt + acc.astype(jnp.int32)
-                            alive = acc
-                    emitted = jnp.stack(emits, axis=0)  # [W, S]
-
-                    # -- fold acceptance into the carried state ---------
-                    # (history shifts by the emitted count so the next
-                    # iteration's bigram lookup sees the new tokens).
-                    cat = jnp.concatenate(
-                        [hist, jnp.maximum(emitted.T, 0)], axis=1
-                    )
-                    hidx = jnp.arange(H)[None, :] + adv[:, None]
-                    hist = jnp.take_along_axis(cat, hidx, axis=1)
-                    core = (
-                        jnp.where(active, last_tok, tokens),
-                        positions + adv,
-                        ctx_lens + adv,
-                        new_done,
-                        min_left,
-                        emitted_cnt + adv,
-                        counts, seen, hist,
-                    )
-                    if drafter == "model":
-                        # Commit the draft-cache cursor: adv = accepted
-                        # + 1 slots now hold exactly the tokens up to
-                        # (excluding) the new committed token.
-                        return core + (
-                            draft_pos + adv, kv_caches, draft_kv,
-                        ), (emitted, nd, acc_cnt)
-                    return core + (kv_caches,), (emitted, nd, acc_cnt)
-
-                init = (tokens, positions, ctx_lens, done, min_left,
-                        jnp.zeros_like(positions), counts, seen, hist)
-                if drafter == "model":
-                    init = init + (draft_pos, kv_caches, draft_kv)
-                else:
-                    init = init + (kv_caches,)
-                carry, ys = jax.lax.scan(body, init, jnp.arange(n_steps))
-                if drafter == "model":
-                    (tokens, positions, ctx_lens, done, min_left, _cnt,
-                     counts, seen, hist, draft_pos, kv_caches,
-                     draft_kv) = carry
-                else:
-                    (tokens, positions, ctx_lens, done, min_left, _cnt,
-                     counts, seen, hist, kv_caches) = carry
-                emitted, drafted, accepted = ys  # [K, W, S], [K, S], [K, S]
-                state = {
-                    "tokens": tokens, "positions": positions,
-                    "ctx_lens": ctx_lens, "done": done,
-                    "min_left": min_left, "counts": counts, "seen": seen,
-                    "hist": hist,
-                }
-                if drafter == "model":
-                    state["draft_pos"] = draft_pos
-                    return (
-                        emitted, drafted, accepted, state, kv_caches,
-                        draft_kv,
-                    )
-                return emitted, drafted, accepted, state, kv_caches
-
-            self._spec_window_fn = self._jit(
-                "spec_window_fn",
-                spec_window,
-                static_argnames=(
-                    "use_penalties", "use_min_floor", "do_prime",
-                ),
-                donate_argnames=(
-                    ("kv_caches", "draft_kv") if drafter == "model"
-                    else ("kv_caches",)
-                ),
+            # Chained-window block-table growth: up to C new blocks a row.
+            self._win_advance_fn = self._jit(
+                "win_advance_fn", step_programs.table_scatter
             )
-
-        if self._window_steps > 1:
-
-            def win_advance(tables, cols, vals):
-                """Chained-window block-table growth: scatter up to C new
-                blocks per row into the device-resident table (col -1 =
-                no growth), mirroring _pipe_advance's single-column
-                form."""
-                rows = jnp.arange(tables.shape[0])[:, None]
-                valid = cols >= 0
-                safe = jnp.where(valid, cols, 0)
-                keep = tables[rows, safe]
-                return tables.at[rows, safe].set(
-                    jnp.where(valid, vals, keep)
-                )
-
-            self._win_advance_fn = self._jit("win_advance_fn", win_advance)
             self._win_occurrence_fn = self._jit(
                 "win_occurrence_fn",
-                partial(sampling_lib.occurrence_state, vocab_size=vocab),
-            )
-
-        # MIXED K-step windows (the sustained-arrival fusion): a waiting
-        # prompt's prefill chunks ride the device-resident decode scan —
-        # each scan iteration runs the packed [S_dec + chunk] mixed
-        # forward (llama.mixed_step, the SAME executable shape the K=1
-        # mixed path compiles), decode rows advancing one token from the
-        # carried state exactly like multi_window while the chunk cursor
-        # (cached_len, valid_len, new-block row) advances through the
-        # precomputed per-iteration schedule carried as scan xs.  The
-        # chunk's accumulated-prefix block table is ONE static [P] array
-        # whose validity the in-graph cursor masks (a block written by
-        # iteration t is attended by iteration t+1 with no host trip).
-        # The final chunk's tail-row logits are captured into the carry
-        # and sampled ON THE HOST at collect through the identical
-        # _finalize_final_prefill path K=1 mixed stepping uses — first
-        # tokens are bit-identical by construction.  The drafter never
-        # engages here (drafting is a pure-decode-window feature);
-        # penalties / min_tokens / stop masks run in-scan as in
-        # multi_window.  Scan length is a static arg bucketed to powers
-        # of two by the dispatcher, so the inventory stays
-        # |chunk buckets| x |decode buckets| x O(log K).
-        self._mixed_window_fn = None
-        if (
-            self._window_steps > 1
-            and self._mixed_fn is not None
-            and config.scheduler.mixed_window_enabled
-        ):
-            model_mixed = partial(self.model.mixed_step, cfg=cfg, mesh=self.mesh)
-            bs = config.cache.block_size
-            vocab = cfg.vocab_size
-
-            def mixed_window(
-                params, tokens, positions, ctx_lens, done, min_left,
-                block_tables, max_steps, kv_caches,
-                temps, top_ps, top_ks, min_ps, seq_seeds,
-                stop_ids, key_base, counts, seen,
-                presence, frequency, repetition,
-                pf_tokens, pf_cached, pf_valid, pf_new_blocks,
-                pf_prefix_ids, pf_adapter,
-                n_steps, use_penalties, use_min_floor,
-                hist=None, lora=None, adapter_idx=None,
-            ):
-                stop_valid = stop_ids >= 0
-                stop_mask = None
-                if use_min_floor:
-                    stop_mask = jax.vmap(
-                        lambda ids, v: jnp.zeros(
-                            (vocab,), jnp.bool_
-                        ).at[jnp.where(v, ids, 0)].max(v)
-                    )(stop_ids, stop_valid)
-                S = tokens.shape[0]
-                T = pf_tokens.shape[1]
-
-                def body(carry, xs):
-                    (tokens, positions, ctx_lens, done, min_left,
-                     counts, seen, hist_c, kv_caches) = carry
-                    # Packed windows: each iteration carries its OWN
-                    # prompt cursor — tokens, block table, and adapter
-                    # slot ride the scan xs, so chunks from several
-                    # prompts share one static [S + T] shape.
-                    t, pft, pfc, pfv, pfnb, pfpid, pfad = xs
-                    active = jnp.logical_and(~done, t < max_steps)
-                    blk = jnp.take_along_axis(
-                        block_tables, (positions // bs)[:, None], axis=1
-                    )[:, 0]
-                    extra = {}
-                    if lora is not None:
-                        # Mixed row layout: [S decode rows + T chunk
-                        # rows sharing ONE adapter] — the _run_mixed
-                        # layout, per iteration.
-                        extra = {
-                            "lora": lora,
-                            "adapter_idx": jnp.concatenate(
-                                [adapter_idx,
-                                 jnp.full((T,), pfad, jnp.int32)]
-                            ),
-                        }
-                    logits, kv_caches = model_mixed(
-                        params,
-                        dec_tokens=tokens,
-                        dec_positions=positions,
-                        dec_block_tables=block_tables,
-                        dec_ctx_lens=ctx_lens,
-                        # Frozen/done rows park their KV write on null
-                        # block 0 — same contract as multi_window.
-                        dec_slot_block_ids=jnp.where(active, blk, 0),
-                        dec_slot_offsets=positions % bs,
-                        pf_tokens=pft,
-                        pf_cached_len=pfc,
-                        pf_prefix_block_ids=pfpid,
-                        pf_new_block_ids=pfnb,
-                        pf_valid_len=pfv,
-                        kv_caches=kv_caches,
-                        **extra,
-                    )
-                    # logits[-1] is the chunk's tail row (last VALID
-                    # token); every iteration's tail rides out as a
-                    # scan output so EACH packed prompt's final chunk
-                    # can be finalized at collect.
-                    tail = logits[-1]
-                    dlogits = logits[:S]
-                    if use_penalties:
-                        dlogits = sampling_lib.apply_penalties_state(
-                            dlogits, counts, seen,
-                            presence, frequency, repetition,
-                        )
-                    if use_min_floor:
-                        bias = (
-                            jnp.logical_and(
-                                stop_mask, (min_left > 0)[:, None]
-                            ).astype(jnp.float32) * -1e9
-                        )
-                        dlogits = dlogits + bias
-                    # Key schedule: iteration t of a window dispatched
-                    # at counter c uses PRNGKey(seed + c + t) — the
-                    # ordinal the K=1 mixed step at counter c+t burns.
-                    sampled = sample_tokens(
-                        dlogits, temps, top_ps, top_ks,
-                        jax.random.PRNGKey(key_base + t), seq_seeds,
-                        min_p=min_ps,
-                    )
-                    stop_hit = jnp.logical_and(
-                        active,
-                        jnp.any(
-                            jnp.logical_and(
-                                sampled[:, None] == stop_ids, stop_valid
-                            ),
-                            axis=1,
-                        ),
-                    )
-                    emitted = jnp.where(active, sampled, -1)
-                    appended = jnp.logical_and(active, ~stop_hit)
-                    if use_penalties:
-                        rows = jnp.arange(counts.shape[0])
-                        counts = counts.at[rows, sampled].add(
-                            appended.astype(jnp.int16)
-                        )
-                        seen = seen.at[rows, sampled].max(appended)
-                    if hist_c is not None:
-                        # Keep the speculative drafter's carried history
-                        # warm across mixed windows (one committed token
-                        # per active row per iteration) so a chained
-                        # pure-decode window drafts from fresh context.
-                        H = hist_c.shape[1]
-                        cat = jnp.concatenate(
-                            [hist_c, jnp.maximum(emitted, 0)[:, None]],
-                            axis=1,
-                        )
-                        hidx = (
-                            jnp.arange(H)[None, :]
-                            + active.astype(jnp.int32)[:, None]
-                        )
-                        hist_c = jnp.take_along_axis(cat, hidx, axis=1)
-                    step = active.astype(jnp.int32)
-                    return (
-                        jnp.where(active, sampled, tokens),
-                        positions + step,
-                        ctx_lens + step,
-                        jnp.logical_or(done, stop_hit),
-                        jnp.maximum(min_left - step, 0),
-                        counts, seen, hist_c, kv_caches,
-                    ), (emitted, tail)
-
-                init = (
-                    tokens, positions, ctx_lens, done, min_left,
-                    counts, seen, hist, kv_caches,
-                )
-                xs = (
-                    jnp.arange(n_steps), pf_tokens, pf_cached, pf_valid,
-                    pf_new_blocks, pf_prefix_ids, pf_adapter,
-                )
-                carry, (emitted, tails) = jax.lax.scan(body, init, xs)
-                (tokens, positions, ctx_lens, done, min_left,
-                 counts, seen, hist, kv_caches) = carry
-                state = {
-                    "tokens": tokens, "positions": positions,
-                    "ctx_lens": ctx_lens, "done": done,
-                    "min_left": min_left, "counts": counts, "seen": seen,
-                }
-                if hist is not None:
-                    state["hist"] = hist
-                return emitted, tails, state, kv_caches
-
-            self._mixed_window_fn = self._jit(
-                "mixed_window_fn",
-                mixed_window,
-                static_argnames=(
-                    "n_steps", "use_penalties", "use_min_floor",
+                partial(
+                    sampling_lib.occurrence_state, vocab_size=cfg.vocab_size
                 ),
-                donate_argnames=("kv_caches",),
             )
+            if (
+                self._mixed_fn is not None
+                and config.scheduler.mixed_window_enabled
+            ):
+                self._mixed_window_fn = self._jit(
+                    "mixed_window_fn",
+                    step_programs.mixed_window_program(
+                        partial(
+                            self.model.mixed_step, cfg=cfg, mesh=self.mesh
+                        ),
+                        **dims,
+                    ),
+                    static_argnames=(
+                        "n_steps", "use_penalties", "use_min_floor",
+                    ),
+                    donate_argnames=("kv_caches",),
+                )
         self._penalties_fn = self._jit(
             "penalties_fn", sampling_lib.apply_penalties
         )
@@ -1449,61 +711,13 @@ class LLMEngine:
         self._gap_steps = 0
         self._last_decode_end: Optional[float] = None
 
-        bs_const = config.cache.block_size
-
-        def _pipe_unpack(packed, tables):
-            """Batch-(re)build path: ONE packed [11, S] int32 transfer
-            carries every per-row scalar (float rows bitcast); the block
-            tables ride in a second transfer only when the batch
-            composition changed."""
-            def as_f32(row):
-                return jax.lax.bitcast_convert_type(row, jnp.float32)
-
-            return {
-                "tokens": packed[0],
-                "positions": packed[1],
-                "ctx_lens": packed[2],
-                "slot_blocks": packed[3],
-                "slot_offsets": packed[4],
-                "temps": as_f32(packed[5]),
-                "top_ps": as_f32(packed[6]),
-                "top_ks": packed[7],
-                "min_ps": as_f32(packed[8]),
-                "seeds": packed[9],
-                "adapter": packed[10],
-                "tables": tables,
-            }
-
-        def _pipe_advance(packed, prev_sampled, tables):
-            """Steady path ("same batch, +1 token"): tokens chain from the
-            in-flight sample; the packed [4, S] int32 delta carries
-            (positions, ctx_lens, upd_col, upd_val) and block-table growth
-            is a jitted in-place scatter of at most one new block per row
-            (col -1 = no growth)."""
-            positions, ctx_lens = packed[0], packed[1]
-            cols, vals = packed[2], packed[3]
-            rows = jnp.arange(tables.shape[0])
-            valid = cols >= 0
-            safe_col = jnp.where(valid, cols, 0)
-            keep = tables[rows, safe_col]
-            tables = tables.at[rows, safe_col].set(
-                jnp.where(valid, vals, keep)
-            )
-            blk = jnp.take_along_axis(
-                tables, (positions // bs_const)[:, None], axis=1
-            )[:, 0]
-            active = ctx_lens > 0
-            return {
-                "tokens": prev_sampled,
-                "positions": positions,
-                "ctx_lens": ctx_lens,
-                "slot_blocks": jnp.where(active, blk, 0),
-                "slot_offsets": positions % bs_const,
-                "tables": tables,
-            }
-
-        self._pipe_unpack_fn = self._jit("pipe_unpack_fn", _pipe_unpack)
-        self._pipe_advance_fn = self._jit("pipe_advance_fn", _pipe_advance)
+        self._pipe_unpack_fn = self._jit(
+            "pipe_unpack_fn", step_programs.pipe_unpack
+        )
+        self._pipe_advance_fn = self._jit(
+            "pipe_advance_fn",
+            step_programs.pipe_advance(config.cache.block_size),
+        )
 
     def _jit(self, name: str, fn, **jit_kwargs):
         """The ONE place a step function gets its name: jitted under
