@@ -1136,7 +1136,7 @@ class LLMEngine:
             # The synchronous paths open their record before the work, so
             # that its spans, programs and launch stamps land on it.
             rec = self._open_record(
-                "prefill", chunks=(cp,), fallback=plan.window_fallback,
+                "prefill", chunks=(cp,), fallback=plan.window_fallback, cover=cp.cover,
             )
             self._stamp_record(rec, t0, gap=False)
             outputs = self._run_prefill(cp, rec)

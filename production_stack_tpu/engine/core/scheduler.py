@@ -21,9 +21,11 @@ LMCache CPU offload, deployment-vllm-multi.yaml:161-166).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import logging
+import math
 from collections import deque
-from typing import Deque, List, Optional
+from typing import Deque, List, Optional, Tuple
 
 from production_stack_tpu.engine.config import SchedulerConfig
 from production_stack_tpu.engine.core.sequence import (
@@ -34,6 +36,43 @@ from production_stack_tpu.engine.core.sequence import (
 from production_stack_tpu.engine.kv.block_pool import BlockPool
 
 logger = logging.getLogger(__name__)
+
+# What one prefill dispatch costs besides its slots, in slots.  Measured on
+# a v5e under int8 mistral-7b (PERF.md section 6, PR 32): a ``prefill_fn``
+# program takes 0.086 ms a slot plus 5.2 ms that do not scale with its slots
+# (the gather of the prefix positions), a later chunk of a run 0.85 ms more
+# for each 256 tokens written before it, and the step thread spends 4-5 ms
+# building and launching each dispatch: ~11 ms, 128 slots.  Inside a run the
+# host's part hides behind the device, so six 256-slot chunks (177 ms) beat
+# the 2,048-slot program (184 ms) by a hair where the constant says they
+# lose; at five chunks and at seven (146 against 182 ms, 210 against 187)
+# the constant and the device agree.
+PREFILL_DISPATCH_SLOTS = 128
+
+
+@functools.lru_cache(maxsize=4096)
+def cover_prefill(num_new: int, buckets: Tuple[int, ...]) -> Tuple[int, ...]:
+    """The cheapest run of prefill programs over ``num_new`` prompt tokens:
+    every chunk but the last fills its bucket, the last is padded.  A run
+    costs the sum over its chunks of (bucket + PREFILL_DISPATCH_SLOTS);
+    ties go to the fewer dispatches, then to the larger bucket first, so
+    the cover of what a chunk leaves is the rest of the same run.  A pure
+    function of its arguments: lockstep replicas plan alike."""
+    step = math.gcd(*buckets)
+    # best[r] = (cost, dispatches, -first bucket) of the cheapest run over r
+    # tokens, for every r that a run over num_new can leave.
+    best = {}
+    for r in range(num_new % step or step, num_new + 1, step):
+        options = []
+        for b in buckets:
+            cost, n = (0, 0) if b >= r else best[r - b][:2]
+            options.append((cost + b + PREFILL_DISPATCH_SLOTS, n + 1, -b))
+        best[r] = min(options)
+    run, r = [], num_new
+    while r > 0:
+        run.append(-best[r][2])
+        r -= run[-1]
+    return tuple(run)
 
 
 @dataclasses.dataclass
@@ -47,6 +86,9 @@ class PrefillPlan:
     # False for a non-final chunk of a long prompt (chunked prefill): the
     # engine writes KV but must not sample — the logits are mid-prompt.
     is_final: bool = True
+    # Dedicated prefill: the buckets of this chunk and of those still to
+    # come for the prompt (``cover_prefill``); empty for a mixed-step chunk.
+    cover: Tuple[int, ...] = ()
 
 
 @dataclasses.dataclass
@@ -238,12 +280,6 @@ class Scheduler:
         return len(self.running)
 
     # -- planning ----------------------------------------------------------
-
-    def _bucket_for(self, n_tokens: int) -> Optional[int]:
-        for bucket in self.config.prefill_buckets:
-            if n_tokens <= bucket:
-                return bucket
-        return None
 
     def _window_for_pass(self) -> int:
         """Window-selection rule: K > 1 pure-decode windows only when no
@@ -751,6 +787,7 @@ class Scheduler:
                     seq, prefix_blocks, cached_len
                 )
         num_new = seq.num_prompt_tokens - cached_len
+        cover: Tuple[int, ...] = ()
         if chunk_budget is not None:
             # Mixed-step chunk: pad to the chunk-bucket set so the fused
             # executable inventory stays |chunk_buckets| x |decode buckets|.
@@ -760,14 +797,13 @@ class Scheduler:
             if not is_final:
                 num_new = bucket
         else:
-            bucket = self._bucket_for(num_new)
-            is_final = bucket is not None
-            if bucket is None:
-                # Prompt longer than the largest bucket: chunked prefill —
-                # run one full-bucket chunk now, keep the sequence at the
-                # queue head, and continue next step from the accumulated
-                # prefix.
-                bucket = self.config.prefill_buckets[-1]
+            # Chunked prefill: run the first chunk of the cheapest cover
+            # now; unless it is the last, keep the sequence at the queue
+            # head and continue next step from the accumulated prefix.
+            cover = cover_prefill(num_new, tuple(self.config.prefill_buckets))
+            bucket = cover[0]
+            is_final = len(cover) == 1
+            if not is_final:
                 num_new = bucket
         bs = self.block_pool.block_size
         blocks_needed = (num_new + bs - 1) // bs
@@ -795,6 +831,7 @@ class Scheduler:
             num_new_tokens=num_new,
             cached_len=cached_len,
             is_final=is_final,
+            cover=cover,
         )
 
     def _window_token_cap(self, window: int) -> int:

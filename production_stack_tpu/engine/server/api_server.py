@@ -2148,8 +2148,8 @@ def main(argv=None) -> None:
     parser.add_argument(
         "--prefill-buckets",
         default=None,
-        help="comma-separated prefill bucket lengths (prompts beyond the "
-        "largest bucket run as chunked prefill)",
+        help="comma-separated token slots of the prefill programs (a prompt "
+        "runs as the cheapest run of them: full chunks, then a padded one)",
     )
     parser.add_argument(
         "--speculative-ngram",

@@ -99,13 +99,17 @@ class WindowRecord:
     # prompts already in the KV cache and skipped.  ``kv_tiles_live`` /
     # ``kv_tiles_grid``: kv tiles, per layer, the flash prefill kernel
     # computes / its grid holds for those chunks (its liveness rule,
-    # evaluated on the host; the rest is skipped).
+    # evaluated on the host; the rest is skipped).  ``cover``: a dedicated
+    # prefill's bucket and those of the chunks its prompt still has to run
+    # (scheduler.cover_prefill): [256, 256, 256], [256, 256], [256] are
+    # one 600-token prompt.
     kv_tokens: int = 0
     new_tokens: int = 0
     bucket_tokens: int = 0
     cached_tokens: int = 0
     kv_tiles_live: int = 0
     kv_tiles_grid: int = 0
+    cover: Tuple[int, ...] = ()
 
     @property
     def launch_ns(self) -> Optional[int]:
@@ -143,6 +147,8 @@ class WindowRecord:
             d["cached_tokens"] = self.cached_tokens
             d["kv_tiles_live"] = self.kv_tiles_live
             d["kv_tiles_grid"] = self.kv_tiles_grid
+        if self.cover:
+            d["cover"] = list(self.cover)
         if self.spec_width:
             d["spec_width"] = self.spec_width
             d["drafter"] = self.drafter
@@ -204,6 +210,7 @@ class FlightRecorder:
         cached_tokens: int = 0,
         kv_tiles_live: int = 0,
         kv_tiles_grid: int = 0,
+        cover: Tuple[int, ...] = (),
         now: Optional[float] = None,
     ) -> Optional[WindowRecord]:
         """Stamp a new record at dispatch.  Returns None when disabled so
@@ -234,6 +241,7 @@ class FlightRecorder:
             cached_tokens=int(cached_tokens),
             kv_tiles_live=int(kv_tiles_live),
             kv_tiles_grid=int(kv_tiles_grid),
+            cover=tuple(cover),
             dispatched_at=now if now is not None else time.time(),
         )
 

@@ -4,6 +4,8 @@ split across multiple full-bucket steps instead of silently truncated
 attended to zero-filled KV for the tail — scheduler.py history).
 """
 
+import pytest
+
 from production_stack_tpu.engine.config import (
     CacheConfig,
     EngineConfig,
@@ -111,3 +113,109 @@ def test_chunked_prefill_interleaves_with_decode():
         outputs.setdefault(seq_id, []).extend(toks)
     assert len(outputs["short"]) == 20
     assert len(outputs["long"]) == 4
+
+
+# -- covers: a prompt under the largest bucket in several smaller programs --
+
+# 600 byte-tokens: three 256-slot programs under (256, 2048), one program
+# under (1024,).
+COVERED_PROMPT = " ".join(f"word{i}" for i in range(88))[:600]
+
+
+def make_cover_engine(buckets, **model):
+    return LLMEngine(EngineConfig(
+        model=ModelConfig(**model),
+        cache=CacheConfig(block_size=16, num_blocks=128),
+        scheduler=SchedulerConfig(
+            max_num_seqs=2, prefill_buckets=buckets, max_model_len=1024,
+        ),
+    ))
+
+
+def prefill_records(engine):
+    windows = engine.obs.windows_payload()["windows"]
+    return sorted(
+        (w for w in windows if w["kind"] == "prefill"),
+        key=lambda w: w["window_id"],
+    )
+
+
+@pytest.mark.parametrize("prompt_tokens", [600, 256, 257])
+def test_covered_prompt_matches_single_bucket_prefill(prompt_tokens):
+    """Greedy streams are equal whether a prompt runs as a cover of small
+    programs or in one large one (257 tokens: the second chunk holds one)."""
+    prompt = COVERED_PROMPT[: prompt_tokens - 1]  # + BOS
+    streams, covers = [], []
+    for buckets in ((256, 2048), (1024,)):
+        engine = make_cover_engine(buckets)
+        assert len(engine.tokenizer.encode(prompt)) == prompt_tokens
+        engine.add_request(
+            "r", prompt=prompt, sampling_params=SamplingParams(max_tokens=8)
+        )
+        streams.append(drain(engine)["r"])
+        covers.append([w["cover"] for w in prefill_records(engine)])
+    assert streams[0] == streams[1]
+    n = -(-prompt_tokens // 256)
+    # The record's ``cover``: this dispatch's bucket, then those to come.
+    assert covers == [[[256] * k for k in range(n, 0, -1)], [[1024]]]
+
+
+def test_prefix_hit_then_cover_matches_a_cold_engine():
+    engine = make_cover_engine((256, 2048))
+    shared, tail = COVERED_PROMPT[:320], COVERED_PROMPT[320:599]
+    engine.add_request(
+        "a", prompt=shared, sampling_params=SamplingParams(max_tokens=2)
+    )
+    drain(engine)
+    hit_before = engine.block_pool.hit_tokens
+    engine.add_request(
+        "b", prompt=shared + tail, sampling_params=SamplingParams(max_tokens=8)
+    )
+    warm = drain(engine)["b"]
+    assert engine.block_pool.hit_tokens - hit_before == 320
+    # 280 new tokens behind the 320 cached: two chunks, the hit their prefix.
+    recs = prefill_records(engine)[-2:]
+    assert [w["cover"] for w in recs] == [[256, 256], [256]]
+    assert [w["cached_tokens"] for w in recs] == [320, 576]
+    assert [w["new_tokens"] for w in recs] == [256, 24]
+    cold = make_cover_engine((1024,))
+    cold.add_request(
+        "b", prompt=shared + tail, sampling_params=SamplingParams(max_tokens=8)
+    )
+    assert drain(cold)["b"] == warm
+
+
+def test_abort_between_two_chunks_of_a_cover_frees_its_blocks():
+    engine = make_cover_engine((256, 2048))
+    free = engine.block_pool.num_free_blocks
+    engine.add_request(
+        "r", prompt=COVERED_PROMPT, sampling_params=SamplingParams(max_tokens=8)
+    )
+    assert engine.step() == []  # first chunk: KV written, nothing sampled
+    assert engine.block_pool.num_free_blocks == free - 16
+    engine.abort_request("r")
+    assert not engine.has_unfinished()
+    assert engine.block_pool.num_free_blocks == free
+
+
+def test_echo_logprobs_of_a_covered_prompt_match_the_single_bucket_ones():
+    """echo+logprobs prompts run the prompt-logprobs prefill chunk by chunk
+    from ``cached_len`` on: a cover changes no position's entry."""
+    plps = []
+    for buckets in ((256, 2048), (1024,)):
+        engine = make_cover_engine(buckets, dtype="float32")
+        engine.add_request("e", prompt=COVERED_PROMPT, sampling_params=SamplingParams(
+            max_tokens=2, echo=True, logprobs=True, top_logprobs=2,
+        ))
+        plp = None
+        while engine.has_unfinished():
+            for out in engine.step():
+                if out.prompt_logprobs is not None:
+                    plp = out.prompt_logprobs
+        plps.append(plp)
+        assert len(prefill_records(engine)) == (3 if len(buckets) > 1 else 1)
+    covered, single = plps
+    assert len(covered) == len(single) == 601 and covered[0] == (None, None)
+    for (lp, pairs), (ref_lp, ref_pairs) in zip(covered[1:], single[1:]):
+        assert abs(lp - ref_lp) < 1e-4
+        assert [t for t, _ in pairs] == [t for t, _ in ref_pairs]

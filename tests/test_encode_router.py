@@ -150,13 +150,23 @@ async def test_repeat_embeddings_served_from_cache_byte_identical():
             first_bytes = await first.read()
             assert state.encode_texts_total == 2
             await _encode_cache_stored(app)
+
+            async def cache_hits() -> float:
+                # The counter is the process's: another test file on this
+                # xdist worker may have counted hits before this one.
+                text = await (await client.get("/metrics")).text()
+                line = next(
+                    (ln for ln in text.splitlines() if ln.startswith(
+                        "tpu_router:semantic_cache_hits_total ")), "x 0")
+                return float(line.split()[1])
+
+            before = await cache_hits()
             second = await client.post("/v1/embeddings", json=body)
             assert second.status == 200
             assert second.headers.get("x-encode-cache") == "hit"
             assert await second.read() == first_bytes  # byte-identical
             assert state.encode_texts_total == 2  # ZERO extra engine work
-            metrics = await (await client.get("/metrics")).text()
-            assert "tpu_router:semantic_cache_hits_total 1.0" in metrics
+            assert await cache_hits() == before + 1.0
         finally:
             await client.close()
             await server.close()

@@ -1,7 +1,13 @@
 """Scheduler: admission, bucketing, block accounting, preemption."""
 
+import pytest
+
 from production_stack_tpu.engine.config import SchedulerConfig
-from production_stack_tpu.engine.core.scheduler import Scheduler
+from production_stack_tpu.engine.core.scheduler import (
+    PREFILL_DISPATCH_SLOTS,
+    Scheduler,
+    cover_prefill,
+)
 from production_stack_tpu.engine.core.sequence import SamplingParams, Sequence
 from production_stack_tpu.engine.kv.block_pool import BlockPool
 
@@ -289,3 +295,184 @@ def test_spec_budget_not_inflated_for_sampled_batches():
     plan = sched.schedule()
     assert plan.decode is not None
     assert plan.decode.steps == [4, 4]
+
+
+# -- the cover: which prefill programs a prompt's new tokens run ----------
+
+DEFAULT_BUCKETS = SchedulerConfig().prefill_buckets  # 128 ... 2048
+
+COVERS = [
+    # The benchmark's bucket set: whole 256-slot chunks while they cost
+    # less than the one 2,048-slot program, dispatches counted.
+    (1, (256, 2048), (256,)),
+    (256, (256, 2048), (256,)),
+    (257, (256, 2048), (256, 256)),
+    (600, (256, 2048), (256, 256, 256)),
+    (1024, (256, 2048), (256,) * 4),
+    (1280, (256, 2048), (256,) * 5),
+    # Six 256-slot dispatches would hold 1,500 tokens in fewer slots, and
+    # take longer: the warm-up's long prompt loads the big program.
+    (1281, (256, 2048), (2048,)),
+    (1500, (256, 2048), (2048,)),
+    (2048, (256, 2048), (2048,)),
+    # Above the largest bucket: the old chunked prefill is the same rule.
+    (2049, (256, 2048), (2048, 256)),
+    (2600, (256, 2048), (2048, 256, 256, 256)),
+    (5000, (256, 2048), (2048, 2048, 256, 256, 256, 256)),
+    # The default set is dense: a cover wins only by a small second chunk.
+    (100, DEFAULT_BUCKETS, (128,)),
+    (300, DEFAULT_BUCKETS, (512,)),
+    (512, DEFAULT_BUCKETS, (512,)),
+    (513, DEFAULT_BUCKETS, (512, 128)),
+    (600, DEFAULT_BUCKETS, (512, 128)),
+    (700, DEFAULT_BUCKETS, (512, 256)),
+    (800, DEFAULT_BUCKETS, (1024,)),
+    (1100, DEFAULT_BUCKETS, (1024, 128)),
+    (1400, DEFAULT_BUCKETS, (1024, 512)),
+    (1600, DEFAULT_BUCKETS, (1024, 512, 128)),
+    (1700, DEFAULT_BUCKETS, (2048,)),
+    (2100, DEFAULT_BUCKETS, (2048, 128)),
+    (4100, DEFAULT_BUCKETS, (2048, 2048, 128)),
+    # One bucket: nothing to choose.
+    (7, (64,), (64,)),
+    (64, (64,), (64,)),
+    (65, (64,), (64, 64)),
+    (200, (64,), (64, 64, 64, 64)),
+    # Small buckets (the tests' engines): a dispatch outweighs any padding.
+    (20, (16, 32, 64), (32,)),
+    (40, (16, 32, 64), (64,)),
+    (100, (16, 32), (32, 32, 32, 16)),
+]
+
+
+@pytest.mark.parametrize("num_new,buckets,run", COVERS)
+def test_cover_prefill_picks_the_cheapest_run(num_new, buckets, run):
+    assert cover_prefill(num_new, buckets) == run
+    # Every chunk but the last is full, the last holds the remainder in
+    # the smallest bucket that does, and no program is invented.
+    assert set(run) <= set(buckets)
+    rest = num_new - sum(run[:-1])
+    assert 0 < rest <= run[-1]
+    assert run[-1] == min(b for b in buckets if b >= rest)
+    # What a chunk leaves is covered by the rest of the same run: a plan
+    # made chunk by chunk is the plan made at the start.
+    if len(run) > 1:
+        assert cover_prefill(num_new - run[0], buckets) == run[1:]
+
+
+@pytest.mark.parametrize("buckets", [(256, 2048), DEFAULT_BUCKETS, (48, 112)])
+def test_cover_prefill_is_pure_and_never_beaten(buckets):
+    """Same inputs, same plan, whatever was asked before (lockstep replicas
+    plan alike); and no other run of full chunks and a last one is cheaper."""
+    def cost(run):
+        return sum(run) + PREFILL_DISPATCH_SLOTS * len(run)
+
+    def runs(n, depth):
+        for b in buckets:
+            if b >= n:
+                yield (b,)
+            elif depth > 1:
+                for rest in runs(n - b, depth - 1):
+                    yield (b,) + rest
+
+    lengths = list(range(1, 3 * max(buckets), 37))
+    first = [cover_prefill(n, buckets) for n in lengths]
+    cover_prefill.cache_clear()
+    again = [cover_prefill(n, buckets) for n in reversed(lengths)]
+    assert first == again[::-1]
+    for n, run in zip(lengths[:40], first):
+        best = min((cost(r), len(r)) for r in runs(n, len(run) + 1))
+        assert (cost(run), len(run)) == best, (n, run)
+
+
+def make_cover_scheduler(num_blocks=256, **kw):
+    pool = BlockPool(num_blocks=num_blocks, block_size=16)
+    cfg = SchedulerConfig(
+        max_num_seqs=4, prefill_buckets=(256, 2048), max_model_len=4096,
+        mixed_batch=False, **kw,
+    )
+    return Scheduler(cfg, pool), pool
+
+
+def test_schedule_runs_a_cover_chunk_by_chunk():
+    sched, pool = make_cover_scheduler()
+    s = seq("a", 600)
+    sched.add_seq(s)
+    plans = [sched.schedule().prefill_chunk for _ in range(3)]
+    assert [p.bucket_len for p in plans] == [256, 256, 256]
+    assert [p.num_new_tokens for p in plans] == [256, 256, 88]
+    assert [p.cached_len for p in plans] == [0, 256, 512]
+    assert [p.is_final for p in plans] == [False, False, True]
+    assert [p.cover for p in plans] == [(256,) * 3, (256,) * 2, (256,)]
+    # Each chunk reads the earlier ones as its cached prefix.
+    assert plans[2].prefix_block_ids == (
+        plans[0].new_block_ids + plans[1].new_block_ids
+    )
+    assert not s.partial_prefill and sched.running == [s]
+    assert len(s.block_table) == -(-600 // 16)
+
+
+def test_a_prefix_hit_is_followed_by_a_cover_of_what_is_new():
+    sched, pool = make_cover_scheduler()
+    old = seq("old", 1024)
+    sched.add_seq(old)
+    while sched.waiting:
+        sched.schedule()
+    sched.finish_seq(old)
+    # Same first 1,024 tokens, 700 new ones: three chunks behind the hit.
+    new = seq("new", 1724)
+    sched.add_seq(new)
+    plans = [sched.schedule().prefill_chunk for _ in range(3)]
+    assert [p.cached_len for p in plans] == [1024, 1280, 1536]
+    assert [p.num_new_tokens for p in plans] == [256, 256, 188]
+    assert plans[0].cover == (256, 256, 256)
+    assert len(plans[0].prefix_block_ids) == 1024 // 16
+    assert plans[2].is_final and sched.running == [new]
+
+
+@pytest.mark.parametrize("how", ["abort", "rollback", "preemption"])
+def test_a_cover_cut_short_frees_every_block(how):
+    """A sequence between two chunks of its cover sits in the waiting queue
+    holding blocks: abort, a rollback under pool pressure and the
+    preemption of a decoder beside it all hand every block back."""
+    sched, pool = make_cover_scheduler(num_blocks=64)  # 63 usable
+    free = pool.num_free_blocks
+    if how == "preemption":
+        short = seq("short", 250, max_tokens=64)
+        sched.add_seq(short)
+        assert sched.schedule().prefill_chunk.is_final
+        short.output_token_ids.append(1)
+    long = seq("long", 700)
+    sched.add_seq(long)
+    first = sched.schedule().prefill_chunk
+    assert first.cover == (256, 256, 256) and long.partial_prefill
+    assert pool.num_free_blocks < free
+    if how == "abort":
+        assert sched.abort_seq("long") is long
+    elif how == "rollback":
+        held = pool.allocate(pool.num_free_blocks)  # someone fills the pool
+        # No second chunk fits and nothing decodes: the cover is rolled
+        # back and starts over in its own blocks, from the same plan.
+        again = sched.schedule().prefill_chunk
+        assert again.cover == first.cover and again.cached_len == 0
+        assert len(long.block_table) == 16
+        pool.free(held)
+        assert sched.abort_seq("long") is long
+    else:
+        # The decoder cannot grow and the cover cannot go on: the decoder
+        # is preempted, the cover rolled back, and the decoder comes back
+        # first, its 257 tokens a prompt under the same rule.
+        held = pool.allocate(pool.num_free_blocks)
+        short.output_token_ids.extend([1] * 6)  # a 17th block
+        plan = sched.schedule()
+        assert sched.num_preemptions == 1
+        assert long.block_table == [] and not long.partial_prefill
+        assert plan.prefill_chunk.seq is short
+        assert plan.prefill_chunk.cover == (256, 256)
+        pool.free(held)
+        while sched.num_waiting:
+            assert not sched.schedule().is_empty
+        for s in (short, long):
+            sched.finish_seq(s)
+    assert pool.num_free_blocks == free
+    assert not sched.has_unfinished()
