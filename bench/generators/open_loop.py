@@ -22,7 +22,7 @@ from typing import Dict, List
 import numpy as np
 
 import generators.text as text
-from generators.warm import bursts
+from generators.warm import until_stable
 
 
 def _lognormal(rng, spec: Dict, n: int) -> np.ndarray:
@@ -66,21 +66,17 @@ async def _offer(client, phase: str, plan: List[Dict], t0: float) -> None:
 
 
 async def warmup(client, traffic: Dict, cell: Dict, stable) -> None:
-    """Replay the mix at a ladder of rates (low rates make the small decode
-    batches, high ones the full batch) until a whole ladder compiled
-    nothing new."""
+    """The fixed warm-up requests, then the mix at a ladder of rates (low
+    rates make the small decode batches, high ones the full batch)."""
     w = traffic["warmup"]
-    await bursts(client, w["bursts"])
-    await stable.check()
-    cycle = 0
-    while True:
+
+    async def ladder(cycle: int) -> None:
         for k, factor in enumerate(w["rate_factors"]):
             plan = schedule(traffic, cell["rate_rps"] * factor,
                             w["seconds_each"], w["seed"] + 1000 * cycle + k)
             await _offer(client, "warmup", plan, time.monotonic())
-        cycle += 1
-        if await stable.check():
-            return
+
+    await until_stable(client, w["bursts"], stable, ladder)
 
 
 async def prepare(client, traffic: Dict, cell: Dict, seed: int):

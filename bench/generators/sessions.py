@@ -23,7 +23,7 @@ from typing import Dict, List
 import numpy as np
 
 import generators.text as text
-from generators.warm import bursts
+from generators.warm import until_stable
 
 
 class Session:
@@ -122,13 +122,11 @@ async def _user_loop(client, phase: str, pop: Dict, slot: int, traffic: Dict,
 
 
 async def warmup(client, traffic: Dict, cell: Dict, stable) -> None:
-    """Warm-up users of their own run the loop until a stretch compiled
-    nothing new."""
+    """The fixed warm-up requests, then warm-up users of their own run the
+    loop for a stretch."""
     w = traffic["warmup"]
-    await bursts(client, w["bursts"])
-    await stable.check()
-    cycle = 0
-    while True:
+
+    async def stretch(cycle: int) -> None:
         pop = population(traffic, w["seed"] + cycle, f"w{cycle}-")
         await _seed_cache(client, "warmup", pop, traffic)
         end = time.monotonic() + w["seconds_each"]
@@ -137,9 +135,8 @@ async def warmup(client, traffic: Dict, cell: Dict, stable) -> None:
                        lambda due: due >= end)
             for i in range(traffic["users"])
         ))
-        cycle += 1
-        if await stable.check():
-            return
+
+    await until_stable(client, w["bursts"], stable, stretch)
 
 
 async def prepare(client, traffic: Dict, cell: Dict, seed: int):

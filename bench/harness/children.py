@@ -108,6 +108,32 @@ class Child:
             time.sleep(0.25)
         raise self.fail(f"{url} not ready in {timeout_s:.0f}s ({last})")
 
+    def cpu_seconds(self) -> Dict:
+        """CPU time the child has used so far (user + system, from /proc):
+        the whole process and its busiest thread.  Set-up is mostly one
+        thread tracing and loading programs, so the same work at more CPU
+        seconds is a slower core, not more work; None where /proc has no
+        such file."""
+        tick = os.sysconf("SC_CLK_TCK")
+
+        def used(path: str) -> Optional[float]:
+            try:
+                with open(path) as f:
+                    fields = f.read().rsplit(")", 1)[1].split()
+                return (int(fields[11]) + int(fields[12])) / tick
+            except (OSError, IndexError, ValueError):
+                return None
+
+        pid = self.proc.pid
+        try:
+            threads = [used(f"/proc/{pid}/task/{tid}/stat")
+                       for tid in os.listdir(f"/proc/{pid}/task")]
+        except OSError:
+            threads = []
+        return {"process": used(f"/proc/{pid}/stat"),
+                "busiest_thread": max((t for t in threads if t is not None),
+                                      default=None)}
+
     def stop(self, grace_s: float = 60.0) -> Optional[int]:
         """SIGTERM (the servers' graceful drain), then SIGKILL past the
         grace; waits for the exit and returns its code."""

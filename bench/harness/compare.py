@@ -1,15 +1,21 @@
 """Part (ii) of ``correct``: the engine's model code against the plain
 reference, logits to logits, in this process after the servers have exited.
 
-At the configuration's published widths and ``compare.layers`` layers,
-seeded random weights in the configuration's own weight format and mesh:
-prefill of one prompt in two chunks (the second attends to a cached
-prefix), prefill of a second prompt, then decode steps of both through the
-paged cache -- the engine's ``llama.prefill`` / ``llama.decode`` with the
-kernels it serves with -- against ``reference/<module>.forward`` over the
-whole sequence.  Tokens are not compared: with random weights the largest
-logit turns on rounding.  The driving code is copied from ``chip_smoke.py``
-(PR 21), which compared kernels with the XLA path, not with a reference.
+Driven by the configuration's file.  At the widths the chip holds
+(``sizes.held``) and ``compare.layers`` layers, seeded random weights in the
+configuration's own weight format and mesh: prefill of the first prompt in
+chunks of 256 (every chunk after the first attends to a cached prefix),
+prefill of the second, then decode steps of both through the paged cache --
+``prefill`` / ``decode`` of the module the engine serves the preset with
+(``models.get_model``), with the kernels it serves with -- against
+``reference/<compare.reference>.forward`` over the whole sequence.  The
+file's ``compare`` block also gives ``prompt_tokens`` (default [300, 100];
+a configuration with a window asks for a prompt longer than it) and
+``preset_keys``, {key of the file: field of the preset's ``ModelConfig``},
+which have to agree before anything runs (default: the six llama keys).
+Tokens are not compared: with random weights the largest logit turns on
+rounding.  The driving code is copied from ``chip_smoke.py`` (PR 21), which
+compared kernels with the XLA path, not with a reference.
 """
 
 from __future__ import annotations
@@ -20,9 +26,20 @@ import os
 import sys
 from typing import Dict, List, Tuple
 
+from harness.sizes import held
+
+PRESET_KEYS = {
+    "hidden_size": "hidden_size", "num_attention_heads": "num_heads",
+    "num_key_value_heads": "num_kv_heads",
+    "intermediate_size": "intermediate_size", "vocab_size": "vocab_size",
+    "sliding_window": "sliding_window",
+}
+BLOCK, CHUNK = 16, 256   # tokens a KV block, slots a prefill chunk
+
 
 def run(config: Dict, chips: int, seed: int, platform: str,
-        env_root: str) -> Tuple[bool, List[str]]:
+        env_root: str) -> Tuple[bool, List[str], Dict[str, List[float]]]:
+    """(ok, one note a compared row, {row: [number, limit]})."""
     sys.path.insert(0, env_root)
     if platform == "cpu":
         for k, v in config.get("rehearsal_env", {}).items():
@@ -36,28 +53,26 @@ def run(config: Dict, chips: int, seed: int, platform: str,
     from jax.sharding import NamedSharding
 
     from production_stack_tpu.engine.config import PRESETS, ParallelConfig
-    from production_stack_tpu.engine.models import llama
+    from production_stack_tpu.engine.models import get_model
     from production_stack_tpu.engine.parallel import shardings as sh
     from production_stack_tpu.engine.parallel.mesh import build_mesh
 
     spec = config["compare"]
     notes = []
     if jax.default_backend() != platform or len(jax.devices()) < chips:
-        return False, [f"the parent's JAX sees {jax.devices()}"]
+        return False, [f"the parent's JAX sees {jax.devices()}"], {}
     cfg = dataclasses.replace(
         PRESETS[config["model"]], num_layers=spec["layers"],
         quantization=spec.get("quantization"))
+    model = get_model(cfg.name)
     reference = importlib.import_module("reference." + spec["reference"])
-    hp = dict(config["published"], head_dim=cfg.head_dim)
-    for ours, theirs in (("hidden_size", cfg.hidden_size),
-                         ("num_attention_heads", cfg.num_heads),
-                         ("num_key_value_heads", cfg.num_kv_heads),
-                         ("intermediate_size", cfg.intermediate_size),
-                         ("vocab_size", cfg.vocab_size),
-                         ("sliding_window", cfg.sliding_window)):
-        if hp.get(ours) != theirs:
-            return False, [f"preset {config['model']} has {ours}={theirs}, "
-                           f"the published config {hp.get(ours)}"]
+    hp = held(config)
+    hp.setdefault("head_dim", cfg.head_dim)
+    for ours, theirs in spec.get("preset_keys", PRESET_KEYS).items():
+        if hp.get(ours) != getattr(cfg, theirs):
+            return False, [f"preset {config['model']} has {theirs}="
+                           f"{getattr(cfg, theirs)}, the configuration's "
+                           f"file {ours}={hp.get(ours)}"], {}
 
     mesh = None
     shardings = kv_sharding = None
@@ -67,30 +82,36 @@ def run(config: Dict, chips: int, seed: int, platform: str,
         shardings = sh.param_shardings(cfg, mesh)
         kv_sharding = NamedSharding(mesh, sh.kv_cache_spec())
     # 2**31 - 1 keeps any driver seed inside what PRNGKey takes.
-    params = llama.init_params(
+    params = model.init_params(
         cfg, jax.random.PRNGKey(seed % (2**31 - 1)), shardings)
     if mesh is None:
-        params = llama.quantize_params(params, cfg)
+        params = model.quantize_params(params, cfg)
 
-    bs, num_blocks, bmax, T = 16, 96, 64, 256
+    len_a, len_b = spec.get("prompt_tokens", [300, 100])
+    steps = spec.get("decode_steps", 2)
+    # Blocks a sequence: a power of two that holds the longer one and its
+    # decode steps, 32 at the least; block 0 is the null block.
+    per = 32
+    while per * BLOCK < max(len_a, len_b) + steps:
+        per *= 2
+    bs, num_blocks, bmax, T = BLOCK, 3 * per, 2 * per, CHUNK
     kv_shape = (num_blocks, bs, cfg.num_kv_heads, cfg.head_dim)
     zeros = jax.jit(lambda: jnp.zeros(kv_shape, cfg.dtype),
                     out_shardings=kv_sharding)
     kv = [(zeros(), zeros()) for _ in range(cfg.num_layers)]
     rng = np.random.default_rng(seed)
-    prompt_a = rng.integers(1, cfg.vocab_size, 300).astype(np.int32)
-    prompt_b = rng.integers(1, cfg.vocab_size, 100).astype(np.int32)
-    steps = spec.get("decode_steps", 2)
+    prompt_a = rng.integers(1, cfg.vocab_size, len_a).astype(np.int32)
+    prompt_b = rng.integers(1, cfg.vocab_size, len_b).astype(np.int32)
     decode_tokens = rng.integers(1, cfg.vocab_size, (steps, 2)).astype(np.int32)
-    blocks_a = np.arange(1, 33, dtype=np.int32)  # block 0 is the null block
-    blocks_b = np.arange(40, 72, dtype=np.int32)
+    blocks_a = np.arange(1, 1 + per, dtype=np.int32)
+    blocks_b = np.arange(per + 8, 2 * per + 8, dtype=np.int32)
 
     prefill = jax.jit(
-        lambda p, t, c, pre, new, v, kv: llama.prefill(
+        lambda p, t, c, pre, new, v, kv: model.prefill(
             p, cfg, t, c, pre, new, v, kv, mesh=mesh),
         donate_argnums=(6,))
     decode = jax.jit(
-        lambda p, t, pos, bt, cl, sb, so, kv: llama.decode(
+        lambda p, t, pos, bt, cl, sb, so, kv: model.decode(
             p, cfg, t, pos, bt, cl, sb, so, kv, mesh=mesh),
         donate_argnums=(7,))
 
@@ -108,13 +129,14 @@ def run(config: Dict, chips: int, seed: int, platform: str,
                        jnp.int32(len(chunk)), kv)
 
     got = []
-    _, kv = run_prefill(prompt_a, 0, blocks_a, kv)    # chunk 1: no prefix
-    out, kv = run_prefill(prompt_a, T, blocks_a, kv)  # chunk 2: 256 cached
-    got.append(("prefill, 256 cached", np.asarray(out, np.float32)))
-    out, kv = run_prefill(prompt_b, 0, blocks_b, kv)
-    got.append(("prefill, no prefix", np.asarray(out, np.float32)))
+    for prompt, blocks in ((prompt_a, blocks_a), (prompt_b, blocks_b)):
+        for start in range(0, len(prompt), T):
+            out, kv = run_prefill(prompt, start, blocks, kv)
+        got.append((f"prefill of {len(prompt)} tokens, " + (
+            f"{start} cached" if start else "no prefix"),
+            np.asarray(out, np.float32)))
     tables = np.zeros((2, bmax), np.int32)
-    tables[0, :32], tables[1, :32] = blocks_a, blocks_b
+    tables[0, :per], tables[1, :per] = blocks_a, blocks_b
     ctx = np.array([len(prompt_a), len(prompt_b)], np.int32)
     for step in range(steps):
         ctx = ctx + 1
@@ -138,11 +160,13 @@ def run(config: Dict, chips: int, seed: int, platform: str,
         want.append(np.stack([ref_a[len(prompt_a) + step],
                               ref_b[len(prompt_b) + step]]))
 
-    ok = True
+    ok, rows = True, {}
     for (name, a), b in zip(got, want):
         err = float(np.max(np.abs(a - b)) / np.max(np.abs(b)))
         fine = bool(np.isfinite(a).all()) and err <= spec["logits_rtol"]
         ok &= fine
         notes.append(f"{name}: max|a-b|/max|b| = {err:.3e} "
                      f"{'<=' if fine else '>'} {spec['logits_rtol']}")
-    return ok, notes
+        rows[name.replace(",", "").replace(" ", "_")] = [
+            err, spec["logits_rtol"]]
+    return ok, notes, rows

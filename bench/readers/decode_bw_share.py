@@ -2,8 +2,12 @@
 a step must stream on each chip (from shapes, ``reduce/shapes.py``) over the
 published bytes/s, over the step's device time.  KV reads are left out, so
 this is a lower bound on the share of the roofline and cannot pass 100 %
-unless the step time leaves out part of the work."""
+unless the step time leaves out part of the work.  The bytes are those of a
+dense gated MLP, so the metric lists its cells in ``BENCHMARK.json``; a
+configuration of another kind brings a share with a bytes function of its
+own beside ``reduce/shapes.py``."""
 
+from harness.sizes import held
 from readers.trace_program import step_ms
 from reduce.shapes import streamed_weight_bytes
 
@@ -14,6 +18,6 @@ def read(ctx, args):
         return None
     spec = ctx.config["compare"]
     weight_bytes = streamed_weight_bytes(
-        ctx.config["published"], spec.get("quantization"), ctx.cell["chips"])
+        held(ctx.config), spec.get("quantization"), ctx.cell["chips"])
     least_ms = weight_bytes / (ctx.peaks()["hbm_gbs"] * 1e9) * 1e3
     return 100.0 * least_ms / ms

@@ -6,6 +6,7 @@ K and V only, counted for the very programs in the trace: what the
 algorithm needs, so the share cannot pass 100 % unless the join is
 wrong."""
 
+from harness.sizes import held
 from reduce import join
 from reduce.kv_bytes import decode_read_bytes, kv_shards
 
@@ -18,7 +19,7 @@ def read(ctx, args):
     seconds = sum(s for name, s, _n in ctx.trace["ops"] if name == marker)
     if not seconds:
         return None
-    hp = ctx.config["published"]
+    hp = held(ctx.config)
     records = ctx.got["windows"]["windows"]
     matched = dict(got["pairs"])
     shards = kv_shards(ctx.config)

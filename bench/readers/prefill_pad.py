@@ -1,7 +1,10 @@
 """Share of the prefilled token slots that held no prompt token: 1 - new
 tokens over bucket tokens, summed over the window's flight records that
-carried a prefill or a chunk, percent.  The scheduler pads every prompt to
-the smallest bucket that holds it.  A count, from the records alone."""
+carried a prefill or a chunk, percent.  The scheduler covers a prompt with
+the cheapest run of the prefill programs it has (``cover_prefill``, PR 32):
+full chunks and one padded chunk, each a record of its own, so the padding
+left is that of the last chunk of every run.  A count, from the records
+alone."""
 
 
 def read(ctx, args):

@@ -3,14 +3,17 @@ kernel, from the trace.  The step programs have no names of their own in
 today's trace, so a program counts as a decode step where the operations
 inside it include ``marker`` (the paged decode kernel) and none of
 ``without`` (the prefill kernel).  A K-step window calls the kernel K times a
-layer, so steps = kernel calls / layers, whatever K is.  A mean over the
+layer, so steps = kernel calls / layers, whatever K is: the layers the chip
+holds (``harness/sizes.py``), not the published depth.  A mean over the
 batch sizes the traced span happened to hold."""
+
+from harness.sizes import held
 
 
 def step_ms(ctx, args):
     if ctx.trace is None:
         return None
-    layers = ctx.config["published"]["num_hidden_layers"]
+    layers = held(ctx.config)["num_hidden_layers"]
     seconds = calls = 0.0
     for _program, _start, dur, inside in ctx.trace["modules"]:
         if inside.get(args["marker"]) and not any(

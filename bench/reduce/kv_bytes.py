@@ -21,7 +21,8 @@ def kv_shards(config: Dict) -> int:
 def kv_bytes_per_token(hp: Dict, shards: int = 1, dtype_bytes: int = 2) -> float:
     """K and V of one token position in every layer, on one chip: tensor
     parallelism splits the KV heads evenly over ``shards`` chips.  ``hp``
-    holds the published keys; bf16 cache unless told otherwise."""
+    holds the sizes the chip holds (``harness/sizes.py: held``); bf16 cache
+    unless told otherwise."""
     head_dim = hp.get("head_dim") or (
         hp["hidden_size"] // hp["num_attention_heads"])
     return (2 * hp["num_hidden_layers"] * hp["num_key_value_heads"] / shards

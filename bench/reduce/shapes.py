@@ -11,7 +11,7 @@ def streamed_weight_bytes(hp: Dict, quantization: Optional[str],
     """Bytes of weights one decode step has to read on each chip: every
     projection of every layer and the output head (the embedding is only
     gathered from, and the norms are a few kilobytes).  ``hp`` holds the
-    published keys.  int8: one byte a weight plus a float32 scale per
+    sizes the chip holds (``harness/sizes.py: held``).  int8: one byte a weight plus a float32 scale per
     output channel; otherwise bf16.  Tensor parallelism splits every
     projection evenly."""
     h, inter = hp["hidden_size"], hp["intermediate_size"]

@@ -8,6 +8,8 @@ sliding window, a SwiGLU feed-forward, a final RMSNorm and an untied output
 head.  ``jax.numpy`` in float32 under ``default_matmul_precision("highest")``
 (a TPU otherwise multiplies float32 in bf16 passes); no cache, no kernels,
 no batching: the whole sequence in one pass, logits for every position.
+Attention takes ``QUERY_ROWS`` query rows at a time against all keys, so that
+a prompt longer than the window fits: the arithmetic of a row is the same.
 
 Weights come in the engine's tree (``embed_tokens``, ``layers[i].q_proj``
 ..., ``norm``, ``lm_head``; projections stored [in, out]).  An int8
@@ -22,6 +24,9 @@ from typing import Dict
 
 import jax
 import jax.numpy as jnp
+
+
+QUERY_ROWS = 1024   # [heads, rows, T] float32 scores held at once
 
 
 def _weight(w) -> jax.Array:
@@ -69,9 +74,15 @@ def forward(params: Dict, hp: Dict, tokens: jax.Array) -> jax.Array:
             # Grouped queries: head i reads key/value head i // (H // K).
             k = jnp.repeat(k, H // K, axis=1)
             v = jnp.repeat(v, H // K, axis=1)
-            scores = jnp.einsum("qhd,khd->hqk", q, k) / jnp.sqrt(float(hd))
-            scores = jnp.where(mask[None], scores, -jnp.inf)
-            attn = jnp.einsum("hqk,khd->qhd", jax.nn.softmax(scores, -1), v)
+            attn = []
+            for lo in range(0, T, QUERY_ROWS):
+                rows = slice(lo, lo + QUERY_ROWS)
+                scores = jnp.einsum("qhd,khd->hqk", q[rows], k)
+                scores = jnp.where(mask[None, rows],
+                                   scores / jnp.sqrt(float(hd)), -jnp.inf)
+                attn.append(jnp.einsum(
+                    "hqk,khd->qhd", jax.nn.softmax(scores, -1), v))
+            attn = jnp.concatenate(attn)
             x = x + attn.reshape(T, H * hd) @ _weight(layer["o_proj"])
             h = _rms_norm(x, _weight(layer["post_attention_layernorm"]),
                           hp["rms_norm_eps"])

@@ -33,6 +33,7 @@ sys.path.insert(0, BENCH)
 from harness.children import Child, free_port  # noqa: E402
 from harness.client import Client  # noqa: E402
 from harness import layers, scrape  # noqa: E402
+from harness.hostmon import LoopLag  # noqa: E402
 from reduce import stats  # noqa: E402
 
 
@@ -79,34 +80,56 @@ def resolve(benchmark_path: str, workload: str):
     return bench, cell, config, traffic, cell_params, dirs
 
 
+def metric_names(bench: Dict, cell: str, traced: bool) -> list:
+    """What a run of ``cell`` reports: the per-layer metrics with ``--trace
+    1``, else the end-to-end ones; a metric that lists ``workloads`` only in
+    those cells."""
+    return [m["name"] for m in bench["per_layer" if traced else "end_to_end"]
+            if "workloads" not in m or cell in m["workloads"]]
+
+
 class Stable:
     """Has the engine compiled anything since the last check?"""
 
     def __init__(self, client: Client, engine_url: str, max_s: float):
         self.client, self.url = client, engine_url
         self.deadline = time.monotonic() + max_s
-        self.seen = -1
+        self.counts: Dict[str, int] = {}
+        self.seen = 0
         self.checks = 0
+        self.history = []   # what each stretch compiled, for run.json
 
-    async def check(self) -> bool:
+    async def check(self, after: str) -> bool:
+        """``after`` names the stretch: ``programs`` (the fixed warm-up
+        requests, which are meant to compile) or ``traffic`` (the cell's
+        own mix, which is meant to find everything compiled)."""
         compiles = await self.client.get_json(self.url + "/debug/compiles")
         # Compile events, not distinct keys: the tracker's key is a
         # truncated signature, so two shapes can share one.
-        now = sum(r["count"] for r in compiles["executables"])
-        rose, self.seen = now > self.seen, now
+        counts = {r["executable"]: r["count"] for r in compiles["executables"]}
+        new = {k: n - self.counts.get(k, 0) for k, n in counts.items()
+               if n > self.counts.get(k, 0)}
+        self.counts, self.seen = counts, sum(counts.values())
         self.checks += 1
-        say("warm-up stretch done", compile_events=now, stretch=self.checks,
+        self.history.append({"after": after, "new": new})
+        say("warm-up stretch done", compile_events=self.seen,
+            stretch=self.checks, after=after, new_events=sum(new.values()),
             compiled_shapes=compiles["compiled_shapes"],
             compile_seconds=compiles["compile_seconds"])
-        return not rose or time.monotonic() > self.deadline
+        if after == "traffic" and new:
+            say("the warm-up's fixed requests missed programs that the "
+                "cell's own traffic then compiled: one more stretch",
+                programs=new)
+        return after == "traffic" and (
+            not new or time.monotonic() > self.deadline)
 
 
 async def sleep_until(t: float) -> None:
     await asyncio.sleep(max(0.0, t - time.monotonic()))
 
 
-async def drive(args, config, traffic, cell_params, router, engine_url,
-                router_url, out_dir) -> Dict:
+async def drive(args, config, traffic, cell_params, engine, router,
+                engine_url, router_url, out_dir) -> Dict:
     """Warm-up, router, window.  Returns what the reduction needs."""
     gen = importlib.import_module("generators." + traffic["generator"])
     got: Dict = {"timing": {}}
@@ -118,7 +141,10 @@ async def drive(args, config, traffic, cell_params, router, engine_url,
                 else cell_params)
         await gen.warmup(client, traffic, warm, stable)
         got["timing"]["warmup_s"] = time.monotonic() - t
-        got["warmup_programs"] = stable.seen
+        # Boot and warm-up in the engine's own CPU seconds: a slow machine
+        # shows here, more work would show in the stretches (PERF.md, PR 33).
+        got["timing"]["engine_cpu_s"] = engine.cpu_seconds()
+        got["warmup_stretches"] = stable.history
 
         # The router starts only now, so that its capacity model never sees
         # a compile's latency (it shed 9 of 18 requests when it did, PR 21).
@@ -144,10 +170,17 @@ async def drive(args, config, traffic, cell_params, router, engine_url,
                 say("sweep rung", **got["sweep"][-1])
                 await asyncio.sleep(2.0)
             return got
+        # The generator's own loop is watched (harness/hostmon.py): a stall
+        # of the machine inside the window is reported, never acted on.
+        lag = LoopLag()
+        watching = asyncio.ensure_future(lag.run())
         got.update(await window(
             args, client, gen, traffic, cell_params, state, engine_url,
             out_dir, stable.seen, trace=bool(args.trace)))
+        watching.cancel()
         got["setup_s"] = got["t0"] - _T_START
+        got["generator_lag_ms"] = lag.worst(
+            got["t0"], got["t0"] + got["seconds"])
         got["windows"] = await client.get_json(engine_url + "/debug/windows")
         got["records"] = client.records
     return got
@@ -285,7 +318,7 @@ def main() -> None:
         boot_s = engine.wait_http_ok(engine_url + "/health", 900.0)
         say("engine up", boot_s=boot_s, device=device)
         got = asyncio.run(drive(
-            args, config, traffic, cell_params, router, engine_url,
+            args, config, traffic, cell_params, engine, router, engine_url,
             router_url, out_dir))
         got["timing"]["engine_boot_s"] = boot_s
         if args.sweep:
@@ -314,10 +347,19 @@ def main() -> None:
         highest_supported_percentile=summary["highest_supported_percentile"],
         end_to_end=None if rehearsal else summary["metrics"],
         exit_codes=exit_codes)
+    say("generator", late_max_ms=max(late, default=None),
+        wakeups_late_ms=got["generator_lag_ms"])
 
     ctx = layers.Context(
         cell=cell, config=config, records=got["records"], late_ms=late,
         got=got, summary=summary, dirs=dirs)
+    # The server's side of a stall: the longest the engine went without
+    # dispatching anything inside the window (its flight records).
+    at = sorted(w["dispatched_at"] for w in ctx.window_records())
+    quiet = max(((b - a, a - got["wall_t0"]) for a, b in zip(at, at[1:])),
+                default=(None, None))
+    say("engine", dispatches=len(at), longest_gap_s=quiet[0],
+        gap_began_at_s=quiet[1])
     served_ok, served_notes = layers.served_path_ok(
         ctx, platform, exit_codes)
     say("served path", ok=served_ok, notes=served_notes)
@@ -338,15 +380,13 @@ def main() -> None:
     from harness import compare
 
     t = time.monotonic()
-    model_ok, compare_notes = compare.run(config, cell["chips"], args.seed,
-                                          platform, env_root=ROOT)
+    model_ok, compare_notes, compared = compare.run(
+        config, cell["chips"], args.seed, platform, env_root=ROOT)
     say("reference compare", ok=model_ok, seconds=time.monotonic() - t,
         notes=compare_notes)
 
     device_out = dict(got["after"]["device"])
-    names = [m["name"] for m in bench["per_layer" if args.trace else
-                                      "end_to_end"]
-             if "workloads" not in m or cell["name"] in m["workloads"]]
+    names = metric_names(bench, cell["name"], bool(args.trace))
     units = {m["name"]: m["unit"]
              for m in bench["end_to_end"] + bench["per_layer"]}
     if args.trace:
@@ -371,12 +411,19 @@ def main() -> None:
     }
     if args.trace and trace is not None and timed:
         result["breakdown"] = layers.breakdown(ctx)
+    # What ``correct`` was decided from, each number beside its limit: last
+    # in the line and last on standard error.
+    result["compared"] = dict(
+        compared, served_path_faults=[len(served_notes), 0])
     with open(os.path.join(out_dir, "records.jsonl"), "w") as f:
         for r in got["records"]:
             f.write(json.dumps(dataclasses.asdict(r)) + "\n")
     with open(os.path.join(out_dir, "run.json"), "w") as f:
         json.dump({"result": result, "summary": summary,
                    "timing": got["timing"], "late_ms": late,
+                   "warmup_stretches": got["warmup_stretches"],
+                   "generator_lag_ms": got["generator_lag_ms"],
+                   "engine_longest_dispatch_gap_s": quiet[0],
                    "compare": compare_notes, "served": served_notes,
                    "engine_after": {k: got["after"][k] for k in (
                        "compiled_shapes", "compile_events", "compile_seconds",
@@ -387,6 +434,9 @@ def main() -> None:
                                        if k != "modules"}}, f, indent=1)
     if not timed:
         say("rehearsal on the CPU: no timing is reported")
+    for name, (value, limit) in result["compared"].items():
+        print(f"compared {name}: {value} limit {limit}", file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(result), flush=True)
 
 

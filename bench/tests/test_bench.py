@@ -63,6 +63,77 @@ def test_sessions_population_is_a_pure_function_of_the_seed():
     assert sum(thinks) / len(thinks) == pytest.approx(0.5, rel=0.05)
 
 
+# -- the warm-up is a fixed list of requests, then the mix as the proof -----
+
+
+def test_the_warm_up_sends_its_fixed_requests_then_one_stretch_if_clean():
+    import asyncio
+
+    from generators import warm
+
+    sent = []
+
+    class FakeClient:
+        async def chat(self, phase, messages, max_tokens):
+            sent.append((phase, len(messages[0]["content"]), max_tokens))
+
+    class FakeStable:
+        def __init__(self, dirty_stretches):
+            self.calls, self.dirty = [], dirty_stretches
+
+        async def check(self, after):
+            self.calls.append(after)
+            return after == "traffic" and self.calls.count("traffic") > self.dirty
+
+    spec = traffic("chat-steady")["warmup"]["bursts"]
+    stretches = []
+
+    async def stretch(cycle):
+        stretches.append(cycle)
+
+    stable = FakeStable(dirty_stretches=0)
+    asyncio.run(warm.until_stable(FakeClient(), spec, stable, stretch))
+    # The bursts, each led by the long prompt; the last overfills the batch.
+    assert len(sent) == sum(spec["sizes"]) and spec["sizes"][-1] > 16
+    assert sent[:2] == [("warmup", 1500, 24), ("warmup", 1500, 24)]
+    assert stable.calls == ["programs", "traffic"] and stretches == [0]
+    # A stretch that still compiled is replayed, as before.
+    stable = FakeStable(dirty_stretches=2)
+    asyncio.run(warm.until_stable(FakeClient(), spec, stable, stretch))
+    assert stable.calls == ["programs", "traffic", "traffic", "traffic"]
+
+
+def test_loop_lag_keeps_the_latest_wakeups_of_a_window():
+    from harness.hostmon import LoopLag
+
+    lag = LoopLag()
+    lag.lags = [(99.0, 2.0), (100.5, 0.003), (101.0, 0.9), (101.05, 0.3),
+                (120.0, 0.002), (146.0, 5.0)]
+    worst = lag.worst(100.0, 145.0)
+    assert worst == [[0.5, 3.0], [1.0, 900.0], [1.05, 300.0], [20.0, 2.0]]
+    assert lag.worst(200.0, 245.0) == []
+
+
+def test_a_childs_cpu_seconds_are_read_from_proc(tmp_path):
+    import time
+
+    from harness.children import Child
+
+    child = Child("spin", [sys.executable, "-c", (
+        "import time\nt = time.process_time()\n"
+        "while time.process_time() - t < 0.3: pass\ntime.sleep(30)")],
+        str(tmp_path)).start()
+    try:
+        deadline = time.monotonic() + 20
+        used = child.cpu_seconds()
+        while used["process"] < 0.3 and time.monotonic() < deadline:
+            time.sleep(0.1)
+            used = child.cpu_seconds()
+        assert 0.3 <= used["busiest_thread"] <= used["process"] < 5
+    finally:
+        child.stop(grace_s=5)
+
+
 # -- percentile, tpot and failure arithmetic on a hand-made record set -----
 
 
@@ -242,6 +313,198 @@ def test_a_dropped_in_config_traffic_generator_and_metric_are_found(tmp_path):
     finally:
         sys.path.remove(str(root))
         for name in ("generators.bursty", "readers.fortytwo"):
+            sys.modules.pop(name, None)
+
+
+ROUTED_REFERENCE = '''
+"""A routed model's forward pass, written out plainly: pre-norm blocks of
+grouped-query attention with rotate-half RoPE and a sliding window, then
+experts: softmax over all, the top k renormalised, SwiGLU each.  Keys of the
+configuration's own naming."""
+import jax
+import jax.numpy as jnp
+
+from reference.mistral import _rms_norm, _rope, _weight
+
+QUERY_ROWS = 128
+
+
+def forward(params, hp, tokens):
+    with jax.default_matmul_precision("highest"):
+        H, K, hd = (hp["num_attention_heads"], hp["num_key_value_heads"],
+                    hp["head_dim"])
+        top = hp["moe_num_active_primary_experts"]
+        T = tokens.shape[0]
+        pos = jnp.arange(T)
+        mask = (pos[None, :] <= pos[:, None]) & (
+            pos[None, :] > pos[:, None] - hp["sliding_window_size"])
+        x = _weight(params["embed_tokens"])[tokens]
+        for layer in params["layers"]:
+            h = _rms_norm(x, _weight(layer["input_layernorm"]),
+                          hp["rms_norm_eps"])
+            q = _rope((h @ _weight(layer["q_proj"])).reshape(T, H, hd),
+                      pos, hp["rope_theta"])
+            k = _rope((h @ _weight(layer["k_proj"])).reshape(T, K, hd),
+                      pos, hp["rope_theta"])
+            v = (h @ _weight(layer["v_proj"])).reshape(T, K, hd)
+            k, v = (jnp.repeat(a, H // K, axis=1) for a in (k, v))
+            attn = []
+            for lo in range(0, T, QUERY_ROWS):
+                rows = slice(lo, lo + QUERY_ROWS)
+                scores = jnp.einsum("qhd,khd->hqk", q[rows], k)
+                scores = jnp.where(mask[None, rows],
+                                   scores / jnp.sqrt(float(hd)), -jnp.inf)
+                attn.append(jnp.einsum(
+                    "hqk,khd->qhd", jax.nn.softmax(scores, -1), v))
+            x = x + jnp.concatenate(attn).reshape(T, H * hd) @ _weight(
+                layer["o_proj"])
+            h = _rms_norm(x, _weight(layer["post_attention_layernorm"]),
+                          hp["rms_norm_eps"])
+            probs = jax.nn.softmax(h @ _weight(layer["gate"]), -1)
+            best, who = jax.lax.top_k(probs, top)
+            best = best / best.sum(-1, keepdims=True)
+            out = jnp.zeros_like(x)
+            for e in range(hp["moe_num_primary_experts"]):
+                y = (jax.nn.silu(h @ _weight(layer["experts_gate"])[e])
+                     * (h @ _weight(layer["experts_up"])[e])
+                     ) @ _weight(layer["experts_down"])[e]
+                out = out + y * jnp.where(who == e, best, 0.0).sum(
+                    -1, keepdims=True)
+            x = x + out
+        x = _rms_norm(x, _weight(params["norm"]), hp["rms_norm_eps"])
+        return x @ _weight(params["lm_head"])
+'''
+
+ROUTED_TOP1 = '''
+from reference import routed
+
+
+def forward(params, hp, tokens):
+    return routed.forward(
+        params, dict(hp, moe_num_active_primary_experts=1), tokens)
+'''
+
+
+def test_a_dropped_in_config_of_another_architecture_cut_in_depth(
+        tmp_path, monkeypatch):
+    """A later PR's configuration whose published keys are not llama's (an
+    expert count and an expert width of its own, no ``intermediate_size``)
+    and which holds 2 of its 8 published layers: new files, none edited.
+    The compare is driven by its file; the readers count the held layers."""
+    import run
+    from harness import compare
+    from harness.sizes import held
+
+    monkeypatch.syspath_prepend(run.ROOT)
+    from production_stack_tpu.engine.config import PRESETS, ModelConfig
+
+    with open(os.path.join(BENCH, "..", "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    bench["configs"].append({
+        "name": "routed-tiny", "source": "https://example.org/routed",
+        "file": "bench/configs/routed-tiny.json",
+        "reduced": ["num_hidden_layers"], "why": "routed experts, a window"})
+    bench["workloads"].append({
+        "name": "routed-tiny.chat-steady", "config": "routed-tiny",
+        "traffic": "chat-steady", "chips": 1, "why": "new"})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    root = tmp_path / "bench"
+    for d in ("configs", "reference"):
+        (root / d).mkdir(parents=True)
+    sizes = {
+        "hidden_size": 64, "num_attention_heads": 4, "head_dim": 16,
+        "num_key_value_heads": 2, "num_hidden_layers": 8,
+        "moe_num_primary_experts": 4, "moe_num_active_primary_experts": 2,
+        "moe_ffn_hidden_size": 32, "sliding_window_size": 128,
+        "vocab_size": 384, "rms_norm_eps": 1e-05, "rope_theta": 10000.0}
+    file = dict(
+        sizes, published=sizes, num_hidden_layers=2,
+        reduced=["num_hidden_layers"], model="routed-tiny", engine_argv=[],
+        compare={
+            "reference": "routed", "layers": 2, "decode_steps": 2,
+            "logits_rtol": 0.03, "prompt_tokens": [300, 100],
+            "preset_keys": {
+                "hidden_size": "hidden_size", "head_dim": "head_dim",
+                "num_attention_heads": "num_heads",
+                "num_key_value_heads": "num_kv_heads",
+                "moe_num_primary_experts": "num_experts",
+                "moe_num_active_primary_experts": "num_experts_per_tok",
+                "moe_ffn_hidden_size": "intermediate_size",
+                "sliding_window_size": "sliding_window",
+                "vocab_size": "vocab_size"}})
+    (root / "configs" / "routed-tiny.json").write_text(json.dumps(file))
+    (root / "reference" / "routed.py").write_text(ROUTED_REFERENCE)
+    (root / "reference" / "routed_top1.py").write_text(ROUTED_TOP1)
+    # The program's side of a later PR: a preset (float32, so that no
+    # near-tie in the top k turns on bf16 rounding).
+    monkeypatch.setitem(PRESETS, "routed-tiny", ModelConfig(
+        name="mixtral-routed-tiny", num_layers=8, intermediate_size=32,
+        num_experts=4, num_experts_per_tok=2, sliding_window=128,
+        dtype="float32"))
+
+    _b, cell, config, _tr, _p, dirs = run.resolve(
+        str(tmp_path / "BENCHMARK.json"), "routed-tiny.chat-steady")
+    try:
+        assert held(config)["num_hidden_layers"] == 2
+        assert config["published"]["num_hidden_layers"] == 8
+        # (a) the compare, by the file alone; a reference that routes to
+        # the best expert only is another model, and reads so.
+        ok, notes, rows = compare.run(config, 1, 3_300_000_001, "cpu",
+                                      env_root=run.ROOT)
+        assert ok and len(notes) == 4, notes
+        assert list(rows) == [
+            "prefill_of_300_tokens_256_cached",
+            "prefill_of_100_tokens_no_prefix", "decode_step_0",
+            "decode_step_1"]
+        assert all(0 < err <= limit == 0.03 for err, limit in rows.values())
+        assert notes[0].startswith("prefill of 300 tokens, 256 cached")
+        assert notes[1].startswith("prefill of 100 tokens, no prefix")
+        top1 = dict(config, compare=dict(config["compare"],
+                                         reference="routed_top1"))
+        ok, notes, rows = compare.run(top1, 1, 3_300_000_001, "cpu",
+                                      env_root=run.ROOT)
+        assert not ok and max(e for e, _l in rows.values()) > 0.03, notes
+        # A preset that disagrees with the file is refused before any run.
+        wider = dict(config, moe_ffn_hidden_size=64, published=dict(
+            sizes, moe_ffn_hidden_size=64))
+        ok, notes, _rows = compare.run(wider, 1, 1, "cpu", env_root=run.ROOT)
+        assert not ok and "moe_ffn_hidden_size=64" in notes[0]
+        # A size changed at the top level with no word in ``reduced`` is
+        # refused, not followed; so is a reduced key with no held value.
+        with pytest.raises(SystemExit, match="moe_ffn_hidden_size"):
+            held(dict(config, moe_ffn_hidden_size=64))
+        with pytest.raises(SystemExit, match="vocab_size"):
+            held(dict({k: v for k, v in config.items() if k != "vocab_size"},
+                      reduced=["num_hidden_layers", "vocab_size"]))
+        # (b) the readers, every per-layer metric the new cell reports: 16
+        # kernel calls over the 2 held layers are 8 steps of 10 ms; the
+        # dense-MLP bandwidth share is not this cell's and is never read.
+        names = run.metric_names(bench, cell["name"], traced=True)
+        assert "decode_step_dev_ms" in names
+        assert "decode_step_bw_share" not in names
+        assert "decode_step_bw_share" in run.metric_names(
+            bench, "m7b-int8.chat-steady", traced=True)
+        kernel = "paged_decode_attention_pallas"
+        trace = {"modules": [
+            ["window_fn", 0, 80e6, {kernel: 16, "fusion": 40}],
+            ["prefill_fn", 90e6, 30e6, {"flash_prefill_attention": 2}]],
+            "busy_s": 0.11, "window_s": 0.125, "span_ns": [0, 125e6],
+            "ops": [], "gaps": []}
+        edge = {"prom": {}, "compile_events": 21,
+                "device": {"kind": "TPU v5 lite"}}
+        ctx = layers.Context(
+            cell=cell, config=config, records=[], late_ms=[1.0, 2.0],
+            got={"windows": {"windows": []}, "t0": 100.0, "wall_t0": 1e9,
+                 "seconds": 45, "drain_s": 15, "before": edge, "after": edge},
+            summary={}, dirs=dirs, trace=trace)
+        values = layers.read_all(ctx, names)
+        assert set(values) == set(names)
+        assert values["decode_step_dev_ms"] == pytest.approx(10.0)
+        assert values["device_idle_share"] == pytest.approx(12.0)
+        assert values["compiles_in_window"] == 0.0
+    finally:
+        sys.path.remove(str(root))
+        for name in ("reference.routed", "reference.routed_top1"):
             sys.modules.pop(name, None)
 
 
