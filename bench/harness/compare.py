@@ -16,6 +16,14 @@ which have to agree before anything runs (default: the six llama keys).
 Tokens are not compared: with random weights the largest logit turns on
 rounding.  The driving code is copied from ``chip_smoke.py`` (PR 21), which
 compared kernels with the XLA path, not with a reference.
+
+Every row (the end of each prefill, each decode step of both sequences) has
+to hold ``logits_rtol``.  ``programs`` and ``drive`` are also what
+``tools/flip_rate.py`` drives a routed model with; a row keeps the
+(sequence, index) of each of its positions for it.  A routed configuration's
+top k meets near-ties that the engine's bf16 arithmetic flips, and no rule
+over rows or positions separates those from a fault at real widths
+(PERF.md, PR 34): the compare that will is not here yet (PERF.md, section 7).
 """
 
 from __future__ import annotations
@@ -37,6 +45,124 @@ PRESET_KEYS = {
 BLOCK, CHUNK = 16, 256   # tokens a KV block, slots a prefill chunk
 
 
+def programs(model, cfg, mesh=None):
+    """``prefill`` and ``decode`` of the served module, jitted as the
+    compare drives them."""
+    import jax
+
+    prefill = jax.jit(
+        lambda p, t, c, pre, new, v, kv: model.prefill(
+            p, cfg, t, c, pre, new, v, kv, mesh=mesh),
+        donate_argnums=(6,))
+    decode = jax.jit(
+        lambda p, t, pos, bt, cl, sb, so, kv: model.decode(
+            p, cfg, t, pos, bt, cl, sb, so, kv, mesh=mesh),
+        donate_argnums=(7,))
+    return prefill, decode
+
+
+def drive(prefill, decode, params, cfg, lens, steps: int, seed: int,
+          kv_sharding=None):
+    """The engine's side.  Returns the two whole sequences (prompt, then the
+    tokens fed to the decode steps) and a list of rows ``(label, where,
+    logits)``: ``where`` is one (sequence, index) a row of ``logits``."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    len_a, len_b = lens
+    # Blocks a sequence: a power of two that holds the longer one and its
+    # decode steps, 32 at the least; block 0 is the null block.
+    per = 32
+    while per * BLOCK < max(len_a, len_b) + steps:
+        per *= 2
+    bs, num_blocks, bmax, T = BLOCK, 3 * per, 2 * per, CHUNK
+    kv_shape = (num_blocks, bs, cfg.num_kv_heads, cfg.head_dim)
+    zeros = jax.jit(lambda: jnp.zeros(kv_shape, cfg.dtype),
+                    out_shardings=kv_sharding)
+    kv = [(zeros(), zeros()) for _ in range(cfg.num_layers)]
+    rng = np.random.default_rng(seed)
+    prompt_a = rng.integers(1, cfg.vocab_size, len_a).astype(np.int32)
+    prompt_b = rng.integers(1, cfg.vocab_size, len_b).astype(np.int32)
+    decode_tokens = rng.integers(1, cfg.vocab_size, (steps, 2)).astype(np.int32)
+    blocks_a = np.arange(1, 1 + per, dtype=np.int32)
+    blocks_b = np.arange(per + 8, 2 * per + 8, dtype=np.int32)
+
+    def run_prefill(prompt, start, blocks, kv):
+        chunk = prompt[start:start + T]
+        tokens = np.zeros((T,), np.int32)
+        tokens[:len(chunk)] = chunk
+        prefix = np.zeros((bmax,), np.int32)
+        prefix[:start // bs] = blocks[:start // bs]
+        new = np.zeros((T // bs,), np.int32)
+        n_new = -(-len(chunk) // bs)
+        new[:n_new] = blocks[start // bs:start // bs + n_new]
+        return prefill(params, jnp.asarray(tokens), jnp.int32(start),
+                       jnp.asarray(prefix), jnp.asarray(new),
+                       jnp.int32(len(chunk)), kv)
+
+    got = []
+    for seq, (prompt, blocks) in enumerate(
+            ((prompt_a, blocks_a), (prompt_b, blocks_b))):
+        for start in range(0, len(prompt), T):
+            out, kv = run_prefill(prompt, start, blocks, kv)
+        label = f"prefill of {len(prompt)} tokens, " + (
+            f"{start} cached" if start else "no prefix")
+        if any(label == other for other, _w, _l in got):
+            label += ", the second prompt"   # two prompts of one length
+        got.append((label, [(seq, len(prompt) - 1)],
+                    np.asarray(out, np.float32)[None]))
+    tables = np.zeros((2, bmax), np.int32)
+    tables[0, :per], tables[1, :per] = blocks_a, blocks_b
+    ctx = np.array([len_a, len_b], np.int32)
+    for step in range(steps):
+        ctx = ctx + 1
+        pos = ctx - 1
+        out, kv = decode(
+            params, jnp.asarray(decode_tokens[step]), jnp.asarray(pos),
+            jnp.asarray(tables), jnp.asarray(ctx),
+            jnp.asarray(tables[np.arange(2), pos // bs]),
+            jnp.asarray(pos % bs), kv)
+        got.append((f"decode step {step}", [(0, int(pos[0])), (1, int(pos[1]))],
+                    np.asarray(out, np.float32)))
+    return (np.concatenate([prompt_a, decode_tokens[:, 0]]),
+            np.concatenate([prompt_b, decode_tokens[:, 1]])), got
+
+
+def reference_rows(forward, seqs, got):
+    """The reference's logits for the rows of ``got``: each whole sequence
+    in one pass, ``forward(tokens) -> [len(tokens), vocab]``.  Attention is
+    causal, so the logits at a position do not depend on what follows it."""
+    import numpy as np
+
+    ref = [np.asarray(forward(tokens)) for tokens in seqs]
+    return [np.stack([ref[s][i] for s, i in where])
+            for _name, where, _logits in got]
+
+
+def error(a, b) -> float:
+    """max|a-b| / max|b|: a row's number (the tool's: a position's)."""
+    import numpy as np
+
+    return float(np.max(np.abs(a - b)) / np.max(np.abs(b)))
+
+
+def score(got, want, rtol: float):
+    """(ok, notes, {row: [number, limit]}) from the engine's rows and the
+    reference's."""
+    import numpy as np
+
+    ok, notes, rows = True, [], {}
+    for (name, _where, a), b in zip(got, want):
+        err = error(a, b)
+        fine = bool(np.isfinite(a).all()) and err <= rtol
+        ok &= fine
+        notes.append(f"{name}: max|a-b|/max|b| = {err:.3e} "
+                     f"{'<=' if fine else '>'} {rtol}")
+        rows[name.replace(",", "").replace(" ", "_")] = [err, rtol]
+    return ok, notes, rows
+
+
 def run(config: Dict, chips: int, seed: int, platform: str,
         env_root: str) -> Tuple[bool, List[str], Dict[str, List[float]]]:
     """(ok, one note a compared row, {row: [number, limit]})."""
@@ -49,7 +175,6 @@ def run(config: Dict, chips: int, seed: int, platform: str,
     enable_compile_cache()
     import jax
     import jax.numpy as jnp
-    import numpy as np
     from jax.sharding import NamedSharding
 
     from production_stack_tpu.engine.config import PRESETS, ParallelConfig
@@ -58,7 +183,6 @@ def run(config: Dict, chips: int, seed: int, platform: str,
     from production_stack_tpu.engine.parallel.mesh import build_mesh
 
     spec = config["compare"]
-    notes = []
     if jax.default_backend() != platform or len(jax.devices()) < chips:
         return False, [f"the parent's JAX sees {jax.devices()}"], {}
     cfg = dataclasses.replace(
@@ -87,86 +211,11 @@ def run(config: Dict, chips: int, seed: int, platform: str,
     if mesh is None:
         params = model.quantize_params(params, cfg)
 
-    len_a, len_b = spec.get("prompt_tokens", [300, 100])
-    steps = spec.get("decode_steps", 2)
-    # Blocks a sequence: a power of two that holds the longer one and its
-    # decode steps, 32 at the least; block 0 is the null block.
-    per = 32
-    while per * BLOCK < max(len_a, len_b) + steps:
-        per *= 2
-    bs, num_blocks, bmax, T = BLOCK, 3 * per, 2 * per, CHUNK
-    kv_shape = (num_blocks, bs, cfg.num_kv_heads, cfg.head_dim)
-    zeros = jax.jit(lambda: jnp.zeros(kv_shape, cfg.dtype),
-                    out_shardings=kv_sharding)
-    kv = [(zeros(), zeros()) for _ in range(cfg.num_layers)]
-    rng = np.random.default_rng(seed)
-    prompt_a = rng.integers(1, cfg.vocab_size, len_a).astype(np.int32)
-    prompt_b = rng.integers(1, cfg.vocab_size, len_b).astype(np.int32)
-    decode_tokens = rng.integers(1, cfg.vocab_size, (steps, 2)).astype(np.int32)
-    blocks_a = np.arange(1, 1 + per, dtype=np.int32)
-    blocks_b = np.arange(per + 8, 2 * per + 8, dtype=np.int32)
-
-    prefill = jax.jit(
-        lambda p, t, c, pre, new, v, kv: model.prefill(
-            p, cfg, t, c, pre, new, v, kv, mesh=mesh),
-        donate_argnums=(6,))
-    decode = jax.jit(
-        lambda p, t, pos, bt, cl, sb, so, kv: model.decode(
-            p, cfg, t, pos, bt, cl, sb, so, kv, mesh=mesh),
-        donate_argnums=(7,))
-
-    def run_prefill(prompt, start, blocks, kv):
-        chunk = prompt[start:start + T]
-        tokens = np.zeros((T,), np.int32)
-        tokens[:len(chunk)] = chunk
-        prefix = np.zeros((bmax,), np.int32)
-        prefix[:start // bs] = blocks[:start // bs]
-        new = np.zeros((T // bs,), np.int32)
-        n_new = -(-len(chunk) // bs)
-        new[:n_new] = blocks[start // bs:start // bs + n_new]
-        return prefill(params, jnp.asarray(tokens), jnp.int32(start),
-                       jnp.asarray(prefix), jnp.asarray(new),
-                       jnp.int32(len(chunk)), kv)
-
-    got = []
-    for prompt, blocks in ((prompt_a, blocks_a), (prompt_b, blocks_b)):
-        for start in range(0, len(prompt), T):
-            out, kv = run_prefill(prompt, start, blocks, kv)
-        got.append((f"prefill of {len(prompt)} tokens, " + (
-            f"{start} cached" if start else "no prefix"),
-            np.asarray(out, np.float32)))
-    tables = np.zeros((2, bmax), np.int32)
-    tables[0, :per], tables[1, :per] = blocks_a, blocks_b
-    ctx = np.array([len(prompt_a), len(prompt_b)], np.int32)
-    for step in range(steps):
-        ctx = ctx + 1
-        pos = ctx - 1
-        out, kv = decode(
-            params, jnp.asarray(decode_tokens[step]), jnp.asarray(pos),
-            jnp.asarray(tables), jnp.asarray(ctx),
-            jnp.asarray(tables[np.arange(2), pos // bs]),
-            jnp.asarray(pos % bs), kv)
-        got.append((f"decode step {step}", np.asarray(out, np.float32)))
-
-    # The reference: each whole sequence in one pass.  Attention is causal,
-    # so the logits at a position do not depend on what follows it.
+    seqs, got = drive(
+        *programs(model, cfg, mesh), params, cfg,
+        spec.get("prompt_tokens", [300, 100]), spec.get("decode_steps", 2),
+        seed, kv_sharding)
     fwd = jax.jit(lambda p, t: reference.forward(p, hp, t))
-    full_a = np.concatenate([prompt_a, decode_tokens[:, 0]])
-    full_b = np.concatenate([prompt_b, decode_tokens[:, 1]])
-    ref_a = np.asarray(fwd(params, jnp.asarray(full_a)))
-    ref_b = np.asarray(fwd(params, jnp.asarray(full_b)))
-    want = [ref_a[len(prompt_a) - 1], ref_b[len(prompt_b) - 1]]
-    for step in range(steps):
-        want.append(np.stack([ref_a[len(prompt_a) + step],
-                              ref_b[len(prompt_b) + step]]))
-
-    ok, rows = True, {}
-    for (name, a), b in zip(got, want):
-        err = float(np.max(np.abs(a - b)) / np.max(np.abs(b)))
-        fine = bool(np.isfinite(a).all()) and err <= spec["logits_rtol"]
-        ok &= fine
-        notes.append(f"{name}: max|a-b|/max|b| = {err:.3e} "
-                     f"{'<=' if fine else '>'} {spec['logits_rtol']}")
-        rows[name.replace(",", "").replace(" ", "_")] = [
-            err, spec["logits_rtol"]]
-    return ok, notes, rows
+    want = reference_rows(lambda tokens: fwd(params, jnp.asarray(tokens)),
+                          seqs, got)
+    return score(got, want, spec["logits_rtol"])

@@ -316,86 +316,67 @@ def test_a_dropped_in_config_traffic_generator_and_metric_are_found(tmp_path):
             sys.modules.pop(name, None)
 
 
-ROUTED_REFERENCE = '''
-"""A routed model's forward pass, written out plainly: pre-norm blocks of
-grouped-query attention with rotate-half RoPE and a sliding window, then
-experts: softmax over all, the top k renormalised, SwiGLU each.  Keys of the
-configuration's own naming."""
+# -- a configuration of another architecture: routed experts ----------------
+
+# A reference that is wrong in one way: the body of ``forward`` in a module
+# of its own beside ``reference/routed.py``.
+FAULT = """
+from unittest import mock
+
 import jax
 import jax.numpy as jnp
 
-from reference.mistral import _rms_norm, _rope, _weight
-
-QUERY_ROWS = 128
-
-
-def forward(params, hp, tokens):
-    with jax.default_matmul_precision("highest"):
-        H, K, hd = (hp["num_attention_heads"], hp["num_key_value_heads"],
-                    hp["head_dim"])
-        top = hp["moe_num_active_primary_experts"]
-        T = tokens.shape[0]
-        pos = jnp.arange(T)
-        mask = (pos[None, :] <= pos[:, None]) & (
-            pos[None, :] > pos[:, None] - hp["sliding_window_size"])
-        x = _weight(params["embed_tokens"])[tokens]
-        for layer in params["layers"]:
-            h = _rms_norm(x, _weight(layer["input_layernorm"]),
-                          hp["rms_norm_eps"])
-            q = _rope((h @ _weight(layer["q_proj"])).reshape(T, H, hd),
-                      pos, hp["rope_theta"])
-            k = _rope((h @ _weight(layer["k_proj"])).reshape(T, K, hd),
-                      pos, hp["rope_theta"])
-            v = (h @ _weight(layer["v_proj"])).reshape(T, K, hd)
-            k, v = (jnp.repeat(a, H // K, axis=1) for a in (k, v))
-            attn = []
-            for lo in range(0, T, QUERY_ROWS):
-                rows = slice(lo, lo + QUERY_ROWS)
-                scores = jnp.einsum("qhd,khd->hqk", q[rows], k)
-                scores = jnp.where(mask[None, rows],
-                                   scores / jnp.sqrt(float(hd)), -jnp.inf)
-                attn.append(jnp.einsum(
-                    "hqk,khd->qhd", jax.nn.softmax(scores, -1), v))
-            x = x + jnp.concatenate(attn).reshape(T, H * hd) @ _weight(
-                layer["o_proj"])
-            h = _rms_norm(x, _weight(layer["post_attention_layernorm"]),
-                          hp["rms_norm_eps"])
-            probs = jax.nn.softmax(h @ _weight(layer["gate"]), -1)
-            best, who = jax.lax.top_k(probs, top)
-            best = best / best.sum(-1, keepdims=True)
-            out = jnp.zeros_like(x)
-            for e in range(hp["moe_num_primary_experts"]):
-                y = (jax.nn.silu(h @ _weight(layer["experts_gate"])[e])
-                     * (h @ _weight(layer["experts_up"])[e])
-                     ) @ _weight(layer["experts_down"])[e]
-                out = out + y * jnp.where(who == e, best, 0.0).sum(
-                    -1, keepdims=True)
-            x = x + out
-        x = _rms_norm(x, _weight(params["norm"]), hp["rms_norm_eps"])
-        return x @ _weight(params["lm_head"])
-'''
-
-ROUTED_TOP1 = '''
 from reference import routed
 
+TOP = "moe_num_active_primary_experts"
+
+
+def unnormalised(logits, top):
+    probs = jax.nn.softmax(logits, -1)
+    best, who = jax.lax.top_k(probs, top)
+    rows = jnp.arange(logits.shape[0])[:, None]
+    return jnp.zeros_like(probs).at[rows, who].set(best)
+
 
 def forward(params, hp, tokens):
-    return routed.forward(
-        params, dict(hp, moe_num_active_primary_experts=1), tokens)
-'''
+    BODY
+"""
+FAULTS = {
+    "routed_top1":
+        "return routed.forward(params, dict(hp, **{TOP: 1}), tokens)",
+    "routed_five_of_six":
+        "return routed.forward(params, dict(hp, **{TOP: hp[TOP] - 1}), tokens)",
+    "routed_unnormalised":
+        "with mock.patch.object(routed, '_route', unnormalised):\n"
+        "        return routed.forward(params, hp, tokens)",
+    "routed_window_off_by_a_block":
+        "return routed.forward(params, dict(hp, sliding_window_size="
+        "hp['sliding_window_size'] + 16), tokens)",
+}
+ROUTED_SIZES = {
+    "hidden_size": 64, "num_attention_heads": 4, "head_dim": 16,
+    "num_key_value_heads": 2, "num_hidden_layers": 8,
+    "moe_num_primary_experts": 16, "moe_num_active_primary_experts": 6,
+    "moe_ffn_hidden_size": 32, "sliding_window_size": 128,
+    "vocab_size": 384, "rms_norm_eps": 1e-05, "rope_theta": 10000.0}
+# Float32 against float32 on the CPU: a sound run reads at most 3.3e-7 and the
+# mildest fault (five of six) at least 1.4e-2, twelve seeds each.
+ROUTED_RTOL = 1e-3
 
 
-def test_a_dropped_in_config_of_another_architecture_cut_in_depth(
-        tmp_path, monkeypatch):
+@pytest.fixture(scope="module")
+def routed_cell(tmp_path_factory):
     """A later PR's configuration whose published keys are not llama's (an
     expert count and an expert width of its own, no ``intermediate_size``)
     and which holds 2 of its 8 published layers: new files, none edited.
-    The compare is driven by its file; the readers count the held layers."""
+    The program's side of that PR is a preset: in float32, because in the
+    served dtype a top k flips on rounding and a flipped position reads
+    another model's error (PERF.md, PR 34; ``tools/flip_rate.py``)."""
     import run
-    from harness import compare
-    from harness.sizes import held
 
-    monkeypatch.syspath_prepend(run.ROOT)
+    tmp_path = tmp_path_factory.mktemp("routed")
+    patch = pytest.MonkeyPatch()
+    patch.syspath_prepend(run.ROOT)
     from production_stack_tpu.engine.config import PRESETS, ModelConfig
 
     with open(os.path.join(BENCH, "..", "BENCHMARK.json")) as f:
@@ -411,18 +392,12 @@ def test_a_dropped_in_config_of_another_architecture_cut_in_depth(
     root = tmp_path / "bench"
     for d in ("configs", "reference"):
         (root / d).mkdir(parents=True)
-    sizes = {
-        "hidden_size": 64, "num_attention_heads": 4, "head_dim": 16,
-        "num_key_value_heads": 2, "num_hidden_layers": 8,
-        "moe_num_primary_experts": 4, "moe_num_active_primary_experts": 2,
-        "moe_ffn_hidden_size": 32, "sliding_window_size": 128,
-        "vocab_size": 384, "rms_norm_eps": 1e-05, "rope_theta": 10000.0}
     file = dict(
-        sizes, published=sizes, num_hidden_layers=2,
+        ROUTED_SIZES, published=ROUTED_SIZES, num_hidden_layers=2,
         reduced=["num_hidden_layers"], model="routed-tiny", engine_argv=[],
         compare={
             "reference": "routed", "layers": 2, "decode_steps": 2,
-            "logits_rtol": 0.03, "prompt_tokens": [300, 100],
+            "logits_rtol": ROUTED_RTOL, "prompt_tokens": [300, 100],
             "preset_keys": {
                 "hidden_size": "hidden_size", "head_dim": "head_dim",
                 "num_attention_heads": "num_heads",
@@ -433,79 +408,114 @@ def test_a_dropped_in_config_of_another_architecture_cut_in_depth(
                 "sliding_window_size": "sliding_window",
                 "vocab_size": "vocab_size"}})
     (root / "configs" / "routed-tiny.json").write_text(json.dumps(file))
-    (root / "reference" / "routed.py").write_text(ROUTED_REFERENCE)
-    (root / "reference" / "routed_top1.py").write_text(ROUTED_TOP1)
-    # The program's side of a later PR: a preset (float32, so that no
-    # near-tie in the top k turns on bf16 rounding).
-    monkeypatch.setitem(PRESETS, "routed-tiny", ModelConfig(
+    for name, body in FAULTS.items():
+        (root / "reference" / (name + ".py")).write_text(
+            FAULT.replace("BODY", body))
+    patch.setitem(PRESETS, "routed-tiny", ModelConfig(
         name="mixtral-routed-tiny", num_layers=8, intermediate_size=32,
-        num_experts=4, num_experts_per_tok=2, sliding_window=128,
+        num_experts=16, num_experts_per_tok=6, sliding_window=128,
         dtype="float32"))
-
-    _b, cell, config, _tr, _p, dirs = run.resolve(
+    bench, cell, config, _tr, _p, dirs = run.resolve(
         str(tmp_path / "BENCHMARK.json"), "routed-tiny.chat-steady")
     try:
-        assert held(config)["num_hidden_layers"] == 2
-        assert config["published"]["num_hidden_layers"] == 8
-        # (a) the compare, by the file alone; a reference that routes to
-        # the best expert only is another model, and reads so.
-        ok, notes, rows = compare.run(config, 1, 3_300_000_001, "cpu",
-                                      env_root=run.ROOT)
-        assert ok and len(notes) == 4, notes
-        assert list(rows) == [
-            "prefill_of_300_tokens_256_cached",
-            "prefill_of_100_tokens_no_prefix", "decode_step_0",
-            "decode_step_1"]
-        assert all(0 < err <= limit == 0.03 for err, limit in rows.values())
-        assert notes[0].startswith("prefill of 300 tokens, 256 cached")
-        assert notes[1].startswith("prefill of 100 tokens, no prefix")
-        top1 = dict(config, compare=dict(config["compare"],
-                                         reference="routed_top1"))
-        ok, notes, rows = compare.run(top1, 1, 3_300_000_001, "cpu",
-                                      env_root=run.ROOT)
-        assert not ok and max(e for e, _l in rows.values()) > 0.03, notes
-        # A preset that disagrees with the file is refused before any run.
-        wider = dict(config, moe_ffn_hidden_size=64, published=dict(
-            sizes, moe_ffn_hidden_size=64))
-        ok, notes, _rows = compare.run(wider, 1, 1, "cpu", env_root=run.ROOT)
-        assert not ok and "moe_ffn_hidden_size=64" in notes[0]
-        # A size changed at the top level with no word in ``reduced`` is
-        # refused, not followed; so is a reduced key with no held value.
-        with pytest.raises(SystemExit, match="moe_ffn_hidden_size"):
-            held(dict(config, moe_ffn_hidden_size=64))
-        with pytest.raises(SystemExit, match="vocab_size"):
-            held(dict({k: v for k, v in config.items() if k != "vocab_size"},
-                      reduced=["num_hidden_layers", "vocab_size"]))
-        # (b) the readers, every per-layer metric the new cell reports: 16
-        # kernel calls over the 2 held layers are 8 steps of 10 ms; the
-        # dense-MLP bandwidth share is not this cell's and is never read.
-        names = run.metric_names(bench, cell["name"], traced=True)
-        assert "decode_step_dev_ms" in names
-        assert "decode_step_bw_share" not in names
-        assert "decode_step_bw_share" in run.metric_names(
-            bench, "m7b-int8.chat-steady", traced=True)
-        kernel = "paged_decode_attention_pallas"
-        trace = {"modules": [
-            ["window_fn", 0, 80e6, {kernel: 16, "fusion": 40}],
-            ["prefill_fn", 90e6, 30e6, {"flash_prefill_attention": 2}]],
-            "busy_s": 0.11, "window_s": 0.125, "span_ns": [0, 125e6],
-            "ops": [], "gaps": []}
-        edge = {"prom": {}, "compile_events": 21,
-                "device": {"kind": "TPU v5 lite"}}
-        ctx = layers.Context(
-            cell=cell, config=config, records=[], late_ms=[1.0, 2.0],
-            got={"windows": {"windows": []}, "t0": 100.0, "wall_t0": 1e9,
-                 "seconds": 45, "drain_s": 15, "before": edge, "after": edge},
-            summary={}, dirs=dirs, trace=trace)
-        values = layers.read_all(ctx, names)
-        assert set(values) == set(names)
-        assert values["decode_step_dev_ms"] == pytest.approx(10.0)
-        assert values["device_idle_share"] == pytest.approx(12.0)
-        assert values["compiles_in_window"] == 0.0
+        yield types.SimpleNamespace(
+            bench=bench, cell=cell, config=config, dirs=dirs, root=run.ROOT)
     finally:
         sys.path.remove(str(root))
-        for name in ("reference.routed", "reference.routed_top1"):
-            sys.modules.pop(name, None)
+        for name in FAULTS:
+            sys.modules.pop("reference." + name, None)
+        patch.undo()
+
+
+def test_a_dropped_in_config_of_another_architecture_cut_in_depth(routed_cell):
+    """The file alone drives the sizes and the readers count the held
+    layers; the compare itself is the tests below."""
+    import run
+    from harness.sizes import held
+
+    bench, cell, config, dirs = (routed_cell.bench, routed_cell.cell,
+                                 routed_cell.config, routed_cell.dirs)
+    assert held(config)["num_hidden_layers"] == 2
+    assert config["published"]["num_hidden_layers"] == 8
+    # A preset that disagrees with the file is refused before any run.
+    wider = dict(config, moe_ffn_hidden_size=64, published=dict(
+        ROUTED_SIZES, moe_ffn_hidden_size=64))
+    from harness import compare
+
+    ok, notes, _rows = compare.run(wider, 1, 1, "cpu", env_root=run.ROOT)
+    assert not ok and "moe_ffn_hidden_size=64" in notes[0]
+    # A size changed at the top level with no word in ``reduced`` is
+    # refused, not followed; so is a reduced key with no held value.
+    with pytest.raises(SystemExit, match="moe_ffn_hidden_size"):
+        held(dict(config, moe_ffn_hidden_size=64))
+    with pytest.raises(SystemExit, match="vocab_size"):
+        held(dict({k: v for k, v in config.items() if k != "vocab_size"},
+                  reduced=["num_hidden_layers", "vocab_size"]))
+    # The readers, every per-layer metric the new cell reports: 16 kernel
+    # calls over the 2 held layers are 8 steps of 10 ms; the dense-MLP
+    # bandwidth share is not this cell's and is never read.
+    names = run.metric_names(bench, cell["name"], traced=True)
+    assert "decode_step_dev_ms" in names
+    assert "decode_step_bw_share" not in names
+    assert "decode_step_bw_share" in run.metric_names(
+        bench, "m7b-int8.chat-steady", traced=True)
+    kernel = "paged_decode_attention_pallas"
+    trace = {"modules": [
+        ["window_fn", 0, 80e6, {kernel: 16, "fusion": 40}],
+        ["prefill_fn", 90e6, 30e6, {"flash_prefill_attention": 2}]],
+        "busy_s": 0.11, "window_s": 0.125, "span_ns": [0, 125e6],
+        "ops": [], "gaps": []}
+    edge = {"prom": {}, "compile_events": 21,
+            "device": {"kind": "TPU v5 lite"}}
+    ctx = layers.Context(
+        cell=cell, config=config, records=[], late_ms=[1.0, 2.0],
+        got={"windows": {"windows": []}, "t0": 100.0, "wall_t0": 1e9,
+             "seconds": 45, "drain_s": 15, "before": edge, "after": edge},
+        summary={}, dirs=dirs, trace=trace)
+    values = layers.read_all(ctx, names)
+    assert set(values) == set(names)
+    assert values["decode_step_dev_ms"] == pytest.approx(10.0)
+    assert values["device_idle_share"] == pytest.approx(12.0)
+    assert values["compiles_in_window"] == 0.0
+
+
+@pytest.mark.parametrize("reference", ["routed", *FAULTS])
+def test_a_routed_configuration_holds_every_row_and_a_fault_does_not(
+        routed_cell, reference):
+    """The compare by the file alone, every row under the tolerance as a
+    dense file's; a reference that routes to fewer experts, leaves out the
+    renormalisation or sees a block further back is another model, and reads
+    so."""
+    from harness import compare
+
+    config = routed_cell.config
+    ok, notes, rows = compare.run(
+        dict(config, compare=dict(config["compare"], reference=reference)),
+        1, 3_300_000_001, "cpu", env_root=routed_cell.root)
+    assert len(notes) == 4 and list(rows) == [
+        "prefill_of_300_tokens_256_cached", "prefill_of_100_tokens_no_prefix",
+        "decode_step_0", "decode_step_1"], notes
+    assert notes[0].startswith("prefill of 300 tokens, 256 cached")
+    assert notes[1].startswith("prefill of 100 tokens, no prefix")
+    assert all(limit == ROUTED_RTOL for _err, limit in rows.values())
+    worst = max(err for err, _limit in rows.values())
+    if reference == "routed":
+        assert ok and 0 < worst <= ROUTED_RTOL, notes
+    else:
+        assert not ok and worst > ROUTED_RTOL, notes
+
+
+def test_two_prompts_of_one_length_keep_a_row_each(routed_cell):
+    from harness import compare
+
+    config = routed_cell.config
+    ok, _notes, rows = compare.run(
+        dict(config, compare=dict(config["compare"], prompt_tokens=[100, 100],
+                                  decode_steps=1)),
+        1, 3_300_000_002, "cpu", env_root=routed_cell.root)
+    assert ok and list(rows) == [
+        "prefill_of_100_tokens_no_prefix",
+        "prefill_of_100_tokens_no_prefix_the_second_prompt", "decode_step_0"]
 
 
 def test_every_name_in_the_benchmark_file_has_its_files():
