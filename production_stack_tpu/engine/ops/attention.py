@@ -5,8 +5,11 @@ Design notes (TPU-first):
 * All shapes are static.  Prefill lengths are bucketed, decode batch is
   padded to the scheduler's ``max_num_seqs``; invalid slots are masked, and
   their KV writes land in the reserved *null block* 0 (never read).
-* Softmax runs in fp32 (MXU accumulates fp32, VPU exponentiates fp32);
-  inputs/outputs are bf16.
+* Both dots take their operands in the cache's dtype (bf16 when serving:
+  the queries, and the probabilities rounded to it for the second dot) and
+  accumulate fp32 on the MXU; scores, the softmax and its statistics are
+  fp32 on the VPU; outputs are the queries' dtype -- on this path and in
+  the Pallas decode kernel alike.
 * The gather-based decode path below materializes [S, max_ctx, K, D] in HBM
   — correct everywhere (CPU tests, interpret mode) and fast enough for
   moderate contexts.  ``decode_attention`` dispatches to the Pallas kernel

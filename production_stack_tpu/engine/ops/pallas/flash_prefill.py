@@ -40,8 +40,8 @@ and emits zeros.
 Layout notes (Mosaic): blocks keep the (head, lane) dims whole — q tiles
 are [Tq, H, D], kv tiles [Tk, K, D] — because Mosaic requires the last
 two block dims divisible by (8, 128) or equal to the array's.  GQA
-regrouping happens in-register via the same swapaxes/reshape moves the
-decode kernel uses (paged_attention.py:114-115); both matmuls are
+regrouping happens in-register via swapaxes/reshape moves (the decode
+kernel spares them: it has G rows a head, not Tq*G); both matmuls are
 K-batched dot_generals contracting the lane dim, so no transposes are
 materialized.  The softmax running max/normalizer are stored broadcast
 across the 128-lane dim (scratch must be lane-tiled anyway) and read

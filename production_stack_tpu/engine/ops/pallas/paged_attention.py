@@ -6,19 +6,32 @@ KV block of its sequence once.  The pure-JAX gather path
 (gather-read, materialize-write, attention-read) and always over the full
 ``Bmax``-padded block table.  This kernel streams each sequence's actual
 blocks HBM->VMEM exactly once with double-buffered async DMA and an online
-softmax, and its per-sequence loop bound is the *real* context length, so a
-256-token sequence in an 8k-token pool touches 16 blocks, not 512.
+softmax, and its loop bound is the *real* context length, so a 256-token
+sequence in an 8k-token pool touches 16 blocks, not 512.
 
-Blocks are fetched in chunks of ``chunk_blocks`` per pipeline stage: one
-16-token block is too small to amortize DMA issue latency or fill the MXU,
-so each stage issues ``chunk_blocks`` parallel block DMAs (their latencies
-overlap in the DMA engine) and runs one online-softmax update over the
-whole ``chunk_blocks * block_size``-token tile.
+Blocks are fetched in stages of ``CHUNK_BLOCKS``: one 16-token block is
+too small to amortize DMA issue latency or fill the MXU, so each stage
+issues that many parallel block DMAs per side and runs one online-softmax
+update over the whole ``CHUNK_BLOCKS * block_size``-token tile.
 
-Grid: one program per sequence.  The block table and context lengths ride
-in SMEM via scalar prefetch so DMA source indices are computable before the
-body runs.  Accumulation is fp32 (softmax on the VPU, score/value matmuls
-on the MXU).
+One program walks every (row, stage) pair of the batch in order, and
+while a stage computes, the walk's next stage is in flight into the other
+buffer -- the next stage of the same row, or the first stage of the next
+live row, so the DMA stream does not stop between rows and padded rows
+(ctx 0) start and wait nothing.  The block table and context lengths
+ride in SMEM via scalar prefetch so DMA source indices are computable
+before the data arrives.
+
+A stage's tiles go to the MXU as they lie in the cache: ``[T, K, D]`` read
+as ``[T*K, D]`` against all ``H`` query heads at once, the scores of a
+head under another KV head's keys masked like positions past the context
+(their probabilities are exact zeros), which costs the MXU ``K`` times the
+useful products, far under its rate, and spares the VPU the
+``[T, K, D] -> [K, T, D]`` relayout of every tile.  Both dots take the
+cache's own dtype (queries and probabilities in it too, as the gather
+path rounds them) and accumulate fp32; scores, softmax statistics and the
+output accumulator are fp32.  An int8 (data, scale) cache is dequantized
+in VMEM by an fp32 multiply and its dots take the queries' dtype.
 
 Replaces the role CUDA PagedAttention kernels play inside the reference's
 external vLLM engine (the reference itself ships no kernels — SURVEY.md
@@ -36,21 +49,24 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+# Blocks a pipeline stage fetches per side.  16 x 16 tokens = 1 MiB of K
+# and V in flight behind the stage that computes, which is what keeps the
+# DMA engine at its own rate on a v5e (8 left it waiting on the compute:
+# tools/paged_decode_microbench.py); VMEM: 2 x CHUNK_BLOCKS x 32 kB a side.
+CHUNK_BLOCKS = 16
 
 
 def _decode_kernel(
     # scalar prefetch (SMEM)
     block_tables_ref,  # [S, Bmax] int32
     ctx_lens_ref,  # [S] int32
-    # inputs: q_ref, k_hbm, v_hbm[, ks_hbm, vs_hbm] (int8 cache scales)
-    # outputs: o_ref
+    # inputs: q_ref [S, H, D], k_hbm, v_hbm[, ks_hbm, vs_hbm] (int8 scales)
+    # outputs: o_ref [S, H, D]
     # scratch: k_buf, v_buf[, ks_buf, vs_buf], sems
     *refs,
     bs: int,
     chunk_blocks: int,
     num_kv_heads: int,
-    q_per_kv: int,
-    head_dim: int,
     scale: float,
     sliding_window: Optional[int],
     quantized: bool,
@@ -61,103 +77,123 @@ def _decode_kernel(
     else:
         q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems = refs
         ks_hbm = vs_hbm = ks_buf = vs_buf = None
-    s = pl.program_id(0)
-    ctx = ctx_lens_ref[s]
-    nb = (ctx + bs - 1) // bs  # live KV blocks for this sequence
-    C = chunk_blocks
-    nc = (nb + C - 1) // C  # dynamic trip count: only live chunks
-    K, G, D = num_kv_heads, q_per_kv, head_dim
+    S, H, D = q_ref.shape
+    C, K = chunk_blocks, num_kv_heads
+    G = H // K  # query heads a KV head (GQA)
     T = C * bs  # tokens per pipeline stage
-
-    # fp32 query, pre-scaled; head h = k*G + g attends kv head k (GQA).
-    q = (q_ref[0].reshape(K, G, D).astype(jnp.float32)) * scale
-
-    def block_id(j):
-        # Chunk-tail blocks past nb read table slot 0 (the null block) —
-        # a valid, masked-out DMA source (tables are 0-padded).
-        return block_tables_ref[s, jnp.minimum(j, nb - 1) * (j < nb)]
-
-    def dma(cache, buf, kv, slot, c, j):
-        return pltpu.make_async_copy(
-            cache.at[block_id(j)], buf.at[slot, c], sems.at[kv, slot, c]
-        )
+    # What both dots take: the cache's dtype, or the queries' once an int8
+    # cache is dequantized.
+    operand_dtype = q_ref.dtype if quantized else k_buf.dtype
 
     streams = [(k_hbm, k_buf, 0), (v_hbm, v_buf, 1)]
     if quantized:
         # Scale planes ride the same pipeline (tiny: [bs, K] fp32/block).
         streams += [(ks_hbm, ks_buf, 2), (vs_hbm, vs_buf, 3)]
 
-    def start_chunk(slot, chunk):
-        for c in range(C):  # static unroll: C parallel DMA issues
-            for cache, buf, kv in streams:
-                dma(cache, buf, kv, slot, c, chunk * C + c).start()
+    def next_live(r):
+        """First row at or after ``r`` with a context; S when none."""
+        return jax.lax.while_loop(
+            lambda r: (r < S) & (ctx_lens_ref[jnp.minimum(r, S - 1)] == 0),
+            lambda r: r + 1, r)
 
-    def wait_chunk(slot, chunk):
+    def start_stage(slot, s, stage):
+        nb = (ctx_lens_ref[s] + bs - 1) // bs  # live KV blocks of the row
+        for c in range(C):  # static unroll: C parallel DMA issues a side
+            j = stage * C + c
+            # Stage-tail blocks past nb read the row's first block: a
+            # valid DMA source whose positions the mask drops.
+            block = block_tables_ref[s, jnp.where(j < nb, j, 0)]
+            for cache, buf, kv in streams:
+                pltpu.make_async_copy(
+                    cache.at[block], buf.at[slot, c], sems.at[kv, slot, c]
+                ).start()
+
+    def wait_stage(slot):
         for c in range(C):
             for cache, buf, kv in streams:
-                dma(cache, buf, kv, slot, c, chunk * C + c).wait()
+                # A wait counts the destination's bytes; its source is
+                # only a shape.
+                pltpu.make_async_copy(
+                    cache.at[0], buf.at[slot, c], sems.at[kv, slot, c]
+                ).wait()
 
-    # Padded batch slots (ctx == 0) must not start DMAs: an un-waited DMA
-    # leaves its semaphore signaled and poisons the next grid step's waits.
-    @pl.when(nc > 0)
+    # Every stage the walk visits is started once, by the stage before it
+    # (the first one here), and waited once: a DMA nobody waits for would
+    # leave its semaphore signalled for the next call's waits.
+    first = next_live(0)
+
+    @pl.when(first < S)
     def _():
-        start_chunk(0, 0)
+        start_stage(0, first, 0)
 
-    def body(i, carry):
-        m, l, acc = carry
-        slot = jax.lax.rem(i, 2)
-        nxt = jax.lax.rem(i + 1, 2)
+    # Column t*K + k of a stage's scores is position t under KV head k;
+    # query head h = k*G + g attends KV head k (GQA).
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, T * K), 1)
+    tok = col // K
+    own_head = col % K == jax.lax.broadcasted_iota(jnp.int32, (H, 1), 0) // G
 
-        @pl.when(i + 1 < nc)
-        def _():
-            start_chunk(nxt, i + 1)
+    def row(s, slot0):
+        ctx = ctx_lens_ref[s]
+        nc = (ctx + T - 1) // T  # dynamic trip count: only live stages
+        following = next_live(s + 1)
+        q = q_ref[s].astype(operand_dtype)  # [H, D]
 
-        wait_chunk(slot, i)
-        # [C, bs, K, D] -> [K, T, D] (Mosaic needs lhs/rhs batch dims in
-        # matching positions, so the kv-head axis moves to the front;
-        # merging the leading dims is layout-free, D stays the lane dim).
-        k = k_buf[slot].astype(jnp.float32).reshape(T, K, D).swapaxes(0, 1)
-        v = v_buf[slot].astype(jnp.float32).reshape(T, K, D).swapaxes(0, 1)
-        if quantized:
-            # Per-(token, head) scales: [C, bs, K] -> [K, T, 1].
-            ks = ks_buf[slot].astype(jnp.float32).reshape(T, K) \
-                .swapaxes(0, 1)[..., None]
-            vs = vs_buf[slot].astype(jnp.float32).reshape(T, K) \
-                .swapaxes(0, 1)[..., None]
-            k = k * ks
-            v = v * vs
+        def stage(i, carry):
+            m, l, acc = carry
+            slot = jax.lax.rem(slot0 + i, 2)
+            last = i + 1 == nc
+            ahead = jnp.where(last, following, s)
 
-        # [K, G, D] x [K, T, D] -> [K, G, T]  (batch over kv heads)
-        scores = jax.lax.dot_general(
-            q, k, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )
-        pos = i * T + jax.lax.broadcasted_iota(jnp.int32, (1, 1, T), 2)
-        mask = pos < ctx
-        if sliding_window is not None:
-            mask &= pos > ctx - 1 - sliding_window
-        scores = jnp.where(mask, scores, NEG_INF)
+            @pl.when(ahead < S)
+            def _():
+                start_stage(1 - slot, ahead, jnp.where(last, 0, i + 1))
 
-        m_new = jnp.maximum(m, jnp.max(scores, axis=-1, keepdims=True))
-        alpha = jnp.exp(m - m_new)
-        p = jnp.exp(scores - m_new)
-        l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        # [K, G, T] x [K, T, D] -> [K, G, D]
-        pv = jax.lax.dot_general(
-            p, v, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )
-        return m_new, l_new, acc * alpha + pv
+            wait_stage(slot)
+            # [C, bs, K, D] -> [T*K, D]: merging leading dims into the
+            # sublane dim is layout-free, D stays the lane dim.
+            k = k_buf[slot].reshape(T * K, D)
+            v = v_buf[slot].reshape(T * K, D)
+            if quantized:
+                # Per-(token, head) scales: [C, bs, K] -> [T*K, 1].
+                k = (k.astype(jnp.float32) * ks_buf[slot].astype(
+                    jnp.float32).reshape(T * K, 1)).astype(operand_dtype)
+                v = (v.astype(jnp.float32) * vs_buf[slot].astype(
+                    jnp.float32).reshape(T * K, 1)).astype(operand_dtype)
 
-    m0 = jnp.full((K, G, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((K, G, 1), jnp.float32)
-    acc0 = jnp.zeros((K, G, D), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(0, nc, body, (m0, l0, acc0))
+            # [H, D] x [T*K, D] -> [H, T*K]
+            scores = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale
+            pos = i * T + tok
+            mask = own_head & (pos < ctx)
+            if sliding_window is not None:
+                mask &= pos > ctx - 1 - sliding_window
+            scores = jnp.where(mask, scores, NEG_INF)
 
-    # Padded batch slots have ctx==0 -> l==0; emit zeros, not NaNs (their
-    # logits are sliced off on the host, but NaN-free keeps debugging sane).
-    l = jnp.where(l == 0.0, 1.0, l)
-    o_ref[0] = (acc / l).reshape(K * G, D).astype(o_ref.dtype)
+            m_new = jnp.maximum(m, jnp.max(scores, axis=-1, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(scores - m_new)
+            l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            # [H, T*K] x [T*K, D] -> [H, D]
+            pv = jax.lax.dot_general(
+                p.astype(operand_dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            return m_new, l_new, acc * alpha + pv
+
+        m0 = jnp.full((H, 1), NEG_INF, jnp.float32)
+        l0 = jnp.zeros((H, 1), jnp.float32)
+        acc0 = jnp.zeros((H, D), jnp.float32)
+        m, l, acc = jax.lax.fori_loop(0, nc, stage, (m0, l0, acc0))
+
+        # Padded batch slots have ctx==0 -> l==0; emit zeros, not NaNs (their
+        # logits are sliced off on the host, but NaN-free keeps debugging sane).
+        l = jnp.where(l == 0.0, 1.0, l)
+        o_ref[s] = (acc / l).astype(o_ref.dtype)
+        return jax.lax.rem(slot0 + nc, 2)
+
+    jax.lax.fori_loop(0, S, row, 0)
 
 
 @functools.partial(
@@ -173,7 +209,7 @@ def paged_decode_attention_pallas(
     *,
     scale: float,
     sliding_window: Optional[int] = None,
-    chunk_blocks: int = 8,
+    chunk_blocks: int = CHUNK_BLOCKS,
     interpret: bool = False,
 ) -> jax.Array:
     """Decode attention over paged KV, streaming blocks HBM->VMEM.
@@ -189,7 +225,6 @@ def paged_decode_attention_pallas(
     quantized = kv_quant.is_quantized(k_cache)
     S, H, D = q.shape
     N, bs, K, _ = kv_quant.cache_shape(k_cache)
-    G = H // K
     C = min(chunk_blocks, block_tables.shape[1])
     if D % 128 and not interpret:
         # The DMA slice needs a 128-lane-aligned head_dim on real TPU;
@@ -202,8 +237,6 @@ def paged_decode_attention_pallas(
         bs=bs,
         chunk_blocks=C,
         num_kv_heads=K,
-        q_per_kv=G,
-        head_dim=D,
         scale=scale,
         sliding_window=sliding_window,
         quantized=quantized,
@@ -225,12 +258,12 @@ def paged_decode_attention_pallas(
     scratch.append(pltpu.SemaphoreType.DMA((4 if quantized else 2, 2, C)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(S,),
+        grid=(1,),  # one program walks the whole batch
         in_specs=[
-            pl.BlockSpec((1, H, D), lambda s, *_: (s, 0, 0)),
+            pl.BlockSpec((S, H, D), lambda i, *_: (0, 0, 0)),
             *cache_in_specs,  # caches (+ scale planes) stay in HBM
         ],
-        out_specs=pl.BlockSpec((1, H, D), lambda s, *_: (s, 0, 0)),
+        out_specs=pl.BlockSpec((S, H, D), lambda i, *_: (0, 0, 0)),
         scratch_shapes=scratch,
     )
     inputs = (

@@ -8,6 +8,7 @@ backends (ops/attention.py:decode_attention dispatch).
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 from production_stack_tpu.engine.ops.attention import paged_decode_attention
 from production_stack_tpu.engine.ops.pallas.paged_attention import (
@@ -32,49 +33,128 @@ def _random_paged_case(
     return q, k_cache, v_cache, jnp.asarray(tables), jnp.asarray(ctx_lens, jnp.int32)
 
 
+# Reading a stage that was never waited for shows as NaN (buffers start
+# as NaN and a DMA lands at its wait) instead of as the right numbers the
+# plain interpreter's copy-at-start leaves there.
+TPU_INTERPRET = pltpu.InterpretParams()
+
+
+def _live(ctx):
+    # Padded slots: kernel emits zeros, gather emits garbage-but-finite;
+    # compare only live rows.
+    return np.asarray(ctx) > 0
+
+
 @pytest.mark.parametrize(
     "ctx_lens",
     [
         [1, 16, 17, 33],  # block-boundary edges
         [64, 3, 0, 0],  # padded slots (ctx 0) must not poison anything
         [40, 40, 40, 40],
+        # Several stages a row (a stage is 4 blocks = 64 tokens here), with
+        # the next row's first stage fetched under this row's last one:
+        # padded rows between and before live ones, a padded last row, a
+        # single live row, none at all.
+        [0, 300, 0, 129],
+        [257, 0, 0, 1],
+        [300, 65, 128, 0],
+        [0, 0, 200, 0],
+        [0, 0, 0, 0],
     ],
 )
-def test_pallas_decode_matches_gather(ctx_lens):
+@pytest.mark.parametrize("interpret", [True, TPU_INTERPRET],
+                         ids=["interpret", "tpu-interpret"])
+def test_pallas_decode_matches_gather(ctx_lens, interpret):
     S, H, K, D, bs = 4, 8, 2, 64, 16
     q, k_cache, v_cache, tables, ctx = _random_paged_case(
-        0, S, H, K, D, bs, num_blocks=64, max_blocks=8, ctx_lens=ctx_lens
+        0, S, H, K, D, bs, num_blocks=64, max_blocks=24, ctx_lens=ctx_lens
     )
     scale = D**-0.5
     want = paged_decode_attention(
         q, k_cache, v_cache, tables, ctx, scale=scale
     )
     got = paged_decode_attention_pallas(
-        q, k_cache, v_cache, tables, ctx, scale=scale, interpret=True
+        q, k_cache, v_cache, tables, ctx, scale=scale, chunk_blocks=4,
+        interpret=interpret,
     )
-    # Padded slots: kernel emits zeros, gather emits garbage-but-finite;
-    # compare only live rows.
-    live = np.asarray(ctx) > 0
+    live = _live(ctx)
     np.testing.assert_allclose(
         np.asarray(got)[live], np.asarray(want)[live], rtol=2e-5, atol=2e-5
     )
     assert np.all(np.isfinite(np.asarray(got)))
 
 
-def test_pallas_decode_sliding_window():
-    S, H, K, D, bs = 2, 4, 2, 32, 8
+@pytest.mark.parametrize(
+    "ctx_lens,window",
+    [
+        ([1, 16, 17, 33], None),
+        ([0, 300, 0, 129], None),
+        ([257, 0, 0, 1], 100),
+        ([300, 65, 128, 0], 70),
+    ],
+)
+def test_pallas_decode_bf16_cache_matches_gather(ctx_lens, window):
+    """A bf16 cache goes to the MXU as it is stored, and so do the queries
+    and the probabilities (both paths round ``p`` to bf16 before the
+    second dot); scores and accumulators are fp32 on both.  What differs
+    is where ``p`` is rounded -- the gather path rounds ``exp(s - max) /
+    sum`` over the whole context, the kernel ``exp(s - running max)`` a
+    stage at a time and divides at the end -- and then each rounds its
+    output to bf16, so they agree to an ulp of that output: 2^-8 relative.
+    The tolerance is twice that, 2^-7 relative plus 2^-7 absolute (outputs
+    are 0.1-3 here; six seeds read at most half of it), an order under
+    what a wrong mask or a stale stage reads (0.1-1)."""
+    S, H, K, D, bs = 4, 8, 2, 64, 16
     q, k_cache, v_cache, tables, ctx = _random_paged_case(
-        1, S, H, K, D, bs, num_blocks=32, max_blocks=8, ctx_lens=[50, 23]
+        2, S, H, K, D, bs, num_blocks=64, max_blocks=24, ctx_lens=ctx_lens,
+        dtype=jnp.bfloat16,
     )
     scale = D**-0.5
     want = paged_decode_attention(
-        q, k_cache, v_cache, tables, ctx, scale=scale, sliding_window=16
+        q, k_cache, v_cache, tables, ctx, scale=scale, sliding_window=window
     )
     got = paged_decode_attention_pallas(
-        q, k_cache, v_cache, tables, ctx, scale=scale, sliding_window=16,
-        interpret=True,
+        q, k_cache, v_cache, tables, ctx, scale=scale, sliding_window=window,
+        chunk_blocks=4, interpret=True,
     )
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5)
+    assert got.dtype == jnp.bfloat16
+    live = _live(ctx)
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32)[live], np.asarray(want, np.float32)[live],
+        rtol=2**-7, atol=2**-7,
+    )
+    assert np.all(np.isfinite(np.asarray(got, np.float32)))
+
+
+@pytest.mark.parametrize(
+    "ctx_lens,window,chunk_blocks",
+    [
+        ([50, 23], 16, 16),  # the window inside one stage
+        # A window shorter than the context across more than two stages
+        # (2 blocks = 16 tokens a stage): whole stages behind the window
+        # are all mask, and the running max must recover from them.
+        ([60, 23], 16, 2),
+        ([64, 33], 20, 2),
+        ([0, 61], 7, 2),
+    ],
+)
+def test_pallas_decode_sliding_window(ctx_lens, window, chunk_blocks):
+    S, H, K, D, bs = 2, 4, 2, 32, 8
+    q, k_cache, v_cache, tables, ctx = _random_paged_case(
+        1, S, H, K, D, bs, num_blocks=32, max_blocks=8, ctx_lens=ctx_lens
+    )
+    scale = D**-0.5
+    want = paged_decode_attention(
+        q, k_cache, v_cache, tables, ctx, scale=scale, sliding_window=window
+    )
+    got = paged_decode_attention_pallas(
+        q, k_cache, v_cache, tables, ctx, scale=scale, sliding_window=window,
+        chunk_blocks=chunk_blocks, interpret=True,
+    )
+    live = _live(ctx)
+    np.testing.assert_allclose(
+        np.asarray(got)[live], np.asarray(want)[live], rtol=2e-5, atol=2e-5
+    )
 
 
 def test_pallas_decode_gqa_head_mapping():
