@@ -18,24 +18,42 @@ rounding.  The driving code is copied from ``chip_smoke.py`` (PR 21), which
 compared kernels with the XLA path, not with a reference.
 
 Every row (the end of each prefill, each decode step of both sequences) has
-to hold ``logits_rtol``.  ``programs`` and ``drive`` are also what
-``tools/flip_rate.py`` drives a routed model with; a row keeps the
-(sequence, index) of each of its positions for it.  A routed configuration's
-top k meets near-ties that the engine's bf16 arithmetic flips, and no rule
-over rows or positions separates those from a fault at real widths
-(PERF.md, PR 34): the compare that will is not here yet (PERF.md, section 7).
+to hold ``logits_rtol``; a row keeps the (sequence, index) of each of its
+positions.  Two things a served module may offer and ``models/llama.py``
+need not:
+
+``init_cache(cfg, num_blocks, block_size, sharding)``: the cache is then the
+module's, handed to its ``prefill`` / ``decode`` as it came; without it, a K
+and a V array a layer, as the engine allocates them.
+
+``return_choice=True`` on ``prefill`` / ``decode``, asked for where the file
+says ``compare.follow_choice``: the call returns ``(logits, cache, choice)``,
+``choice`` int32 [routed layers, rows, k], the experts each row went to, ids
+over the router's published width.  A routed configuration's top k meets
+near-ties that the engine's bf16 arithmetic flips, and a flipped position
+reads another model's error (PERF.md, PR 34), so the reference follows the
+engine's choice at every position of both sequences
+(``forward(params, hp, tokens, choice, rows) -> (logits, shortfall)``), every
+row holds ``logits_rtol`` as a dense file's does, and the choice itself is
+held by ``compare.choice_shortfall``: how far, at the worst, its weakest expert
+lay below the reference's own k-th, in the reference's measure.  The logits
+of the first prefill with and without ``return_choice`` have to be bit-equal.
+``tools/flip_rate.py`` drives ``run`` and reads its ``detail``.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import importlib
+import inspect
 import os
 import sys
-from typing import Dict, List, Tuple
+from typing import Dict, List, Optional, Tuple
 
 from harness.sizes import held
 
+# The llama keys, for a file that names none: a file of another architecture,
+# or one whose module keeps a cache of its own shape, gives ``preset_keys``.
 PRESET_KEYS = {
     "hidden_size": "hidden_size", "num_attention_heads": "num_heads",
     "num_key_value_heads": "num_kv_heads",
@@ -45,28 +63,60 @@ PRESET_KEYS = {
 BLOCK, CHUNK = 16, 256   # tokens a KV block, slots a prefill chunk
 
 
-def programs(model, cfg, mesh=None):
+def check_file(config: Dict) -> None:
+    """What can be refused from the file alone, before anything runs."""
+    spec = config.get("compare") or {}
+    if spec.get("follow_choice") and "choice_shortfall" not in spec:
+        raise SystemExit(
+            f"bench: configuration {config.get('name')!r} says "
+            f"compare.follow_choice and gives no compare.choice_shortfall: "
+            f"a choice that is followed has to be held by a limit")
+
+
+def programs(model, cfg, mesh=None, follow: bool = False):
     """``prefill`` and ``decode`` of the served module, jitted as the
-    compare drives them."""
+    compare drives them; with ``follow`` each also returns its choice."""
     import jax
 
+    more = {"return_choice": True} if follow else {}
     prefill = jax.jit(
         lambda p, t, c, pre, new, v, kv: model.prefill(
-            p, cfg, t, c, pre, new, v, kv, mesh=mesh),
+            p, cfg, t, c, pre, new, v, kv, mesh=mesh, **more),
         donate_argnums=(6,))
     decode = jax.jit(
         lambda p, t, pos, bt, cl, sb, so, kv: model.decode(
-            p, cfg, t, pos, bt, cl, sb, so, kv, mesh=mesh),
+            p, cfg, t, pos, bt, cl, sb, so, kv, mesh=mesh, **more),
         donate_argnums=(7,))
     return prefill, decode
 
 
-def drive(prefill, decode, params, cfg, lens, steps: int, seed: int,
-          kv_sharding=None):
-    """The engine's side.  Returns the two whole sequences (prompt, then the
-    tokens fed to the decode steps) and a list of rows ``(label, where,
-    logits)``: ``where`` is one (sequence, index) a row of ``logits``."""
+def cache_of(model, cfg, sharding=None):
+    """``make(num_blocks, block_size)``: the module's own cache where it
+    makes one, else a K and a V array a layer."""
     import jax
+    import jax.numpy as jnp
+
+    if hasattr(model, "init_cache"):
+        return lambda blocks, bs: model.init_cache(cfg, blocks, bs, sharding)
+
+    def pair_a_layer(blocks, bs):
+        zeros = jax.jit(
+            lambda: jnp.zeros((blocks, bs, cfg.num_kv_heads, cfg.head_dim),
+                              cfg.dtype), out_shardings=sharding)
+        return [(zeros(), zeros()) for _ in range(cfg.num_layers)]
+
+    return pair_a_layer
+
+
+def drive(prefill, decode, params, vocab: int, lens, steps: int, seed: int,
+          make_cache, plain_prefill=None):
+    """The engine's side.  Returns the two whole sequences (prompt, then the
+    tokens fed to the decode steps), a list of rows ``(label, where,
+    logits)``, ``where`` one (sequence, index) a row of ``logits``, and what
+    following found: None, or with ``plain_prefill`` (the programs then
+    return their choice) ``{"choice": [a sequence: int32 [layers, its
+    positions, k]], "plain_differs": elements of the first prefill's logits
+    that differ from the plain program's}``."""
     import jax.numpy as jnp
     import numpy as np
 
@@ -77,18 +127,25 @@ def drive(prefill, decode, params, cfg, lens, steps: int, seed: int,
     while per * BLOCK < max(len_a, len_b) + steps:
         per *= 2
     bs, num_blocks, bmax, T = BLOCK, 3 * per, 2 * per, CHUNK
-    kv_shape = (num_blocks, bs, cfg.num_kv_heads, cfg.head_dim)
-    zeros = jax.jit(lambda: jnp.zeros(kv_shape, cfg.dtype),
-                    out_shardings=kv_sharding)
-    kv = [(zeros(), zeros()) for _ in range(cfg.num_layers)]
+    kv = make_cache(num_blocks, bs)
+    choice = [None, None]   # a sequence: [layers, its positions, k]
+    plain_differs = None
+
+    def note(who, seq, positions, rows) -> None:
+        who = np.asarray(who)
+        if choice[seq] is None:
+            choice[seq] = np.full(
+                (who.shape[0], lens[seq] + steps, who.shape[2]), -1, np.int32)
+        choice[seq][:, positions] = who[:, rows]
+
     rng = np.random.default_rng(seed)
-    prompt_a = rng.integers(1, cfg.vocab_size, len_a).astype(np.int32)
-    prompt_b = rng.integers(1, cfg.vocab_size, len_b).astype(np.int32)
-    decode_tokens = rng.integers(1, cfg.vocab_size, (steps, 2)).astype(np.int32)
+    prompt_a = rng.integers(1, vocab, len_a).astype(np.int32)
+    prompt_b = rng.integers(1, vocab, len_b).astype(np.int32)
+    decode_tokens = rng.integers(1, vocab, (steps, 2)).astype(np.int32)
     blocks_a = np.arange(1, 1 + per, dtype=np.int32)
     blocks_b = np.arange(per + 8, 2 * per + 8, dtype=np.int32)
 
-    def run_prefill(prompt, start, blocks, kv):
+    def prefill_args(prompt, start, blocks, kv):
         chunk = prompt[start:start + T]
         tokens = np.zeros((T,), np.int32)
         tokens[:len(chunk)] = chunk
@@ -97,15 +154,23 @@ def drive(prefill, decode, params, cfg, lens, steps: int, seed: int,
         new = np.zeros((T // bs,), np.int32)
         n_new = -(-len(chunk) // bs)
         new[:n_new] = blocks[start // bs:start // bs + n_new]
-        return prefill(params, jnp.asarray(tokens), jnp.int32(start),
-                       jnp.asarray(prefix), jnp.asarray(new),
-                       jnp.int32(len(chunk)), kv)
+        return (params, jnp.asarray(tokens), jnp.int32(start),
+                jnp.asarray(prefix), jnp.asarray(new), jnp.int32(len(chunk)),
+                kv), len(chunk)
 
     got = []
     for seq, (prompt, blocks) in enumerate(
             ((prompt_a, blocks_a), (prompt_b, blocks_b))):
         for start in range(0, len(prompt), T):
-            out, kv = run_prefill(prompt, start, blocks, kv)
+            args, n = prefill_args(prompt, start, blocks, kv)
+            if plain_prefill is not None and plain_differs is None:
+                plain = np.asarray(plain_prefill(
+                    *args[:-1], make_cache(num_blocks, bs))[0])
+            out, kv, *who = prefill(*args)
+            if who:
+                note(who[0], seq, slice(start, start + n), slice(0, n))
+                if plain_differs is None:
+                    plain_differs = int((plain != np.asarray(out)).sum())
         label = f"prefill of {len(prompt)} tokens, " + (
             f"{start} cached" if start else "no prefix")
         if any(label == other for other, _w, _l in got):
@@ -118,26 +183,44 @@ def drive(prefill, decode, params, cfg, lens, steps: int, seed: int,
     for step in range(steps):
         ctx = ctx + 1
         pos = ctx - 1
-        out, kv = decode(
+        out, kv, *who = decode(
             params, jnp.asarray(decode_tokens[step]), jnp.asarray(pos),
             jnp.asarray(tables), jnp.asarray(ctx),
             jnp.asarray(tables[np.arange(2), pos // bs]),
             jnp.asarray(pos % bs), kv)
+        for seq in range(2 if who else 0):
+            note(who[0], seq, int(pos[seq]), seq)
         got.append((f"decode step {step}", [(0, int(pos[0])), (1, int(pos[1]))],
                     np.asarray(out, np.float32)))
     return (np.concatenate([prompt_a, decode_tokens[:, 0]]),
-            np.concatenate([prompt_b, decode_tokens[:, 1]])), got
+            np.concatenate([prompt_b, decode_tokens[:, 1]])), got, (
+        {"choice": choice, "plain_differs": plain_differs}
+        if plain_prefill is not None else None)
 
 
-def reference_rows(forward, seqs, got):
+def reference_rows(forward, seqs, got, choice=None):
     """The reference's logits for the rows of ``got``: each whole sequence
     in one pass, ``forward(tokens) -> [len(tokens), vocab]``.  Attention is
-    causal, so the logits at a position do not depend on what follows it."""
+    causal, so the logits at a position do not depend on what follows it.
+    With ``choice`` (a sequence's [layers, positions, k]) the pass follows it,
+    ``forward(tokens, choice, rows) -> ([len(rows), vocab], shortfall
+    [layers, positions])`` for the compared positions ``rows`` alone, and the
+    shortfalls come back too, a sequence after another along the positions."""
     import numpy as np
 
-    ref = [np.asarray(forward(tokens)) for tokens in seqs]
+    if choice is None:
+        ref = [np.asarray(forward(tokens)) for tokens in seqs]
+        return [np.stack([ref[s][i] for s, i in where])
+                for _name, where, _logits in got]
+    ref, shortfall = [], []
+    for seq, tokens in enumerate(seqs):
+        rows = sorted({i for _n, where, _l in got for s, i in where
+                       if s == seq})
+        logits, short = forward(tokens, choice[seq], np.asarray(rows, np.int32))
+        ref.append(dict(zip(rows, np.asarray(logits))))
+        shortfall.append(np.asarray(short))
     return [np.stack([ref[s][i] for s, i in where])
-            for _name, where, _logits in got]
+            for _name, where, _logits in got], np.concatenate(shortfall, 1)
 
 
 def error(a, b) -> float:
@@ -163,9 +246,38 @@ def score(got, want, rtol: float):
     return ok, notes, rows
 
 
-def run(config: Dict, chips: int, seed: int, platform: str,
-        env_root: str) -> Tuple[bool, List[str], Dict[str, List[float]]]:
-    """(ok, one note a compared row, {row: [number, limit]})."""
+def score_choice(followed, shortfall, limit: float):
+    """(ok, notes, {entry: [number, limit]}) for a choice that was followed:
+    the largest shortfall over every routed layer and every position of both
+    sequences against the file's limit, and the first prefill's logits with
+    and without ``return_choice``, which have to be bit-equal.  The share of
+    positions where the reference alone would have chosen otherwise (a
+    shortfall over 0 in some layer) is said and judged by nothing."""
+    import numpy as np
+
+    worst, differs = float(np.max(shortfall)), followed["plain_differs"]
+    fine = worst <= limit   # false for nan too
+    flipped = (shortfall > 0).any(0)
+    notes = [
+        f"choice: the weakest expert handed in lies at most {worst:.3e} "
+        f"below the reference's own k-th {'<=' if fine else '>'} {limit}",
+        f"choice: the reference alone would have chosen otherwise at "
+        f"{int(flipped.sum())} of {flipped.size} positions "
+        f"({100 * flipped.mean():.2f} %; by layer "
+        f"{[int(n) for n in (shortfall > 0).sum(1)]}); judged by nothing",
+        f"choice: {differs} logits of the first prefill differ from the "
+        f"program without return_choice; limit 0"]
+    return (fine and differs == 0, notes,
+            {"choice_shortfall": [worst, limit],
+             "return_choice_logits_differ": [differs, 0]})
+
+
+def run(config: Dict, chips: int, seed: int, platform: str, env_root: str,
+        detail: Optional[Dict] = None,
+        ) -> Tuple[bool, List[str], Dict[str, List[float]]]:
+    """(ok, one note a compared row, {row: [number, limit]}).  ``detail``,
+    where a dict is handed in, keeps what the numbers were made from (the
+    builder's tool reads it)."""
     sys.path.insert(0, env_root)
     if platform == "cpu":
         for k, v in config.get("rehearsal_env", {}).items():
@@ -182,6 +294,7 @@ def run(config: Dict, chips: int, seed: int, platform: str,
     from production_stack_tpu.engine.parallel import shardings as sh
     from production_stack_tpu.engine.parallel.mesh import build_mesh
 
+    check_file(config)
     spec = config["compare"]
     if jax.default_backend() != platform or len(jax.devices()) < chips:
         return False, [f"the parent's JAX sees {jax.devices()}"], {}
@@ -191,12 +304,19 @@ def run(config: Dict, chips: int, seed: int, platform: str,
     model = get_model(cfg.name)
     reference = importlib.import_module("reference." + spec["reference"])
     hp = held(config)
-    hp.setdefault("head_dim", cfg.head_dim)
+    if not hasattr(model, "init_cache"):
+        hp.setdefault("head_dim", cfg.head_dim)
     for ours, theirs in spec.get("preset_keys", PRESET_KEYS).items():
         if hp.get(ours) != getattr(cfg, theirs):
             return False, [f"preset {config['model']} has {theirs}="
                            f"{getattr(cfg, theirs)}, the configuration's "
                            f"file {ours}={hp.get(ours)}"], {}
+    follow = bool(spec.get("follow_choice"))
+    for step in (model.prefill, model.decode) if follow else ():
+        if "return_choice" not in inspect.signature(step).parameters:
+            return False, [f"the file says compare.follow_choice and "
+                           f"{model.__name__}.{step.__name__} takes no "
+                           f"return_choice"], {}
 
     mesh = None
     shardings = kv_sharding = None
@@ -211,11 +331,30 @@ def run(config: Dict, chips: int, seed: int, platform: str,
     if mesh is None:
         params = model.quantize_params(params, cfg)
 
-    seqs, got = drive(
-        *programs(model, cfg, mesh), params, cfg,
+    seqs, got, followed = drive(
+        *programs(model, cfg, mesh, follow), params, cfg.vocab_size,
         spec.get("prompt_tokens", [300, 100]), spec.get("decode_steps", 2),
-        seed, kv_sharding)
-    fwd = jax.jit(lambda p, t: reference.forward(p, hp, t))
-    want = reference_rows(lambda tokens: fwd(params, jnp.asarray(tokens)),
-                          seqs, got)
-    return score(got, want, spec["logits_rtol"])
+        seed, cache_of(model, cfg, kv_sharding),
+        programs(model, cfg, mesh)[0] if follow else None)
+    if not follow:
+        fwd = jax.jit(lambda p, t: reference.forward(p, hp, t))
+        want = reference_rows(lambda tokens: fwd(params, jnp.asarray(tokens)),
+                              seqs, got)
+    else:
+        fwd = jax.jit(lambda p, t, c, r: reference.forward(
+            p, hp, t, choice=c, rows=r))
+        want, shortfall = reference_rows(
+            lambda tokens, choice, rows: fwd(
+                params, jnp.asarray(tokens), jnp.asarray(choice),
+                jnp.asarray(rows)),
+            seqs, got, followed["choice"])
+    ok, notes, rows = score(got, want, spec["logits_rtol"])
+    if follow:
+        held_ok, more, entries = score_choice(
+            followed, shortfall, spec["choice_shortfall"])
+        ok, notes, rows = ok and held_ok, notes + more, {**rows, **entries}
+        if detail is not None:
+            detail.update(followed, shortfall=shortfall)
+    if detail is not None:
+        detail.update(seqs=seqs, got=got, want=want)
+    return ok, notes, rows

@@ -47,6 +47,29 @@ def tpot_s(rec) -> Optional[float]:
     return (rec.last - rec.first) / (rec.completion_tokens - 1)
 
 
+def tail_mean(values: Sequence[float], share: float) -> float:
+    """The mean of the slowest ``share`` of ``values``; the value at the
+    edge counts by the fraction of it that lies inside, so that one request
+    more or fewer moves the result smoothly."""
+    xs = sorted(values, reverse=True)
+    if not xs:
+        raise ValueError("tail mean of no values")
+    want = len(xs) * share
+    whole = min(len(xs), math.floor(want))
+    total = sum(xs[:whole])
+    if whole < len(xs):
+        total += xs[whole] * (want - whole)
+    return total / want
+
+
+def starts_a_user(rec) -> bool:
+    """A session's first request by a user born after the cache was seeded
+    (``generators/sessions.py``: ``<seat>.<born>`` at round 0): the whole
+    history is new behind the shared system prompt."""
+    user = str(rec.meta.get("user", ""))
+    return rec.meta.get("round") == 0 and not user.endswith(".0")
+
+
 def summarize(records: List, t0: float, seconds: float,
               drain_s: float) -> Dict:
     """End-to-end numbers of one window [t0, t0 + seconds).
@@ -92,7 +115,12 @@ def summarize(records: List, t0: float, seconds: float,
     if ttft:
         m["ttft_mean_ms"] = sum(ttft) / len(ttft)
         m["ttft_p50_ms"] = percentile(ttft, 50)
+        m["ttft_p90_ms"] = percentile(ttft, 90)
         m["ttft_p95_ms"] = percentile(ttft, 95)
+        m["ttft_slow10_mean_ms"] = tail_mean(ttft, 0.1)
+    new_users = [(r.first - r.due) * 1e3 for r in ok if starts_a_user(r)]
+    if new_users:
+        m["ttft_new_user_p50_ms"] = percentile(new_users, 50)
     if tpot:
         m["tpot_p95_ms"] = percentile(tpot, 95)
     m["out_tok_s"] = sum(r.completion_tokens for r in finished_inside) / seconds

@@ -32,7 +32,7 @@ sys.path.insert(0, BENCH)
 
 from harness.children import Child, free_port  # noqa: E402
 from harness.client import Client  # noqa: E402
-from harness import layers, scrape  # noqa: E402
+from harness import compare, layers, scrape  # noqa: E402
 from harness.hostmon import LoopLag  # noqa: E402
 from reduce import stats  # noqa: E402
 
@@ -277,6 +277,7 @@ def main() -> None:
                   if args.sweep else None)
     bench, cell, config, traffic, cell_params, dirs = resolve(
         args.benchmark, args.workload)
+    compare.check_file(config)
 
     # No TPU is a failure.  The one exception is an explicit CPU rehearsal
     # of a configuration marked for it, which prints no timing.
@@ -377,8 +378,6 @@ def main() -> None:
             window_s=trace["window_s"], programs=trace["programs"][:12])
     ctx.trace = trace
 
-    from harness import compare
-
     t = time.monotonic()
     model_ok, compare_notes, compared = compare.run(
         config, cell["chips"], args.seed, platform, env_root=ROOT)
@@ -420,6 +419,7 @@ def main() -> None:
             f.write(json.dumps(dataclasses.asdict(r)) + "\n")
     with open(os.path.join(out_dir, "run.json"), "w") as f:
         json.dump({"result": result, "summary": summary,
+                   "window": {k: got[k] for k in ("t0", "seconds", "drain_s")},
                    "timing": got["timing"], "late_ms": late,
                    "warmup_stretches": got["warmup_stretches"],
                    "generator_lag_ms": got["generator_lag_ms"],

@@ -181,6 +181,48 @@ def test_summary_counts_a_429_a_short_answer_and_an_undrained_request():
     assert s["samples"] == {"ttft": 2, "tpot": 2, "finished_inside": 4}
 
 
+def test_the_tail_mean_and_the_new_users_median_on_hand_made_records(tmp_path):
+    # The slowest tenth of 25 values is two and a half of them.
+    assert stats.tail_mean([float(v) for v in range(1, 26)], 0.1) == (
+        pytest.approx((25 + 24 + 0.5 * 23) / 2.5))
+    assert stats.tail_mean([7.0], 0.1) == pytest.approx(7.0)
+    assert stats.tail_mean([1.0, 3.0], 1.0) == pytest.approx(2.0)
+    # Twenty rounds of 100-119 ms; three of them start a user born in the
+    # window (round 0, not the seat's first user) and read 400, 500, 900.
+    records = []
+    for i in range(20):
+        r = rec(100.0 + 0.4 * i, first=100.1 + 0.401 * i, last=100.3 + 0.4 * i)
+        r.meta = {"user": f"u{i % 4}.0", "round": i % 8}
+        records.append(r)
+    for i, ttft in ((3, 0.4), (9, 0.5), (15, 0.9)):
+        records[i].meta = {"user": f"u{i % 4}.1", "round": 0}
+        records[i].first = records[i].due + ttft
+        records[i].last = records[i].first + 0.2
+    m = stats.summarize(records, 100.0, 10.0, 2.0)["metrics"]
+    assert m["ttft_new_user_p50_ms"] == pytest.approx(500.0)
+    assert m["ttft_slow10_mean_ms"] == pytest.approx((900 + 500) / 2)
+    assert m["ttft_p90_ms"] == pytest.approx(
+        stats.percentile([(r.first - r.due) * 1e3 for r in records], 90))
+    # A seat's first user at round 0 found its history in the seeded cache.
+    assert not stats.starts_a_user(records[0])
+    # The tool tables a statistic from kept records by the same function.
+    sys.path.insert(0, os.path.join(BENCH, "tools"))
+    try:
+        import spread
+    finally:
+        sys.path.pop(0)
+    import dataclasses
+    for name in ("c2-A-1", "c2-A-2", "c2-B-1", "c2-B-2"):
+        (tmp_path / name).mkdir()
+        (tmp_path / name / "run.json").write_text(json.dumps(
+            {"window": {"t0": 100.0, "seconds": 10.0, "drain_s": 2.0}}))
+        (tmp_path / name / "records.jsonl").write_text("".join(
+            json.dumps(dataclasses.asdict(r)) + "\n" for r in records))
+    sets = spread.from_records(str(tmp_path), "c2", ["ttft_slow10_mean_ms"])
+    assert sets == {s: [{"ttft_slow10_mean_ms": pytest.approx(700.0)}] * 2
+                    for s in "AB"}
+
+
 def test_client_ttft_leaves_out_what_the_trace_stalled():
     from readers import client_ttft
 
@@ -331,11 +373,12 @@ from reference import routed
 TOP = "moe_num_active_primary_experts"
 
 
-def unnormalised(logits, top):
+def unnormalised(logits, top, choice=None):
     probs = jax.nn.softmax(logits, -1)
     best, who = jax.lax.top_k(probs, top)
     rows = jnp.arange(logits.shape[0])[:, None]
-    return jnp.zeros_like(probs).at[rows, who].set(best)
+    return (jnp.zeros_like(probs).at[rows, who].set(best),
+            jnp.zeros(logits.shape[0]))
 
 
 def forward(params, hp, tokens):
@@ -516,6 +559,179 @@ def test_two_prompts_of_one_length_keep_a_row_each(routed_cell):
     assert ok and list(rows) == [
         "prefill_of_100_tokens_no_prefix",
         "prefill_of_100_tokens_no_prefix_the_second_prompt", "decode_step_0"]
+
+
+# -- what a served module may offer: its choice, its cache ------------------
+
+ROUTED_ROWS = ["prefill_of_300_tokens_256_cached",
+               "prefill_of_100_tokens_no_prefix", "decode_step_0",
+               "decode_step_1"]
+SHORTFALL = 0.1   # the tests' limit; the stub swaps near-ties under 0.05
+
+
+@pytest.fixture
+def stub_config(routed_cell, monkeypatch):
+    """``configure(module, **compare keys)``: the routed configuration's file
+    with ``module`` (``routed_stub.module(...)``, or any other) as what the
+    program serves its preset with."""
+    from production_stack_tpu.engine.config import PRESETS, ModelConfig
+    from production_stack_tpu.engine.models.registry import MODEL_REGISTRY
+
+    def configure(module, **more):
+        monkeypatch.setitem(MODEL_REGISTRY, "routedstub", module)
+        monkeypatch.setitem(PRESETS, "routed-stub", ModelConfig(
+            name="routedstub-tiny", num_layers=8, intermediate_size=32,
+            num_experts=16, num_experts_per_tok=6, sliding_window=128,
+            dtype="float32"))
+        config = routed_cell.config
+        return dict(config, model="routed-stub",
+                    compare=dict(config["compare"], **more))
+
+    return configure
+
+
+def followed(stub_config, routed_cell, seed=3_800_000_001, **switches):
+    import routed_stub
+    from harness import compare
+
+    config = stub_config(routed_stub.module(**switches), follow_choice=True,
+                         choice_shortfall=SHORTFALL, why_shortfall="a test")
+    return compare.run(config, 1, seed, "cpu", env_root=routed_cell.root)
+
+
+def test_a_followed_choice_that_differs_in_near_ties_is_correct(
+        stub_config, routed_cell):
+    """(a) The module hands back the experts it chose; where the k-th and the
+    (k+1)-th are all but tied it chose the other one, as a sound computation
+    in another precision would.  The reference follows: every row holds the
+    float32 tolerance, the shortfall is small and not zero, and the share of
+    positions the reference alone would have routed otherwise is said."""
+    ok, notes, rows = followed(stub_config, routed_cell,
+                               fault="swap_near_ties")
+    assert ok, notes
+    assert list(rows) == ROUTED_ROWS + [
+        "choice_shortfall", "return_choice_logits_differ"]
+    assert all(rows[r][0] <= ROUTED_RTOL for r in ROUTED_ROWS)
+    assert 0 < rows["choice_shortfall"][0] < 0.05
+    assert rows["choice_shortfall"][1] == SHORTFALL
+    assert rows["return_choice_logits_differ"] == [0, 0]
+    said = [n for n in notes if "would have chosen otherwise" in n]
+    assert len(said) == 1 and " 0 of " not in said[0], notes
+    # The sound module: the same rows, and nothing flipped in float32.
+    ok, notes, sound = followed(stub_config, routed_cell)
+    assert ok and sound["choice_shortfall"][0] == 0, notes
+    assert any("otherwise at 0 of 404 positions" in n for n in notes), notes
+
+
+@pytest.mark.parametrize("fault, entry", [
+    ("k_plus_8", "choice_shortfall"), ("no_renorm", "a row"),
+    ("misreport", "a row")])
+def test_a_wrong_choice_a_wrong_share_and_a_choice_not_used_are_not_correct(
+        stub_config, routed_cell, fault, entry):
+    """(b) Each by the entry that is there for it: an expert far down the
+    ranking is followed faithfully and fails the shortfall; shares that are
+    not renormalised, or a reported choice that the program did not use, fail
+    a row."""
+    ok, notes, rows = followed(stub_config, routed_cell, fault=fault)
+    assert not ok, notes
+    worst = max(rows[r][0] for r in ROUTED_ROWS)
+    if entry == "choice_shortfall":
+        assert rows["choice_shortfall"][0] > 3 * SHORTFALL
+        assert worst <= ROUTED_RTOL, notes
+    else:
+        assert worst > 10 * ROUTED_RTOL, notes
+
+
+def test_a_module_with_a_cache_of_its_own_is_handed_it_back(
+        stub_config, routed_cell):
+    """(c) One array a layer where the engine keeps a K and a V: the compare
+    asks the module, and the rows are the plain module's."""
+    import routed_stub
+    from harness import compare
+
+    plain = compare.run(routed_cell.config, 1, 3_800_000_002, "cpu",
+                        env_root=routed_cell.root)
+    stub = routed_stub.module(one_array=True)
+    made = []
+    stub.init_cache = (lambda inner: lambda *a: made.append(a[1:3])
+                       or inner(*a))(stub.init_cache)
+    ok, notes, rows = compare.run(stub_config(stub), 1, 3_800_000_002, "cpu",
+                                  env_root=routed_cell.root)
+    assert ok and plain[0] and rows == plain[2], notes
+    assert made == [(96, 16)]
+
+
+def test_the_dense_rehearsal_reads_what_it_always_read():
+    """(d) ``mistral`` on the CPU rehearsal: the four rows of PRs 33 and 34,
+    to the digit."""
+    import run
+    from harness import compare
+
+    path = os.path.join(DATA, "rehearsal", "BENCHMARK.json")
+    config = run.resolve(path, "rehearsal.sessions-prefix")[2]
+    try:
+        ok, notes, rows = compare.run(config, 1, 1, "cpu", env_root=run.ROOT)
+    finally:
+        sys.path.remove(os.path.join(DATA, "rehearsal", "bench"))
+    assert ok and [f"{err:.3e}" for err, _limit in rows.values()] == [
+        "3.511e-03", "3.176e-03", "3.286e-03", "3.339e-03"], notes
+    assert "choice_shortfall" not in rows
+
+
+def test_a_followed_choice_with_no_limit_or_no_choice_is_refused(
+        stub_config, routed_cell):
+    """(e) From the file alone, before anything runs; and a module that
+    cannot return its choice, before anything is built."""
+    from harness import compare
+    from production_stack_tpu.engine.models import llama
+
+    spec = dict(routed_cell.config["compare"], follow_choice=True)
+    with pytest.raises(SystemExit, match="choice_shortfall"):
+        compare.check_file(dict(routed_cell.config, compare=spec))
+    compare.check_file({"model": "tiny-llama"})   # no compare block: fine
+    ok, notes, rows = compare.run(
+        stub_config(llama, follow_choice=True, choice_shortfall=SHORTFALL),
+        1, 1, "cpu", env_root=routed_cell.root)
+    assert not ok and rows == {} and "takes no return_choice" in notes[0]
+
+
+def test_held_keeps_the_published_value_of_a_reduced_key(routed_cell):
+    """(f)"""
+    from harness.sizes import held
+
+    hp = held(routed_cell.config)
+    assert hp["num_hidden_layers"] == 2
+    assert hp["published"]["num_hidden_layers"] == 8
+    assert hp["published"] == routed_cell.config["published"]
+    assert "published" not in hp["published"]
+
+
+def test_a_share_of_the_experts_and_a_one_array_cache_are_additions_only(
+        stub_config, routed_cell):
+    """A later PR's configuration that holds 8 of its 16 experts (the router
+    keeps its width) and whose module keeps one cache array a layer: a file
+    that lists the expert count in ``reduced``, the module, and the reference
+    that is there; no file of ``bench/`` is edited."""
+    import routed_stub
+    from harness import compare
+
+    config = stub_config(
+        routed_stub.module(held=8, one_array=True, fault="swap_near_ties"),
+        follow_choice=True, choice_shortfall=SHORTFALL,
+        preset_keys={k: v for k, v in
+                     routed_cell.config["compare"]["preset_keys"].items()
+                     if k != "moe_num_primary_experts"})
+    config = dict(config, moe_num_primary_experts=8,
+                  reduced=["num_hidden_layers", "moe_num_primary_experts"])
+    detail = {}
+    ok, notes, rows = compare.run(config, 1, 3_800_000_003, "cpu",
+                                  env_root=routed_cell.root, detail=detail)
+    assert ok and 0 < rows["choice_shortfall"][0] < 0.05, notes
+    # Ids over the router's whole width came back, for every position.
+    choice = detail["choice"]
+    assert [c.shape for c in choice] == [(2, 302, 6), (2, 102, 6)]
+    assert min(c.min() for c in choice) >= 0
+    assert max(c.max() for c in choice) > 8
 
 
 def test_every_name_in_the_benchmark_file_has_its_files():

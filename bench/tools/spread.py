@@ -7,12 +7,26 @@ spreads, each set without its run farthest from the median where that
 narrows it.
 
     python bench/tools/spread.py <dir> <prefix>     # <dir>/<prefix>-A-*.out, -B-
+    python bench/tools/spread.py <dir> <prefix> --records ttft_p90_ms,ttft_p95_ms
+
+The first reads result lines.  The second reads the kept request records of
+each run, ``<dir>/<prefix>-<set>-<seed>/records.jsonl`` with the ``run.json``
+beside it (its ``window``: t0, seconds, drain), and computes the named
+statistics with ``reduce/stats.py: summarize``, the function a run's own
+result line comes from: a candidate is tabled by the code that would judge
+it.
 """
 
 import glob
 import json
+import os
 import statistics
 import sys
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from harness.client import Record  # noqa: E402
+from reduce import stats  # noqa: E402
 
 
 def last_line(path):
@@ -34,21 +48,43 @@ def trimmed(values):
     return min(spread(values), spread(rest)) if len(rest) > 1 else spread(values)
 
 
-def main() -> None:
-    directory, prefix = sys.argv[1], sys.argv[2]
+def from_result_lines(directory, prefix):
+    """{set: [{metric: value} a run]} from the runs' last lines."""
     sets = {}
     for name in "AB":
         runs = [last_line(p) for p in sorted(
             glob.glob(f"{directory}/{prefix}-{name}-*.out"))]
-        sets[name] = [r for r in runs if "metrics" in r]
-        print(name, "runs", len(sets[name]), "failed",
-              [r["failed"] for r in sets[name]], "correct",
-              all(r["correct"] for r in sets[name]))
-    metrics = sorted(sets["A"][0]["metrics"])
-    for m in metrics:
+        runs = [r for r in runs if "metrics" in r]
+        print(name, "runs", len(runs), "failed", [r["failed"] for r in runs],
+              "correct", all(r["correct"] for r in runs))
+        sets[name] = [{m: v["value"] for m, v in r["metrics"].items()}
+                      for r in runs]
+    return sets
+
+
+def from_records(directory, prefix, names):
+    """The same from each run's kept records, by ``stats.summarize``."""
+    sets = {}
+    for name in "AB":
+        sets[name] = []
+        for run in sorted(glob.glob(f"{directory}/{prefix}-{name}-*/")):
+            with open(run + "run.json") as f:
+                window = json.load(f)["window"]
+            with open(run + "records.jsonl") as f:
+                records = [Record(**json.loads(ln)) for ln in f]
+            got = stats.summarize(records, window["t0"], window["seconds"],
+                                  window["drain_s"])
+            sets[name].append({m: got["metrics"][m] for m in names
+                               if m in got["metrics"]})
+        print(name, "runs", len(sets[name]))
+    return sets
+
+
+def table(sets):
+    for m in sorted(sets["A"][0]):
         row = {}
         for name, runs in sets.items():
-            values = [r["metrics"][m]["value"] for r in runs if m in r["metrics"]]
+            values = [r[m] for r in runs if m in r]
             if len(values) < 2:
                 continue
             row[name] = {"median": round(statistics.median(values), 3),
@@ -63,6 +99,14 @@ def main() -> None:
         check = round(statistics.mean(v["trimmed"] for v in row.values()), 4)
         print(m, json.dumps(row), "widest", widest, "x5", round(5 * widest, 4),
               "check", check, "B/A-1", drift)
+
+
+def main() -> None:
+    directory, prefix = sys.argv[1], sys.argv[2]
+    if len(sys.argv) > 4 and sys.argv[3] == "--records":
+        table(from_records(directory, prefix, sys.argv[4].split(",")))
+    else:
+        table(from_result_lines(directory, prefix))
 
 
 if __name__ == "__main__":
