@@ -52,6 +52,28 @@ class ModelConfig:
     # nearly doubling the decode roofline).  None | "int8" (per-out-channel
     # symmetric scales; embeddings/norms/biases stay in dtype).
     quantization: Optional[str] = None
+    # Latent attention (models/sarvam_mla.py; 0 = none): the cache keeps a
+    # latent of ``kv_lora_rank`` and one rotary key of ``qk_rope_head_dim``
+    # a position, ``head_dim`` is their sum (the cache's width) and
+    # ``num_kv_heads`` 1.  A query head is ``qk_nope_head_dim`` +
+    # ``qk_rope_head_dim`` wide, a value head ``v_head_dim``.
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    use_qk_norm: bool = False
+    # Routed experts behind a biased sigmoid router, held by share (same
+    # module).  ``num_experts`` and ``vocab_size`` are what THIS chip holds;
+    # ``router_experts`` and ``published_vocab_size`` are the widths the
+    # source publishes (the router scores all of them; 0 = nothing is cut).
+    # ``intermediate_size`` is the leading dense layers' width,
+    # ``moe_intermediate_size`` an expert's.
+    router_experts: int = 0
+    published_vocab_size: int = 0
+    moe_intermediate_size: int = 0
+    num_shared_experts: int = 0
+    first_k_dense_replace: int = 0
+    routed_scaling_factor: float = 1.0
 
     def __post_init__(self):
         if self.head_dim is None:
@@ -248,6 +270,86 @@ PRESETS = {
         rope_theta=1000000.0,
         rms_norm_eps=1e-6,
         attention_bias=True,
+    ),
+    # sarvam-105b (https://huggingface.co/sarvamai/sarvam-105b, model_type
+    # sarvam_mla) AS ONE OF FOUR CHIPS' SHARE, not the whole model: every
+    # width as published, and of the published 32 layers, 128 experts a
+    # routed layer and 262,144 vocabulary rows this preset holds 6 layers
+    # (the dense lead and five routed), experts 0-31 behind a router that
+    # stays 128 wide, and 65,536 rows: 10.92 GB of bf16, what one v5e chip
+    # of four that share every layer holds (bench/configs/
+    # sarvam-105b-ep4.json states the deployment; PERF.md section 4 the
+    # arithmetic).  The published max is 131,072 positions; 32,768 is the
+    # serving limit the cache is sized for.
+    "sarvam-105b-ep4": ModelConfig(
+        name="sarvam-105b-ep4",
+        vocab_size=65536,
+        published_vocab_size=262144,
+        hidden_size=4096,
+        intermediate_size=16384,
+        num_layers=6,
+        num_heads=64,
+        num_kv_heads=1,
+        head_dim=576,
+        max_model_len=32768,
+        rope_theta=10000.0,
+        rope_scaling={
+            "type": "deepseek_yarn",
+            "factor": 40,
+            "original_max_position_embeddings": 4096,
+            "beta_fast": 32,
+            "beta_slow": 1,
+            "mscale": 1,
+            "mscale_all_dim": 1,
+        },
+        rms_norm_eps=1e-6,
+        kv_lora_rank=512,
+        qk_nope_head_dim=128,
+        qk_rope_head_dim=64,
+        v_head_dim=128,
+        use_qk_norm=True,
+        num_experts=32,
+        router_experts=128,
+        num_experts_per_tok=8,
+        moe_intermediate_size=2048,
+        num_shared_experts=1,
+        first_k_dense_replace=1,
+        routed_scaling_factor=2.5,
+    ),
+    # The same module at a size the CPU tests run: one dense layer and two
+    # routed, 4 of a router's 8 experts held, 2 a token.
+    "tiny-sarvam": ModelConfig(
+        name="tiny-sarvam-mla",
+        vocab_size=384,
+        hidden_size=64,
+        intermediate_size=128,
+        num_layers=3,
+        num_heads=4,
+        num_kv_heads=1,
+        head_dim=48,
+        max_model_len=2048,
+        rope_scaling={
+            "type": "deepseek_yarn",
+            "factor": 40,
+            "original_max_position_embeddings": 64,
+            "beta_fast": 32,
+            "beta_slow": 1,
+            "mscale": 1,
+            "mscale_all_dim": 1,
+        },
+        rms_norm_eps=1e-6,
+        kv_lora_rank=32,
+        qk_nope_head_dim=16,
+        qk_rope_head_dim=16,
+        v_head_dim=16,
+        use_qk_norm=True,
+        num_experts=4,
+        router_experts=8,
+        num_experts_per_tok=2,
+        moe_intermediate_size=32,
+        num_shared_experts=1,
+        first_k_dense_replace=1,
+        routed_scaling_factor=2.5,
     ),
 }
 

@@ -121,6 +121,9 @@ class _PendingStep:
     # "model") for the per-drafter accounting.
     spec_stats: Optional[tuple] = None
     spec_drafter: Optional[str] = None
+    # A routed model's still-in-flight per-step counts ([K, n] int32, the
+    # module's ROUTING_STATS), read back with the tokens at collect.
+    routing: Optional[object] = None
     # Mixed K-step windows: the chunk schedule that rode the scan (one
     # PrefillPlan per live iteration — packed windows interleave several
     # prompts' chunks), the still-in-flight per-iteration tail logits
@@ -136,6 +139,12 @@ class _PendingStep:
     # off (the recorder is never consulted) or the step completed its
     # record synchronously at dispatch.
     rec: Optional[object] = None
+
+
+def _own_cache(cfg) -> bool:
+    """A module with ``init_cache`` keeps a cache of its own shape
+    (models/registry.py): allocation, byte count and sharding ask it."""
+    return hasattr(get_model(cfg.name), "init_cache")
 
 
 class LLMEngine:
@@ -294,6 +303,7 @@ class LLMEngine:
             restore_cb=self.restore_seq_blocks,
             remote_prefix_cb=self.fetch_remote_prefix if imports else None,
         )
+        weights_in_use = self.device_report()["memory"][0]["bytes_in_use"]
         self.kv_caches = self._allocate_kv(num_blocks)
         logger.info(
             "KV pool: %d blocks x %d tokens (%.2f GiB)",
@@ -301,6 +311,15 @@ class LLMEngine:
             config.cache.block_size,
             self._kv_bytes(num_blocks) / 2**30,
         )
+        mem = self.device_report()["memory"][0]
+        if mem["bytes_in_use"] is not None:
+            # What a deployment would hold: the weights, then the pool.
+            logger.info(
+                "Device memory: %.3f GB in use after the weights, %.3f GB "
+                "with the KV pool, of %.3f GB",
+                weights_in_use / 1e9, mem["bytes_in_use"] / 1e9,
+                (mem["bytes_limit"] or 0) / 1e9,
+            )
 
         # Dedicated draft-KV pool (model drafter only): the draft
         # model's device-resident cache lives in its OWN small block
@@ -430,11 +449,23 @@ class LLMEngine:
 
         # Jitted step functions.  KV caches are donated so updates alias the
         # same HBM; cfg and mesh are closed over (static).
+        # A module that counts what its router did on the device
+        # (ROUTING_STATS, models/registry.py) is asked for the counts on the
+        # steps the served path takes: the dedicated prefill and the K-step
+        # window.  They come back as one more result and are read with the
+        # tokens; a module without the attribute is called as ever.
+        self._routing_names = getattr(self.model, "ROUTING_STATS", ())
+        counting = {"return_stats": True} if self._routing_names else {}
+        # Prefill dispatches' counts still on the device: (record, [n]).
+        self._routing_pending: Deque[tuple] = deque()
+        # tpu:moe_assignments_total{where} / tpu:moe_experts_touched_total.
+        self.moe_assignments: Dict[str, int] = {"held": 0, "away": 0}
+        self.moe_experts_touched = 0
         self._prefill_fn = self._jit(
             "prefill_fn",
             partial(
                 self.model.prefill, cfg=cfg, mesh=self.mesh,
-                sp_mode=par.sequence_parallel_mode,
+                sp_mode=par.sequence_parallel_mode, **counting,
             ),
             donate_argnames=("kv_caches",),
             static_argnames=("prompt_topk",),
@@ -478,7 +509,8 @@ class LLMEngine:
             self._window_fn = self._jit(
                 "window_fn",
                 step_programs.window_program(
-                    model_decode, n_steps=self._window_steps, **dims
+                    partial(model_decode, **counting),
+                    n_steps=self._window_steps, **dims
                 ),
                 static_argnames=("use_penalties", "use_min_floor"),
                 donate_argnames=("kv_caches",),
@@ -738,6 +770,12 @@ class LLMEngine:
 
     def _kv_bytes(self, num_blocks: int) -> int:
         cfg = self.config.model
+        if _own_cache(cfg):
+            # A module that makes its own cache says what a position costs.
+            return (
+                num_blocks * self.config.cache.block_size
+                * self.model.cache_bytes_per_token(cfg)
+            )
         if self.config.cache.kv_cache_dtype == "int8":
             # int8 data + one fp32 scale per (token, kv head): bytes per
             # token roughly halve vs bf16, so _decide_num_blocks fits
@@ -784,6 +822,13 @@ class LLMEngine:
                 "PSTPU_DISABLE_PALLAS is set: both Pallas attention kernels "
                 "are switched off, every step takes the XLA gather/dense path"
             )
+        if _own_cache(cfg):
+            self._refuse_what_the_module_lacks()
+            logger.info(
+                "Attention: decode=xla-absorbed-latent "
+                "prefill=xla-expanded-latent (%s)", self.model.__name__,
+            )
+            return
         decode_kernel = attn_ops.use_pallas_decode(
             cfg.num_kv_heads // par.tensor_parallel, cfg.head_dim
         )
@@ -809,6 +854,43 @@ class LLMEngine:
                 "--kv-cache-dtype int8 cannot run on a TPU yet: the int8-KV "
                 "decode kernel does not compile for it (scale-plane layout, "
                 "ROADMAP S10); serve with the default bf16 KV cache"
+            )
+
+    def _refuse_what_the_module_lacks(self) -> None:
+        """A module that keeps a cache of its own offers ``prefill`` and
+        ``decode`` over it and nothing else unless it says so.  Whatever
+        the configuration asks of it beyond that is refused here, at boot
+        and by name: the tiers that unpack a (K, V) pair a layer, a mesh
+        it has no sharding rules for, weight or cache formats it does not
+        have, step functions it does not bring.  No silent fallback."""
+        config, module = self.config, self.model.__name__
+        asked = {
+            "tensor/data/sequence parallelism (a mesh of more than one "
+            "device)": self.mesh.size > 1,
+            "--quantization (int8 weights)":
+                config.model.quantization is not None,
+            "--kv-cache-dtype int8": config.cache.kv_cache_dtype == "int8",
+            "LoRA adapters (--max-loras)": config.lora.enabled,
+            "host KV offload (--host-offload-gb)":
+                config.cache.host_offload_gb > 0,
+            "the remote KV store, prefix prefetch and disaggregated "
+            "prefill (--remote-kv-url, --disagg-role)":
+                bool(config.cache.remote_kv_url)
+                or config.cache.disagg_role is not None,
+            "speculative decoding (--speculative-ngram, "
+            "--speculative-model)":
+                bool(config.scheduler.speculative_ngram)
+                or config.scheduler.speculative_model is not None,
+            "mixed prefill+decode steps (--mixed-batch; the module has no "
+            "mixed_step)":
+                config.scheduler.mixed_batch is True
+                and not hasattr(self.model, "mixed_step"),
+        }
+        refused = [what for what, on in asked.items() if on]
+        if refused:
+            raise ValueError(
+                f"{module} keeps a cache of its own (one array a layer) and "
+                f"cannot serve with: {'; '.join(refused)}"
             )
 
     def _decide_num_blocks(self) -> int:
@@ -846,6 +928,12 @@ class LLMEngine:
     def _allocate_kv(self, num_blocks: int):
         cfg = self.config.model
         bs = self.config.cache.block_size
+        if _own_cache(cfg):
+            # Whatever the module keeps a layer (a latent cache is one
+            # array, no K and V); the step programs thread it as a tree.
+            return self.model.init_cache(
+                cfg, num_blocks, bs, NamedSharding(self.mesh, P())
+            )
         shape = (num_blocks, bs, cfg.num_kv_heads, cfg.head_dim)
         dtype = jnp.dtype(cfg.dtype)
         # Allocate directly sharded (jit with out_shardings): materializing
@@ -1834,6 +1922,7 @@ class LLMEngine:
         # bit-identical across window sizes with speculation configured.
         spec_stats = None
         spec_drafter = None
+        routing = ()   # a counting model's per-step counts (plain window)
         use_spec = self._spec_window_fn is not None and all(
             self._host_state_flags(s)[2] for s in seqs
         )
@@ -1918,7 +2007,7 @@ class LLMEngine:
             # model-spec window must re-prime from `hist`.
             self._draft_primed = False
             with self.obs.phase("launch", rec):
-                emitted, out_state, self.kv_caches = self._window_fn(
+                emitted, out_state, self.kv_caches, *routing = self._window_fn(
                     self.params,
                     tokens=state["tokens"],
                     positions=state["positions"],
@@ -1970,6 +2059,7 @@ class LLMEngine:
             host_s=time.time() - t0, steps=list(decode.steps),
             win_state=state, spec_stats=spec_stats,
             spec_drafter=spec_drafter, rec=rec,
+            routing=routing[0] if routing else None,
         )
 
     # stackcheck: root=step-thread
@@ -2186,6 +2276,9 @@ class LLMEngine:
         t0 = time.time()
         with self.obs.phase("collect", p.rec):
             arr = np.asarray(p.sampled)  # the ONE device sync point
+            if p.routing is not None:
+                # Same program, already done: no second wait.
+                self._count_routing(p.rec, np.asarray(p.routing))
         if p.spec_drafter == "model":
             # Scan seconds attributed to draft forwards
             # (tpu:spec_draft_fraction_seconds): the measured collect
@@ -2206,6 +2299,27 @@ class LLMEngine:
             self._note_compiles(p.rec, [s.seq_id for s in p.seqs])
             self.obs.recorder.on_collect(p.rec, host_s=p.host_s, **counts)
         return outputs
+
+    def _count_routing(self, rec, counts) -> None:
+        """Fold a window's routing counts (``[K, n]``, a row a step) into
+        the totals and onto its flight record, then those of the prefill
+        chunks whose programs have finished since (``[n]`` each)."""
+        done = [(rec, counts)]
+        while self._routing_pending and self._routing_pending[0][1].is_ready():
+            chunk_rec, chunk = self._routing_pending.popleft()
+            done.append((chunk_rec, np.asarray(chunk)))
+        for rec, counts in done:
+            # Counts add over the steps; the last, a fullest expert's rows,
+            # is a max.
+            counts = counts.reshape(-1, counts.shape[-1])
+            folded = [int(n) for n in counts[:, :-1].sum(0)] + [
+                int(counts[:, -1].max())]
+            assigned, here, touched = folded[:3]
+            self.moe_assignments["held"] += here
+            self.moe_assignments["away"] += assigned - here
+            self.moe_experts_touched += touched
+            if rec is not None:
+                rec.routing = dict(zip(self._routing_names, folded))
 
     def _replay_window(self, p: _PendingStep, arr):
         """The host half of a window's collect: (outputs, the token counts
@@ -2968,6 +3082,11 @@ class LLMEngine:
             out = self._prefill_fn(
                 self.params, kv_caches=self.kv_caches, **kwargs
             )
+        if self._routing_names:
+            # Left on the device: a chunk's counts are read once a later
+            # collect has waited for a program launched after it.
+            *out, routing = out
+            self._routing_pending.append((rec, routing))
         if want_plp:
             logits, self.kv_caches, plp = out
             with self.obs.phase("collect", rec, family=False):
@@ -4246,6 +4365,11 @@ class LLMEngine:
             "multistep_fallback": dict(self.multistep_fallback),
             "multistep_wasted_tokens": self.multistep_wasted_tokens,
             "prefill_attn_tiles": dict(self.prefill_attn_tiles),
+            # Routed experts held by share: (row, expert) pairs by where
+            # they fell, and held experts with at least one row a layer
+            # and step (zero for a model that routes nothing).
+            "moe_assignments": dict(self.moe_assignments),
+            "moe_experts_touched": self.moe_experts_touched,
             # Quantized KV tiering plane: bytes crossing each tier
             # boundary by wire format, and snapshot serde versions put
             # on the kvserver wire (tpu:kv_wire_bytes_total /

@@ -172,7 +172,9 @@ def window_program(model_decode, *, block_size, n_steps, vocab):
             blk = jnp.take_along_axis(
                 block_tables, (positions // bs)[:, None], axis=1
             )[:, 0]
-            logits, kv_caches = model_decode(
+            # ``counted``: what a model that counts on the device (a routed
+            # model's [n] int32 of its dispatch) hands back beside the two.
+            logits, kv_caches, *counted = model_decode(
                 params,
                 tokens=tokens,
                 positions=positions,
@@ -207,9 +209,9 @@ def window_program(model_decode, *, block_size, n_steps, vocab):
             return advance_rows(
                 sampled, active, stop_hit,
                 tokens, positions, ctx_lens, done, min_left,
-            ) + (counts, seen, kv_caches), emitted
+            ) + (counts, seen, kv_caches), (emitted, *counted)
 
-        carry, emitted = jax.lax.scan(
+        carry, (emitted, *counted) = jax.lax.scan(
             body,
             (tokens, positions, ctx_lens, done, min_left,
              counts, seen, kv_caches),
@@ -219,8 +221,10 @@ def window_program(model_decode, *, block_size, n_steps, vocab):
         # No all-finished reduction on the device: every stop is visible in
         # the emitted [K, S] tokens the host reads back anyway, so collect()
         # evaluates the predicate from host state and drops queued
-        # successor windows without another device sync.
-        return emitted, dict(zip(CARRY_KEYS, row)), kv_caches
+        # successor windows without another device sync.  A counting
+        # model's [K, n] per-step counts ride out the same way, a fourth
+        # result, for the host to read with the tokens.
+        return (emitted, dict(zip(CARRY_KEYS, row)), kv_caches, *counted)
 
     return multi_window
 

@@ -1,10 +1,11 @@
 """Model zoo: decoder-only transformer families, functional JAX style.
 
-``llama.py`` covers the Llama 2/3(.x) and Mistral/Qwen-style architectures
-(RMSNorm + rotate-half RoPE + GQA + SwiGLU, optional sliding window).
-``opt.py`` covers OPT (learned positions + ReLU MLP + pre-LN) for tiny CPU
-smoke deployments (the reference's facebook/opt-125m minimal install,
-tutorials/assets/values-01-minimal-example.yaml).
+``llama.py`` covers the Llama 2/3(.x), Mistral, Qwen2, Mixtral and Gemma
+architectures (RMSNorm + rotate-half RoPE + GQA + gated MLP, optional sliding
+window, softmax-routed experts).  ``sarvam_mla.py`` is latent attention (MLA,
+a cache of one array a layer) over experts behind a biased sigmoid router,
+held by share.  ``registry.py`` maps a preset's name to its module and says
+what a module must and may offer the engine.
 """
 
 from production_stack_tpu.engine.models.registry import get_model, MODEL_REGISTRY

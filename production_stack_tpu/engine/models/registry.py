@@ -1,14 +1,48 @@
-"""Model registry: architecture name -> functional model module.
+"""Model registry: architecture name -> functional model module, and what
+such a module offers the engine (the one place that says so).
 
-Each module exposes ``init_params(cfg, key)``, ``prefill(...)``,
-``decode(...)`` with the signatures defined in llama.py.
+**A module must offer** (``models/llama.py`` is the pattern and gives the
+signatures): ``init_params(cfg, key, shardings=None)`` (seeded weights, each
+tensor made in its final sharding), ``quantize_params(params, cfg)``,
+``prefill(params, cfg, tokens, cached_len, prefix_block_ids, new_block_ids,
+valid_len, kv_caches, mesh=None, sp_mode=..., prompt_targets=None,
+prompt_topk=0) -> (last logits [V], caches)`` and ``decode(params, cfg,
+tokens, positions, block_tables, ctx_lens, slot_block_ids, slot_offsets,
+kv_caches, mesh=None) -> (logits [S, V], caches)``.  ``kv_caches`` is a tree
+the step programs (``core/step_programs.py``) carry without looking inside.
+
+**A module may offer**, and the engine asks with ``hasattr``:
+
+- ``mixed_step``, ``encode`` / ``encode_batch``, ``lora=`` / ``adapter_idx=``
+  on the steps: fused mixed batches, embeddings, adapters (llama.py has
+  all; without them the engine turns the auto gate off or refuses the
+  request or the flag by name).
+- ``init_cache(cfg, num_blocks, block_size, sharding)`` with
+  ``cache_bytes_per_token(cfg)``: a cache of the module's own shape (a
+  latent cache is one array a layer, no K and V).  The engine's allocator,
+  its byte count and the benchmark's compare then ask the module; without
+  it a K and a V array a layer of ``num_kv_heads x head_dim``.  Such a
+  module is refused at boot with the tiers that unpack a (K, V) pair
+  (offload, remote store, prefetch, disaggregation), int8 KV, LoRA,
+  speculation and a mesh (``core/engine.py:
+  _refuse_what_the_module_lacks``).
+- ``param_specs(cfg)``: the PartitionSpec tree of its own parameters
+  (``parallel/shardings.py`` asks before it assumes llama's tree).
+- ``return_choice=True`` on ``prefill`` / ``decode``: one more result, the
+  experts each row chose (int32 [routed layers, rows, k], ids over the
+  router's published width), logits bit-equal with and without; the
+  benchmark's compare follows it (``bench/harness/compare.py``).
+- ``ROUTING_STATS`` (names) with ``return_stats=True`` on both steps: one
+  more result, an int32 vector of what routing did, counted on the device;
+  the engine asks for it on the dedicated prefill and inside the K-step
+  window and reads it back with the tokens (flight records, ``/metrics``).
 """
 
 from __future__ import annotations
 
 from types import ModuleType
 
-from production_stack_tpu.engine.models import llama
+from production_stack_tpu.engine.models import llama, sarvam_mla
 
 MODEL_REGISTRY = {
     # llama.py covers every RMSNorm+RoPE+GQA+gated-MLP family member; the
@@ -20,6 +54,9 @@ MODEL_REGISTRY = {
     "mixtral": llama,
     "qwen2": llama,
     "gemma": llama,
+    # Latent attention over routed experts held by share: a cache of one
+    # array a layer, which the module makes (init_cache).
+    "sarvam": sarvam_mla,
 }
 
 

@@ -33,15 +33,21 @@ def load_params(
     ``seed`` when no path is given.  ``shardings`` (parallel/shardings.py
     param_shardings) places, and under cfg.quantization quantizes, each
     tensor directly in its final sharding (models/llama.py place_weight)."""
-    from production_stack_tpu.engine.models import llama
+    from production_stack_tpu.engine.models import get_model, llama
 
+    model = get_model(cfg.name)
     if weights_path:
         if not os.path.isdir(weights_path):
             raise FileNotFoundError(
                 f"weights path {weights_path!r} is not a directory"
             )
+        if model is not llama:
+            raise ValueError(
+                f"no checkpoint loader for {model.__name__}: "
+                f"{cfg.name!r} serves seeded random weights only"
+            )
         return load_hf_safetensors(cfg, weights_path, shardings)
-    return llama.init_params(cfg, jax.random.PRNGKey(seed), shardings)
+    return model.init_params(cfg, jax.random.PRNGKey(seed), shardings)
 
 
 def _open_safetensors(weights_path: str) -> Dict[str, np.ndarray]:

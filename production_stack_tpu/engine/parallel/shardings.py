@@ -116,7 +116,13 @@ def _layer_specs(cfg) -> Dict[str, P]:
 
 
 def param_specs(cfg: ModelConfig) -> Dict:
-    """PartitionSpec tree matching the param tree from models/llama.py."""
+    """PartitionSpec tree matching the param tree from models/llama.py, or
+    the tree of a module that brings its own (``param_specs``)."""
+    from production_stack_tpu.engine.models import get_model
+
+    model = get_model(cfg.name)
+    if hasattr(model, "param_specs"):
+        return model.param_specs(cfg)
     specs: Dict = {
         "embed_tokens": P(TP, None),
         "norm": P(),

@@ -390,6 +390,10 @@ def build_engine_app(
             # K-step decode windows: emitted-but-undeliverable tokens
             # (the labeled fallback family renders below).
             (vocab.TPU_MULTISTEP_WASTED_TOKENS, s["multistep_wasted_tokens"]),
+            # Routed experts held by share: held experts with a row, over
+            # routed layers and decode steps (the labeled family, pairs
+            # by where they fell, renders below).
+            (vocab.TPU_MOE_EXPERTS_TOUCHED, s["moe_experts_touched"]),
             # Slice-group lifecycle (0 on single-host engines): the group
             # epoch steps on every group restart, and drain relays count
             # follower-initiated slice-wide drains (docs/robustness.md).
@@ -412,6 +416,9 @@ def build_engine_app(
             + vocab.render_labeled_counter(
                 vocab.TPU_PREFILL_ATTN_TILES, "state",
                 s["prefill_attn_tiles"],
+            )
+            + vocab.render_labeled_counter(
+                vocab.TPU_MOE_ASSIGNMENTS, "where", s["moe_assignments"],
             )
             # Fused speculative windows: outcome x drafter (one engine
             # runs at most one proposal source, so the live counts land

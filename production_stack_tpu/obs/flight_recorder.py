@@ -110,6 +110,14 @@ class WindowRecord:
     kv_tiles_live: int = 0
     kv_tiles_grid: int = 0
     cover: Tuple[int, ...] = ()
+    # What routing did in this dispatch, counted on the device by a model
+    # that routes (models/sarvam_mla.py: ROUTING_STATS) and read back with
+    # its tokens; None for a model that routes nothing.  ``moe_assigned``:
+    # (row, expert) pairs its live rows chose, over routed layers and
+    # steps; ``moe_assigned_here``: those that fell on experts held here;
+    # ``experts_touched``: held experts with at least one row, summed over
+    # routed layers and steps; ``expert_rows_max``: the fullest one's rows.
+    routing: Optional[Dict[str, int]] = None
 
     @property
     def launch_ns(self) -> Optional[int]:
@@ -149,6 +157,8 @@ class WindowRecord:
             d["kv_tiles_grid"] = self.kv_tiles_grid
         if self.cover:
             d["cover"] = list(self.cover)
+        if self.routing:
+            d.update(self.routing)
         if self.spec_width:
             d["spec_width"] = self.spec_width
             d["drafter"] = self.drafter

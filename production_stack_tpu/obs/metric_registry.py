@@ -307,6 +307,23 @@ REGISTRY = {
                 "valid_len, padded query tiles); host arithmetic from "
                 "each plan, no device read",
     },
+    "tpu:moe_assignments_total": {
+        "kind": "counter", "layer": "engine", "labels": ("where",),
+        "mirrors": ("fake_engine", "dashboard", "docs"),
+        "help": "(row, expert) pairs a routed model's router chose, over "
+                "routed layers and steps, by where the expert lives "
+                "(where: held — on this chip, computed; away — on "
+                "another chip of the deployment this engine is a share "
+                "of, left out); counted on the device, read back with "
+                "the tokens; zero for a model that routes nothing",
+    },
+    "tpu:moe_experts_touched_total": {
+        "kind": "counter", "layer": "engine",
+        "mirrors": ("fake_engine", "dashboard", "docs"),
+        "help": "Held experts with at least one row, summed over routed "
+                "layers and decode steps: what a decode step streams of "
+                "the expert stacks",
+    },
     "tpu:kv_wire_bytes_total": {
         "kind": "counter", "layer": "engine", "labels": ("tier", "format"),
         "mirrors": ("fake_engine", "dashboard", "docs"),

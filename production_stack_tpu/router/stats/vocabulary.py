@@ -204,6 +204,15 @@ TPU_MULTISTEP_WASTED_TOKENS = "tpu:multistep_wasted_tokens_total"
 # host from each plan's (bucket, cached_len, new tokens).
 TPU_PREFILL_ATTN_TILES = "tpu:prefill_attn_tiles_total"
 TPU_PREFILL_ATTN_TILE_STATES = ("live", "skipped")
+# Routed experts held by share (engine/models/sarvam_mla.py): (row, expert)
+# pairs the router chose, by where the expert lives — held: on this chip,
+# computed; away: on a chip of the deployment this engine stands for, left
+# out — and held experts with at least one row, summed over routed layers
+# and decode steps.  Counted on the device, read back with the tokens; zero
+# for a model that routes nothing.
+TPU_MOE_ASSIGNMENTS = "tpu:moe_assignments_total"
+TPU_MOE_ASSIGNMENT_WHERE = ("held", "away")
+TPU_MOE_EXPERTS_TOUCHED = "tpu:moe_experts_touched_total"
 # Mixed K-step windows (scheduler mixed_window): prompt tokens whose
 # prefill chunks rode the device-resident decode scan — the subset of
 # tpu:prefill_chunk_tokens that did NOT pay a per-chunk host
@@ -308,6 +317,7 @@ TPU_COUNTERS = frozenset({
     TPU_ADMISSION_REJECTED,
     TPU_DEADLINE_EXPIRED,
     TPU_MULTISTEP_WASTED_TOKENS,
+    TPU_MOE_EXPERTS_TOUCHED,
     TPU_MIXED_WINDOW_CHUNK_TOKENS,
     TPU_ENCODE_TEXTS,
     TPU_WINDOW_TRANSFER_OVERLAP_SECONDS,

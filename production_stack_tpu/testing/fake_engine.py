@@ -538,6 +538,8 @@ def build_fake_engine_app(state: FakeEngineState | None = None) -> web.Applicati
             # families must exist for the scrape contract
             # (TPU_MULTISTEP_FALLBACK renders its labeled header below).
             (vocab.TPU_MULTISTEP_WASTED_TOKENS, 0),
+            # The fake routes nothing: the families, at zero (SC303).
+            (vocab.TPU_MOE_EXPERTS_TOUCHED, 0),
             # Batched encode lane (embed/rerank/score): live values from
             # the fake lane below — texts encoded and the queue-depth
             # gauge — so router encode-lane CI asserts batching through
@@ -556,6 +558,9 @@ def build_fake_engine_app(state: FakeEngineState | None = None) -> web.Applicati
             # No prefill kernel in the fake: the family, at zero (SC303).
             vocab.TPU_PREFILL_ATTN_TILES, "state",
             dict.fromkeys(vocab.TPU_PREFILL_ATTN_TILE_STATES, 0),
+        ) + vocab.render_labeled_counter(
+            vocab.TPU_MOE_ASSIGNMENTS, "where",
+            dict.fromkeys(vocab.TPU_MOE_ASSIGNMENT_WHERE, 0),
         ) + vocab.render_labeled_counter2(
             # Fused speculative windows: no device, so no drafts — but
             # the family (all outcome x drafter cells) must exist for
