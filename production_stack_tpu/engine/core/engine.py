@@ -825,8 +825,8 @@ class LLMEngine:
         if _own_cache(cfg):
             self._refuse_what_the_module_lacks()
             logger.info(
-                "Attention: decode=xla-absorbed-latent "
-                "prefill=xla-expanded-latent (%s)", self.model.__name__,
+                "Attention: decode=%s prefill=%s (%s)",
+                *self.model.attention_paths(cfg), self.model.__name__,
             )
             return
         decode_kernel = attn_ops.use_pallas_decode(

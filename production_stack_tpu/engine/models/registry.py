@@ -26,6 +26,9 @@ the step programs (``core/step_programs.py``) carry without looking inside.
   (offload, remote store, prefetch, disaggregation), int8 KV, LoRA,
   speculation and a mesh (``core/engine.py:
   _refuse_what_the_module_lacks``).
+- with ``init_cache``, ``attention_paths(cfg) -> (decode, prefill)``: the
+  names of the attention paths its steps take in this process, which the
+  engine's boot line says (``Attention: decode=... prefill=...``).
 - ``param_specs(cfg)``: the PartitionSpec tree of its own parameters
   (``parallel/shardings.py`` asks before it assumes llama's tree).
 - ``return_choice=True`` on ``prefill`` / ``decode``: one more result, the

@@ -23,9 +23,11 @@ per-head K and V, a tile of keys at a time under a running softmax
 (:func:`_expanded_attention`): no ``[chunk, context, heads]`` score array is
 ever built.  :func:`decode` absorbs ``W_kvb`` into the query and the output
 (``q~ = W_UK^T q_nope``, ``score = [q~ ; q_rope] . [c ; r]``,
-``o = W_UV sum p c``) and reads the latent pages as they lie, a tile of
-blocks at a time (:func:`_absorbed_attention`).  Both are plain XLA: the
-baseline a kernel has to beat.
+``o = W_UV sum p c``) and reads the latent pages as they lie
+(:func:`_absorbed_attention`): on a TPU by the Pallas kernel of
+``ops/pallas/latent_attention.py``, each live page copied HBM -> VMEM once;
+elsewhere by an XLA walk, a tile of blocks at a time (:func:`_latent_walk`).
+The prefill is plain XLA: the baseline a kernel has to beat.
 
 **Routed FFN.**  ``s = sigmoid(W_r x)`` in float32 over the router's
 published width ``cfg.router_experts``; the ``num_experts_per_tok`` largest of
@@ -401,31 +403,41 @@ def _expanded_attention(layer, cfg, q_nope, q_rope, rows, cache,
     return out.reshape(H, T, -1).transpose(1, 0, 2)    # [T, H, v]
 
 
-PAGE_TILE = 128     # blocks a tile of the absorbed (decode) attention
+PAGE_TILE = 128     # blocks a tile of the XLA walk over the latent pages
 
 
-def _absorbed_attention(layer, cfg, q_nope, q_rope, cache, block_tables,
-                        ctx_lens):
-    """Decode: one query a row [S, H, .] over that row's latent pages as they
-    lie.  ``W_kvb`` is absorbed: the query goes into the latent space
-    (``q~``), scores are ``[q~ ; q_rope] . [c ; r]``, the probabilities weigh
-    the latents themselves and ``W_UV`` lifts the sum; the scores are
-    [S, H, positions of a tile]: there is no chunk axis to multiply them.
-    The pages are read a tile of PAGE_TILE blocks a row at a time, for as
-    long as the longest row is live (``tools/latent_decode_microbench.py``:
-    a sixth of the time of gathering every row's positions to the block
-    table's full width, PERF.md section 5)."""
-    S, H = q_nope.shape[:2]
-    L, nope = cfg.kv_lora_rank, cfg.qk_nope_head_dim
-    bs, lanes = cache.shape[1:]
-    w = _kv_b(layer, cfg)
-    q_lat = jnp.concatenate([
-        jnp.einsum("shd,lhd->shl", q_nope, w[..., :nope],
-                   preferred_element_type=jnp.float32).astype(q_nope.dtype),
-        q_rope,
-        jnp.zeros((S, H, lanes - cache_width(cfg)), q_rope.dtype)],
-        axis=-1)                                            # [S, H, lanes]
-    scale = softmax_scale(cfg)
+def use_pallas_latent_decode(lanes: int) -> bool:
+    """Trace-time dispatch check for the paged latent decode kernel
+    (``ops/pallas/latent_attention.py``), as ``ops/attention.py:
+    use_pallas_decode`` decides for the dense models: a real TPU, a cache
+    row of whole 128-lane tiles (a DMA'd row has to be), and the A/B
+    switch not set.  Everything else walks the pages in XLA."""
+    from production_stack_tpu.engine.ops.attention import pallas_disabled
+
+    if pallas_disabled() or lanes % 128:
+        return False
+    return jax.default_backend() == "tpu"
+
+
+def attention_paths(cfg: ModelConfig):
+    """(decode, prefill): which path each step's attention takes in this
+    process, for the engine's boot line."""
+    kernel = use_pallas_latent_decode(cache_lanes(cfg))
+    return ("pallas-latent" if kernel else "xla-absorbed-latent",
+            "xla-expanded-latent")
+
+
+def _latent_walk(q_lat, cache, block_tables, ctx_lens, latent_rank, scale):
+    """``q_lat`` [S, H, lanes] over each row's latent pages as they lie, in
+    XLA: softmax of ``q_lat . page * scale`` over the row's ``ctx_lens``
+    positions, weighing the pages' first ``latent_rank`` lanes ->
+    [S, H, latent_rank] float32.  The pages are read a tile of PAGE_TILE
+    blocks a row at a time, for as long as the longest row is live
+    (``tools/latent_decode_microbench.py``: a sixth of the time of gathering
+    every row's positions to the block table's full width,
+    PERF.md section 5)."""
+    S, H, lanes = q_lat.shape
+    bs = cache.shape[1]
     blocks = min(PAGE_TILE, block_tables.shape[1])
     tile = blocks * bs
     tables = jnp.pad(block_tables,
@@ -440,14 +452,49 @@ def _absorbed_attention(layer, cfg, q_nope, q_rope, cache, block_tables,
         scores = jnp.where((k_pos[None] < ctx_lens[:, None])[:, None],
                            scores, -jnp.inf)
         return _online(state, scores, lambda p: jnp.einsum(
-            "shk,skl->shl", p.astype(pages.dtype), pages[..., :L],
+            "shk,skl->shl", p.astype(pages.dtype), pages[..., :latent_rank],
             preferred_element_type=jnp.float32))
 
     state = (jnp.full((S, H), -jnp.inf), jnp.zeros((S, H)),
-             jnp.zeros((S, H, L)))
+             jnp.zeros((S, H, latent_rank)))
     _m, l, acc = jax.lax.fori_loop(
         0, (jnp.max(ctx_lens) + tile - 1) // tile, page_tile, state)
-    latent = (acc / jnp.maximum(l, 1e-30)[..., None]).astype(q_nope.dtype)
+    return acc / jnp.maximum(l, 1e-30)[..., None]
+
+
+def _absorbed_attention(layer, cfg, q_nope, q_rope, cache, block_tables,
+                        ctx_lens):
+    """Decode: one query a row [S, H, .] over that row's latent pages as they
+    lie.  ``W_kvb`` is absorbed: the query goes into the latent space
+    (``q~``), scores are ``[q~ ; q_rope] . [c ; r]``, the probabilities weigh
+    the latents themselves and ``W_UV`` lifts the sum; the scores are
+    [S, H, positions of a tile]: there is no chunk axis to multiply them.
+    Between the two weight einsums the pages are read by the Pallas kernel
+    on a TPU (each live page HBM -> VMEM once, key and value at once) and by
+    :func:`_latent_walk` elsewhere (:func:`use_pallas_latent_decode`): the
+    same operand dtypes and float32 statistics on both."""
+    S, H = q_nope.shape[:2]
+    L, nope = cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    lanes = cache.shape[-1]
+    w = _kv_b(layer, cfg)
+    q_lat = jnp.concatenate([
+        jnp.einsum("shd,lhd->shl", q_nope, w[..., :nope],
+                   preferred_element_type=jnp.float32).astype(q_nope.dtype),
+        q_rope,
+        jnp.zeros((S, H, lanes - cache_width(cfg)), q_rope.dtype)],
+        axis=-1)                                            # [S, H, lanes]
+    scale = softmax_scale(cfg)
+    if use_pallas_latent_decode(lanes):
+        from production_stack_tpu.engine.ops.pallas.latent_attention import (
+            latent_decode_attention_pallas,
+        )
+
+        latent = latent_decode_attention_pallas(
+            q_lat, cache, block_tables, ctx_lens, latent_rank=L, scale=scale)
+    else:
+        latent = _latent_walk(
+            q_lat, cache, block_tables, ctx_lens, L, scale
+        ).astype(q_nope.dtype)
     out = jnp.einsum("shl,lhd->shd", latent, w[..., nope:],
                      preferred_element_type=jnp.float32)
     return out.astype(q_nope.dtype)                          # [S, H, v]

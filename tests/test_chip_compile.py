@@ -5,7 +5,8 @@ a topology that is described (``v5e:2x2``), about two seconds a kernel.
 Interpret mode (every other kernel test) cannot see what Mosaic refuses —
 a slice not aligned to the tiling, too much VMEM — so these compiles guard
 each kernel variant the engine can dispatch for mistral-7b, at its
-published widths and at its tp=4 shard shapes, against every later PR.
+published widths and at its tp=4 shard shapes, and the latent decode kernel
+at sarvam-105b's, against every later PR.
 A compile that passes is not a chip run: nothing executes here.
 
 The topology is described inside a module-scoped fixture (never at import,
@@ -22,6 +23,9 @@ from jax.sharding import SingleDeviceSharding
 from production_stack_tpu.engine.config import PRESETS
 from production_stack_tpu.engine.ops.pallas.flash_prefill import (
     flash_prefill_attention,
+)
+from production_stack_tpu.engine.ops.pallas.latent_attention import (
+    latent_decode_attention_pallas,
 )
 from production_stack_tpu.engine.ops.pallas.paged_attention import (
     paged_decode_attention_pallas,
@@ -133,6 +137,28 @@ def test_flash_prefill_kernel_compiles(sds, no_persistent_cache, heads, T, C):
         ),
         sds((T, H, D), jnp.bfloat16), new, new, prefix, prefix,
         sds((), jnp.int32), sds((), jnp.int32),
+    )
+
+
+# The latent (MLA) decode kernel at sarvam-105b's published widths: 64 heads,
+# a cache row of 576 values in 640 lanes, the cell's pool (34,959 blocks) and
+# its block table (32,768 positions); the whole batch's queries and outputs,
+# the ring's three slots and the [16, 2048] table in SMEM have to fit.
+@pytest.mark.parametrize("S", [8, 16], ids=["S8", "S16"])
+def test_latent_decode_kernel_compiles(sds, no_persistent_cache, S):
+    from production_stack_tpu.engine.models import sarvam_mla
+
+    cfg = PRESETS["sarvam-105b-ep4"]
+    lanes = sarvam_mla.cache_lanes(cfg)
+    assert lanes == 640
+    _compile(
+        lambda q, c, bt, cl: latent_decode_attention_pallas(
+            q, c, bt, cl, latent_rank=cfg.kv_lora_rank,
+            scale=sarvam_mla.softmax_scale(cfg),
+        ),
+        sds((S, cfg.num_heads, lanes), jnp.bfloat16),
+        sds((34959, BS, lanes), jnp.bfloat16),
+        sds((S, cfg.max_model_len // BS), jnp.int32), sds((S,), jnp.int32),
     )
 
 
