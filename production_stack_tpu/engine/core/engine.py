@@ -984,7 +984,12 @@ class LLMEngine:
         prompt_token_ids: Optional[List[int]] = None,
         sampling_params: Optional[SamplingParams] = None,
         adapter: Optional[str] = None,
+        arrival_time: Optional[float] = None,
+        submitted_time: Optional[float] = None,
     ) -> None:
+        """``arrival_time`` / ``submitted_time``: the API server's stamps
+        of where the request arrived and where it was handed to the step
+        thread (obs/engine.py); without them the arrival is now."""
         if prompt_token_ids is None:
             if prompt is None:
                 raise ValueError("need prompt or prompt_token_ids")
@@ -1035,6 +1040,12 @@ class LLMEngine:
             echo_prompt_len=len(prompt_token_ids),
             guide=guide,
         )
+        if arrival_time is not None:
+            # The stamp the Sequence took just now is its admission; the
+            # arrival is the handler's, before the wait for this thread.
+            seq.admitted_time = seq.arrival_time
+            seq.submitted_time = submitted_time
+            seq.arrival_time = arrival_time
         self._seqs[request_id] = seq
         self.scheduler.add_seq(seq)
         self.total_prompt_tokens += len(prompt_token_ids)

@@ -99,6 +99,13 @@ class Sequence:
     # histogram).  Maintained only when obs.tracing is on.
     first_scheduled_time: Optional[float] = None
     last_token_time: Optional[float] = None
+    # A request that came through the API server: ``arrival_time`` is the
+    # handler's stamp, ``submitted_time`` the append to the step thread's
+    # hand-over list, ``admitted_time`` add_request on the step thread.
+    # Both None where add_request was called directly (tests, a lockstep
+    # follower): the arrival is the admission then.  Read by obs/ only.
+    submitted_time: Optional[float] = None
+    admitted_time: Optional[float] = None
     # Host-offload bookkeeping: host buffer ids per paged-out block.
     offloaded: bool = False
     # Mid-chunked-prefill: the sequence sits at its queue's head holding

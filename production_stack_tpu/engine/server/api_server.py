@@ -30,7 +30,10 @@ from production_stack_tpu.engine.server.async_engine import (
     DeadlineExceeded,
 )
 from production_stack_tpu.obs.histogram import render_histogram
-from production_stack_tpu.obs.trace import parse_traceparent
+from production_stack_tpu.obs.trace import (
+    parse_request_start,
+    parse_traceparent_ids,
+)
 from production_stack_tpu.router.stats import vocabulary as vocab
 from production_stack_tpu.utils.drain import DrainController
 from production_stack_tpu.utils.log import init_logger
@@ -558,6 +561,11 @@ def build_engine_app(
         return await _serve_completion(request, chat=False)
 
     async def _serve_completion(request: web.Request, chat: bool) -> web.StreamResponse:
+        obs = engine.engine.obs
+        # Where the request arrives, before its body is read: the start of
+        # its trace and of tpu:ttft_seconds / tpu:e2e_latency_seconds
+        # (Sequence.arrival_time).  None with tracing off: no stamp taken.
+        received = time.time() if obs.enabled else None
         try:
             body = await request.json()
         except json.JSONDecodeError:
@@ -870,6 +878,7 @@ def build_engine_app(
                 sampling_params=prime_params,
                 request_id=request_id,
                 adapter=adapter,
+                received=received,
             )
             try:
                 async for _event in gen:
@@ -943,19 +952,24 @@ def build_engine_app(
             else:
                 engine.engine.disagg_handoff_misses += 1
 
-        obs = engine.engine.obs
         if obs.enabled:
             # Start the trace only AFTER every validation 400 above: a
             # rejected request must not leave a permanently-active trace
             # (the bounded active map would evict legitimate in-flight
-            # timelines under a stream of rejects).  The router-propagated
-            # W3C context joins this timeline to the router's.  With n>1
+            # timelines under a stream of rejects); it opens AT
+            # ``received`` all the same.  The router-propagated W3C
+            # context joins this timeline to the router's: its trace id,
+            # and the router's span as this root's parent.  With n>1
             # the trace follows the PRIMARY choice (choice 0 shares the
             # request id); sibling choices' engine lifecycles are not
             # traced — their token counts still land in the histograms.
+            trace_id, parent_span_id = parse_traceparent_ids(
+                request.headers.get("traceparent"))
             obs.start_request(
-                request_id,
-                parse_traceparent(request.headers.get("traceparent")),
+                request_id, trace_id, received=received,
+                upstream_start=parse_request_start(
+                    request.headers.get("x-request-start"), received),
+                parent_span_id=parent_span_id,
                 model=model_name, path=request.path, stream=stream,
                 n=n_choices,
             )
@@ -975,6 +989,7 @@ def build_engine_app(
                 sampling_params=choice_params(i),
                 request_id=choice_ids[i],
                 adapter=adapter,
+                received=received,
             )
             for i in range(n_choices)
         ]
@@ -1092,6 +1107,8 @@ def build_engine_app(
             # the router proxy sniffs it to keep a compile-excluded TTFT
             # window without parsing every chunk.
             compile_stamped = False
+            # The stream's first write has not returned yet (obs only).
+            first_write_due = obs.enabled
             try:
                 remaining = n_choices
                 while remaining:
@@ -1148,6 +1165,9 @@ def build_engine_app(
                         await response.write(
                             f"data: {json.dumps(payload)}\n\n".encode()
                         )
+                        if first_write_due:
+                            first_write_due = False
+                            obs.on_first_written(request_id, time.time())
                         first[i] = False
                     if stopped or event.finished:
                         if stopped or event.finish_reason == FinishReason.STOP:

@@ -96,7 +96,9 @@ class AsyncEngine:
             self._slice_monitor = GroupLivenessMonitor(lockstep)
         self._loop: Optional[asyncio.AbstractEventLoop] = None
         self._queues: Dict[str, asyncio.Queue] = {}
-        self._pending: List = []  # (request_id, prompt_ids, sampling_params)
+        # (request_id, prompt_ids, sampling_params, adapter, received,
+        # submitted): the last two are obs stamps, None with tracing off.
+        self._pending: List = []
         self._aborts: List[str] = []
         self._lock = threading.Lock()
         self._shutdown = threading.Event()
@@ -175,7 +177,12 @@ class AsyncEngine:
         sampling_params: Optional[SamplingParams] = None,
         request_id: Optional[str] = None,
         adapter: Optional[str] = None,
+        received: Optional[float] = None,
     ) -> AsyncIterator[TokenEvent]:
+        """``received``: the handler's stamp of the request's arrival
+        (tracing on), carried to ``Sequence.arrival_time`` so that the
+        engine's latency families start where the request arrived and not
+        where the step thread first met it."""
         request_id = request_id or f"req-{uuid.uuid4().hex[:12]}"
         queue: asyncio.Queue = asyncio.Queue()
         self._queues[request_id] = queue
@@ -184,9 +191,11 @@ class AsyncEngine:
         params = sampling_params or SamplingParams()
         if params.deadline is not None:
             self._any_deadlines = True
+        submitted = time.time() if received is not None else None
         with self._lock:
             self._pending.append(
-                (request_id, prompt_token_ids, params, adapter)
+                (request_id, prompt_token_ids, params, adapter, received,
+                 submitted)
             )
             self._pending_tokens += len(prompt_token_ids)
         self._wakeup.set()
@@ -416,10 +425,9 @@ class AsyncEngine:
                 )
 
                 self._lockstep.publish(StepEvents(
-                    requests=[
-                        (rid, toks, params, adapter)
-                        for rid, toks, params, adapter in pending
-                    ],
+                    # The wire format carries no stamps: a follower's
+                    # observations are on its own clock.
+                    requests=[p[:4] for p in pending],
                     aborts=list(aborts),
                 ))
                 last_publish = time.time()
@@ -436,13 +444,16 @@ class AsyncEngine:
                         "queued; shed before occupying a batch slot"
                     ),
                 )
-            for request_id, token_ids, params, adapter in pending:
+            for (request_id, token_ids, params, adapter, received,
+                 submitted) in pending:
                 try:
                     self.engine.add_request(
                         request_id,
                         prompt_token_ids=token_ids,
                         sampling_params=params,
                         adapter=adapter,
+                        arrival_time=received,
+                        submitted_time=submitted,
                     )
                 except Exception as e:
                     self._emit(request_id, e)

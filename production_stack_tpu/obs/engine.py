@@ -13,6 +13,7 @@ allocations, no per-step bookkeeping — restoring the pre-tracing fast path.
 from __future__ import annotations
 
 import contextlib
+import logging
 import threading
 import time
 from collections import deque
@@ -28,6 +29,8 @@ from production_stack_tpu.obs.histogram import (
     render_histogram,
 )
 from production_stack_tpu.obs.trace import Tracer
+
+logger = logging.getLogger(__name__)
 
 # Engine step phases (host-side attribution of ONE engine step; every
 # observation is per-step so the families are unit-comparable).  Keys map
@@ -80,8 +83,25 @@ _ENCLOSING = frozenset(STEP_PHASES) - frozenset(PHASES)
 # TOTAL host detokenize cost (accumulated across its tokens in the API
 # server) — a request-level quantity, which is why it lives here and not
 # in the per-step families above.
+#
+# A request's time to first token, hop by hop (one stamp each, time.time()):
+#   upstream (router's x-request-start) -> received (handler entry)
+#   -> submitted (AsyncEngine.generate's append) -> admitted (add_request
+#   on the step thread) -> first scheduled -> first token -> first_written
+#   (the stream's first write returned)
+# request_upstream / request_admit / request_pending / queue_time /
+# prefill_time / first_token_write are the six gaps, in that order; ttft
+# and e2e_latency start at ``received``, so that ttft = admit + pending +
+# queue + prefill.
 REQUEST_HISTS = ("ttft", "itl", "e2e_latency", "queue_time", "prefill_time",
-                 "decode_time", "detokenize_time")
+                 "decode_time", "detokenize_time", "request_upstream",
+                 "request_admit", "request_pending", "first_token_write")
+
+# A step-thread phase longer than this is a stall: one WARNING line and
+# tpu:step_stall_total{phase}.  Every stream the engine serves stands still
+# for as long, and tpu:last_step_age_seconds shows it only to a scrape that
+# lands inside it.
+STALL_S = 1.0
 
 # Async KV transfer-plane phases -> ``tpu:*_seconds`` families
 # (vocabulary.TPU_KV_HISTOGRAMS).  Observed from the plane's BACKGROUND
@@ -98,12 +118,19 @@ KV_PHASES = ("remote_kv_fetch", "offload_stage")
 # accumulated host time interleaved WITH engine.decode (marked
 # accumulated=True on the span): it can push phase_sum slightly above
 # total for detokenize-heavy outputs, bounded by the detokenize fraction.
-# The other five partition the wall clock.
+# The others partition the wall clock: the engine's follow each other from
+# its hand-over list on without hole or overlap (engine.first_write is
+# there for a streamed request; engine.decode starts where it ends).
+# engine.upstream and engine.admit are left out: the router's own two spans
+# cover the same stretch (a stream's response headers, which end
+# router.backend_connect, leave the engine at engine.admit's end).
 PHASE_SPAN_NAMES = (
     "router.queue",
     "router.backend_connect",
+    "engine.pending",
     "engine.queue",
     "engine.prefill",
+    "engine.first_write",
     "engine.decode",
     "engine.detokenize",
 )
@@ -161,6 +188,8 @@ class _PhaseSpan:
             if obs.compile_tracker.events_total != self._compiles0:
                 name = "compile"
             obs._keep_phase(self._rec, name, self._t0, t1)
+            if t1 - self._t0 > STALL_S * 1e9:
+                obs._on_stall(name, self._rec, (t1 - self._t0) / 1e9)
         return False
 
 
@@ -218,6 +247,8 @@ class EngineObs:
         # Last profiler session (/start_profile, /stop_profile): unix ns
         # taken before and after start_trace / stop_trace.
         self._profile: Dict[str, List[int]] = {}
+        # Phases that lasted over STALL_S, by phase (step thread writes).
+        self.step_stalls: Dict[str, int] = dict.fromkeys(PHASES, 0)
         if self.enabled:
             self.compile_tracker.on_launch = self._on_launch
 
@@ -264,6 +295,17 @@ class EngineObs:
             else:
                 self._loose.append([name, t0, t1])
 
+    def _on_stall(self, phase: str, rec: Optional[WindowRecord],
+                  seconds: float) -> None:
+        self.step_stalls[phase] += 1
+        logger.warning(
+            "step thread stalled: phase=%s seconds=%.3f window_id=%s "
+            "kind=%s k=%s rows=%s",
+            phase, seconds,
+            *((rec.window_id, rec.kind, rec.k, rec.rows)
+              if rec is not None else (None,) * 4),
+        )
+
     # stackcheck: allow=SC201 reason=launch stamps are observability sinks; no plan state reads them (obs layer is plan-inert by contract)
     def _on_launch(self, program: str) -> None:
         """A tracked jit callable is about to be called (compile tracker
@@ -290,23 +332,72 @@ class EngineObs:
 
     # -- request lifecycle (engine step thread) ----------------------------
 
+    @staticmethod
+    def _hops(seq):
+        """(arrival, submitted, admitted) of a sequence; where add_request
+        was called directly the three are one instant."""
+        arrival, admitted = seq.arrival_time, seq.admitted_time
+        if admitted is None:
+            return arrival, arrival, arrival
+        submitted = seq.submitted_time
+        return arrival, admitted if submitted is None else submitted, admitted
+
     # stackcheck: allow=SC201 reason=observability timeline math; the whole obs layer is plan-inert by contract (tracing=False removes it entirely and greedy parity is asserted in tests)
     def on_first_scheduled(self, seq, now: Optional[float] = None) -> None:
-        """First prefill chunk launched: the queue-wait span ends here."""
+        """First prefill chunk launched: the queue-wait span ends here,
+        and the two before it (handler -> hand-over list -> this thread)
+        are known by now."""
         if not self.enabled:
             return
         now = now if now is not None else time.time()
-        self.request_hists["queue_time"].observe(now - seq.arrival_time)
-        self.tracer.add_span(seq.seq_id, "engine.queue", seq.arrival_time, now)
+        arrival, submitted, admitted = self._hops(seq)
+        spans = [("engine.queue", admitted, now)]
+        if seq.admitted_time is not None:
+            spans = [("engine.admit", arrival, submitted),
+                     ("engine.pending", submitted, admitted)] + spans
+        self.tracer.with_trace(
+            seq.seq_id, lambda t: [t.add_span(*span) for span in spans])
 
     def on_first_token(self, seq, now: float) -> None:
+        """The time to first token and its four parts, observed together:
+        over any set of requests ttft = admit + pending + queue + prefill
+        exactly (a request that never gets here is in none of them)."""
         if not self.enabled:
             return
-        self.request_hists["ttft"].observe(now - seq.arrival_time)
+        hists = self.request_hists
+        hists["ttft"].observe(now - seq.arrival_time)
         sched = seq.first_scheduled_time
         if sched is not None:
-            self.request_hists["prefill_time"].observe(now - sched)
+            arrival, submitted, admitted = self._hops(seq)
+            if seq.admitted_time is not None:
+                hists["request_admit"].observe(submitted - arrival)
+                hists["request_pending"].observe(admitted - submitted)
+            hists["queue_time"].observe(sched - admitted)
+            hists["prefill_time"].observe(now - sched)
             self.tracer.add_span(seq.seq_id, "engine.prefill", sched, now)
+
+    def on_first_written(self, request_id: str, now: float) -> None:
+        """The stream's first write has returned (event loop): the first
+        token's way from the step thread to the socket, engine.prefill's
+        end -> ``now``.  engine.decode starts where this span ends, also
+        where the request finished before the write returned."""
+        if not self.enabled:
+            return
+
+        def mark(trace):
+            ends = [s.end for s in trace.spans if s.name == "engine.prefill"]
+            if not ends:
+                return None
+            first = ends[0]
+            trace.add_span("engine.first_write", first, max(first, now))
+            for s in trace.spans:
+                if s.name == "engine.decode":
+                    s.start = min(max(s.start, now), s.end)
+            return max(0.0, now - first)
+
+        seconds = self.tracer.with_trace(request_id, mark)
+        if seconds is not None:
+            self.request_hists["first_token_write"].observe(seconds)
 
     def on_token_gap(self, seq, gap: float) -> None:
         if not self.enabled:
@@ -324,7 +415,15 @@ class EngineObs:
         first = seq.first_token_time
         if first is not None:
             self.request_hists["decode_time"].observe(now - first)
-            self.tracer.add_span(seq.seq_id, "engine.decode", first, now)
+
+            def decode(trace):
+                # After the first write where there was one: the spans
+                # tile the timeline; the family above keeps its meaning.
+                start = max([first] + [s.end for s in trace.spans
+                                       if s.name == "engine.first_write"])
+                trace.add_span("engine.decode", min(start, now), now)
+
+            self.tracer.with_trace(seq.seq_id, decode)
         self.tracer.finish(
             seq.seq_id,
             end=now,
@@ -365,11 +464,28 @@ class EngineObs:
     # -- server-side hooks -------------------------------------------------
 
     def start_request(
-        self, request_id: str, trace_id: Optional[str], **attrs
+        self, request_id: str, trace_id: Optional[str],
+        received: Optional[float] = None,
+        upstream_start: Optional[float] = None,
+        parent_span_id: Optional[str] = None, **attrs
     ) -> None:
+        """Open the trace at ``received`` (the handler's entry; now where
+        the caller took no stamp).  ``upstream_start``: when the router in
+        front took the request (a sane x-request-start header), observed
+        as engine.upstream / tpu:request_upstream_seconds.
+        ``parent_span_id``: the sender's span (traceparent's parent-id),
+        kept as an attribute: the span that caused this root."""
         if not self.enabled:
             return
-        self.tracer.start(request_id, trace_id=trace_id, attrs=attrs)
+        if parent_span_id is not None:
+            attrs["parent_span_id"] = parent_span_id
+        self.tracer.start(
+            request_id, trace_id=trace_id, attrs=attrs, start=received)
+        if upstream_start is not None and received is not None:
+            self.request_hists["request_upstream"].observe(
+                received - upstream_start)
+            self.tracer.add_span(
+                request_id, "engine.upstream", upstream_start, received)
 
     def record_detokenize(self, request_id: str, seconds: float) -> None:
         """Accumulated host detokenize time for one request, reported by
@@ -401,6 +517,8 @@ class EngineObs:
             parts.append(render_histogram(vocab.TPU_STEP_HISTOGRAMS[phase], hist))
         for phase, hist in self.kv_hists.items():
             parts.append(render_histogram(vocab.TPU_KV_HISTOGRAMS[phase], hist))
+        parts.append(vocab.render_labeled_counter(
+            vocab.TPU_STEP_STALL, "phase", self.step_stalls))
         return "".join(parts)
 
     def debug_payload(self) -> Dict:

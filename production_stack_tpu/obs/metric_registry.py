@@ -317,6 +317,12 @@ REGISTRY = {
                 "of, left out); counted on the device, read back with "
                 "the tokens; zero for a model that routes nothing",
     },
+    "tpu:step_stall_total": {
+        "kind": "counter", "layer": "engine", "labels": ("phase",),
+        "mirrors": ("fake_engine", "dashboard", "docs"),
+        "help": "Step-thread phases that lasted over a second (every "
+                "stream stood still as long); a WARNING line names each",
+    },
     "tpu:moe_experts_touched_total": {
         "kind": "counter", "layer": "engine",
         "mirrors": ("fake_engine", "dashboard", "docs"),
@@ -400,7 +406,7 @@ REGISTRY = {
     "tpu:ttft_seconds": {
         "kind": "histogram", "layer": "engine",
         "mirrors": ("fake_engine", "dashboard", "docs"),
-        "help": "Per-request time to first token",
+        "help": "Per-request time to first token, from the handler's entry",
     },
     "tpu:itl_seconds": {
         "kind": "histogram", "layer": "engine",
@@ -410,12 +416,35 @@ REGISTRY = {
     "tpu:e2e_latency_seconds": {
         "kind": "histogram", "layer": "engine",
         "mirrors": ("fake_engine", "dashboard", "docs"),
-        "help": "Per-request end-to-end latency",
+        "help": "Per-request end-to-end latency, from the handler's entry",
     },
     "tpu:queue_time_seconds": {
         "kind": "histogram", "layer": "engine",
         "mirrors": ("fake_engine", "dashboard", "docs"),
         "help": "Admission -> first schedule",
+    },
+    "tpu:request_upstream_seconds": {
+        "kind": "histogram", "layer": "engine",
+        "mirrors": ("fake_engine", "dashboard", "docs"),
+        "help": "The router's x-request-start -> the handler's entry "
+                "(observed only where the header is present and sane)",
+    },
+    "tpu:request_admit_seconds": {
+        "kind": "histogram", "layer": "engine",
+        "mirrors": ("fake_engine", "dashboard", "docs"),
+        "help": "Handler entry -> handed to the step thread: body, "
+                "validation, template, tokenise, admission check",
+    },
+    "tpu:request_pending_seconds": {
+        "kind": "histogram", "layer": "engine",
+        "mirrors": ("fake_engine", "dashboard", "docs"),
+        "help": "Handed to the step thread -> admitted by it: the wait "
+                "for the pass in flight to end",
+    },
+    "tpu:first_token_write_seconds": {
+        "kind": "histogram", "layer": "engine",
+        "mirrors": ("fake_engine", "dashboard", "docs"),
+        "help": "First token sampled -> the stream's first write returned",
     },
     "tpu:prefill_time_seconds": {
         "kind": "histogram", "layer": "engine",

@@ -19,7 +19,7 @@ import secrets
 import threading
 import time
 from collections import OrderedDict, deque
-from typing import Deque, Dict, List, Optional
+from typing import Callable, Deque, Dict, List, Optional, Tuple
 
 
 def new_trace_id() -> str:
@@ -30,24 +30,68 @@ def new_span_id() -> str:
     return secrets.token_hex(8)
 
 
-def parse_traceparent(value: Optional[str]) -> Optional[str]:
-    """Extract the trace-id from a W3C traceparent header
-    (``00-<32 hex trace-id>-<16 hex parent-id>-<2 hex flags>``).
-    Returns None for absent/malformed headers (a malformed header must
-    start a fresh trace, never 500 the request path)."""
-    if not value:
-        return None
-    parts = value.strip().split("-")
-    if len(parts) < 4:
-        return None
-    trace_id = parts[1].lower()
-    if len(trace_id) != 32 or trace_id == "0" * 32:
+def _hex_id(value: str, width: int) -> Optional[str]:
+    value = value.lower()
+    if len(value) != width or value == "0" * width:
         return None
     try:
-        int(trace_id, 16)
+        int(value, 16)
     except ValueError:
         return None
-    return trace_id
+    return value
+
+
+def parse_traceparent_ids(
+    value: Optional[str],
+) -> Tuple[Optional[str], Optional[str]]:
+    """(trace-id, parent-id) of a W3C traceparent header
+    (``00-<32 hex trace-id>-<16 hex parent-id>-<2 hex flags>``).  The
+    parent-id is the sender's span: the one that caused the receiver's
+    root.  ``(None, None)`` for an absent or malformed header (it must
+    start a fresh trace, never 500 the request path); a sound trace-id
+    with an unsound parent-id keeps the trace-id."""
+    if not value:
+        return None, None
+    parts = value.strip().split("-")
+    if len(parts) < 4:
+        return None, None
+    trace_id = _hex_id(parts[1], 32)
+    if trace_id is None:
+        return None, None
+    return trace_id, _hex_id(parts[2], 16)
+
+
+def parse_traceparent(value: Optional[str]) -> Optional[str]:
+    """The trace-id alone (see :func:`parse_traceparent_ids`)."""
+    return parse_traceparent_ids(value)[0]
+
+
+# ``x-request-start: t=<unix seconds>`` (the convention nginx and Heroku
+# use): when the proxy in front took the request.  Older than this, or in
+# the future, and the two hosts' clocks do not agree: no span is made.
+REQUEST_START_MAX_AGE_S = 300.0
+
+
+def make_request_start(t: float) -> str:
+    return f"t={t:.6f}"
+
+
+def parse_request_start(value: Optional[str], now: float) -> Optional[float]:
+    """The instant an ``x-request-start`` header names, or None where it is
+    absent, malformed, in the future or stale: nothing is observed then,
+    and the request is served all the same."""
+    if not value:
+        return None
+    head, _, tail = value.strip().partition("=")
+    if head != "t":
+        return None
+    try:
+        t = float(tail)
+    except ValueError:
+        return None
+    if not (now - REQUEST_START_MAX_AGE_S <= t <= now):
+        return None
+    return t
 
 
 def make_traceparent(trace_id: str, span_id: Optional[str] = None) -> str:
@@ -240,15 +284,23 @@ class Tracer:
         with self._lock:
             return [t.to_dict() for t in self._completed]
 
+    def with_trace(self, request_id: str, fn: Callable[[RequestTrace], object]):
+        """``fn(trace)`` under the lock, for an active or a recently
+        completed trace: the way to read and change one trace in one step
+        from either thread.  None when the trace is unknown or tracing is
+        off."""
+        if not self.enabled:
+            return None
+        with self._lock:
+            trace = self._get_locked(request_id)
+            return None if trace is None else fn(trace)
+
     def add_span(
         self, request_id: str, name: str, start: float, end: float, **attrs
     ) -> None:
-        if not self.enabled:
-            return
-        trace = self.get(request_id)
-        if trace is not None:
-            with self._lock:
-                trace.add_span(name, start, end, **attrs)
+        self.with_trace(
+            request_id, lambda t: t.add_span(name, start, end, **attrs)
+        )
 
     def get_attr(self, request_id: str, key: str, default=None):
         """Lock-held read of one trace attribute (e.g. the compile taint

@@ -28,7 +28,12 @@ from typing import Any, Dict, Optional
 import aiohttp
 from aiohttp import web
 
-from production_stack_tpu.obs.trace import make_traceparent, parse_traceparent
+from production_stack_tpu.obs.trace import (
+    make_request_start,
+    make_traceparent,
+    new_span_id,
+    parse_traceparent,
+)
 from production_stack_tpu.router.capacity import (
     CAPACITY_MODEL,
     FLEET_ADMISSION,
@@ -97,6 +102,10 @@ _HOP_BY_HOP = {
     # explicitly (the inbound value may be the one we minted from a
     # `timeout` body field).
     "x-request-deadline",
+    # When the router took the request (``t=<unix seconds>``, the nginx /
+    # Heroku convention): re-stamped from in_router_time so the engine can
+    # time the hop; a client's own value must not reach it.
+    "x-request-start",
     # Disagg control plane: the router mints these itself (the prime
     # marker and the handoff token) — an external client must not be able
     # to smuggle either through the proxy.
@@ -434,6 +443,7 @@ async def process_request(
 
     headers = _forward_headers(request.headers)
     headers["x-request-id"] = request_id
+    headers["x-request-start"] = make_request_start(in_router_time)
     if extra_headers:
         # Router-minted control headers (the disagg handoff token) —
         # added after the hop-by-hop strip so clients cannot spoof them.
@@ -445,8 +455,12 @@ async def process_request(
         headers["x-request-deadline"] = repr(float(deadline))
     if trace is not None:
         # Propagate the trace context so the engine's timeline joins this
-        # one under the same trace id (/debug/requests/{id}).
-        headers["traceparent"] = make_traceparent(trace.trace_id)
+        # one under the same trace id (/debug/requests/{id}); the span id
+        # sent is kept here, and the engine keeps it as its root's
+        # ``parent_span_id``.
+        span_id = new_span_id()
+        tracer.set_attrs(request_id, span_id=span_id)
+        headers["traceparent"] = make_traceparent(trace.trace_id, span_id)
     elif request.headers.get("traceparent"):
         # Tracing off: stay a transparent proxy for the caller's context
         # (it was stripped from the generic forward set above).

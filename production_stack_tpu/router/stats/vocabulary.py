@@ -213,6 +213,9 @@ TPU_PREFILL_ATTN_TILE_STATES = ("live", "skipped")
 TPU_MOE_ASSIGNMENTS = "tpu:moe_assignments_total"
 TPU_MOE_ASSIGNMENT_WHERE = ("held", "away")
 TPU_MOE_EXPERTS_TOUCHED = "tpu:moe_experts_touched_total"
+# Step-thread phases (obs.engine.PHASES) that lasted over a second: every
+# stream stood still for as long.  One WARNING line each names the window.
+TPU_STEP_STALL = "tpu:step_stall_total"
 # Mixed K-step windows (scheduler mixed_window): prompt tokens whose
 # prefill chunks rode the device-resident decode scan — the subset of
 # tpu:prefill_chunk_tokens that did NOT pay a per-chunk host
@@ -347,6 +350,17 @@ TPU_REQUEST_HISTOGRAMS = {
     "prefill_time": "tpu:prefill_time_seconds",
     "decode_time": "tpu:decode_time_seconds",
     "detokenize_time": "tpu:detokenize_time_seconds",
+    # The time to first token hop by hop (obs/engine.py): the router's
+    # x-request-start -> the handler's entry; -> AsyncEngine.generate's
+    # append (parse, template, tokenise, admission check); -> add_request
+    # on the step thread (the wait for the pass in flight to end); then
+    # queue_time and prefill_time; then the first token's way from the
+    # step thread to the socket.  ttft and e2e_latency start at the
+    # handler's entry: ttft = admit + pending + queue_time + prefill_time.
+    "request_upstream": "tpu:request_upstream_seconds",
+    "request_admit": "tpu:request_admit_seconds",
+    "request_pending": "tpu:request_pending_seconds",
+    "first_token_write": "tpu:first_token_write_seconds",
 }
 
 # Engine step-phase families, keyed by obs.EngineObs.STEP_PHASES names

@@ -26,7 +26,10 @@ from aiohttp import web
 
 from production_stack_tpu.obs.engine import EngineObs
 from production_stack_tpu.obs.histogram import Histogram, render_histogram
-from production_stack_tpu.obs.trace import parse_traceparent
+from production_stack_tpu.obs.trace import (
+    parse_request_start,
+    parse_traceparent_ids,
+)
 from production_stack_tpu.router.stats import vocabulary as vocab
 
 
@@ -872,9 +875,15 @@ def build_fake_engine_app(state: FakeEngineState | None = None) -> web.Applicati
             else:
                 state.disagg_handoff_misses += 1
         t_recv = time.time()
+        # As the real engine: the router's trace id and span, and the hop
+        # from the router (x-request-start) where it says when it began.
+        trace_id, parent_span_id = parse_traceparent_ids(
+            request.headers.get("traceparent"))
         state.obs.start_request(
-            request_id,
-            parse_traceparent(request.headers.get("traceparent")),
+            request_id, trace_id, received=t_recv,
+            upstream_start=parse_request_start(
+                request.headers.get("x-request-start"), t_recv),
+            parent_span_id=parent_span_id,
             model=body.get("model", state.model), stream=stream,
         )
         state.obs.tracer.add_span(request_id, "engine.queue", t_recv, t_recv)

@@ -252,11 +252,14 @@ def check_metrics(sources: List[SourceFile], cfg: C.Config) -> List[Violation]:
                 if re.search(rf"\b{re.escape(dname)}\b", fake_text):
                     mirrored.update(fams)
             # EngineObs.render_metrics() renders every histogram family
-            # in the vocabulary dicts — using it IS the mirror.
+            # in the vocabulary dicts and the step thread's stall counter
+            # — using it IS the mirror.
             if "render_metrics" in fake_text or "EngineObs" in fake_text:
                 for dname in ("TPU_REQUEST_HISTOGRAMS", "TPU_STEP_HISTOGRAMS",
                               "TPU_KV_HISTOGRAMS"):
                     mirrored.update(dicts.get(dname, set()))
+                if "TPU_STEP_STALL" in consts:
+                    mirrored.add(consts["TPU_STEP_STALL"])
         for fam, meta in sorted(registry.items()):
             if meta.get("layer") != "engine":
                 continue
