@@ -461,6 +461,11 @@ class LLMEngine:
         # tpu:moe_assignments_total{where} / tpu:moe_experts_touched_total.
         self.moe_assignments: Dict[str, int] = {"held": 0, "away": 0}
         self.moe_experts_touched = 0
+        # tpu:sample_dispatch_total / tpu:sample_sorted_dispatch_total:
+        # dispatched programs that sample, and those whose rows make the
+        # sampler sort the vocabulary (sampling.needs_sort).
+        self.sample_dispatches = 0
+        self.sample_sorted_dispatches = 0
         self._prefill_fn = self._jit(
             "prefill_fn",
             partial(
@@ -734,6 +739,7 @@ class LLMEngine:
         # transfers.
         self._pipe_tables = None
         self._pipe_sampling = None  # (temps, top_ps, top_ks, min_ps, seeds)
+        self._pipe_sample_sorts = False  # needs_sort of those, on the host
         self._pipe_adapter = None
         self._pipe_table_lens: List[int] = []
         # decode_host_gap_ms: host time between one decode step retiring
@@ -1537,6 +1543,14 @@ class LLMEngine:
             self.obs.compile_tracker.drain_events(), rec,
         )
 
+    def _count_sample_dispatch(self, sorts) -> None:
+        """One more dispatched program that samples (decode window, mixed
+        window, single step, prefill tail).  ``sorts``: the host's reading
+        of the device's predicate (``sampling.needs_sort``) over the
+        parameter arrays the program was handed; no read-back."""
+        self.sample_dispatches += 1
+        self.sample_sorted_dispatches += bool(sorts)
+
     def _note_decode_launch(self) -> None:
         """Host-gap bookkeeping: time since the previous decode step
         retired with the device left idle.  Lookahead dispatches count a
@@ -1597,6 +1611,7 @@ class LLMEngine:
             sampled = self._sample_fn(
                 logits, temps, top_ps, top_ks, step_key, seeds, min_p=min_ps,
             )
+            self._count_sample_dispatch(self._pipe_sample_sorts)
         self._step_counter += 1
         self._note_compiles(rec)
         # stackcheck: allow=SC201 reason=host_s is a stats field (host-gap metric); no plan state reads it
@@ -1627,6 +1642,9 @@ class LLMEngine:
                 adapter[i] = seq.adapter_idx
             temps, top_ps, top_ks, min_ps, seeds = self._sampling_arrays(
                 seqs, S
+            )
+            self._pipe_sample_sorts = sampling_lib.needs_sort(
+                temps, top_ps, top_ks
             )
             packed = np.stack([
                 tokens, positions, ctx_lens, slot_blocks, slot_offsets,
@@ -1775,6 +1793,10 @@ class LLMEngine:
             "repetition": self._put(h["repetition"], batch_spec),
             "use_penalties": h["use_penalties"],
             "use_min_floor": h["use_min_floor"],
+            # The host's reading of the sampler's device predicate, for
+            # the dispatch counters; chained windows carry it unchanged,
+            # as they carry the arrays.
+            "sample_sorts": sampling_lib.needs_sort(temps, top_ps, top_ks),
         }
         if h["use_penalties"]:
             # Device-resident occurrence state, built by scatter from
@@ -2053,6 +2075,7 @@ class LLMEngine:
                     use_min_floor=state["use_min_floor"],
                     **lora_kwargs,
                 )
+            self._count_sample_dispatch(state["sample_sorts"])
             # One key ordinal per iteration: single-token stepping would
             # have burned exactly these counter values for the same
             # tokens.
@@ -2188,6 +2211,7 @@ class LLMEngine:
                     **lora_kwargs,
                 )
             )
+        self._count_sample_dispatch(state["sample_sorts"])
         # chunk_ordinal is the window's BASE step counter: a final
         # chunk at iteration f is K=1 step (base + f), and the
         # collect-side first-token sample burns exactly that ordinal —
@@ -3726,6 +3750,9 @@ class LLMEngine:
             self._step_counter if step_ordinal is None else step_ordinal
         )
         step_key = jax.random.PRNGKey(self.config.seed + ordinal)
+        self._count_sample_dispatch(
+            sampling_lib.needs_sort(temps, top_ps, top_ks)
+        )
         return logits, self._sample_fn(
             logits,
             jnp.asarray(temps),
@@ -4381,6 +4408,10 @@ class LLMEngine:
             # and step (zero for a model that routes nothing).
             "moe_assignments": dict(self.moe_assignments),
             "moe_experts_touched": self.moe_experts_touched,
+            # Dispatched programs that sample, and those among them whose
+            # rows make the sampler sort the vocabulary.
+            "sample_dispatches": self.sample_dispatches,
+            "sample_sorted_dispatches": self.sample_sorted_dispatches,
             # Quantized KV tiering plane: bytes crossing each tier
             # boundary by wire format, and snapshot serde versions put
             # on the kvserver wire (tpu:kv_wire_bytes_total /

@@ -1,8 +1,13 @@
 """Token sampling: greedy / temperature / top-k / top-p, fully vectorized.
 
 One jitted function handles a mixed batch (each sequence has its own
-temperature/top-k/top-p/seed); the greedy-vs-sampled choice is a
-``jnp.where``, not control flow, so the whole batch stays one XLA program.
+temperature/top-k/top-p/seed) and stays one XLA program.  What a step pays
+follows what its rows ask for, decided on the device from the parameter
+arrays the program already holds (``lax.cond``: one branch runs): a batch
+in which no row samples takes the argmax and nothing else, one in which a
+row samples draws, and sorts the vocabulary (once, for both filters) only
+if a sampling row set top-k or top-p.  Within a sampling batch the
+greedy-vs-sampled choice per row is a ``jnp.where``.
 """
 
 from __future__ import annotations
@@ -15,19 +20,31 @@ import jax.numpy as jnp
 NEG_INF = -1e30
 
 
-def _apply_top_k(logits: jax.Array, top_k: jax.Array) -> jax.Array:
-    """Mask logits below the k-th largest.  top_k<=0 disables."""
+def needs_sort(temperature, top_p, top_k):
+    """Does a row that samples ask for a filter over the sorted vocabulary?
+    Operators only: the device predicate (jax arrays) and the host's
+    counter of it (numpy, ``tpu:sample_sorted_dispatch_total``) are this
+    one expression."""
+    return ((temperature > 0) & ((top_k > 0) | (top_p < 1.0))).any()
+
+
+def _apply_top_k_top_p(
+    logits: jax.Array, top_k: jax.Array, top_p: jax.Array
+) -> jax.Array:
+    """Mask logits below the k-th largest (top_k<=0 disables), then nucleus
+    filtering of what is left (top_p>=1 disables), from ONE descending sort.
+    The top-k mask is monotone, so applied to the sorted row it gives the
+    sorted form of the masked row: what a second sort would return."""
     V = logits.shape[-1]
+    use_k = (top_k > 0)[:, None]
     sorted_desc = jnp.sort(logits, axis=-1)[..., ::-1]  # [S, V]
     k = jnp.clip(top_k, 1, V)
     kth = jnp.take_along_axis(sorted_desc, (k - 1)[:, None], axis=-1)  # [S,1]
     masked = jnp.where(logits < kth, NEG_INF, logits)
-    return jnp.where((top_k > 0)[:, None], masked, logits)
+    logits = jnp.where(use_k, masked, logits)
+    masked = jnp.where(sorted_desc < kth, NEG_INF, sorted_desc)
+    sorted_desc = jnp.where(use_k, masked, sorted_desc)
 
-
-def _apply_top_p(logits: jax.Array, top_p: jax.Array) -> jax.Array:
-    """Nucleus filtering.  top_p>=1 disables."""
-    sorted_desc = jnp.sort(logits, axis=-1)[..., ::-1]
     probs = jax.nn.softmax(sorted_desc, axis=-1)
     cumulative = jnp.cumsum(probs, axis=-1)
     # Keep tokens whose cumulative mass (exclusive) is below top_p; the
@@ -61,20 +78,28 @@ def sample_tokens(
 ) -> jax.Array:
     """Returns sampled token ids [S] (int32)."""
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    samples = temperature > 0
 
-    safe_temp = jnp.where(temperature > 0, temperature, 1.0)
-    scaled = logits / safe_temp[:, None]
-    scaled = _apply_top_k(scaled, top_k)
-    scaled = _apply_top_p(scaled, top_p)
-    if min_p is not None:
-        scaled = _apply_min_p(scaled, min_p)
+    def draw():
+        safe_temp = jnp.where(samples, temperature, 1.0)
+        scaled = logits / safe_temp[:, None]
+        scaled = jax.lax.cond(
+            needs_sort(temperature, top_p, top_k),
+            lambda x: _apply_top_k_top_p(x, top_k, top_p),
+            lambda x: x,
+            scaled,
+        )
+        if min_p is not None:
+            scaled = _apply_min_p(scaled, min_p)
+        keys = jax.vmap(lambda s: jax.random.fold_in(step_key, s))(seq_seeds)
+        sampled = jax.vmap(
+            lambda key, row: jax.random.categorical(key, row)
+        )(keys, scaled).astype(jnp.int32)
+        return jnp.where(samples, sampled, greedy)
 
-    keys = jax.vmap(lambda s: jax.random.fold_in(step_key, s))(seq_seeds)
-    sampled = jax.vmap(
-        lambda key, row: jax.random.categorical(key, row)
-    )(keys, scaled).astype(jnp.int32)
-
-    return jnp.where(temperature > 0, sampled, greedy)
+    # Padded rows carry temperature 0, so the batch itself says when
+    # nobody samples.
+    return jax.lax.cond(samples.any(), draw, lambda: greedy)
 
 
 def compute_logprobs(logits: jax.Array, token_ids: jax.Array) -> jax.Array:

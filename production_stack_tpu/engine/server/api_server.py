@@ -397,6 +397,11 @@ def build_engine_app(
             # routed layers and decode steps (the labeled family, pairs
             # by where they fell, renders below).
             (vocab.TPU_MOE_EXPERTS_TOUCHED, s["moe_experts_touched"]),
+            # Programs that sample, and those that sort the vocabulary for
+            # it: both from boot, so that their ratio reads 0, not nothing.
+            (vocab.TPU_SAMPLE_DISPATCH, s["sample_dispatches"]),
+            (vocab.TPU_SAMPLE_SORTED_DISPATCH,
+             s["sample_sorted_dispatches"]),
             # Slice-group lifecycle (0 on single-host engines): the group
             # epoch steps on every group restart, and drain relays count
             # follower-initiated slice-wide drains (docs/robustness.md).

@@ -323,6 +323,18 @@ REGISTRY = {
         "help": "Step-thread phases that lasted over a second (every "
                 "stream stood still as long); a WARNING line names each",
     },
+    "tpu:sample_dispatch_total": {
+        "kind": "counter", "layer": "engine",
+        "mirrors": ("fake_engine", "dashboard", "docs"),
+        "help": "Dispatched programs that sample: decode window, mixed "
+                "window, single step, prefill tail",
+    },
+    "tpu:sample_sorted_dispatch_total": {
+        "kind": "counter", "layer": "engine",
+        "mirrors": ("fake_engine", "dashboard", "docs"),
+        "help": "Of those, the ones in which a sampling row set top-k or "
+                "top-p, so that every step sorts the vocabulary once",
+    },
     "tpu:moe_experts_touched_total": {
         "kind": "counter", "layer": "engine",
         "mirrors": ("fake_engine", "dashboard", "docs"),

@@ -213,6 +213,13 @@ TPU_PREFILL_ATTN_TILE_STATES = ("live", "skipped")
 TPU_MOE_ASSIGNMENTS = "tpu:moe_assignments_total"
 TPU_MOE_ASSIGNMENT_WHERE = ("held", "away")
 TPU_MOE_EXPERTS_TOUCHED = "tpu:moe_experts_touched_total"
+# The sampler does what its rows ask for (engine/sampling.py): dispatched
+# programs that sample (decode window, mixed window, single step, prefill
+# tail), and those among them in which a sampling row set top-k or top-p,
+# so that the step sorts the vocabulary.  Counted on the host from the
+# arrays the program is handed, with the device predicate's expression.
+TPU_SAMPLE_DISPATCH = "tpu:sample_dispatch_total"
+TPU_SAMPLE_SORTED_DISPATCH = "tpu:sample_sorted_dispatch_total"
 # Step-thread phases (obs.engine.PHASES) that lasted over a second: every
 # stream stood still for as long.  One WARNING line each names the window.
 TPU_STEP_STALL = "tpu:step_stall_total"
@@ -321,6 +328,8 @@ TPU_COUNTERS = frozenset({
     TPU_DEADLINE_EXPIRED,
     TPU_MULTISTEP_WASTED_TOKENS,
     TPU_MOE_EXPERTS_TOUCHED,
+    TPU_SAMPLE_DISPATCH,
+    TPU_SAMPLE_SORTED_DISPATCH,
     TPU_MIXED_WINDOW_CHUNK_TOKENS,
     TPU_ENCODE_TEXTS,
     TPU_WINDOW_TRANSFER_OVERLAP_SECONDS,

@@ -543,6 +543,9 @@ def build_fake_engine_app(state: FakeEngineState | None = None) -> web.Applicati
             (vocab.TPU_MULTISTEP_WASTED_TOKENS, 0),
             # The fake routes nothing: the families, at zero (SC303).
             (vocab.TPU_MOE_EXPERTS_TOUCHED, 0),
+            # The fake samples nothing on a device: the families, at zero.
+            (vocab.TPU_SAMPLE_DISPATCH, 0),
+            (vocab.TPU_SAMPLE_SORTED_DISPATCH, 0),
             # Batched encode lane (embed/rerank/score): live values from
             # the fake lane below — texts encoded and the queue-depth
             # gauge — so router encode-lane CI asserts batching through

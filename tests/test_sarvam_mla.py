@@ -522,8 +522,11 @@ def test_a_dense_models_step_programs_lower_to_the_text_they_had():
     commit before PR 39 (2b161f0), byte for byte: a module that hands back
     routing counts adds a result to ``window_program`` for itself alone.
     Pinned under the pinned JAX: a PR that means to change a dense cell's
-    programs re-pins these and says so."""
+    programs re-pins these and says so.  PR 43 meant to: ``window_fn`` and
+    ``sample_fn`` hold the sampler, whose work now stands under two
+    conditionals (0fcdfab572754c1d and b98d249a33611f1d before it; the
+    tokens are the same, tests/test_sampler_paths.py)."""
     assert _lowered_hashes() == {
-        "prefill_fn": "6992c098e4286882", "window_fn": "0fcdfab572754c1d",
+        "prefill_fn": "6992c098e4286882", "window_fn": "caf2df83d2edb4da",
         "win_advance_fn": "325e8149c1081481",
-        "sample_fn": "b98d249a33611f1d"}
+        "sample_fn": "18cb405d3f877b3e"}
