@@ -74,6 +74,19 @@ class ModelConfig:
     num_shared_experts: int = 0
     first_k_dense_replace: int = 0
     routed_scaling_factor: float = 1.0
+    # Same module.  ``q_lora_rank`` (0 = one full query projection): the
+    # query goes through a latent of that rank with an RMSNorm of its own.
+    # ``hc_mult`` (0 = the plain residual, decided in Python at trace time):
+    # a token carries that many residual streams, and each sub-layer reads
+    # a mix of them and writes back through a mixing matrix made doubly
+    # stochastic by ``hc_sinkhorn_iters`` row-then-column normalisations
+    # (``hc_eps`` in each divisor) of ``exp`` of its entries clamped to
+    # +-``hc_res_clamp`` (models/sarvam_mla.py: the residual path).
+    q_lora_rank: int = 0
+    hc_mult: int = 0
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp: float = 30.0
 
     def __post_init__(self):
         if self.head_dim is None:
@@ -350,6 +363,90 @@ PRESETS = {
         num_shared_experts=1,
         first_k_dense_replace=1,
         routed_scaling_factor=2.5,
+    ),
+    # Xing4.0-29B-A4B (https://huggingface.co/XingChen-AGI/Xing4.0-29B-A4B,
+    # model_type xing4_0) AS ONE PIPELINE STAGE, not the whole model: every
+    # width, all 64 experts (4 a token, 1 shared) and the whole vocabulary as
+    # published, and of the published 40 layers (2 dense leads) one dense
+    # lead and five routed: 4.793 B parameters, 9.59 GB of bf16, what one
+    # v5e chip of a pipeline that holds whole layers has (bench/configs/
+    # xing4.0-29b-a4b-stage.json states the deployment; PERF.md section 4
+    # the arithmetic).  The checkpoint's multi-token-prediction block is not
+    # built.  The published max is 262,144 positions; 32,768 is the serving
+    # limit the cache is sized for.
+    "xing4.0-29b-a4b-stage": ModelConfig(
+        name="xing4.0-29b-a4b-stage",
+        vocab_size=131072,
+        hidden_size=3584,
+        intermediate_size=9216,
+        num_layers=6,
+        num_heads=32,
+        num_kv_heads=1,
+        head_dim=576,
+        max_model_len=32768,
+        rope_theta=10000.0,
+        rope_scaling={
+            "type": "deepseek_yarn",   # the config says "yarn": read as this
+            "factor": 64,
+            "original_max_position_embeddings": 4096,
+            "beta_fast": 32,
+            "beta_slow": 1,
+            "mscale": 1,
+            "mscale_all_dim": 1,
+        },
+        rms_norm_eps=1e-6,
+        kv_lora_rank=512,
+        qk_nope_head_dim=128,
+        qk_rope_head_dim=64,
+        v_head_dim=128,
+        q_lora_rank=768,
+        num_experts=64,
+        router_experts=64,
+        num_experts_per_tok=4,
+        moe_intermediate_size=1024,
+        num_shared_experts=1,
+        first_k_dense_replace=1,
+        routed_scaling_factor=2.0,
+        hc_mult=4,
+        hc_sinkhorn_iters=20,
+        hc_eps=1e-6,
+        hc_res_clamp=30.0,
+    ),
+    # Its CPU size: a dense lead and two routed layers, 4 residual streams,
+    # 20 normalisations, every one of the router's 8 experts held.
+    "tiny-xing": ModelConfig(
+        name="tiny-xing",
+        vocab_size=384,
+        hidden_size=64,
+        intermediate_size=128,
+        num_layers=3,
+        num_heads=4,
+        num_kv_heads=1,
+        head_dim=48,
+        max_model_len=2048,
+        rope_scaling={
+            "type": "deepseek_yarn",
+            "factor": 64,
+            "original_max_position_embeddings": 64,
+            "beta_fast": 32,
+            "beta_slow": 1,
+            "mscale": 1,
+            "mscale_all_dim": 1,
+        },
+        rms_norm_eps=1e-6,
+        kv_lora_rank=32,
+        qk_nope_head_dim=16,
+        qk_rope_head_dim=16,
+        v_head_dim=16,
+        q_lora_rank=24,
+        num_experts=8,
+        router_experts=8,
+        num_experts_per_tok=2,
+        moe_intermediate_size=32,
+        num_shared_experts=1,
+        first_k_dense_replace=1,
+        routed_scaling_factor=2.0,
+        hc_mult=4,
     ),
 }
 

@@ -122,7 +122,7 @@ class _PendingStep:
     spec_stats: Optional[tuple] = None
     spec_drafter: Optional[str] = None
     # A routed model's still-in-flight per-step counts ([K, n] int32, the
-    # module's ROUTING_STATS), read back with the tokens at collect.
+    # module's stats_names), read back with the tokens at collect.
     routing: Optional[object] = None
     # Mixed K-step windows: the chunk schedule that rode the scan (one
     # PrefillPlan per live iteration — packed windows interleave several
@@ -449,18 +449,33 @@ class LLMEngine:
 
         # Jitted step functions.  KV caches are donated so updates alias the
         # same HBM; cfg and mesh are closed over (static).
-        # A module that counts what its router did on the device
-        # (ROUTING_STATS, models/registry.py) is asked for the counts on the
+        # A module that counts on the device what its router (or its residual
+        # path) did (stats_names, models/registry.py) is asked for the counts on the
         # steps the served path takes: the dedicated prefill and the K-step
         # window.  They come back as one more result and are read with the
         # tokens; a module without the attribute is called as ever.
-        self._routing_names = getattr(self.model, "ROUTING_STATS", ())
+        self._routing_names = (
+            self.model.stats_names(cfg) if hasattr(self.model, "stats_names")
+            else ())
+        # Which of them fold by a maximum over steps and dispatches (a
+        # fullest expert's rows, a worst row sum); every other adds.
+        self._routing_max = np.array([
+            name in self.model.STATS_MAX for name in self._routing_names],
+            bool)
         counting = {"return_stats": True} if self._routing_names else {}
         # Prefill dispatches' counts still on the device: (record, [n]).
         self._routing_pending: Deque[tuple] = deque()
         # tpu:moe_assignments_total{where} / tpu:moe_experts_touched_total.
         self.moe_assignments: Dict[str, int] = {"held": 0, "away": 0}
         self.moe_experts_touched = 0
+        # tpu:mhc_clamped_total / tpu:mhc_entries_total /
+        # tpu:mhc_sinkhorn_err: a residual path of several streams' mixing
+        # matrices (models/sarvam_mla.py: RESIDUAL_STATS), entries the clamp
+        # changed of entries seen, and the largest |row sum - 1| any
+        # dispatch has read after the last normalisation.
+        self.mhc_clamped = 0
+        self.mhc_entries = 0
+        self.mhc_sinkhorn_err = 0.0
         # tpu:sample_dispatch_total / tpu:sample_sorted_dispatch_total:
         # dispatched programs that sample, and those whose rows make the
         # sampler sort the vocabulary (sampling.needs_sort).
@@ -834,6 +849,11 @@ class LLMEngine:
                 "Attention: decode=%s prefill=%s (%s)",
                 *self.model.attention_paths(cfg), self.model.__name__,
             )
+            if hasattr(self.model, "residual_path"):
+                residual = self.model.residual_path(cfg)
+                if residual:
+                    logger.info(
+                        "Residual: streams=%d sinkhorn=%d (%s)", *residual)
             return
         decode_kernel = attn_ops.use_pallas_decode(
             cfg.num_kv_heads // par.tensor_parallel, cfg.head_dim
@@ -2344,17 +2364,22 @@ class LLMEngine:
             chunk_rec, chunk = self._routing_pending.popleft()
             done.append((chunk_rec, np.asarray(chunk)))
         for rec, counts in done:
-            # Counts add over the steps; the last, a fullest expert's rows,
-            # is a max.
+            # Counts add over the steps; a fullest expert's rows and a worst
+            # row sum are maxima.
             counts = counts.reshape(-1, counts.shape[-1])
-            folded = [int(n) for n in counts[:, :-1].sum(0)] + [
-                int(counts[:, -1].max())]
-            assigned, here, touched = folded[:3]
+            folded = dict(zip(self._routing_names, (int(n) for n in np.where(
+                self._routing_max, counts.max(0), counts.sum(0)))))
+            here = folded["moe_assigned_here"]
             self.moe_assignments["held"] += here
-            self.moe_assignments["away"] += assigned - here
-            self.moe_experts_touched += touched
+            self.moe_assignments["away"] += folded["moe_assigned"] - here
+            self.moe_experts_touched += folded["experts_touched"]
+            if "mhc_entries" in folded:
+                self.mhc_clamped += folded["mhc_clamped"]
+                self.mhc_entries += folded["mhc_entries"]
+                self.mhc_sinkhorn_err = max(
+                    self.mhc_sinkhorn_err, folded["mhc_err_e6"] / 1e6)
             if rec is not None:
-                rec.routing = dict(zip(self._routing_names, folded))
+                rec.routing = folded
 
     def _replay_window(self, p: _PendingStep, arr):
         """The host half of a window's collect: (outputs, the token counts
@@ -4408,6 +4433,12 @@ class LLMEngine:
             # and step (zero for a model that routes nothing).
             "moe_assignments": dict(self.moe_assignments),
             "moe_experts_touched": self.moe_experts_touched,
+            # Several residual streams' mixing matrices (zero for a model
+            # with one stream): entries the clamp changed, entries seen,
+            # the largest |row sum - 1| after the last normalisation.
+            "mhc_clamped": self.mhc_clamped,
+            "mhc_entries": self.mhc_entries,
+            "mhc_sinkhorn_err": self.mhc_sinkhorn_err,
             # Dispatched programs that sample, and those among them whose
             # rows make the sampler sort the vocabulary.
             "sample_dispatches": self.sample_dispatches,

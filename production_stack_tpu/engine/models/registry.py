@@ -35,10 +35,27 @@ the step programs (``core/step_programs.py``) carry without looking inside.
   experts each row chose (int32 [routed layers, rows, k], ids over the
   router's published width), logits bit-equal with and without; the
   benchmark's compare follows it (``bench/harness/compare.py``).
-- ``ROUTING_STATS`` (names) with ``return_stats=True`` on both steps: one
-  more result, an int32 vector of what routing did, counted on the device;
-  the engine asks for it on the dedicated prefill and inside the K-step
-  window and reads it back with the tokens (flight records, ``/metrics``).
+- ``stats_names(cfg)`` (names) and ``STATS_MAX`` (those of them that fold by
+  a maximum, not a sum) with ``return_stats=True`` on both steps: one more
+  result, an int32 vector of what routing (and a residual path of several
+  streams) did, counted on the device; the engine asks for it on the
+  dedicated prefill and inside the K-step window and reads it back with the
+  tokens (flight records, ``/metrics``).
+- with ``init_cache``, ``residual_path(cfg) -> None | (streams,
+  normalisations, path)``: the boot line ``Residual: ...`` of a module whose
+  tokens carry several residual streams.
+
+**What runs, by mechanism** (ROADMAP Queue 2 lists what does not): dense GQA
+with one sliding window, int8 weights, a softmax-routed MoE (``llama.py``);
+and in ``sarvam_mla.py`` a latent (MLA) cache of one array a layer with the
+absorbed decode in a Pallas kernel, a full or a low-rank query path
+(``q_lora_rank``), a norm a query head or none, routed experts behind a biased
+sigmoid router held by share or whole with a shared expert, leading dense
+layers, ``deepseek_yarn``, and several residual streams mixed at every
+sub-layer by a Sinkhorn-normalised matrix (``hc_mult``, ``hc_sinkhorn_iters``,
+``hc_eps``, ``hc_res_clamp``; counters ``mhc_clamped`` / ``mhc_entries`` /
+``mhc_err_e6``), taken in Python at trace time so that a configuration
+without them traces the program it always had.
 """
 
 from __future__ import annotations
@@ -60,6 +77,10 @@ MODEL_REGISTRY = {
     # Latent attention over routed experts held by share: a cache of one
     # array a layer, which the module makes (init_cache).
     "sarvam": sarvam_mla,
+    # The same module's other family: several residual streams mixed by a
+    # doubly stochastic matrix around that latent attention with a low-rank
+    # query path, every routed expert held (cfg.hc_mult, cfg.q_lora_rank).
+    "xing": sarvam_mla,
 }
 
 

@@ -3,9 +3,28 @@ paged latent cache): the ``sarvam_mla`` architecture.
 
 Pre-norm blocks, RMSNorm: ``h = x + Attn(norm1(x))``, ``y = h + FFN(norm2(h))``.
 The first ``cfg.first_k_dense_replace`` layers' FFN is a SwiGLU of
-``cfg.intermediate_size``; the others are routed.
+``cfg.intermediate_size``; the others are routed.  Two families are served,
+told apart by the configuration alone: ``sarvam_mla`` (one residual stream, a
+full query projection, a norm a query head, experts held by share) and
+``xing4_0`` (``cfg.hc_mult`` residual streams, a low-rank query path, every
+expert held).  One latent attention, one cache, one router and one grouped
+dispatch serve both.
 
-**Latent attention.**  ``q = W_q x`` per head, split into a part without
+**The residual path.**  With ``cfg.hc_mult`` 0 the plain sum above, taken in
+Python at trace time: no operation of what follows is in such a program.
+Else a token carries ``n = cfg.hc_mult`` streams ``X [n, d]`` (the embedding
+copied into each; summed before the final norm) and every sub-layer ``F`` owns
+a mapping (:func:`_mhc`): ``[p, q, r] = RMSNorm(vec X) W``, ``H_pre =
+sigmoid(a_pre p + b_pre)``, ``H_post = 2 sigmoid(a_post q + b_post)``,
+``H_res`` = ``exp`` of ``a_res mat(r) + b_res`` clamped to
++-``cfg.hc_res_clamp``, made doubly stochastic by ``cfg.hc_sinkhorn_iters``
+row-then-column normalisations; ``y = F(norm(H_pre X))``, ``X' = H_res X +
+H_post^T y``.  The mapping is float32 throughout (its weights too); the
+streams keep the activations' dtype.
+
+**Latent attention.**  ``q = W_q x`` per head (with ``cfg.q_lora_rank``:
+``q = W_qb RMSNorm(W_qa x)``, a latent of that rank with a learned scale),
+split into a part without
 position (``qk_nope_head_dim``) and a rotary part (``qk_rope_head_dim``);
 ``[c ; r] = W_kva x``, ``c`` the latent (``kv_lora_rank``), ``r`` one rotary
 key shared by all heads.  With ``cfg.use_qk_norm`` an RMSNorm with a learned
@@ -45,8 +64,9 @@ experts its rows touch.
 What a module must offer the engine, and what it may, is in
 ``models/registry.py``.  This one offers ``init_params``, ``quantize_params``
 (identity: bf16 throughout), ``prefill``, ``decode``, ``init_cache``,
-``cache_bytes_per_token``, ``param_specs`` and, on both steps,
-``return_choice`` and ``return_stats``.  It has no ``mixed_step``, no
+``cache_bytes_per_token``, ``param_specs``, ``attention_paths``,
+``residual_path``, ``stats_names`` and, on both steps, ``return_choice`` and
+``return_stats``.  It has no ``mixed_step``, no
 ``encode``, no LoRA, no int8, no tensor parallelism: the engine refuses those
 at boot by name.
 """
@@ -75,8 +95,21 @@ LatentCaches = List[jax.Array]   # a layer: [num_blocks, block_size, width]
 ROUTING_STATS = (
     "moe_assigned", "moe_assigned_here", "experts_touched", "expert_rows_max",
 )
+# What the residual path appends to that vector where ``cfg.hc_mult`` is set,
+# over live rows and every mapping of the dispatch: entries of ``R`` the clamp
+# changed; entries seen; the largest |row sum - 1| of an ``H_res`` after its
+# last normalisation, x 1e6 (its columns sum to 1 by construction).
+RESIDUAL_STATS = ("mhc_clamped", "mhc_entries", "mhc_err_e6")
+# Of all those, the ones that fold by a maximum (over layers here, over steps
+# and dispatches in the engine); every other adds.
+STATS_MAX = ("expert_rows_max", "mhc_err_e6")
 KEY_TILE = 512      # keys a tile of the expanded (prefill) attention
 SCORE_ROWS = 32768  # heads x chunk slots of one tile's scores held at once
+
+
+def stats_names(cfg: ModelConfig) -> tuple:
+    """The names of ``return_stats``' vector for this configuration."""
+    return ROUTING_STATS + (RESIDUAL_STATS if cfg.hc_mult else ())
 
 
 def cache_width(cfg: ModelConfig) -> int:
@@ -120,16 +153,28 @@ def _shapes(cfg: ModelConfig, layer_idx: int) -> Dict[str, tuple]:
     shapes = {
         "input_layernorm": (h,),
         "post_attention_layernorm": (h,),
-        "q_proj": (h, H * qd),
         "kv_a_proj": (h, cache_width(cfg)),
         "kv_a_layernorm": (cfg.kv_lora_rank,),
         "kv_b_proj": (cfg.kv_lora_rank,
                       H * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
         "o_proj": (H * cfg.v_head_dim, h),
     }
+    if cfg.q_lora_rank:
+        shapes.update({"q_a_proj": (h, cfg.q_lora_rank),
+                       "q_a_layernorm": (cfg.q_lora_rank,),
+                       "q_b_proj": (cfg.q_lora_rank, H * qd)})
+    else:
+        shapes["q_proj"] = (h, H * qd)
     if cfg.use_qk_norm:
         shapes["q_norm"] = (qd,)
         shapes["k_rope_norm"] = (cfg.qk_rope_head_dim,)
+    n = cfg.hc_mult
+    for sub in ("attn", "ffn") if n else ():
+        # [p, q, r] = x W: 2n + n^2 columns; a scale each of the three
+        # groups; a bias a column.
+        shapes.update({f"hc_{sub}_w": (n * h, 2 * n + n * n),
+                       f"hc_{sub}_alpha": (3,),
+                       f"hc_{sub}_bias": (2 * n + n * n,)})
     if _is_routed(cfg, layer_idx):
         E, I = cfg.num_experts, cfg.moe_intermediate_size
         S = cfg.num_shared_experts * I
@@ -148,7 +193,11 @@ def _shapes(cfg: ModelConfig, layer_idx: int) -> Dict[str, tuple]:
 
 
 _NORMS = ("input_layernorm", "post_attention_layernorm", "kv_a_layernorm",
-          "q_norm", "k_rope_norm")
+          "q_a_layernorm", "q_norm", "k_rope_norm")
+# What the mappings' R is drawn to: a_res x (r of unit variance) + b_res
+# spreads over several units, so that exp(R) spans orders of magnitude and
+# twenty normalisations differ from three (tests/test_xing_mhc.py).
+HC_RES_SPREAD = 3.0
 
 
 def param_specs(cfg: ModelConfig) -> Dict:
@@ -166,7 +215,9 @@ def init_params(cfg: ModelConfig, key: jax.Array, shardings=None) -> Params:
     one expert stack is the largest thing that ever exists beside the
     weights.  Norm scales are 1; the router's logits have unit variance at
     any width and the selection bias is drawn too (0.1, under half a
-    score's spread), so that a bias misused as a weight shows."""
+    score's spread), so that a bias misused as a weight shows.  A mapping of
+    the residual path is float32: ``W`` drawn so that ``[p, q, r]`` have unit
+    variance, its biases with 0.5, its scales 1, 1 and HC_RES_SPREAD."""
     dtype = jnp.dtype(cfg.dtype)
     makers = {}
 
@@ -212,6 +263,15 @@ def init_params(cfg: ModelConfig, key: jax.Array, shardings=None) -> Params:
                 # Scores that spread at any width: logits of unit variance.
                 layer[name] = dense(k, shapes[name], sh.get(name),
                                     cfg.hidden_size ** -0.5)
+            elif name.startswith("hc_") and name.endswith("_alpha"):
+                layer[name] = jax.device_put(
+                    jnp.array([1.0, 1.0, HC_RES_SPREAD], jnp.float32),
+                    sh.get(name))
+            elif name.startswith("hc_"):
+                std = (0.5 if name.endswith("_bias")
+                       else shapes[name][0] ** -0.5)
+                layer[name] = dense(k, shapes[name], sh.get(name), std,
+                                    jnp.float32)
             else:
                 layer[name] = dense(k, shapes[name], sh.get(name))
         params["layers"].append(layer)
@@ -290,7 +350,13 @@ def _project(layer: Params, cfg: ModelConfig, x: jax.Array, cos, sin):
     cache's rows [T, lanes]: the normed latent, the rotated key, zeros)."""
     T, H = x.shape[0], cfg.num_heads
     nope, eps = cfg.qk_nope_head_dim, cfg.rms_norm_eps
-    q = _dot(x, layer["q_proj"]).astype(x.dtype).reshape(T, H, -1)
+    if cfg.q_lora_rank:
+        c_q = rms_norm(_dot(x, layer["q_a_proj"]).astype(x.dtype),
+                       layer["q_a_layernorm"], eps)
+        q = _dot(c_q, layer["q_b_proj"])
+    else:
+        q = _dot(x, layer["q_proj"])
+    q = q.astype(x.dtype).reshape(T, H, -1)
     kv = _dot(x, layer["kv_a_proj"]).astype(x.dtype)
     c = rms_norm(kv[:, :cfg.kv_lora_rank], layer["kv_a_layernorm"], eps)
     r = kv[:, cfg.kv_lora_rank:]
@@ -406,17 +472,26 @@ def _expanded_attention(layer, cfg, q_nope, q_rope, rows, cache,
 PAGE_TILE = 128     # blocks a tile of the XLA walk over the latent pages
 
 
+def _pallas_serves() -> bool:
+    """A real TPU, and the A/B switch not set."""
+    from production_stack_tpu.engine.ops.attention import pallas_disabled
+
+    return not pallas_disabled() and jax.default_backend() == "tpu"
+
+
+def use_pallas_sinkhorn() -> bool:
+    """Trace-time dispatch check for the residual path's normalisation
+    kernel (``ops/pallas/mhc_sinkhorn.py``)."""
+    return _pallas_serves()
+
+
 def use_pallas_latent_decode(lanes: int) -> bool:
     """Trace-time dispatch check for the paged latent decode kernel
     (``ops/pallas/latent_attention.py``), as ``ops/attention.py:
     use_pallas_decode`` decides for the dense models: a real TPU, a cache
     row of whole 128-lane tiles (a DMA'd row has to be), and the A/B
     switch not set.  Everything else walks the pages in XLA."""
-    from production_stack_tpu.engine.ops.attention import pallas_disabled
-
-    if pallas_disabled() or lanes % 128:
-        return False
-    return jax.default_backend() == "tpu"
+    return lanes % 128 == 0 and _pallas_serves()
 
 
 def attention_paths(cfg: ModelConfig):
@@ -565,18 +640,143 @@ def _ffn(layer, cfg, layer_idx, x, live):
     return (shared + routed).astype(x.dtype), who, stats
 
 
-def _sum_stats(stats):
-    """Routed layers' [4] vectors -> one: counts add, the fullest is a max."""
+# -- the residual path -------------------------------------------------------
+
+
+def residual_path(cfg: ModelConfig):
+    """None for the plain residual, else (streams, normalisations, which
+    path normalises in this process), for the engine's boot line."""
+    if not cfg.hc_mult:
+        return None
+    return (cfg.hc_mult, cfg.hc_sinkhorn_iters,
+            "pallas" if use_pallas_sinkhorn() else "xla")
+
+
+def _sinkhorn(M, iters: int, eps: float):
+    """``M`` [n, n, T] positive, the tokens on the last (lane) axis ->
+    doubly stochastic a token: rows then columns, ``iters`` times, unrolled.
+    The plain form, which serves off the TPU; on it the same arithmetic is
+    one kernel (:func:`use_pallas_sinkhorn`)."""
+    for _ in range(iters):
+        M = M / (M.sum(1, keepdims=True) + eps)
+        M = M / (M.sum(0, keepdims=True) + eps)
+    return M
+
+
+def _mhc(layer: Params, cfg: ModelConfig, sub: str, X, live):
+    """One sub-layer's mapping, from the streams it is about to read: ``X``
+    [T, n, d] -> (H_pre [T, n], H_post [T, n], H_res [n, n, T], its counts
+    [3] int32, RESIDUAL_STATS, over ``live`` rows), float32 all through."""
+    T, n, _d = X.shape
+    x = X.reshape(T, -1).astype(jnp.float32)
+    x = x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True)
+                          + cfg.rms_norm_eps)
+    z = jnp.dot(x, layer[f"hc_{sub}_w"], precision=jax.lax.Precision.HIGHEST)
+    alpha, bias = layer[f"hc_{sub}_alpha"], layer[f"hc_{sub}_bias"]
+    h_pre = jax.nn.sigmoid(alpha[0] * z[:, :n] + bias[:n])
+    h_post = 2.0 * jax.nn.sigmoid(alpha[1] * z[:, n:2 * n] + bias[n:2 * n])
+    raw = (alpha[2] * z[:, 2 * n:] + bias[2 * n:]).T.reshape(n, n, T)
+    R = jnp.clip(raw, -cfg.hc_res_clamp, cfg.hc_res_clamp)
+    if use_pallas_sinkhorn():
+        from production_stack_tpu.engine.ops.pallas.mhc_sinkhorn import (
+            mhc_sinkhorn_pallas,
+        )
+
+        M = mhc_sinkhorn_pallas(
+            jnp.exp(R), iters=cfg.hc_sinkhorn_iters, eps=cfg.hc_eps)
+    else:
+        M = _sinkhorn(jnp.exp(R), cfg.hc_sinkhorn_iters, cfg.hc_eps)
+    err = jnp.max(jnp.where(live, jnp.abs(M.sum(1) - 1.0), 0.0))
+    counted = jnp.stack([
+        jnp.sum((R != raw) & live), live.sum() * n * n,
+        jnp.minimum(err * 1e6, 2.0**30)]).astype(jnp.int32)
+    return h_pre, h_post, M, counted
+
+
+def _sub_layer(layer: Params, cfg: ModelConfig, sub: str, x, live, F):
+    """One sub-layer on the residual path: ``x`` [T, d] (plain) or [T, n, d]
+    (streams), ``F`` its function of what it reads [T, d] -> [T, d].  Returns
+    (the path after it, the mapping's counts or None)."""
+    if not cfg.hc_mult:
+        return x + F(x), None
+    n = cfg.hc_mult
+    with jax.named_scope("mhc"):
+        h_pre, h_post, h_res, counted = _mhc(layer, cfg, sub, x, live)
+        xf = x.astype(jnp.float32)
+        h = sum(h_pre[:, j, None] * xf[:, j] for j in range(n)).astype(x.dtype)
+    y = F(h)
+    with jax.named_scope("mhc"):
+        # Four streams: sums of products, no [4, 4] matmul a token.
+        yf = y.astype(jnp.float32)
+        out = jnp.stack([
+            sum(h_res[i, j][:, None] * xf[:, j] for j in range(n))
+            + h_post[:, i, None] * yf for i in range(n)], axis=1)
+    return out.astype(x.dtype), counted
+
+
+def _streams_in(cfg: ModelConfig, x):
+    """The embedding, copied into every stream."""
+    if not cfg.hc_mult:
+        return x
+    return jnp.broadcast_to(x[:, None], (x.shape[0], cfg.hc_mult, x.shape[1]))
+
+
+def _streams_out(cfg: ModelConfig, x):
+    """The streams, summed (float32) before the final norm."""
+    if not cfg.hc_mult:
+        return x
+    return jnp.sum(x.astype(jnp.float32), axis=1).astype(x.dtype)
+
+
+def _blocks(params: Params, cfg: ModelConfig, kv_caches, x, live, attention):
+    """The layers of both steps: embeddings ``x`` [T, d] -> (what the final
+    norm reads [T, d], the new caches, each routed layer's choice, its counts,
+    each mapping's counts).  ``attention(layer, cache, normed h) -> (the
+    heads' outputs [T, H, v], the layer's new cache)`` is the step's own."""
+    T = x.shape[0]
+    x = _streams_in(cfg, x)
+    caches, choice, stats, residual = [], [], [], []
+    for i, (layer, cache) in enumerate(zip(params["layers"], kv_caches)):
+        def attend(h):
+            h = rms_norm(h, layer["input_layernorm"], cfg.rms_norm_eps)
+            out, new = attention(layer, cache, h)
+            caches.append(new)
+            return _dot(out.reshape(T, -1), layer["o_proj"]).astype(h.dtype)
+
+        def feed(h):
+            h = rms_norm(h, layer["post_attention_layernorm"],
+                         cfg.rms_norm_eps)
+            y, who, counted = _ffn(layer, cfg, i, h, live)
+            if who is not None:
+                choice.append(who)
+                stats.append(counted)
+            return y
+
+        for sub, F in (("attn", attend), ("ffn", feed)):
+            x, counted = _sub_layer(layer, cfg, sub, x, live, F)
+            if counted is not None:
+                residual.append(counted)
+    return _streams_out(cfg, x), caches, choice, stats, residual
+
+
+def _sum_stats(stats, residual):
+    """Routed layers' [4] vectors and the mappings' [3] -> one: counts add,
+    the fullest expert and the worst row sum are maxima (STATS_MAX)."""
     stats = jnp.stack(stats)
-    return jnp.concatenate([stats[:, :3].sum(0), stats[:, 3:].max(0)])
+    out = [stats[:, :3].sum(0), stats[:, 3:].max(0)]
+    if residual:
+        residual = jnp.stack(residual)
+        out += [residual[:, :2].sum(0), residual[:, 2:].max(0)]
+    return jnp.concatenate(out)
 
 
-def _result(logits, caches, choice, stats, return_choice, return_stats):
+def _result(logits, caches, choice, stats, residual, return_choice,
+            return_stats):
     out = (logits, caches)
     if return_choice:
         out += (jnp.stack(choice),)
     if return_stats:
-        out += (_sum_stats(stats),)
+        out += (_sum_stats(stats, residual),)
     return out
 
 
@@ -602,35 +802,30 @@ def prefill(
     """One sequence's prefill chunk: (last valid token's logits [V], new
     caches), then with ``return_choice`` the experts every slot chose
     (int32 [routed layers, T, k], ids over the router's width), then with
-    ``return_stats`` the chunk's routing counts ([4] int32, ROUTING_STATS)
-    over its valid slots.  With ``prompt_targets`` the third result is
+    ``return_stats`` the chunk's counts (int32, :func:`stats_names`) over its
+    valid slots.  With ``prompt_targets`` the third result is
     ``models/llama.py: prefill``'s (target_logprob [T], top_ids [T, k],
     top_logps [T, k]), the head swept in row chunks."""
     T = tokens.shape[0]
     cos, sin = _rope_tables(cfg, cached_len + jnp.arange(T))
     live = jnp.arange(T) < valid_len
-    x = params["embed_tokens"][tokens]
-    caches, choice, stats = [], [], []
-    for i, (layer, cache) in enumerate(zip(params["layers"], kv_caches)):
-        x_n = rms_norm(x, layer["input_layernorm"], cfg.rms_norm_eps)
-        q_nope, q_rope, rows = _project(layer, cfg, x_n, cos, sin)
+
+    def attention(layer, cache, h):
+        q_nope, q_rope, rows = _project(layer, cfg, h, cos, sin)
         with jax.named_scope("latent_attention_expanded"):
             out = _expanded_attention(
                 layer, cfg, q_nope, q_rope, rows, cache, prefix_block_ids,
                 cached_len, valid_len)
         bs = cache.shape[1]
-        caches.append(cache.at[new_block_ids].set(
-            rows.reshape(T // bs, bs, -1).astype(cache.dtype)))
-        x = x + _dot(out.reshape(T, -1), layer["o_proj"]).astype(x.dtype)
-        x_n = rms_norm(x, layer["post_attention_layernorm"], cfg.rms_norm_eps)
-        y, who, counted = _ffn(layer, cfg, i, x_n, live)
-        x = x + y
-        if who is not None:
-            choice.append(who)
-            stats.append(counted)
+        return out, cache.at[new_block_ids].set(
+            rows.reshape(T // bs, bs, -1).astype(cache.dtype))
+
+    x, caches, *counted = _blocks(
+        params, cfg, kv_caches, params["embed_tokens"][tokens], live,
+        attention)
     x = rms_norm(x, params["norm"], cfg.rms_norm_eps)
     logits = _dot(x[jnp.maximum(valid_len - 1, 0)], params["lm_head"])
-    out = _result(logits, caches, choice, stats, return_choice, return_stats)
+    out = _result(logits, caches, *counted, return_choice, return_stats)
     if prompt_targets is None:
         return out
     C, k = math.gcd(T, 128), max(prompt_topk, 1)
@@ -666,30 +861,24 @@ def decode(
     :func:`prefill`.  A row whose write is parked on the null block 0 (a
     padding row, a row the window froze) is not live: it is routed nowhere,
     touches no expert and is not counted."""
-    S = tokens.shape[0]
     cos, sin = _rope_tables(cfg, positions)
     live = slot_block_ids != 0
-    x = params["embed_tokens"][tokens]
-    caches, choice, stats = [], [], []
-    for i, (layer, cache) in enumerate(zip(params["layers"], kv_caches)):
-        x_n = rms_norm(x, layer["input_layernorm"], cfg.rms_norm_eps)
-        q_nope, q_rope, rows = _project(layer, cfg, x_n, cos, sin)
+
+    def attention(layer, cache, h):
+        q_nope, q_rope, rows = _project(layer, cfg, h, cos, sin)
         # Write, then attend: ctx_lens counts the new token.
         bs = cache.shape[1]
         cache = cache.reshape(-1, cache.shape[-1]).at[
             slot_block_ids * bs + slot_offsets].set(
                 rows.astype(cache.dtype)).reshape(cache.shape)
-        caches.append(cache)
         with jax.named_scope("latent_attention_absorbed"):
             out = _absorbed_attention(
                 layer, cfg, q_nope, q_rope, cache, block_tables, ctx_lens)
-        x = x + _dot(out.reshape(S, -1), layer["o_proj"]).astype(x.dtype)
-        x_n = rms_norm(x, layer["post_attention_layernorm"], cfg.rms_norm_eps)
-        y, who, counted = _ffn(layer, cfg, i, x_n, live)
-        x = x + y
-        if who is not None:
-            choice.append(who)
-            stats.append(counted)
-    x = rms_norm(x, params["norm"], cfg.rms_norm_eps)
-    logits = _dot(x, params["lm_head"])
-    return _result(logits, caches, choice, stats, return_choice, return_stats)
+        return out, cache
+
+    x, caches, *counted = _blocks(
+        params, cfg, kv_caches, params["embed_tokens"][tokens], live,
+        attention)
+    logits = _dot(rms_norm(x, params["norm"], cfg.rms_norm_eps),
+                  params["lm_head"])
+    return _result(logits, caches, *counted, return_choice, return_stats)

@@ -397,6 +397,11 @@ def build_engine_app(
             # routed layers and decode steps (the labeled family, pairs
             # by where they fell, renders below).
             (vocab.TPU_MOE_EXPERTS_TOUCHED, s["moe_experts_touched"]),
+            # Several residual streams' mixing matrices: clamped entries
+            # of entries seen, and the worst row sum's distance from 1.
+            (vocab.TPU_MHC_CLAMPED, s["mhc_clamped"]),
+            (vocab.TPU_MHC_ENTRIES, s["mhc_entries"]),
+            (vocab.TPU_MHC_SINKHORN_ERR, s["mhc_sinkhorn_err"]),
             # Programs that sample, and those that sort the vocabulary for
             # it: both from boot, so that their ratio reads 0, not nothing.
             (vocab.TPU_SAMPLE_DISPATCH, s["sample_dispatches"]),

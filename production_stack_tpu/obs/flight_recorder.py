@@ -117,6 +117,10 @@ class WindowRecord:
     # steps; ``moe_assigned_here``: those that fell on experts held here;
     # ``experts_touched``: held experts with at least one row, summed over
     # routed layers and steps; ``expert_rows_max``: the fullest one's rows.
+    # A model with several residual streams adds (RESIDUAL_STATS):
+    # ``mhc_clamped`` / ``mhc_entries``: entries of its mixing matrices'
+    # exponents the clamp changed / seen; ``mhc_err_e6``: the largest
+    # |row sum - 1| after the last normalisation, x 1e6.
     routing: Optional[Dict[str, int]] = None
 
     @property

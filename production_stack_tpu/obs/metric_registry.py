@@ -342,6 +342,27 @@ REGISTRY = {
                 "layers and decode steps: what a decode step streams of "
                 "the expert stacks",
     },
+    "tpu:mhc_clamped_total": {
+        "kind": "counter", "layer": "engine",
+        "mirrors": ("fake_engine", "dashboard", "docs"),
+        "help": "Entries of the residual streams' mixing-matrix exponents "
+                "that the clamp changed, over live rows and every mapping "
+                "of a dispatch; counted on the device, read back with the "
+                "tokens; zero for a model with one residual stream",
+    },
+    "tpu:mhc_entries_total": {
+        "kind": "counter", "layer": "engine",
+        "mirrors": ("fake_engine", "dashboard", "docs"),
+        "help": "Entries of those exponents seen (live rows x mappings x "
+                "streams squared): the clamped share's denominator",
+    },
+    "tpu:mhc_sinkhorn_err": {
+        "kind": "gauge", "layer": "engine",
+        "mirrors": ("fake_engine", "dashboard", "docs"),
+        "help": "Largest |row sum - 1| of a mixing matrix after its last "
+                "Sinkhorn normalisation that any dispatch since boot has "
+                "read (its columns sum to 1 by construction)",
+    },
     "tpu:kv_wire_bytes_total": {
         "kind": "counter", "layer": "engine", "labels": ("tier", "format"),
         "mirrors": ("fake_engine", "dashboard", "docs"),

@@ -213,6 +213,14 @@ TPU_PREFILL_ATTN_TILE_STATES = ("live", "skipped")
 TPU_MOE_ASSIGNMENTS = "tpu:moe_assignments_total"
 TPU_MOE_ASSIGNMENT_WHERE = ("held", "away")
 TPU_MOE_EXPERTS_TOUCHED = "tpu:moe_experts_touched_total"
+# A residual path of several streams (engine/models/sarvam_mla.py:
+# RESIDUAL_STATS): entries of the mixing matrices' exponents the clamp
+# changed, entries seen, and (a gauge) the largest |row sum - 1| any dispatch
+# has read after the last Sinkhorn normalisation.  Counted on the device over
+# live rows, read back with the tokens; zero for a model with one stream.
+TPU_MHC_CLAMPED = "tpu:mhc_clamped_total"
+TPU_MHC_ENTRIES = "tpu:mhc_entries_total"
+TPU_MHC_SINKHORN_ERR = "tpu:mhc_sinkhorn_err"
 # The sampler does what its rows ask for (engine/sampling.py): dispatched
 # programs that sample (decode window, mixed window, single step, prefill
 # tail), and those among them in which a sampling row set top-k or top-p,
@@ -328,6 +336,8 @@ TPU_COUNTERS = frozenset({
     TPU_DEADLINE_EXPIRED,
     TPU_MULTISTEP_WASTED_TOKENS,
     TPU_MOE_EXPERTS_TOUCHED,
+    TPU_MHC_CLAMPED,
+    TPU_MHC_ENTRIES,
     TPU_SAMPLE_DISPATCH,
     TPU_SAMPLE_SORTED_DISPATCH,
     TPU_MIXED_WINDOW_CHUNK_TOKENS,

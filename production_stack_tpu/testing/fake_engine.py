@@ -543,6 +543,10 @@ def build_fake_engine_app(state: FakeEngineState | None = None) -> web.Applicati
             (vocab.TPU_MULTISTEP_WASTED_TOKENS, 0),
             # The fake routes nothing: the families, at zero (SC303).
             (vocab.TPU_MOE_EXPERTS_TOUCHED, 0),
+            # The fake has one residual stream: the families, at zero.
+            (vocab.TPU_MHC_CLAMPED, 0),
+            (vocab.TPU_MHC_ENTRIES, 0),
+            (vocab.TPU_MHC_SINKHORN_ERR, 0.0),
             # The fake samples nothing on a device: the families, at zero.
             (vocab.TPU_SAMPLE_DISPATCH, 0),
             (vocab.TPU_SAMPLE_SORTED_DISPATCH, 0),

@@ -143,12 +143,15 @@ def test_flash_prefill_kernel_compiles(sds, no_persistent_cache, heads, T, C):
 # The latent (MLA) decode kernel at sarvam-105b's published widths: 64 heads,
 # a cache row of 576 values in 640 lanes, the cell's pool (34,959 blocks) and
 # its block table (32,768 positions); the whole batch's queries and outputs,
-# the ring's three slots and the [16, 2048] table in SMEM have to fit.
+# the ring's three slots and the [16, 2048] table in SMEM have to fit.  And at
+# Xing4.0-29B-A4B's: 32 heads a row, the same cache row.
+@pytest.mark.parametrize("preset", ["sarvam-105b-ep4",
+                                    "xing4.0-29b-a4b-stage"])
 @pytest.mark.parametrize("S", [8, 16], ids=["S8", "S16"])
-def test_latent_decode_kernel_compiles(sds, no_persistent_cache, S):
+def test_latent_decode_kernel_compiles(sds, no_persistent_cache, S, preset):
     from production_stack_tpu.engine.models import sarvam_mla
 
-    cfg = PRESETS["sarvam-105b-ep4"]
+    cfg = PRESETS[preset]
     lanes = sarvam_mla.cache_lanes(cfg)
     assert lanes == 640
     _compile(
@@ -159,6 +162,24 @@ def test_latent_decode_kernel_compiles(sds, no_persistent_cache, S):
         sds((S, cfg.num_heads, lanes), jnp.bfloat16),
         sds((34959, BS, lanes), jnp.bfloat16),
         sds((S, cfg.max_model_len // BS), jnp.int32), sds((S,), jnp.int32),
+    )
+
+
+# The residual mappings' normalisation kernel at the shapes the
+# xing4.0-29b-a4b-stage cell runs it at: a decode batch and both prefill
+# programs' slots (each padded to whole (8, 128) tiles an entry inside).
+@pytest.mark.parametrize("T", [16, 256, 2048], ids=["T16", "T256", "T2048"])
+def test_mhc_sinkhorn_kernel_compiles(sds, no_persistent_cache, T):
+    from production_stack_tpu.engine.ops.pallas.mhc_sinkhorn import (
+        mhc_sinkhorn_pallas,
+    )
+
+    cfg = PRESETS["xing4.0-29b-a4b-stage"]
+    n = cfg.hc_mult
+    _compile(
+        lambda E: mhc_sinkhorn_pallas(
+            E, iters=cfg.hc_sinkhorn_iters, eps=cfg.hc_eps),
+        sds((n, n, T), jnp.float32),
     )
 
 

@@ -83,6 +83,9 @@ def main() -> None:
     p.add_argument("--chunk-blocks", type=_ints, default=None,
                    help="blocks a stage of the kernel, several with commas "
                    "(the kernel's CHUNK_BLOCKS where not given)")
+    p.add_argument("--preset", default="sarvam-105b-ep4",
+                   help="the latent preset whose widths are timed "
+                   "(xing4.0-29b-a4b-stage: 32 query heads a row)")
     p.add_argument("--buffers", type=_ints, default=None,
                    help="slots of the kernel's ring, several with commas "
                    "(the kernel's BUFFERS where not given)")
@@ -91,7 +94,8 @@ def main() -> None:
         raise SystemExit("latent_decode_microbench: needs the chip")
     if args.page_tile:
         m.PAGE_TILE = args.page_tile
-    cfg = dataclasses.replace(PRESETS["sarvam-105b-ep4"], num_layers=2)
+    cfg = dataclasses.replace(PRESETS[args.preset], num_layers=2)
+    H = cfg.num_heads
     layer = m.init_params(cfg, jax.random.PRNGKey(0))["layers"][1]
     S, bs, bmax = args.rows, 16, cfg.max_model_len // 16
     lanes = m.cache_lanes(cfg)
@@ -105,8 +109,8 @@ def main() -> None:
     for s in range(S):
         tables[s, :need] = rng.choice(args.blocks - 1, need, replace=False) + 1
     ctx = jnp.full((S,), args.ctx, jnp.int32)
-    qn = jax.random.normal(jax.random.PRNGKey(2), (S, 64, 128), jnp.bfloat16)
-    qr = jax.random.normal(jax.random.PRNGKey(3), (S, 64, 64), jnp.bfloat16)
+    qn = jax.random.normal(jax.random.PRNGKey(2), (S, H, 128), jnp.bfloat16)
+    qr = jax.random.normal(jax.random.PRNGKey(3), (S, H, 64), jnp.bfloat16)
     tables = jnp.asarray(tables)
     need_bytes = S * args.ctx * m.cache_width(cfg) * 2
 
@@ -117,6 +121,7 @@ def main() -> None:
         jax.jit(lambda *a: m._absorbed_attention(layer, cfg, *a)),
         (qn, qr, cache, tables, ctx), args.repeat)
     print(json.dumps({
+        "preset": args.preset, "heads": H,
         "rows": S, "ctx": args.ctx, "page_tile": m.PAGE_TILE,
         "served": ("pallas" if m.use_pallas_latent_decode(lanes)
                    else "xla-walk"),
@@ -130,7 +135,7 @@ def main() -> None:
 
     L, scale = cfg.kv_lora_rank, m.softmax_scale(cfg)
     q_lat = jax.random.normal(
-        jax.random.PRNGKey(4), (S, 64, lanes), jnp.bfloat16
+        jax.random.PRNGKey(4), (S, H, lanes), jnp.bfloat16
     ) * (jnp.arange(lanes) < m.cache_width(cfg))
     inputs = (q_lat.astype(jnp.bfloat16), cache, tables, ctx)
     walk_s, want = _timed(
