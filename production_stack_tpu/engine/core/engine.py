@@ -56,6 +56,7 @@ from production_stack_tpu.engine.core.sequence import (
 )
 from production_stack_tpu.engine.kv.block_pool import (
     BlockPool,
+    extend_prefix_chain,
     prefix_block_hashes,
 )
 from production_stack_tpu.engine.kv import quant as kv_quant
@@ -481,6 +482,12 @@ class LLMEngine:
         # sampler sort the vocabulary (sampling.needs_sort).
         self.sample_dispatches = 0
         self.sample_sorted_dispatches = 0
+        # tpu:prefix_chain_blocks_total / ..._step_blocks_total: blocks of
+        # a sequence's prefix chain hashed by the API server's handler
+        # (event-loop-only writer, here) and on the step thread (the
+        # pool's chain_blocks_hashed); stats() reports the sum and the
+        # step thread's part.
+        self.prefix_chain_handler_blocks = 0
         self._prefill_fn = self._jit(
             "prefill_fn",
             partial(
@@ -1012,10 +1019,15 @@ class LLMEngine:
         adapter: Optional[str] = None,
         arrival_time: Optional[float] = None,
         submitted_time: Optional[float] = None,
+        prefix_chain: Optional[List[bytes]] = None,
     ) -> None:
         """``arrival_time`` / ``submitted_time``: the API server's stamps
         of where the request arrived and where it was handed to the step
-        thread (obs/engine.py); without them the arrival is now."""
+        thread (obs/engine.py); without them the arrival is now.
+        ``prefix_chain``: the prompt's chain as ``prompt_prefix_chain``
+        made it, off this thread; without it (a direct caller, a lockstep
+        follower, an adapter's namespace) the chain is hashed here at
+        first need."""
         if prompt_token_ids is None:
             if prompt is None:
                 raise ValueError("need prompt or prompt_token_ids")
@@ -1066,6 +1078,8 @@ class LLMEngine:
             echo_prompt_len=len(prompt_token_ids),
             guide=guide,
         )
+        if prefix_chain is not None and not cache_ns:
+            seq.prefix_chain = prefix_chain
         if arrival_time is not None:
             # The stamp the Sequence took just now is its admission; the
             # arrival is the handler's, before the wait for this thread.
@@ -1081,6 +1095,23 @@ class LLMEngine:
         # in host staging — and never fetched inside schedule().
         if self.kv_prefetch is not None and self._imports:
             self._submit_prefix_prefetch(seq)
+
+    def prompt_prefix_chain(
+        self, prompt_token_ids: List[int], adapter: Optional[str] = None
+    ) -> Optional[List[bytes]]:
+        """The chain of a prompt's full blocks for ``add_request(...,
+        prefix_chain=)``, hashed by the caller's thread — the API server's
+        handler, while the pass in flight keeps the device busy — so that
+        the step thread plans the admission without hashing.  None where
+        the namespace is the step thread's to resolve (an adapter)."""
+        if adapter or not self.block_pool.enable_prefix_caching:
+            return None
+        chain: List[bytes] = []
+        bs = self.block_pool.block_size
+        self.prefix_chain_handler_blocks += extend_prefix_chain(
+            chain, prompt_token_ids, bs, len(prompt_token_ids) // bs
+        )
+        return chain
 
     def abort_request(self, request_id: str) -> None:
         seq = self.scheduler.abort_seq(request_id)
@@ -2607,20 +2638,15 @@ class LLMEngine:
         return self._px_prefix_cache
 
     def _seq_prefix_hashes(self, seq) -> List[bytes]:
-        """Per-sequence memo: the chain is O(prompt) blake2b work and the
-        scheduler may retry admission many times.  Keyed on the prompt
-        length so recompute-preemption (which absorbs generated tokens
-        into prompt_token_ids) invalidates the memo and the absorbed
-        blocks become export/fetch-able too."""
-        key = len(seq.prompt_token_ids)
-        if getattr(seq, "_px_hashes_key", None) != key:
-            seq._px_hashes = prefix_block_hashes(
-                seq.prompt_token_ids,
-                self.block_pool.block_size,
-                namespace=seq.cache_ns,
-            )
-            seq._px_hashes_key = key
-        return seq._px_hashes
+        """The prompt's part of the sequence's chain (``Sequence.
+        prefix_chain``), with the bound match_prefix keeps: >= 1 prompt
+        token is left to prefill.  Hashes only what the memo lacks, e.g.
+        the blocks that recompute-preemption absorbed into the prompt,
+        which become export/fetch-able too."""
+        n = (seq.num_prompt_tokens - 1) // self.block_pool.block_size
+        return self.block_pool.extend_chain(
+            seq.prefix_chain, seq.prompt_token_ids, n, seq.cache_ns
+        )[:n]
 
     def _transfer_inflight(self) -> bool:
         """Any async KV transfer the scheduler may be waiting out."""
@@ -4443,6 +4469,13 @@ class LLMEngine:
             # rows make the sampler sort the vocabulary.
             "sample_dispatches": self.sample_dispatches,
             "sample_sorted_dispatches": self.sample_sorted_dispatches,
+            # Blocks of sequences' prefix chains hashed, and the part of
+            # them hashed on the step thread (the rest: by the handler).
+            "prefix_chain_blocks": (
+                self.block_pool.chain_blocks_hashed
+                + self.prefix_chain_handler_blocks
+            ),
+            "prefix_chain_step_blocks": self.block_pool.chain_blocks_hashed,
             # Quantized KV tiering plane: bytes crossing each tier
             # boundary by wire format, and snapshot serde versions put
             # on the kvserver wire (tpu:kv_wire_bytes_total /

@@ -780,7 +780,8 @@ class Scheduler:
             prefix_blocks, cached_len = [], 0
         else:
             prefix_blocks, cached_len = self.block_pool.match_prefix(
-                seq.prompt_token_ids, namespace=seq.cache_ns
+                seq.prompt_token_ids, namespace=seq.cache_ns,
+                chain=seq.prefix_chain,
             )
             if self.remote_prefix_cb is not None:
                 prefix_blocks, cached_len = self.remote_prefix_cb(
@@ -1209,7 +1210,8 @@ class Scheduler:
         # Register the sequence's full blocks for prefix reuse BEFORE
         # freeing, so the freed blocks enter the reclaimable LRU tier.
         self.block_pool.register_prefix(
-            seq.all_token_ids, seq.block_table, namespace=seq.cache_ns
+            seq.all_token_ids, seq.block_table, namespace=seq.cache_ns,
+            chain=seq.prefix_chain,
         )
         self._release(seq)
         seq.status = SequenceStatus.FINISHED

@@ -92,6 +92,14 @@ class Sequence:
     output_token_ids: List[int] = dataclasses.field(default_factory=list)
     block_table: List[int] = dataclasses.field(default_factory=list)
     num_cached_tokens: int = 0  # prefix-cache hit length at admission
+    # The prefix chain (kv/block_pool.py: extend_prefix_chain): the digest
+    # of every full block of prompt + outputs hashed so far, under
+    # ``cache_ns``.  Tokens only ever append (recompute-preemption moves
+    # outputs into the prompt and changes no position), so an entry is
+    # never invalidated: each block is hashed once in the sequence's life.
+    # Filled for the prompt by the API server's handler before the step
+    # thread meets the request, else on the step thread at first need.
+    prefix_chain: List[bytes] = dataclasses.field(default_factory=list)
     finish_reason: Optional[FinishReason] = None
     first_token_time: Optional[float] = None
     # Observability (obs/): first prefill-chunk launch (ends the queue-wait

@@ -17,12 +17,62 @@ import hashlib
 from collections import OrderedDict
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import numpy as np
+
+_NO_PREV = b"\x00" * 16
+
+
+def _pack(token_ids: Sequence[int]) -> bytes:
+    """Token ids as little-endian int32, packed in one go: fixed-width ids
+    cannot run into each other (``[1, 23]`` / ``[12, 3]``), and no
+    per-token object is made."""
+    return np.asarray(token_ids, "<i4").tobytes()
+
+
+def _block_digest(prev: bytes, ids: bytes) -> bytes:
+    """THE digest of one block: blake2b-128 over the previous block's digest
+    (16 zero bytes at the head of a chain) and the block's packed ids."""
+    return hashlib.blake2b(prev + ids, digest_size=16).digest()
+
 
 def _chain_hash(prev: Optional[bytes], tokens: Sequence[int]) -> bytes:
-    h = hashlib.blake2b(digest_size=16)
-    h.update(prev or b"\x00" * 16)
-    h.update(b",".join(str(t).encode() for t in tokens))
-    return h.digest()
+    """One block's digest from its ids (tests and tools; the served path
+    packs a sequence once, ``extend_prefix_chain``)."""
+    return _block_digest(prev or _NO_PREV, _pack(tokens))
+
+
+def _namespace_seed(namespace: int) -> Optional[bytes]:
+    """Seed the hash chain per namespace (e.g. LoRA adapter slot): KV
+    computed under one adapter must never be served to another.
+    Namespace 0 keeps the unseeded chain."""
+    return _chain_hash(None, [namespace]) if namespace else None
+
+
+def extend_prefix_chain(
+    chain: List[bytes],
+    token_ids: Sequence[int],
+    block_size: int,
+    num_blocks: int,
+    namespace: int = 0,
+) -> int:
+    """Grow ``chain`` — the digests of the leading full blocks of
+    ``token_ids`` under ``namespace`` — to ``num_blocks`` entries, hashing
+    only the blocks it lacks, and return how many that was.  The missing
+    ids are packed once and each block is a slice of that buffer.  Tokens
+    only ever append to a sequence, so an entry once made stays true: a
+    sequence keeps one chain for its life (``Sequence.prefix_chain``) and
+    every reader of the chain — match, register, the remote tiers, the
+    router — goes through here."""
+    have = len(chain)
+    if num_blocks <= have:
+        return 0
+    width = 4 * block_size
+    buf = _pack(token_ids[have * block_size : num_blocks * block_size])
+    prev = (chain[-1] if have else _namespace_seed(namespace)) or _NO_PREV
+    for start in range(0, len(buf) - width + 1, width):
+        prev = _block_digest(prev, buf[start : start + width])
+        chain.append(prev)
+    return len(chain) - have
 
 
 def prefix_block_hashes(
@@ -33,14 +83,11 @@ def prefix_block_hashes(
     for cross-engine prefix sharing through the remote KV store — two
     engines hashing the same tokens under the same namespace produce the
     same keys."""
-    usable = len(token_ids) - 1
-    prev: Optional[bytes] = (
-        _chain_hash(None, [namespace]) if namespace else None
-    )
     out: List[bytes] = []
-    for start in range(0, usable - usable % block_size, block_size):
-        prev = _chain_hash(prev, token_ids[start : start + block_size])
-        out.append(prev)
+    extend_prefix_chain(
+        out, token_ids, block_size, (len(token_ids) - 1) // block_size,
+        namespace,
+    )
     return out
 
 
@@ -61,6 +108,8 @@ class BlockPool:
         # Metrics (token-granularity, like vLLM's hit-rate gauge).
         self.query_tokens = 0
         self.hit_tokens = 0
+        # Blocks whose digest was computed through this pool (extend_chain).
+        self.chain_blocks_hashed = 0
 
     # -- capacity ----------------------------------------------------------
 
@@ -136,36 +185,53 @@ class BlockPool:
 
     # -- prefix caching ----------------------------------------------------
 
-    @staticmethod
-    def _namespace_seed(namespace: int) -> Optional[bytes]:
-        """Seed the hash chain per namespace (e.g. LoRA adapter slot): KV
-        computed under one adapter must never be served to another.
-        Namespace 0 keeps the legacy unseeded chain."""
-        return _chain_hash(None, [namespace]) if namespace else None
+    def extend_chain(
+        self,
+        chain: Optional[List[bytes]],
+        token_ids: Sequence[int],
+        num_blocks: int,
+        namespace: int = 0,
+    ) -> List[bytes]:
+        """``extend_prefix_chain`` at this pool's block size (a caller
+        without a memo gets a chain of the call's own), counted: the pool
+        belongs to the step thread, so what is hashed here is hashed there,
+        while the device may be waiting for the plan."""
+        if chain is None:
+            chain = []
+        self.chain_blocks_hashed += extend_prefix_chain(
+            chain, token_ids, self.block_size, num_blocks, namespace
+        )
+        return chain
 
     def match_prefix(
-        self, token_ids: Sequence[int], namespace: int = 0
+        self,
+        token_ids: Sequence[int],
+        namespace: int = 0,
+        chain: Optional[List[bytes]] = None,
     ) -> Tuple[List[int], int]:
         """Longest cached full-block prefix of token_ids.
 
         Returns (block_ids, num_cached_tokens); increments the matched
         blocks' refcounts (caller owns them until free()).  At least one
         token is always left uncached so prefill has work to do.
+
+        ``chain`` is the sequence's memo of digests (``Sequence.
+        prefix_chain``): what it holds is looked up and not hashed again,
+        what it lacks is hashed here and kept in it.
         """
         self.query_tokens += len(token_ids)
         if not self.enable_prefix_caching:
             return [], 0
         bs = self.block_size
-        usable = len(token_ids) - 1  # leave >=1 token for prefill
+        # leave >=1 token for prefill
+        usable_blocks = max(len(token_ids) - 1, 0) // bs
+        chain = self.extend_chain(chain, token_ids, usable_blocks, namespace)
         blocks: List[int] = []
-        prev: Optional[bytes] = self._namespace_seed(namespace)
-        for start in range(0, usable - usable % bs, bs):
-            digest = _chain_hash(prev, token_ids[start : start + bs])
+        for digest in chain[:usable_blocks]:
             block = self._hash_to_block.get(digest)
             if block is None:
                 break
             blocks.append(block)
-            prev = digest
         for block in blocks:
             if block in self._cached_free:
                 del self._cached_free[block]
@@ -211,19 +277,18 @@ class BlockPool:
         token_ids: Sequence[int],
         block_table: Sequence[int],
         namespace: int = 0,
+        chain: Optional[List[bytes]] = None,
     ) -> None:
         """Record hash chain for every *full* block of this sequence so later
-        requests with the same prefix hit the cache."""
+        requests with the same prefix hit the cache.  With the sequence's
+        ``chain`` only the blocks that its generated tokens completed are
+        hashed."""
         if not self.enable_prefix_caching:
             return
-        bs = self.block_size
-        prev: Optional[bytes] = self._namespace_seed(namespace)
-        for i in range(len(token_ids) // bs):
-            digest = _chain_hash(prev, token_ids[i * bs : (i + 1) * bs])
-            block = block_table[i]
-            existing = self._hash_to_block.get(digest)
-            if existing is None:
+        num_blocks = len(token_ids) // self.block_size
+        chain = self.extend_chain(chain, token_ids, num_blocks, namespace)
+        for digest, block in zip(chain[:num_blocks], block_table):
+            if digest not in self._hash_to_block:
                 self._evict_hash(block)  # block may have held older content
                 self._hash_to_block[digest] = block
                 self._block_to_hash[block] = digest
-            prev = digest

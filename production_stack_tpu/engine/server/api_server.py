@@ -407,6 +407,11 @@ def build_engine_app(
             (vocab.TPU_SAMPLE_DISPATCH, s["sample_dispatches"]),
             (vocab.TPU_SAMPLE_SORTED_DISPATCH,
              s["sample_sorted_dispatches"]),
+            # Blocks of prefix chains hashed, and the part the step thread
+            # hashed: from boot, so that their ratio reads 0, not nothing.
+            (vocab.TPU_PREFIX_CHAIN_BLOCKS, s["prefix_chain_blocks"]),
+            (vocab.TPU_PREFIX_CHAIN_STEP_BLOCKS,
+             s["prefix_chain_step_blocks"]),
             # Slice-group lifecycle (0 on single-host engines): the group
             # epoch steps on every group restart, and drain relays count
             # follower-initiated slice-wide drains (docs/robustness.md).

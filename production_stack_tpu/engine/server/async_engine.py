@@ -191,11 +191,14 @@ class AsyncEngine:
         params = sampling_params or SamplingParams()
         if params.deadline is not None:
             self._any_deadlines = True
+        # The prompt's prefix chain, hashed here while the pass in flight
+        # keeps the device busy, so the step thread plans without hashing.
+        chain = self.engine.prompt_prefix_chain(prompt_token_ids, adapter)
         submitted = time.time() if received is not None else None
         with self._lock:
             self._pending.append(
                 (request_id, prompt_token_ids, params, adapter, received,
-                 submitted)
+                 submitted, chain)
             )
             self._pending_tokens += len(prompt_token_ids)
         self._wakeup.set()
@@ -445,7 +448,7 @@ class AsyncEngine:
                     ),
                 )
             for (request_id, token_ids, params, adapter, received,
-                 submitted) in pending:
+                 submitted, chain) in pending:
                 try:
                     self.engine.add_request(
                         request_id,
@@ -454,6 +457,7 @@ class AsyncEngine:
                         adapter=adapter,
                         arrival_time=received,
                         submitted_time=submitted,
+                        prefix_chain=chain,
                     )
                 except Exception as e:
                     self._emit(request_id, e)

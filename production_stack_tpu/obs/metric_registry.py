@@ -335,6 +335,19 @@ REGISTRY = {
         "help": "Of those, the ones in which a sampling row set top-k or "
                 "top-p, so that every step sorts the vocabulary once",
     },
+    "tpu:prefix_chain_blocks_total": {
+        "kind": "counter", "layer": "engine",
+        "mirrors": ("fake_engine", "dashboard", "docs"),
+        "help": "Blocks of sequences' prefix chains hashed, by the API "
+                "server's handler or on the step thread: once a block in "
+                "a sequence's life",
+    },
+    "tpu:prefix_chain_step_blocks_total": {
+        "kind": "counter", "layer": "engine",
+        "mirrors": ("fake_engine", "dashboard", "docs"),
+        "help": "Of those, the blocks hashed on the step thread, where "
+                "the device may wait for the plan",
+    },
     "tpu:moe_experts_touched_total": {
         "kind": "counter", "layer": "engine",
         "mirrors": ("fake_engine", "dashboard", "docs"),

@@ -48,8 +48,10 @@ exists.
 
 Hash contract: with a ``tokenize`` callable the router derives its prefix
 keys from the ENGINE'S OWN chain — ``prefix_block_hashes`` over token-id
-blocks (engine/kv/block_pool.py, a pure-python module), byte-identical to
-the engine's ``_seq_prefix_hashes`` and therefore to the content keys
+blocks (engine/kv/block_pool.py: hashlib and numpy, no JAX; blake2b over
+the previous digest and the block's ids as little-endian int32),
+byte-identical to the engine's ``Sequence.prefix_chain`` and therefore to
+the content keys
 under which engines export/import KV blocks through the shared store.  A
 silent divergence here would steer "affine" requests to replicas whose
 store entries never match (tests/test_kv_prefetch.py asserts the

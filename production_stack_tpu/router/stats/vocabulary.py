@@ -228,6 +228,13 @@ TPU_MHC_SINKHORN_ERR = "tpu:mhc_sinkhorn_err"
 # arrays the program is handed, with the device predicate's expression.
 TPU_SAMPLE_DISPATCH = "tpu:sample_dispatch_total"
 TPU_SAMPLE_SORTED_DISPATCH = "tpu:sample_sorted_dispatch_total"
+# A sequence's prefix chain (engine/kv/block_pool.py: extend_prefix_chain)
+# is hashed once a block, by the API server's handler where it can be:
+# blocks hashed on either thread, and those among them hashed on the step
+# thread (an adapter's namespace, a lockstep follower, a direct caller,
+# and the few blocks a request's generated tokens complete).
+TPU_PREFIX_CHAIN_BLOCKS = "tpu:prefix_chain_blocks_total"
+TPU_PREFIX_CHAIN_STEP_BLOCKS = "tpu:prefix_chain_step_blocks_total"
 # Step-thread phases (obs.engine.PHASES) that lasted over a second: every
 # stream stood still for as long.  One WARNING line each names the window.
 TPU_STEP_STALL = "tpu:step_stall_total"
@@ -340,6 +347,8 @@ TPU_COUNTERS = frozenset({
     TPU_MHC_ENTRIES,
     TPU_SAMPLE_DISPATCH,
     TPU_SAMPLE_SORTED_DISPATCH,
+    TPU_PREFIX_CHAIN_BLOCKS,
+    TPU_PREFIX_CHAIN_STEP_BLOCKS,
     TPU_MIXED_WINDOW_CHUNK_TOKENS,
     TPU_ENCODE_TEXTS,
     TPU_WINDOW_TRANSFER_OVERLAP_SECONDS,

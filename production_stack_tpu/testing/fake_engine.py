@@ -550,6 +550,9 @@ def build_fake_engine_app(state: FakeEngineState | None = None) -> web.Applicati
             # The fake samples nothing on a device: the families, at zero.
             (vocab.TPU_SAMPLE_DISPATCH, 0),
             (vocab.TPU_SAMPLE_SORTED_DISPATCH, 0),
+            # The fake keeps no block pool and hashes no chain: at zero.
+            (vocab.TPU_PREFIX_CHAIN_BLOCKS, 0),
+            (vocab.TPU_PREFIX_CHAIN_STEP_BLOCKS, 0),
             # Batched encode lane (embed/rerank/score): live values from
             # the fake lane below — texts encoded and the queue-depth
             # gauge — so router encode-lane CI asserts batching through

@@ -103,3 +103,65 @@ def test_disabled_prefix_caching():
     pool.free(blocks)
     matched, cached = pool.match_prefix(list(range(8)))
     assert matched == [] and cached == 0
+
+
+# -- the sequence's chain handed in (kv/block_pool.py: extend_prefix_chain) --
+
+
+@pytest.mark.parametrize("handed", [False, True])
+def test_prefix_roundtrip_with_chain(handed):
+    """The chain a sequence keeps gives the hit of the unhanded call, and
+    holds afterwards what was hashed for it."""
+    pool = BlockPool(num_blocks=20, block_size=4)
+    tokens = list(range(10))
+    blocks = pool.allocate(3)
+    chain = [] if handed else None
+    pool.register_prefix(tokens, blocks, chain=chain)
+    pool.free(blocks)
+    hashed = pool.chain_blocks_hashed
+    assert hashed == 2
+    matched, cached = pool.match_prefix(tokens, chain=chain)
+    assert (matched, cached) == (blocks[:2], 8)
+    # With the memo the admission hashed nothing; without, both blocks again.
+    assert pool.chain_blocks_hashed - hashed == (0 if handed else 2)
+    if handed:
+        assert len(chain) == 2
+
+
+def test_chain_shorter_than_the_prompt_is_extended_in_place():
+    pool = BlockPool(num_blocks=20, block_size=4)
+    tokens = list(range(13))  # 3 full blocks, 1 token left to prefill
+    blocks = pool.allocate(4)
+    pool.register_prefix(tokens, blocks)
+    pool.free(blocks)
+    chain = []
+    pool.match_prefix(tokens[:5], chain=chain)  # the first block only
+    assert len(chain) == 1
+    hashed = pool.chain_blocks_hashed
+    matched, cached = pool.match_prefix(tokens, chain=chain)
+    assert cached == 12 and matched == blocks[:3]
+    assert len(chain) == 3 and pool.chain_blocks_hashed - hashed == 2
+
+
+def test_chain_longer_than_usable_leaves_one_token_to_prefill():
+    """A memo that already holds the digest of the prompt's last full block
+    (the handler hashes every full block) must not turn an exact-multiple
+    prompt into a full hit."""
+    pool = BlockPool(num_blocks=20, block_size=4)
+    tokens = list(range(8))
+    blocks = pool.allocate(2)
+    chain = []
+    pool.register_prefix(tokens, blocks, chain=chain)
+    pool.free(blocks)
+    assert len(chain) == 2
+    matched, cached = pool.match_prefix(tokens, chain=chain)
+    assert cached == 4 and matched == blocks[:1]
+
+
+def test_prefix_caching_off_hashes_nothing():
+    pool = BlockPool(num_blocks=20, block_size=4, enable_prefix_caching=False)
+    chain = []
+    blocks = pool.allocate(2)
+    pool.register_prefix(list(range(8)), blocks, chain=chain)
+    assert pool.match_prefix(list(range(9)), chain=chain) == ([], 0)
+    assert chain == [] and pool.chain_blocks_hashed == 0

@@ -22,6 +22,7 @@ from production_stack_tpu.engine.config import (
 )
 from production_stack_tpu.engine.core.engine import LLMEngine
 from production_stack_tpu.engine.core.sequence import SamplingParams
+from production_stack_tpu.engine.kv.block_pool import prefix_block_hashes
 from production_stack_tpu.kvserver.server import KVStore, handle_client
 
 
@@ -267,22 +268,28 @@ def test_malformed_store_entry_leaks_no_blocks(kv_port):
     assert len(tokens) == 2
 
 
-def test_prefix_hash_memo_invalidated_on_prompt_growth(kv_port):
+def test_prefix_hash_memo_follows_prompt_growth(kv_port):
     """Recompute-preemption absorbs generated tokens into
-    prompt_token_ids; the per-seq hash memo must follow (advisor r4)."""
+    prompt_token_ids; the per-seq chain must follow (advisor r4).  Tokens
+    only append, so the memo is extended, never thrown away: the blocks
+    hashed before are not hashed again."""
     engine = make_engine("decode", kv_port)
     engine.offload.remote_client.close()
     engine.offload.remote_client = None
     engine.add_request("r", prompt=PROMPT,
                        sampling_params=SamplingParams(max_tokens=2))
     seq = engine.scheduler.waiting[0]
+    bs = engine.block_pool.block_size
     h1 = engine._seq_prefix_hashes(seq)
-    assert engine._seq_prefix_hashes(seq) is h1  # memo hit
-    seq.prompt_token_ids = list(seq.prompt_token_ids) + [7, 8, 9, 10]
+    hashed = engine.block_pool.chain_blocks_hashed
+    assert engine._seq_prefix_hashes(seq) == h1  # memo hit
+    assert engine.block_pool.chain_blocks_hashed == hashed
+    seq.prompt_token_ids = list(seq.prompt_token_ids) + list(range(7, 7 + bs))
     h2 = engine._seq_prefix_hashes(seq)
-    assert h2 is not h1
-    assert len(h2) >= len(h1)
+    assert len(h2) == len(h1) + 1
     assert h2[: len(h1)] == h1  # chain prefix property preserved
+    assert engine.block_pool.chain_blocks_hashed == hashed + 1
+    assert h2 == prefix_block_hashes(seq.prompt_token_ids, bs)
 
 
 def test_disagg_role_requires_remote_url():
@@ -342,8 +349,7 @@ def test_remote_prefix_extension_clamped_to_prompt_minus_one(kv_port):
     for start in range(0, len(prompt_ids), bs):
         prev = _chain_hash(prev, prompt_ids[start : start + bs])
         full_chain.append(prev)
-    seq._px_hashes = full_chain
-    seq._px_hashes_key = len(prompt_ids)
+    seq.prefix_chain = list(full_chain)
 
     blocks, cached = engine.fetch_remote_prefix(seq, [], 0)
     assert cached <= len(prompt_ids) - 1
@@ -351,7 +357,7 @@ def test_remote_prefix_extension_clamped_to_prompt_minus_one(kv_port):
     assert len(blocks) == cached // bs
     # The plan built from this extension always has work to prefill.
     engine.block_pool.free(blocks)
-    seq._px_hashes = full_chain  # memo survives the free
+    assert seq.prefix_chain == full_chain  # memo survives the free
     tokens = []
     steps = 0
     while engine.has_unfinished():
