@@ -87,6 +87,24 @@ class ModelConfig:
     hc_sinkhorn_iters: int = 20
     hc_eps: float = 1e-6
     hc_res_clamp: float = 30.0
+    # Linear attention beside softmax attention (models/solar_kda.py).
+    # ``layer_kinds`` names each held layer's mix, "gqa" (softmax over K and V
+    # pages) or "kda" (the gated delta rule: a [linear_head_dim,
+    # linear_head_dim] float32 state a head a sequence and the last
+    # ``linear_conv_kernel`` - 1 rows of three depthwise convolutions, no
+    # keys); empty = every layer keeps keys.  ``use_rope`` False: no position
+    # encoding at all.  ``use_gqa_gate``: the softmax heads' output times
+    # sigmoid(x W_gate), a column an output channel.  ``linear_gate_rank``:
+    # the rank of the decay's and the output gate's projections.
+    # ``kda_allow_neg_eigval``: beta = 2 sigmoid, not sigmoid.
+    layer_kinds: Tuple[str, ...] = ()
+    use_rope: bool = True
+    use_gqa_gate: bool = False
+    linear_num_heads: int = 0
+    linear_head_dim: int = 0
+    linear_conv_kernel: int = 0
+    linear_gate_rank: int = 0
+    kda_allow_neg_eigval: bool = False
 
     def __post_init__(self):
         if self.head_dim is None:
@@ -363,6 +381,73 @@ PRESETS = {
         num_shared_experts=1,
         first_k_dense_replace=1,
         routed_scaling_factor=2.5,
+    ),
+    # Solar-Open2-250B (https://huggingface.co/upstage/Solar-Open2-250B,
+    # model_type solar_open2) AS ONE OF EIGHT CHIPS' SHARE, not the whole
+    # model: every width as published, and of the published 48 layers (a
+    # softmax NoPE GQA layer, then three gated delta-rule layers, twelve
+    # times), 320 experts a layer and 196,608 vocabulary rows this preset
+    # holds one whole period (layers 0-3), experts 0-39 behind a router that
+    # stays 320 wide, and 24,576 rows: 6.6 GB of bf16
+    # (bench/configs/solar-open2-250b-ep8.json states the deployment;
+    # PERF.md section 4 the arithmetic).  The published max is 1,048,576
+    # positions; 32,768 is the serving limit the caches are sized for.
+    "solar-open2-250b-ep8": ModelConfig(
+        name="solar-open2-250b-ep8",
+        vocab_size=24576,
+        published_vocab_size=196608,
+        hidden_size=4096,
+        intermediate_size=10240,
+        num_layers=4,
+        num_heads=64,
+        num_kv_heads=8,
+        head_dim=128,
+        max_model_len=32768,
+        rms_norm_eps=1e-5,
+        num_experts=40,
+        router_experts=320,
+        num_experts_per_tok=8,
+        moe_intermediate_size=1280,
+        num_shared_experts=1,
+        first_k_dense_replace=0,
+        routed_scaling_factor=1.0,
+        layer_kinds=("gqa", "kda", "kda", "kda"),
+        use_rope=False,
+        use_gqa_gate=True,
+        linear_num_heads=64,
+        linear_head_dim=128,
+        linear_conv_kernel=4,
+        linear_gate_rank=128,
+        kda_allow_neg_eigval=True,
+    ),
+    # The same module at a size the CPU tests run: one period, 4 of a
+    # router's 8 experts held, 2 a token.
+    "tiny-solar": ModelConfig(
+        name="tiny-solar-kda",
+        vocab_size=384,
+        hidden_size=64,
+        intermediate_size=128,
+        num_layers=4,
+        num_heads=4,
+        num_kv_heads=2,
+        head_dim=16,
+        max_model_len=2048,
+        rms_norm_eps=1e-5,
+        num_experts=4,
+        router_experts=8,
+        num_experts_per_tok=2,
+        moe_intermediate_size=32,
+        num_shared_experts=1,
+        first_k_dense_replace=0,
+        routed_scaling_factor=1.0,
+        layer_kinds=("gqa", "kda", "kda", "kda"),
+        use_rope=False,
+        use_gqa_gate=True,
+        linear_num_heads=4,
+        linear_head_dim=16,
+        linear_conv_kernel=4,
+        linear_gate_rank=8,
+        kda_allow_neg_eigval=True,
     ),
     # Xing4.0-29B-A4B (https://huggingface.co/XingChen-AGI/Xing4.0-29B-A4B,
     # model_type xing4_0) AS ONE PIPELINE STAGE, not the whole model: every

@@ -61,6 +61,7 @@ from production_stack_tpu.engine.kv.block_pool import (
 )
 from production_stack_tpu.engine.kv import quant as kv_quant
 from production_stack_tpu.engine.kv.offload import HostOffloadManager, OffloadStager
+from production_stack_tpu.engine.kv.state_pool import StatePool, pool_slots
 from production_stack_tpu.engine.kv.prefetch import PrefetchedChain, PrefetchManager
 from production_stack_tpu.engine.models import get_model
 from production_stack_tpu.engine.models.weights import load_params
@@ -267,6 +268,14 @@ class LLMEngine:
                     ),
                 )
 
+        # A module that keeps recurrent state beside its keys
+        # (state_bytes_per_slot, models/registry.py) gets a pool of slots
+        # beside the block pool, sized by rule from the batch; its bytes come
+        # off what the block pool is sized from.
+        self.state_pool = None
+        if hasattr(self.model, "state_bytes_per_slot"):
+            self.state_pool = StatePool(
+                *pool_slots(config.scheduler.max_num_seqs))
         num_blocks = self._decide_num_blocks()
         self.block_pool = BlockPool(
             num_blocks,
@@ -303,6 +312,9 @@ class LLMEngine:
             offload_cb=self.offload_seq_blocks,
             restore_cb=self.restore_seq_blocks,
             remote_prefix_cb=self.fetch_remote_prefix if imports else None,
+            state_pool=self.state_pool,
+            state_stride=(
+                self.model.snapshot_stride(cfg) if self.state_pool else 0),
         )
         weights_in_use = self.device_report()["memory"][0]["bytes_in_use"]
         self.kv_caches = self._allocate_kv(num_blocks)
@@ -312,6 +324,16 @@ class LLMEngine:
             config.cache.block_size,
             self._kv_bytes(num_blocks) / 2**30,
         )
+        if self.state_pool is not None:
+            logger.info(
+                "State pool: %d live + %d snapshot slots x %.2f MB "
+                "(%.2f GiB), a snapshot every %d tokens of a prompt's last "
+                "chunk",
+                self.state_pool.live_slots, self.state_pool.snapshot_slots,
+                self.model.state_bytes_per_slot(cfg) / 1e6,
+                self._state_bytes() / 2**30,
+                self.model.snapshot_stride(cfg),
+            )
         mem = self.device_report()["memory"][0]
         if mem["bytes_in_use"] is not None:
             # What a deployment would hold: the weights, then the pool.
@@ -763,6 +785,8 @@ class LLMEngine:
         self._pipe_sampling = None  # (temps, top_ps, top_ks, min_ps, seeds)
         self._pipe_sample_sorts = False  # needs_sort of those, on the host
         self._pipe_adapter = None
+        # {"state_slots": the rows' live slots} under a state pool, else {}.
+        self._pipe_state_kwargs: Dict = {}
         self._pipe_table_lens: List[int] = []
         # decode_host_gap_ms: host time between one decode step retiring
         # and the next decode launch while the device had nothing queued —
@@ -814,6 +838,20 @@ class LLMEngine:
                 2 * cfg.num_kv_heads * cfg.head_dim * _dtype_size(cfg.dtype)
             )
         return num_blocks * self.config.cache.block_size * per_token * cfg.num_layers
+
+    def _state_bytes(self) -> int:
+        """Bytes of the state pool's slots on the device (0 without one)."""
+        if self.state_pool is None:
+            return 0
+        return self.state_pool.num_slots * self.model.state_bytes_per_slot(
+            self.config.model)
+
+    def _state_stats(self) -> Dict[str, int]:
+        """tpu:state_*: the state pool's book, zeros without one."""
+        book = ("slots_in_use", "snapshots_taken", "resumes", "resume_misses",
+                "recomputed_tokens")
+        return {"state_" + name: getattr(self.state_pool, name, 0)
+                for name in book}
 
     def device_report(self) -> Dict:
         """The devices this process sees, as JAX reports them (logged at
@@ -950,7 +988,7 @@ class LLMEngine:
                 )
             budget.append(
                 mem["bytes_limit"] * cache.hbm_utilization
-                - (mem["bytes_in_use"] or 0)
+                - (mem["bytes_in_use"] or 0) - self._state_bytes()
             )
         # KV heads are sharded over tp, so each device holds 1/tp of a
         # block; size the pool against the fullest device.
@@ -964,8 +1002,11 @@ class LLMEngine:
         if _own_cache(cfg):
             # Whatever the module keeps a layer (a latent cache is one
             # array, no K and V); the step programs thread it as a tree.
+            slots = {}
+            if self.state_pool is not None:
+                slots["state_slots"] = self.state_pool.num_slots
             return self.model.init_cache(
-                cfg, num_blocks, bs, NamedSharding(self.mesh, P())
+                cfg, num_blocks, bs, NamedSharding(self.mesh, P()), **slots
             )
         shape = (num_blocks, bs, cfg.num_kv_heads, cfg.head_dim)
         dtype = jnp.dtype(cfg.dtype)
@@ -1538,6 +1579,10 @@ class LLMEngine:
         for cp in chunks:
             first.setdefault(cp.seq.seq_id, cp.cached_len)
         new_tokens = sum(cp.num_new_tokens for cp in chunks)
+        if self.state_pool is not None:
+            fields["state_rows"] = len(seqs)
+            if chunks:
+                fields["state_resumed"] = any(cp.resumed for cp in chunks)
         return self.obs.recorder.on_dispatch(
             kind, rows=len(seqs),
             seq_ids=tuple(s.seq_id for s in seqs) + tuple(first),
@@ -1654,6 +1699,7 @@ class LLMEngine:
                 slot_offsets=st["slot_offsets"],
                 kv_caches=self.kv_caches,
                 **lora_kwargs,
+                **self._pipe_state_kwargs,
             )
             temps, top_ps, top_ks, min_ps, seeds = self._pipe_sampling
             step_key = jax.random.PRNGKey(
@@ -1712,6 +1758,7 @@ class LLMEngine:
             )
             self._pipe_adapter = st["adapter"]
             self._pipe_table_lens = [len(s.block_table) for s in seqs]
+            self._pipe_state_kwargs = self._state_kwargs(seqs, S)
         else:
             positions = np.zeros((S,), np.int32)
             ctx_lens = np.zeros((S,), np.int32)
@@ -1923,8 +1970,20 @@ class LLMEngine:
             for i, seq in enumerate(seqs):
                 adapter[i] = seq.adapter_idx
             state["adapter"] = self._put(adapter, batch_spec)
+        state["state_kwargs"] = self._state_kwargs(seqs, S)
         self._win_table_lens = [len(s.block_table) for s in seqs]
         return state
+
+    def _state_kwargs(self, seqs: List[Sequence], S: int) -> Dict:
+        """What a decode program is told of the state pool: ``state_slots``
+        [S] int32 on the device, each row's live slot and the null slot 0 for
+        a padding row; nothing without a pool."""
+        if self.state_pool is None:
+            return {}
+        slots = np.zeros((S,), np.int32)
+        slots[: len(seqs)] = [s.state_slot for s in seqs]
+        return {"state_slots": self._put(
+            slots, shardings_lib.decode_batch_spec())}
 
     def _window_chain(self, prev: _PendingStep, seqs: List[Sequence],
                       steps: List[int]) -> dict:
@@ -2125,6 +2184,7 @@ class LLMEngine:
                     use_penalties=state["use_penalties"],
                     use_min_floor=state["use_min_floor"],
                     **lora_kwargs,
+                    **state["state_kwargs"],
                 )
             self._count_sample_dispatch(state["sample_sorts"])
             # One key ordinal per iteration: single-token stepping would
@@ -3233,6 +3293,14 @@ class LLMEngine:
                 "prompt_topk": 20,
             }
 
+        state_kwargs = {}
+        if self.state_pool is not None:
+            state_kwargs = {
+                "state_slot": jnp.int32(plan.state_slot),
+                "state_from": jnp.int32(plan.state_from),
+                "snapshot_slot": jnp.int32(plan.snapshot_slot),
+                "snapshot_len": jnp.int32(plan.snapshot_len),
+            }
         return dict(
             tokens=self._put(tokens, P(AXES.SP)),
             cached_len=jnp.int32(plan.cached_len),
@@ -3241,6 +3309,7 @@ class LLMEngine:
             valid_len=jnp.int32(plan.num_new_tokens),
             **plp_kwargs,
             **lora_kwargs,
+            **state_kwargs,
         ), want_plp
 
     def _collect_prompt_logprobs(self, seq, plan, plp) -> None:
@@ -3467,6 +3536,7 @@ class LLMEngine:
                 self._decode_batch_arrays(seqs, S), batch_spec,
                 self._lora_kwargs(seqs, S, 1, batch_spec),
             )
+            kwargs.update(self._state_kwargs(seqs, S))
         self._note_decode_launch()
         with self.obs.phase("launch", rec):
             logits, self.kv_caches = self._decode_fn(
@@ -4465,6 +4535,8 @@ class LLMEngine:
             "mhc_clamped": self.mhc_clamped,
             "mhc_entries": self.mhc_entries,
             "mhc_sinkhorn_err": self.mhc_sinkhorn_err,
+            # The state pool of a model with recurrent state (zero without).
+            **self._state_stats(),
             # Dispatched programs that sample, and those among them whose
             # rows make the sampler sort the vocabulary.
             "sample_dispatches": self.sample_dispatches,

@@ -89,6 +89,16 @@ class PrefillPlan:
     # Dedicated prefill: the buckets of this chunk and of those still to
     # come for the prompt (``cover_prefill``); empty for a mixed-step chunk.
     cover: Tuple[int, ...] = ()
+    # Under a state pool (kv/state_pool.py): the sequence's live slot; the
+    # slot its linear layers start this chunk from (a snapshot's on a resumed
+    # admission, the live slot on a later chunk, < 0: zeros); the slot that
+    # keeps the state ``snapshot_len`` tokens into the chunk (the live slot
+    # itself: no snapshot); whether the admission resumed from a snapshot.
+    state_slot: int = 0
+    state_from: int = -1
+    snapshot_slot: int = 0
+    snapshot_len: int = 0
+    resumed: bool = False
 
 
 @dataclasses.dataclass
@@ -171,9 +181,25 @@ class Scheduler:
         offload_cb=None,
         restore_cb=None,
         remote_prefix_cb=None,
+        state_pool=None,
+        state_stride: int = 0,
     ):
         self.config = config
         self.block_pool = block_pool
+        # A model that keeps recurrent state beside its keys
+        # (kv/state_pool.py): admission asks both pools, and a cached prefix
+        # is cut back to the deepest block that has a snapshot of the state.
+        # ``state_stride``: a final chunk leaves a snapshot at the deepest
+        # multiple of it, counted from the chunk's start, below its last
+        # token (the module's, ``snapshot_stride``).
+        self.state_pool = state_pool
+        self.state_stride = state_stride
+        if state_pool is not None:
+            if state_stride <= 0 or state_stride % block_pool.block_size:
+                raise ValueError(
+                    f"snapshot stride {state_stride} is not a multiple of "
+                    f"the {block_pool.block_size}-token block")
+            block_pool.on_evict = state_pool.drop
         # offload_cb(seq, block_ids) -> bool: snapshot blocks before they
         # are freed (engine wires offload_seq_blocks).  With the async
         # transfer plane (cache.remote_prefetch) the callback only
@@ -386,8 +412,7 @@ class Scheduler:
                            getattr(s, "_admit_idx", 0)),
         )
         logger.debug("Rolling back partial prefill of %s (pool pressure)", seq.seq_id)
-        self.block_pool.free(seq.block_table)
-        seq.block_table = []
+        self._release(seq)
         seq.num_cached_tokens = 0
         seq.partial_prefill = False
         return True
@@ -740,6 +765,7 @@ class Scheduler:
         if not queue:
             return None
         seq = queue[0]
+        cut_back = 0
         if chunk_budget is not None:
             if force_bucket is not None:
                 chunk_buckets = [force_bucket]
@@ -787,6 +813,10 @@ class Scheduler:
                 prefix_blocks, cached_len = self.remote_prefix_cb(
                     seq, prefix_blocks, cached_len
                 )
+            if self.state_pool is not None:
+                prefix_blocks, cached_len, cut_back = self._cut_back_to_state(
+                    seq, prefix_blocks, cached_len
+                )
         num_new = seq.num_prompt_tokens - cached_len
         cover: Tuple[int, ...] = ()
         if chunk_budget is not None:
@@ -813,6 +843,11 @@ class Scheduler:
                 self.block_pool.free(prefix_blocks)
             return None
         new_blocks = self.block_pool.allocate(blocks_needed)
+        state = {}
+        if self.state_pool is not None:
+            state = self._plan_state(
+                seq, cached_len, num_new, is_final, cut_back
+            )
         seq.num_cached_tokens = cached_len
         seq.block_table = prefix_blocks + new_blocks
         if is_final:
@@ -833,6 +868,63 @@ class Scheduler:
             cached_len=cached_len,
             is_final=is_final,
             cover=cover,
+            **state,
+        )
+
+    def _cut_back_to_state(self, seq: Sequence, prefix_blocks, cached_len):
+        """A linear layer cannot start from keys: of the cached blocks the
+        block pool matched, keep those up to the deepest one whose boundary
+        the state pool holds a snapshot of, and give the others back (their
+        tokens are prefilled again, into blocks of the sequence's own).
+        Returns (blocks, cached_len, tokens cut); the pool's hit count is
+        what the admission skips after the cut, not before it."""
+        bs = self.block_pool.block_size
+        kept = self.state_pool.deepest(seq.prefix_chain, len(prefix_blocks))
+        cut = prefix_blocks[kept:]
+        if cut:
+            self.block_pool.free(cut)
+            self.block_pool.hit_tokens -= len(cut) * bs
+        return prefix_blocks[:kept], kept * bs, cached_len - kept * bs
+
+    def _plan_state(self, seq: Sequence, cached_len: int, num_new: int,
+                    is_final: bool, cut_back: int) -> dict:
+        """The state slots of one planned chunk (``PrefillPlan``'s fields),
+        once its blocks are allocated: the first chunk of an admission takes
+        a live slot and starts from the snapshot at ``cached_len`` (from zeros
+        where that is 0); a later chunk goes on from the live slot; a final
+        chunk leaves a snapshot at the deepest stride boundary below its last
+        token, keyed by that block's digest, unless one is there, and then
+        lets the snapshot the admission resumed from be the next evicted."""
+        pool, bs = self.state_pool, self.block_pool.block_size
+        chain = seq.prefix_chain
+        resumed = False
+        if seq.state_slot is None:
+            seq.state_slot = pool.allocate_live(seq.seq_id)
+            start = -1
+            seq.state_resumed_from = None
+            if cached_len:
+                seq.state_resumed_from = chain[cached_len // bs - 1]
+                start = pool.resume(seq.state_resumed_from)
+                resumed = True
+            elif cut_back:
+                pool.resume_misses += 1
+            pool.recomputed_tokens += cut_back
+        else:
+            start = seq.state_slot
+        snap_slot, snap_len = seq.state_slot, 0
+        if is_final and self.block_pool.enable_prefix_caching:
+            at = (num_new - 1) // self.state_stride * self.state_stride
+            block = (cached_len + at) // bs
+            if 0 < block <= len(chain) and not pool.has_snapshot(
+                chain[block - 1]
+            ):
+                snap_slot, snap_len = pool.take_snapshot(chain[block - 1]), at
+                # The session's next round starts from this one: the one it
+                # came from is the first to go when the snapshots are full.
+                pool.supersede(seq.state_resumed_from)
+        return dict(
+            state_slot=seq.state_slot, state_from=start,
+            snapshot_slot=snap_slot, snapshot_len=snap_len, resumed=resumed,
         )
 
     def _window_token_cap(self, window: int) -> int:
@@ -1182,8 +1274,7 @@ class Scheduler:
         if self.config.preemption_mode == "offload" and self.offload_cb is not None:
             # Page the blocks to host DRAM *before* the pool can reuse them.
             seq.offloaded = bool(self.offload_cb(seq, list(seq.block_table)))
-        self.block_pool.free(seq.block_table)
-        seq.block_table = []
+        self._release(seq)
         # Re-prefill path treats all prior tokens as the new prompt.
         seq.outputs_absorbed += len(seq.output_token_ids)
         seq.prompt_token_ids = seq.all_token_ids
@@ -1203,6 +1294,9 @@ class Scheduler:
         if seq.block_table:
             self.block_pool.free(seq.block_table)
             seq.block_table = []
+        if seq.state_slot is not None:
+            self.state_pool.free_live(seq.state_slot)
+            seq.state_slot = None
 
     def finish_seq(self, seq: Sequence) -> None:
         if seq in self.running:

@@ -100,6 +100,13 @@ class Sequence:
     # Filled for the prompt by the API server's handler before the step
     # thread meets the request, else on the step thread at first need.
     prefix_chain: List[bytes] = dataclasses.field(default_factory=list)
+    # The sequence's live slot in the state pool (kv/state_pool.py), from its
+    # first prefill chunk until it finishes, is aborted or is preempted; None
+    # under a model that keeps no recurrent state.
+    state_slot: Optional[int] = None
+    # The digest of the snapshot this admission's state started from (None:
+    # from zeros), kept until its last chunk leaves a deeper one.
+    state_resumed_from: Optional[bytes] = None
     finish_reason: Optional[FinishReason] = None
     first_token_time: Optional[float] = None
     # Observability (obs/): first prefill-chunk launch (ends the queue-wait

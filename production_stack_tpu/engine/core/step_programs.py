@@ -147,6 +147,14 @@ def _lora_extra(lora, adapter_idx):
     return {"lora": lora, "adapter_idx": adapter_idx}
 
 
+def _state_extra(state_slots):
+    """A model with a state pool (kv/state_pool.py) is told each row's live
+    slot; its states ride the cache tree, which the scan carries as it is."""
+    if state_slots is None:
+        return {}
+    return {"state_slots": state_slots}
+
+
 # -- the K-step decode window ------------------------------------------------
 
 
@@ -161,7 +169,7 @@ def window_program(model_decode, *, block_size, n_steps, vocab):
         stop_ids, key_base, counts, seen,
         presence, frequency, repetition,
         use_penalties, use_min_floor,
-        lora=None, adapter_idx=None,
+        lora=None, adapter_idx=None, state_slots=None,
     ):
         stop_valid, banned = _stops(stop_ids, vocab, use_min_floor)
 
@@ -186,6 +194,7 @@ def window_program(model_decode, *, block_size, n_steps, vocab):
                 slot_offsets=positions % bs,
                 kv_caches=kv_caches,
                 **_lora_extra(lora, adapter_idx),
+                **_state_extra(state_slots),
             )
             logits = shape_logits(
                 logits, counts, seen, min_left, banned,

@@ -110,6 +110,10 @@ class BlockPool:
         self.hit_tokens = 0
         # Blocks whose digest was computed through this pool (extend_chain).
         self.chain_blocks_hashed = 0
+        # Called with the digest of a block whose content leaves the prefix
+        # cache: what else is keyed by that digest (a state snapshot,
+        # kv/state_pool.py) dies with it.
+        self.on_evict = None
 
     # -- capacity ----------------------------------------------------------
 
@@ -182,6 +186,8 @@ class BlockPool:
         digest = self._block_to_hash.pop(block, None)
         if digest is not None and self._hash_to_block.get(digest) == block:
             del self._hash_to_block[digest]
+            if self.on_evict is not None:
+                self.on_evict(digest)
 
     # -- prefix caching ----------------------------------------------------
 

@@ -44,6 +44,29 @@ the step programs (``core/step_programs.py``) carry without looking inside.
 - with ``init_cache``, ``residual_path(cfg) -> None | (streams,
   normalisations, path)``: the boot line ``Residual: ...`` of a module whose
   tokens carry several residual streams.
+- with ``init_cache``, **a state pool**: ``state_bytes_per_slot(cfg)`` and
+  ``snapshot_stride(cfg)`` say that some layers keep, in place of keys, a
+  recurrent state that does not grow with the context.  The engine then makes
+  a ``kv/state_pool.py: StatePool`` beside the block pool (sized by rule from
+  ``max_num_seqs``; its bytes come off what the block pool is sized from),
+  calls ``init_cache(..., state_slots=n)`` so that the one cache tree holds
+  pages for the layers with keys and ``n`` slots for those with state (slot 0
+  is the null slot, the padding rows'), and hands the slots as **keyword
+  arguments**: ``prefill(..., state_slot=, state_from=, snapshot_slot=,
+  snapshot_len=)`` (the sequence's live slot; the slot the chunk starts from,
+  a snapshot's on a resumed admission, negative: zeros; the slot that keeps
+  the state ``snapshot_len`` tokens into the chunk, a multiple of the stride;
+  the live slot itself: none) and ``decode(..., state_slots=[S])`` (each
+  row's live slot, through ``step_programs.window_program`` too).  ``cache_
+  bytes_per_token`` counts the layers with keys alone.  A padded slot of a
+  chunk and a dead row of a decode batch must be the identity on the state.
+  **The default the benchmark's compare relies on**: ``bench/harness/
+  compare.py`` calls both steps with the cache ``init_cache(cfg, blocks,
+  block_size, sharding)`` returned and nothing else, so where no slot is
+  handed the module derives it from what is there: the first block id of the
+  row's table modulo the slots ``init_cache`` made, a chunk with
+  ``cached_len == 0`` starting from zeros and a later one going on from its
+  slot, no snapshot; bit-equal to the same run with explicit slots.
 
 **What runs, by mechanism** (ROADMAP Queue 2 lists what does not): dense GQA
 with one sliding window, int8 weights, a softmax-routed MoE (``llama.py``);
@@ -55,14 +78,22 @@ layers, ``deepseek_yarn``, and several residual streams mixed at every
 sub-layer by a Sinkhorn-normalised matrix (``hc_mult``, ``hc_sinkhorn_iters``,
 ``hc_eps``, ``hc_res_clamp``; counters ``mhc_clamped`` / ``mhc_entries`` /
 ``mhc_err_e6``), taken in Python at trace time so that a configuration
-without them traces the program it always had.
+without them traces the program it always had; and in ``solar_kda.py`` layers
+of two kinds by a list (``layer_kinds``): softmax GQA with no position encoding
+at all (``use_rope`` False) and an elementwise sigmoid gate on the heads'
+output, through the two dense Pallas kernels; and the gated delta rule with a
+decay a channel (depthwise causal convolution, low-rank decay and gate,
+``beta`` up to 2), a float32 state a head a sequence in a slot of the state
+pool, chunkwise in prefill and one step in decode (``ops/pallas/kda.py``),
+with snapshots at block boundaries that the prefix cache resumes from; over
+``sarvam_mla``'s routed experts held by share, imported.
 """
 
 from __future__ import annotations
 
 from types import ModuleType
 
-from production_stack_tpu.engine.models import llama, sarvam_mla
+from production_stack_tpu.engine.models import llama, sarvam_mla, solar_kda
 
 MODEL_REGISTRY = {
     # llama.py covers every RMSNorm+RoPE+GQA+gated-MLP family member; the
@@ -81,6 +112,9 @@ MODEL_REGISTRY = {
     # doubly stochastic matrix around that latent attention with a low-rank
     # query path, every routed expert held (cfg.hc_mult, cfg.q_lora_rank).
     "xing": sarvam_mla,
+    # Gated delta-rule layers beside gated softmax layers without position
+    # encoding: two kinds of state in one cache tree, pages and slots.
+    "solar": solar_kda,
 }
 
 

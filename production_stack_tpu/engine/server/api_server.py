@@ -412,6 +412,13 @@ def build_engine_app(
             (vocab.TPU_PREFIX_CHAIN_BLOCKS, s["prefix_chain_blocks"]),
             (vocab.TPU_PREFIX_CHAIN_STEP_BLOCKS,
              s["prefix_chain_step_blocks"]),
+            # The state pool of a model with recurrent state (zero without).
+            (vocab.TPU_STATE_SLOTS_IN_USE, s["state_slots_in_use"]),
+            (vocab.TPU_STATE_SNAPSHOTS_TAKEN, s["state_snapshots_taken"]),
+            (vocab.TPU_STATE_RESUMES, s["state_resumes"]),
+            (vocab.TPU_STATE_RESUME_MISS, s["state_resume_misses"]),
+            (vocab.TPU_STATE_RECOMPUTED_TOKENS,
+             s["state_recomputed_tokens"]),
             # Slice-group lifecycle (0 on single-host engines): the group
             # epoch steps on every group restart, and drain relays count
             # follower-initiated slice-wide drains (docs/robustness.md).

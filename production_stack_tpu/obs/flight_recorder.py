@@ -122,6 +122,13 @@ class WindowRecord:
     # exponents the clamp changed / seen; ``mhc_err_e6``: the largest
     # |row sum - 1| after the last normalisation, x 1e6.
     routing: Optional[Dict[str, int]] = None
+    # Under a model with a state pool (kv/state_pool.py).  ``state_rows``:
+    # decode rows that read and write a slot of recurrent state each step.
+    # ``state_resumed``, on a prefill record: whether this chunk began an
+    # admission that started from a snapshot of the state (False: from
+    # zeros, or a later chunk of its prompt); None without a state pool.
+    state_rows: int = 0
+    state_resumed: Optional[bool] = None
 
     @property
     def launch_ns(self) -> Optional[int]:
@@ -163,6 +170,10 @@ class WindowRecord:
             d["cover"] = list(self.cover)
         if self.routing:
             d.update(self.routing)
+        if self.state_rows:
+            d["state_rows"] = self.state_rows
+        if self.state_resumed is not None:
+            d["state_resumed"] = self.state_resumed
         if self.spec_width:
             d["spec_width"] = self.spec_width
             d["drafter"] = self.drafter
@@ -225,6 +236,8 @@ class FlightRecorder:
         kv_tiles_live: int = 0,
         kv_tiles_grid: int = 0,
         cover: Tuple[int, ...] = (),
+        state_rows: int = 0,
+        state_resumed: Optional[bool] = None,
         now: Optional[float] = None,
     ) -> Optional[WindowRecord]:
         """Stamp a new record at dispatch.  Returns None when disabled so
@@ -256,6 +269,8 @@ class FlightRecorder:
             kv_tiles_live=int(kv_tiles_live),
             kv_tiles_grid=int(kv_tiles_grid),
             cover=tuple(cover),
+            state_rows=int(state_rows),
+            state_resumed=state_resumed,
             dispatched_at=now if now is not None else time.time(),
         )
 

@@ -348,6 +348,38 @@ REGISTRY = {
         "help": "Of those, the blocks hashed on the step thread, where "
                 "the device may wait for the plan",
     },
+    "tpu:state_slots_in_use": {
+        "kind": "gauge", "layer": "engine",
+        "mirrors": ("fake_engine", "dashboard", "docs"),
+        "help": "Slots of the state pool held: a live sequence's recurrent "
+                "state over all linear-attention layers, or a snapshot of "
+                "it at a block boundary; zero for a model without such state",
+    },
+    "tpu:state_snapshots_taken_total": {
+        "kind": "counter", "layer": "engine",
+        "mirrors": ("fake_engine", "dashboard", "docs"),
+        "help": "Snapshots of the recurrent state a prompt's last prefill "
+                "chunk left at a block boundary, keyed by that block's "
+                "digest in the prefix chain",
+    },
+    "tpu:state_resumes_total": {
+        "kind": "counter", "layer": "engine",
+        "mirrors": ("fake_engine", "dashboard", "docs"),
+        "help": "Admissions whose linear-attention layers started from a "
+                "snapshot at the end of their cached prefix",
+    },
+    "tpu:state_resume_miss_total": {
+        "kind": "counter", "layer": "engine",
+        "mirrors": ("fake_engine", "dashboard", "docs"),
+        "help": "Admissions whose cached prefix was cut back to nothing "
+                "for want of a snapshot: keys were cached, the state was not",
+    },
+    "tpu:state_recomputed_tokens_total": {
+        "kind": "counter", "layer": "engine",
+        "mirrors": ("fake_engine", "dashboard", "docs"),
+        "help": "Cached prompt tokens prefilled again between the snapshot "
+                "an admission started from and the deepest cached block",
+    },
     "tpu:moe_experts_touched_total": {
         "kind": "counter", "layer": "engine",
         "mirrors": ("fake_engine", "dashboard", "docs"),

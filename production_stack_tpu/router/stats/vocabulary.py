@@ -235,6 +235,17 @@ TPU_SAMPLE_SORTED_DISPATCH = "tpu:sample_sorted_dispatch_total"
 # and the few blocks a request's generated tokens complete).
 TPU_PREFIX_CHAIN_BLOCKS = "tpu:prefix_chain_blocks_total"
 TPU_PREFIX_CHAIN_STEP_BLOCKS = "tpu:prefix_chain_step_blocks_total"
+# A model that keeps recurrent state beside its keys (engine/kv/state_pool.py):
+# slots held (live sequences' and snapshots'), snapshots of the state left at
+# block boundaries, admissions that started from one, admissions whose cached
+# prefix was cut back to nothing for want of one, and the cached tokens
+# prefilled again between a snapshot and the deepest cached block.  Zero for a
+# model without such state.
+TPU_STATE_SLOTS_IN_USE = "tpu:state_slots_in_use"
+TPU_STATE_SNAPSHOTS_TAKEN = "tpu:state_snapshots_taken_total"
+TPU_STATE_RESUMES = "tpu:state_resumes_total"
+TPU_STATE_RESUME_MISS = "tpu:state_resume_miss_total"
+TPU_STATE_RECOMPUTED_TOKENS = "tpu:state_recomputed_tokens_total"
 # Step-thread phases (obs.engine.PHASES) that lasted over a second: every
 # stream stood still for as long.  One WARNING line each names the window.
 TPU_STEP_STALL = "tpu:step_stall_total"
@@ -349,6 +360,10 @@ TPU_COUNTERS = frozenset({
     TPU_SAMPLE_SORTED_DISPATCH,
     TPU_PREFIX_CHAIN_BLOCKS,
     TPU_PREFIX_CHAIN_STEP_BLOCKS,
+    TPU_STATE_SNAPSHOTS_TAKEN,
+    TPU_STATE_RESUMES,
+    TPU_STATE_RESUME_MISS,
+    TPU_STATE_RECOMPUTED_TOKENS,
     TPU_MIXED_WINDOW_CHUNK_TOKENS,
     TPU_ENCODE_TEXTS,
     TPU_WINDOW_TRANSFER_OVERLAP_SECONDS,

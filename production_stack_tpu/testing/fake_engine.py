@@ -553,6 +553,12 @@ def build_fake_engine_app(state: FakeEngineState | None = None) -> web.Applicati
             # The fake keeps no block pool and hashes no chain: at zero.
             (vocab.TPU_PREFIX_CHAIN_BLOCKS, 0),
             (vocab.TPU_PREFIX_CHAIN_STEP_BLOCKS, 0),
+            # The fake keeps no recurrent state: the families, at zero.
+            (vocab.TPU_STATE_SLOTS_IN_USE, 0),
+            (vocab.TPU_STATE_SNAPSHOTS_TAKEN, 0),
+            (vocab.TPU_STATE_RESUMES, 0),
+            (vocab.TPU_STATE_RESUME_MISS, 0),
+            (vocab.TPU_STATE_RECOMPUTED_TOKENS, 0),
             # Batched encode lane (embed/rerank/score): live values from
             # the fake lane below — texts encoded and the queue-depth
             # gauge — so router encode-lane CI asserts batching through

@@ -183,6 +183,75 @@ def test_mhc_sinkhorn_kernel_compiles(sds, no_persistent_cache, T):
     )
 
 
+# solar-open2-250b-ep8 (models/solar_kda.py): the two delta-rule kernels at the
+# published 64 heads of 128 (the chunkwise form at both prefill programs'
+# slots, the one-step form at the cell's 16 rows over its 59 slots), and the
+# two dense kernels at its softmax layer's geometry, 64 query heads over 8
+# key/value heads with no window, behind 32,768 gathered prefix slots: a later
+# change to either dense kernel is held by two configurations.
+@pytest.mark.parametrize("T", [256, 2048], ids=["T256", "T2048"])
+def test_kda_prefill_kernel_compiles(sds, no_persistent_cache, T):
+    from production_stack_tpu.engine.ops.pallas.kda import kda_prefill_pallas
+
+    cfg = PRESETS["solar-open2-250b-ep8"]
+    H, Dl = cfg.linear_num_heads, cfg.linear_head_dim
+    rows = sds((T, H, Dl), jnp.float32)
+    _compile(
+        lambda q, k, v, g, b, s0, at: kda_prefill_pallas(
+            q, k, v, g, b, s0, at),
+        rows, rows, rows, rows, sds((T, H), jnp.float32),
+        sds((H, Dl, Dl), jnp.float32), sds((), jnp.int32),
+    )
+
+
+@pytest.mark.parametrize("S", [8, 16], ids=["S8", "S16"])
+def test_kda_decode_kernel_compiles(sds, no_persistent_cache, S):
+    from production_stack_tpu.engine.kv.state_pool import pool_slots
+    from production_stack_tpu.engine.ops.pallas.kda import kda_decode_pallas
+
+    cfg = PRESETS["solar-open2-250b-ep8"]
+    H, Dl = cfg.linear_num_heads, cfg.linear_head_dim
+    slots = 1 + sum(pool_slots(16))
+    rows = sds((S, H, Dl), jnp.float32)
+    pool = (slots, H, Dl, Dl)
+    compiled = jax.jit(
+        lambda q, k, v, g, b, state, at: kda_decode_pallas(
+            q, k, v, g, b, state, at),
+        donate_argnums=(5,),           # as the engine donates the cache tree
+    ).lower(
+        rows, rows, rows, rows, sds((S, H), jnp.float32),
+        sds(pool, jnp.float32), sds((S,), jnp.int32),
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # The pool goes in and comes out in place: no second 250 MB array.
+    shape = "f32[" + ",".join(map(str, pool)) + "]"
+    assert not [line for line in text.splitlines()
+                if " copy(" in line and shape in line.split("=")[1][:60]]
+
+
+def test_the_dense_kernels_compile_at_solars_geometry(sds, no_persistent_cache):
+    cfg = PRESETS["solar-open2-250b-ep8"]
+    H, K, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    assert (H, K, hd, cfg.sliding_window) == (64, 8, 128, None)
+    cache = sds((20000, BS, K, hd), jnp.bfloat16)
+    _compile(
+        lambda q, k, v, bt, cl: paged_decode_attention_pallas(
+            q, k, v, bt, cl, scale=hd ** -0.5, sliding_window=None),
+        sds((16, H, hd), jnp.bfloat16), cache, cache,
+        sds((16, cfg.max_model_len // BS), jnp.int32), sds((16,), jnp.int32),
+    )
+    new = sds((256, K, hd), jnp.bfloat16)
+    prefix = sds((cfg.max_model_len, K, hd), jnp.bfloat16)
+    _compile(
+        lambda q, k, v, kp, vp, cached, valid: flash_prefill_attention(
+            q, k, v, kp, vp, cached, valid, scale=hd ** -0.5,
+            sliding_window=None),
+        sds((256, H, hd), jnp.bfloat16), new, new, prefix, prefix,
+        sds((), jnp.int32), sds((), jnp.int32),
+    )
+
+
 def test_int8_kv_decode_kernel_is_still_refused(sds, no_persistent_cache):
     """ROADMAP S10: Mosaic refuses the int8-KV decode kernel (the
     [N, bs, K] fp32 scale planes are no 128-lane DMA slice, and since the
@@ -243,6 +312,8 @@ def test_kv_pool_is_sized_from_every_device_and_never_guessed_on_a_tpu():
     class Boot:
         config = config_from_preset("tiny-llama")
         _kv_bytes = LLMEngine._kv_bytes
+        _state_bytes = LLMEngine._state_bytes
+        state_pool = None
         memory = []
 
         def device_report(self):
