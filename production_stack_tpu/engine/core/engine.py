@@ -216,6 +216,7 @@ class LLMEngine:
                     "ring shards the prefix block table over sp)"
                 )
         self.mesh = build_mesh(par)
+        self._shardings: Dict[P, NamedSharding] = {}  # _sharding's memo
         self._report_device_and_kernels()
 
         logger.info("Loading params for %s ...", cfg.name)
@@ -510,11 +511,24 @@ class LLMEngine:
         # pool's chain_blocks_hashed); stats() reports the sum and the
         # step thread's part.
         self.prefix_chain_handler_blocks = 0
+        # What a prefill chunk is told beside its arrays, in the order its
+        # packed vector holds them (step_programs.prefill_program).
+        self._prefill_scalars = ("cached_len", "valid_len")
+        if config.lora.enabled:
+            self._prefill_scalars += ("adapter_idx",)
+        if self.state_pool is not None:
+            self._prefill_scalars += (
+                "state_slot", "state_from", "snapshot_slot", "snapshot_len",
+            )
         self._prefill_fn = self._jit(
             "prefill_fn",
-            partial(
-                self.model.prefill, cfg=cfg, mesh=self.mesh,
-                sp_mode=par.sequence_parallel_mode, **counting,
+            step_programs.prefill_program(
+                partial(
+                    self.model.prefill, cfg=cfg, mesh=self.mesh,
+                    sp_mode=par.sequence_parallel_mode, **counting,
+                ),
+                self._prefill_scalars, config.cache.block_size,
+                max(self._bmax, 1),
             ),
             donate_argnames=("kv_caches",),
             static_argnames=("prompt_topk",),
@@ -802,6 +816,35 @@ class LLMEngine:
             "pipe_advance_fn",
             step_programs.pipe_advance(config.cache.block_size),
         )
+        # A window rebuilt from host state gets its per-row scalars the same
+        # way: one packed [N, S] int32 array (the rows this configuration
+        # has), unpacked on the device into the shardings the window
+        # program's inputs have always had.
+        # tpu:step_build_transfers_total / tpu:step_unchained_dispatch_total:
+        # calls of _stage (the one host -> device transfer of a dispatch
+        # built from host state) and such dispatches: prefills and rebuilt
+        # windows.  Step-thread-only writers.
+        self.build_transfers = 0
+        self.unchained_dispatches = 0
+        self._win_rows = step_programs.WIN_ROWS
+        if self.lora_registry is not None:
+            self._win_rows += ("adapter",)
+        if self.state_pool is not None:
+            self._win_rows += ("state_slots",)
+        if self.draft_block_pool is not None:
+            self._win_rows += ("draft_pos",)
+        self._win_row_at = {
+            name: i for i, name in enumerate(self._win_rows)
+        }
+        self._win_unpack_fn = None
+        if self._window_steps > 1:
+            batch = self._sharding(shardings_lib.decode_batch_spec())
+            out = {name: batch for name in self._win_rows}
+            out["counts"] = out["seen"] = self._sharding(P(AXES.DP, None))
+            self._win_unpack_fn = self._jit(
+                "win_unpack_fn", step_programs.win_unpack(self._win_rows),
+                out_shardings=out,
+            )
 
     def _jit(self, name: str, fn, **jit_kwargs):
         """The ONE place a step function gets its name: jitted under
@@ -1045,9 +1088,37 @@ class LLMEngine:
         )
         return [(zeros(), zeros()) for _ in range(cfg.num_layers)]
 
+    # Where each array of a dispatch built from host state lives (_stage):
+    # what _put gave it when each went alone.
+    _BUILD_SPECS = {
+        "packed": P(None, AXES.DP),
+        **{k: P(AXES.DP, None) for k in (
+            "tables", "stop_ids", "hist", "draft_tables",
+            "out_tokens", "ctx_tokens",
+        )},
+        "chunk": P(),
+    }
+
+    def _sharding(self, spec: P) -> NamedSharding:
+        got = self._shardings.get(spec)
+        if got is None:
+            got = self._shardings[spec] = NamedSharding(self.mesh, spec)
+        return got
+
+    def _stage(self, arrays: Dict[str, np.ndarray]) -> Dict[str, jax.Array]:
+        """Everything a dispatch built from host state sends to the device,
+        in ONE transfer: the host arrays as they are (no hop through the
+        default device, no eager op), each to its sharding.  Counted
+        (tpu:step_build_transfers_total): a prefill or a rebuilt window
+        that calls this more than twice has grown a transfer."""
+        self.build_transfers += 1
+        return jax.device_put(
+            arrays, {k: self._sharding(self._BUILD_SPECS[k]) for k in arrays}
+        )
+
     def _put(self, arr: np.ndarray, spec: P) -> jax.Array:
         """Host array -> device array with an explicit mesh sharding."""
-        return jax.device_put(jnp.asarray(arr), NamedSharding(self.mesh, spec))
+        return jax.device_put(jnp.asarray(arr), self._sharding(spec))
 
     # -- request lifecycle -------------------------------------------------
 
@@ -1810,119 +1881,106 @@ class LLMEngine:
             ids.append(eos)
         return tuple(sorted(set(ids)))
 
+    def _row_static(self, seq: Sequence) -> tuple:
+        """What a window's rebuild reads of a request's SamplingParams, read
+        once in its life (the pattern of ``seq_host_state_flags``): (its
+        column of step_programs.WIN_SAMPLING_ROWS, int32 with the floats
+        bitcast; whether it set a seed, else the row's index stands in;
+        min_tokens; its stop set)."""
+        static = seq._row_static
+        if static is None:
+            sp = seq.sampling_params
+            values = {
+                "temps": sp.temperature, "top_ps": sp.top_p,
+                "top_ks": sp.top_k, "min_ps": sp.min_p,
+                "seeds": sp.seed if sp.seed is not None else 0,
+                "presence": sp.presence_penalty,
+                "frequency": sp.frequency_penalty,
+                "repetition": sp.repetition_penalty,
+            }
+            names = step_programs.WIN_ROWS[step_programs.WIN_SAMPLING_ROWS]
+            col = np.zeros((len(names),), np.int32)
+            f32 = col.view(np.float32)
+            for i, name in enumerate(names):
+                into = f32 if name in step_programs.WIN_FLOAT_ROWS else col
+                into[i] = values[name]
+            seq._row_static = static = (
+                col, sp.seed is not None, sp.min_tokens,
+                self._stop_set_ids(seq),
+            )
+        return static
+
     def _window_host_state(self, seqs: List[Sequence], steps: List[int]):
-        """Host arrays + static flags for a window batch (re)build."""
+        """Host arrays + static flags for a window batch (re)build: every
+        per-row scalar as one row of ``packed`` [N, S] int32 (the rows of
+        ``self._win_rows``, floats bitcast), the block tables, the stop
+        ids.  Padding rows: ``done``, ``top_ps`` 1, ``repetition`` 1, else
+        0 (null block, temperature 0)."""
         S = self._decode_bucket(len(seqs))
+        n = len(seqs)
         (tokens, positions, tables, ctx_lens, _sb, _so) = (
             self._decode_batch_arrays(seqs, S)
         )
-        max_steps = np.zeros((S,), np.int32)
-        max_steps[: len(seqs)] = steps
-        done = np.ones((S,), bool)
-        done[: len(seqs)] = False
-        pad = S - len(seqs)
-        min_left = np.array(
-            [
-                max(0, s.sampling_params.min_tokens
-                    - len(s.output_token_ids))
-                for s in seqs
-            ] + [0] * pad,
-            np.int32,
-        )
-        presence = np.array(
-            [s.sampling_params.presence_penalty for s in seqs] + [0.0] * pad,
-            np.float32,
-        )
-        frequency = np.array(
-            [s.sampling_params.frequency_penalty for s in seqs] + [0.0] * pad,
-            np.float32,
-        )
-        repetition = np.array(
-            [s.sampling_params.repetition_penalty for s in seqs]
-            + [1.0] * pad,
-            np.float32,
-        )
-        stop_lists = [self._stop_set_ids(s) for s in seqs]
+        at = self._win_row_at
+        packed = np.zeros((len(at), S), np.int32)
+        f32 = packed.view(np.float32)
+        packed[at["tokens"]] = tokens
+        packed[at["positions"]] = positions
+        packed[at["ctx_lens"]] = ctx_lens
+        packed[at["done"], n:] = 1
+        packed[at["max_steps"], :n] = steps
+        f32[at["top_ps"], n:] = 1.0
+        f32[at["repetition"], n:] = 1.0
+        min_left = packed[at["min_left"]]
+        seeds = packed[at["seeds"]]
+        stop_lists = []
+        for i, seq in enumerate(seqs):
+            col, seeded, min_tokens, stop = self._row_static(seq)
+            packed[step_programs.WIN_SAMPLING_ROWS, i] = col
+            if not seeded:
+                seeds[i] = i
+            if min_tokens:
+                min_left[i] = max(0, min_tokens - len(seq.output_token_ids))
+            stop_lists.append(stop)
+        if "adapter" in at:
+            packed[at["adapter"], :n] = [s.adapter_idx for s in seqs]
+        if "state_slots" in at:
+            # Each row's live slot; the null slot 0 for a padding row.
+            packed[at["state_slots"], :n] = [s.state_slot for s in seqs]
         B = self._pow2_bucket(
             max([len(ids) for ids in stop_lists] + [1]), 1
         )
         stop_ids = np.full((S, B), -1, np.int32)
         for i, ids in enumerate(stop_lists):
             stop_ids[i, : len(ids)] = ids
-        use_penalties = bool(
-            np.any(presence) or np.any(frequency) or np.any(repetition != 1.0)
-        )
-        use_min_floor = bool(np.any(min_left > 0))
         return {
-            "S": S, "tokens": tokens, "positions": positions,
-            "tables": tables, "ctx_lens": ctx_lens,
-            "max_steps": max_steps, "done": done, "min_left": min_left,
-            "presence": presence, "frequency": frequency,
-            "repetition": repetition, "stop_ids": stop_ids,
-            "use_penalties": use_penalties, "use_min_floor": use_min_floor,
-        }
-
-    def _window_build(self, seqs: List[Sequence], steps: List[int]) -> dict:
-        """Full batch (re)build: transfer every window input to the
-        device and construct the occurrence state the penalty math
-        reads.  Runs once per batch composition; steady-state windows
-        chain through _window_chain's delta transfer instead."""
-        h = self._window_host_state(seqs, steps)
-        S = h["S"]
-        batch_spec = shardings_lib.decode_batch_spec()
-        row_spec = P(AXES.DP, None)
-        temps, top_ps, top_ks, min_ps, seeds = self._sampling_arrays(seqs, S)
-        state = {
-            "tokens": self._put(h["tokens"], batch_spec),
-            "positions": self._put(h["positions"], batch_spec),
-            "ctx_lens": self._put(h["ctx_lens"], batch_spec),
-            "done": self._put(h["done"], batch_spec),
-            "min_left": self._put(h["min_left"], batch_spec),
-            "tables": self._put(h["tables"], row_spec),
-            "max_steps": self._put(h["max_steps"], batch_spec),
-            "temps": self._put(temps, batch_spec),
-            "top_ps": self._put(top_ps, batch_spec),
-            "top_ks": self._put(top_ks, batch_spec),
-            "min_ps": self._put(min_ps, batch_spec),
-            "seeds": self._put(seeds, batch_spec),
-            "stop_ids": self._put(h["stop_ids"], row_spec),
-            "presence": self._put(h["presence"], batch_spec),
-            "frequency": self._put(h["frequency"], batch_spec),
-            "repetition": self._put(h["repetition"], batch_spec),
-            "use_penalties": h["use_penalties"],
-            "use_min_floor": h["use_min_floor"],
+            "S": S, "packed": packed, "tables": tables, "stop_ids": stop_ids,
+            "use_penalties": bool(
+                np.any(f32[at["presence"]]) or np.any(f32[at["frequency"]])
+                or np.any(f32[at["repetition"]] != 1.0)
+            ),
+            "use_min_floor": bool(np.any(min_left > 0)),
             # The host's reading of the sampler's device predicate, for
             # the dispatch counters; chained windows carry it unchanged,
             # as they carry the arrays.
-            "sample_sorts": sampling_lib.needs_sort(temps, top_ps, top_ks),
+            "sample_sorts": sampling_lib.needs_sort(
+                f32[at["temps"]], f32[at["top_ps"]], packed[at["top_ks"]]
+            ),
         }
-        if h["use_penalties"]:
-            # Device-resident occurrence state, built by scatter from
-            # the bucketed [S, L] id arrays (same content as the host
-            # path's arrays, so penalty values are bit-identical).
-            L = self._pow2_bucket(
-                max([len(s.output_token_ids) for s in seqs] + [1]), 64
-            )
-            out_tokens = np.full((S, L), -1, np.int32)
-            for i, s in enumerate(seqs):
-                ids = s.output_token_ids[-L:]
-                out_tokens[i, : len(ids)] = ids
-            Lc = self._pow2_bucket(
-                max(len(s.all_token_ids) for s in seqs), 64
-            )
-            ctx_tokens = np.full((S, Lc), -1, np.int32)
-            for i, s in enumerate(seqs):
-                ids = s.all_token_ids[-Lc:]
-                ctx_tokens[i, : len(ids)] = ids
-            counts, seen = self._win_occurrence_fn(
-                self._put(out_tokens, row_spec),
-                self._put(ctx_tokens, row_spec),
-            )
-        else:
-            counts = self._put(np.zeros((S, 1), np.int16), row_spec)
-            seen = self._put(np.zeros((S, 1), bool), row_spec)
-        state["counts"] = counts
-        state["seen"] = seen
+
+    def _window_build(self, seqs: List[Sequence], steps: List[int]) -> dict:
+        """Full batch (re)build: ONE transfer (_stage) carries every window
+        input to the device, the per-row scalars packed and unpacked there
+        (win_unpack_fn); the occurrence state the penalty math reads takes
+        a second where a row has penalties.  Runs once per batch
+        composition; steady-state windows chain through _window_chain's
+        delta transfer instead."""
+        h = self._window_host_state(seqs, steps)
+        S = h["S"]
+        host = {
+            "packed": h["packed"], "tables": h["tables"],
+            "stop_ids": h["stop_ids"],
+        }
         if self._spec_window_fn is not None:
             # Carried drafting history for the fused speculative window:
             # the last H tokens (prompt + generated), left -1-padded so
@@ -1932,9 +1990,9 @@ class LLMEngine:
             H = self._SPEC_HIST_WINDOW
             hist = np.full((S, H), -1, np.int32)
             for i, s in enumerate(seqs):
-                ids = s.all_token_ids[-H:]
+                ids = s.tail_token_ids(H)
                 hist[i, H - len(ids):] = ids
-            state["hist"] = self._put(hist, row_spec)
+            host["hist"] = hist
         if self.draft_block_pool is not None:
             # Model drafter: per-row draft-KV block tables from the
             # DEDICATED pool (static [S, Bd] width — the draft cache is
@@ -1961,23 +2019,49 @@ class LLMEngine:
                 dt = np.zeros((S, bd), np.int32)
                 for i in range(len(seqs)):
                     dt[i] = blocks[i * bd:(i + 1) * bd]
-                state["draft_tables"] = self._put(dt, row_spec)
-                state["draft_pos"] = self._put(
-                    np.zeros((S,), np.int32), batch_spec
-                )
-        if self.lora_registry is not None:
-            adapter = np.zeros((S,), np.int32)
-            for i, seq in enumerate(seqs):
-                adapter[i] = seq.adapter_idx
-            state["adapter"] = self._put(adapter, batch_spec)
-        state["state_kwargs"] = self._state_kwargs(seqs, S)
+                host["draft_tables"] = dt
+        self.unchained_dispatches += 1
+        dev = self._stage(host)
+        state = self._win_unpack_fn(dev.pop("packed"))
+        state.update(dev)
+        for flag in ("use_penalties", "use_min_floor", "sample_sorts"):
+            state[flag] = h[flag]
+        if h["use_penalties"]:
+            # Device-resident occurrence state, built by scatter from
+            # the bucketed [S, L] id arrays (same content as the host
+            # path's arrays, so penalty values are bit-identical).
+            L = self._pow2_bucket(
+                max([len(s.output_token_ids) for s in seqs] + [1]), 64
+            )
+            out_tokens = np.full((S, L), -1, np.int32)
+            for i, s in enumerate(seqs):
+                ids = s.output_token_ids[-L:]
+                out_tokens[i, : len(ids)] = ids
+            Lc = self._pow2_bucket(max(s.num_tokens for s in seqs), 64)
+            ctx_tokens = np.full((S, Lc), -1, np.int32)
+            for i, s in enumerate(seqs):
+                ids = s.all_token_ids[-Lc:]
+                ctx_tokens[i, : len(ids)] = ids
+            occ = self._stage(
+                {"out_tokens": out_tokens, "ctx_tokens": ctx_tokens}
+            )
+            state["counts"], state["seen"] = self._win_occurrence_fn(
+                occ["out_tokens"], occ["ctx_tokens"]
+            )
+        if "draft_tables" not in state:
+            # No drafter, or its pool declined this batch.
+            state.pop("draft_pos", None)
+        state["state_kwargs"] = (
+            {"state_slots": state.pop("state_slots")}
+            if self.state_pool is not None else {}
+        )
         self._win_table_lens = [len(s.block_table) for s in seqs]
         return state
 
     def _state_kwargs(self, seqs: List[Sequence], S: int) -> Dict:
-        """What a decode program is told of the state pool: ``state_slots``
-        [S] int32 on the device, each row's live slot and the null slot 0 for
-        a padding row; nothing without a pool."""
+        """What a single-step decode program is told of the state pool:
+        ``state_slots`` [S] int32 on the device, each row's live slot and the
+        null slot 0 for a padding row; nothing without a pool."""
         if self.state_pool is None:
             return {}
         slots = np.zeros((S,), np.int32)
@@ -2173,7 +2257,7 @@ class LLMEngine:
                     # single-token stepping; past it, +t wraps in-graph,
                     # which PRNGKey treats as bits — still deterministic
                     # across lockstep replicas.
-                    key_base=jnp.int32(
+                    key_base=np.int32(
                         (self.config.seed + self._step_counter) & 0x7FFFFFFF
                     ),
                     counts=state["counts"],
@@ -2301,7 +2385,7 @@ class LLMEngine:
                     seq_seeds=state["seeds"],
                     stop_ids=state["stop_ids"],
                     # Same 31-bit masking rationale as _dispatch_window.
-                    key_base=jnp.int32(
+                    key_base=np.int32(
                         (self.config.seed + self._step_counter) & 0x7FFFFFFF
                     ),
                     counts=state["counts"],
@@ -3256,26 +3340,21 @@ class LLMEngine:
 
     def _prefill_kwargs(self, plan: PrefillPlan):
         """(the dedicated prefill executable's keyword arguments, on the
-        device; whether it also returns prompt logprobs)."""
+        device; whether it also returns prompt logprobs).  Everything built
+        here goes over in ONE vector (step_programs.prefill_program)."""
         seq = plan.seq
-        T = plan.bucket_len
-        tokens, new_block_ids, prefix_ids = self._prefill_plan_arrays(plan)
-
-        lora_kwargs = {}
+        parts = list(self._prefill_plan_arrays(plan))
+        kwargs = {}
         if self.lora_registry is not None:
-            lora_kwargs = {
-                "lora": self.lora_registry.params,
-                "adapter_idx": jnp.int32(seq.adapter_idx),
-            }
+            kwargs["lora"] = self.lora_registry.params
 
         sp = seq.sampling_params
         want_plp = sp.echo and sp.logprobs
-        plp_kwargs = {}
         if want_plp:
             # Target of row t (absolute position cached_len+t) is the NEXT
             # prompt token; rows at/past the prompt tail target 0 (their
             # entries are discarded below).
-            targets = np.zeros((T,), np.int32)
+            targets = np.zeros((plan.bucket_len,), np.int32)
             m = min(
                 plan.num_new_tokens,
                 len(seq.prompt_token_ids) - plan.cached_len - 1,
@@ -3284,33 +3363,25 @@ class LLMEngine:
                 targets[:m] = seq.prompt_token_ids[
                     plan.cached_len + 1 : plan.cached_len + 1 + m
                 ]
-            plp_kwargs = {
-                "prompt_targets": self._put(targets, P(AXES.SP)),
-                # Fixed k: prompt_topk is a STATIC jit arg, and a
-                # per-request value would compile a fresh prefill variant
-                # per (bucket, k) pair; _collect_prompt_logprobs slices to
-                # the request's k host-side.
-                "prompt_topk": 20,
-            }
-
-        state_kwargs = {}
-        if self.state_pool is not None:
-            state_kwargs = {
-                "state_slot": jnp.int32(plan.state_slot),
-                "state_from": jnp.int32(plan.state_from),
-                "snapshot_slot": jnp.int32(plan.snapshot_slot),
-                "snapshot_len": jnp.int32(plan.snapshot_len),
-            }
-        return dict(
-            tokens=self._put(tokens, P(AXES.SP)),
-            cached_len=jnp.int32(plan.cached_len),
-            prefix_block_ids=self._put(prefix_ids, P(AXES.SP)),
-            new_block_ids=self._put(new_block_ids, P(AXES.SP)),
-            valid_len=jnp.int32(plan.num_new_tokens),
-            **plp_kwargs,
-            **lora_kwargs,
-            **state_kwargs,
-        ), want_plp
+            parts.append(targets)
+            # Fixed k: prompt_topk is a STATIC jit arg, and a
+            # per-request value would compile a fresh prefill variant
+            # per (bucket, k) pair; _collect_prompt_logprobs slices to
+            # the request's k host-side.
+            kwargs["prompt_topk"] = 20
+        scalars = {
+            "cached_len": plan.cached_len, "valid_len": plan.num_new_tokens,
+            "adapter_idx": seq.adapter_idx, "state_slot": plan.state_slot,
+            "state_from": plan.state_from,
+            "snapshot_slot": plan.snapshot_slot,
+            "snapshot_len": plan.snapshot_len,
+        }
+        parts.append(np.array(
+            [scalars[name] for name in self._prefill_scalars], np.int32
+        ))
+        self.unchained_dispatches += 1
+        kwargs.update(self._stage({"chunk": np.concatenate(parts)}))
+        return kwargs, want_plp
 
     def _collect_prompt_logprobs(self, seq, plan, plp) -> None:
         """Stitch one chunk's (target_lp, top_ids, top_lps) into the
@@ -3402,11 +3473,12 @@ class LLMEngine:
         slot_offsets = np.zeros((S,), np.int32)
         for i, seq in enumerate(seqs):
             pos = seq.num_tokens - 1
-            tokens[i] = seq.all_token_ids[-1]
+            tokens[i] = seq.last_token_id
             positions[i] = pos
-            table = seq.block_table[: self._bmax]
+            # No walk over the context: the table as the sequence keeps it.
+            table = seq.block_table_array()[: self._bmax]
             block_tables[i, : len(table)] = table
-            ctx_lens[i] = seq.num_tokens
+            ctx_lens[i] = pos + 1
             slot_blocks[i] = seq.block_table[pos // bs]
             slot_offsets[i] = pos % bs
         return tokens, positions, block_tables, ctx_lens, slot_blocks, slot_offsets
@@ -3676,7 +3748,7 @@ class LLMEngine:
             drafts.append(draft)
             pos0 = seq.num_tokens - 1
             table = seq.block_table[: self._bmax]
-            chain = [seq.all_token_ids[-1]] + draft
+            chain = [seq.last_token_id] + draft
             for j, tok in enumerate(chain):
                 r = i * W + j
                 tokens[r] = tok
@@ -3712,32 +3784,17 @@ class LLMEngine:
         return outputs
 
     def _sampling_arrays(self, seqs: List[Sequence], S: int):
-        """Padded per-sequence sampling parameter arrays [S]."""
-        pad = S - len(seqs)
-        temps = np.array(
-            [s.sampling_params.temperature for s in seqs] + [0.0] * pad,
-            np.float32,
-        )
-        top_ps = np.array(
-            [s.sampling_params.top_p for s in seqs] + [1.0] * pad,
-            np.float32,
-        )
-        top_ks = np.array(
-            [s.sampling_params.top_k for s in seqs] + [0] * pad, np.int32
-        )
-        min_ps = np.array(
-            [s.sampling_params.min_p for s in seqs] + [0.0] * pad,
-            np.float32,
-        )
-        seeds = np.array(
-            [
-                (s.sampling_params.seed if s.sampling_params.seed is not None else idx)
-                for idx, s in enumerate(seqs)
-            ]
-            + [0] * pad,
-            np.int32,
-        )
-        return temps, top_ps, top_ks, min_ps, seeds
+        """Padded per-sequence sampling parameter arrays [S], from what
+        each request's admission left (_row_static)."""
+        packed = np.zeros((5, S), np.int32)
+        f32 = packed.view(np.float32)
+        f32[1, len(seqs):] = 1.0  # top_p of a padding row
+        for i, seq in enumerate(seqs):
+            col, seeded, _, _ = self._row_static(seq)
+            packed[:, i] = col[:5]
+            if not seeded:
+                packed[4, i] = i
+        return f32[0], f32[1], packed[2], f32[3], packed[4]
 
     def _sample_batch(
         self, logits: jax.Array, seqs: List[Sequence],
@@ -4548,6 +4605,8 @@ class LLMEngine:
                 + self.prefix_chain_handler_blocks
             ),
             "prefix_chain_step_blocks": self.block_pool.chain_blocks_hashed,
+            "step_build_transfers": self.build_transfers,
+            "step_unchained_dispatches": self.unchained_dispatches,
             # Quantized KV tiering plane: bytes crossing each tier
             # boundary by wire format, and snapshot serde versions put
             # on the kvserver wire (tpu:kv_wire_bytes_total /

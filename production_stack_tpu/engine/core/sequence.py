@@ -7,6 +7,8 @@ import enum
 import time
 from typing import List, Optional, Union
 
+import numpy as np
+
 
 class SequenceStatus(enum.Enum):
     WAITING = "waiting"
@@ -151,6 +153,20 @@ class Sequence:
     # crossing and re-armed when preemption empties output_token_ids.
     _hs_flags: Optional[tuple] = None
     _min_tok_pending: Optional[bool] = None
+    # What a dispatch builder reads of this request every rebuild, kept so
+    # that a rebuild costs what is new and not the context's length.
+    # ``_row_static``: LLMEngine._row_static's verdict (the sampling
+    # parameters as one packed int32 column, the stop set), static over the
+    # request's life like ``_hs_flags``.  ``_table``: ``block_table`` as
+    # int32 (block_table_array), valid for the list object ``_table_of`` up
+    # to ``_table_len`` entries.
+    _row_static: Optional[tuple] = dataclasses.field(
+        default=None, compare=False, repr=False)
+    _table: Optional[np.ndarray] = dataclasses.field(
+        default=None, compare=False, repr=False)
+    _table_of: Optional[list] = dataclasses.field(
+        default=None, compare=False, repr=False)
+    _table_len: int = dataclasses.field(default=0, compare=False, repr=False)
 
     @property
     def num_prompt_tokens(self) -> int:
@@ -163,6 +179,44 @@ class Sequence:
     @property
     def all_token_ids(self) -> List[int]:
         return self.prompt_token_ids + self.output_token_ids
+
+    @property
+    def last_token_id(self) -> int:
+        """``all_token_ids[-1]`` without building the list."""
+        return (self.output_token_ids or self.prompt_token_ids)[-1]
+
+    def tail_token_ids(self, n: int) -> List[int]:
+        """``all_token_ids[-n:]`` at the cost of n, not of the context."""
+        out = self.output_token_ids
+        if len(out) >= n:
+            return out[-n:]
+        return self.prompt_token_ids[len(out) - n:] + out
+
+    def block_table_array(self) -> np.ndarray:
+        """``block_table`` as an int32 array (a view: copy out of it, keep
+        no reference).  The scheduler grows a table in place (``extend``)
+        or assigns a new list (a prefill chunk, a restore, a preemption),
+        so the array kept from the last call holds for the same list object
+        and is extended by the blocks appended since: O(new blocks) a call,
+        where ``np.asarray(block_table)`` walks 1,500 ids at 24k tokens."""
+        table = self.block_table
+        n = len(table)
+        have = self._table_len
+        arr = self._table
+        if self._table_of is not table or n < have or (
+            have and table[have - 1] != arr[have - 1]
+        ):
+            # Another list, or one cut back in place (nothing does): anew.
+            self._table_of, have = table, 0
+        if arr is None or n > arr.shape[0]:
+            arr = np.zeros((max(64, 2 * n),), np.int32)
+            if have:
+                arr[:have] = self._table[:have]
+            self._table = arr
+        if n > have:
+            arr[have:n] = table[have:]
+        self._table_len = n
+        return arr[:n]
 
     @property
     def num_generated(self) -> int:

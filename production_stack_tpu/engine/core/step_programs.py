@@ -1,8 +1,8 @@
 """The step programs: what runs on the device between two host round trips.
 
 ``LLMEngine.__init__`` jits one function per program under a fixed name
-(``window_fn``, ``spec_window_fn``, ``mixed_window_fn``, ``win_advance_fn``,
-``pipe_unpack_fn``, ``pipe_advance_fn``); this module builds those functions.
+(``prefill_fn``, ``window_fn``, ``spec_window_fn``, ``mixed_window_fn``, ``win_unpack_fn``,
+``win_advance_fn``, ``pipe_unpack_fn``, ``pipe_advance_fn``); this module builds those functions.
 Each builder takes what the program needs of the engine as arguments — the
 model arrives as a callable — and imports nothing of the engine, the
 scheduler or obs, so a program can be lowered, timed and tested alone.
@@ -693,6 +693,87 @@ def spec_window_program(
         return emitted, drafted, accepted, state, kv_caches
 
     return spec_window
+
+
+# -- a dedicated prefill's inputs, from one packed transfer -------------------
+
+
+def prefill_program(model_prefill, scalars, block_size, prefix_blocks):
+    """``prefill_fn``: the model's prefill, with everything the host builds
+    for a chunk in ONE int32 vector (a transfer costs the step thread some
+    0.2 ms an array on a TPU's host, whatever its size, and the device is
+    empty meanwhile): ``[tokens T | new_block_ids T/bs | prefix_block_ids
+    | prompt_targets T, under ``prompt_topk`` alone | one entry a name of
+    ``scalars``]``.  T follows from the vector's length; the slices are
+    static, so the model's program is what it was behind them."""
+
+    def prefill(params, chunk, kv_caches, prompt_topk=0, **extra):
+        per_block = block_size * (2 if prompt_topk else 1) + 1
+        n_blocks, rest = divmod(
+            chunk.shape[0] - prefix_blocks - len(scalars), per_block
+        )
+        if rest or n_blocks <= 0:
+            raise ValueError(
+                f"no prefill bucket packs into {chunk.shape[0]} entries"
+            )
+        fields = [
+            ("tokens", n_blocks * block_size), ("new_block_ids", n_blocks),
+            ("prefix_block_ids", prefix_blocks),
+        ]
+        if prompt_topk:
+            fields.append(("prompt_targets", n_blocks * block_size))
+            extra["prompt_topk"] = prompt_topk
+        kwargs, at = {}, 0
+        for name, n in fields:
+            kwargs[name] = chunk[at:at + n]
+            at += n
+        for i, name in enumerate(scalars):
+            kwargs[name] = chunk[at + i]
+        return model_prefill(params, kv_caches=kv_caches, **kwargs, **extra)
+
+    return prefill
+
+
+# -- a rebuilt window's batch state, from one packed transfer ----------------
+
+# The rows of the packed [N, S] int32 array in which every per-row scalar of a
+# window rebuilt from host state travels (engine.py: _window_host_state), in
+# this order; a configuration appends the rows it has ("adapter",
+# "state_slots", "draft_pos").  WIN_SAMPLING_ROWS are a request's own, static
+# over its life: the engine keeps them as one column a sequence.
+WIN_ROWS = (
+    "tokens", "positions", "ctx_lens", "done", "min_left", "max_steps",
+    "temps", "top_ps", "top_ks", "min_ps", "seeds",
+    "presence", "frequency", "repetition",
+)
+WIN_SAMPLING_ROWS = slice(WIN_ROWS.index("temps"), len(WIN_ROWS))
+WIN_FLOAT_ROWS = frozenset(
+    ("temps", "top_ps", "min_ps", "presence", "frequency", "repetition")
+)
+
+
+def win_unpack(rows):
+    """``win_unpack_fn``: the packed array's rows under their names (float
+    rows bitcast back, ``done`` a bool again), and the empty occurrence state
+    of a batch without penalties.  What ``pipe_unpack`` is to the K=1
+    pipeline; the tables and stop ids ride beside it in the same transfer and
+    need no program."""
+
+    def unpack(packed):
+        state = {}
+        for i, name in enumerate(rows):
+            row = packed[i]
+            if name in WIN_FLOAT_ROWS:
+                row = jax.lax.bitcast_convert_type(row, jnp.float32)
+            elif name == "done":
+                row = row != 0
+            state[name] = row
+        S = packed.shape[1]
+        state["counts"] = jnp.zeros((S, 1), jnp.int16)
+        state["seen"] = jnp.zeros((S, 1), bool)
+        return state
+
+    return unpack
 
 
 # -- the K=1 pipeline's device-resident batch state --------------------------

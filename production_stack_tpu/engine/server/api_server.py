@@ -412,6 +412,12 @@ def build_engine_app(
             (vocab.TPU_PREFIX_CHAIN_BLOCKS, s["prefix_chain_blocks"]),
             (vocab.TPU_PREFIX_CHAIN_STEP_BLOCKS,
              s["prefix_chain_step_blocks"]),
+            # Dispatches built from host state (prefills, rebuilt windows)
+            # and the host -> device transfers their builds started: from
+            # boot, so that their ratio reads over any window.
+            (vocab.TPU_STEP_BUILD_TRANSFERS, s["step_build_transfers"]),
+            (vocab.TPU_STEP_UNCHAINED_DISPATCH,
+             s["step_unchained_dispatches"]),
             # The state pool of a model with recurrent state (zero without).
             (vocab.TPU_STATE_SLOTS_IN_USE, s["state_slots_in_use"]),
             (vocab.TPU_STATE_SNAPSHOTS_TAKEN, s["state_snapshots_taken"]),

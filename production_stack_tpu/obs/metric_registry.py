@@ -348,6 +348,21 @@ REGISTRY = {
         "help": "Of those, the blocks hashed on the step thread, where "
                 "the device may wait for the plan",
     },
+    "tpu:step_build_transfers_total": {
+        "kind": "counter", "layer": "engine",
+        "mirrors": ("fake_engine", "dashboard", "docs"),
+        "help": "Host to device transfers the step thread started while "
+                "building a dispatch from host state: one staged transfer "
+                "a prefill or a rebuilt window, two where a row has "
+                "penalties",
+    },
+    "tpu:step_unchained_dispatch_total": {
+        "kind": "counter", "layer": "engine",
+        "mirrors": ("fake_engine", "dashboard", "docs"),
+        "help": "Dispatches built from host state with nothing in flight "
+                "to chain from: dedicated prefills and decode windows "
+                "rebuilt after the running set changed",
+    },
     "tpu:state_slots_in_use": {
         "kind": "gauge", "layer": "engine",
         "mirrors": ("fake_engine", "dashboard", "docs"),

@@ -235,6 +235,13 @@ TPU_SAMPLE_SORTED_DISPATCH = "tpu:sample_sorted_dispatch_total"
 # and the few blocks a request's generated tokens complete).
 TPU_PREFIX_CHAIN_BLOCKS = "tpu:prefix_chain_blocks_total"
 TPU_PREFIX_CHAIN_STEP_BLOCKS = "tpu:prefix_chain_step_blocks_total"
+# A dispatch built from host state, with the device empty behind it (a
+# dedicated prefill, a decode window rebuilt because the running set changed),
+# sends what it built in one staged transfer (engine/core/engine.py: _stage):
+# such dispatches, and the transfers their builds started.  Their ratio is 1,
+# 2 where a row has penalties; more says a build grew a transfer of its own.
+TPU_STEP_BUILD_TRANSFERS = "tpu:step_build_transfers_total"
+TPU_STEP_UNCHAINED_DISPATCH = "tpu:step_unchained_dispatch_total"
 # A model that keeps recurrent state beside its keys (engine/kv/state_pool.py):
 # slots held (live sequences' and snapshots'), snapshots of the state left at
 # block boundaries, admissions that started from one, admissions whose cached

@@ -553,6 +553,9 @@ def build_fake_engine_app(state: FakeEngineState | None = None) -> web.Applicati
             # The fake keeps no block pool and hashes no chain: at zero.
             (vocab.TPU_PREFIX_CHAIN_BLOCKS, 0),
             (vocab.TPU_PREFIX_CHAIN_STEP_BLOCKS, 0),
+            # The fake builds no dispatch and stages no transfer: at zero.
+            (vocab.TPU_STEP_BUILD_TRANSFERS, 0),
+            (vocab.TPU_STEP_UNCHAINED_DISPATCH, 0),
             # The fake keeps no recurrent state: the families, at zero.
             (vocab.TPU_STATE_SLOTS_IN_USE, 0),
             (vocab.TPU_STATE_SNAPSHOTS_TAKEN, 0),

@@ -486,13 +486,12 @@ def _lowered_hashes():
     decode = partial(llama.decode, cfg=cfg, mesh=None)
     return {
         "prefill_fn": sha(
-            jax.jit(named("prefill_fn", partial(
-                llama.prefill, cfg=cfg, mesh=None, sp_mode="ring")),
+            jax.jit(named("prefill_fn", step_programs.prefill_program(
+                partial(llama.prefill, cfg=cfg, mesh=None, sp_mode="ring"),
+                ("cached_len", "valid_len"), BS, bmax)),
                 donate_argnames=("kv_caches",),
                 static_argnames=("prompt_topk",)),
-            params, tokens=i32(256), cached_len=i32(),
-            prefix_block_ids=i32(bmax), new_block_ids=i32(16),
-            valid_len=i32(), kv_caches=kv),
+            params, i32(256 + 256 // BS + bmax + 2), kv_caches=kv),
         "window_fn": sha(
             jax.jit(named("window_fn", step_programs.window_program(
                 decode, n_steps=8, block_size=BS, vocab=V)),
@@ -525,8 +524,11 @@ def test_a_dense_models_step_programs_lower_to_the_text_they_had():
     programs re-pins these and says so.  PR 43 meant to: ``window_fn`` and
     ``sample_fn`` hold the sampler, whose work now stands under two
     conditionals (0fcdfab572754c1d and b98d249a33611f1d before it; the
-    tokens are the same, tests/test_sampler_paths.py)."""
+    tokens are the same, tests/test_sampler_paths.py).  PR 49 meant to:
+    ``prefill_fn`` takes what the host builds for a chunk as one int32 vector
+    and slices it (``step_programs.prefill_program``; 6992c098e4286882 before
+    it; the model is handed the same values, tests/test_dispatch_build.py)."""
     assert _lowered_hashes() == {
-        "prefill_fn": "6992c098e4286882", "window_fn": "caf2df83d2edb4da",
+        "prefill_fn": "7b371a1a2d3fe956", "window_fn": "caf2df83d2edb4da",
         "win_advance_fn": "325e8149c1081481",
         "sample_fn": "18cb405d3f877b3e"}

@@ -22,7 +22,7 @@ from production_stack_tpu.obs.engine import PHASES, STEP_PHASES, EngineObs
 # traced under (LLMEngine._jit).
 PROGRAMS = (
     "prefill_fn", "decode_fn", "mixed_fn", "sample_fn", "window_fn",
-    "spec_window_fn", "mixed_window_fn", "win_advance_fn",
+    "spec_window_fn", "mixed_window_fn", "win_unpack_fn", "win_advance_fn",
     "win_occurrence_fn", "pipe_unpack_fn", "pipe_advance_fn",
     "penalties_fn", "argmax_fn", "logprobs_fn",
 )
