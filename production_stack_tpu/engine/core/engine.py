@@ -136,6 +136,13 @@ class _PendingStep:
     chunk_sched: Optional[List] = None
     chunk_logits: Optional[object] = None
     chunk_ordinal: int = 0
+    # A dedicated prefill launched without its read-back
+    # (_dispatch_prefill_async): its plan and, for a final chunk, the prompt's
+    # first token as the sampler left it on the device ([1] int32), which
+    # collect() reads back and the window launched behind this prefill
+    # takes on the device.  ``sampled`` stays None: nothing chains from it.
+    chunk: Optional[PrefillPlan] = None
+    first_token: Optional[object] = None
     # Window flight record (obs/flight_recorder.WindowRecord) stamped at
     # dispatch; collect() completes + publishes it.  None when tracing is
     # off (the recorder is never consulted) or the step completed its
@@ -826,6 +833,13 @@ class LLMEngine:
         # windows.  Step-thread-only writers.
         self.build_transfers = 0
         self.unchained_dispatches = 0
+        # tpu:step_dispatch_behind_total{kind} /
+        # tpu:step_dispatch_behind_declined_total{reason}: of those
+        # dispatches, the ones launched while another program was in flight
+        # (_dispatch_behind), and the admissions that met a program in
+        # flight and took the synchronous path all the same, by why.
+        self.dispatch_behind: Dict[str, int] = {"prefill": 0, "window": 0}
+        self.dispatch_behind_declined: Dict[str, int] = {}
         self._win_rows = step_programs.WIN_ROWS
         if self.lora_registry is not None:
             self._win_rows += ("adapter",)
@@ -844,6 +858,11 @@ class LLMEngine:
             self._win_unpack_fn = self._jit(
                 "win_unpack_fn", step_programs.win_unpack(self._win_rows),
                 out_shardings=out,
+            )
+            # win_unpack_fn's token argument where no row's first token is
+            # on the device: made once, so that a rebuild sends nothing more.
+            self._no_first_token = jax.device_put(
+                np.zeros((1,), np.int32), self._sharding(P())
             )
 
     def _jit(self, name: str, fn, **jit_kwargs):
@@ -1097,6 +1116,7 @@ class LLMEngine:
             "out_tokens", "ctx_tokens",
         )},
         "chunk": P(),
+        "first_row": P(),
     }
 
     def _sharding(self, spec: P) -> NamedSharding:
@@ -1303,6 +1323,8 @@ class LLMEngine:
         p = self._pending.popleft()
         if p.outputs is not None:
             outputs = p.outputs
+        elif p.chunk is not None:
+            outputs = self._collect_prefill(p)
         elif p.steps is not None:
             outputs = self._collect_window(p)
         else:
@@ -1340,21 +1362,18 @@ class LLMEngine:
             # "every row finished" says nothing about the chunk schedule
             # — dropping it would skip the final chunk's first-token
             # finalization (and the chunk/waste accounting) for a prompt
-            # whose KV the device already wrote.
-            while (
-                self._pending
-                and self._pending[0].sampled is not None
-                and self._pending[0].chunk_sched is None
-                and all(s.is_finished for s in self._pending[0].seqs)
-            ):
+            # whose KV the device already wrote.  A prefill launched
+            # behind this step whose prompt was aborted since has no one
+            # to read its token for.
+            while self._pending and self._nothing_to_deliver(self._pending[0]):
                 d = self._pending.popleft()
                 if d.rec is not None:
                     # Complete the dropped overrun's record so every
                     # dispatched window appears exactly once: a plain
                     # window's rows are all frozen (the device emitted
                     # nothing), a single step sampled one discarded
-                    # token per row.
-                    n = 0 if d.steps is not None else len(d.seqs)
+                    # token per row, an aborted prompt's prefill none.
+                    n = 0 if d.steps is not None or d.chunk else len(d.seqs)
                     self.obs.recorder.on_collect(
                         d.rec, host_s=d.host_s,
                         tokens_emitted=n, tokens_wasted=n,
@@ -1368,6 +1387,18 @@ class LLMEngine:
         # stackcheck: allow=SC201 reason=duty-cycle window trim; feeds the tpu:duty_cycle metric only, never a plan (replicas may report different utilization, they may not schedule differently)
         self._busy_window = [(t, d) for (t, d) in self._busy_window if t > cutoff]
         return outputs
+
+    @staticmethod
+    def _nothing_to_deliver(p: _PendingStep) -> bool:
+        """An in-flight step collect() may drop unread: every sequence it
+        would deliver a token to has finished since it was launched."""
+        if p.chunk is not None:
+            return p.chunk.seq.is_finished
+        return (
+            p.sampled is not None
+            and p.chunk_sched is None
+            and all(s.is_finished for s in p.seqs)
+        )
 
     def _dispatch_front(self) -> bool:
         """Dispatch with nothing in flight: full scheduler knowledge
@@ -1401,6 +1432,19 @@ class LLMEngine:
             )
         if plan.decode is None:
             cp = plan.prefill_chunk
+            if (
+                self._pipeline_enabled
+                and self._window_fn is not None
+                and self._prefill_behind_decline(cp.seq) is None
+            ):
+                # Nothing to launch it behind, but nothing to read back
+                # for either: the window that follows (or the next chunk)
+                # goes behind it (_dispatch_behind).
+                # stackcheck: allow=SC201 reason=t0 stamps the flight record and host_s (stats fields); no plan state reads it
+                self._pending.append(self._dispatch_prefill_async(
+                    cp, behind=False, t0=t0, fallback=plan.window_fallback,
+                ))
+                return True
             # The synchronous paths open their record before the work, so
             # that its spans, programs and launch stamps land on it.
             rec = self._open_record(
@@ -1503,6 +1547,8 @@ class LLMEngine:
         if not self._pipeline_enabled:
             return False
         prev = self._pending[-1]
+        if prev.chunk is not None:
+            return self._dispatch_behind(prev)
         if prev.sampled is None:
             return False  # only pipelined decode steps chain
         if prev.win_state is not None:
@@ -1511,7 +1557,11 @@ class LLMEngine:
                     prev.seqs, prev.steps
                 )
             if plan is None:
-                return False
+                # No window chains from this one.  Where that is because a
+                # prompt waits, its prefill does not wait for the read-back.
+                return bool(
+                    self.scheduler.num_waiting
+                ) and self._dispatch_behind(prev)
             if plan.chunk_schedule is not None:
                 # A waiting head's chunks chain onto the in-flight
                 # carry as a mixed window — the pipeline never drains
@@ -1532,6 +1582,133 @@ class LLMEngine:
             self._dispatch_decode_async(plan.seqs, True, prev.sampled)
         )
         return True
+
+    def _dispatch_behind(self, prev: _PendingStep) -> bool:
+        """An admission's dispatches, launched behind the program in flight
+        instead of after its read-back: while a prompt waits, its next
+        prefill chunk (behind the window, or behind the prefill before it);
+        once none does and ``prev`` is that prefill, the decode window that
+        follows, rebuilt from host state with the row the prefill admits
+        taking its first token on the device.  The launches are those of
+        the synchronous order — window, prefill, its sampler, window — with
+        the same arrays; what moves is when the host does its part.  Where
+        the plan or the rows need what only collected state gives, declines
+        (counted by reason) and _dispatch_front serves the admission at the
+        boundary, as ever."""
+        sched = self.scheduler
+        if sched.num_waiting:
+            t0 = time.time()
+            reason = self._prefill_behind_decline(sched._admission_queue()[0])
+            plan = None
+            if reason is None:
+                with self.obs.phase("schedule"):
+                    plan, reason = sched.schedule_prefill_behind()
+            if plan is not None:
+                # stackcheck: allow=SC201 reason=t0 stamps the flight record and host_s (stats fields); no plan state reads it
+                step = self._dispatch_prefill_async(plan, behind=True, t0=t0)
+                self._pending.append(step)
+                return True
+        else:
+            plan, reason = self._plan_window_behind(prev)
+            if plan is not None:
+                self._pending.append(self._dispatch_window(plan, behind=prev))
+                return True
+        if reason is not None:
+            self.dispatch_behind_declined[reason] = (
+                self.dispatch_behind_declined.get(reason, 0) + 1
+            )
+        return False
+
+    def _prefill_behind_decline(self, seq: Sequence) -> Optional[str]:
+        """Why ``seq``'s prefill has to be read back before anything follows
+        it, from what the engine and the request show; None: it may be
+        launched and left on the device (_dispatch_prefill_async)."""
+        if self.config.scheduler.mixed_enabled:
+            # With rows decoding, schedule() hands an admission to the mixed
+            # planners, which plan at the boundary: a window launched early
+            # behind this prefill would make the next prompt wait for it.
+            return "mixed_batch"
+        if self._spec_window_fn is not None:
+            return "speculative"  # the window after it carries `hist`
+        if self._exports:
+            return "prefix_export"  # due at finalize, from collected state
+        sp = seq.sampling_params
+        if sp.echo and sp.logprobs:
+            return "prompt_logprobs"
+        if sp.max_tokens == 0:
+            return "max_tokens_0"
+        if self._host_state_flags(seq)[0]:
+            return "host_state"
+        return None
+
+    def _plan_window_behind(self, prev: _PendingStep):
+        """(the plan of the window to launch behind the prefill ``prev``,
+        None), or (None, why it waits for that prefill's read-back; no
+        reason where there is no window to launch)."""
+        if not prev.chunk.is_final:
+            return None, None
+        seq = prev.chunk.seq
+        first = seq if seq in self.scheduler.running else None
+        if first is not None and self._host_state_flags(first)[1]:
+            # Its occurrence state counts the token still on the device.
+            return None, "penalties"
+        if self._batch_uses_host_state(self.scheduler.running):
+            return None, "host_state"
+        with self.obs.phase("schedule"):
+            return self.scheduler.schedule_window_behind(first)
+
+    def _dispatch_prefill_async(
+        self, plan: PrefillPlan, behind: bool, t0: float,
+        fallback: Optional[str] = None,
+    ) -> _PendingStep:
+        """A dedicated prefill chunk launched and, after a final chunk, the
+        sampler of the prompt's first token, both left on the device for
+        collect() (_collect_prefill).  ``behind``: a program was in flight
+        (counted); else the device was empty (_dispatch_front) and only
+        what follows gains."""
+        rec = self._open_record(
+            "prefill", chunks=(plan,), cover=plan.cover, behind=behind,
+            fallback=fallback,
+        )
+        self._stamp_record(rec, t0, gap=False)
+        logits, _ = self._launch_prefill(plan, rec)
+        first_token = None
+        if plan.is_final:
+            with self.obs.phase("launch", rec):
+                _, first_token = self._sample_launch(
+                    logits[None, :], [plan.seq], None
+                )
+        self._step_counter += 1
+        self._note_compiles(rec)
+        self.dispatch_behind["prefill"] += behind
+        # stackcheck: allow=SC201 reason=host_s is a stats field (host-gap metric); no plan state reads it
+        return _PendingStep(
+            chunk=plan, first_token=first_token, host_s=time.time() - t0,
+            rec=rec,
+        )
+
+    def _collect_prefill(self, p: _PendingStep) -> List[StepOutput]:
+        """The read-back of a prefill left on the device: a final chunk's
+        first token, appended as _finalize_final_prefill appends it.
+        A sequence aborted while the prefill flew takes nothing."""
+        seq, rec = p.chunk.seq, p.rec
+        outputs: List[StepOutput] = []
+        if p.first_token is not None:
+            with self.obs.phase("collect", rec, family=False):
+                token = int(np.asarray(p.first_token)[0])
+            if not seq.is_finished:
+                with self.obs.phase("sample", rec, family=False):
+                    outputs = self._append_and_check(
+                        [seq], [token], first_token=True
+                    )
+        if rec is not None:
+            self._note_compiles(rec)
+            self.obs.recorder.on_collect(
+                rec, host_s=p.host_s,
+                tokens_emitted=len(outputs), tokens_delivered=len(outputs),
+                chunk_tokens_delivered=p.chunk.num_new_tokens,
+            )
+        return outputs
 
     # Host-state verdicts are cached per-sequence at admission instead of
     # re-reading SamplingParams attribute chains in a Python loop on the
@@ -1618,9 +1795,9 @@ class LLMEngine:
         its decode rows, ``chunks`` the PrefillPlans riding it.  Opened
         BEFORE the work so that the work's phase spans, program launches
         and stamps land on it; None with tracing off.  ``ahead``: tokens
-        still in flight on the device, one number for every row or the
-        ``_PendingStep`` this dispatch chains from (a lookahead dispatch's
-        rows are that much longer than the host knows).
+        still in flight on the device, one number for every row, a number
+        a sequence id, or the ``_PendingStep`` this dispatch chains from (a
+        lookahead dispatch's rows are that much longer than the host knows).
         ``bucket_tokens``: the chunk program's token slots where they are
         not the plans' buckets (a mixed window scans a power of two)."""
         if bucket_tokens is None:
@@ -1638,6 +1815,8 @@ class LLMEngine:
                 s.seq_id: n for s, n in zip(ahead.seqs, ahead.steps)
             }
             ahead = 0
+        elif isinstance(ahead, dict):
+            budget, ahead = ahead, 0
         else:
             budget = {}
         kv_tokens = 0
@@ -1910,17 +2089,24 @@ class LLMEngine:
             )
         return static
 
-    def _window_host_state(self, seqs: List[Sequence], steps: List[int]):
+    def _window_host_state(self, seqs: List[Sequence], steps: List[int],
+                           first_row: int = -1):
         """Host arrays + static flags for a window batch (re)build: every
         per-row scalar as one row of ``packed`` [N, S] int32 (the rows of
         ``self._win_rows``, floats bitcast), the block tables, the stop
         ids.  Padding rows: ``done``, ``top_ps`` 1, ``repetition`` 1, else
-        0 (null block, temperature 0)."""
+        0 (null block, temperature 0).  ``first_row``: the row whose first
+        token is still on the device (-1: none): it stands one token further
+        along than its sequence says, its token for the device to fill in."""
         S = self._decode_bucket(len(seqs))
         n = len(seqs)
         (tokens, positions, tables, ctx_lens, _sb, _so) = (
             self._decode_batch_arrays(seqs, S)
         )
+        if first_row >= 0:
+            tokens[first_row] = 0
+            positions[first_row] += 1
+            ctx_lens[first_row] += 1
         at = self._win_row_at
         packed = np.zeros((len(at), S), np.int32)
         f32 = packed.view(np.float32)
@@ -1940,7 +2126,10 @@ class LLMEngine:
             if not seeded:
                 seeds[i] = i
             if min_tokens:
-                min_left[i] = max(0, min_tokens - len(seq.output_token_ids))
+                min_left[i] = max(
+                    0, min_tokens - len(seq.output_token_ids)
+                    - (i == first_row)
+                )
             stop_lists.append(stop)
         if "adapter" in at:
             packed[at["adapter"], :n] = [s.adapter_idx for s in seqs]
@@ -1968,18 +2157,28 @@ class LLMEngine:
             ),
         }
 
-    def _window_build(self, seqs: List[Sequence], steps: List[int]) -> dict:
+    def _window_build(self, seqs: List[Sequence], steps: List[int],
+                      first: Optional[tuple] = None) -> dict:
         """Full batch (re)build: ONE transfer (_stage) carries every window
         input to the device, the per-row scalars packed and unpacked there
         (win_unpack_fn); the occurrence state the penalty math reads takes
         a second where a row has penalties.  Runs once per batch
         composition; steady-state windows chain through _window_chain's
-        delta transfer instead."""
-        h = self._window_host_state(seqs, steps)
+        delta transfer instead.  ``first``: (a sequence of ``seqs`` whose
+        prefill is still in flight, its sampled first token [1] on the
+        device), which win_unpack_fn writes into that row's ``tokens``."""
+        first_row, first_token = -1, self._no_first_token
+        if first is not None:
+            first_row = seqs.index(first[0])
+            # The sampler's output under the sharding of the constant it
+            # stands in for: one compiled win_unpack_fn for both.
+            first_token = jax.device_put(first[1], self._sharding(P()))
+        h = self._window_host_state(seqs, steps, first_row)
         S = h["S"]
         host = {
             "packed": h["packed"], "tables": h["tables"],
             "stop_ids": h["stop_ids"],
+            "first_row": np.array([first_row], np.int32),
         }
         if self._spec_window_fn is not None:
             # Carried drafting history for the fused speculative window:
@@ -2022,7 +2221,9 @@ class LLMEngine:
                 host["draft_tables"] = dt
         self.unchained_dispatches += 1
         dev = self._stage(host)
-        state = self._win_unpack_fn(dev.pop("packed"))
+        state = self._win_unpack_fn(
+            dev.pop("packed"), first_token, dev.pop("first_row")
+        )
         state.update(dev)
         for flag in ("use_penalties", "use_min_floor", "sample_sorts"):
             state[flag] = h[flag]
@@ -2104,38 +2305,49 @@ class LLMEngine:
 
     # stackcheck: root=step-thread
     @_enclosed("dispatch")
-    def _dispatch_window(self, plan, chain_from: Optional[_PendingStep] = None
+    def _dispatch_window(self, plan, chain_from: Optional[_PendingStep] = None,
+                         behind: Optional[_PendingStep] = None,
                          ) -> _PendingStep:
         """Enqueue one K-step decode window on the device and return
         without any host round-trip.  ``chain_from=None`` (re)builds the
         device-resident window state from host bookkeeping;  otherwise
         the state chains from the previous window's in-flight carry
-        (pipelined windows — the device never drains between them)."""
+        (pipelined windows — the device never drains between them).
+        ``behind``: the prefill still in flight this rebuilt window is
+        launched behind; the row it admits, if the plan has it, takes its
+        first token on the device."""
         t0 = time.time()
         decode = plan.decode
         seqs = decode.seqs
         depth = 0
         if chain_from is not None and chain_from.rec is not None:
             depth = chain_from.rec.chain_depth + 1
+        first = None
+        if behind is not None and behind.chunk.seq in seqs:
+            first = (behind.chunk.seq, behind.first_token)
         # Opened as a plain decode window; the fused speculative path
         # below renames it once it is known to be taken.
         rec = self._open_record(
-            "decode", seqs=seqs, ahead=chain_from or 0,
+            "decode", seqs=seqs,
+            ahead={first[0].seq_id: 1} if first else chain_from or 0,
             k=self._window_steps, chain_depth=depth,
             provisional=chain_from is not None,
-            fallback=plan.window_fallback,
+            fallback=plan.window_fallback, behind=behind is not None,
         )
-        self._stamp_record(rec, t0)
+        # A program in flight: no gap to inherit, as under a chained window.
+        self._stamp_record(rec, t0, gap=behind is None)
         with self.obs.phase("build", rec):
             if chain_from is None:
-                state = self._window_build(seqs, decode.steps)
+                state = self._window_build(seqs, decode.steps, first)
             else:
                 state = self._window_chain(chain_from, seqs, decode.steps)
-        if chain_from is None:
+        if chain_from is None and behind is None:
             self._note_decode_launch()
         else:
             self._gap_steps += 1  # device busy: zero gap by construction
             self._last_decode_end = None
+        if behind is not None:
+            self.dispatch_behind["window"] += 1
         lora_kwargs = {}
         if self.lora_registry is not None:
             lora_kwargs = {
@@ -3303,6 +3515,27 @@ class LLMEngine:
 
     def _run_prefill(self, plan: PrefillPlan, rec=None) -> List[StepOutput]:
         seq = plan.seq
+        logits, want_plp = self._launch_prefill(plan, rec)
+        if not plan.is_final:
+            # Non-final chunk of a long prompt: KV is written, but the
+            # logits are mid-prompt — nothing to sample yet.
+            return []
+        outputs = self._finalize_final_prefill(seq, logits, rec=rec)
+        if want_plp and outputs and seq.prompt_lp is not None:
+            # Attach the assembled per-position entries to the request's
+            # FIRST token event (position 0 has no predictor -> None).
+            n = seq.echo_prompt_len
+            entries: List = [(None, None)]
+            for pos in range(1, n):
+                entries.append(seq.prompt_lp.get(pos, (None, None)))
+            outputs[0].prompt_logprobs = entries
+        return outputs
+
+    def _launch_prefill(self, plan: PrefillPlan, rec=None):
+        """One chunk's dedicated prefill program, built and launched: (its
+        last valid row's logits [V], still on the device; whether prompt
+        logprobs were asked for, which are then read back here)."""
+        seq = plan.seq
         if self.obs.enabled and seq.first_scheduled_time is None:
             seq.first_scheduled_time = time.time()
             self.obs.on_first_scheduled(seq, seq.first_scheduled_time)
@@ -3323,20 +3556,7 @@ class LLMEngine:
                 self._collect_prompt_logprobs(seq, plan, plp)
         else:
             logits, self.kv_caches = out
-        if not plan.is_final:
-            # Non-final chunk of a long prompt: KV is written, but the
-            # logits are mid-prompt — nothing to sample yet.
-            return []
-        outputs = self._finalize_final_prefill(seq, logits, rec=rec)
-        if want_plp and outputs and seq.prompt_lp is not None:
-            # Attach the assembled per-position entries to the request's
-            # FIRST token event (position 0 has no predictor -> None).
-            n = seq.echo_prompt_len
-            entries: List = [(None, None)]
-            for pos in range(1, n):
-                entries.append(seq.prompt_lp.get(pos, (None, None)))
-            outputs[0].prompt_logprobs = entries
-        return outputs
+        return logits, want_plp
 
     def _prefill_kwargs(self, plan: PrefillPlan):
         """(the dedicated prefill executable's keyword arguments, on the
@@ -4607,6 +4827,10 @@ class LLMEngine:
             "prefix_chain_step_blocks": self.block_pool.chain_blocks_hashed,
             "step_build_transfers": self.build_transfers,
             "step_unchained_dispatches": self.unchained_dispatches,
+            "step_dispatch_behind": dict(self.dispatch_behind),
+            "step_dispatch_behind_declined": dict(
+                self.dispatch_behind_declined
+            ),
             # Quantized KV tiering plane: bytes crossing each tier
             # boundary by wire format, and snapshot serde versions put
             # on the kvserver wire (tpu:kv_wire_bytes_total /

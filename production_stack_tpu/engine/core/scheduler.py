@@ -1256,6 +1256,77 @@ class Scheduler:
                 seq.block_table.extend(self.block_pool.allocate(need))
         return DecodePlan(seqs=list(self.running), steps=[1] * len(self.running))
 
+    # -- an admission behind the work in flight -----------------------------
+
+    def schedule_prefill_behind(
+        self,
+    ) -> Tuple[Optional[PrefillPlan], Optional[str]]:
+        """Plan the head prompt's next dedicated prefill chunk while a
+        program is still in flight, from what the host knows without that
+        program's read-back: the chunk ``schedule()`` would plan once the
+        pass in flight is collected, under the optimistic no-finish
+        assumption the chained windows use (a row that finishes in flight
+        frees its slot and blocks at collect, after this plan).  Never
+        preempts, restores or fetches.  Returns (plan, None), or (None, why
+        not): the synchronous path then plans at the boundary, with
+        collected state.  (Under ``mixed_enabled`` the admission is the
+        mixed planners'; the engine does not ask.)"""
+        if self.preempted:
+            return None, "preempted"
+        head = self.waiting[0]
+        if head.offloaded or self.remote_prefix_cb is not None:
+            return None, "block_fetch"
+        if len(self.running) >= self.config.max_num_seqs:
+            return None, "no_free_row"
+        plan = self._try_schedule_prefill()
+        if plan is None:
+            return None, "no_free_blocks"
+        return plan, None
+
+    def schedule_window_behind(
+        self, first: Optional[Sequence]
+    ) -> Tuple[Optional[StepPlan], Optional[str]]:
+        """Plan the decode window that follows an admission while the
+        admitting prefill is still in flight: every running row with the
+        budget ``_try_schedule_decode`` gives it, and ``first`` (the row that
+        prefill admits; its first token is on the device) one token further
+        along than the host's bookkeeping says.  A ``first`` whose budget is
+        that one token stays in the plan with no step to run: it finishes
+        at the prefill's collect and is an overrun of this window.  Returns
+        (plan, None); (None, None) where no row has a step to run (a window
+        of nothing is not launched); (None, why) where backing the window
+        would take a preemption."""
+        window = self.config.window_steps
+        if window <= 1:
+            return None, None
+        bs = self.block_pool.block_size
+        max_tok = self._window_token_cap(window)
+        steps: List[int] = []
+        needs: List[int] = []
+        for seq in self.running:
+            ahead = 1 if seq is first else 0
+            base_tokens = seq.num_tokens + ahead
+            room_len = self.config.max_model_len - base_tokens
+            room_out = (
+                seq.sampling_params.max_tokens - seq.num_generated - ahead
+            )
+            k = max(1 - ahead, min(max_tok, room_len, room_out))
+            steps.append(k)
+            slots = base_tokens + k - 1
+            needs.append(max(0, -(-slots // bs) - len(seq.block_table)))
+        if not any(steps):
+            return None, None
+        total = sum(needs)
+        if total and not self.block_pool.can_allocate(total):
+            return None, "no_free_blocks"
+        for seq, need in zip(self.running, needs):
+            if need:
+                seq.block_table.extend(self.block_pool.allocate(need))
+        return StepPlan(
+            decode=DecodePlan(seqs=list(self.running), steps=steps),
+            decode_window=window,
+        ), None
+
     # -- preemption / release ---------------------------------------------
 
     def _preempt_youngest(self) -> None:

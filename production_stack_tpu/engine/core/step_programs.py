@@ -757,9 +757,12 @@ def win_unpack(rows):
     rows bitcast back, ``done`` a bool again), and the empty occurrence state
     of a batch without penalties.  What ``pipe_unpack`` is to the K=1
     pipeline; the tables and stop ids ride beside it in the same transfer and
-    need no program."""
+    need no program.  ``first_token`` [1] / ``first_row`` [1]: a window
+    launched behind the prefill that admits one of its rows takes that row's
+    token from the prefill's sampler, still on the device; ``first_row`` -1
+    (every other rebuild) leaves ``tokens`` as the host packed them."""
 
-    def unpack(packed):
+    def unpack(packed, first_token, first_row):
         state = {}
         for i, name in enumerate(rows):
             row = packed[i]
@@ -769,6 +772,10 @@ def win_unpack(rows):
                 row = row != 0
             state[name] = row
         S = packed.shape[1]
+        state["tokens"] = jnp.where(
+            jnp.arange(S, dtype=jnp.int32) == first_row[0],
+            first_token[0].astype(jnp.int32), state["tokens"],
+        )
         state["counts"] = jnp.zeros((S, 1), jnp.int16)
         state["seen"] = jnp.zeros((S, 1), bool)
         return state

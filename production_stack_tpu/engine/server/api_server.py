@@ -448,6 +448,21 @@ def build_engine_app(
                 vocab.TPU_PREFILL_ATTN_TILES, "state",
                 s["prefill_attn_tiles"],
             )
+            # Unchained dispatches launched behind a program in flight, and
+            # the admissions that waited for a read-back, by why: every
+            # series from boot, so that a share reads 0, not nothing.
+            + vocab.render_labeled_counter(
+                vocab.TPU_STEP_DISPATCH_BEHIND, "kind",
+                s["step_dispatch_behind"],
+            )
+            + vocab.render_labeled_counter(
+                vocab.TPU_STEP_DISPATCH_BEHIND_DECLINED, "reason",
+                {
+                    **dict.fromkeys(
+                        vocab.TPU_STEP_DISPATCH_BEHIND_DECLINE_REASONS, 0),
+                    **s["step_dispatch_behind_declined"],
+                },
+            )
             + vocab.render_labeled_counter(
                 vocab.TPU_MOE_ASSIGNMENTS, "where", s["moe_assignments"],
             )

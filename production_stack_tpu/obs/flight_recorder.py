@@ -59,6 +59,7 @@ class WindowRecord:
     seq_ids: Tuple[str, ...]       # sequences riding this dispatch
     chain_depth: int = 0           # 0 = cold dispatch; n = nth chained window
     provisional: bool = False      # planned off in-flight carry (lookahead)
+    behind: bool = False           # built from host state, launched behind a program in flight
     spec_width: int = 0            # draft tokens per iteration (spec windows)
     drafter: str = ""              # proposal source ("ngram"/"model"), spec only
     chunk_prompts: int = 0         # distinct prompts whose chunks packed in
@@ -143,6 +144,7 @@ class WindowRecord:
             "seq_ids": list(self.seq_ids),
             "chain_depth": self.chain_depth,
             "provisional": self.provisional,
+            "behind": self.behind,
             "fallback": self.fallback,
             "host_gap_s": round(self.host_gap_s, 6),
             "host_s": round(self.host_s, 6),
@@ -222,6 +224,7 @@ class FlightRecorder:
         seq_ids: Tuple[str, ...] = (),
         chain_depth: int = 0,
         provisional: bool = False,
+        behind: bool = False,
         spec_width: int = 0,
         drafter: str = "",
         chunk_prompts: int = 0,
@@ -255,6 +258,7 @@ class FlightRecorder:
             seq_ids=tuple(seq_ids),
             chain_depth=int(chain_depth),
             provisional=bool(provisional),
+            behind=bool(behind),
             spec_width=int(spec_width),
             drafter=str(drafter),
             chunk_prompts=int(chunk_prompts),

@@ -363,6 +363,23 @@ REGISTRY = {
                 "to chain from: dedicated prefills and decode windows "
                 "rebuilt after the running set changed",
     },
+    "tpu:step_dispatch_behind_total": {
+        "kind": "counter", "layer": "engine", "labels": ("kind",),
+        "mirrors": ("fake_engine", "dashboard", "docs"),
+        "help": "Of the dispatches built from host state, those launched "
+                "while another program was in flight (kind: prefill | "
+                "window): an admission that did not empty the device",
+    },
+    "tpu:step_dispatch_behind_declined_total": {
+        "kind": "counter", "layer": "engine", "labels": ("reason",),
+        "mirrors": ("fake_engine", "dashboard", "docs"),
+        "help": "Admissions that met a program in flight and waited for "
+                "its read-back all the same, by why: what the plan or the "
+                "rows needed of collected state (reason: prompt_logprobs | "
+                "max_tokens_0 | prefix_export | host_state | penalties | "
+                "speculative | mixed_batch | preempted | block_fetch | "
+                "no_free_row | no_free_blocks)",
+    },
     "tpu:state_slots_in_use": {
         "kind": "gauge", "layer": "engine",
         "mirrors": ("fake_engine", "dashboard", "docs"),

@@ -242,6 +242,20 @@ TPU_PREFIX_CHAIN_STEP_BLOCKS = "tpu:prefix_chain_step_blocks_total"
 # 2 where a row has penalties; more says a build grew a transfer of its own.
 TPU_STEP_BUILD_TRANSFERS = "tpu:step_build_transfers_total"
 TPU_STEP_UNCHAINED_DISPATCH = "tpu:step_unchained_dispatch_total"
+# Of those dispatches, the ones launched while another program was in flight
+# (engine/core/engine.py: _dispatch_behind): an admission's prefill behind the
+# window (or the prefill) before it, and the window that follows behind that
+# prefill, the new row's first token taken on the device.  The rest met an
+# empty device: an idle engine, or an admission that needed collected state,
+# counted by why under the second family.
+TPU_STEP_DISPATCH_BEHIND = "tpu:step_dispatch_behind_total"
+TPU_STEP_DISPATCH_BEHIND_KINDS = ("prefill", "window")
+TPU_STEP_DISPATCH_BEHIND_DECLINED = "tpu:step_dispatch_behind_declined_total"
+TPU_STEP_DISPATCH_BEHIND_DECLINE_REASONS = (
+    "prompt_logprobs", "max_tokens_0", "prefix_export", "host_state",
+    "penalties", "speculative", "mixed_batch", "preempted", "block_fetch",
+    "no_free_row", "no_free_blocks",
+)
 # A model that keeps recurrent state beside its keys (engine/kv/state_pool.py):
 # slots held (live sequences' and snapshots'), snapshots of the state left at
 # block boundaries, admissions that started from one, admissions whose cached

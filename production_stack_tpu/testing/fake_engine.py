@@ -577,6 +577,13 @@ def build_fake_engine_app(state: FakeEngineState | None = None) -> web.Applicati
             vocab.TPU_MULTISTEP_FALLBACK, "reason",
             dict.fromkeys(vocab.TPU_MULTISTEP_FALLBACK_REASONS, 0),
         ) + vocab.render_labeled_counter(
+            # The fake dispatches nothing, behind or not: at zero (SC303).
+            vocab.TPU_STEP_DISPATCH_BEHIND, "kind",
+            dict.fromkeys(vocab.TPU_STEP_DISPATCH_BEHIND_KINDS, 0),
+        ) + vocab.render_labeled_counter(
+            vocab.TPU_STEP_DISPATCH_BEHIND_DECLINED, "reason",
+            dict.fromkeys(vocab.TPU_STEP_DISPATCH_BEHIND_DECLINE_REASONS, 0),
+        ) + vocab.render_labeled_counter(
             # No prefill kernel in the fake: the family, at zero (SC303).
             vocab.TPU_PREFILL_ATTN_TILES, "state",
             dict.fromkeys(vocab.TPU_PREFILL_ATTN_TILE_STATES, 0),

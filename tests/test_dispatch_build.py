@@ -317,9 +317,15 @@ class Builds:
             return got, (engine.build_transfers - before[0],
                          engine.unchained_dispatches - before[1])
 
-        def on_window(seqs, steps):
+        def on_window(seqs, steps, first=None):
+            if first is not None:
+                # A window launched behind the prefill that admits a row
+                # (PR 51): what the parent built once it had read the token.
+                first[0].output_token_ids.append(int(first[1][0]))
             want = legacy_window_build(engine, seqs, steps)
-            got, grew = counted(window_build, seqs, steps)
+            if first is not None:
+                first[0].output_token_ids.pop()
+            got, grew = counted(window_build, seqs, steps, first)
             self.windows.append((dict(got), want, grew))
             return got
 
@@ -532,7 +538,8 @@ def test_win_unpack_names_its_rows():
     f32 = packed.view(np.float32)
     f32[rows.index("temps")] = [0.0, 0.7, 1.5, -0.0]
     packed[rows.index("done")] = [0, 1, 0, 1]
-    state = jax.jit(step_programs.win_unpack(rows))(packed)
+    state = jax.jit(step_programs.win_unpack(rows))(
+        packed, np.zeros((1,), np.int32), np.full((1,), -1, np.int32))
     assert sorted(state) == sorted(rows + ("counts", "seen"))
     for i, name in enumerate(rows):
         if name in step_programs.WIN_FLOAT_ROWS:

@@ -15,10 +15,16 @@ dispatched inside the measured window it tables, for each kind of dispatch
 - ``window_chained``  a decode window chained off an in-flight carry,
 
 the count, a second, and the mean / median / 95th percentile of its ``build``
-and ``launch`` spans in ms, and how many dispatches a second were unchained
-(prefills + rebuilt windows).  The table is one JSON object on stderr and in
-``chiprun_out/pr49/phase_table.<label>.json``.  Host times: a chip run's are
-the TPU host's, a CPU run's say nothing about a deployment.
+and ``launch`` spans in ms, how many dispatches a second were unchained
+(prefills + rebuilt windows), and for those two kinds **behind / device
+empty**: how many were launched while another program was in flight (the
+record's ``behind``, PR 51) and how many met an empty device, with the
+engine's own counters over the window beside them (``/metrics``:
+``tpu:step_dispatch_behind_total{kind}`` and
+``tpu:step_dispatch_behind_declined_total{reason}``; nothing from a checkout
+without them).  The table is one JSON object on stderr and in
+``chiprun_out/phase_table/phase_table.<label>.json``.  Host times: a chip
+run's are the TPU host's, a CPU run's say nothing about a deployment.
 """
 
 from __future__ import annotations
@@ -31,6 +37,22 @@ import statistics
 import sys
 
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+UNCHAINED = ("prefill", "window_rebuilt")
+BEHIND_FAMILIES = ("tpu:step_dispatch_behind_total",
+                   "tpu:step_dispatch_behind_declined_total")
+
+
+def behind_counters(text: str) -> dict:
+    """{family: {label value: count}} of the two families, from /metrics."""
+    out = {}
+    for line in text.splitlines():
+        head, _, value = line.rpartition(" ")
+        name, _, labels = head.partition("{")
+        if name in BEHIND_FAMILIES and labels:
+            out.setdefault(name, {})[labels.split('"')[1]] = float(value)
+    return out
 
 
 def kind_of(w: dict) -> str:
@@ -55,6 +77,9 @@ def table(windows: list, lo: float, hi: float) -> dict:
     for kind in sorted({kind_of(w) for w in inside}):
         rows = [w for w in inside if kind_of(w) == kind]
         entry = {"n": len(rows), "per_s": len(rows) / seconds}
+        if kind in UNCHAINED:
+            entry["behind"] = sum(bool(w.get("behind")) for w in rows)
+            entry["device_empty"] = len(rows) - entry["behind"]
         for phase in ("build", "launch"):
             ms = sorted(span_ms(w, phase) for w in rows)
             entry[phase + "_ms"] = {
@@ -63,8 +88,7 @@ def table(windows: list, lo: float, hi: float) -> dict:
                 "p95": ms[min(len(ms) - 1, int(0.95 * len(ms)))],
             }
         out["kinds"][kind] = entry
-    unchained = sum(out["kinds"].get(k, {"n": 0})["n"]
-                    for k in ("prefill", "window_rebuilt"))
+    unchained = sum(out["kinds"].get(k, {"n": 0})["n"] for k in UNCHAINED)
     out["unchained_per_s"] = unchained / seconds
     return out
 
@@ -92,6 +116,14 @@ def main() -> None:
         return got
 
     run.drive = keeping
+    scrapes = []
+    parse_prom = run.scrape.parse_prom
+
+    def keeping_labels(text):
+        scrapes.append(behind_counters(text))
+        return parse_prom(text)
+
+    run.scrape.parse_prom = keeping_labels
     sys.argv = [os.path.join(root, "bench", "run.py"), *rest]
     try:
         run.main()
@@ -100,7 +132,14 @@ def main() -> None:
             result = table(kept["windows"]["windows"], kept["wall_t0"],
                            kept["wall_t0"] + kept["seconds"])
             result.update(label=args.label, root=root, argv=rest)
-            out_dir = os.path.join(HERE, "chiprun_out", "pr49")
+            # The engine's counters over the window: the last scrape less
+            # the first (before and after the measured window).
+            if scrapes and scrapes[-1]:
+                result["counters"] = {
+                    family: {label: n - scrapes[0].get(family, {}).get(label, 0)
+                             for label, n in labels.items()}
+                    for family, labels in scrapes[-1].items()}
+            out_dir = os.path.join(HERE, "chiprun_out", "phase_table")
             os.makedirs(out_dir, exist_ok=True)
             with open(os.path.join(
                     out_dir, f"phase_table.{args.label}.json"), "w") as f:
