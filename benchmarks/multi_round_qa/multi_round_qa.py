@@ -76,12 +76,6 @@ class WorkloadConfig:
     # load-aware placement policies keep repairing fleet balance —
     # all-at-once joins freeze placement after round 1.
     join_window: Optional[float] = None
-    # Content salt folded into the shared system prompt: back-to-back A/B
-    # arms over the SAME engines (bench.py multi_round real-engine
-    # ladder) salt each arm so arm N's prompts can never hit arm N-1's
-    # prefix cache — every arm measures from cold content without
-    # rebooting engines.
-    prompt_salt: str = ""
     # Replay real conversations instead of the synthetic workload
     # (reference ShareGPT mode, multi-round-qa.py:181-260,373-381): a JSON
     # list of {"num_round": int, "conversations": [{"value": str,
@@ -149,7 +143,7 @@ class UserSession:
 
     def _system_prompt(self) -> str:
         return (
-            f"{self.config.prompt_salt}Hi, here's some system prompt: "
+            "Hi, here's some system prompt: "
             f"{_dummy_text(self.config.system_prompt_len)}. "
             f"For user {self.user_id}, here are some other context: "
             f"{_dummy_text(self.config.user_info_len)}."

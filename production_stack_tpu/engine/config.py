@@ -721,11 +721,6 @@ class SchedulerConfig:
     prefill_chunk_buckets: Tuple[int, ...] = (128, 256, 512)
     # "recompute" (drop + re-prefill) or "offload" (page out to host DRAM)
     preemption_mode: str = "offload"
-    # Legacy spelling of the K-step decode window (vLLM's
-    # --num-scheduler-steps): a value > 1 forces window size K =
-    # num_scheduler_steps through the same device-resident window
-    # machinery multi_step_window gates.  1 = defer to multi_step_window.
-    num_scheduler_steps: int = 1
     # K-step device-resident decode windows — THE default decode fast
     # path: the scheduler emits pure-decode plans with a decode_window-
     # iteration budget whenever no prompt is waiting, and the engine runs
@@ -737,10 +732,9 @@ class SchedulerConfig:
     # per dispatch (tpu:multistep_fallback_total).  With
     # speculative_ngram set, the n-gram drafter runs INSIDE the window
     # scan (spec_window_enabled).  None = auto (ON); False
-    # (--no-multi-step-window) restores single-token stepping exactly —
-    # and, with speculative_ngram, the legacy host-side speculative path
-    # (greedy parity asserted in tests/test_multistep_window.py and
-    # tests/test_speculative.py).
+    # (--no-multi-step-window) restores single-token stepping exactly
+    # (greedy parity asserted in tests/test_multistep_window.py) and is
+    # refused with either drafter: speculation runs inside the window.
     multi_step_window: Optional[bool] = None
     # Window size K for multi_step_window (compiled-shape inventory grows
     # by one scan executable per decode bucket; scan compile cost is
@@ -758,11 +752,8 @@ class SchedulerConfig:
     # scan iteration, never a host round-trip.  Greedy-only (acceptance
     # compares the model's own argmax); batches with sampled rows run
     # the plain window, and logprobs/logit_bias/guided rows fall back to
-    # single-step like any other window batch.  With
-    # multi_step_window=False the LEGACY host-side speculative path runs
-    # instead (drafts built on the host, one wide verify dispatch per
-    # step — the A/B baseline and the fallback the host-state rows use).
-    # 0 = off.
+    # single-step (the plain decode step, no drafting) like any other
+    # window batch.  0 = off.
     speculative_ngram: int = 0
     # Draft-MODEL speculative decoding: a second, tiny model (a PRESETS
     # name, e.g. "tiny-llama" — loaded through the same registry/weights
@@ -777,8 +768,8 @@ class SchedulerConfig:
     # penalties, min_tokens, stop masks and the PRNG ordinal schedule
     # are shared and greedy streams stay byte-identical across
     # {none, ngram, model}.  Mutually exclusive with speculative_ngram
-    # (one proposal source per engine); requires the window machinery
-    # (no legacy host path exists for the model drafter).  Unlike the
+    # (one proposal source per engine); requires the window machinery,
+    # like the n-gram drafter.  Unlike the
     # n-gram drafter, proposals depend only on draft weights + carried
     # state, so acceptance holds up on non-templated text.  None = off.
     speculative_model: Optional[str] = None
@@ -792,40 +783,26 @@ class SchedulerConfig:
     # cannot allocate draft blocks declines to a plain (non-speculative)
     # window, counted under tpu:multistep_fallback_total{reason=draft_pool}.
     speculative_draft_pool_blocks: Optional[int] = None
-    # Mixed K-step windows: a waiting prompt's prefill chunks ride the
+    # Mixed K-step windows: waiting prompts' prefill chunks ride the
     # device-resident decode scan instead of forcing K=1 steps — each
     # scan iteration runs the packed [decode + chunk] mixed forward
-    # (decode rows advance one token from the carried state; the head
-    # prompt's NEXT chunk rides the same forward with its chunk cursor
-    # carried in-graph), so under sustained arrivals the fleet keeps the
-    # K-fold host-round-trip amortization it used to forfeit whenever a
-    # prompt waited.  The window length is min(decode_window, chunks
-    # remaining for the head prompt, an adaptive clamp halving per
-    # extra waiter) so the window ALWAYS ends at an admission boundary
-    # — greedy streams stay byte-identical and seeded streams
-    # bit-identical to the K=1 mixed path, and TTFT never regresses
-    # more than one window's worth.  None = auto (ON whenever mixed
-    # steps and K-step windows are both active); False
-    # (--no-mixed-window) restores the K=1 mixed scheduling exactly
-    # (waiting head -> K=1 steps, tpu:multistep_fallback_total
-    # {reason="waiting_head"}).
-    mixed_window: Optional[bool] = None
-    # Multi-prompt packed mixed windows: each scan iteration of a mixed
-    # K-step window may carry a chunk cursor from a DIFFERENT waiting
-    # prompt (ragged per-iteration cursors over the same static
+    # (decode rows advance one token from the carried state; a chunk
+    # rides the same forward with its cursor carried in-graph), so under
+    # sustained arrivals the fleet keeps the K-fold host-round-trip
+    # amortization it used to forfeit whenever a prompt waited.  Each
+    # iteration may carry a chunk cursor from a DIFFERENT waiting prompt
+    # (ragged per-iteration cursors over the same static
     # prefill_chunk_buckets shapes — steady-state serving never
     # recompiles), so deep queues fill the window instead of shrinking
-    # it.  The packed path retires the adaptive K-halving clamp
-    # (mixed_window_clamp) and runs full-K pure-decode windows when the
-    # batch is slot-full (no admission is possible mid-window anyway),
-    # driving {reason="waiting_head"} fallbacks to zero under surge.
-    # Admission still happens only at window boundaries, so greedy
-    # streams stay byte-identical and seeded streams bit-identical to
-    # the single-head path.  None = auto (ON whenever
-    # mixed_window_enabled); False (--no-multi-prompt-window) restores
-    # the PR-15 single-head window + adaptive clamp exactly,
-    # plan-by-plan.
-    multi_prompt_window: Optional[bool] = None
+    # it, and a slot-full batch runs full-K pure-decode windows (no
+    # admission is possible mid-window anyway).  Admission happens only
+    # at window boundaries, so greedy streams stay byte-identical and
+    # seeded streams bit-identical to the K=1 mixed path.  None = auto
+    # (ON whenever mixed steps and K-step windows are both active);
+    # False (--no-mixed-window) restores the K=1 mixed scheduling
+    # exactly (waiting head -> K=1 steps, tpu:multistep_fallback_total
+    # {reason="waiting_head"}).
+    mixed_window: Optional[bool] = None
     # Bounded admission (overload protection): once the waiting queue
     # holds this many requests (or prompt tokens), the API server rejects
     # new work with a structured 429 + Retry-After instead of queueing it
@@ -872,10 +849,7 @@ class SchedulerConfig:
     # host-state sampling features fall back per step, and K-step windows
     # chain through the device-resident window carry (done/penalty state
     # rides along, so stopped rows stay frozen in the successor).
-    # None = auto (ON unless the LEGACY host-side speculative path is
-    # active — speculative_ngram with the window disabled — whose wide
-    # verify dispatch is synchronous); explicit True conflicts with that
-    # legacy combination; False forces synchronous stepping.
+    # None = auto (ON); False forces synchronous stepping.
     pipeline_decode: Optional[bool] = None
 
     def __post_init__(self):
@@ -896,43 +870,15 @@ class SchedulerConfig:
                 "exclusive (one proposal source per engine); drop "
                 "--speculative-ngram or pass --no-speculative-model"
             )
-        if self.speculative_model is not None and self.multi_step_window is False:
+        if self.spec_drafter is not None and self.multi_step_window is False:
             raise ValueError(
-                "speculative_model runs INSIDE the K-step window scan and "
-                "has no legacy host-side path; drop --no-multi-step-window "
-                "or --speculative-model"
+                "speculation runs inside the K-step window "
+                f"(speculative_{self.spec_drafter} drafts and verifies in "
+                "the window scan); drop --no-multi-step-window or the "
+                "drafter"
             )
         if self.decode_window < 1:
             raise ValueError("decode_window must be >= 1")
-        if self.num_scheduler_steps > 1 and self.multi_step_window is False:
-            raise ValueError(
-                "num_scheduler_steps > 1 requests a K-step decode window "
-                "but multi_step_window=False disables the window machinery "
-                "that runs it; drop one of the two"
-            )
-        # speculative_ngram COMPOSES with multi_step_window /
-        # num_scheduler_steps / pipeline_decode / mixed_batch: the
-        # drafter runs inside the window scan (draft-and-verify per scan
-        # iteration, acceptance folded into the carried state).  Only
-        # the LEGACY host-side speculative path — speculative_ngram with
-        # the window explicitly disabled — keeps the old conflicts: its
-        # wide verify dispatch is synchronous and one-plan-shaped.
-        legacy_spec = bool(self.speculative_ngram) and self.window_steps == 1
-        if self.pipeline_decode and legacy_spec:
-            raise ValueError(
-                "pipeline_decode requires the fused speculative window; "
-                "the legacy host-side speculative path (speculative_ngram "
-                "with multi_step_window=False) dispatches synchronously — "
-                "drop --no-multi-step-window or --no-pipeline-decode"
-            )
-        if self.mixed_batch and legacy_spec:
-            raise ValueError(
-                "mixed_batch requires the fused speculative window; the "
-                "legacy host-side speculative path (speculative_ngram "
-                "with multi_step_window=False) assumes one plan shape per "
-                "dispatch — drop --no-multi-step-window or "
-                "--no-mixed-batch"
-            )
         if self.mixed_window and self.multi_step_window is False:
             raise ValueError(
                 "mixed_window=True requests prefill chunks riding the "
@@ -943,12 +889,6 @@ class SchedulerConfig:
             raise ValueError(
                 "mixed_window=True requires mixed_batch (the chunk "
                 "machinery); drop --no-mixed-batch or --mixed-window"
-            )
-        if self.multi_prompt_window and self.mixed_window is False:
-            raise ValueError(
-                "multi_prompt_window=True packs prompts into mixed K-step "
-                "windows but mixed_window=False disables those windows; "
-                "drop --no-mixed-window or --multi-prompt-window"
             )
         if not self.prefill_chunk_buckets:
             raise ValueError("prefill_chunk_buckets must be non-empty")
@@ -991,15 +931,9 @@ class SchedulerConfig:
     def window_steps(self) -> int:
         """Resolved K-step decode-window size: iterations a pure-decode
         plan may fuse into one device dispatch.  1 = single-token steps
-        (window off); num_scheduler_steps > 1 keeps its legacy meaning
-        as an explicit window size.  Speculation no longer resolves the
-        window off — the drafter runs INSIDE the scan (spec_window_enabled);
-        only the explicit multi_step_window=False escape hatch restores
-        the legacy host-side speculative path."""
+        (multi_step_window=False, or decode_window=1)."""
         if self.multi_step_window is False:
             return 1
-        if self.num_scheduler_steps > 1:
-            return self.num_scheduler_steps
         return max(1, self.decode_window)
 
     @property
@@ -1026,10 +960,8 @@ class SchedulerConfig:
     def spec_window_enabled(self) -> bool:
         """The fused draft-and-verify path: speculation (n-gram or draft
         model) proposed, verified, and folded INSIDE the K-step window
-        scan.  False means either no speculation, or the legacy host-side
-        speculative path (speculative_ngram with multi_step_window=False;
-        the model drafter has no legacy path — it is simply inert at
-        K=1)."""
+        scan.  False means no speculation, or a drafter left inert by
+        decode_window=1."""
         return self.spec_drafter is not None and self.window_steps > 1
 
     @property
@@ -1046,26 +978,24 @@ class SchedulerConfig:
 
     @property
     def pipeline_enabled(self) -> bool:
-        """Resolved pipeline gate: auto (None) turns on unless the
-        LEGACY host-side speculative path owns the dispatch shape
-        (fused speculative windows chain through the pipeline like any
-        window: N+1 dispatched off window N's device-resident carry,
-        draft history included)."""
+        """Resolved pipeline gate: auto (None) means ON (fused
+        speculative windows chain through the pipeline like any window:
+        N+1 dispatched off window N's device-resident carry, draft
+        history included)."""
         if self.pipeline_decode is None:
-            return not (self.speculative_ngram and self.window_steps == 1)
+            return True
         return self.pipeline_decode
 
     @property
     def mixed_enabled(self) -> bool:
-        """Resolved mixed-step gate: auto (None) turns on unless the
-        LEGACY host-side speculative path is active (mixed steps coexist
-        with K-step windows — speculative or not: the scheduler picks
-        K=1 mixed steps while a prompt waits and K>1 pure-decode windows
-        otherwise).  The engine additionally clears ``mixed_batch`` when
-        the mesh has a dp/sp axis (the packed mixed batch is not
-        dp/sp-shardable)."""
+        """Resolved mixed-step gate: auto (None) means ON (mixed steps
+        coexist with K-step windows — speculative or not: the scheduler
+        picks K=1 mixed steps while a prompt waits and K>1 pure-decode
+        windows otherwise).  The engine additionally clears
+        ``mixed_batch`` when the mesh has a dp/sp axis (the packed mixed
+        batch is not dp/sp-shardable)."""
         if self.mixed_batch is None:
-            return not (self.speculative_ngram and self.window_steps == 1)
+            return True
         return self.mixed_batch
 
     @property
@@ -1078,26 +1008,6 @@ class SchedulerConfig:
         if self.mixed_window is False:
             return False
         return self.mixed_enabled and self.window_steps > 1
-
-    @property
-    def multi_prompt_window_enabled(self) -> bool:
-        """Resolved packed-window gate: auto (None) rides
-        mixed_window_enabled — packing is the default whenever mixed
-        K-step windows exist.  False (--no-multi-prompt-window) keeps
-        the windows but restores the PR-15 single-head planner and its
-        adaptive clamp exactly."""
-        if self.multi_prompt_window is False:
-            return False
-        return self.mixed_window_enabled
-
-    def mixed_window_clamp(self, num_waiting: int) -> int:
-        """Adaptive per-window iteration clamp keyed to waiting-queue
-        depth: the head prompt gets the full window to itself, and each
-        EXTRA waiter halves it (deep queue -> shorter windows -> more
-        frequent admission re-evaluation), so no waiter's TTFT regresses
-        more than one window's worth behind the head's chunks."""
-        extra = max(0, num_waiting - 1)
-        return max(1, self.window_steps >> min(extra, 8))
 
     @property
     def admission_enabled(self) -> bool:

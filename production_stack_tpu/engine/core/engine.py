@@ -638,13 +638,8 @@ class LLMEngine:
         self._penalties_fn = self._jit(
             "penalties_fn", sampling_lib.apply_penalties
         )
-        self._argmax_fn = self._jit(
-            "argmax_fn",
-            lambda logits: jnp.argmax(logits, axis=-1).astype(jnp.int32),
-        )
-        # Speculative decoding effectiveness counters (fed by the
-        # legacy host-side n-gram path and the fused window path, both
-        # drafters).
+        # Speculative decoding effectiveness counters (fed by the fused
+        # window path, both drafters).
         self.spec_tokens_drafted = 0
         self.spec_tokens_accepted = 0
         # Fused speculative-window outcomes per collected window
@@ -2515,7 +2510,7 @@ class LLMEngine:
         chunks ride the same forward, each iteration's cursor
         (cached_len / valid_len / new-block row / prefix table /
         adapter slot) precomputed per iteration and carried as scan xs.
-        Packed windows (multi_prompt_window) interleave cursors from
+        Packed windows interleave cursors from
         SEVERAL prompts: a final chunk's iteration f finalizes its
         prompt at collect with PRNG ordinal base+f, and the next
         iteration's xs switch to the next prompt's tokens and block
@@ -3804,31 +3799,20 @@ class LLMEngine:
         seqs = plan.seqs
         S = self._decode_bucket(len(seqs))
 
-        # Speculative path first — it builds its own (wider) batch, so
-        # deciding here avoids assembling the S-sized arrays only to
-        # discard them.  Greedy-only (acceptance compares argmax), and
-        # every host-state feature falls back like multi-step.
-        spec_k = self.config.scheduler.speculative_ngram
-        if spec_k > 0 and all(
-            s.sampling_params.temperature <= 0
-            and not s.sampling_params.presence_penalty
-            and not s.sampling_params.frequency_penalty
-            and s.sampling_params.repetition_penalty == 1.0
-            and not s.sampling_params.min_tokens
-            and not s.sampling_params.logprobs
-            and not s.sampling_params.logit_bias
-            and s.guide is None
-            for s in seqs
-        ):
-            return self._run_decode_speculative(plan, spec_k, rec)
-
         with self.obs.phase("build", rec):
             batch_spec = shardings_lib.decode_batch_spec()
-            kwargs = self._decode_kwargs(
-                self._decode_batch_arrays(seqs, S), batch_spec,
-                self._lora_kwargs(seqs, S, 1, batch_spec),
+            (tokens, positions, block_tables, ctx_lens, slot_blocks,
+             slot_offsets) = self._decode_batch_arrays(seqs, S)
+            kwargs = dict(
+                tokens=self._put(tokens, batch_spec),
+                positions=self._put(positions, batch_spec),
+                block_tables=self._put(block_tables, P(AXES.DP, None)),
+                ctx_lens=self._put(ctx_lens, batch_spec),
+                slot_block_ids=self._put(slot_blocks, batch_spec),
+                slot_offsets=self._put(slot_offsets, batch_spec),
+                **self._lora_kwargs(seqs, S, batch_spec),
+                **self._state_kwargs(seqs, S),
             )
-            kwargs.update(self._state_kwargs(seqs, S))
         self._note_decode_launch()
         with self.obs.phase("launch", rec):
             logits, self.kv_caches = self._decode_fn(
@@ -3843,47 +3827,24 @@ class LLMEngine:
                 logprob_info=logprob_info,
             )
 
-    def _decode_kwargs(self, arrays, batch_spec, lora_kwargs: Dict) -> Dict:
-        """The decode executable's keyword arguments, on the device, from
-        ``_decode_batch_arrays``-shaped host arrays."""
-        (tokens, positions, block_tables, ctx_lens, slot_blocks,
-         slot_offsets) = arrays
-        return dict(
-            tokens=self._put(tokens, batch_spec),
-            positions=self._put(positions, batch_spec),
-            block_tables=self._put(block_tables, P(AXES.DP, None)),
-            ctx_lens=self._put(ctx_lens, batch_spec),
-            slot_block_ids=self._put(slot_blocks, batch_spec),
-            slot_offsets=self._put(slot_offsets, batch_spec),
-            **lora_kwargs,
-        )
-
-    def _lora_kwargs(self, seqs: List[Sequence], S: int, width: int,
-                     batch_spec) -> Dict:
-        """Decode-call LoRA kwargs with each sequence's adapter repeated
-        across its `width` batch rows (1 for classic decode, K+1 for the
-        speculative chain) — the ONE place the adapter row layout lives."""
+    def _lora_kwargs(self, seqs: List[Sequence], S: int, batch_spec) -> Dict:
+        """Decode-call LoRA kwargs: each sequence's adapter on its batch
+        row — the ONE place the adapter row layout lives."""
         if self.lora_registry is None:
             return {}
-        adapter_idx = np.zeros((S * width,), np.int32)
+        adapter_idx = np.zeros((S,), np.int32)
         for i, seq in enumerate(seqs):
-            adapter_idx[i * width:(i + 1) * width] = seq.adapter_idx
+            adapter_idx[i] = seq.adapter_idx
         return {
             "lora": self.lora_registry.params,
             "adapter_idx": self._put(adapter_idx, batch_spec),
         }
 
-    # Backward-scan bound for drafting: repetition useful to speculation
-    # is overwhelmingly recent (chat history, code loops), and an
-    # unbounded scan would cost O(context) of host time per sequence per
-    # step at long contexts.
-    _DRAFT_SCAN_WINDOW = 1024
-
     # Device-resident history window the FUSED drafter matches against
     # (a fixed [S, H] carry in the window scan — compile-time constant so
-    # the executable inventory never keys on context length).  Smaller
-    # than the host path's scan bound: the lookup is O(S*H) per scan
-    # iteration and recent repetition dominates prompt-lookup hits.
+    # the executable inventory never keys on context length): the
+    # lookup is O(S*H) per scan iteration and recent repetition
+    # dominates prompt-lookup hits.
     _SPEC_HIST_WINDOW = 128
 
     # Model-drafter skip-prime chain length: windows that may chain off
@@ -3894,114 +3855,6 @@ class LLMEngine:
     # sizes the per-row draft-pool capacity: H + chain x
     # window_max_tokens compact slots, rounded up to whole blocks.
     _DRAFT_PRIME_CHAIN = 8
-
-    @classmethod
-    def _draft_ngram(cls, seq: Sequence, k: int, n: int = 2) -> List[int]:
-        """Prompt-lookup drafting: find the most recent earlier occurrence
-        of the trailing n-gram within the scan window of the sequence's
-        own history and propose the k tokens that followed it.  Empty when
-        no match — the step degenerates to a normal decode."""
-        hist = seq.all_token_ids
-        if len(hist) < n + 1:
-            return []
-        key = tuple(hist[-n:])
-        lo = max(0, len(hist) - n - 1 - cls._DRAFT_SCAN_WINDOW)
-        for start in range(len(hist) - n - 1, lo - 1, -1):
-            if tuple(hist[start:start + n]) == key:
-                return list(hist[start + n:start + n + k])
-        return []
-
-    def _run_decode_speculative(
-        self, plan: DecodePlan, k: int, rec=None
-    ) -> List[StepOutput]:
-        """Verify K n-gram-drafted tokens + sample one bonus token in ONE
-        forward: each sequence occupies K+1 rows of an expanded decode
-        batch.  Row j consumes the token at position pos0+j (the last real
-        token, then the drafts), writes its KV, and attends with
-        ctx_len = num_tokens + j — exactly the single-token decode
-        semantics, so the EXISTING decode executable verifies the chain.
-        Accepted drafts' KV is already correct (the written K/V came from
-        the very tokens that were accepted); rejected rows' KV occupies
-        positions that are overwritten when real tokens later reach them
-        (the same argument as multi-step overruns, and the same
-        full-block prefix-registration boundary protects the cache)."""
-        seqs = plan.seqs
-        S = self._decode_bucket(len(seqs))
-        W = k + 1  # rows per sequence
-        with self.obs.phase("build", rec):
-            arrays, drafts = self._speculative_arrays(plan, k, S)
-            batch_spec = shardings_lib.decode_batch_spec()
-            kwargs = self._decode_kwargs(
-                arrays, batch_spec, self._lora_kwargs(seqs, S, W, batch_spec)
-            )
-        self._note_decode_launch()
-        with self.obs.phase("launch", rec):
-            logits, self.kv_caches = self._decode_fn(
-                self.params, kv_caches=self.kv_caches, **kwargs
-            )
-            greedy = self._argmax_fn(logits)
-        with self.obs.phase("collect", rec, family=False):
-            greedy = np.asarray(greedy)  # [R] — one sync
-        with self.obs.phase("sample", rec, family=False):
-            return self._accept_drafts(seqs, drafts, greedy, W)
-
-    def _speculative_arrays(self, plan: DecodePlan, k: int, S: int):
-        """(``_decode_batch_arrays``-shaped host arrays of the expanded
-        [S * (k + 1)] batch, each sequence's draft)."""
-        seqs = plan.seqs
-        W = k + 1
-        R = S * W
-        bs = self.block_pool.block_size
-
-        tokens = np.zeros((R,), np.int32)
-        positions = np.zeros((R,), np.int32)
-        block_tables = np.zeros((R, self._bmax), np.int32)
-        ctx_lens = np.zeros((R,), np.int32)
-        slot_blocks = np.zeros((R,), np.int32)
-        slot_offsets = np.zeros((R,), np.int32)
-        drafts: List[List[int]] = []
-        for i, seq in enumerate(seqs):
-            # Usable draft rows: bounded by the plan's per-seq budget
-            # (blocks were allocated for `steps[i]` appended tokens).
-            nd = min(k, plan.steps[i] - 1)
-            draft = self._draft_ngram(seq, nd) if nd > 0 else []
-            drafts.append(draft)
-            pos0 = seq.num_tokens - 1
-            table = seq.block_table[: self._bmax]
-            chain = [seq.last_token_id] + draft
-            for j, tok in enumerate(chain):
-                r = i * W + j
-                tokens[r] = tok
-                positions[r] = pos0 + j
-                block_tables[r, : len(table)] = table
-                ctx_lens[r] = seq.num_tokens + j
-                slot_blocks[r] = seq.block_table[(pos0 + j) // bs]
-                slot_offsets[r] = (pos0 + j) % bs
-            # Rows past the chain stay inactive: null block 0, ctx 0.
-        return (tokens, positions, block_tables, ctx_lens, slot_blocks,
-                slot_offsets), drafts
-
-    def _accept_drafts(self, seqs: List[Sequence], drafts: List[List[int]],
-                       greedy, W: int) -> List[StepOutput]:
-        # Greedy verification: accept the longest draft prefix the model
-        # agrees with, then take the model's own token from the first
-        # disagreeing (or final) row as the bonus.
-        outputs: List[StepOutput] = []
-        for i, seq in enumerate(seqs):
-            base = i * W
-            draft = drafts[i]
-            m = 0
-            while m < len(draft) and int(greedy[base + m]) == draft[m]:
-                m += 1
-            accepted = draft[:m] + [int(greedy[base + m])]
-            self.spec_tokens_drafted += len(draft)
-            self.spec_tokens_accepted += m
-            for tok in accepted:
-                outs = self._append_and_check([seq], [tok], first_token=False)
-                outputs.extend(outs)
-                if outs and outs[0].finished:
-                    break
-        return outputs
 
     def _sampling_arrays(self, seqs: List[Sequence], S: int):
         """Padded per-sequence sampling parameter arrays [S], from what

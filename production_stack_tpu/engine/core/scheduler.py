@@ -135,7 +135,7 @@ class StepPlan:
                                       iterations runs the packed
                                       [decode + chunk] mixed forward —
                                       decode rows advance one token from
-                                      the carried state while the head
+                                      the carried state while a waiting
                                       prompt's next chunk rides the same
                                       forward, chunk cursor carried
                                       in-graph.  The window always ends
@@ -147,10 +147,9 @@ class StepPlan:
     in flight (optimistic no-finish assumption; the engine rolls back
     at collect).  ``window_fallback`` names the reason a pass that
     WANTED a K>1 window was forced to K=1 (``"waiting_head"`` — the
-    head prompt forced per-token admission; ``"bucket_mismatch"`` —
-    the final chunk's natural bucket differed from the window's static
-    scan shape; ``"pool_pressure"`` — block pool / restore pressure
-    ended chunking early); the engine folds it into
+    head prompt forced per-token admission; ``"pool_pressure"`` — block
+    pool / restore pressure ended chunking early); the engine folds it
+    into
     ``tpu:multistep_fallback_total``."""
 
     decode: Optional[DecodePlan] = None
@@ -158,13 +157,11 @@ class StepPlan:
     decode_window: int = 1
     provisional: bool = False
     # Mixed K-step window: one PrefillPlan per scan iteration, all at
-    # ONE chunk bucket (static scan shape).  Packed windows
-    # (multi_prompt_window) may carry chunks from SEVERAL prompts: a
-    # final chunk mid-schedule admits its prompt and the next iteration
-    # starts the next waiting prompt's cursor (later prompts ride
-    # padded at the window's established bucket — pf_valid masks
-    # identically).  Under --no-multi-prompt-window only the last chunk
-    # may be final (the PR-15 single-head shape).
+    # ONE chunk bucket (static scan shape).  The schedule may carry
+    # chunks from SEVERAL prompts: a final chunk mid-schedule admits its
+    # prompt and the next iteration starts the next waiting prompt's
+    # cursor (later prompts ride padded at the window's established
+    # bucket — pf_valid masks identically).
     chunk_schedule: Optional[List[PrefillPlan]] = None
     window_fallback: Optional[str] = None
 
@@ -242,9 +239,6 @@ class Scheduler:
         # window planning over N waiters must not recompute it per
         # chunk.
         self.budget_computations = 0
-        # Why the last _extend_chunk_schedule stopped early (None = it
-        # ran to a natural end) — window_fallback attribution.
-        self._chunk_stop_reason: Optional[str] = None
 
     # -- admission ---------------------------------------------------------
 
@@ -316,7 +310,7 @@ class Scheduler:
         dedicated prefill — is re-evaluated every token, not every K
         tokens (counted as ``window_fallback="waiting_head"``).
 
-        Packed-window exception (multi_prompt_window): when every batch
+        Packed-window exception (mixed windows on): when every batch
         slot is occupied, NO admission is possible this pass no matter
         how often it is re-evaluated — dropping to K=1 would burn K
         host round-trips purely on ceremony.  Run a pure-decode window
@@ -329,7 +323,7 @@ class Scheduler:
         window = self.config.window_steps
         if window > 1 and self.num_waiting:
             if (
-                self.config.multi_prompt_window_enabled
+                self.config.mixed_window_enabled
                 and len(self.running) >= self.config.max_num_seqs
             ):
                 # Floor 2: still a window (a K=1 pass here would be
@@ -531,57 +525,29 @@ class Scheduler:
         return head
 
     def _extend_chunk_schedule(
-        self, head: Sequence, first: PrefillPlan, buckets: List[int],
-        k_cap: int, budget: int,
+        self, first: PrefillPlan, k_cap: int, budget: int,
     ) -> List[PrefillPlan]:
         """Grow a window's chunk schedule past its first chunk, one
-        ``_try_schedule_prefill`` chunk at a time.
-
-        Single-head mode (--no-multi-prompt-window) iterates the SAME
-        bucket rule K=1 mixed stepping uses, so the planned chunk
-        shapes (and therefore the compiled forwards, and the streams)
-        are identical to the escape-hatch path.  Stops at ``k_cap``, at
-        the head's final chunk, at pool pressure (the window ends
-        non-final and the next window continues), or when the K=1 rule
-        would pick a DIFFERENT bucket for the final chunk (one scan has
-        ONE static chunk shape; the mismatched final chunk runs as the
-        next pass's K=1 mixed step instead — bit-identical either way).
-
-        Packed mode keeps filling the window across prompts: a final
-        chunk admits its prompt, and the next iteration starts the next
-        packable head's cursor.  Every chunk after the first is FORCED
-        to the window's established bucket T — a chunk smaller than T
-        rides padded (pf_valid masks padding out of attention and the
-        tail-logit gather reads the last VALID row, so the compute is
-        bit-identical to the chunk's natural bucket) — which keeps the
-        scan shape static without ever rolling back committed plan
-        state when a prefix hit shrinks a chunk at planning time."""
+        ``_try_schedule_prefill`` chunk at a time, across prompts: a
+        final chunk admits its prompt, and the next iteration starts the
+        next packable head's cursor.  Stops at ``k_cap``, behind a final
+        chunk with nothing packable waiting, or at pool pressure (the
+        window ends non-final and the next window continues).  Every
+        chunk after the first is FORCED to the window's established
+        bucket T — a chunk smaller than T rides padded (pf_valid masks
+        padding out of attention and the tail-logit gather reads the
+        last VALID row, so the compute is bit-identical to the chunk's
+        natural bucket) — which keeps the scan shape static without ever
+        rolling back committed plan state when a prefix hit shrinks a
+        chunk at planning time."""
         schedule = [first]
-        T = first.bucket_len
-        packed = self.config.multi_prompt_window_enabled
-        # Why extension stopped EARLY (window_fallback attribution when
-        # the schedule collapses to K=1): a final chunk / k_cap exit is a
-        # natural end and leaves this None.
-        self._chunk_stop_reason = None
         while len(schedule) < k_cap:
-            if schedule[-1].is_final:
-                if not packed or self._next_packable_head() is None:
-                    break
-            if packed:
-                nxt = self._try_schedule_prefill(
-                    chunk_budget=budget, force_bucket=T
-                )
-            else:
-                remaining = head.num_prompt_tokens - head.num_cached_tokens
-                fit = [b for b in buckets if b >= remaining]
-                if fit and fit[0] != T:
-                    # One scan has ONE static chunk shape; the final
-                    # chunk's natural bucket differs.
-                    self._chunk_stop_reason = "bucket_mismatch"
-                    break
-                nxt = self._try_schedule_prefill(chunk_budget=budget)
+            if schedule[-1].is_final and self._next_packable_head() is None:
+                break
+            nxt = self._try_schedule_prefill(
+                chunk_budget=budget, force_bucket=first.bucket_len
+            )
             if nxt is None:
-                self._chunk_stop_reason = "pool_pressure"
                 break
             schedule.append(nxt)
         return schedule
@@ -605,8 +571,7 @@ class Scheduler:
         return steps
 
     def _try_schedule_mixed_window(self) -> Optional[StepPlan]:
-        """Plan a MIXED K-step window: K = min(window_steps, chunks the
-        head prompt needs, the adaptive queue-depth clamp) scan
+        """Plan a MIXED K-step window: up to window_steps scan
         iterations, each running the packed [decode + chunk] mixed
         forward.  The window always ends at an admission boundary (its
         last chunk is final, or the prompt keeps chunking next window),
@@ -618,12 +583,10 @@ class Scheduler:
         echo+logprobs special cases); a planned single-chunk outcome is
         emitted in the K=1 shape directly (nothing to amortize).
 
-        Packed mode (multi_prompt_window): K is no longer clamped by
-        queue depth — the adaptive clamp existed to re-evaluate
-        admission often, and a packed window IS the admission: a final
-        chunk mid-window admits its prompt and the next iteration
-        starts the next waiter's cursor, so deep queues fill the
-        window instead of shrinking it."""
+        K is not clamped by queue depth: a packed window IS the
+        admission — a final chunk mid-window admits its prompt and the
+        next iteration starts the next waiter's cursor, so deep queues
+        fill the window instead of shrinking it."""
         head = self._mixed_window_head()
         if head is None:
             return None
@@ -631,99 +594,44 @@ class Scheduler:
         buckets = self._chunk_buckets_in_budget(budget)
         if not buckets:
             return None
-        packed = self.config.multi_prompt_window_enabled
-        if packed:
-            k_cap = self.config.window_steps
-        else:
-            k_cap = min(
-                self.config.window_steps,
-                self.config.mixed_window_clamp(self.num_waiting),
-            )
-        if k_cap < 2:
-            # Deep waiting queue: the adaptive clamp demands per-token
-            # admission re-evaluation — today's K=1 behavior.
-            return None
+        k_cap = self.config.window_steps
         # Multi-chunk precheck before committing any state: a head that
         # fits one chunk bucket admits completely in one K=1 mixed step
         # (a false positive from an unknown prefix hit just ends the
-        # window early at the final chunk).  Packed windows keep going
-        # when OTHER waiters could fill the remaining iterations.
+        # window early at the final chunk).  The window keeps going when
+        # OTHER waiters could fill the remaining iterations.
         remaining_max = head.num_prompt_tokens - (
             head.num_cached_tokens if head.partial_prefill else 0
         )
-        if remaining_max <= buckets[-1] and (
-            not packed or self.num_waiting <= 1
-        ):
+        if remaining_max <= buckets[-1] and self.num_waiting <= 1:
             return None
         decode = self._mixed_window_decode_plan(k_cap)
         if decode is None:
             return None
         first = self._try_schedule_prefill(chunk_budget=budget)
-        if first is None or (first.is_final and not packed):
-            # Pool pressure / restore retry, or a prefix hit shrank the
-            # prompt to one final chunk: emit the exact K=1 mixed shape
-            # (decode blocks are over-allocated for the declined window
-            # — they sit in the block tables and back later steps).
-            self._recap_steps_k1(decode)
-            # first can only be None (pool pressure / restore retry) or
-            # final here; a final single chunk is a natural K=1 shape,
-            # not a decline.
-            return StepPlan(
-                decode=decode, prefill_chunk=first, decode_window=1,
-                window_fallback="pool_pressure" if first is None else None,
-            )
-        schedule = self._extend_chunk_schedule(
-            head, first, buckets, k_cap, budget
+        schedule = (
+            [] if first is None
+            else self._extend_chunk_schedule(first, k_cap, budget)
         )
         k_eff = len(schedule)
-        if k_eff == 1:
-            # Couldn't extend (pool pressure / bucket-mismatched final
-            # chunk / nothing packable behind a final first chunk): the
-            # planned chunk runs as today's K=1 mixed step.
-            self._recap_steps_k1(decode)
-            # _extend_chunk_schedule says WHY it stopped when it stopped
-            # early (pool_pressure / bucket_mismatch); a final first
-            # chunk is a natural K=1 shape, not a decline.
+        if k_eff < 2:
+            # Nothing to amortize: emit the exact K=1 mixed shape (decode
+            # blocks are over-allocated for the declined window — they
+            # sit in the block tables and back later steps).  A final
+            # first chunk with nothing packable behind it is a natural
+            # K=1 shape, not a decline; no chunk at all, or no second one
+            # behind a non-final first, is the pool (or a restore retry)
+            # refusing the blocks.
+            decode.steps = [1] * len(decode.seqs)
+            natural = first is not None and first.is_final
             return StepPlan(
                 decode=decode, prefill_chunk=first, decode_window=1,
-                window_fallback=(
-                    None if first.is_final
-                    else (self._chunk_stop_reason or "waiting_head")
-                ),
+                window_fallback=None if natural else "pool_pressure",
             )
         decode.steps = self._mixed_window_decode_steps(decode.seqs, k_eff)
         return StepPlan(
             decode=decode, chunk_schedule=schedule, decode_window=k_eff,
         )
-
-    def _recap_steps_k1(self, decode: DecodePlan) -> None:
-        """Re-budget a declined mixed window's decode rows for a K=1
-        emission.  The K=1 budget is NOT always 1: with the legacy
-        host-side speculative path active, ``_step_budget(seq, 1)`` is
-        ngram+1 — which can exceed the k_cap-iteration block allocation
-        ``_mixed_window_decode_plan`` made (a deep-queue clamp can push
-        k_cap below the draft budget), and the speculative dispatch
-        indexes the block table for its whole budget.  Top the
-        allocation up; under pool pressure trim the budget to the
-        blocks held instead (the drafter derives its draft count from
-        the budget, so a trimmed row just drafts less — greedy output
-        is unchanged, acceptance merely caps earlier)."""
-        bs = self.block_pool.block_size
-        steps = []
-        for seq in decode.seqs:
-            k = self._step_budget(seq, 1)
-            slots = seq.num_tokens + k - 1
-            need = max(0, -(-slots // bs) - len(seq.block_table))
-            if need:
-                if self.block_pool.can_allocate(need):
-                    seq.block_table.extend(self.block_pool.allocate(need))
-                else:
-                    k = max(
-                        1,
-                        len(seq.block_table) * bs - seq.num_tokens + 1,
-                    )
-            steps.append(k)
-        decode.steps = steps
 
     def _mixed_window_decode_plan(self, k_cap: int) -> Optional[DecodePlan]:
         """Decode rows for a mixed K-step window, blocks pre-allocated
@@ -951,8 +859,8 @@ class Scheduler:
         return window
 
     def _step_budget(self, seq: Sequence, window: int = 1) -> int:
-        """Decode TOKENS this sequence may emit in one window (or
-        speculative) plan: bounded by max_model_len and the request's
+        """Decode TOKENS this sequence may emit in one plan (1 at K=1,
+        drafters included): bounded by max_model_len and the request's
         max_tokens (stop/EOS cut shorter — the device stop-mask freezes
         the row; a mismatching host-only condition discards on readback).
         Under the fused speculative window a K-iteration plan can land
@@ -960,12 +868,7 @@ class Scheduler:
         and the block pre-allocation derived from it — covers the
         max-acceptance growth (_window_token_cap), never just the
         iteration count."""
-        if window > 1:
-            n = self._window_token_cap(window)
-        else:
-            # Legacy host-side speculation (and K=1 passes with
-            # speculation on): K drafts + the bonus token per dispatch.
-            n = max(1, self.config.speculative_ngram + 1)
+        n = self._window_token_cap(window)
         room_len = self.config.max_model_len - seq.num_tokens
         room_out = seq.sampling_params.max_tokens - seq.num_generated
         return max(1, min(n, room_len, room_out))
@@ -1033,13 +936,9 @@ class Scheduler:
             # while the newcomers PARK for one window (their first
             # token is already finalized at the in-flight window's
             # collect; they join the batch at the next synchronous
-            # rebuild).  Only the packed planner creates this shape,
-            # and only when MORE packing work is waiting — otherwise
-            # break the pipeline so the parked rows join immediately
-            # (which also keeps the single-head seeded key-ordinal
-            # stream bit-identical to the K=1 path).
-            if not self.config.multi_prompt_window_enabled:
-                return None
+            # rebuild) — only when MORE packing work is waiting;
+            # otherwise break the pipeline so the parked rows join
+            # immediately.
             if any(
                 seq.num_generated > 0
                 for seq in self.running[len(inflight_seqs):]
@@ -1052,7 +951,7 @@ class Scheduler:
             if plan is not None:
                 return plan
             if parked or not (
-                self.config.multi_prompt_window_enabled
+                self.config.mixed_window_enabled
                 and len(self.running) >= self.config.max_num_seqs
             ):
                 return None
@@ -1066,7 +965,7 @@ class Scheduler:
                 # waiting prompt could pack.  Break the pipeline; the
                 # synchronous replan sees the freed slot.
                 return None
-            # Packed mode with a slot-full batch: no admission is
+            # Mixed windows on and a slot-full batch: no admission is
             # possible at this boundary no matter how it replans, so
             # chain a full pure-decode window off the carry instead of
             # breaking the pipeline into K=1 waiting_head steps
@@ -1134,27 +1033,19 @@ class Scheduler:
         buckets = self._chunk_buckets_in_budget(budget)
         if not buckets:
             return None
-        packed = cfg.multi_prompt_window_enabled
-        if packed:
-            k_cap = cfg.window_steps
-        else:
-            k_cap = min(
-                cfg.window_steps, cfg.mixed_window_clamp(self.num_waiting)
-            )
+        k_cap = cfg.window_steps
         # Single-chunk heads decline (pipeline break -> the sync K=1
         # mixed step admits them whole): a 1-iteration scan would mint
         # a whole executable variant for zero amortization.  A prefix
         # hit discovered at chunk planning can still shrink a
         # multi-chunk head to one final chunk — that rare case emits
         # the 1-iteration window below rather than rolling back
-        # committed plan state.  Packed windows keep chaining when
+        # committed plan state.  The window keeps chaining when
         # OTHER waiters could fill the remaining iterations.
         remaining_max = head.num_prompt_tokens - (
             head.num_cached_tokens if head.partial_prefill else 0
         )
-        if remaining_max <= buckets[-1] and (
-            not packed or self.num_waiting <= 1
-        ):
+        if remaining_max <= buckets[-1] and self.num_waiting <= 1:
             return None
         bs = self.block_pool.block_size
         bases = [
@@ -1185,12 +1076,7 @@ class Scheduler:
             # decode blocks above stay in the block tables and back the
             # replanned step.
             return None
-        if first.is_final and not packed:
-            schedule = [first]
-        else:
-            schedule = self._extend_chunk_schedule(
-                head, first, buckets, k_cap, budget
-            )
+        schedule = self._extend_chunk_schedule(first, k_cap, budget)
         k_eff = len(schedule)
         return StepPlan(
             decode=DecodePlan(

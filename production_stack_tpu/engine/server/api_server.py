@@ -2231,8 +2231,8 @@ def main(argv=None) -> None:
         "active (the default) the drafter runs INSIDE the window scan — "
         "drafts proposed on-device, acceptance folded into the carried "
         "state, a rejected draft costs a scan iteration, never a host "
-        "round-trip.  Greedy-only; with --no-multi-step-window the "
-        "legacy host-side speculative path runs instead",
+        "round-trip.  Greedy-only; refused with --no-multi-step-window "
+        "(speculation runs inside the window)",
     )
     parser.add_argument(
         "--speculative-model",
@@ -2246,8 +2246,8 @@ def main(argv=None) -> None:
         "(dedicated draft pool; target KV capacity untouched), and the "
         "target verifies draft+1 rows in the same wide forward the "
         "n-gram drafter uses.  Mutually exclusive with "
-        "--speculative-ngram; requires the window machinery (no legacy "
-        "host path).  Unlike n-gram lookup, acceptance holds up on "
+        "--speculative-ngram; requires the window machinery.  Unlike "
+        "n-gram lookup, acceptance holds up on "
         "non-templated text",
     )
     parser.add_argument(
@@ -2276,24 +2276,14 @@ def main(argv=None) -> None:
         "non-speculative behavior exactly)",
     )
     parser.add_argument(
-        "--num-scheduler-steps",
-        type=int,
-        default=1,
-        help="legacy spelling of the K-step decode window (vLLM "
-        "--num-scheduler-steps): a value > 1 forces window size K "
-        "through the same device-resident machinery --decode-window "
-        "sizes; 1 defers to --decode-window",
-    )
-    parser.add_argument(
         "--no-multi-step-window",
         action="store_true",
         help="disable K-step device-resident decode windows (the default "
         "decode fast path: K decode+sample iterations per device "
         "dispatch with on-device penalties, the min_tokens EOS floor "
         "and per-row stop masking) and restore single-token stepping "
-        "exactly — A/B baseline / debugging.  With --speculative-ngram "
-        "this is the compat escape hatch selecting the legacy host-side "
-        "speculative path",
+        "exactly — A/B baseline / debugging.  Refused with either "
+        "drafter (--speculative-ngram, --speculative-model)",
     )
     parser.add_argument(
         "--decode-window",
@@ -2310,18 +2300,15 @@ def main(argv=None) -> None:
         help="disable the async lookahead decode pipeline (dispatch "
         "decode step or K-step window N+1 while N's tokens are in "
         "flight; greedy streams are identical, decode_host_gap_ms shows "
-        "the recovered host serialization).  Auto-disabled only by the "
-        "legacy host-side speculative path (--speculative-ngram with "
-        "--no-multi-step-window)",
+        "the recovered host serialization)",
     )
     parser.add_argument(
         "--no-mixed-batch",
         action="store_true",
         help="disable fused mixed prefill+decode steps (arriving prompts "
         "then stall all decoders for a full prefill bucket per step — "
-        "the pre-mixed alternating scheduler).  Auto-disabled by the "
-        "legacy host-side speculative path (--speculative-ngram with "
-        "--no-multi-step-window) and dp/sp meshes",
+        "the pre-mixed alternating scheduler).  Auto-disabled by dp/sp "
+        "meshes",
     )
     parser.add_argument(
         "--no-mixed-window",
@@ -2331,14 +2318,6 @@ def main(argv=None) -> None:
         "K=1 mixed scheduling exactly: a waiting head forces "
         "single-token steps, counted under tpu:multistep_fallback_total"
         '{reason="waiting_head"} — A/B baseline / debugging',
-    )
-    parser.add_argument(
-        "--no-multi-prompt-window",
-        action="store_true",
-        help="disable multi-prompt packing inside mixed K-step windows "
-        "and restore the single-head window planner exactly (one "
-        "waiting prompt's chunks per window, adaptive K-halving clamp "
-        "under deep queues) — A/B baseline / debugging",
     )
     parser.add_argument(
         "--max-num-batched-tokens",
@@ -2520,7 +2499,6 @@ def main(argv=None) -> None:
                 if args.prefill_buckets
                 else {}
             ),
-            "scheduler.num_scheduler_steps": args.num_scheduler_steps,
             "scheduler.speculative_ngram": args.speculative_ngram,
             **(
                 {
@@ -2556,10 +2534,6 @@ def main(argv=None) -> None:
             **(
                 {"scheduler.mixed_window": False}
                 if args.no_mixed_window else {}
-            ),
-            **(
-                {"scheduler.multi_prompt_window": False}
-                if args.no_multi_prompt_window else {}
             ),
             **(
                 {"scheduler.max_num_batched_tokens": args.max_num_batched_tokens}

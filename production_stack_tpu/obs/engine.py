@@ -47,7 +47,7 @@ logger = logging.getLogger(__name__)
 # schedule covers every step; dispatch/collect/sample are the PIPELINED
 # decode split (the steady-state hot path).  Synchronous steps (prefill,
 # host-state fallbacks) are cut into the finer PHASES spans below but stay
-# out of these families, whose sums the dashboard and bench.py read per
+# out of these families, whose sums the dashboard reads per
 # pipelined step.  Mixed steps are synchronous by design and get their own
 # family instead.
 STEP_PHASES = ("schedule", "dispatch", "collect", "sample", "mixed")

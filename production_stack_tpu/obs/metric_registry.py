@@ -208,8 +208,7 @@ REGISTRY = {
                 "features (reason: logprobs | logit_bias | guided) or "
                 "because a waiting prompt forced K=1 admission cadence "
                 "and the mixed K-step window could not serve it — split "
-                "by WHY the mixed window declined (reason: bucket_mismatch "
-                "— the head chunk fit no static chunk bucket; "
+                "by WHY the mixed window declined (reason: "
                 "pool_pressure — the KV pool could not hold the chunk; "
                 "waiting_head — residual decline, e.g. mixed windows off "
                 "or an unpackable final chunk; draft_pool — the draft "

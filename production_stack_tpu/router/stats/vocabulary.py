@@ -184,15 +184,15 @@ TPU_MULTISTEP_FALLBACK = "tpu:multistep_fallback_total"
 # The closed reason set, pre-seeded as zero-valued series so scrapers,
 # dashboards, and rate() see stable label sets from boot.  The mixed-
 # window decline reasons are split so the flight recorder (and this
-# family) can say WHY a waiting prompt forced K=1: bucket_mismatch — the
-# head chunk fit no static chunk bucket; pool_pressure — the KV pool had
-# no room for the chunk's blocks; waiting_head — the residual decline
+# family) can say WHY a waiting prompt forced K=1: pool_pressure — the
+# KV pool had no room for the chunk's blocks; waiting_head — the residual
+# decline
 # (mixed windows disabled, or an unpackable final chunk); draft_pool —
 # the draft model's dedicated KV pool could not cover the batch, so the
 # window ran plain (non-speculative) instead.
 TPU_MULTISTEP_FALLBACK_REASONS = (
     "guided", "logit_bias", "logprobs", "waiting_head",
-    "bucket_mismatch", "pool_pressure", "draft_pool",
+    "pool_pressure", "draft_pool",
 )
 TPU_MULTISTEP_WASTED_TOKENS = "tpu:multistep_wasted_tokens_total"
 # The flash prefill kernel's kv tiles (ops/pallas/flash_prefill.py), per
@@ -276,11 +276,11 @@ TPU_STEP_STALL = "tpu:step_stall_total"
 # round-trip.  Its ratio to tpu:prefill_chunk_tokens is the window
 # coverage of sustained-arrival prefill traffic.
 TPU_MIXED_WINDOW_CHUNK_TOKENS = "tpu:mixed_window_chunk_tokens_total"
-# Packed multi-prompt windows (scheduler multi_prompt_window): distinct
+# Packed multi-prompt windows (scheduler mixed_window): distinct
 # prompts whose chunks rode EACH mixed K-step window, as a histogram —
 # the packing depth.  A mass at bucket 1 under queue depth means the
-# packed path is not engaging (flag off, or per-window admission
-# declining); mass in the >1 buckets is queue depth being converted
+# packed path is not engaging (mixed windows off, or per-window
+# admission declining); mass in the >1 buckets is queue depth being converted
 # into device utilization.
 TPU_MIXED_WINDOW_PROMPTS = "tpu:mixed_window_prompts_per_window"
 # Batched encode lane (scheduler encode_lane; docs/engine.md "The encode

@@ -33,10 +33,13 @@ Fault injection rides :meth:`FakeEngineState.inject`: ``kill`` (connect
 refusal), ``stall`` (stream hangs mid-token), ``flap_429`` (a 429 storm
 from one backend), all revertible mid-replay.
 
-Determinism: arrivals, prompts and injection schedules derive from one
-``random.Random(seed)``; wall-clock enters only through the replay
-clock itself, so aggregate assertions (goodput ratio, shed ordering,
-zero drops) are stable in CI.
+Determinism: arrivals (how many, when due, which lane), prompts and
+injection schedules derive from one ``random.Random(seed)``, drawn
+before the replay clock starts.  The wall clock enters through service
+times and through what the router has learned of them, so what a test
+may hold exactly are counts and orderings (the arrivals, every request
+accounted for, zero drops, who shed first); a rate or a tail is the
+machine's as much as the router's.
 """
 
 from __future__ import annotations
@@ -511,45 +514,46 @@ class FleetHarness:
         about; ``embed_repeat_pool`` makes the embed side repeat-heavy
         (semantic-cache fodder)."""
         events = sorted(events or [], key=lambda e: e[0])
+        # The whole arrival schedule is drawn before the clock starts, so
+        # it is the seed's and nothing else's: a loop that wakes late (a
+        # loaded machine) launches what is overdue and catches up, where
+        # drawing each gap from the moment the loop noticed the last
+        # arrival let the machine's load thin the offered traffic.
+        arrivals: List[Tuple[float, str, Optional[int]]] = []
+        rate = self.qps_at(0.0, duration_s, base_qps, peak_qps)
+        t = self.rng.expovariate(rate) if rate > 0 else duration_s
+        while t < duration_s:
+            lane, priority = "chat", None
+            if embed_frac and self.rng.random() < embed_frac:
+                lane = "embed"
+            elif low_priority_frac and self.rng.random() < low_priority_frac:
+                priority = 1
+            arrivals.append((t, lane, priority))
+            rate = self.qps_at(t, duration_s, base_qps, peak_qps)
+            t += self.rng.expovariate(rate) if rate > 0 else duration_s
         tasks: List[asyncio.Task] = []
         t_start = self.now()
-        next_event = 0
-
-        def rel() -> float:
-            return self.now() - t_start
-
-        first_rate = self.qps_at(0.0, duration_s, base_qps, peak_qps)
-        t_next_arrival = (
-            self.rng.expovariate(first_rate) if first_rate > 0 else duration_s
-        )
+        next_event = next_arrival = 0
         while True:
-            t = rel()
+            t = self.now() - t_start
             if t >= duration_s:
                 break
             while next_event < len(events) and events[next_event][0] <= t:
                 await events[next_event][1]()
                 next_event += 1
-            if t >= t_next_arrival:
-                if embed_frac and self.rng.random() < embed_frac:
+            while next_arrival < len(arrivals) and arrivals[next_arrival][0] <= t:
+                _, lane, priority = arrivals[next_arrival]
+                next_arrival += 1
+                if lane == "embed":
                     coro = self.one_embed_request(
                         phase=phase, repeat_pool=embed_repeat_pool
                     )
                 else:
-                    priority = (
-                        1
-                        if low_priority_frac
-                        and self.rng.random() < low_priority_frac
-                        else None
-                    )
                     coro = self.one_request(phase=phase, priority=priority)
                 tasks.append(asyncio.ensure_future(coro))
-                rate = self.qps_at(t, duration_s, base_qps, peak_qps)
-                t_next_arrival = t + (
-                    self.rng.expovariate(rate) if rate > 0 else duration_s
-                )
-                continue
             wake = min(
-                t_next_arrival,
+                arrivals[next_arrival][0] if next_arrival < len(arrivals)
+                else duration_s,
                 duration_s,
                 events[next_event][0] if next_event < len(events) else duration_s,
             )

@@ -34,7 +34,7 @@ import dataclasses
 import importlib.util
 import json
 from pathlib import Path
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Tuple
 
 from production_stack_tpu.testing.fake_engine import fake_prefix_chain
 from production_stack_tpu.testing.fleet import FleetHarness
@@ -59,7 +59,7 @@ ROUTING_LADDER: Dict[str, Tuple[str, Tuple[str, ...]]] = {
 
 def load_multi_round_module():
     """Import benchmarks/multi_round_qa/multi_round_qa.py (not a package)
-    by file path — shared by the tier-1 test and bench.py."""
+    by file path."""
     import sys
 
     existing = sys.modules.get("multi_round_qa")
@@ -122,14 +122,6 @@ class MultiRoundFleetConfig:
     # hashes backend URLs, so ephemeral ports would re-roll session's
     # user placement every run and the seeded A/B would not be an A/B.
     base_port: int = 19360
-    # Shared KV store across the fleet (the PR-4 plane, simulated):
-    # computed chunks export; store-resident chunks import at ~4x the
-    # prefill rate and count as cache hits (the prefetch plane lands
-    # imports in the prefix cache before schedule).  OFF for the ladder
-    # A/B — a fleet-wide store makes every policy's misses into imports
-    # and the hit-rate axis stops discriminating routing; the bench adds
-    # a dedicated popularity+store rung to show the warming win.
-    shared_store: bool = False
     request_timeout: float = 30.0
 
 
@@ -154,7 +146,6 @@ def shared_prefix_digests(mod, config, chunk_chars: int) -> List[str]:
 async def run_fleet_multi_round(
     policy: str,
     cfg: Optional[MultiRoundFleetConfig] = None,
-    router_args: Sequence[str] = (),
 ) -> Dict[str, object]:
     """One ladder rung: FleetHarness fleet + the multi-round-QA workload,
     measured on fleet KV hit rate / TTFT percentiles / output tok/s /
@@ -168,9 +159,6 @@ async def run_fleet_multi_round(
         "prefill_chars_per_sec": cfg.prefill_chars_per_sec,
         "prefill_scales_with_load": True,
     }
-    if cfg.shared_store:
-        engine_kwargs["shared_store"] = set()   # ONE set for the fleet
-        engine_kwargs["remote_store_import"] = True
 
     h = FleetHarness(
         num_engines=cfg.num_engines,
@@ -182,9 +170,9 @@ async def run_fleet_multi_round(
         max_tokens=cfg.answer_len,
         routing_logic=routing_logic,
         # Fleet admission stays out of the ladder comparison: the A/B
-        # isolates ROUTING; admission on/off is fleet_surge_ab's axis.
+        # isolates ROUTING.
         fleet_admission=False,
-        router_args=tuple(policy_args) + tuple(router_args),
+        router_args=tuple(policy_args),
         engine_kwargs=engine_kwargs,
         base_port=cfg.base_port,
     )
@@ -234,12 +222,6 @@ async def run_fleet_multi_round(
             "ttft_p95_ms": round(pct(95) * 1e3, 1),
             "output_tok_s": summary["output_tokens_per_s"],
             "shared_prefix_backends": resident,
-            # Raw samples + token totals so callers can POOL repeated
-            # runs into one percentile estimate (bench.py runs each arm
-            # twice — pooled p50 halves the CI loop-noise variance).
-            "ttft_samples": [round(t, 5) for t in ttfts],
-            "hit_tokens": int(hit),
-            "query_tokens": int(query),
         }
         router_obj = h.registry.get("routing_logic")
         if hasattr(router_obj, "popularity_snapshot"):

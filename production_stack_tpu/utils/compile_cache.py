@@ -2,7 +2,7 @@
 
 A cold 32-layer step program takes a quarter of a minute to a minute and a
 half to compile, and every entry point that reaches the chip (the engine
-server, ``bench.py``, ``chip_smoke.py`` and the children they start) compiles
+server, ``chip_smoke.py`` and the children they start) compiles
 the same programs.  They all call :func:`enable_compile_cache` first thing, so
 a second process, or a second run on the same machine, loads what the first
 one compiled.

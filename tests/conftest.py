@@ -19,7 +19,7 @@ import os
 import sys
 
 # Tests run on the CPU, on eight virtual devices, whatever the machine
-# holds; chip_smoke.py and bench.py are the entries that need the chip.
+# holds; chip_smoke.py and bench/run.py are the entries that need the chip.
 os.environ["JAX_PLATFORMS"] = "cpu"
 _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:

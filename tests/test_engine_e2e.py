@@ -4,6 +4,7 @@ the OpenAI server surface.
 """
 
 
+import pytest
 from aiohttp.test_utils import TestClient, TestServer
 
 from production_stack_tpu.engine.config import (
@@ -222,3 +223,48 @@ async def test_api_server_end_to_end():
         assert "tpu:total_generated_tokens" in text
     finally:
         await client.close()
+
+
+async def test_overlong_prompt_rejected_with_400():
+    """An over-max_model_len prompt must 400 cleanly, not truncate an SSE
+    stream mid-flight (ClientPayloadError at the client)."""
+    from production_stack_tpu.engine.config import config_from_preset
+    from production_stack_tpu.engine.server.api_server import build_engine_app
+    from production_stack_tpu.engine.server.async_engine import AsyncEngine
+
+    config = config_from_preset(
+        "tiny-llama",
+        **{"scheduler.max_num_seqs": 2, "scheduler.max_model_len": 128,
+           "cache.num_blocks": 64},
+    )
+    engine = AsyncEngine(config)
+    client = TestClient(TestServer(build_engine_app(engine, "tiny-llama")))
+    await client.start_server()
+    try:
+        resp = await client.post(
+            "/v1/chat/completions",
+            json={
+                "model": "tiny-llama",
+                "messages": [{"role": "user", "content": "word " * 400}],
+                "stream": True,
+                "max_tokens": 4,
+            },
+        )
+        assert resp.status == 400
+        payload = await resp.json()
+        assert payload["error"]["code"] == "context_length_exceeded"
+    finally:
+        await client.close()
+
+
+@pytest.mark.parametrize("argv", [
+    ["--num-scheduler-steps", "4"],      # decode_window's old name
+    ["--no-multi-prompt-window"],        # the single-head mixed planner
+])
+def test_removed_engine_flags_are_rejected_by_the_parser(argv, capsys):
+    from production_stack_tpu.engine.server import api_server
+
+    with pytest.raises(SystemExit) as exit_:
+        api_server.main(["--model", "tiny-llama", *argv])
+    assert exit_.value.code == 2
+    assert "unrecognized arguments: " + argv[0] in capsys.readouterr().err

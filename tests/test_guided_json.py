@@ -31,7 +31,7 @@ def make_engine(n_steps=1):
         cache=CacheConfig(block_size=4, num_blocks=96),
         scheduler=SchedulerConfig(
             max_num_seqs=2, prefill_buckets=(16, 32, 64), max_model_len=256,
-            num_scheduler_steps=n_steps,
+            **({"decode_window": n_steps} if n_steps > 1 else {}),
         ),
     ))
 

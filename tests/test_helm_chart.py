@@ -147,16 +147,16 @@ def test_chat_template_configmap_and_mount():
     assert volumes["chat-template"]["configMap"]["name"] == \
         "ct-llama3-8b-chat-template"
 
-    # numSchedulerSteps flows through when set.
+    # decodeWindow flows through when set.
     values["servingEngineSpec"]["modelSpec"][0]["engineConfig"][
-        "numSchedulerSteps"] = 8
+        "decodeWindow"] = 8
     objs = load_manifests(render_chart(CHART_DIR, values, release_name="ct"))
     engine = [
         o for o in by_kind(objs, "Deployment")
         if o["metadata"]["name"] == "ct-llama3-8b-deployment-engine"
     ][0]
     cmd = engine["spec"]["template"]["spec"]["containers"][0]["command"]
-    assert cmd[cmd.index("--num-scheduler-steps") + 1] == "8"
+    assert cmd[cmd.index("--decode-window") + 1] == "8"
 
 
 def test_router_rbac_matches_discovery():
@@ -301,6 +301,22 @@ def test_values_match_schema():
         jsonschema.validate(yaml.safe_load(f), schema)
     jsonschema.validate(tpu_values(), schema)
     jsonschema.validate(ci_values(), schema)
+
+
+def test_schema_rejects_the_removed_engine_value():
+    """numSchedulerSteps (decodeWindow's old name) is gone from the chart:
+    the template passes no flag for it, so the schema must refuse it
+    rather than let it be set and silently ignored."""
+    jsonschema = pytest.importorskip("jsonschema")
+    with open(os.path.join(CHART_DIR, "values.schema.json")) as f:
+        schema = json.load(f)
+    values = tpu_values()
+    engine_config = values["servingEngineSpec"]["modelSpec"][0]["engineConfig"]
+    engine_config["decodeWindow"] = 4
+    jsonschema.validate(values, schema)
+    engine_config["numSchedulerSteps"] = 4
+    with pytest.raises(jsonschema.ValidationError, match="numSchedulerSteps"):
+        jsonschema.validate(values, schema)
 
 
 def test_ingress_renders_when_enabled():

@@ -4,9 +4,9 @@ sustained arrivals stop forcing K=1 steps.
 
 The tentpole contract (docs/engine.md, "Unified step plan"): when a
 multi-chunk prompt waits, ``schedule()`` emits a StepPlan with a
-``chunk_schedule`` — K = min(decode_window, chunks needed, adaptive
-queue-depth clamp) scan iterations, each running the packed
-[decode + chunk] mixed forward with the chunk cursor carried in-graph —
+``chunk_schedule`` — up to decode_window scan iterations, each running
+the packed [decode + chunk] mixed forward with the chunk cursor carried
+in-graph, a final chunk handing the next iteration to the next waiter —
 and the window always ENDS at an admission boundary, which is what
 keeps greedy streams byte-identical and seeded streams bit-identical to
 the ``--no-mixed-window`` K=1 escape hatch (iteration t of a window
@@ -105,17 +105,8 @@ def test_mixed_window_default_on_and_gate_off():
         SchedulerConfig(mixed_window=True, mixed_batch=False)
 
 
-def test_adaptive_clamp_halves_per_extra_waiter():
-    cfg = SchedulerConfig(decode_window=8)
-    # The head prompt gets the full window to itself; each EXTRA waiter
-    # halves it — a deep queue degrades to today's K=1 admission cadence.
-    assert [cfg.mixed_window_clamp(n) for n in (0, 1, 2, 3, 4, 20)] == [
-        8, 8, 4, 2, 1, 1,
-    ]
-
-
 def test_escape_hatches_compose():
-    """--no-mixed-window composes with the legacy escape hatches."""
+    """--no-mixed-window composes with the other escape hatches."""
     cfg = SchedulerConfig(mixed_window=False, multi_step_window=False)
     assert cfg.window_steps == 1 and not cfg.mixed_window_enabled
     cfg = SchedulerConfig(mixed_window=False, mixed_batch=False)
@@ -184,28 +175,6 @@ def test_longer_prompt_chunks_across_chained_windows():
     assert not plan.chunk_schedule[-1].is_final
     assert long.partial_prefill
     assert long.num_cached_tokens == 8 * 16
-
-
-def test_deep_queue_clamps_to_k1():
-    """The adaptive clamp (--no-multi-prompt-window single-head path):
-    3 extra waiters -> clamp 1 -> today's K=1 mixed step, counted as a
-    waiting_head fallback (TTFT of the extra waiters never regresses
-    more than one window's worth).  The packed default retires this
-    clamp — test_packed_window_* cover that path."""
-    sched, _ = _scheduler(multi_prompt_window=False)
-    run = Sequence("run", list(RUN_PROMPT), SamplingParams(max_tokens=64))
-    sched.add_seq(run)
-    sched.schedule()
-    run.output_token_ids.append(1)
-    for i in range(4):
-        sched.add_seq(Sequence(
-            f"w{i}", list(LONG_PROMPT), SamplingParams(max_tokens=8)
-        ))
-    plan = sched.schedule()
-    assert plan.chunk_schedule is None
-    assert plan.decode_window == 1
-    assert plan.prefill_chunk is not None  # head still chunks, at K=1
-    assert plan.window_fallback == "waiting_head"
 
 
 def test_single_chunk_head_is_not_a_fallback():
@@ -495,30 +464,24 @@ def test_all_finished_drop_never_discards_a_chunked_window():
 
 
 def test_k1_fallback_respects_spec_budget_block_invariant():
-    """A declined mixed window re-emitted at K=1 must leave every
-    decode row's block table covering its K=1 budget — which under the
-    legacy host-side speculative path is ngram+1 tokens, MORE than the
-    clamp-bounded window allocation (the speculative dispatch indexes
-    the table for its whole budget; a short table is a step-thread
-    crash).  Single-head path: the packed default would extend past
-    the bucket-mismatched final chunk (forced-bucket ride-along)
-    instead of falling back."""
+    """A waiting head served at K=1 on a speculative engine: the drafter
+    engages only inside a window, so every decode row's K=1 budget is one
+    token — the plain decode step's — and its block table covers it (a
+    short table is a step-thread crash)."""
     pool = BlockPool(num_blocks=256, block_size=4)
     cfg = SchedulerConfig(
         max_num_seqs=8, prefill_buckets=(16, 32, 64),
         prefill_chunk_buckets=(16, 32), max_model_len=512,
         decode_window=8, speculative_ngram=3, pipeline_decode=False,
-        multi_prompt_window=False,
+        mixed_window=False,
     )
     sched = Scheduler(cfg, pool)
     run = Sequence("run", list(RUN_PROMPT), SamplingParams(max_tokens=64))
     sched.add_seq(run)
     sched.schedule()
     run.output_token_ids.append(1)
-    # Head: 40 tokens -> chunk1 at bucket 32 (non-final), remaining 8
-    # fits bucket 16 != 32 -> bucket-mismatched final -> k_eff == 1
-    # fallback.  Two extra waiters clamp k_cap to 2 < the speculative
-    # K=1 budget of ngram+1 = 4.
+    # Head: 40 tokens -> chunk 1 at bucket 32 (non-final): the pass is a
+    # K=1 mixed step, counted as the forfeit it is.
     sched.add_seq(Sequence("head", list(range(40)),
                            SamplingParams(max_tokens=8)))
     for i in range(2):
@@ -527,40 +490,60 @@ def test_k1_fallback_respects_spec_budget_block_invariant():
     plan = sched.schedule()
     assert plan.decode_window == 1 and plan.chunk_schedule is None
     assert plan.prefill_chunk is not None
+    assert plan.window_fallback == "waiting_head"
     bs = pool.block_size
     for seq, k in zip(plan.decode.seqs, plan.decode.steps):
-        assert k >= 1
-        slots = seq.num_tokens + k - 1
-        assert len(seq.block_table) * bs >= slots, (
+        assert k == 1
+        assert len(seq.block_table) * bs >= seq.num_tokens, (
             f"{seq.seq_id}: budget {k} not block-backed"
         )
-        # The speculative budget survived (blocks were topped up, not
-        # the budget trimmed — the pool has room).
-        assert k == 4
 
 
-# -- packed multi-prompt windows (SchedulerConfig.multi_prompt_window) ------
+# -- packed multi-prompt windows --------------------------------------------
 
 
-def test_multi_prompt_window_default_on_and_gate():
-    cfg = SchedulerConfig()
-    assert cfg.multi_prompt_window_enabled
-    assert not SchedulerConfig(
-        multi_prompt_window=False).multi_prompt_window_enabled
-    # Packing rides the window machinery: no mixed windows, no packing.
-    assert not SchedulerConfig(
-        mixed_window=False).multi_prompt_window_enabled
-    assert not SchedulerConfig(
-        multi_step_window=False).multi_prompt_window_enabled
-    # A directly contradictory explicit combo refuses loudly.
-    with pytest.raises(ValueError, match="multi_prompt_window"):
-        SchedulerConfig(multi_prompt_window=True, mixed_window=False)
+@pytest.mark.parametrize("gate, packs", [
+    ({}, True),
+    ({"mixed_window": False}, False),
+    # What the benchmark's cells run (--no-mixed-batch): a slot-full batch
+    # with a prompt waiting drops to K=1, as it did before the single-head
+    # planner went.  Un-gating the exception is a scheduling change with a
+    # claim of its own (ROADMAP S6).
+    ({"mixed_batch": False}, False),
+    ({"multi_step_window": False}, False),
+])
+def test_multi_prompt_window_default_on_and_gate(gate, packs):
+    """Packing is what a mixed window does: it follows
+    mixed_window_enabled, and so does the packed-window exception — a
+    slot-full batch runs a pure-decode window past a waiting prompt (no
+    admission fits either way), clamped to the first step a slot could
+    free."""
+    assert SchedulerConfig(**gate).mixed_window_enabled == packs
+    sched, _ = _scheduler(max_num_seqs=2, **gate)
+    for i, budget in enumerate((64, 5)):
+        seq = Sequence(f"run{i}", list(RUN_PROMPT),
+                       SamplingParams(max_tokens=budget))
+        sched.add_seq(seq)
+        while sched.num_waiting:  # the second admits as chunks, K=1
+            sched.schedule()
+        seq.output_token_ids.append(1)
+    sched.add_seq(Sequence("wait", list(LONG_PROMPT),
+                           SamplingParams(max_tokens=8)))
+    plan = sched.schedule()
+    assert plan.prefill_chunk is None and plan.chunk_schedule is None
+    assert [s.seq_id for s in plan.decode.seqs] == ["run0", "run1"]
+    if packs:
+        # min(window 8, run1's 4 tokens left): the boundary is where a
+        # slot frees and packing becomes possible again.
+        assert plan.decode_window == 4 and plan.window_fallback is None
+    else:
+        assert plan.decode_window == 1
 
 
 def test_packed_window_plans_multiple_prompts():
     """Three 2-chunk waiters pack back-to-back into ONE window: each
     final chunk admits its prompt mid-schedule and the next iteration
-    starts the next waiter's cursor — no K-halving clamp, no
+    starts the next waiter's cursor — no clamp by queue depth, no
     waiting_head fallback."""
     sched, _ = _scheduler()
     run = Sequence("run", list(RUN_PROMPT), SamplingParams(max_tokens=64))
@@ -614,84 +597,6 @@ def test_packed_window_forces_first_chunk_bucket():
     assert head_chunks[-1].is_final
     # The next waiter's chunks ride the same window at the same bucket.
     assert any(cp.seq.seq_id == "next" for cp in plan.chunk_schedule)
-
-
-def test_no_multi_prompt_window_restores_single_head_plans():
-    """--no-multi-prompt-window is an exact single-head restore: with
-    ONE waiter the packed and unpacked planners emit identical plans
-    pass-by-pass (packing is a no-op at P=1); with a deep queue the
-    unpacked planner clamps and never packs a second prompt."""
-
-    def fingerprint(plan):
-        fp = {
-            "window": plan.decode_window,
-            "fallback": plan.window_fallback,
-        }
-        if plan.decode is not None:
-            fp["decode"] = (
-                [s.seq_id for s in plan.decode.seqs],
-                list(plan.decode.steps),
-            )
-        if plan.prefill_chunk is not None:
-            cp = plan.prefill_chunk
-            fp["chunk"] = (cp.seq.seq_id, cp.bucket_len, cp.cached_len,
-                           cp.num_new_tokens, cp.is_final)
-        if plan.chunk_schedule is not None:
-            fp["sched"] = [
-                (cp.seq.seq_id, cp.bucket_len, cp.cached_len,
-                 cp.num_new_tokens, cp.is_final)
-                for cp in plan.chunk_schedule
-            ]
-        return fp
-
-    def script(sched):
-        run = Sequence("run", list(RUN_PROMPT),
-                       SamplingParams(max_tokens=64))
-        sched.add_seq(run)
-        plans = [sched.schedule()]
-        run.output_token_ids.append(1)
-        sched.add_seq(Sequence("wait", list(LONG_PROMPT),
-                               SamplingParams(max_tokens=8)))
-        for _ in range(4):
-            plan = sched.schedule()
-            plans.append(plan)
-            if plan.decode is not None:
-                for seq, k in zip(plan.decode.seqs, plan.decode.steps):
-                    seq.output_token_ids.extend([1] * max(k, 1))
-            for seq in sched.running:  # simulate first-token finalize
-                if not seq.output_token_ids:
-                    seq.output_token_ids.append(1)
-        return [fingerprint(p) for p in plans]
-
-    packed = script(_scheduler()[0])
-    unpacked = script(_scheduler(multi_prompt_window=False)[0])
-    assert packed == unpacked
-    # Deep queue: the unpacked planner clamps (never >1 distinct prompt
-    # per window) while the packed planner packs several.
-    for kw, expect_packed in ((dict(), True),
-                              (dict(multi_prompt_window=False), False)):
-        sched, _ = _scheduler(**kw)
-        run = Sequence("run", list(RUN_PROMPT),
-                       SamplingParams(max_tokens=64))
-        sched.add_seq(run)
-        sched.schedule()
-        run.output_token_ids.append(1)
-        for i in range(3):
-            sched.add_seq(Sequence(
-                f"w{i}", [(3 * j + i) % 97 for j in range(32)],
-                SamplingParams(max_tokens=8),
-            ))
-        plan = sched.schedule()
-        if expect_packed:
-            assert plan.chunk_schedule is not None
-            distinct = {cp.seq.seq_id for cp in plan.chunk_schedule}
-            assert len(distinct) > 1
-        else:
-            distinct = {
-                cp.seq.seq_id for cp in (plan.chunk_schedule or [])
-            } | ({plan.prefill_chunk.seq.seq_id}
-                 if plan.prefill_chunk is not None else set())
-            assert len(distinct) <= 1
 
 
 def test_packed_planning_budget_is_o1_in_queue_depth():

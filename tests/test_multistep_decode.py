@@ -1,7 +1,7 @@
-"""Multi-step decode scheduling (the legacy num_scheduler_steps spelling
-of the K-step decode window).
+"""Multi-step decode scheduling (the K-step decode window's basic
+contract).
 
-vLLM's --num-scheduler-steps analogue: N decode iterations run as ONE
+N decode iterations run as ONE
 device dispatch (lax.scan with on-device sampling), so greedy outputs must
 be bit-identical to classic single-token stepping, stop conditions must
 truncate (now via the device stop-mask), and block allocation must cover
@@ -31,7 +31,7 @@ def make_engine(n_steps: int, **sched_kw):
     # windows decode (multi_step_window auto-on), so the reference must
     # disable it explicitly.
     if n_steps > 1:
-        sched["num_scheduler_steps"] = n_steps
+        sched["decode_window"] = n_steps
     else:
         sched["multi_step_window"] = False
     sched.update(sched_kw)
@@ -141,23 +141,6 @@ def test_multi_step_matches_under_continuous_batching():
         return outs
 
     assert run(1) == run(4)
-
-
-def test_legacy_spelling_composes_with_speculation():
-    """num_scheduler_steps > 1 + speculative_ngram (formerly mutually
-    exclusive) now routes speculation through the same fused window
-    machinery — greedy parity with single-token stepping holds."""
-    reqs = [
-        ("a", "the cat sat on the mat the cat sat", SamplingParams(
-            max_tokens=21)),
-        ("b", "pack my box with", SamplingParams(max_tokens=13)),
-    ]
-    ref, ref_fin = drain(make_engine(1), reqs)
-    engine = make_engine(4, speculative_ngram=3)
-    assert engine._spec_window_fn is not None
-    got, got_fin = drain(engine, reqs)
-    assert got == ref
-    assert got_fin == ref_fin
 
 
 def test_prefix_cache_not_polluted_by_overrun():

@@ -10,8 +10,6 @@ chained off window N's in-flight carry through the lookahead pipeline.
 Greedy output must be byte-identical and seeded-sampling output
 bit-identical to single-token stepping (``multi_step_window=False``),
 including penalty / min_tokens batches that used to force a fallback.
-The legacy ``num_scheduler_steps`` spelling is covered in
-tests/test_multistep_decode.py.
 """
 
 import pytest
@@ -81,8 +79,6 @@ def test_window_default_on_and_gate_off():
     assert SchedulerConfig(decode_window=4).window_steps == 4
     assert SchedulerConfig(multi_step_window=False).window_steps == 1
     with pytest.raises(ValueError):
-        SchedulerConfig(num_scheduler_steps=4, multi_step_window=False)
-    with pytest.raises(ValueError):
         SchedulerConfig(decode_window=0)
 
 
@@ -98,29 +94,45 @@ def test_speculation_composes_with_window():
     # Explicit window + speculation is a valid (formerly rejected) combo.
     cfg = SchedulerConfig(multi_step_window=True, speculative_ngram=3)
     assert cfg.spec_window_enabled
-    # The legacy num_scheduler_steps spelling composes the same way.
-    cfg = SchedulerConfig(num_scheduler_steps=4, speculative_ngram=4)
+    cfg = SchedulerConfig(decode_window=4, speculative_ngram=4)
     assert cfg.window_steps == 4 and cfg.spec_window_enabled
     assert cfg.window_max_tokens == 4 * 5
 
 
-def test_legacy_spec_escape_hatch_resolution():
-    """--no-multi-step-window + speculative_ngram restores the legacy
-    host-side speculative path: window off, pipeline and mixed steps
-    auto-off (its wide verify dispatch is synchronous), and the explicit
-    conflicting gates still refuse."""
-    cfg = SchedulerConfig(speculative_ngram=3, multi_step_window=False)
-    assert cfg.window_steps == 1
-    assert not cfg.spec_window_enabled
-    assert not cfg.pipeline_enabled
-    assert not cfg.mixed_enabled
-    assert cfg.window_max_tokens == 1
-    with pytest.raises(ValueError, match="legacy host-side"):
-        SchedulerConfig(speculative_ngram=3, multi_step_window=False,
-                        pipeline_decode=True)
-    with pytest.raises(ValueError, match="legacy host-side"):
-        SchedulerConfig(speculative_ngram=3, multi_step_window=False,
-                        mixed_batch=True)
+DRAFTERS = {
+    "ngram": {"speculative_ngram": 3},
+    "model": {"speculative_model": "tiny-llama"},
+}
+
+
+@pytest.mark.parametrize("drafter", sorted(DRAFTERS))
+def test_speculation_without_the_window_is_refused(drafter):
+    """One rule for both drafters: speculation runs inside the K-step
+    window, so a drafter with --no-multi-step-window refuses to boot
+    (there is no host-side verify to fall back to), whatever the other
+    gates say."""
+    for extra in ({}, {"pipeline_decode": False}, {"mixed_batch": False}):
+        with pytest.raises(ValueError, match="inside the K-step window"):
+            SchedulerConfig(multi_step_window=False, **DRAFTERS[drafter],
+                            **extra)
+    # The gates no longer look at speculation to resolve "auto".
+    cfg = SchedulerConfig(**DRAFTERS[drafter])
+    assert cfg.spec_window_enabled and cfg.spec_drafter == drafter
+    assert cfg.pipeline_enabled and cfg.mixed_enabled
+    assert SchedulerConfig(multi_step_window=False).pipeline_enabled
+    assert SchedulerConfig(multi_step_window=False).mixed_enabled
+
+
+def test_config_composition():
+    """Speculation composes with the window by fusing into the scan; a
+    drafter left with a one-step window is inert, not refused."""
+    cfg = SchedulerConfig(decode_window=4, speculative_ngram=4)
+    assert cfg.window_steps == 4 and cfg.spec_window_enabled
+    inert = SchedulerConfig(decode_window=1, speculative_ngram=4)
+    assert inert.window_steps == 1 and not inert.spec_window_enabled
+    assert inert.window_max_tokens == 1
+    with pytest.raises(ValueError, match="drop --no-multi-step-window"):
+        SchedulerConfig(speculative_ngram=4, multi_step_window=False)
 
 
 def test_gate_off_restores_single_step_machinery():
@@ -328,6 +340,34 @@ def test_logprobs_request_falls_back_and_counts():
     assert got == ref
 
 
+@pytest.mark.parametrize("drafter", sorted(DRAFTERS))
+def test_speculative_engine_takes_plain_steps_for_a_logprobs_row(drafter):
+    """A host-state row drops a speculative engine's batch to K=1 like
+    any other window batch, and a K=1 step is the plain decode step:
+    nothing drafts, nothing verifies, and the streams are the
+    non-speculative engine's."""
+    reqs = [
+        ("lp", "logprobs request one two one two", SamplingParams(
+            max_tokens=12, logprobs=2)),
+        # Ends first: no dispatch is left without the logprobs row.
+        ("plain", "co-scheduled stream one two one two", SamplingParams(
+            max_tokens=6)),
+    ]
+    eng = make_engine(4, **DRAFTERS[drafter])
+    assert eng._spec_window_fn is not None
+    got, fin = drain(eng, reqs)
+    assert eng.multistep_fallback.get("logprobs", 0) > 0
+    assert eng.spec_tokens_drafted == 0
+    decodes = [w for w in eng.obs.windows_payload()["windows"]
+               if w["kind"] not in ("prefill", "mixed")]
+    assert decodes and all(w["k"] == 1 for w in decodes)
+    for w in decodes:
+        assert "decode_fn" in w["programs"]
+        assert not {"spec_window_fn", "argmax_fn"} & set(w["programs"])
+    ref, ref_fin = drain(make_engine(1), reqs)
+    assert got == ref and fin == ref_fin
+
+
 def test_abort_mid_window_counts_wasted_tokens():
     """Tokens emitted on-device for a sequence aborted while its window
     was in flight are undeliverable — counted, not silently vanished."""
@@ -438,8 +478,8 @@ def test_spec_window_greedy_parity():
 
 def test_spec_window_acceptance_counters_consistent():
     """Repetitive prompts draft on-device; accepted + rejected must
-    equal drafted, acceptance feeds the same tpu:spec_tokens_* family
-    the legacy path uses, and stats() mirrors the outcome split."""
+    equal drafted, acceptance feeds the tpu:spec_tokens_* family, and
+    stats() mirrors the outcome split."""
     eng = make_engine(8, speculative_ngram=3)
     drain(eng, [("a", "one two three one two three one two three",
                  SamplingParams(max_tokens=48, ignore_eos=True))])
@@ -605,3 +645,59 @@ def test_admission_mid_stream_parity():
         return outs
 
     assert run(1) == run(8)
+
+
+async def test_spec_counters_exported_at_metrics():
+    """The drafted/accepted counters surface on the engine's /metrics in
+    the tpu: vocabulary (dashboards derive the acceptance rate)."""
+    import aiohttp
+    from aiohttp.test_utils import TestServer
+
+    from production_stack_tpu.engine.config import config_from_preset
+    from production_stack_tpu.engine.server.api_server import build_engine_app
+    from production_stack_tpu.engine.server.async_engine import AsyncEngine
+
+    config = config_from_preset(
+        "tiny-llama",
+        **{"scheduler.max_num_seqs": 2, "scheduler.max_model_len": 256,
+           "cache.num_blocks": 128, "scheduler.speculative_ngram": 2},
+    )
+    engine = AsyncEngine(config)
+    server = TestServer(build_engine_app(engine, "tiny-llama"))
+    await server.start_server()
+    url = f"http://127.0.0.1:{server.port}"
+    try:
+        async with aiohttp.ClientSession() as session:
+            async with session.post(f"{url}/v1/completions", json={
+                "model": "tiny-llama",
+                "prompt": "one two three one two three one two three",
+                "max_tokens": 12,
+            }) as resp:
+                assert resp.status == 200
+            async with session.get(f"{url}/metrics") as resp:
+                text = await resp.text()
+        assert "tpu:spec_tokens_drafted" in text
+        assert "tpu:spec_tokens_accepted" in text
+        # The fused-window outcome family renders with its closed
+        # outcome x drafter label set from boot (this server runs the
+        # fused path: spec + the default K-step window).
+        for outcome in ("accepted", "rejected", "wasted"):
+            for drafter in ("ngram", "model"):
+                assert (
+                    'tpu:spec_window_tokens_total{outcome="%s",'
+                    'drafter="%s"}' % (outcome, drafter)
+                    in text
+                )
+        assert "tpu:spec_draft_fraction_seconds" in text
+        # Drafting is opportunistic (depends on n-gram hits in the random
+        # model's output); the contract here is exported, parseable,
+        # consistent counters.
+        def read(name):
+            return [float(ln.rsplit(" ", 1)[1]) for ln in text.splitlines()
+                    if ln.startswith(name + " ")]
+        drafted = read("tpu:spec_tokens_drafted")
+        accepted = read("tpu:spec_tokens_accepted")
+        assert drafted and accepted
+        assert 0 <= accepted[0] <= drafted[0] or drafted[0] == 0
+    finally:
+        await server.close()

@@ -1,8 +1,8 @@
 """Pallas paged-decode-attention kernel vs the pure-JAX gather reference.
 
 Runs the kernel in Pallas interpret mode on the CPU test mesh; the same
-compiled path is exercised on real TPU by bench.py and by the engine on TPU
-backends (ops/attention.py:decode_attention dispatch).
+compiled path is exercised on real TPU by tools/paged_decode_microbench.py
+and by the engine on TPU backends (ops/attention.py:decode_attention dispatch).
 """
 
 import jax.numpy as jnp

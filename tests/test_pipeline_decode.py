@@ -210,25 +210,22 @@ def test_preemption_parity_under_pool_pressure():
 
 
 def test_pipeline_composes_with_speculation_and_chains_windows():
-    # Since PR 11 speculation fuses INTO the window scan, and fused
-    # speculative windows chain through the pipeline like any window —
-    # only the LEGACY host-side speculative path (window explicitly off)
-    # still conflicts with an explicit pipeline request.
+    # Speculation fuses INTO the window scan, and fused speculative
+    # windows chain through the pipeline like any window; without the
+    # window a drafter is refused, whatever the pipeline gate says.
     cfg = SchedulerConfig(pipeline_decode=True, speculative_ngram=3)
     assert cfg.pipeline_enabled and cfg.spec_window_enabled
     assert SchedulerConfig(speculative_ngram=3).pipeline_enabled
     with pytest.raises(ValueError):
         SchedulerConfig(pipeline_decode=True, speculative_ngram=3,
                         multi_step_window=False)
-    assert not SchedulerConfig(
-        speculative_ngram=3, multi_step_window=False
-    ).pipeline_enabled
+    assert SchedulerConfig(multi_step_window=False).pipeline_enabled
     # The multi-step<->pipeline mutual exclusion stays lifted: the
     # pipeline chains K-step windows (window N+1 dispatched off window
     # N's in-flight carry), so both auto-resolve on together.
-    cfg = SchedulerConfig(pipeline_decode=True, num_scheduler_steps=4)
+    cfg = SchedulerConfig(pipeline_decode=True, decode_window=4)
     assert cfg.pipeline_enabled and cfg.window_steps == 4
-    assert SchedulerConfig(num_scheduler_steps=4).pipeline_enabled
+    assert SchedulerConfig(decode_window=4).pipeline_enabled
     assert SchedulerConfig().pipeline_enabled
     assert not SchedulerConfig(pipeline_decode=False).pipeline_enabled
 

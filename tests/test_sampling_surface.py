@@ -28,7 +28,7 @@ def make_engine(n_steps=1):
             # now windows decode, so the reference disables it explicitly
             # (same convention as tests/test_multistep_decode.py).
             **(
-                {"num_scheduler_steps": n_steps}
+                {"decode_window": n_steps}
                 if n_steps > 1 else {"multi_step_window": False}
             ),
         ),

@@ -105,10 +105,9 @@ def test_drafter_mutual_exclusion():
 
 
 def test_model_drafter_requires_window_machinery():
-    """The model drafter runs INSIDE the scan and has no legacy
-    host-side path — --no-multi-step-window with it is an error, not a
-    silent degrade."""
-    with pytest.raises(ValueError, match="legacy"):
+    """The model drafter runs INSIDE the scan — --no-multi-step-window
+    with it is an error, not a silent degrade."""
+    with pytest.raises(ValueError, match="inside the K-step window"):
         SchedulerConfig(speculative_model="debug-1l",
                         multi_step_window=False)
     with pytest.raises(ValueError):

@@ -24,7 +24,7 @@ PROGRAMS = (
     "prefill_fn", "decode_fn", "mixed_fn", "sample_fn", "window_fn",
     "spec_window_fn", "mixed_window_fn", "win_unpack_fn", "win_advance_fn",
     "win_occurrence_fn", "pipe_unpack_fn", "pipe_advance_fn",
-    "penalties_fn", "argmax_fn", "logprobs_fn",
+    "penalties_fn", "logprobs_fn",
 )
 
 # Engine set-ups that between them run every dispatch path and program.
@@ -38,8 +38,6 @@ SETUPS = {
                {"r0": {"logprobs": True, "top_logprobs": 2, "max_tokens": 4},
                 "r1": {"presence_penalty": 0.5, "max_tokens": 6}}),
     "spec_window": ({"scheduler.speculative_ngram": 2}, {}),
-    "legacy_spec": ({"scheduler.speculative_ngram": 2,
-                     "scheduler.multi_step_window": False}, {}),
 }
 
 
@@ -126,8 +124,6 @@ PATHS = {
     "mixed_step": ("single", lambda w: w["kind"] == "mixed", "mixed_fn"),
     "spec_window": ("spec_window", lambda w: w["kind"] == "spec",
                     "spec_window_fn"),
-    "legacy_spec": ("legacy_spec", lambda w: "argmax_fn" in w["programs"],
-                    "decode_fn"),
 }
 
 
@@ -228,7 +224,7 @@ def test_phase_nests_lands_on_its_record_and_feeds_every_sink():
             with obs.phase("launch"):             # inherits a, nested
                 obs.compile_tracker.on_launch("sample_fn")
             with obs.phase("collect", b, family=False):   # b's, nested
-                obs.compile_tracker.on_launch("argmax_fn")
+                obs.compile_tracker.on_launch("penalties_fn")
             obs.compile_tracker.on_launch("logprobs_fn")
     with obs.phase("collect", a):
         pass
@@ -243,7 +239,7 @@ def test_phase_nests_lands_on_its_record_and_feeds_every_sink():
     assert [p[0] for p in a.phases] == ["build", "sample", "collect"]
     assert b.phases == []
     assert a.programs == ["sample_fn", "logprobs_fn"]
-    assert b.programs == ["argmax_fn"]
+    assert b.programs == ["penalties_fn"]
     assert a.launch_ns == a.program_ns[0] and b.launch_ns == b.program_ns[0]
     assert a.phases[1][1] <= a.launch_ns <= b.launch_ns <= a.phases[1][2]
     assert a.collected_ns == a.phases[2][2] and b.collected_ns is None

@@ -112,9 +112,9 @@ def test_min_tokens_suppresses_early_stop_token():
 
 def test_min_tokens_under_multistep_engine():
     """min_tokens drops the batch to single-step while unmet; output
-    still honors the floor under a num_scheduler_steps=4 engine."""
+    still honors the floor under a decode_window=4 engine."""
     tokens = _drain(
-        _engine(num_scheduler_steps=4),
+        _engine(decode_window=4),
         SamplingParams(max_tokens=10, min_tokens=10),
     )
     assert len(tokens) == 10
