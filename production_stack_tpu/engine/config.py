@@ -88,11 +88,15 @@ class ModelConfig:
     hc_eps: float = 1e-6
     hc_res_clamp: float = 30.0
     # Linear attention beside softmax attention (models/solar_kda.py).
-    # ``layer_kinds`` names each held layer's mix, "gqa" (softmax over K and V
-    # pages) or "kda" (the gated delta rule: a [linear_head_dim,
-    # linear_head_dim] float32 state a head a sequence and the last
-    # ``linear_conv_kernel`` - 1 rows of three depthwise convolutions, no
-    # keys); empty = every layer keeps keys.  ``use_rope`` False: no position
+    # ``layer_kinds`` names each held layer's mix, one period of the pattern
+    # (tiled over ``num_layers``): "gqa" (softmax over K and V pages), "kda"
+    # (the gated delta rule: a [linear_head_dim, linear_head_dim] float32
+    # state a head a sequence and the last ``linear_conv_kernel`` - 1 rows of
+    # three depthwise convolutions, no keys) or "mamba" (models/jamba.py, the
+    # selective state-space mixer: a [mamba_d_state, mamba_expand x
+    # hidden_size] float32 state a sequence and the last ``mamba_d_conv`` - 1
+    # rows of one depthwise convolution, no keys); empty = every layer keeps
+    # keys.  ``use_rope`` False: no position
     # encoding at all.  ``use_gqa_gate``: the softmax heads' output times
     # sigmoid(x W_gate), a column an output channel.  ``linear_gate_rank``:
     # the rank of the decay's and the output gate's projections.
@@ -105,6 +109,18 @@ class ModelConfig:
     linear_conv_kernel: int = 0
     linear_gate_rank: int = 0
     kda_allow_neg_eigval: bool = False
+    # The selective state-space mixer (models/jamba.py; 0 = none): inner
+    # width ``mamba_expand`` x ``hidden_size``, ``mamba_d_state`` states a
+    # channel, a causal depthwise convolution over the last ``mamba_d_conv``
+    # positions (with a bias where ``mamba_conv_bias``), the step size
+    # through a projection of rank ``mamba_dt_rank``; ``mamba_proj_bias``:
+    # biases on the in and out projections (no served preset has them).
+    mamba_expand: int = 0
+    mamba_d_state: int = 0
+    mamba_d_conv: int = 0
+    mamba_dt_rank: int = 0
+    mamba_conv_bias: bool = True
+    mamba_proj_bias: bool = False
 
     def __post_init__(self):
         if self.head_dim is None:
@@ -124,6 +140,18 @@ class ModelConfig:
     @property
     def q_per_kv(self) -> int:
         return self.num_heads // self.num_kv_heads
+
+    def layer_kind(self, layer_idx: int) -> str:
+        """The mix of held layer ``layer_idx``: ``layer_kinds`` tiled over the
+        depth (the one tiling rule; a module, the engine's boot line and the
+        compare's shorter depth all read it)."""
+        return self.layer_kinds[layer_idx % len(self.layer_kinds)]
+
+
+def _jamba_period(period: int, offset: int) -> Tuple[str, ...]:
+    """``attn_layer_period`` / ``attn_layer_offset`` as one period of
+    ``layer_kinds``: attention where ``i % period == offset``."""
+    return tuple("gqa" if i == offset else "mamba" for i in range(period))
 
 
 # Preset architectures (shapes from the public HF configs of each family;
@@ -448,6 +476,57 @@ PRESETS = {
         linear_conv_kernel=4,
         linear_gate_rank=8,
         kda_allow_neg_eigval=True,
+    ),
+    # AI21-Jamba2-3B (https://huggingface.co/ai21labs/AI21-Jamba2-3B,
+    # model_type jamba) WHOLE: all 28 layers, the whole vocabulary, every
+    # width as published, 3.03 B parameters, 6.06 GB of bf16.  Layer i is
+    # softmax attention where i % attn_layer_period (14) == attn_layer_offset
+    # (7), layers 7 and 21: 20 query heads over ONE key/value head, no
+    # position encoding; the other 26 are selective state-space (Mamba-1)
+    # mixers with an RMSNorm on the step size, B and C.  num_experts 1 in
+    # the source: every FFN is one dense SwiGLU (num_experts 0 here, the
+    # field counting routed experts).  The published max is 262,144
+    # positions; 32,768 is the serving limit the caches are sized for
+    # (bench/configs/jamba2-3b.json).
+    "jamba2-3b": ModelConfig(
+        name="jamba2-3b",
+        vocab_size=65536,
+        hidden_size=2560,
+        intermediate_size=8192,
+        num_layers=28,
+        num_heads=20,
+        num_kv_heads=1,
+        head_dim=128,
+        max_model_len=32768,
+        rms_norm_eps=1e-6,
+        tie_word_embeddings=True,
+        layer_kinds=_jamba_period(14, 7),
+        use_rope=False,
+        mamba_expand=2,
+        mamba_d_state=16,
+        mamba_d_conv=4,
+        mamba_dt_rank=160,
+    ),
+    # The same module at a size the CPU tests run: one period of four
+    # (attention at index 1), 4 query heads over one key head.
+    "tiny-jamba": ModelConfig(
+        name="tiny-jamba",
+        vocab_size=384,
+        hidden_size=64,
+        intermediate_size=128,
+        num_layers=4,
+        num_heads=4,
+        num_kv_heads=1,
+        head_dim=16,
+        max_model_len=2048,
+        rms_norm_eps=1e-6,
+        tie_word_embeddings=True,
+        layer_kinds=_jamba_period(4, 1),
+        use_rope=False,
+        mamba_expand=2,
+        mamba_d_state=16,
+        mamba_d_conv=4,
+        mamba_dt_rank=8,
     ),
     # Xing4.0-29B-A4B (https://huggingface.co/XingChen-AGI/Xing4.0-29B-A4B,
     # model_type xing4_0) AS ONE PIPELINE STAGE, not the whole model: every

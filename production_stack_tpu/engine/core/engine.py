@@ -334,11 +334,13 @@ class LLMEngine:
         )
         if self.state_pool is not None:
             logger.info(
-                "State pool: %d live + %d snapshot slots x %.2f MB "
-                "(%.2f GiB), a snapshot every %d tokens of a prompt's last "
-                "chunk",
+                "State pool: %d live + %d snapshot slots x %.2f MB (%d "
+                "layers' state a slot; %.2f GiB), a snapshot every %d tokens "
+                "of a prompt's last chunk",
                 self.state_pool.live_slots, self.state_pool.snapshot_slots,
                 self.model.state_bytes_per_slot(cfg) / 1e6,
+                sum(cfg.layer_kind(i) != "gqa"
+                    for i in range(cfg.num_layers)),
                 self._state_bytes() / 2**30,
                 self.model.snapshot_stride(cfg),
             )
@@ -507,6 +509,11 @@ class LLMEngine:
         self.mhc_clamped = 0
         self.mhc_entries = 0
         self.mhc_sinkhorn_err = 0.0
+        # tpu:ssm_state_absmax / tpu:ssm_dt_max: a module with selective
+        # state-space layers (models/jamba.py: SSM_STATS), the largest |h|
+        # any dispatch has left in a slot and the largest step size.
+        self.ssm_state_absmax = 0.0
+        self.ssm_dt_max = 0.0
         # tpu:sample_dispatch_total / tpu:sample_sorted_dispatch_total:
         # dispatched programs that sample, and those whose rows make the
         # sampler sort the vocabulary (sampling.needs_sort).
@@ -2751,10 +2758,17 @@ class LLMEngine:
             counts = counts.reshape(-1, counts.shape[-1])
             folded = dict(zip(self._routing_names, (int(n) for n in np.where(
                 self._routing_max, counts.max(0), counts.sum(0)))))
-            here = folded["moe_assigned_here"]
-            self.moe_assignments["held"] += here
-            self.moe_assignments["away"] += folded["moe_assigned"] - here
-            self.moe_experts_touched += folded["experts_touched"]
+            if "moe_assigned" in folded:
+                here = folded["moe_assigned_here"]
+                self.moe_assignments["held"] += here
+                self.moe_assignments["away"] += folded["moe_assigned"] - here
+                self.moe_experts_touched += folded["experts_touched"]
+            if "ssm_dt_max_e3" in folded:
+                self.ssm_state_absmax = max(
+                    self.ssm_state_absmax,
+                    folded["ssm_state_absmax_e3"] / 1e3)
+                self.ssm_dt_max = max(
+                    self.ssm_dt_max, folded["ssm_dt_max_e3"] / 1e3)
             if "mhc_entries" in folded:
                 self.mhc_clamped += folded["mhc_clamped"]
                 self.mhc_entries += folded["mhc_entries"]
@@ -4665,6 +4679,10 @@ class LLMEngine:
             "mhc_clamped": self.mhc_clamped,
             "mhc_entries": self.mhc_entries,
             "mhc_sinkhorn_err": self.mhc_sinkhorn_err,
+            # Selective state-space layers (zero without): the largest |h|
+            # any dispatch left in a slot, the largest step size.
+            "ssm_state_absmax": self.ssm_state_absmax,
+            "ssm_dt_max": self.ssm_dt_max,
             # The state pool of a model with recurrent state (zero without).
             **self._state_stats(),
             # Dispatched programs that sample, and those among them whose

@@ -46,7 +46,8 @@ the step programs (``core/step_programs.py``) carry without looking inside.
   tokens carry several residual streams.
 - with ``init_cache``, **a state pool**: ``state_bytes_per_slot(cfg)`` and
   ``snapshot_stride(cfg)`` say that some layers keep, in place of keys, a
-  recurrent state that does not grow with the context.  The engine then makes
+  recurrent state that does not grow with the context (two modules do:
+  ``solar_kda.py``, ``jamba.py``).  The engine then makes
   a ``kv/state_pool.py: StatePool`` beside the block pool (sized by rule from
   ``max_num_seqs``; its bytes come off what the block pool is sized from),
   calls ``init_cache(..., state_slots=n)`` so that the one cache tree holds
@@ -86,14 +87,27 @@ decay a channel (depthwise causal convolution, low-rank decay and gate,
 ``beta`` up to 2), a float32 state a head a sequence in a slot of the state
 pool, chunkwise in prefill and one step in decode (``ops/pallas/kda.py``),
 with snapshots at block boundaries that the prefix cache resumes from; over
-``sarvam_mla``'s routed experts held by share, imported.
+``sarvam_mla``'s routed experts held by share, imported; and in ``jamba.py``
+the selective state-space mixer (Mamba-1: causal depthwise convolution with a
+bias, a step size through a low-rank projection with a bias and a softplus, an
+RMSNorm on the step size, ``B`` and ``C``, ``A = -exp(A_log)``, a skip ``D``,
+a SiLU gate), a float32 state ``[states, channels]`` a sequence a layer in a
+slot of the same state pool (26 layers deep at the published depth), a scan
+over a chunk in prefill and one step in decode (``ops/pallas/ssm.py``), beside
+``solar_kda``'s softmax layer with its gate off at one key/value head
+(multi-query, 20 query heads: the paged kernel takes a one-head page as
+``[block, head_dim]``), ``llama.py``'s dense SwiGLU and tied head; layer kinds
+from one period (``attn_layer_period`` / ``attn_layer_offset``); counters
+``ssm_state_absmax_e3`` / ``ssm_dt_max_e3``.
 """
 
 from __future__ import annotations
 
 from types import ModuleType
 
-from production_stack_tpu.engine.models import llama, sarvam_mla, solar_kda
+from production_stack_tpu.engine.models import (
+    jamba, llama, sarvam_mla, solar_kda,
+)
 
 MODEL_REGISTRY = {
     # llama.py covers every RMSNorm+RoPE+GQA+gated-MLP family member; the
@@ -115,6 +129,11 @@ MODEL_REGISTRY = {
     # Gated delta-rule layers beside gated softmax layers without position
     # encoding: two kinds of state in one cache tree, pages and slots.
     "solar": solar_kda,
+    # Selective state-space (Mamba-1) layers beside multi-query softmax
+    # layers without position encoding, a dense MLP, a tied head: the second
+    # user of the state pool (its layer loop, its softmax path and its slot
+    # addressing are solar_kda's, imported).
+    "jamba": jamba,
 }
 
 

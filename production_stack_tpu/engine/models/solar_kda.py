@@ -99,7 +99,7 @@ def snapshot_stride(cfg: ModelConfig) -> int:
 
 
 def layer_kind(cfg: ModelConfig, layer_idx: int) -> str:
-    return cfg.layer_kinds[layer_idx % len(cfg.layer_kinds)]
+    return cfg.layer_kind(layer_idx)
 
 
 def _kinds(cfg: ModelConfig) -> List[str]:
@@ -149,8 +149,8 @@ def init_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
 def default_slot(cfg: ModelConfig, first_block_id, kv_caches):
     """The slot of a row nobody named one for: the first block id of its
     table modulo the slots there are."""
-    slots = kv_caches[_kinds(cfg).index("kda")][0].shape[0]
-    return first_block_id % slots
+    stateful = next(i for i, kind in enumerate(_kinds(cfg)) if kind != "gqa")
+    return first_block_id % kv_caches[stateful][0].shape[0]
 
 
 def _shapes(cfg: ModelConfig, layer_idx: int) -> Dict[str, tuple]:
@@ -487,6 +487,30 @@ def _gqa_out(layer, cfg, x, out):
     return out
 
 
+def _gqa_prefill(layer, cfg, cache, h, cached_len, prefix_block_ids,
+                 new_block_ids, valid_len):
+    """A chunk through one ``gqa`` layer: (what W_o reads, the new pages)."""
+    q, k, v = _gqa_project(layer, cfg, h)
+    k_prefix, v_prefix = attn_ops.gather_prefix_kv(
+        *cache, prefix_block_ids, dtype=k.dtype)
+    out = attn_ops.prefill_attention(
+        q, k, v, k_prefix, v_prefix, cached_len, valid_len,
+        scale=cfg.head_dim ** -0.5)
+    return _gqa_out(layer, cfg, h, out), attn_ops.write_prefill_kv(
+        *cache, k, v, new_block_ids)
+
+
+def _gqa_decode(layer, cfg, cache, h, block_tables, ctx_lens, slot_block_ids,
+                slot_offsets):
+    """One token a row through one ``gqa`` layer."""
+    q, k, v = _gqa_project(layer, cfg, h)
+    cache = attn_ops.append_decode_kv(
+        *cache, k, v, slot_block_ids, slot_offsets)
+    out = attn_ops.decode_attention(
+        q, *cache, block_tables, ctx_lens, scale=cfg.head_dim ** -0.5)
+    return _gqa_out(layer, cfg, h, out), cache
+
+
 # -- the layers --------------------------------------------------------------
 
 
@@ -499,9 +523,11 @@ def _ffn(layer, cfg, x, live):
     return (shared + routed).astype(x.dtype), who, stats
 
 
-def _blocks(params, cfg, kv_caches, x, live, mix):
+def _blocks(params, cfg, kv_caches, x, live, mix, ffn=_ffn):
     """Both steps' layers: ``mix(kind, layer, cache, normed h) -> (what W_o
-    reads [T, .], the layer's new cache)`` is the step's own."""
+    reads [T, .], the layer's new cache)`` is the step's own; ``ffn(layer,
+    cfg, normed h, live) -> (y, choice, counts)`` the module's
+    (``models/jamba.py`` hands a dense one)."""
     caches, choice, stats = [], [], []
     for i, (layer, cache) in enumerate(zip(params["layers"], kv_caches)):
         h = rms_norm(x, layer["input_layernorm"], cfg.rms_norm_eps)
@@ -509,7 +535,7 @@ def _blocks(params, cfg, kv_caches, x, live, mix):
         caches.append(new)
         x = x + _dot(out, layer["o_proj"]).astype(x.dtype)
         h = rms_norm(x, layer["post_attention_layernorm"], cfg.rms_norm_eps)
-        y, who, counted = _ffn(layer, cfg, h, live)
+        y, who, counted = ffn(layer, cfg, h, live)
         choice.append(who)
         stats.append(counted)
         x = x + y
@@ -555,18 +581,12 @@ def prefill(
     if state_from is None:
         state_from = jnp.where(cached_len > 0, state_slot, -1)
     slots = (state_slot, state_from, snapshot_slot, snapshot_len)
-    scale = cfg.head_dim ** -0.5
 
     def mix(kind, layer, cache, h):
         if kind == "kda":
             return _kda_prefill(layer, cfg, cache, h, live, valid_len, slots)
-        q, k, v = _gqa_project(layer, cfg, h)
-        k_prefix, v_prefix = attn_ops.gather_prefix_kv(
-            *cache, prefix_block_ids, dtype=k.dtype)
-        out = attn_ops.prefill_attention(
-            q, k, v, k_prefix, v_prefix, cached_len, valid_len, scale=scale)
-        return _gqa_out(layer, cfg, h, out), attn_ops.write_prefill_kv(
-            *cache, k, v, new_block_ids)
+        return _gqa_prefill(layer, cfg, cache, h, cached_len,
+                            prefix_block_ids, new_block_ids, valid_len)
 
     x, caches, *counted = _blocks(
         params, cfg, kv_caches, params["embed_tokens"][tokens], live, mix)
@@ -596,17 +616,12 @@ def decode(
     live = slot_block_ids != 0
     if state_slots is None:
         state_slots = default_slot(cfg, block_tables[:, 0], kv_caches)
-    scale = cfg.head_dim ** -0.5
 
     def mix(kind, layer, cache, h):
         if kind == "kda":
             return _kda_decode(layer, cfg, cache, h, live, state_slots)
-        q, k, v = _gqa_project(layer, cfg, h)
-        cache = attn_ops.append_decode_kv(
-            *cache, k, v, slot_block_ids, slot_offsets)
-        out = attn_ops.decode_attention(
-            q, *cache, block_tables, ctx_lens, scale=scale)
-        return _gqa_out(layer, cfg, h, out), cache
+        return _gqa_decode(layer, cfg, cache, h, block_tables, ctx_lens,
+                           slot_block_ids, slot_offsets)
 
     x, caches, *counted = _blocks(
         params, cfg, kv_caches, params["embed_tokens"][tokens], live, mix)

@@ -244,10 +244,15 @@ def paged_decode_attention_pallas(
     cache_in_specs = [pl.BlockSpec(memory_space=pl.ANY)] * (
         4 if quantized else 2
     )
+    # One KV head (multi-query): a page goes in as [bs, D], the layout XLA
+    # keeps [N, bs, 1, D] in anyway (the reshape is a bitcast).  Mosaic
+    # tiles a memref's last two dims, and a second-minor dim of 1 is padded
+    # to a tile of 2 that a one-head DMA slice is not aligned to.
+    page = (bs, D) if K == 1 and not quantized else (bs, K, D)
     scratch = [
-        pltpu.VMEM((2, C, bs, K, D),
+        pltpu.VMEM((2, C, *page),
                    jnp.int8 if quantized else k_cache.dtype),
-        pltpu.VMEM((2, C, bs, K, D),
+        pltpu.VMEM((2, C, *page),
                    jnp.int8 if quantized else v_cache.dtype),
     ]
     if quantized:
@@ -268,7 +273,8 @@ def paged_decode_attention_pallas(
     )
     inputs = (
         (q, k_cache[0], v_cache[0], k_cache[1], v_cache[1])
-        if quantized else (q, k_cache, v_cache)
+        if quantized else
+        (q, k_cache.reshape(N, *page), v_cache.reshape(N, *page))
     )
     return pl.pallas_call(
         kernel,

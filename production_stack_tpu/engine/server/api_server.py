@@ -402,6 +402,10 @@ def build_engine_app(
             (vocab.TPU_MHC_CLAMPED, s["mhc_clamped"]),
             (vocab.TPU_MHC_ENTRIES, s["mhc_entries"]),
             (vocab.TPU_MHC_SINKHORN_ERR, s["mhc_sinkhorn_err"]),
+            # Selective state-space layers: the largest |h| left in a slot
+            # and the largest step size since boot.
+            (vocab.TPU_SSM_STATE_ABSMAX, s["ssm_state_absmax"]),
+            (vocab.TPU_SSM_DT_MAX, s["ssm_dt_max"]),
             # Programs that sample, and those that sort the vocabulary for
             # it: both from boot, so that their ratio reads 0, not nothing.
             (vocab.TPU_SAMPLE_DISPATCH, s["sample_dispatches"]),

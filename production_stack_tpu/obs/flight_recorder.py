@@ -121,7 +121,10 @@ class WindowRecord:
     # A model with several residual streams adds (RESIDUAL_STATS):
     # ``mhc_clamped`` / ``mhc_entries``: entries of its mixing matrices'
     # exponents the clamp changed / seen; ``mhc_err_e6``: the largest
-    # |row sum - 1| after the last normalisation, x 1e6.
+    # |row sum - 1| after the last normalisation, x 1e6.  A model with
+    # selective state-space layers hands (models/jamba.py: SSM_STATS)
+    # ``ssm_state_absmax_e3`` / ``ssm_dt_max_e3``: the largest |h| the
+    # dispatch left in a slot and its largest step size, x 1000.
     routing: Optional[Dict[str, int]] = None
     # Under a model with a state pool (kv/state_pool.py).  ``state_rows``:
     # decode rows that read and write a slot of recurrent state each step.

@@ -439,6 +439,21 @@ REGISTRY = {
                 "Sinkhorn normalisation that any dispatch since boot has "
                 "read (its columns sum to 1 by construction)",
     },
+    "tpu:ssm_state_absmax": {
+        "kind": "gauge", "layer": "engine",
+        "mirrors": ("fake_engine", "dashboard", "docs"),
+        "help": "Largest |h| that any dispatch since boot has left in a "
+                "slot of a selective state-space layer's recurrent state "
+                "(counted on the device, read back with the tokens); zero "
+                "for a model without such layers",
+    },
+    "tpu:ssm_dt_max": {
+        "kind": "gauge", "layer": "engine",
+        "mirrors": ("fake_engine", "dashboard", "docs"),
+        "help": "Largest step size (softplus of the projected step plus "
+                "its bias) of a live token that any dispatch since boot "
+                "has read in a selective state-space layer",
+    },
     "tpu:kv_wire_bytes_total": {
         "kind": "counter", "layer": "engine", "labels": ("tier", "format"),
         "mirrors": ("fake_engine", "dashboard", "docs"),

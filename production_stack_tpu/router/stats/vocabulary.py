@@ -221,6 +221,13 @@ TPU_MOE_EXPERTS_TOUCHED = "tpu:moe_experts_touched_total"
 TPU_MHC_CLAMPED = "tpu:mhc_clamped_total"
 TPU_MHC_ENTRIES = "tpu:mhc_entries_total"
 TPU_MHC_SINKHORN_ERR = "tpu:mhc_sinkhorn_err"
+# Selective state-space layers (engine/models/jamba.py: SSM_STATS), two
+# gauges: the largest |h| any dispatch has left in a slot of recurrent state
+# (a state that blows up) and the largest step size of a live token (near 0
+# everywhere: nothing is written; large: everything is forgotten).  Counted on
+# the device, read back with the tokens; zero for a model without such layers.
+TPU_SSM_STATE_ABSMAX = "tpu:ssm_state_absmax"
+TPU_SSM_DT_MAX = "tpu:ssm_dt_max"
 # The sampler does what its rows ask for (engine/sampling.py): dispatched
 # programs that sample (decode window, mixed window, single step, prefill
 # tail), and those among them in which a sampling row set top-k or top-p,

@@ -547,6 +547,9 @@ def build_fake_engine_app(state: FakeEngineState | None = None) -> web.Applicati
             (vocab.TPU_MHC_CLAMPED, 0),
             (vocab.TPU_MHC_ENTRIES, 0),
             (vocab.TPU_MHC_SINKHORN_ERR, 0.0),
+            # The fake has no state-space layer: the families, at zero.
+            (vocab.TPU_SSM_STATE_ABSMAX, 0.0),
+            (vocab.TPU_SSM_DT_MAX, 0.0),
             # The fake samples nothing on a device: the families, at zero.
             (vocab.TPU_SAMPLE_DISPATCH, 0),
             (vocab.TPU_SAMPLE_SORTED_DISPATCH, 0),

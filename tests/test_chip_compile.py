@@ -252,6 +252,83 @@ def test_the_dense_kernels_compile_at_solars_geometry(sds, no_persistent_cache):
     )
 
 
+# jamba2-3b (models/jamba.py): the two state-space kernels at the published
+# 5120 channels of 16 states (the scan at both prefill programs' slots, the
+# one-step form at 1 to 16 rows over the cell's 59 slots), and the two dense
+# kernels at its attention layers' geometry, 20 query heads over ONE key/value
+# head: a third configuration holds them.  One key head is what Mosaic had
+# refused (a page [16, 1, 128] pads its second-minor dim to a tile of 2 that a
+# one-head DMA slice is not aligned to): the wrapper hands such a page in as
+# [16, 128], a bitcast of what XLA keeps.
+@pytest.mark.parametrize("T", [256, 2048], ids=["T256", "T2048"])
+def test_ssm_prefill_kernel_compiles(sds, no_persistent_cache, T):
+    from production_stack_tpu.engine.ops.pallas.ssm import ssm_prefill_pallas
+
+    cfg = PRESETS["jamba2-3b"]
+    Di, N = cfg.mamba_expand * cfg.hidden_size, cfg.mamba_d_state
+    assert (Di, N) == (5120, 16)
+    rows, cols = sds((T, Di), jnp.float32), sds((T, N), jnp.float32)
+    state = sds((N, Di), jnp.float32)
+    _compile(
+        lambda c, dt, z, B, C, A, D, s0, at: ssm_prefill_pallas(
+            c, dt, z, B, C, A, D, s0, at),
+        rows, rows, rows, cols, cols, state, sds((Di,), jnp.float32), state,
+        sds((), jnp.int32),
+    )
+
+
+@pytest.mark.parametrize("S", [1, 8, 16], ids=["S1", "S8", "S16"])
+def test_ssm_decode_kernel_compiles(sds, no_persistent_cache, S):
+    from production_stack_tpu.engine.kv.state_pool import pool_slots
+    from production_stack_tpu.engine.ops.pallas.ssm import ssm_decode_pallas
+
+    cfg = PRESETS["jamba2-3b"]
+    Di, N = cfg.mamba_expand * cfg.hidden_size, cfg.mamba_d_state
+    pool = (1 + sum(pool_slots(16)), N, Di)
+    rows, cols = sds((S, Di), jnp.float32), sds((S, N), jnp.float32)
+    compiled = jax.jit(
+        lambda c, dt, z, B, C, A, D, state, at: ssm_decode_pallas(
+            c, dt, z, B, C, A, D, state, at),
+        donate_argnums=(7,),           # as the engine donates the cache tree
+    ).lower(
+        rows, rows, rows, cols, cols, sds((N, Di), jnp.float32),
+        sds((Di,), jnp.float32), sds(pool, jnp.float32), sds((S,), jnp.int32),
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # The pool goes in and comes out in place: no second array of it.
+    shape = "f32[" + ",".join(map(str, pool)) + "]"
+    assert not [line for line in text.splitlines()
+                if " copy(" in line and shape in line.split("=")[1][:60]]
+
+
+def test_the_dense_kernels_compile_at_jambas_geometry(sds, no_persistent_cache):
+    cfg = PRESETS["jamba2-3b"]
+    H, K, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    assert (H, K, hd, cfg.sliding_window) == (20, 1, 128, None)
+    pages = (500_000, BS, K, hd)       # the cell's pool: half a million blocks
+    cache = sds(pages, jnp.bfloat16)
+    text = _compile(
+        lambda q, k, v, bt, cl: paged_decode_attention_pallas(
+            q, k, v, bt, cl, scale=hd ** -0.5, sliding_window=None),
+        sds((16, H, hd), jnp.bfloat16), cache, cache,
+        sds((16, cfg.max_model_len // BS), jnp.int32), sds((16,), jnp.int32),
+    ).as_text()
+    # The 2 GB of pages reach the kernel as they lie: a bitcast, no copy.
+    assert not [line for line in text.splitlines()
+                if " copy(" in line and "bf16[500000," in line.split("=")[1][:60]]
+    for T in (256, 2048):
+        new = sds((T, K, hd), jnp.bfloat16)
+        prefix = sds((cfg.max_model_len, K, hd), jnp.bfloat16)
+        _compile(
+            lambda q, k, v, kp, vp, cached, valid: flash_prefill_attention(
+                q, k, v, kp, vp, cached, valid, scale=hd ** -0.5,
+                sliding_window=None),
+            sds((T, H, hd), jnp.bfloat16), new, new, prefix, prefix,
+            sds((), jnp.int32), sds((), jnp.int32),
+        )
+
+
 def test_int8_kv_decode_kernel_is_still_refused(sds, no_persistent_cache):
     """ROADMAP S10: Mosaic refuses the int8-KV decode kernel (the
     [N, bs, K] fp32 scale planes are no 128-lane DMA slice, and since the
@@ -333,3 +410,35 @@ def test_kv_pool_is_sized_from_every_device_and_never_guessed_on_a_tpu():
         LLMEngine._decide_num_blocks(boot)
     boot.device_report = lambda: {"platform": "cpu", "memory": boot.memory}
     assert LLMEngine._decide_num_blocks(boot) == 512
+
+
+def test_the_state_pools_bytes_come_off_what_the_kv_pool_is_sized_from():
+    """jamba2-3b at the published widths: 59 slots x 9,318,400 B of state are
+    taken off the chip's budget before the 16 kB blocks (1,024 B a position)
+    are counted: ~8 M positions, half a million blocks."""
+    from production_stack_tpu.engine.config import config_from_preset
+    from production_stack_tpu.engine.core.engine import LLMEngine
+    from production_stack_tpu.engine.kv.state_pool import StatePool, pool_slots
+    from production_stack_tpu.engine.models import jamba
+
+    GB = 10**9
+
+    class Boot:
+        config = config_from_preset(
+            "jamba2-3b", **{"scheduler.max_num_seqs": 16})
+        model = jamba
+        _kv_bytes = LLMEngine._kv_bytes
+        _state_bytes = LLMEngine._state_bytes
+        state_pool = StatePool(*pool_slots(16))
+
+        def device_report(self):
+            return {"platform": "tpu", "memory": [
+                {"id": 0, "bytes_limit": 16 * GB, "bytes_in_use": 6 * GB}]}
+
+    boot = Boot()
+    assert boot.state_pool.num_slots == 59
+    assert boot._state_bytes() == 59 * 9_318_400
+    assert boot._kv_bytes(1) == 16 * 1024
+    blocks = LLMEngine._decide_num_blocks(boot)
+    assert blocks == int((0.9 * 16 * GB - 6 * GB - 59 * 9_318_400) // 16384)
+    assert 450_000 < blocks < 520_000
