@@ -285,10 +285,14 @@ class LLMEngine:
             self.state_pool = StatePool(
                 *pool_slots(config.scheduler.max_num_seqs))
         num_blocks = self._decide_num_blocks()
+        # Pages one DMA of the paged decode walk carries for this model
+        # (1: every page alone); the pool keeps runs of as many together.
+        self._kv_group_blocks = self._decide_kv_group_blocks()
         self.block_pool = BlockPool(
             num_blocks,
             config.cache.block_size,
             enable_prefix_caching=config.cache.enable_prefix_caching,
+            run=self._kv_group_blocks,
         )
         # Cross-engine prefix sharing (cache.disagg_role): content-keyed
         # block export/import through the remote store.
@@ -777,6 +781,14 @@ class LLMEngine:
         # prefill chunks (tpu:prefill_attn_tiles_total{state}); host
         # arithmetic in _count_kv_tiles, step-thread-only writer.
         self.prefill_attn_tiles: Dict[str, int] = {"live": 0, "skipped": 0}
+        # Where a descriptor of the paged decode walk carries several pages
+        # (_kv_group_blocks > 1): the groups the live rows' tables held and
+        # those that were one region of the pool, by the kernel's own rule,
+        # summed over the decode batches built from host state
+        # (tpu:paged_decode_groups*_total); step-thread-only writer.  The
+        # last batch's pair goes on its flight record.
+        self.paged_decode_groups = {"total": 0, "coalesced": 0}
+        self._last_kv_groups = (0, 0)
         # Last _can_window decline reason, stamped on the flight record
         # of the K=1 dispatch that replaced the declined window (step-
         # thread-only, overwritten every _can_window call).
@@ -1027,6 +1039,23 @@ class LLMEngine:
                 f"{module} keeps a cache of its own (one array a layer) and "
                 f"cannot serve with: {'; '.join(refused)}"
             )
+
+    def _decide_kv_group_blocks(self) -> int:
+        """``blocks_per_descriptor`` of the page the paged decode kernel is
+        given for this model (its K / tp heads a shard), where that kernel
+        serves; 1 on any other path."""
+        from production_stack_tpu.engine.ops.pallas.paged_attention import (
+            blocks_per_descriptor,
+        )
+
+        cfg, cache = self.config.model, self.config.cache
+        heads = cfg.num_kv_heads // self.config.parallel.tensor_parallel
+        if not attn_ops.use_pallas_decode(heads, cfg.head_dim):
+            return 1
+        return blocks_per_descriptor(
+            cache.block_size * heads * cfg.head_dim * _dtype_size(cfg.dtype),
+            quantized=cache.kv_cache_dtype == "int8",
+        )
 
     def _decide_num_blocks(self) -> int:
         cache = self.config.cache
@@ -2341,6 +2370,7 @@ class LLMEngine:
         with self.obs.phase("build", rec):
             if chain_from is None:
                 state = self._window_build(seqs, decode.steps, first)
+                self._record_kv_groups(rec)
             else:
                 state = self._window_chain(chain_from, seqs, decode.steps)
         if chain_from is None and behind is None:
@@ -2557,6 +2587,7 @@ class LLMEngine:
         with self.obs.phase("build", rec):
             if chain_from is None:
                 state = self._window_build(seqs, decode.steps)
+                self._record_kv_groups(rec)
             else:
                 state = self._window_chain(chain_from, seqs, decode.steps)
         if chain_from is None:
@@ -3710,7 +3741,33 @@ class LLMEngine:
             ctx_lens[i] = pos + 1
             slot_blocks[i] = seq.block_table[pos // bs]
             slot_offsets[i] = pos % bs
+        if self._kv_group_blocks > 1:
+            self._count_kv_groups(block_tables, ctx_lens)
         return tokens, positions, block_tables, ctx_lens, slot_blocks, slot_offsets
+
+    def _record_kv_groups(self, rec) -> None:
+        """The batch just built, on its flight record."""
+        if rec is not None and self._kv_group_blocks > 1:
+            rec.kv_groups, rec.kv_groups_coalesced = self._last_kv_groups
+
+    def _count_kv_groups(self, block_tables, ctx_lens) -> None:
+        """Groups of ``_kv_group_blocks`` table entries the live rows hold,
+        and those the paged decode kernel fetches in one DMA a side
+        (``whole_groups``, its own rule): one compare over the batch's
+        tables."""
+        from production_stack_tpu.engine.ops.pallas.paged_attention import (
+            whole_groups,
+        )
+
+        R, bs = self._kv_group_blocks, self.block_pool.block_size
+        whole = whole_groups(
+            block_tables[:, : block_tables.shape[1] // R * R], R, xp=np)
+        groups = -(-ctx_lens // (bs * R))
+        live = np.arange(whole.shape[1]) < groups[:, None]
+        pair = int(groups.sum()), int((whole & live).sum())
+        self.paged_decode_groups["total"] += pair[0]
+        self.paged_decode_groups["coalesced"] += pair[1]
+        self._last_kv_groups = pair
 
     def _decode_bucket(self, n: int) -> int:
         """Static decode batch sizes: the smallest bucket of the
@@ -4668,6 +4725,8 @@ class LLMEngine:
             "multistep_fallback": dict(self.multistep_fallback),
             "multistep_wasted_tokens": self.multistep_wasted_tokens,
             "prefill_attn_tiles": dict(self.prefill_attn_tiles),
+            # Zero where every page travels alone.
+            "paged_decode_groups": dict(self.paged_decode_groups),
             # Routed experts held by share: (row, expert) pairs by where
             # they fell, and held experts with at least one row a layer
             # and step (zero for a model that routes nothing).

@@ -633,6 +633,13 @@ class Scheduler:
             decode=decode, chunk_schedule=schedule, decode_window=k_eff,
         )
 
+    def _grow(self, seq: Sequence, need: int) -> None:
+        """``need`` more blocks for a running row, next to its last where
+        the pool can (kv/block_pool.py: a row that grows keeps its run)."""
+        table = seq.block_table
+        table.extend(self.block_pool.allocate(
+            need, after=table[-1] if table else None))
+
     def _mixed_window_decode_plan(self, k_cap: int) -> Optional[DecodePlan]:
         """Decode rows for a mixed K-step window, blocks pre-allocated
         for the whole k_cap budget.  Declines instead of preempting —
@@ -652,7 +659,7 @@ class Scheduler:
             return None
         for seq, need in zip(self.running, needs):
             if need:
-                seq.block_table.extend(self.block_pool.allocate(need))
+                self._grow(seq, need)
         return DecodePlan(seqs=list(self.running), steps=steps)
 
     def _try_schedule_prefill(
@@ -750,7 +757,8 @@ class Scheduler:
             if not seq.partial_prefill:
                 self.block_pool.free(prefix_blocks)
             return None
-        new_blocks = self.block_pool.allocate(blocks_needed)
+        new_blocks = self.block_pool.allocate(
+            blocks_needed, after=prefix_blocks[-1] if prefix_blocks else None)
         state = {}
         if self.state_pool is not None:
             state = self._plan_state(
@@ -897,7 +905,7 @@ class Scheduler:
         for seq in self.running:
             need = blocks_needed(seq)
             if need:
-                seq.block_table.extend(self.block_pool.allocate(need))
+                self._grow(seq, need)
         return DecodePlan(
             seqs=list(self.running),
             steps=[self._step_budget(seq, window) for seq in self.running],
@@ -1000,7 +1008,7 @@ class Scheduler:
             return None
         for seq, need in zip(rows, needs):
             if need:
-                seq.block_table.extend(self.block_pool.allocate(need))
+                self._grow(seq, need)
         return StepPlan(
             decode=DecodePlan(seqs=list(rows), steps=steps),
             decode_window=window,
@@ -1064,7 +1072,7 @@ class Scheduler:
             return None
         for seq, need in zip(rows, needs):
             if need:
-                seq.block_table.extend(self.block_pool.allocate(need))
+                self._grow(seq, need)
         # Snapshot BEFORE chunk planning: a final chunk pops the head
         # into self.running at plan time, and the popped head has no
         # decode row in THIS window (it joins at the next boundary).
@@ -1139,7 +1147,7 @@ class Scheduler:
             return None
         for seq, need in zip(self.running, needs):
             if need:
-                seq.block_table.extend(self.block_pool.allocate(need))
+                self._grow(seq, need)
         return DecodePlan(seqs=list(self.running), steps=[1] * len(self.running))
 
     # -- an admission behind the work in flight -----------------------------
@@ -1207,7 +1215,7 @@ class Scheduler:
             return None, "no_free_blocks"
         for seq, need in zip(self.running, needs):
             if need:
-                seq.block_table.extend(self.block_pool.allocate(need))
+                self._grow(seq, need)
         return StepPlan(
             decode=DecodePlan(seqs=list(self.running), steps=steps),
             decode_window=window,

@@ -9,6 +9,24 @@ Grafana dashboard key off (reference scrapes the same concept from vLLM as
 
 Block 0 is the reserved *null block*: padding scatter targets land there and
 it is never read or allocated.
+
+**Which block a taker gets.**  Ids are handed out ascending: ``allocate(n)``
+from a fresh pool returns ``b, b+1, ..., b+n-1``, because pages that lie next
+to each other in the pool travel in one DMA of the paged decode walk where a
+page is small (``ops/pallas/paged_attention.py: blocks_per_descriptor``; the
+engine hands its ``R`` in as ``run``).  At ``run > 1`` the pool also keeps a
+growing row's run together: ``allocate(n, after=b)`` takes ``b+1, b+2, ...``
+while they are free, and a taker that cannot go on (no ``after``, or the next
+id is taken) starts at the head of an aligned group of ``run`` ids that is
+wholly free and leaves that group's rest for its own continuation.  Plain
+takers draw from wholly free groups before they draw from a broken group's
+rest; a fresh pool's groups are taken in ascending order, and a group that
+frees made whole again waits behind them.
+Nothing is reserved: every free block is any taker's, counts, eviction and
+the prefix cache do not know about runs, and a block evicted from the
+cached-free tier simply breaks a run (the kernel fetches that group page by
+page).  At ``run == 1`` there are no groups, ``after`` is ignored and freed
+blocks are reused last-in-first-out.
 """
 
 from __future__ import annotations
@@ -92,13 +110,30 @@ def prefix_block_hashes(
 
 
 class BlockPool:
-    def __init__(self, num_blocks: int, block_size: int, enable_prefix_caching: bool = True):
+    def __init__(self, num_blocks: int, block_size: int,
+                 enable_prefix_caching: bool = True, run: int = 1):
         if num_blocks < 2:
             raise ValueError("need at least 2 blocks (block 0 is the null block)")
         self.num_blocks = num_blocks
         self.block_size = block_size
         self.enable_prefix_caching = enable_prefix_caching
-        self._free: List[int] = list(range(1, num_blocks))  # 0 = null block
+        self.run = max(int(run), 1)
+        # Plain free blocks, an ordered set: O(1) "is b free" and removal,
+        # last in first out (``popitem``); listed descending so that a
+        # fresh pool hands out 1, 2, 3, ...  (0 = null block).
+        self._free: Dict[int, None] = dict.fromkeys(range(num_blocks - 1, 0, -1))
+        if self.run > 1:
+            # Free blocks a group of ``run`` aligned ids, and the groups
+            # that are wholly free, taken from the front: the fresh ones
+            # ascending, then those that frees made whole again, oldest
+            # first.  The null block's group and a short last one never
+            # count ``run``, so they are never whole.
+            groups = -(-num_blocks // self.run)
+            self._group_free = [self.run] * groups
+            self._group_free[0] -= 1
+            self._group_free[-1] -= groups * self.run - num_blocks
+            self._whole: "OrderedDict[int, None]" = OrderedDict.fromkeys(
+                g for g in range(groups) if self._group_free[g] == self.run)
         self._ref_counts: Dict[int, int] = {}
         # Prefix cache: chain hash -> block id; and reverse map.
         self._hash_to_block: Dict[bytes, int] = {}
@@ -148,8 +183,10 @@ class BlockPool:
     def can_allocate(self, n: int) -> bool:
         return self.num_free_blocks >= n
 
-    def allocate(self, n: int) -> List[int]:
-        """Allocate n blocks, evicting LRU cached-free blocks as needed."""
+    def allocate(self, n: int, after: Optional[int] = None) -> List[int]:
+        """Allocate n blocks, evicting LRU cached-free blocks as needed.
+        ``after``: the taker's last block, whose run the new ones continue
+        where they can (module docstring)."""
         if not self.can_allocate(n):
             raise RuntimeError(
                 f"KV pool exhausted: need {n} blocks, have {self.num_free_blocks}"
@@ -157,13 +194,33 @@ class BlockPool:
         out: List[int] = []
         for _ in range(n):
             if self._free:
-                block = self._free.pop()
+                block = self._take_free(after)
             else:
                 block, _ = self._cached_free.popitem(last=False)  # LRU evict
                 self._evict_hash(block)
             self._ref_counts[block] = 1
             out.append(block)
+            after = block
         return out
+
+    def _take_free(self, after: Optional[int]) -> int:
+        """One plain free block: the one after ``after``, else the head of a
+        wholly free group, else the last freed."""
+        if self.run == 1:
+            return self._free.popitem()[0]
+        if after is not None and after + 1 in self._free:
+            block = after + 1
+            del self._free[block]
+        elif self._whole:
+            block = next(iter(self._whole)) * self.run
+            del self._free[block]
+        else:
+            block = self._free.popitem()[0]
+        group = block // self.run
+        if self._group_free[group] == self.run:
+            del self._whole[group]
+        self._group_free[group] -= 1
+        return block
 
     def free(self, blocks: Sequence[int]) -> None:
         for block in blocks:
@@ -180,7 +237,12 @@ class BlockPool:
                 self._cached_free[block] = None
                 self._cached_free.move_to_end(block)
             else:
-                self._free.append(block)
+                self._free[block] = None
+                if self.run > 1:
+                    group = block // self.run
+                    self._group_free[group] += 1
+                    if self._group_free[group] == self.run:
+                        self._whole[group] = None
 
     def _evict_hash(self, block: int) -> None:
         digest = self._block_to_hash.pop(block, None)

@@ -9,10 +9,23 @@ blocks HBM->VMEM exactly once with double-buffered async DMA and an online
 softmax, and its loop bound is the *real* context length, so a 256-token
 sequence in an 8k-token pool touches 16 blocks, not 512.
 
-Blocks are fetched in stages of ``CHUNK_BLOCKS``: one 16-token block is
-too small to amortize DMA issue latency or fill the MXU, so each stage
-issues that many parallel block DMAs per side and runs one online-softmax
-update over the whole ``CHUNK_BLOCKS * block_size``-token tile.
+Blocks are fetched in stages of ``CHUNK_BLOCKS`` descriptors a side: one
+16-token block is too small to amortize DMA issue latency or fill the MXU,
+so each stage issues that many parallel DMAs per side and runs one
+online-softmax update over the whole tile.  A descriptor carries 32 kB
+(``DESCRIPTOR_BYTES``): issuing and retiring one costs the walk ~30 ns
+whatever it holds, which a page of eight key heads (32 kB, 40 ns of bytes)
+hides and a page of one key head (4 kB, 5 ns) does not.  So where a page is
+smaller, ``R = blocks_per_descriptor(page bytes)`` pages that lie next to
+each other in the pool -- which is how ``kv/block_pool.py`` hands them out
+-- travel in one DMA, and a stage is ``CHUNK_BLOCKS * R`` blocks (one key
+head: 8 pages a descriptor, 128 blocks = 2,048 positions a stage, the 512 kB
+a side in flight that eight heads have).  ``R`` follows the shape the
+kernel is given and nothing else; at ``R == 1`` the walk is the single-page
+walk, operand for operand.  Which groups of ``R`` table entries are such
+neighbours is read from the block table by XLA before the kernel
+(``whole_groups``) and prefetched beside it; a group that is not goes page
+by page, bit-equal.
 
 One program walks every (row, stage) pair of the batch in order, and
 while a stage computes, the walk's next stage is in flight into the other
@@ -49,28 +62,68 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
-# Blocks a pipeline stage fetches per side.  16 x 16 tokens = 1 MiB of K
-# and V in flight behind the stage that computes, which is what keeps the
-# DMA engine at its own rate on a v5e (8 left it waiting on the compute:
-# tools/paged_decode_microbench.py); VMEM: 2 x CHUNK_BLOCKS x 32 kB a side.
+# Descriptors a pipeline stage issues per side: blocks where a page is a
+# descriptor of its own, groups of blocks_per_descriptor() pages where it is
+# smaller.  16 x 32 kB a side = 1 MiB of K and V in flight behind the stage
+# that computes, which is what keeps the DMA engine at its own rate on a v5e
+# (8 left it waiting on the compute: tools/paged_decode_microbench.py);
+# VMEM: 2 x CHUNK_BLOCKS x 32 kB a side.
 CHUNK_BLOCKS = 16
+# What one DMA descriptor should carry.  The walk cannot issue and retire a
+# descriptor in under ~30 ns whatever it holds (PERF.md section 6, PR 54):
+# a 32 kB page is 40 ns of bytes at 819 GB/s and hides that, a one-key-head
+# page of 4 kB is 5 ns and does not.
+DESCRIPTOR_BYTES = 32 * 1024
+
+
+def blocks_per_descriptor(page_bytes: int, quantized: bool = False) -> int:
+    """THE rule, page bytes -> ``R``: how many pool-adjacent pages one DMA
+    of the decode walk carries.  The kernel's wrapper calls it with the
+    page of the shape it is given, the engine with the served model's page
+    to tell the block pool how long a run to keep together
+    (``BlockPool(run=R)``), so the two cannot disagree.  An int8 (data,
+    scale) cache keeps single pages: its scale planes ride the same
+    pipeline."""
+    if quantized:
+        return 1
+    return max(1, DESCRIPTOR_BYTES // max(page_bytes, 1))
+
+
+def whole_groups(block_tables, group_blocks: int, xp=jnp):
+    """``[S, Bmax // R]`` bool: which groups of ``R`` consecutive table
+    entries (``Bmax`` a multiple of ``R``) are ``R`` ascending neighbours
+    in the pool, the last of them allocated (block 0 is the null block) --
+    one region of ``R`` pages, one DMA.  Read from the table alone (not
+    from the contexts, which grow inside a window's scan while the tables
+    are its constants); the host counts with the same function over its
+    numpy tables (``xp=np``)."""
+    S, bmax = block_tables.shape
+    t = block_tables.reshape(S, bmax // group_blocks, group_blocks)
+    run = t[..., :1] + xp.arange(group_blocks, dtype=t.dtype)
+    return (t == run).all(-1) & (t[..., -1] != 0)
 
 
 def _decode_kernel(
     # scalar prefetch (SMEM)
     block_tables_ref,  # [S, Bmax] int32
     ctx_lens_ref,  # [S] int32
+    # [whole_ref [S, Bmax // R], stage_whole_ref [S, Bmax // C] int32 when
+    # R > 1: whole_groups() a group and a stage]
     # inputs: q_ref [S, H, D], k_hbm, v_hbm[, ks_hbm, vs_hbm] (int8 scales)
     # outputs: o_ref [S, H, D]
     # scratch: k_buf, v_buf[, ks_buf, vs_buf], sems
     *refs,
     bs: int,
     chunk_blocks: int,
+    group_blocks: int,
     num_kv_heads: int,
     scale: float,
     sliding_window: Optional[int],
     quantized: bool,
 ):
+    R = group_blocks  # pages a descriptor; 1: the single-page walk
+    if R > 1:
+        whole_ref, stage_whole_ref, *refs = refs
     if quantized:
         (q_ref, k_hbm, v_hbm, ks_hbm, vs_hbm, o_ref,
          k_buf, v_buf, ks_buf, vs_buf, sems) = refs
@@ -108,7 +161,7 @@ def _decode_kernel(
                     cache.at[block], buf.at[slot, c], sems.at[kv, slot, c]
                 ).start()
 
-    def wait_stage(slot):
+    def wait_stage(slot, s, stage):
         for c in range(C):
             for cache, buf, kv in streams:
                 # A wait counts the destination's bytes; its source is
@@ -116,6 +169,95 @@ def _decode_kernel(
                 pltpu.make_async_copy(
                     cache.at[0], buf.at[slot, c], sems.at[kv, slot, c]
                 ).wait()
+
+    # R > 1: a stage is Cg = C // R groups of R table entries.  A group the
+    # table says is R ascending neighbours (whole_groups) is one region of
+    # the pool and goes in one DMA a side; any other goes page by page as
+    # above.  Either way the same bytes land at the same offsets of the
+    # stage's tile and signal the (side, slot)'s one semaphore, and a wait
+    # counts the destination's bytes, so what follows the wait does not
+    # know which way a group came.
+    #
+    # A stage whose groups are all whole (stage_whole) is Cg descriptors a
+    # side issued from a static unroll, no test a group, and one wait a side
+    # for the whole tile: the scalar work of a stage has to stay under the
+    # time its bytes take, and a loop with a branch a group does not (PERF.md
+    # section 6, PR 54).  Any other stage -- a broken group in it, or a row's
+    # last, whose table ends inside it -- takes the loop over the row's live
+    # groups; what lies behind them is masked, and its V pages are zeroed
+    # so that no stale page of another row multiplies the exact zeros of
+    # the masked probabilities.
+    Cg = C // R
+
+    def live_groups(s, stage):
+        nb = (ctx_lens_ref[s] + bs - 1) // bs
+        return jnp.clip((nb + R - 1) // R - stage * Cg, 0, Cg)
+
+    def start_group(slot, g, block):
+        for cache, buf, kv in streams:
+            pltpu.make_async_copy(
+                cache.at[pl.ds(block, R)], buf.at[slot, pl.ds(g * R, R)],
+                sems.at[kv, slot],
+            ).start()
+
+    def start_stage_grouped(slot, s, stage):
+        all_whole = stage_whole_ref[s, stage] != 0
+
+        @pl.when(all_whole)
+        def _():
+            for g in range(Cg):
+                start_group(slot, g, block_tables_ref[s, stage * C + g * R])
+
+        @pl.when(jnp.logical_not(all_whole))
+        def _():
+            nb = (ctx_lens_ref[s] + bs - 1) // bs
+            live = live_groups(s, stage)
+
+            def group(g, _):
+                gi = stage * Cg + g
+                whole = whole_ref[s, gi] != 0
+
+                @pl.when(whole)
+                def _():
+                    start_group(slot, g, block_tables_ref[s, gi * R])
+
+                @pl.when(jnp.logical_not(whole))
+                def _():
+                    for r in range(R):
+                        j = gi * R + r
+                        block = block_tables_ref[s, jnp.where(j < nb, j, 0)]
+                        for cache, buf, kv in streams:
+                            pltpu.make_async_copy(
+                                cache.at[block], buf.at[slot, g * R + r],
+                                sems.at[kv, slot],
+                            ).start()
+
+            jax.lax.fori_loop(0, live, group, None)
+
+            def dead(g, _):
+                at = (slot, pl.ds(g * R, R))
+                v_buf[at] = jnp.zeros_like(v_buf[at])
+
+            jax.lax.fori_loop(live, Cg, dead, None)
+
+    def wait_stage_grouped(slot, s, stage):
+        def wait(first, blocks):
+            for cache, buf, kv in streams:
+                pltpu.make_async_copy(
+                    cache.at[pl.ds(0, blocks)],
+                    buf.at[slot, pl.ds(first, blocks)], sems.at[kv, slot],
+                ).wait()
+
+        all_whole = stage_whole_ref[s, stage] != 0
+        pl.when(all_whole)(lambda: wait(0, C))
+
+        @pl.when(jnp.logical_not(all_whole))
+        def _():
+            jax.lax.fori_loop(0, live_groups(s, stage),
+                              lambda g, _: wait(g * R, R), None)
+
+    if R > 1:
+        start_stage, wait_stage = start_stage_grouped, wait_stage_grouped
 
     # Every stage the walk visits is started once, by the stage before it
     # (the first one here), and waited once: a DMA nobody waits for would
@@ -148,7 +290,7 @@ def _decode_kernel(
             def _():
                 start_stage(1 - slot, ahead, jnp.where(last, 0, i + 1))
 
-            wait_stage(slot)
+            wait_stage(slot, s, i)
             # [C, bs, K, D] -> [T*K, D]: merging leading dims into the
             # sublane dim is layout-free, D stays the lane dim.
             k = k_buf[slot].reshape(T * K, D)
@@ -198,7 +340,8 @@ def _decode_kernel(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("scale", "sliding_window", "chunk_blocks", "interpret"),
+    static_argnames=("scale", "sliding_window", "chunk_blocks",
+                     "group_blocks", "interpret"),
 )
 def paged_decode_attention_pallas(
     q: jax.Array,  # [S, H, D]
@@ -210,6 +353,7 @@ def paged_decode_attention_pallas(
     scale: float,
     sliding_window: Optional[int] = None,
     chunk_blocks: int = CHUNK_BLOCKS,
+    group_blocks: Optional[int] = None,
     interpret: bool = False,
 ) -> jax.Array:
     """Decode attention over paged KV, streaming blocks HBM->VMEM.
@@ -219,23 +363,45 @@ def paged_decode_attention_pallas(
     double-buffered pipeline and the dequantize (one VPU multiply per
     element) happens in VMEM — HBM traffic is the int8 bytes plus ~3%
     scales, the whole point of the mode.
+
+    A stage is ``chunk_blocks`` descriptors a side, each of
+    ``blocks_per_descriptor`` pages of the page this call is given.
+    ``group_blocks`` is for the tests alone, which name 1 (every page
+    alone, whatever the table says) to hold the grouped walk bit-equal to
+    the single-page one; nothing that serves passes it.
     """
     from production_stack_tpu.engine.kv import quant as kv_quant
 
     quantized = kv_quant.is_quantized(k_cache)
     S, H, D = q.shape
     N, bs, K, _ = kv_quant.cache_shape(k_cache)
-    C = min(chunk_blocks, block_tables.shape[1])
     if D % 128 and not interpret:
         # The DMA slice needs a 128-lane-aligned head_dim on real TPU;
         # dispatch (ops/attention.py) keeps such models on the gather
         # path.  Interpret mode (CPU tests) has no tiling constraint.
         raise ValueError(f"pallas decode kernel requires head_dim%128==0, got {D}")
+    itemsize = 1 if quantized else k_cache.dtype.itemsize
+    R = group_blocks or blocks_per_descriptor(
+        bs * K * D * itemsize, quantized)
+    R = min(R, N)  # a pool of a few blocks (tests) has no region of more
+    prefetch = [block_tables, ctx_lens]
+    # Blocks a stage: chunk_blocks descriptors of R pages, at most the table.
+    C = min(chunk_blocks * R, -(-block_tables.shape[1] // R) * R)
+    if R > 1:
+        # Whole groups and whole stages only: a table whose width the stage
+        # does not divide gains null entries, which no context reaches.
+        block_tables = jnp.pad(
+            block_tables, ((0, 0), (0, -block_tables.shape[1] % C)))
+        whole = whole_groups(block_tables, R)
+        prefetch = [
+            block_tables, ctx_lens, whole.astype(jnp.int32),
+            whole.reshape(S, -1, C // R).all(-1).astype(jnp.int32)]
 
     kernel = functools.partial(
         _decode_kernel,
         bs=bs,
         chunk_blocks=C,
+        group_blocks=R,
         num_kv_heads=K,
         scale=scale,
         sliding_window=sliding_window,
@@ -260,9 +426,12 @@ def paged_decode_attention_pallas(
             pltpu.VMEM((2, C, bs, K), k_cache[1].dtype),
             pltpu.VMEM((2, C, bs, K), v_cache[1].dtype),
         ]
-    scratch.append(pltpu.SemaphoreType.DMA((4 if quantized else 2, 2, C)))
+    # One semaphore a (stream, slot, page), or a (stream, slot) where
+    # descriptors carry groups and waits count a stage's bytes.
+    scratch.append(pltpu.SemaphoreType.DMA(
+        (len(cache_in_specs), 2, C) if R == 1 else (len(cache_in_specs), 2)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=len(prefetch),
         grid=(1,),  # one program walks the whole batch
         in_specs=[
             pl.BlockSpec((S, H, D), lambda i, *_: (0, 0, 0)),
@@ -284,4 +453,4 @@ def paged_decode_attention_pallas(
         # What the device trace calls the kernel (%<name>.N on XLA Ops):
         # the benchmark's readers find it by this name.
         name="paged_decode_attention_pallas",
-    )(block_tables, ctx_lens, *inputs)
+    )(*prefetch, *inputs)

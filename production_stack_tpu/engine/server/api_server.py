@@ -429,6 +429,13 @@ def build_engine_app(
             (vocab.TPU_STATE_RESUME_MISS, s["state_resume_misses"]),
             (vocab.TPU_STATE_RECOMPUTED_TOKENS,
              s["state_recomputed_tokens"]),
+            # Where one DMA of the paged decode walk carries a group of
+            # pages: groups held and groups that were one region of the
+            # pool (zero where a page is a descriptor of its own).
+            (vocab.TPU_PAGED_DECODE_GROUPS,
+             s["paged_decode_groups"]["total"]),
+            (vocab.TPU_PAGED_DECODE_GROUPS_COALESCED,
+             s["paged_decode_groups"]["coalesced"]),
             # Slice-group lifecycle (0 on single-host engines): the group
             # epoch steps on every group restart, and drain relays count
             # follower-initiated slice-wide drains (docs/robustness.md).

@@ -94,7 +94,11 @@ class WindowRecord:
     # Token counters, known on the host at dispatch.  ``kv_tokens``: KV
     # positions the decode rows attend, per row min(context, sliding
     # window) rounded up to whole blocks (what the paged kernel must
-    # read on the first step).  ``new_tokens`` / ``bucket_tokens``: prompt
+    # read on the first step).  ``kv_groups`` / ``kv_groups_coalesced``, on
+    # a decode batch built from host state where one DMA of that kernel
+    # carries several pages (paged_attention.py: blocks_per_descriptor):
+    # the groups of so many table entries its rows held / those that were
+    # one region of the pool.  ``new_tokens`` / ``bucket_tokens``: prompt
     # tokens really computed / token slots of the prefill or chunk program
     # that ran (the rest is padding).  ``cached_tokens``: tokens of those
     # prompts already in the KV cache and skipped.  ``kv_tiles_live`` /
@@ -105,6 +109,8 @@ class WindowRecord:
     # (scheduler.cover_prefill): [256, 256, 256], [256, 256], [256] are
     # one 600-token prompt.
     kv_tokens: int = 0
+    kv_groups: int = 0
+    kv_groups_coalesced: int = 0
     new_tokens: int = 0
     bucket_tokens: int = 0
     cached_tokens: int = 0
@@ -165,6 +171,9 @@ class WindowRecord:
         }
         if self.rows:
             d["kv_tokens"] = self.kv_tokens
+        if self.kv_groups:
+            d["kv_groups"] = self.kv_groups
+            d["kv_groups_coalesced"] = self.kv_groups_coalesced
         if self.bucket_tokens:
             d["new_tokens"] = self.new_tokens
             d["bucket_tokens"] = self.bucket_tokens

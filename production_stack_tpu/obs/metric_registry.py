@@ -411,6 +411,21 @@ REGISTRY = {
         "help": "Cached prompt tokens prefilled again between the snapshot "
                 "an admission started from and the deepest cached block",
     },
+    "tpu:paged_decode_groups_total": {
+        "kind": "counter", "layer": "engine",
+        "mirrors": ("dashboard", "docs"),
+        "help": "Groups of table entries the decode rows held, summed over "
+                "decode batches built from host state, where one DMA of the "
+                "paged decode kernel carries a group of small pages; zero "
+                "where a page is a descriptor of its own",
+    },
+    "tpu:paged_decode_groups_coalesced_total": {
+        "kind": "counter", "layer": "engine",
+        "mirrors": ("dashboard", "docs"),
+        "help": "Those of tpu:paged_decode_groups_total that were ascending "
+                "neighbours in the pool and went in one DMA a side; the "
+                "ratio falling says the pool has fragmented back to pages",
+    },
     "tpu:moe_experts_touched_total": {
         "kind": "counter", "layer": "engine",
         "mirrors": ("fake_engine", "dashboard", "docs"),

@@ -274,6 +274,13 @@ TPU_STATE_SNAPSHOTS_TAKEN = "tpu:state_snapshots_taken_total"
 TPU_STATE_RESUMES = "tpu:state_resumes_total"
 TPU_STATE_RESUME_MISS = "tpu:state_resume_miss_total"
 TPU_STATE_RECOMPUTED_TOKENS = "tpu:state_recomputed_tokens_total"
+# Where one DMA of the paged decode kernel carries a group of small pages
+# (engine/ops/pallas/paged_attention.py: blocks_per_descriptor > 1): the
+# groups the decode rows' tables held, and those that were ascending
+# neighbours in the pool and went in one DMA a side.  Zero where a page is a
+# descriptor of its own.
+TPU_PAGED_DECODE_GROUPS = "tpu:paged_decode_groups_total"
+TPU_PAGED_DECODE_GROUPS_COALESCED = "tpu:paged_decode_groups_coalesced_total"
 # Step-thread phases (obs.engine.PHASES) that lasted over a second: every
 # stream stood still for as long.  One WARNING line each names the window.
 TPU_STEP_STALL = "tpu:step_stall_total"

@@ -317,6 +317,20 @@ def test_the_dense_kernels_compile_at_jambas_geometry(sds, no_persistent_cache):
     # The 2 GB of pages reach the kernel as they lie: a bitcast, no copy.
     assert not [line for line in text.splitlines()
                 if " copy(" in line and "bf16[500000," in line.split("=")[1][:60]]
+    # What compiled is the grouped stage (paged_attention.py: eight 4 kB
+    # pages a descriptor, 16 descriptors a side = 128 blocks a stage, two
+    # slots of 512 kB a side in VMEM): the kernel takes a flag a group of
+    # the table and a flag a stage beside the table and the contexts.
+    from production_stack_tpu.engine.ops.pallas.paged_attention import (
+        CHUNK_BLOCKS, blocks_per_descriptor,
+    )
+    R = blocks_per_descriptor(BS * K * hd * 2)
+    bmax = cfg.max_model_len // BS
+    assert (R, CHUNK_BLOCKS) == (8, 16)
+    call = next(line for line in text.splitlines()
+                if "tpu_custom_call" in line and "custom-call(" in line)
+    assert f"s32[16,{bmax // R}]" in call
+    assert f"s32[16,{bmax // (R * CHUNK_BLOCKS)}]" in call
     for T in (256, 2048):
         new = sds((T, K, hd), jnp.bfloat16)
         prefix = sds((cfg.max_model_len, K, hd), jnp.bfloat16)

@@ -64,7 +64,11 @@ def _live(ctx):
 )
 @pytest.mark.parametrize("interpret", [True, TPU_INTERPRET],
                          ids=["interpret", "tpu-interpret"])
-def test_pallas_decode_matches_gather(ctx_lens, interpret):
+# 1: every page a DMA of its own, a stage of 4 blocks as the cases were
+# written for; None: what the page's 8 kB give (4 pages a DMA, a stage of
+# 16 blocks), the tables being ascending neighbours.
+@pytest.mark.parametrize("group_blocks", [1, None], ids=["single", "grouped"])
+def test_pallas_decode_matches_gather(ctx_lens, interpret, group_blocks):
     S, H, K, D, bs = 4, 8, 2, 64, 16
     q, k_cache, v_cache, tables, ctx = _random_paged_case(
         0, S, H, K, D, bs, num_blocks=64, max_blocks=24, ctx_lens=ctx_lens
@@ -75,7 +79,7 @@ def test_pallas_decode_matches_gather(ctx_lens, interpret):
     )
     got = paged_decode_attention_pallas(
         q, k_cache, v_cache, tables, ctx, scale=scale, chunk_blocks=4,
-        interpret=interpret,
+        group_blocks=group_blocks, interpret=interpret,
     )
     live = _live(ctx)
     np.testing.assert_allclose(
@@ -139,6 +143,8 @@ def test_pallas_decode_bf16_cache_matches_gather(ctx_lens, window):
     ],
 )
 def test_pallas_decode_sliding_window(ctx_lens, window, chunk_blocks):
+    # Every page a DMA of its own: the stages are the cases' (a 2 kB page
+    # would otherwise go 16 to a DMA and the whole table be one stage).
     S, H, K, D, bs = 2, 4, 2, 32, 8
     q, k_cache, v_cache, tables, ctx = _random_paged_case(
         1, S, H, K, D, bs, num_blocks=32, max_blocks=8, ctx_lens=ctx_lens
@@ -149,11 +155,20 @@ def test_pallas_decode_sliding_window(ctx_lens, window, chunk_blocks):
     )
     got = paged_decode_attention_pallas(
         q, k_cache, v_cache, tables, ctx, scale=scale, sliding_window=window,
-        chunk_blocks=chunk_blocks, interpret=True,
+        chunk_blocks=chunk_blocks, group_blocks=1, interpret=True,
     )
     live = _live(ctx)
     np.testing.assert_allclose(
         np.asarray(got)[live], np.asarray(want)[live], rtol=2e-5, atol=2e-5
+    )
+    # And with the groups the page gives: one stage, the same numbers.
+    grouped = paged_decode_attention_pallas(
+        q, k_cache, v_cache, tables, ctx, scale=scale, sliding_window=window,
+        chunk_blocks=chunk_blocks, interpret=True,
+    )
+    np.testing.assert_allclose(
+        np.asarray(grouped)[live], np.asarray(want)[live],
+        rtol=2e-5, atol=2e-5,
     )
 
 
@@ -175,6 +190,275 @@ def test_pallas_decode_gqa_head_mapping():
     np.testing.assert_allclose(out[0, 1], 1.0, atol=1e-6)
     np.testing.assert_allclose(out[0, 2], -1.0, atol=1e-6)  # kv head 1
     np.testing.assert_allclose(out[0, 3], -1.0, atol=1e-6)
+
+
+# -- groups of pages in one DMA (one key head: a 4 kB page) -------------------
+
+from production_stack_tpu.engine.ops.pallas.paged_attention import (
+    blocks_per_descriptor,
+    whole_groups,
+)
+
+# jamba2-3b's attention layers: 20 query heads over ONE key head of 128,
+# 16-token pages of 4 kB, eight to a descriptor.  Small stages (2 groups =
+# 16 blocks = 256 positions) so that rows span several.
+MQ = dict(H=20, K=1, D=128, bs=16)
+MQ_R, MQ_CHUNK = 8, 2
+MQ_POOL = 1200
+
+
+def _runs(rng, nb, lo, hi, free):
+    """``nb`` table entries as runs of ascending neighbours, each run's
+    length drawn from [lo, hi] and its place among the ``free`` ids."""
+    out = []
+    while len(out) < nb:
+        n = min(int(rng.integers(lo, hi + 1)), nb - len(out))
+        while True:
+            b = int(rng.integers(1, MQ_POOL - n + 1))
+            if all(i in free for i in range(b, b + n)):
+                break
+        free.difference_update(range(b, b + n))
+        out.extend(range(b, b + n))
+    return out
+
+
+def _layout_tables(layout):
+    """(tables [S, Bmax], ctx [S]): one way a row's blocks can lie in the
+    pool.  A stage is 16 table entries, a group 8."""
+    rng = np.random.default_rng(7)
+    bs, N = MQ["bs"], MQ_POOL
+    blocks = lambda tokens: -(-tokens // bs)
+    ctx = [700, 0, 333, 1000]                   # 44, 0, 21, 63 blocks
+    if layout == "adjacent":
+        # Ascending neighbours, as the pool hands them out.
+        ctx = [700, 512, 333, 1000]
+        rows = [range(1, 45), range(50, 82), range(100, 121),
+                range(200, 263)]
+    elif layout == "padded_row":
+        # The same with a padded row between live ones.
+        rows = [range(1, 45), [], range(100, 121), range(200, 263)]
+    elif layout == "descending":
+        rows = [range(44, 0, -1), [], range(120, 99, -1), range(262, 199, -1)]
+    elif layout == "permuted":
+        ids = rng.permutation(np.arange(1, N)).tolist()
+        rows = [ids[:44], [], ids[44:65], ids[65:128]]
+    elif layout == "mixed":
+        ctx = [6000, 0, 4100]                   # runs of 1-200 blocks
+        free = set(range(1, N))
+        rows = [_runs(rng, blocks(c), 1, 200, free) for c in ctx]
+    elif layout == "offset":
+        # A 62-block prefix every row shares, then the row's own run: the
+        # group of entries 56..63 straddles the two.
+        ctx = [70 * bs + 5, 0, 62 * bs + 9, 100 * bs]
+        shared = list(range(900, 962))
+        rows = [shared + list(range(1, 10)), [],
+                shared + [300], shared + list(range(400, 438))]
+    elif layout == "cross_stage":
+        # Ten blocks from anywhere, then one run over entries 10..: it
+        # starts inside group 1 and crosses the stage boundary at entry 16.
+        ctx = [30 * bs, 0, 0, 40 * bs - 7]
+        head = rng.permutation(np.arange(600, 700)).tolist()
+        rows = [head[:10] + list(range(50, 70)), [], [],
+                head[10:20] + list(range(150, 180))]
+    elif layout == "ctx_in_group":
+        # The table is allocated to the end of the group the context ends
+        # in: the merged copy brings pages past the context, which the mask
+        # drops.  Contexts end on a group's last block, its first, inside.
+        ctx = [21 * bs + 3, 24 * bs, 16 * bs + 1, 9]
+        rows = [range(1, 25), range(100, 124), range(200, 224),
+                range(300, 308)]
+    elif layout == "pool_end":
+        # The pool's last region as one group (ids N-8 .. N-1), and a run
+        # that ends on the last block in the middle of a group (its group
+        # would need id N to be whole).
+        ctx = [24 * bs - 5, 0, 20 * bs, 0]
+        rows = [list(range(1, 17)) + list(range(N - 8, N)), [],
+                list(range(500, 516)) + list(range(N - 4, N)), []]
+    else:
+        raise ValueError(layout)
+    bmax = -(-max(blocks(c) for c in ctx) // 16) * 16 + 16
+    tables = np.zeros((len(ctx), bmax), np.int32)
+    for s, ids in enumerate(rows):
+        ids = list(ids)
+        assert len(ids) >= blocks(ctx[s]), (layout, s)
+        tables[s, :len(ids)] = ids
+    return tables, np.asarray(ctx, np.int32)
+
+
+@pytest.mark.parametrize(
+    "layout",
+    ["adjacent", "descending", "permuted", "mixed", "offset", "cross_stage",
+     "ctx_in_group", "padded_row", "pool_end"],
+)
+def test_grouped_walk_is_the_single_page_walk_bit_for_bit(layout):
+    """However a row's blocks lie in the pool, the walk that fetches whole
+    groups in one DMA gives the bits of the walk that fetches every page
+    alone (``group_blocks=1``, the same stage), and both agree with the
+    gather path."""
+    H, K, D, bs = MQ["H"], MQ["K"], MQ["D"], MQ["bs"]
+    tables, ctx = _layout_tables(layout)
+    assert tables.max() < MQ_POOL
+    rng = np.random.default_rng(11)
+    S = len(ctx)
+    q = jnp.asarray(rng.standard_normal((S, H, D)), jnp.bfloat16)
+    k_cache = jnp.asarray(
+        rng.standard_normal((MQ_POOL, bs, K, D)), jnp.bfloat16)
+    v_cache = jnp.asarray(
+        rng.standard_normal((MQ_POOL, bs, K, D)), jnp.bfloat16)
+    args = (q, k_cache, v_cache, jnp.asarray(tables), jnp.asarray(ctx))
+    scale = D ** -0.5
+    assert blocks_per_descriptor(bs * K * D * 2) == MQ_R
+    whole = np.asarray(whole_groups(tables, MQ_R, xp=np))
+    live = np.arange(whole.shape[1]) < -(-ctx // (bs * MQ_R))[:, None]
+    share = (whole & live).sum() / max(live.sum(), 1)
+    # Each layout takes the path it is here for.
+    if layout in ("adjacent", "padded_row", "ctx_in_group"):
+        assert share > 0.8
+    elif layout in ("descending", "permuted"):
+        assert share == 0
+    else:
+        assert 0 < share < 1
+
+    got = paged_decode_attention_pallas(
+        *args, scale=scale, chunk_blocks=MQ_CHUNK, interpret=TPU_INTERPRET)
+    single = paged_decode_attention_pallas(
+        *args, scale=scale, chunk_blocks=MQ_CHUNK * MQ_R, group_blocks=1,
+        interpret=TPU_INTERPRET)
+    np.testing.assert_array_equal(
+        np.asarray(got, np.float32), np.asarray(single, np.float32))
+    want = paged_decode_attention(*args, scale=scale)
+    rows = _live(ctx)
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32)[rows], np.asarray(want, np.float32)[rows],
+        rtol=2**-7, atol=2**-7,
+    )
+    assert np.all(np.isfinite(np.asarray(got, np.float32)))
+
+
+def test_whole_groups_is_the_rule_entry_by_entry():
+    rng = np.random.default_rng(3)
+    R = 4
+    tables = rng.integers(0, 40, (6, 24)).astype(np.int32)
+    tables[0, 4:8] = [9, 10, 11, 12]          # whole
+    tables[1, 0:4] = [0, 1, 2, 3]             # starts at the null block
+    tables[2, 8:12] = [5, 6, 7, 0]            # its last entry not allocated
+    tables[3, 12:16] = [20, 21, 23, 24]       # a gap
+    tables[4, 16:20] = [33, 32, 31, 30]       # descending
+    want = np.zeros((6, 6), bool)
+    for s in range(6):
+        for g in range(6):
+            t = tables[s, g * R:(g + 1) * R]
+            want[s, g] = all(t[i] == t[0] + i for i in range(R)) and t[-1] != 0
+    assert want[0, 1] and want[1, 0] and not want[2, 2]
+    assert not want[3, 3] and not want[4, 4]
+    np.testing.assert_array_equal(whole_groups(tables, R, xp=np), want)
+    np.testing.assert_array_equal(
+        np.asarray(whole_groups(jnp.asarray(tables), R)), want)
+
+
+@pytest.mark.parametrize(
+    "bs,K,D,itemsize,quantized,want",
+    [
+        (16, 1, 128, 2, False, 8),    # jamba2-3b: 4 kB a page
+        (16, 2, 128, 2, False, 4),    # mistral-7b under tp=4: 8 kB
+        (16, 8, 128, 2, False, 1),    # mistral-7b, solar: 32 kB
+        (16, 8, 128, 4, False, 1),    # a page over 32 kB
+        (16, 1, 128, 1, True, 1),     # an int8 (data, scale) cache
+    ],
+)
+def test_blocks_per_descriptor(bs, K, D, itemsize, quantized, want):
+    assert blocks_per_descriptor(bs * K * D * itemsize, quantized) == want
+
+
+def test_a_32kb_page_lowers_to_the_single_page_walk():
+    """At eight key heads a page is a descriptor of its own: the kernel
+    takes the block table and the contexts as its only scalar operands (no
+    flags are computed or passed) and a stage is 16 blocks -- the program
+    mistral-7b and solar ran before groups existed."""
+    import jax
+
+    H, K, D, bs = 32, 8, 128, 16
+    cache = jax.ShapeDtypeStruct((64, bs, K, D), jnp.bfloat16)
+
+    def call(K):
+        cache = jax.ShapeDtypeStruct((64, bs, K, D), jnp.bfloat16)
+        return jax.make_jaxpr(
+            lambda q, k, v, bt, cl: paged_decode_attention_pallas(
+                q, k, v, bt, cl, scale=D ** -0.5, interpret=True)
+        )(jax.ShapeDtypeStruct((4, H, D), jnp.bfloat16), cache, cache,
+          jax.ShapeDtypeStruct((4, 64), jnp.int32),
+          jax.ShapeDtypeStruct((4,), jnp.int32))
+
+    def pallas_eqn(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                return eqn, jaxpr
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                found = pallas_eqn(sub)
+                if found:
+                    return found
+
+    eqn, holder = pallas_eqn(call(8).jaxpr)
+    assert len(eqn.invars) == 5                 # tables, contexts, q, k, v
+    assert [e.primitive.name for e in holder.eqns] == ["pallas_call"]
+    k_buf = eqn.params["grid_mapping"].scratch_avals[0] if hasattr(
+        eqn.params["grid_mapping"], "scratch_avals") else None
+    if k_buf is not None:
+        assert k_buf.shape[:2] == (2, 16)
+    grouped, _ = pallas_eqn(call(1).jaxpr)
+    assert len(grouped.invars) == 7             # + groups' and stages' flags
+
+
+def test_the_engine_counts_groups_by_the_kernels_rule():
+    """``kv_groups`` / ``kv_groups_coalesced`` on a decode window's flight
+    record and /metrics' two counters: the groups the rows' tables hold and
+    those that ``whole_groups`` calls one region, counted from the batch's
+    numpy tables where a descriptor carries more than a page -- and zero
+    where it carries one (every CPU engine: the kernel does not serve)."""
+    from production_stack_tpu.engine.config import config_from_preset
+    from production_stack_tpu.engine.core.engine import LLMEngine
+    from production_stack_tpu.engine.core.sequence import SamplingParams
+
+    config = config_from_preset(
+        "tiny-llama",
+        **{"cache.num_blocks": 64, "scheduler.max_num_seqs": 2,
+           "scheduler.prefill_buckets": (16, 32, 64)},
+    )
+    eng = LLMEngine(config)
+    assert eng._kv_group_blocks == 1 and eng.block_pool.run == 1
+    assert eng.stats()["paged_decode_groups"] == {"total": 0, "coalesced": 0}
+    # As a TPU engine of a model with a small page would be set: 2 pages a
+    # descriptor (the pool's hand-out is already ascending).
+    eng._kv_group_blocks = 2
+
+    def run(rid, n):
+        eng.add_request(rid, prompt_token_ids=list(range(3, 3 + n)),
+                        sampling_params=SamplingParams(
+                            max_tokens=3, ignore_eos=True))
+        while eng.has_unfinished():
+            eng.step()
+
+    run("a", 40)        # 3 blocks: one whole group and a half
+    records = [w for w in eng.obs.windows_payload()["windows"]
+               if w.get("kv_groups")]
+    assert records
+    for w in records:
+        assert 0 < w["kv_groups_coalesced"] <= w["kv_groups"]
+        assert w["kv_groups"] == -(-w["kv_tokens"] // (16 * 2))
+    got = eng.stats()["paged_decode_groups"]
+    assert got["total"] >= sum(w["kv_groups"] for w in records)
+    assert 0 < got["coalesced"] < got["total"]
+
+    # The rule itself, on a batch by hand: row 0 two whole groups and a
+    # half one, row 1 a broken group, row 2 padding.
+    tables = np.zeros((3, 8), np.int32)
+    tables[0, :5] = [4, 5, 6, 7, 9]
+    tables[1, :2] = [11, 10]
+    before = dict(eng.paged_decode_groups)
+    eng._count_kv_groups(tables, np.asarray([16 * 4 + 3, 20, 0], np.int32))
+    assert eng._last_kv_groups == (3 + 1, 2)
+    assert eng.paged_decode_groups == {
+        "total": before["total"] + 4, "coalesced": before["coalesced"] + 2}
 
 
 # -- flash prefill kernel ---------------------------------------------------

@@ -12,8 +12,9 @@ from production_stack_tpu.engine.kv.state_pool import StatePool, pool_slots
 BS, STRIDE = 4, 8
 
 
-def make(num_blocks=64, max_num_seqs=4, live=6, snapshots=4, caching=True):
-    blocks = BlockPool(num_blocks, BS, enable_prefix_caching=caching)
+def make(num_blocks=64, max_num_seqs=4, live=6, snapshots=4, caching=True,
+         run=1):
+    blocks = BlockPool(num_blocks, BS, enable_prefix_caching=caching, run=run)
     states = StatePool(live, snapshots)
     cfg = SchedulerConfig(
         max_num_seqs=max_num_seqs, prefill_buckets=(16, 32),
@@ -202,6 +203,28 @@ def test_a_deeper_match_is_cut_back_to_a_shallower_snapshot():
     # The cut blocks went back to the pool: b's own hold those positions.
     assert len(b.block_table) == 15
     assert blocks.num_free_blocks == 63 - 15
+
+
+@pytest.mark.parametrize("run", [1, 8])
+def test_a_sessions_chunks_and_growth_go_on_after_its_last_block(run):
+    """The scheduler names the taker's last block to the pool: a prompt's
+    second chunk goes on after its first, and decode growth after that, a
+    block a turn beside another session's.  At ``run`` 8 every full group
+    of each table is eight neighbours, which the decode kernel fetches in
+    one DMA; at ``run`` 1 the pool knows no runs and the tables interleave."""
+    sched, blocks, _ = make(num_blocks=512, run=run)
+    a, b = seq("a", range(100, 143)), seq("b", range(300, 343))
+    for s in (a, b):
+        assert len(prefill(sched, s)) == 2
+    for _ in range(6):
+        for s in (a, b):
+            sched._grow(s, 1)
+    for s in (a, b):
+        table = s.block_table
+        assert len(table) == 17
+        whole = all(table[j:j + 8] == list(range(table[j], table[j] + 8))
+                    for j in (0, 8))
+        assert whole == (run == 8)
 
 
 def test_a_snapshot_dies_with_its_block():

@@ -14,6 +14,21 @@ call, beside the share of 819 GB/s that is for the K and V bytes
 128 positions of one row (524,288 bytes: 0.64 µs at 819 GB/s).  The first
 case is also compared with the gather path, once.  A CPU run refuses to
 time anything.
+
+Another geometry and another pool (PR 54), e.g. jamba2-3b's two attention
+layers as cell 6 serves them:
+
+    ... --heads 20 --kv-heads 1 --window 0 --pool-blocks 525184 \
+        --max-len 32768 --rows 16 --context 24000 --layout adjacent
+
+``--layout`` says how a row's blocks lie in the pool: ``permuted`` (the
+default: no two neighbours), ``adjacent`` (a row's blocks ascend one by
+one, as the pool hands them out), ``mixed:<share>`` (that share of the
+table's groups are single blocks from anywhere, the rest neighbours; a
+group is what 32 kB holds of the geometry's pages).  Every line carries
+``coalesced_pct``, the share of the live rows' groups that one descriptor
+can fetch, by the kernel's own rule where ``--root`` has it.  ``--rows`` and
+``--context`` replace the default cases by one.
 """
 
 from __future__ import annotations
@@ -49,23 +64,72 @@ CASES = [
 ]
 
 
-def make_case(rng: np.random.Generator, S: int, contexts):
+def group_blocks() -> int:
+    """Pages of this geometry that one descriptor of the kernel carries:
+    the root's own rule (``paged_attention.py: blocks_per_descriptor``), or
+    what 32 kB holds for a root from before the rule, so that a parent's
+    lines carry ``coalesced_pct`` for their tables too."""
+    page_bytes = BS * K * D * 2
+    try:
+        from production_stack_tpu.engine.ops.pallas.paged_attention import (
+            blocks_per_descriptor,
+        )
+    except ImportError:
+        return max(1, 32 * 1024 // page_bytes)
+    return blocks_per_descriptor(page_bytes)
+
+
+def make_case(rng: np.random.Generator, S: int, contexts, layout="permuted"):
     ctx = np.zeros(S, np.int32)
     ctx[:len(contexts)] = contexts
     tables = np.zeros((S, MAX_LEN // BS), np.int32)
-    free = rng.permutation(np.arange(1, POOL_BLOCKS))  # 0 is the null block
-    used = 0
-    for s, c in enumerate(ctx):
-        nb = -(-int(c) // BS)
-        tables[s, :nb] = free[used:used + nb]
-        used += nb
+    R = group_blocks()
+    share = {"permuted": 1.0, "adjacent": 0.0}.get(layout)
+    if share is None:
+        share = float(layout.partition("mixed:")[2])
+    # (row, first entry, entries, singles?) a group of the tables.
+    groups = [(s, j0, min(R, nb - j0), share == 1.0
+               or (share > 0.0 and rng.random() < share))
+              for s, c in enumerate(ctx)
+              for nb in [-(-int(c) // BS)] for j0 in range(0, nb, R)]
+    # Runs ascend from block 1 (0 is the null block), one after another;
+    # singles are drawn from a permutation of what the runs leave.
+    in_runs = sum(n for *_, n, single in groups if not single)
+    free = rng.permutation(np.arange(1 + in_runs, POOL_BLOCKS))
+    used, next_run = 0, 1
+    for s, j0, n, single in groups:
+        if single:
+            tables[s, j0:j0 + n] = free[used:used + n]
+            used += n
+        else:
+            tables[s, j0:j0 + n] = np.arange(next_run, next_run + n)
+            next_run += n
     assert used <= len(free), "the case does not fit the pool"
     return tables, ctx
 
 
-def run(kernel, root: str, iters: int) -> None:
+def coalesced_pct(tables: np.ndarray, ctx: np.ndarray) -> float:
+    """Share of the live rows' groups that are R ascending neighbours, by
+    the root's ``whole_groups`` where it has one."""
+    R = group_blocks()
+    S, bmax = tables.shape
+    t = tables[:, :bmax // R * R]
+    try:
+        from production_stack_tpu.engine.ops.pallas.paged_attention import (
+            whole_groups,
+        )
+        whole = whole_groups(t, R, xp=np)
+    except ImportError:
+        t = t.reshape(S, -1, R)
+        whole = (t == t[..., :1] + np.arange(R)).all(-1) & (t[..., -1] != 0)
+    groups = -(-(-(-ctx // BS)) // R)
+    live = np.arange(whole.shape[1])[None, :] < groups[:, None]
+    return round(100.0 * (whole & live).sum() / max(live.sum(), 1), 2)
+
+
+def run(kernel, root: str, iters: int, cases, layout: str) -> None:
     """Time ``kernel`` (the signature of ``paged_decode_attention_pallas``)
-    over CASES and print a line a case, then the fit."""
+    over ``cases`` and print a line a case, then the fit."""
     import jax
     import jax.numpy as jnp
     from reduce.kv_bytes import kv_bytes_per_token
@@ -78,6 +142,7 @@ def run(kernel, root: str, iters: int) -> None:
         sys.exit(f"no TPU here ({dev.platform}): a CPU run times nothing")
 
     kw = dict(scale=D ** -0.5, sliding_window=WINDOW)
+    window = WINDOW or MAX_LEN
     bytes_per_token = kv_bytes_per_token(
         {"num_hidden_layers": 1, "num_key_value_heads": K, "head_dim": D})
 
@@ -94,12 +159,13 @@ def run(kernel, root: str, iters: int) -> None:
     v_cache = jax.random.normal(keys[1], pool, jnp.bfloat16)
 
     rows = []
-    for name, S, contexts in CASES:
-        tables, ctx = make_case(rng, S, contexts)
+    for name, S, contexts in cases:
+        tables, ctx = make_case(rng, S, contexts, layout)
         q = jax.random.normal(keys[2], (S, H, D), jnp.bfloat16)
         a = (q, k_cache, v_cache, jnp.asarray(tables), jnp.asarray(ctx))
         line = {"root": root, "device": dev.device_kind, "case": name,
-                "S": S, "live_rows": len(contexts)}
+                "S": S, "live_rows": len(contexts), "layout": layout,
+                "coalesced_pct": coalesced_pct(tables, ctx)}
         if not rows:
             got = kernel(*a, **kw)
             want = paged_decode_attention(*a, **kw)
@@ -116,7 +182,7 @@ def run(kernel, root: str, iters: int) -> None:
         out.block_until_ready()
         us = (time.perf_counter() - t0) / iters / LAYERS * 1e6
         # What kv_bytes.py counts: min(context, window) in whole blocks.
-        kv_tokens = sum(-(-min(c, WINDOW) // BS) * BS for c in contexts)
+        kv_tokens = sum(-(-min(c, window) // BS) * BS for c in contexts)
         chunks = sum(-(-c // CHUNK) for c in contexts)
         line.update({
             "positions": sum(contexts), "chunks": chunks,
@@ -127,6 +193,8 @@ def run(kernel, root: str, iters: int) -> None:
         rows.append(line)
         print(json.dumps(line), flush=True)
 
+    if len(rows) < 3:
+        return  # one case: nothing to fit
     x = np.array([[r["chunks"], r["live_rows"], 1.0] for r in rows])
     y = np.array([r["us_per_call"] for r in rows])
     (a_us, b_us, c_us), *_ = np.linalg.lstsq(x, y, rcond=None)
@@ -142,11 +210,28 @@ def run(kernel, root: str, iters: int) -> None:
 
 
 def main() -> None:
+    global H, K, WINDOW, POOL_BLOCKS, MAX_LEN
     here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     ap = argparse.ArgumentParser()
     ap.add_argument("--root", default=here)
     ap.add_argument("--iters", type=int, default=20)
+    ap.add_argument("--heads", type=int, default=H)
+    ap.add_argument("--kv-heads", type=int, default=K)
+    ap.add_argument("--window", type=int, default=WINDOW,
+                    help="sliding window; 0 for none")
+    ap.add_argument("--pool-blocks", type=int, default=POOL_BLOCKS)
+    ap.add_argument("--max-len", type=int, default=MAX_LEN)
+    ap.add_argument("--layout", default="permuted",
+                    help="permuted | adjacent | mixed:<share of single blocks>")
+    ap.add_argument("--rows", type=int, default=16)
+    ap.add_argument("--context", type=int, default=None,
+                    help="one case of --rows rows at this context each")
     args = ap.parse_args()
+    H, K, WINDOW = args.heads, args.kv_heads, args.window or None
+    POOL_BLOCKS, MAX_LEN = args.pool_blocks, args.max_len
+    cases = CASES if args.context is None else [
+        (f"{args.rows}x{args.context}", args.rows,
+         [args.context] * args.rows)]
     sys.path.insert(0, os.path.abspath(args.root))
     # The benchmark's own arithmetic, imported the way bench/run.py does.
     sys.path.append(os.path.join(here, "bench"))
@@ -154,7 +239,8 @@ def main() -> None:
         paged_decode_attention_pallas,
     )
 
-    run(paged_decode_attention_pallas, args.root, args.iters)
+    run(paged_decode_attention_pallas, args.root, args.iters, cases,
+        args.layout)
 
 
 if __name__ == "__main__":
