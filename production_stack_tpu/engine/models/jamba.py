@@ -67,7 +67,7 @@ from production_stack_tpu.engine.models import llama
 # cache_bytes_per_token is the engine's to ask: the ``gqa`` layers' K and V.
 from production_stack_tpu.engine.models.solar_kda import (  # noqa: F401
     _blocks, _dot, _gqa_decode, _gqa_prefill, _kinds, _pallas_serves,
-    cache_bytes_per_token, default_slot, layer_kind,
+    cache_bytes_per_token, default_slot, layer_kind, rows_pool_shape,
 )
 from production_stack_tpu.engine.ops import attention as attn_ops
 from production_stack_tpu.engine.ops.layers import rms_norm
@@ -109,8 +109,8 @@ def state_bytes_per_slot(cfg: ModelConfig) -> int:
 def init_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
                sharding=None, state_slots: Optional[int] = None):
     """One tree: a layer's ``(K, V)`` pages ``[num_blocks, block_size, kv
-    heads, head_dim]`` or its ``(state [slots, N, Di] float32, conv [slots,
-    K - 1, Di])`` slots."""
+    heads, head_dim]`` or its ``(state [slots, N, Di] float32, conv
+    ``solar_kda.rows_pool_shape`` of K - 1 rows of Di)`` slots."""
     slots = state_slots or DEFAULT_STATE_SLOTS
     dtype = jnp.dtype(cfg.dtype)
 
@@ -121,7 +121,8 @@ def init_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
     return [
         (zeros(page, dtype), zeros(page, dtype)) if kind == "gqa" else
         (zeros((slots, cfg.mamba_d_state, _inner(cfg)), jnp.float32),
-         zeros((slots, cfg.mamba_d_conv - 1, _inner(cfg)), dtype))
+         zeros(rows_pool_shape(slots, cfg.mamba_d_conv - 1, _inner(cfg)),
+               dtype))
         for kind in _kinds(cfg)]
 
 
@@ -325,7 +326,7 @@ def _mamba_prefill(layer, cfg, cache, x, live, valid_len, slots):
     T, K, Di = x.shape[0], cfg.mamba_d_conv, _inner(cfg)
     fresh = start < 0
     s0 = jnp.where(fresh, 0.0, state[jnp.maximum(start, 0)])
-    c0 = jnp.where(fresh, 0, conv[jnp.maximum(start, 0)])
+    c0 = jnp.where(fresh, 0, conv[jnp.maximum(start, 0)]).reshape(K - 1, Di)
     uz = _dot(x, layer["in_proj"]).astype(x.dtype)
     u, z = uz[:, :Di], uz[:, Di:]
     full = jnp.concatenate([c0, u], axis=0)                # [K - 1 + T, Di]
@@ -341,7 +342,8 @@ def _mamba_prefill(layer, cfg, cache, x, live, valid_len, slots):
             c.astype(jnp.float32), dt, z.astype(jnp.float32), B, C,
             layer["A_log"], layer["D"], s0,
             None if snap_slot is None else snap_len)
-    rows = lambda at: jax.lax.dynamic_slice_in_dim(full, at, K - 1, axis=0)
+    rows = lambda at: jax.lax.dynamic_slice_in_dim(
+        full, at, K - 1, axis=0).reshape(conv.shape[1:])
     if snap_slot is not None:
         state = state.at[snap_slot].set(snap)
         conv = conv.at[snap_slot].set(rows(snap_len))
@@ -356,7 +358,9 @@ def _mamba_decode(layer, cfg, cache, x, live, slots):
     Di = _inner(cfg)
     uz = _dot(x, layer["in_proj"]).astype(x.dtype)
     u, z = uz[:, :Di], uz[:, Di:]
-    window = jnp.concatenate([conv[slots], u[:, None]], axis=1)
+    R = u.shape[0]
+    window = jnp.concatenate(
+        [conv[slots].reshape(R, -1, Di), u[:, None]], axis=1)
     c = _convolved(layer, cfg, [window[:, j] for j in range(window.shape[1])])
     dt, B, C = _selective(layer, cfg, c, live)
     args = (c.astype(jnp.float32), dt, z.astype(jnp.float32), B, C,
@@ -372,8 +376,9 @@ def _mamba_decode(layer, cfg, cache, x, live, slots):
             y, rows = ssm_step_plain(*args, state[slots])
             state = state.at[slots].set(rows)
             absmax = jnp.max(jnp.abs(rows), axis=1)
-    conv = conv.at[slots].set(
-        jnp.where(live[:, None, None], window[:, 1:], window[:, :-1]))
+    conv = conv.at[slots].set(jnp.where(
+        live[:, None, None], window[:, 1:], window[:, :-1]).reshape(
+            R, *conv.shape[1:]))
     stats = _ssm_stats(jnp.where(live[:, None], absmax, 0.0), dt)
     return y.astype(x.dtype), (state, conv), stats
 

@@ -61,6 +61,21 @@ the step programs (``core/step_programs.py``) carry without looking inside.
   row's live slot, through ``step_programs.window_program`` too).  ``cache_
   bytes_per_token`` counts the layers with keys alone.  A padded slot of a
   chunk and a dead row of a decode batch must be the identity on the state.
+  **A dispatch touches the slots it names and nothing else of a pool**: the
+  module reads and writes slots where they lie, so that the compiled
+  ``prefill_fn`` / ``window_fn`` hold no copy of a pool.  A state goes into a
+  kernel and comes out of it in the pool's own orientation, or the pool is
+  aliased through the kernel; and a slot is whole tiles of the device's memory,
+  as a page of keys is: a slot's few rows of another kind -- the convolution's
+  last ``kernel - 1`` -- lie one after another in lines of 128 channels,
+  ``solar_kda.rows_pool_shape``: ``[slots, rows x width / 128, 128]`` (a
+  ``[slots, 3, width]`` array is kept rows-outermost at a program's boundary
+  and slots-outermost inside it, one relayout in and one out of the whole
+  pool a layer a dispatch; in ``[slots, 3 x width]`` a slot is a sub-tile line
+  and every scatter stages the pool or rewrites tiles row by row).
+  ``tests/test_chip_compile.py: test_a_state_models_served_programs_copy_no_pool``
+  compiles both modules' served programs for a described v5e and is what holds
+  a third such module to it: add the preset to its ``STATE_MODELS``.
   **The default the benchmark's compare relies on**: ``bench/harness/
   compare.py`` calls both steps with the cache ``init_cache(cfg, blocks,
   block_size, sharding)`` returned and nothing else, so where no slot is

@@ -110,6 +110,20 @@ def _conv_width(cfg: ModelConfig) -> int:
     return 3 * cfg.linear_num_heads * cfg.linear_head_dim
 
 
+def rows_pool_shape(slots: int, rows: int, width: int) -> tuple:
+    """A pool of ``rows`` rows of ``width`` channels a slot (a convolution's
+    last ``kernel - 1``): ``[slots, rows * width / lanes, lanes]``, a slot's
+    rows one after another, the oldest first, in lines of ``lanes`` = 128
+    channels (the widest power of two up to that which divides ``width``), so
+    that a slot is whole tiles of the device's memory, as a page of keys is,
+    and a gather or a scatter of slots moves those slots.  (``[slots, rows,
+    width]`` with 3 rows is kept rows-outermost at a program's boundary and
+    slots-outermost inside it: the whole pool is relaid, in and out, by every
+    layer of every dispatch.)"""
+    lanes = math.gcd(width, 128)
+    return slots, rows * width // lanes, lanes
+
+
 def cache_bytes_per_token(cfg: ModelConfig) -> int:
     """Bytes of cache one position takes on the device: the ``gqa`` layers' K
     and V alone; a ``kda`` layer's state does not grow."""
@@ -130,7 +144,7 @@ def init_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
                sharding=None, state_slots: Optional[int] = None):
     """One tree: a layer's ``(K, V)`` pages ``[num_blocks, block_size, kv
     heads, head_dim]`` or its ``(state [slots, heads, D, D] float32, conv
-    [slots, kernel - 1, 3 heads D])`` slots."""
+    :func:`rows_pool_shape` of kernel - 1 rows of 3 heads D)`` slots."""
     slots = state_slots or DEFAULT_STATE_SLOTS
     H, D = cfg.linear_num_heads, cfg.linear_head_dim
     dtype = jnp.dtype(cfg.dtype)
@@ -142,7 +156,8 @@ def init_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
     return [
         (zeros(page, dtype), zeros(page, dtype)) if kind == "gqa" else
         (zeros((slots, H, D, D), jnp.float32),
-         zeros((slots, cfg.linear_conv_kernel - 1, _conv_width(cfg)), dtype))
+         zeros(rows_pool_shape(slots, cfg.linear_conv_kernel - 1,
+                               _conv_width(cfg)), dtype))
         for kind in _kinds(cfg)]
 
 
@@ -414,7 +429,7 @@ def _kda_prefill(layer, cfg, cache, x, live, valid_len, slots):
     T, K = x.shape[0], cfg.linear_conv_kernel
     fresh = start < 0
     s0 = jnp.where(fresh, 0.0, state[jnp.maximum(start, 0)])
-    c0 = jnp.where(fresh, 0, conv[jnp.maximum(start, 0)])
+    c0 = jnp.where(fresh, 0, conv[jnp.maximum(start, 0)]).reshape(K - 1, -1)
     u = _dot(x, layer["qkv_proj"]).astype(x.dtype)
     full = jnp.concatenate([c0, u], axis=0)                # [K - 1 + T, W]
     mixed = jax.nn.silu(sum(
@@ -434,7 +449,8 @@ def _kda_prefill(layer, cfg, cache, x, live, valid_len, slots):
             o, s1, snap = kda_chunk_plain(
                 q, k, v, g, beta, s0,
                 None if snap_slot is None else snap_len)
-    rows = lambda at: jax.lax.dynamic_slice_in_dim(full, at, K - 1, axis=0)
+    rows = lambda at: jax.lax.dynamic_slice_in_dim(
+        full, at, K - 1, axis=0).reshape(conv.shape[1:])
     if snap_slot is not None:
         state = state.at[snap_slot].set(snap)
         conv = conv.at[snap_slot].set(rows(snap_len))
@@ -447,7 +463,9 @@ def _kda_decode(layer, cfg, cache, x, live, slots):
     """One token a row through one ``kda`` layer."""
     state, conv = cache
     u = _dot(x, layer["qkv_proj"]).astype(x.dtype)
-    window = jnp.concatenate([conv[slots], u[:, None]], axis=1)
+    R, W = u.shape
+    window = jnp.concatenate(
+        [conv[slots].reshape(R, -1, W), u[:, None]], axis=1)
     q, k, v, g, beta = _kda_inputs(
         layer, cfg, x, _convolve(layer, window), live)
     with jax.named_scope("kda_decode"):
@@ -460,8 +478,9 @@ def _kda_decode(layer, cfg, cache, x, live, slots):
         else:
             o, rows = kda_step_plain(q, k, v, g, beta, state[slots])
             state = state.at[slots].set(rows)
-    conv = conv.at[slots].set(
-        jnp.where(live[:, None, None], window[:, 1:], window[:, :-1]))
+    conv = conv.at[slots].set(jnp.where(
+        live[:, None, None], window[:, 1:], window[:, :-1]).reshape(
+            R, *conv.shape[1:]))
     return _kda_out(layer, cfg, x, o), (state, conv)
 
 

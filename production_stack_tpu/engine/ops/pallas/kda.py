@@ -20,7 +20,11 @@ token tile); a head's state stays in VMEM over all tiles of the call (the
 output block of the state does not move along the tile axis) and is read and
 written once.  Inside a tile, ``CHUNK`` tokens at a time, the algebra of
 ``solar_kda.kda_chunk_plain``, on the transposed state ``S^T`` so that every
-product but one contracts the lanes of both operands: the pseudo-values
+product but one contracts the lanes of both operands (the state comes in and
+goes out as the pool keeps it, ``[k, v]``: the kernel transposes a head's tile
+in VMEM at the first tile's load and the last tile's store -- a transpose left
+to XLA beside the scatter into the pool is folded into the *pool's* layout,
+two copies of the whole pool a layer): the pseudo-values
 ``U = (I + A)^-1 (beta v - (beta k e^G) S_0)``, ``A`` strictly lower
 triangular, its inverse the product ``(I - A)(I + A^2)(I + A^4)(I + A^8)``.
 The state after ``snapshot_len`` tokens (a multiple of ``CHUNK``; negative:
@@ -127,8 +131,8 @@ def _prefill_kernel(snap_at_ref, q_ref, k_ref, kb_ref, vb_ref, g_ref, s0_ref,
 
     @pl.when(t == 0)
     def _():
-        s1_ref[0] = s0_ref[0]
-        snap_ref[0] = s0_ref[0]
+        s1_ref[0] = s0_ref[0].T
+        snap_ref[0] = s0_ref[0].T
 
     row = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
     column = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
@@ -165,6 +169,11 @@ def _prefill_kernel(snap_at_ref, q_ref, k_ref, kb_ref, vb_ref, g_ref, s0_ref,
     s1_ref[0] = ST
     snap_ref[0] = snap
 
+    @pl.when(t == pl.num_programs(1) - 1)
+    def _():
+        s1_ref[0] = ST.T
+        snap_ref[0] = snap.T
+
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def kda_prefill_pallas(q, k, v, g, beta, s0, snapshot_len=None, *,
@@ -194,8 +203,6 @@ def kda_prefill_pallas(q, k, v, g, beta, s0, snapshot_len=None, *,
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="kda_prefill_pallas",
-    )(snap_at, heads(q), heads(k), heads(b * k), heads(b * v), heads(g),
-      s0.transpose(0, 2, 1))
-    back = lambda s: s.transpose(0, 2, 1)
-    return (o.transpose(1, 0, 2), back(s1),
-            None if snapshot_len is None else back(snap))
+    )(snap_at, heads(q), heads(k), heads(b * k), heads(b * v), heads(g), s0)
+    return (o.transpose(1, 0, 2), s1,
+            None if snapshot_len is None else snap)

@@ -15,6 +15,9 @@ given this file loads the TPU library.  All of these tests stay in this
 one file for the same reason.
 """
 
+import collections
+import functools
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -341,6 +344,107 @@ def test_the_dense_kernels_compile_at_jambas_geometry(sds, no_persistent_cache):
             sds((T, H, hd), jnp.bfloat16), new, new, prefix, prefix,
             sds((), jnp.int32), sds((), jnp.int32),
         )
+
+
+# A module that owns a state pool reads and writes slots where they lie
+# (models/registry.py): the programs the engine serves it with -- the packed
+# ``prefill_fn`` at 256 slots with a snapshot slot named, the module's
+# ``decode`` at 16 rows and the K = 8 ``window_fn`` that scans it -- compiled at
+# the cells' pool sizes (59 slots; the K/V pools the engine's log gives) with
+# the cache tree donated, hold NO synchronous ``copy`` of an array the size of
+# a cache leaf (a state pool, a convolution rows' pool, a K/V pool).  What the
+# parent of PR 55 compiled to: 12 such copies in solar's prefill (6 of them the
+# 247.5 MB delta-rule pool: XLA folded the prefill kernel's transposes into
+# the pool's layout), 6 in its decode, 52 in each of jamba's (a ``[slots, 3,
+# W]`` pool is kept taps-outermost at the program's boundary and slots-
+# outermost by the gather and the scatter).  The ``copy-start``s are what the
+# compiler's own staging leaves -- one layer's pool moved through the faster
+# memory, asynchronously -- pinned by count, so that one more fails: {what:
+# count}, "rows" the convolution rows' pools, "state" the state pools.  (A
+# two-dimensional rows' pool, ``[slots, 3 W]``, passes the first half and
+# reads 24 and 26 ``copy-start``s in jamba's decode and window: every layer's
+# pool staged whole, a slot being a sub-tile line of it; on the chip solar's
+# step was slower than the parent's.)
+STATE_MODELS = {
+    # preset: (module, K/V blocks of its cell, {program: copy-starts})
+    "solar-open2-250b-ep8": ("solar_kda", 119_517, {
+        "prefill": {}, "decode": {"rows": 1}, "window": {"rows": 1}}),
+    "jamba2-3b": ("jamba", 525_184, {
+        "prefill": {}, "decode": {"state": 1},
+        "window": {"rows": 1, "state": 1}}),
+}
+@pytest.mark.parametrize("program", ["prefill", "decode", "window"])
+@pytest.mark.parametrize("preset", list(STATE_MODELS))
+def test_a_state_models_served_programs_copy_no_pool(
+        one_chip, no_persistent_cache, monkeypatch, preset, program):
+    import importlib
+
+    from production_stack_tpu.engine.core import step_programs
+    from production_stack_tpu.engine.kv.state_pool import pool_slots
+
+    module, blocks, staged = STATE_MODELS[preset]
+    model = importlib.import_module(
+        f"production_stack_tpu.engine.models.{module}")
+    cfg = PRESETS[preset]
+    # The module asks JAX for its backend to pick the served kernels.
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    on_chip = lambda tree: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    params = on_chip(jax.eval_shape(
+        lambda: model.init_params(cfg, jax.random.PRNGKey(0))))
+    cache = on_chip(jax.eval_shape(lambda: model.init_cache(
+        cfg, blocks, BS, state_slots=1 + sum(pool_slots(16)))))
+    arg = lambda dtype, *shape: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+    i32, f32 = (functools.partial(arg, dt) for dt in (jnp.int32, jnp.float32))
+    S, T, bmax = 16, 256, cfg.max_model_len // BS
+    if program == "prefill":
+        scalars = ("cached_len", "valid_len", "state_slot", "state_from",
+                   "snapshot_slot", "snapshot_len")
+        lowered = jax.jit(step_programs.prefill_program(
+            functools.partial(model.prefill, cfg=cfg, return_stats=True),
+            scalars, BS, bmax), donate_argnames=("kv_caches",),
+        ).lower(params, i32(T + T // BS + bmax + len(scalars)),
+                kv_caches=cache)
+    elif program == "decode":
+        lowered = jax.jit(
+            functools.partial(model.decode, cfg=cfg),
+            donate_argnames=("kv_caches",),
+        ).lower(params, tokens=i32(S), positions=i32(S),
+                block_tables=i32(S, bmax), ctx_lens=i32(S),
+                slot_block_ids=i32(S), slot_offsets=i32(S), kv_caches=cache,
+                state_slots=i32(S))
+    else:
+        lowered = jax.jit(step_programs.window_program(
+            functools.partial(model.decode, cfg=cfg, return_stats=True),
+            block_size=BS, n_steps=8, vocab=cfg.vocab_size),
+            static_argnames=("use_penalties", "use_min_floor"),
+            donate_argnames=("kv_caches",),
+        ).lower(
+            params, tokens=i32(S), positions=i32(S), ctx_lens=i32(S),
+            done=arg(jnp.bool_, S), min_left=i32(S), block_tables=i32(S, bmax),
+            max_steps=i32(S), kv_caches=cache, temps=f32(S), top_ps=f32(S),
+            top_ks=i32(S), min_ps=f32(S), seq_seeds=i32(S), stop_ids=i32(S, 4),
+            key_base=i32(), counts=arg(jnp.int16, S, 1),
+            seen=arg(jnp.bool_, S, 1), presence=f32(S), frequency=f32(S),
+            repetition=f32(S), use_penalties=False, use_min_floor=False,
+            state_slots=i32(S))
+    text = lowered.compile().as_text()
+    assert "tpu_custom_call" in text
+    # The counter is the microbenchmark's (it counts the program that ran).
+    from tools.state_pool_microbench import pool_copies
+
+    leaves, what = [], []
+    for pair in cache:
+        stateful = pair[0].dtype == jnp.float32
+        leaves += pair
+        what += ("state", "rows") if stateful else ("pages", "pages")
+    found = collections.Counter(
+        (op, what[i]) for op, i in pool_copies(text, leaves))
+    assert not {k: n for k, n in found.items() if k[0] == "copy"}
+    for (_, name), n in found.items():
+        assert n <= staged[program].get(name, 0), (name, n)
 
 
 def test_int8_kv_decode_kernel_is_still_refused(sds, no_persistent_cache):

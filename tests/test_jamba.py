@@ -7,6 +7,7 @@ two Pallas kernels in interpret mode.  CPU, the ``tiny-jamba`` preset
 
 import asyncio
 import dataclasses
+import functools
 import hashlib
 import importlib.util
 import os
@@ -341,6 +342,110 @@ def test_the_decode_kernel_is_one_step_in_place():
     _close(after[4], one[1], 1e-5)
 
 
+# -- a slot is read and written where it lies ---------------------------------
+
+
+@pytest.fixture
+def kernels_serve(monkeypatch):
+    """The module's TPU branch on the CPU: both kernels, interpreted."""
+    monkeypatch.setattr(jamba, "use_pallas_ssm", lambda cfg: True)
+    for name in ("ssm_prefill_pallas", "ssm_decode_pallas"):
+        monkeypatch.setattr(ssm, name, functools.partial(
+            getattr(ssm, name), interpret=True))
+
+
+def _layer_case(T, seed=0, slots=6):
+    """One ``mamba`` layer of the tiny preset (128 channels of 16 states), a
+    pool whose every slot holds something, a chunk's normed input."""
+    cfg = _cfg()
+    Di, N = jamba._inner(cfg), cfg.mamba_d_state
+    layer = jamba.init_params(cfg, jax.random.PRNGKey(seed))["layers"][0]
+    ks = jax.random.split(jax.random.PRNGKey(seed + 7), 3)
+    pools = (jax.random.normal(ks[0], (slots, N, Di)),
+             jax.random.normal(
+                 ks[1], solar_kda.rows_pool_shape(slots, 3, Di)))
+    return cfg, layer, pools, jax.random.normal(ks[2], (T, cfg.hidden_size))
+
+
+def _others_bit_equal(before, after, written):
+    for was, now in zip(before, after):
+        for slot in set(range(was.shape[0])) - set(written):
+            np.testing.assert_array_equal(was[slot], now[slot])
+
+
+@pytest.mark.parametrize("path", ["kernel", "plain"])
+@pytest.mark.parametrize("T, valid, start, slot, snap_slot, snap_len", [
+    (256, 200, -1, 1, None, None),   # from zeros, no snapshot (the compare's)
+    (256, 200, -1, 1, 4, 64),        # from zeros, a snapshot mid-chunk
+    (256, 256, 1, 1, 1, 0),          # a second chunk: start == slot, and the
+                                     # served "no snapshot": its own slot at 0
+    (512, 300, 4, 2, 0, 0),          # resumed from a snapshot, the null slot
+    (512, 512, 4, 2, 5, 448),        # ... a snapshot at the last boundary
+], ids=["zeros", "zeros-snapshot", "own-slot", "resumed", "resumed-last"])
+def test_a_prefill_writes_the_slots_it_names_and_nothing_else(
+        path, request, T, valid, start, slot, snap_slot, snap_len):
+    """``_mamba_prefill`` by either scan: the slots the chunk names hold the
+    state and the stream's last three rows at their places; every other slot
+    of both pools keeps its bits."""
+    if path == "kernel":
+        request.getfixturevalue("kernels_serve")
+    cfg, layer, pools, x = _layer_case(T)
+    Di = jamba._inner(cfg)
+    live = jnp.arange(T) < valid
+    named = tuple(None if v is None else jnp.int32(v)
+                  for v in (slot, start, snap_slot, snap_len))
+    out, after, _ = jax.jit(lambda pools, x: jamba._mamba_prefill(
+        layer, cfg, pools, x, live, jnp.int32(valid), named))(pools, x)
+    _others_bit_equal(pools, after, {slot} | ({snap_slot} - {None}))
+    u = jamba._dot(x, layer["in_proj"]).astype(x.dtype)[:, :Di]
+    head = jnp.zeros((3, Di)) if start < 0 else pools[1][start].reshape(3, Di)
+    full = jnp.concatenate([head, u])
+    np.testing.assert_array_equal(
+        after[1][slot].reshape(-1), full[valid:valid + 3].reshape(-1))
+    s0 = jnp.zeros_like(pools[0][0]) if start < 0 else pools[0][start]
+
+    def scanned(n):
+        """The state ``n`` tokens in, by the plain scan over the same
+        stream."""
+        if n == 0:
+            return s0
+        c = jamba._convolved(layer, cfg, [full[j:j + n] for j in range(4)])
+        dt, B, C = jamba._selective(layer, cfg, c, live[:n])
+        return jamba.ssm_scan_plain(
+            c, dt, jnp.zeros_like(c), B, C, layer["A_log"], layer["D"], s0)[1]
+
+    _close(after[0][slot], scanned(valid), 1e-5)
+    if snap_slot not in (None, slot):
+        np.testing.assert_array_equal(
+            after[1][snap_slot].reshape(-1),
+            full[snap_len:snap_len + 3].reshape(-1))
+        _close(after[0][snap_slot], scanned(snap_len), 1e-5)
+
+
+@pytest.mark.parametrize("path", ["kernel", "plain"])
+def test_a_decode_step_leaves_dead_rows_and_unnamed_slots_alone(
+        path, request):
+    """Rows 0 and 1 live on slots 4 and 2; two padding rows share the null
+    slot and a dead row names slot 3: of both pools only slots 4 and 2 move,
+    and their rows shift by one."""
+    if path == "kernel":
+        request.getfixturevalue("kernels_serve")
+    cfg, layer, pools, x = _layer_case(5, seed=2)
+    Di = jamba._inner(cfg)
+    slots = jnp.asarray([4, 2, 0, 0, 3], jnp.int32)
+    live = jnp.asarray([True, True, False, False, False])
+    out, after, _ = jax.jit(lambda pools, x: jamba._mamba_decode(
+        layer, cfg, pools, x, live, slots))(pools, x)
+    _others_bit_equal(pools, after, {4, 2})
+    u = jamba._dot(x, layer["in_proj"]).astype(x.dtype)[:, :Di]
+    for row, slot in ((0, 4), (1, 2)):
+        was, now = (a[1][slot].reshape(3, Di) for a in (pools, after))
+        np.testing.assert_array_equal(now[:2], was[1:])
+        np.testing.assert_array_equal(now[2], u[row])
+        assert not np.array_equal(after[0][slot], pools[0][slot])
+    assert out.shape == (5, Di)
+
+
 # -- what the shared files' other users lower to ------------------------------
 
 
@@ -373,14 +478,16 @@ def _lowered(preset):
 
 @pytest.mark.parametrize("preset, want", [
     ("tiny-llama", ["873b5204c91b164f", "0247206c6c71daca"]),
-    ("tiny-solar", ["2afd96b51fcd3ec0", "529eae6b689206f5"]),
+    ("tiny-solar", ["8199b97afa9c86f8", "50b476ee875f80f7"]),
 ])
 def test_the_other_modules_programs_lower_as_before_this_module(preset, want):
     """``solar_kda.py``'s layer loop and softmax path were factored for
     ``jamba.py`` to import: ``tiny-solar``'s and ``tiny-llama``'s ``prefill``
     and ``decode`` lower to the text they had at the commit before (hashes
     taken there, same JAX), at the default precision as the engine jits
-    them."""
+    them.  ``tiny-solar``'s were taken again when a slot of its convolution
+    rows' pool became lines of 128 channels (PR 55; ``2afd96b51fcd3ec0``,
+    ``529eae6b689206f5`` before): the module's own change."""
     with jax.default_matmul_precision(None):
         assert _lowered(preset) == want
 

@@ -6,6 +6,7 @@ interpret mode.  CPU, the ``tiny-solar`` preset, float32, seeded weights."""
 
 import asyncio
 import dataclasses
+import functools
 import importlib.util
 import os
 
@@ -390,6 +391,107 @@ def test_the_decode_kernel_is_one_step_in_place():
     _close(o[0], one[0][0], 1e-5)
 
 
+# -- a slot is read and written where it lies ---------------------------------
+
+
+@pytest.fixture
+def kernels_serve(monkeypatch):
+    """The module's TPU branch on the CPU: both kernels, interpreted."""
+    monkeypatch.setattr(solar_kda, "use_pallas_kda", lambda cfg: True)
+    for name in ("kda_prefill_pallas", "kda_decode_pallas"):
+        monkeypatch.setattr(kda, name, functools.partial(
+            getattr(kda, name), interpret=True))
+
+
+def _layer_case(T, seed=0, slots=6):
+    """One ``kda`` layer at the kernels' tile (2 heads of 128), a pool whose
+    every slot holds something, a chunk's normed input."""
+    cfg = _cfg(linear_num_heads=2, linear_head_dim=128)
+    layer = _params(cfg, seed)["layers"][1]
+    ks = jax.random.split(jax.random.PRNGKey(seed + 7), 3)
+    pools = (jax.random.normal(ks[0], (slots, 2, 128, 128)) * 0.1,
+             jax.random.normal(ks[1], solar_kda.rows_pool_shape(
+                 slots, 3, solar_kda._conv_width(cfg))))
+    return cfg, layer, pools, jax.random.normal(ks[2], (T, cfg.hidden_size))
+
+
+def _others_bit_equal(before, after, written):
+    for was, now in zip(before, after):
+        for slot in set(range(was.shape[0])) - set(written):
+            np.testing.assert_array_equal(was[slot], now[slot])
+
+
+# (T, valid, start, slot, snapshot slot, snapshot length)
+@pytest.mark.parametrize("T, valid, start, slot, snap_slot, snap_len", [
+    (256, 200, -1, 1, None, None),   # from zeros, no snapshot (the compare's)
+    (256, 200, -1, 1, 4, 64),        # from zeros, a snapshot mid-chunk
+    (256, 256, 1, 1, 1, 0),          # a second chunk: start == slot, and the
+                                     # served "no snapshot": its own slot at 0
+    (512, 300, 4, 2, 0, 0),          # resumed from a snapshot, the null slot
+    (512, 512, 4, 2, 5, 448),        # ... a snapshot at the last boundary
+    (512, 500, 4, 2, 5, 256),        # ... at the second tile's first token
+], ids=["zeros", "zeros-snapshot", "own-slot", "resumed", "resumed-last",
+        "resumed-tile"])
+def test_a_prefill_writes_the_slots_it_names_and_nothing_else(
+        kernels_serve, T, valid, start, slot, snap_slot, snap_len):
+    """``_kda_prefill`` through ``kda_prefill_pallas`` (which takes and hands
+    back the state as the pool keeps it) against ``kda_chunk_plain`` and
+    XLA's scatter; every slot the chunk does not name keeps its bits."""
+    cfg, layer, pools, x = _layer_case(T)
+    live = jnp.arange(T) < valid
+    named = tuple(None if v is None else jnp.int32(v)
+                  for v in (slot, start, snap_slot, snap_len))
+    run = jax.jit(lambda pools, x: solar_kda._kda_prefill(
+        layer, cfg, pools, x, live, jnp.int32(valid), named))
+    out, after = run(pools, x)
+    with pytest.MonkeyPatch.context() as plain:
+        plain.setattr(solar_kda, "use_pallas_kda", lambda cfg: False)
+        want_out, want = jax.jit(lambda pools, x: solar_kda._kda_prefill(
+            layer, cfg, pools, x, live, jnp.int32(valid), named))(pools, x)
+    _close(out, want_out, 1e-5)
+    written = {slot} | ({snap_slot} - {None})
+    for slot_ in written:
+        _close(after[0][slot_], want[0][slot_], 1e-5)
+    np.testing.assert_array_equal(after[1], want[1])   # the rows: one code
+    _others_bit_equal(pools, after, written)
+    _others_bit_equal(pools, want, written)
+    # The rows are the last three of the stream at each place.
+    W = solar_kda._conv_width(cfg)
+    u = solar_kda._dot(x, layer["qkv_proj"]).astype(x.dtype)
+    head = (jnp.zeros((3, W)) if start < 0 else pools[1][start].reshape(3, W))
+    full = jnp.concatenate([head, u])
+    np.testing.assert_array_equal(
+        after[1][slot].reshape(-1), full[valid:valid + 3].reshape(-1))
+    if snap_slot not in (None, slot):
+        np.testing.assert_array_equal(
+            after[1][snap_slot].reshape(-1),
+            full[snap_len:snap_len + 3].reshape(-1))
+
+
+@pytest.mark.parametrize("path", ["kernel", "plain"])
+def test_a_decode_step_leaves_dead_rows_and_unnamed_slots_alone(
+        path, request):
+    """Rows 0 and 1 live on slots 4 and 2; two padding rows share the null
+    slot and a dead row names slot 3: of both pools only slots 4 and 2 move,
+    and their rows shift by one."""
+    if path == "kernel":
+        request.getfixturevalue("kernels_serve")
+    cfg, layer, pools, x = _layer_case(5, seed=2)
+    slots = jnp.asarray([4, 2, 0, 0, 3], jnp.int32)
+    live = jnp.asarray([True, True, False, False, False])
+    out, after = jax.jit(lambda pools, x: solar_kda._kda_decode(
+        layer, cfg, pools, x, live, slots))(pools, x)
+    _others_bit_equal(pools, after, {4, 2})
+    W = solar_kda._conv_width(cfg)
+    u = solar_kda._dot(x, layer["qkv_proj"]).astype(x.dtype)
+    for row, slot in ((0, 4), (1, 2)):
+        was, now = (a[1][slot].reshape(3, W) for a in (pools, after))
+        np.testing.assert_array_equal(now[:2], was[1:])
+        np.testing.assert_array_equal(now[2], u[row])
+        assert not np.array_equal(after[0][slot], pools[0][slot])
+    assert out.shape == (5, 2 * 128)
+
+
 # -- the engine, both pools -------------------------------------------------
 
 
@@ -416,7 +518,7 @@ def test_the_engine_serves_it_end_to_end():
         eng.block_pool.num_blocks, BS, cfg.num_kv_heads, cfg.head_dim)
     for state, conv in eng.kv_caches[1:]:
         assert state.shape == (17, 4, 16, 16) and state.dtype == jnp.float32
-        assert conv.shape == (17, 3, 3 * 4 * 16)
+        assert conv.shape == (17, 3 * 3 * 4 * 16 // 64, 64)
     assert eng._state_bytes() == 17 * solar_kda.state_bytes_per_slot(cfg)
     rng = np.random.default_rng(0)
     shared = rng.integers(1, 260, 200).tolist()
