@@ -9,7 +9,32 @@ mesh shape, memory is an HBM fraction for the paged-KV pool.
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Tuple
+from typing import Dict, Optional, Tuple
+
+
+# Layer kinds (``ModelConfig.layer_kinds``) whose keys lie in pages of the
+# block pool, a position a row; every other kind keeps, a sequence, a slot of
+# the state pool that does not grow with the context.
+PAGED_KINDS = ("gqa", "full")
+
+
+@dataclasses.dataclass(frozen=True)
+class AttentionSpec:
+    """One kind of softmax layer where a model has several
+    (``ModelConfig.attention_specs``, a spec a layer kind): its query heads
+    (over the model's ``num_kv_heads`` of ``head_dim``), its window (positions
+    a query sees, its own included; None: all), the share of a head's
+    dimensions that rotate (the first ``partial_rotary_factor x head_dim``,
+    rotate-half pairing; the rest pass through), the rotary base, and YaRN
+    over the rotated dimensions (``models/sarvam_mla.py: yarn_inv_freq``'s
+    keys and ``attention_factor``, which multiplies cos and sin; None: plain
+    frequencies)."""
+
+    num_heads: int
+    window: Optional[int] = None
+    partial_rotary_factor: float = 1.0
+    rope_theta: float = 10000.0
+    rope_scaling: Optional[dict] = None
 
 
 @dataclasses.dataclass
@@ -104,6 +129,18 @@ class ModelConfig:
     layer_kinds: Tuple[str, ...] = ()
     use_rope: bool = True
     use_gqa_gate: bool = False
+    # Softmax layers of several kinds in one model (models/laguna.py): "full"
+    # (keys in pages, every position attended) and "window" (a query sees the
+    # last ``window`` positions, whose rotated keys and values lie in a
+    # rolling buffer, position p at row p mod window, in a slot of the state
+    # pool).  ``attention_specs`` gives each such kind its query heads, window
+    # and rotary form; ``num_heads`` / ``sliding_window`` / ``rope_theta`` /
+    # ``rope_scaling`` above stay what a model of one kind reads.
+    # ``use_head_gate``: each head's output times sigmoid(x W_g), a column a
+    # query head.
+    attention_specs: Dict[str, AttentionSpec] = dataclasses.field(
+        default_factory=dict)
+    use_head_gate: bool = False
     linear_num_heads: int = 0
     linear_head_dim: int = 0
     linear_conv_kernel: int = 0
@@ -146,6 +183,10 @@ class ModelConfig:
         depth (the one tiling rule; a module, the engine's boot line and the
         compare's shorter depth all read it)."""
         return self.layer_kinds[layer_idx % len(self.layer_kinds)]
+
+    def layers_of(self, kind: str) -> int:
+        """How many of the held layers are of ``kind``."""
+        return sum(self.layer_kind(i) == kind for i in range(self.num_layers))
 
 
 def _jamba_period(period: int, offset: int) -> Tuple[str, ...]:
@@ -611,6 +652,96 @@ PRESETS = {
         first_k_dense_replace=1,
         routed_scaling_factor=2.0,
         hc_mult=4,
+    ),
+    # Laguna-XS.2 (https://huggingface.co/poolside/Laguna-XS.2, model_type
+    # laguna) AS ONE OF TWO CHIPS THAT SHARE EVERY LAYER, not the whole model:
+    # every width as published, and of the published 40 layers (a full softmax
+    # layer then three window layers, ten times; layer 0's MLP dense, every
+    # other routed), 256 experts a layer and 100,352 vocabulary rows this
+    # preset holds two whole periods (layers 0-7), experts 0-127 behind a
+    # router that stays 256 wide, and 50,176 rows: 3.39 B parameters, 6.77 GB
+    # of bf16 (bench/configs/laguna-xs.2-ep2.json states the deployment;
+    # PERF.md section 4 the arithmetic).  A full layer has 48 query heads,
+    # rotates the first 64 of a head's 128 dimensions with YaRN x 64 over
+    # 4,096 positions (theta 500,000) and keeps keys in pages; a window layer
+    # has 64, rotates all 128 (theta 10,000) and keeps its last 512 keys in
+    # a slot of the state pool.  ``num_heads`` is the source's
+    # ``num_attention_heads`` and read by nothing here.  The published max is
+    # 262,144 positions; 32,768 is the serving limit the caches are sized for.
+    "laguna-xs.2-ep2": ModelConfig(
+        name="laguna-xs.2-ep2",
+        vocab_size=50176,
+        published_vocab_size=100352,
+        hidden_size=2048,
+        intermediate_size=8192,
+        num_layers=8,
+        num_heads=48,
+        num_kv_heads=8,
+        head_dim=128,
+        max_model_len=32768,
+        rms_norm_eps=1e-6,
+        num_experts=128,
+        router_experts=256,
+        num_experts_per_tok=8,
+        moe_intermediate_size=512,
+        num_shared_experts=1,
+        first_k_dense_replace=1,
+        routed_scaling_factor=2.5,
+        layer_kinds=("full", "window", "window", "window"),
+        attention_specs={
+            "full": AttentionSpec(
+                num_heads=48, partial_rotary_factor=0.5, rope_theta=500000.0,
+                rope_scaling={
+                    "type": "deepseek_yarn",   # the config says "yarn"
+                    "factor": 64,
+                    "original_max_position_embeddings": 4096,
+                    "beta_fast": 64,
+                    "beta_slow": 1,
+                    "attention_factor": 1.4158883083359672,
+                }),
+            "window": AttentionSpec(
+                num_heads=64, window=512, rope_theta=10000.0),
+        },
+        use_head_gate=True,
+    ),
+    # The same module at a size the CPU tests run: one period with the dense
+    # lead and the next period's routed full layer, 6 and 8 query heads over
+    # 2 key heads, a window of 24 (smaller than a prefill chunk, no multiple
+    # of the 16-token block), 4 of a router's 8 experts held, 2 a token.
+    "tiny-laguna": ModelConfig(
+        name="tiny-laguna",
+        vocab_size=384,
+        hidden_size=64,
+        intermediate_size=128,
+        num_layers=5,
+        num_heads=6,
+        num_kv_heads=2,
+        head_dim=16,
+        max_model_len=2048,
+        rms_norm_eps=1e-6,
+        num_experts=4,
+        router_experts=8,
+        num_experts_per_tok=2,
+        moe_intermediate_size=32,
+        num_shared_experts=1,
+        first_k_dense_replace=1,
+        routed_scaling_factor=2.5,
+        layer_kinds=("full", "window", "window", "window"),
+        attention_specs={
+            "full": AttentionSpec(
+                num_heads=6, partial_rotary_factor=0.5, rope_theta=500000.0,
+                rope_scaling={
+                    "type": "deepseek_yarn",
+                    "factor": 64,
+                    "original_max_position_embeddings": 64,
+                    "beta_fast": 64,
+                    "beta_slow": 1,
+                    "attention_factor": 1.4158883083359672,
+                }),
+            "window": AttentionSpec(
+                num_heads=8, window=24, rope_theta=10000.0),
+        },
+        use_head_gate=True,
     ),
 }
 

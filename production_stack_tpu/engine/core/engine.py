@@ -39,7 +39,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from production_stack_tpu.engine.config import PRESETS, EngineConfig
+from production_stack_tpu.engine.config import (
+    PAGED_KINDS, PRESETS, EngineConfig,
+)
 from production_stack_tpu.engine.core import step_programs
 from production_stack_tpu.engine.core.scheduler import (
     DecodePlan,
@@ -343,7 +345,7 @@ class LLMEngine:
                 "of a prompt's last chunk",
                 self.state_pool.live_slots, self.state_pool.snapshot_slots,
                 self.model.state_bytes_per_slot(cfg) / 1e6,
-                sum(cfg.layer_kind(i) != "gqa"
+                sum(cfg.layer_kind(i) not in PAGED_KINDS
                     for i in range(cfg.num_layers)),
                 self._state_bytes() / 2**30,
                 self.model.snapshot_stride(cfg),
@@ -789,6 +791,15 @@ class LLMEngine:
         # last batch's pair goes on its flight record.
         self.paged_decode_groups = {"total": 0, "coalesced": 0}
         self._last_kv_groups = (0, 0)
+        # The model's kinds of layer that attend over keys, as _open_record
+        # and _count_kv_tiles read them: (label, window or None, layers of
+        # the kind, whether its keys lie in slots of the state pool).  One
+        # kind for a model with one (its scalar sliding_window); a kind a
+        # spec where the layers differ (config.AttentionSpec).  And the
+        # positions the decode rows attended, a row a layer a planned step,
+        # by label (tpu:attn_positions_total{kind}); step-thread-only writer.
+        self._attn_kinds = self._decide_attn_kinds()
+        self.attn_positions = {"full": 0, "window": 0}
         # Last _can_window decline reason, stamped on the flight record
         # of the K=1 dispatch that replaced the declined window (step-
         # thread-only, overwritten every _can_window call).
@@ -1039,6 +1050,19 @@ class LLMEngine:
                 f"{module} keeps a cache of its own (one array a layer) and "
                 f"cannot serve with: {'; '.join(refused)}"
             )
+
+    def _decide_attn_kinds(self):
+        cfg = self.config.model
+        if cfg.attention_specs:
+            return [(kind, spec.window, cfg.layers_of(kind),
+                     kind not in PAGED_KINDS)
+                    for kind, spec in cfg.attention_specs.items()]
+        keyed = (sum(cfg.layer_kind(i) in PAGED_KINDS
+                     for i in range(cfg.num_layers))
+                 if cfg.layer_kinds else cfg.num_layers)
+        window = cfg.sliding_window
+        return [("full" if window is None else "window", window, keyed,
+                 False)]
 
     def _decide_kv_group_blocks(self) -> int:
         """``blocks_per_descriptor`` of the page the paged decode kernel is
@@ -1837,10 +1861,7 @@ class LLMEngine:
         kv_tiles_live, kv_tiles_grid = self._count_kv_tiles(
             chunks, bucket_tokens
         )
-        if not self.obs.enabled:
-            return None
         bs = self.block_pool.block_size
-        window = self.config.model.sliding_window
         if isinstance(ahead, _PendingStep):
             budget = {
                 s.seq_id: n for s, n in zip(ahead.seqs, ahead.steps)
@@ -1850,12 +1871,27 @@ class LLMEngine:
             budget, ahead = ahead, 0
         else:
             budget = {}
-        kv_tokens = 0
-        for s in seqs:
-            ctx = s.num_tokens + budget.get(s.seq_id, ahead)
+        # Positions the decode rows attend, a layer of each kind, whole
+        # blocks (what the decode kernels must read on the first step), and
+        # those of them that lie in slots of the state pool.
+        ctx = np.array(
+            [s.num_tokens + budget.get(s.seq_id, ahead) for s in seqs],
+            np.int64)
+        steps = np.arange(fields.get("k", 1))
+        kv_tokens = kv_tokens_slots = 0
+        for label, window, layers, in_slots in (
+                self._attn_kinds if seqs else ()):
+            seen = ctx if window is None else np.minimum(ctx, window)
+            first_step = int((-(-seen // bs) * bs).sum())
+            kv_tokens += first_step
+            if in_slots:
+                kv_tokens_slots += first_step
+            grown = ctx[:, None] + steps
             if window is not None:
-                ctx = min(ctx, window)
-            kv_tokens += -(-ctx // bs) * bs
+                grown = np.minimum(grown, window)
+            self.attn_positions[label] += int(grown.sum()) * layers
+        if not self.obs.enabled:
+            return None
         first = {}
         for cp in chunks:
             first.setdefault(cp.seq.seq_id, cp.cached_len)
@@ -1868,8 +1904,8 @@ class LLMEngine:
             kind, rows=len(seqs),
             seq_ids=tuple(s.seq_id for s in seqs) + tuple(first),
             chunk_prompts=len(first), chunk_tokens_planned=new_tokens,
-            kv_tokens=kv_tokens, new_tokens=new_tokens,
-            bucket_tokens=bucket_tokens,
+            kv_tokens=kv_tokens, kv_tokens_slots=kv_tokens_slots,
+            new_tokens=new_tokens, bucket_tokens=bucket_tokens,
             cached_tokens=sum(first.values()),
             kv_tiles_live=kv_tiles_live, kv_tiles_grid=kv_tiles_grid,
             **fields,
@@ -1889,13 +1925,19 @@ class LLMEngine:
         )
 
         C = max(self._bmax, 1) * self.block_pool.block_size
-        window = self.config.model.sliding_window
         live = grid = slots = 0
         for cp in chunks:
-            n_live, n_grid = count_kv_tiles(
-                cp.bucket_len, C, cp.cached_len, cp.num_new_tokens, window
-            )
-            live += n_live
+            n_grid = 0
+            # A layer of each kind: a kind whose keys lie in slots of the
+            # state pool has one window of them as its gathered prefix.
+            for _label, window, _layers, in_slots in self._attn_kinds:
+                n_live, n = count_kv_tiles(
+                    cp.bucket_len, window if in_slots else C,
+                    min(cp.cached_len, window) if in_slots else cp.cached_len,
+                    cp.num_new_tokens, window,
+                )
+                live += n_live
+                n_grid += n
             grid += n_grid
             slots += cp.bucket_len
         # A mixed window's scan pads its schedule (one bucket) to a power
@@ -4727,6 +4769,9 @@ class LLMEngine:
             "prefill_attn_tiles": dict(self.prefill_attn_tiles),
             # Zero where every page travels alone.
             "paged_decode_groups": dict(self.paged_decode_groups),
+            # Positions the decode rows attended, a row a layer a planned
+            # step, by the layers' kind (a window layer: at most a window).
+            "attn_positions": dict(self.attn_positions),
             # Routed experts held by share: (row, expert) pairs by where
             # they fell, and held experts with at least one row a layer
             # and step (zero for a model that routes nothing).

@@ -46,8 +46,9 @@ the step programs (``core/step_programs.py``) carry without looking inside.
   tokens carry several residual streams.
 - with ``init_cache``, **a state pool**: ``state_bytes_per_slot(cfg)`` and
   ``snapshot_stride(cfg)`` say that some layers keep, in place of keys, a
-  recurrent state that does not grow with the context (two modules do:
-  ``solar_kda.py``, ``jamba.py``).  The engine then makes
+  state that does not grow with the context (three modules do:
+  ``solar_kda.py`` and ``jamba.py`` a recurrent state, ``laguna.py`` a window
+  layer's last keys and values in a rolling buffer).  The engine then makes
   a ``kv/state_pool.py: StatePool`` beside the block pool (sized by rule from
   ``max_num_seqs``; its bytes come off what the block pool is sized from),
   calls ``init_cache(..., state_slots=n)`` so that the one cache tree holds
@@ -74,8 +75,12 @@ the step programs (``core/step_programs.py``) carry without looking inside.
   pool a layer a dispatch; in ``[slots, 3 x width]`` a slot is a sub-tile line
   and every scatter stages the pool or rewrites tiles row by row).
   ``tests/test_chip_compile.py: test_a_state_models_served_programs_copy_no_pool``
-  compiles both modules' served programs for a described v5e and is what holds
-  a third such module to it: add the preset to its ``STATE_MODELS``.
+  compiles the three modules' served programs for a described v5e and is what
+  holds a fourth such module to it: add the preset to its ``STATE_MODELS``.
+  (A value written into a slot that is computed from a slot of the same pool
+  -- a buffer carried over from the slot a chunk starts from -- is taken out
+  whole first, ``jax.lax.optimization_barrier``: fused with the write, XLA
+  copies the pool to keep the read safe.)
   **The default the benchmark's compare relies on**: ``bench/harness/
   compare.py`` calls both steps with the cache ``init_cache(cfg, blocks,
   block_size, sharding)`` returned and nothing else, so where no slot is
@@ -113,7 +118,22 @@ over a chunk in prefill and one step in decode (``ops/pallas/ssm.py``), beside
 (multi-query, 20 query heads: the paged kernel takes a one-head page as
 ``[block, head_dim]``), ``llama.py``'s dense SwiGLU and tied head; layer kinds
 from one period (``attn_layer_period`` / ``attn_layer_offset``); counters
-``ssm_state_absmax_e3`` / ``ssm_dt_max_e3``.
+``ssm_state_absmax_e3`` / ``ssm_dt_max_e3``; and in ``laguna.py`` softmax
+layers of two kinds in one model, each kind with a spec of its own
+(``ModelConfig.attention_specs``, ``config.AttentionSpec``: query heads,
+window, the share of a head that rotates, the rotary base, YaRN with its
+``attention_factor``): ``"full"`` layers (48 query heads over 8 at the
+published size, 6 a key head, partial rotary) through ``solar_kda``'s paged
+softmax path with their own projection, and ``"window"`` layers (64 over 8, a
+window of 512) whose rotated keys and values lie in a rolling buffer, position
+p at row ``p mod window``, in a slot of the same state pool: decode reads the
+slot as pool-adjacent pages through the paged kernel under the name
+``window_decode_attention_pallas``, a prefill chunk takes the buffer in order
+as its cached prefix through the flash kernel under the window mask; a sigmoid
+gate a head (``use_head_gate``); a leading dense layer and ``sarvam_mla``'s
+routed experts held by share with no selection bias, imported; counter
+``tpu:attn_positions_total{kind}`` and the records' ``kv_tokens_slots``
+(``config.PAGED_KINDS`` says which kinds keep pages).
 """
 
 from __future__ import annotations
@@ -121,7 +141,7 @@ from __future__ import annotations
 from types import ModuleType
 
 from production_stack_tpu.engine.models import (
-    jamba, llama, sarvam_mla, solar_kda,
+    jamba, laguna, llama, sarvam_mla, solar_kda,
 )
 
 MODEL_REGISTRY = {
@@ -149,6 +169,13 @@ MODEL_REGISTRY = {
     # user of the state pool (its layer loop, its softmax path and its slot
     # addressing are solar_kda's, imported).
     "jamba": jamba,
+    # Window and full softmax layers 3:1 with head counts and rotary forms by
+    # layer kind and a gate a head, over routed experts held by share behind
+    # a dense lead: the third user of the state pool (a window layer's last
+    # keys in a rolling buffer a slot; the layer loop, the paged softmax path
+    # and the slot addressing are solar_kda's, the routed FFN sarvam_mla's,
+    # imported).
+    "laguna": laguna,
 }
 
 

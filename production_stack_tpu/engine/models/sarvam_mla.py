@@ -585,9 +585,10 @@ def _swiglu(x, gate, up, down):
 
 def route(layer: Params, cfg: ModelConfig, x: jax.Array):
     """x [T, h] -> (chosen experts [T, k] int32 over the router's whole
-    width, their shares g [T, k] float32)."""
+    width, their shares g [T, k] float32).  A layer without a
+    ``router_bias`` (``models/laguna.py``) chooses by the scores alone."""
     s = jax.nn.sigmoid(_dot(x, layer["router"]))
-    _best, who = jax.lax.top_k(s + layer["router_bias"],
+    _best, who = jax.lax.top_k(s + layer.get("router_bias", 0.0),
                                cfg.num_experts_per_tok)
     chosen = jnp.take_along_axis(s, who, axis=-1)
     g = cfg.routed_scaling_factor * chosen / chosen.sum(-1, keepdims=True)

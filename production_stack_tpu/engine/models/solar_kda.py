@@ -69,7 +69,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from production_stack_tpu.engine.config import ModelConfig
+from production_stack_tpu.engine.config import PAGED_KINDS, ModelConfig
 from production_stack_tpu.engine.models.sarvam_mla import (
     ROUTING_STATS, STATS_MAX, _dot, _result, _swiglu, held_experts, route,
 )
@@ -125,10 +125,11 @@ def rows_pool_shape(slots: int, rows: int, width: int) -> tuple:
 
 
 def cache_bytes_per_token(cfg: ModelConfig) -> int:
-    """Bytes of cache one position takes on the device: the ``gqa`` layers' K
-    and V alone; a ``kda`` layer's state does not grow."""
+    """Bytes of cache one position takes on the device: K and V of the
+    layers that keep pages (``config.PAGED_KINDS``) alone; another layer's
+    state does not grow."""
     return (2 * cfg.num_kv_heads * cfg.head_dim * jnp.dtype(cfg.dtype).itemsize
-            * _kinds(cfg).count("gqa"))
+            * sum(kind in PAGED_KINDS for kind in _kinds(cfg)))
 
 
 def state_bytes_per_slot(cfg: ModelConfig) -> int:
@@ -164,7 +165,8 @@ def init_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
 def default_slot(cfg: ModelConfig, first_block_id, kv_caches):
     """The slot of a row nobody named one for: the first block id of its
     table modulo the slots there are."""
-    stateful = next(i for i, kind in enumerate(_kinds(cfg)) if kind != "gqa")
+    stateful = next(i for i, kind in enumerate(_kinds(cfg))
+                    if kind not in PAGED_KINDS)
     return first_block_id % kv_caches[stateful][0].shape[0]
 
 
@@ -507,27 +509,29 @@ def _gqa_out(layer, cfg, x, out):
 
 
 def _gqa_prefill(layer, cfg, cache, h, cached_len, prefix_block_ids,
-                 new_block_ids, valid_len):
-    """A chunk through one ``gqa`` layer: (what W_o reads, the new pages)."""
-    q, k, v = _gqa_project(layer, cfg, h)
+                 new_block_ids, valid_len, project=_gqa_project, out=_gqa_out):
+    """A chunk through one layer that keeps pages: (what W_o reads, the new
+    pages).  ``project`` and ``out`` are this module's; another module hands
+    its own (``models/laguna.py``: rotary by layer kind, a gate a head)."""
+    q, k, v = project(layer, cfg, h)
     k_prefix, v_prefix = attn_ops.gather_prefix_kv(
         *cache, prefix_block_ids, dtype=k.dtype)
-    out = attn_ops.prefill_attention(
+    attended = attn_ops.prefill_attention(
         q, k, v, k_prefix, v_prefix, cached_len, valid_len,
         scale=cfg.head_dim ** -0.5)
-    return _gqa_out(layer, cfg, h, out), attn_ops.write_prefill_kv(
+    return out(layer, cfg, h, attended), attn_ops.write_prefill_kv(
         *cache, k, v, new_block_ids)
 
 
 def _gqa_decode(layer, cfg, cache, h, block_tables, ctx_lens, slot_block_ids,
-                slot_offsets):
-    """One token a row through one ``gqa`` layer."""
-    q, k, v = _gqa_project(layer, cfg, h)
+                slot_offsets, project=_gqa_project, out=_gqa_out):
+    """One token a row through one layer that keeps pages."""
+    q, k, v = project(layer, cfg, h)
     cache = attn_ops.append_decode_kv(
         *cache, k, v, slot_block_ids, slot_offsets)
-    out = attn_ops.decode_attention(
+    attended = attn_ops.decode_attention(
         q, *cache, block_tables, ctx_lens, scale=cfg.head_dim ** -0.5)
-    return _gqa_out(layer, cfg, h, out), cache
+    return out(layer, cfg, h, attended), cache
 
 
 # -- the layers --------------------------------------------------------------

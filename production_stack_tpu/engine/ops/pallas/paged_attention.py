@@ -341,7 +341,7 @@ def _decode_kernel(
 @functools.partial(
     jax.jit,
     static_argnames=("scale", "sliding_window", "chunk_blocks",
-                     "group_blocks", "interpret"),
+                     "group_blocks", "interpret", "name"),
 )
 def paged_decode_attention_pallas(
     q: jax.Array,  # [S, H, D]
@@ -355,6 +355,7 @@ def paged_decode_attention_pallas(
     chunk_blocks: int = CHUNK_BLOCKS,
     group_blocks: Optional[int] = None,
     interpret: bool = False,
+    name: str = "paged_decode_attention_pallas",
 ) -> jax.Array:
     """Decode attention over paged KV, streaming blocks HBM->VMEM.
 
@@ -368,7 +369,11 @@ def paged_decode_attention_pallas(
     ``blocks_per_descriptor`` pages of the page this call is given.
     ``group_blocks`` is for the tests alone, which name 1 (every page
     alone, whatever the table says) to hold the grouped walk bit-equal to
-    the single-page one; nothing that serves passes it.
+    the single-page one; nothing that serves passes it.  ``name`` is what
+    the device trace calls this call: a caller whose pages are no block
+    pool's (``models/laguna.py``: a window layer's rolling buffer in a slot
+    of the state pool, read as pages) gives its own, so that its seconds
+    can be told from the walk over the block pool.
     """
     from production_stack_tpu.engine.kv import quant as kv_quant
 
@@ -452,5 +457,5 @@ def paged_decode_attention_pallas(
         interpret=interpret,
         # What the device trace calls the kernel (%<name>.N on XLA Ops):
         # the benchmark's readers find it by this name.
-        name="paged_decode_attention_pallas",
+        name=name,
     )(*prefetch, *inputs)

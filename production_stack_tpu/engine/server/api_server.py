@@ -459,6 +459,9 @@ def build_engine_app(
                 vocab.TPU_PREFILL_ATTN_TILES, "state",
                 s["prefill_attn_tiles"],
             )
+            + vocab.render_labeled_counter(
+                vocab.TPU_ATTN_POSITIONS, "kind", s["attn_positions"],
+            )
             # Unchained dispatches launched behind a program in flight, and
             # the admissions that waited for a read-back, by why: every
             # series from boot, so that a share reads 0, not nothing.

@@ -94,7 +94,11 @@ class WindowRecord:
     # Token counters, known on the host at dispatch.  ``kv_tokens``: KV
     # positions the decode rows attend, per row min(context, sliding
     # window) rounded up to whole blocks (what the paged kernel must
-    # read on the first step).  ``kv_groups`` / ``kv_groups_coalesced``, on
+    # read on the first step), a layer of each kind summed over the kinds
+    # where a model's layers differ (config.AttentionSpec);
+    # ``kv_tokens_slots``: those of them that lie in slots of the state pool
+    # (a window layer's rolling buffer), not in pages.  ``kv_groups`` /
+    # ``kv_groups_coalesced``, on
     # a decode batch built from host state where one DMA of that kernel
     # carries several pages (paged_attention.py: blocks_per_descriptor):
     # the groups of so many table entries its rows held / those that were
@@ -109,6 +113,7 @@ class WindowRecord:
     # (scheduler.cover_prefill): [256, 256, 256], [256, 256], [256] are
     # one 600-token prompt.
     kv_tokens: int = 0
+    kv_tokens_slots: int = 0
     kv_groups: int = 0
     kv_groups_coalesced: int = 0
     new_tokens: int = 0
@@ -171,6 +176,8 @@ class WindowRecord:
         }
         if self.rows:
             d["kv_tokens"] = self.kv_tokens
+        if self.kv_tokens_slots:
+            d["kv_tokens_slots"] = self.kv_tokens_slots
         if self.kv_groups:
             d["kv_groups"] = self.kv_groups
             d["kv_groups_coalesced"] = self.kv_groups_coalesced
@@ -245,6 +252,7 @@ class FlightRecorder:
         host_gap_s: float = 0.0,
         transfer_overlap_s: float = 0.0,
         kv_tokens: int = 0,
+        kv_tokens_slots: int = 0,
         new_tokens: int = 0,
         bucket_tokens: int = 0,
         cached_tokens: int = 0,
@@ -279,6 +287,7 @@ class FlightRecorder:
             host_gap_s=float(host_gap_s),
             transfer_overlap_s=float(transfer_overlap_s),
             kv_tokens=int(kv_tokens),
+            kv_tokens_slots=int(kv_tokens_slots),
             new_tokens=int(new_tokens),
             bucket_tokens=int(bucket_tokens),
             cached_tokens=int(cached_tokens),

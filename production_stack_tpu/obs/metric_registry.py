@@ -426,6 +426,15 @@ REGISTRY = {
                 "neighbours in the pool and went in one DMA a side; the "
                 "ratio falling says the pool has fragmented back to pages",
     },
+    "tpu:attn_positions_total": {
+        "kind": "counter", "layer": "engine", "labels": ("kind",),
+        "mirrors": ("docs",),
+        "help": "Positions the decode rows attended, a row a layer a "
+                "planned step, by the layers' kind (kind: full -- the whole "
+                "context; window -- at most the kind's window, in pages or "
+                "in a rolling buffer of the state pool); host arithmetic "
+                "from each dispatch's contexts, no device read",
+    },
     "tpu:moe_experts_touched_total": {
         "kind": "counter", "layer": "engine",
         "mirrors": ("fake_engine", "dashboard", "docs"),

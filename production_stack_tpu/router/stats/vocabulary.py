@@ -281,6 +281,12 @@ TPU_STATE_RECOMPUTED_TOKENS = "tpu:state_recomputed_tokens_total"
 # descriptor of its own.
 TPU_PAGED_DECODE_GROUPS = "tpu:paged_decode_groups_total"
 TPU_PAGED_DECODE_GROUPS_COALESCED = "tpu:paged_decode_groups_coalesced_total"
+# Positions the decode rows attended, a row a layer a planned step, by the
+# layers' kind: ``full`` (the whole context) or ``window`` (at most the
+# kind's window, wherever its keys lie).  Host arithmetic from each dispatch's
+# contexts.  window / (window layers x the full kind's positions a layer) is
+# what a window saves of the reads.
+TPU_ATTN_POSITIONS = "tpu:attn_positions_total"
 # Step-thread phases (obs.engine.PHASES) that lasted over a second: every
 # stream stood still for as long.  One WARNING line each names the window.
 TPU_STEP_STALL = "tpu:step_stall_total"

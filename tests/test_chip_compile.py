@@ -346,6 +346,49 @@ def test_the_dense_kernels_compile_at_jambas_geometry(sds, no_persistent_cache):
         )
 
 
+# laguna-xs.2-ep2 (models/laguna.py): the two dense kernels at its full
+# layers' geometry, 48 query heads over 8 key heads -- 6 a key head, no power
+# of two and no multiple of the sublane tile -- and at its window layers' (64
+# over 8, the cell's 59 slots of 512 rows read as 32 pages of 16 each, under
+# the call's own name; the flash kernel behind a prefix of one window).
+def test_the_dense_kernels_compile_at_lagunas_geometries(
+        sds, no_persistent_cache):
+    from production_stack_tpu.engine.models.laguna import WINDOW_DECODE_KERNEL
+
+    cfg = PRESETS["laguna-xs.2-ep2"]
+    K, hd = cfg.num_kv_heads, cfg.head_dim
+    full, window = cfg.attention_specs["full"], cfg.attention_specs["window"]
+    assert (full.num_heads, window.num_heads, K, hd, window.window) == (
+        48, 64, 8, 128, 512)
+    assert full.num_heads // K == 6
+    pages = sds((58_768, BS, K, hd), jnp.bfloat16)
+    _compile(
+        lambda q, k, v, bt, cl: paged_decode_attention_pallas(
+            q, k, v, bt, cl, scale=hd ** -0.5),
+        sds((16, full.num_heads, hd), jnp.bfloat16), pages, pages,
+        sds((16, cfg.max_model_len // BS), jnp.int32), sds((16,), jnp.int32),
+    )
+    slots = sds((59 * 512 // BS, BS, K, hd), jnp.bfloat16)
+    text = _compile(
+        lambda q, k, v, bt, cl: paged_decode_attention_pallas(
+            q, k, v, bt, cl, scale=hd ** -0.5, name=WINDOW_DECODE_KERNEL),
+        sds((16, window.num_heads, hd), jnp.bfloat16), slots, slots,
+        sds((16, 512 // BS), jnp.int32), sds((16,), jnp.int32),
+    ).as_text()
+    assert WINDOW_DECODE_KERNEL in text
+    for T in (256, 2048):
+        new = sds((T, K, hd), jnp.bfloat16)
+        for spec, C in ((full, cfg.max_model_len), (window, window.window)):
+            prefix = sds((C, K, hd), jnp.bfloat16)
+            _compile(
+                lambda q, k, v, kp, vp, cached, valid: flash_prefill_attention(
+                    q, k, v, kp, vp, cached, valid, scale=hd ** -0.5,
+                    sliding_window=spec.window),
+                sds((T, spec.num_heads, hd), jnp.bfloat16), new, new, prefix,
+                prefix, sds((), jnp.int32), sds((), jnp.int32),
+            )
+
+
 # A module that owns a state pool reads and writes slots where they lie
 # (models/registry.py): the programs the engine serves it with -- the packed
 # ``prefill_fn`` at 256 slots with a snapshot slot named, the module's
@@ -372,6 +415,9 @@ STATE_MODELS = {
     "jamba2-3b": ("jamba", 525_184, {
         "prefill": {}, "decode": {"state": 1},
         "window": {"rows": 1, "state": 1}}),
+    # "state": a window layer's rolling buffers, K and V (bf16, as pages are).
+    "laguna-xs.2-ep2": ("laguna", 58_768, {
+        "prefill": {}, "decode": {}, "window": {}}),
 }
 @pytest.mark.parametrize("program", ["prefill", "decode", "window"])
 @pytest.mark.parametrize("preset", list(STATE_MODELS))
@@ -435,11 +481,17 @@ def test_a_state_models_served_programs_copy_no_pool(
     # The counter is the microbenchmark's (it counts the program that ran).
     from tools.state_pool_microbench import pool_copies
 
+    from production_stack_tpu.engine.config import PAGED_KINDS
+
     leaves, what = [], []
-    for pair in cache:
-        stateful = pair[0].dtype == jnp.float32
+    for i, pair in enumerate(cache):
         leaves += pair
-        what += ("state", "rows") if stateful else ("pages", "pages")
+        if cfg.layer_kind(i) in PAGED_KINDS:
+            what += ("pages", "pages")
+        elif pair[0].dtype == jnp.float32:
+            what += ("state", "rows")
+        else:
+            what += ("state", "state")
     found = collections.Counter(
         (op, what[i]) for op, i in pool_copies(text, leaves))
     assert not {k: n for k, n in found.items() if k[0] == "copy"}
