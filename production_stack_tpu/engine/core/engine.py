@@ -778,8 +778,9 @@ class LLMEngine:
         # step-thread-only writers.
         self.multistep_fallback: Dict[str, int] = {}
         self.multistep_wasted_tokens = 0
-        # Kv tiles of the flash prefill kernel's grid that were computed /
-        # skipped by its liveness rule, per layer, summed over dispatched
+        # Kv tiles of the prefill attention kernel's grid (the flash prefill
+        # kernel's, or the module's own: _count_kv_tiles) that were computed
+        # / skipped by its liveness rule, per layer, summed over dispatched
         # prefill chunks (tpu:prefill_attn_tiles_total{state}); host
         # arithmetic in _count_kv_tiles, step-thread-only writer.
         self.prefill_attn_tiles: Dict[str, int] = {"live": 0, "skipped": 0}
@@ -1912,30 +1913,41 @@ class LLMEngine:
         )
 
     def _count_kv_tiles(self, chunks, bucket_tokens: int):
-        """(kv tiles the flash prefill kernel computes, kv tiles in its
+        """(kv tiles the prefill attention kernel computes, kv tiles in its
         grid) per layer for the PrefillPlans of one dispatch, by the
         kernel's own liveness rule — host arithmetic, no device read; also
-        feeds ``tpu:prefill_attn_tiles_total``.  The counts describe the
-        kernel's grid whether or not it is the path that runs (under a tp
-        mesh prefill takes the dense path)."""
+        feeds ``tpu:prefill_attn_tiles_total``.  The kernel is the module's
+        own where it says so (``prefill_attn_tiles``: the latent prefill
+        kernel's (query tile, key stage) pairs), else the flash prefill
+        kernel.  The counts describe the kernel's grid whether or not it is
+        the path that runs (under a tp mesh, and off the TPU, prefill takes
+        the XLA path)."""
         if not chunks:
             return 0, 0
         from production_stack_tpu.engine.ops.pallas.flash_prefill import (
             count_kv_tiles,
         )
 
-        C = max(self._bmax, 1) * self.block_pool.block_size
+        bmax, bs = max(self._bmax, 1), self.block_pool.block_size
+        C = bmax * bs
+        own_rule = getattr(self.model, "prefill_attn_tiles", None)
         live = grid = slots = 0
         for cp in chunks:
             n_grid = 0
             # A layer of each kind: a kind whose keys lie in slots of the
             # state pool has one window of them as its gathered prefix.
             for _label, window, _layers, in_slots in self._attn_kinds:
-                n_live, n = count_kv_tiles(
-                    cp.bucket_len, window if in_slots else C,
-                    min(cp.cached_len, window) if in_slots else cp.cached_len,
-                    cp.num_new_tokens, window,
-                )
+                if own_rule is not None:
+                    n_live, n = own_rule(
+                        self.config.model, cp.bucket_len, bmax, bs,
+                        cp.cached_len, cp.num_new_tokens)
+                else:
+                    n_live, n = count_kv_tiles(
+                        cp.bucket_len, window if in_slots else C,
+                        min(cp.cached_len, window) if in_slots
+                        else cp.cached_len,
+                        cp.num_new_tokens, window,
+                    )
                 live += n_live
                 n_grid += n
             grid += n_grid

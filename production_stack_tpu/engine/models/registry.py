@@ -29,6 +29,13 @@ the step programs (``core/step_programs.py``) carry without looking inside.
 - with ``init_cache``, ``attention_paths(cfg) -> (decode, prefill)``: the
   names of the attention paths its steps take in this process, which the
   engine's boot line says (``Attention: decode=... prefill=...``).
+- with ``init_cache``, ``prefill_attn_tiles(cfg, bucket_len, prefix_blocks,
+  block_size, cached_len, num_new_tokens) -> (live, grid)``: the tiles a
+  layer's prefill attention computes and those of its grid for one chunk, by
+  the rule of the module's own prefill kernel; the engine's ``kv_tiles_live``
+  / ``kv_tiles_grid`` and ``tpu:prefill_attn_tiles_total`` then count those
+  (``core/engine.py: _count_kv_tiles``), and the flash prefill kernel's kv
+  tiles for a module without it.
 - ``param_specs(cfg)``: the PartitionSpec tree of its own parameters
   (``parallel/shardings.py`` asks before it assumes llama's tree).
 - ``return_choice=True`` on ``prefill`` / ``decode``: one more result, the
@@ -92,7 +99,8 @@ the step programs (``core/step_programs.py``) carry without looking inside.
 **What runs, by mechanism** (ROADMAP Queue 2 lists what does not): dense GQA
 with one sliding window, int8 weights, a softmax-routed MoE (``llama.py``);
 and in ``sarvam_mla.py`` a latent (MLA) cache of one array a layer with the
-absorbed decode in a Pallas kernel, a full or a low-rank query path
+absorbed decode and the absorbed prefill each in a Pallas kernel, a full or a
+low-rank query path
 (``q_lora_rank``), a norm a query head or none, routed experts behind a biased
 sigmoid router held by share or whole with a shared expert, leading dense
 layers, ``deepseek_yarn``, and several residual streams mixed at every

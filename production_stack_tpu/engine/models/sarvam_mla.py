@@ -37,16 +37,27 @@ width pair up), frequencies by ``deepseek_yarn`` (:func:`yarn_inv_freq`).
 ``[k_nope ; v] = W_kvb c`` per head; ``score = (q_nope.k_nope + q_rope.r) *
 q_head_dim^-1/2 * m^2`` with ``m = 0.1 * mscale_all_dim * ln(factor) + 1``.
 
-Two paths, one result.  :func:`prefill` expands cached and new latents to
-per-head K and V, a tile of keys at a time under a running softmax
-(:func:`_expanded_attention`): no ``[chunk, context, heads]`` score array is
-ever built.  :func:`decode` absorbs ``W_kvb`` into the query and the output
-(``q~ = W_UK^T q_nope``, ``score = [q~ ; q_rope] . [c ; r]``,
-``o = W_UV sum p c``) and reads the latent pages as they lie
-(:func:`_absorbed_attention`): on a TPU by the Pallas kernel of
-``ops/pallas/latent_attention.py``, each live page copied HBM -> VMEM once;
-elsewhere by an XLA walk, a tile of blocks at a time (:func:`_latent_walk`).
-The prefill is plain XLA: the baseline a kernel has to beat.
+Two forms, one result.  **Absorbed**: ``W_kvb`` goes into the query and the
+output (``q~ = W_UK^T q_nope``, ``score = [q~ ; q_rope] . [c ; r]``,
+``o = W_UV sum p c``) and the latent pages are read as they lie, key and value
+at once.  **Expanded**: cached and new latents become per-head K and V
+(``W_kvb c``), a tile of keys at a time under a running softmax; no ``[chunk,
+context, heads]`` score array is ever built.  What runs where:
+
+- :func:`decode` is absorbed everywhere (:func:`_absorbed_attention`): on a
+  TPU by the Pallas kernel ``latent_decode_attention_pallas`` of
+  ``ops/pallas/latent_attention.py``, each live page copied HBM -> VMEM once;
+  elsewhere by an XLA walk, a tile of blocks at a time (:func:`_latent_walk`).
+- :func:`prefill` (:func:`_prefill_attention`) is absorbed on a TPU, one call
+  of ``latent_prefill_attention_pallas`` a layer: the cached prefix's pages
+  read through the block table, the chunk's own rows causally after them, a
+  stage's scores and probabilities kept in VMEM, a tile of slots past
+  ``valid_len`` skipped and written as zeros (PR 57).  Elsewhere -- the CPU,
+  a cache row that is not whole 128-lane tiles, heads that do not fill a
+  sublane tile, the A/B switch -- it is expanded in XLA
+  (:func:`_expanded_attention`): the statement the kernel is held to
+  (``tests/test_pallas_latent_prefill.py``) and the baseline it was measured
+  against (``tools/latent_prefill_microbench.py``).
 
 **Routed FFN.**  ``s = sigmoid(W_r x)`` in float32 over the router's
 published width ``cfg.router_experts``; the ``num_experts_per_tok`` largest of
@@ -469,6 +480,34 @@ def _expanded_attention(layer, cfg, q_nope, q_rope, rows, cache,
     return out.reshape(H, T, -1).transpose(1, 0, 2)    # [T, H, v]
 
 
+def _prefill_attention(layer, cfg, q_nope, q_rope, rows, cache,
+                       prefix_block_ids, cached_len, valid_len):
+    """A prefill chunk's attention [T, H, v].  On a TPU absorbed, as the
+    decode's is, between the same two weight einsums: one Pallas kernel
+    reads the prefix's pages through the block table and the chunk's own
+    rows, keeps a stage's scores in VMEM and skips the tiles of slots past
+    ``valid_len`` (:func:`use_pallas_latent_prefill`).  Elsewhere
+    :func:`_expanded_attention`, the statement the kernel is held to."""
+    lanes = cache.shape[-1]
+    if not use_pallas_latent_prefill(lanes, cfg.num_heads):
+        with jax.named_scope("latent_attention_expanded"):
+            return _expanded_attention(
+                layer, cfg, q_nope, q_rope, rows, cache, prefix_block_ids,
+                cached_len, valid_len)
+    from production_stack_tpu.engine.ops.pallas.latent_attention import (
+        latent_prefill_attention_pallas,
+    )
+
+    w = _kv_b(layer, cfg)
+    with jax.named_scope("latent_attention_absorbed_prefill"):
+        latent = latent_prefill_attention_pallas(
+            _into_latent(w, cfg, q_nope, q_rope, lanes),
+            rows.astype(cache.dtype), cache, prefix_block_ids, cached_len,
+            valid_len, latent_rank=cfg.kv_lora_rank,
+            scale=softmax_scale(cfg))
+        return _out_of_latent(w, cfg, latent)
+
+
 PAGE_TILE = 128     # blocks a tile of the XLA walk over the latent pages
 
 
@@ -494,12 +533,37 @@ def use_pallas_latent_decode(lanes: int) -> bool:
     return lanes % 128 == 0 and _pallas_serves()
 
 
+def use_pallas_latent_prefill(lanes: int, heads: int) -> bool:
+    """Trace-time dispatch check for the latent prefill kernel of the same
+    file: what :func:`use_pallas_latent_decode` asks, and heads that fill
+    whole sublane tiles (a query tile ``[slots, heads, lanes]`` is read as
+    ``[slots x heads, lanes]`` where it lies)."""
+    return heads % 16 == 0 and use_pallas_latent_decode(lanes)
+
+
 def attention_paths(cfg: ModelConfig):
     """(decode, prefill): which path each step's attention takes in this
     process, for the engine's boot line."""
-    kernel = use_pallas_latent_decode(cache_lanes(cfg))
-    return ("pallas-latent" if kernel else "xla-absorbed-latent",
-            "xla-expanded-latent")
+    lanes = cache_lanes(cfg)
+    return ("pallas-latent" if use_pallas_latent_decode(lanes)
+            else "xla-absorbed-latent",
+            "pallas-latent" if use_pallas_latent_prefill(lanes, cfg.num_heads)
+            else "xla-expanded-latent")
+
+
+def prefill_attn_tiles(cfg: ModelConfig, bucket_len: int, prefix_blocks: int,
+                       block_size: int, cached_len: int, num_new_tokens: int):
+    """((query tile, key stage) pairs a layer's prefill attention computes,
+    pairs in its grid) for one chunk, by the latent prefill kernel's own
+    rule: what the engine's ``kv_tiles_live`` / ``kv_tiles_grid`` count for
+    this module (``core/engine.py: _count_kv_tiles``)."""
+    from production_stack_tpu.engine.ops.pallas.latent_attention import (
+        count_tiles,
+    )
+
+    return count_tiles(
+        bucket_len, cached_len, num_new_tokens, num_heads=cfg.num_heads,
+        prefix_blocks=prefix_blocks, block_size=block_size)
 
 
 def _latent_walk(q_lat, cache, block_tables, ctx_lens, latent_rank, scale):
@@ -537,6 +601,25 @@ def _latent_walk(q_lat, cache, block_tables, ctx_lens, latent_rank, scale):
     return acc / jnp.maximum(l, 1e-30)[..., None]
 
 
+def _into_latent(w, cfg, q_nope, q_rope, lanes):
+    """Queries [S, H, .] as the cache's rows are laid out: ``[q~ ; q_rope ;
+    0]`` [S, H, lanes], ``q~ = W_UK^T q_nope``; ``w`` is :func:`_kv_b`'s."""
+    S, H = q_nope.shape[:2]
+    return jnp.concatenate([
+        jnp.einsum("shd,lhd->shl", q_nope, w[..., :cfg.qk_nope_head_dim],
+                   preferred_element_type=jnp.float32).astype(q_nope.dtype),
+        q_rope,
+        jnp.zeros((S, H, lanes - cache_width(cfg)), q_rope.dtype)],
+        axis=-1)
+
+
+def _out_of_latent(w, cfg, latent):
+    """``W_UV`` lifts the weighed latents [S, H, latent] -> [S, H, v]."""
+    out = jnp.einsum("shl,lhd->shd", latent, w[..., cfg.qk_nope_head_dim:],
+                     preferred_element_type=jnp.float32)
+    return out.astype(latent.dtype)
+
+
 def _absorbed_attention(layer, cfg, q_nope, q_rope, cache, block_tables,
                         ctx_lens):
     """Decode: one query a row [S, H, .] over that row's latent pages as they
@@ -548,16 +631,9 @@ def _absorbed_attention(layer, cfg, q_nope, q_rope, cache, block_tables,
     on a TPU (each live page HBM -> VMEM once, key and value at once) and by
     :func:`_latent_walk` elsewhere (:func:`use_pallas_latent_decode`): the
     same operand dtypes and float32 statistics on both."""
-    S, H = q_nope.shape[:2]
-    L, nope = cfg.kv_lora_rank, cfg.qk_nope_head_dim
-    lanes = cache.shape[-1]
+    L, lanes = cfg.kv_lora_rank, cache.shape[-1]
     w = _kv_b(layer, cfg)
-    q_lat = jnp.concatenate([
-        jnp.einsum("shd,lhd->shl", q_nope, w[..., :nope],
-                   preferred_element_type=jnp.float32).astype(q_nope.dtype),
-        q_rope,
-        jnp.zeros((S, H, lanes - cache_width(cfg)), q_rope.dtype)],
-        axis=-1)                                            # [S, H, lanes]
+    q_lat = _into_latent(w, cfg, q_nope, q_rope, lanes)
     scale = softmax_scale(cfg)
     if use_pallas_latent_decode(lanes):
         from production_stack_tpu.engine.ops.pallas.latent_attention import (
@@ -570,9 +646,7 @@ def _absorbed_attention(layer, cfg, q_nope, q_rope, cache, block_tables,
         latent = _latent_walk(
             q_lat, cache, block_tables, ctx_lens, L, scale
         ).astype(q_nope.dtype)
-    out = jnp.einsum("shl,lhd->shd", latent, w[..., nope:],
-                     preferred_element_type=jnp.float32)
-    return out.astype(q_nope.dtype)                          # [S, H, v]
+    return _out_of_latent(w, cfg, latent)                    # [S, H, v]
 
 
 # -- the feed-forward halves -------------------------------------------------
@@ -813,10 +887,9 @@ def prefill(
 
     def attention(layer, cache, h):
         q_nope, q_rope, rows = _project(layer, cfg, h, cos, sin)
-        with jax.named_scope("latent_attention_expanded"):
-            out = _expanded_attention(
-                layer, cfg, q_nope, q_rope, rows, cache, prefix_block_ids,
-                cached_len, valid_len)
+        out = _prefill_attention(
+            layer, cfg, q_nope, q_rope, rows, cache, prefix_block_ids,
+            cached_len, valid_len)
         bs = cache.shape[1]
         return out, cache.at[new_block_ids].set(
             rows.reshape(T // bs, bs, -1).astype(cache.dtype))

@@ -106,9 +106,11 @@ class WindowRecord:
     # tokens really computed / token slots of the prefill or chunk program
     # that ran (the rest is padding).  ``cached_tokens``: tokens of those
     # prompts already in the KV cache and skipped.  ``kv_tiles_live`` /
-    # ``kv_tiles_grid``: kv tiles, per layer, the flash prefill kernel
-    # computes / its grid holds for those chunks (its liveness rule,
-    # evaluated on the host; the rest is skipped).  ``cover``: a dedicated
+    # ``kv_tiles_grid``: kv tiles, per layer, the prefill attention kernel
+    # computes / its grid holds for those chunks (the flash prefill kernel's
+    # liveness rule, or the module's own -- the latent prefill kernel's
+    # (query tile, key stage) pairs -- evaluated on the host; the rest is
+    # skipped).  ``cover``: a dedicated
     # prefill's bucket and those of the chunks its prompt still has to run
     # (scheduler.cover_prefill): [256, 256, 256], [256, 256], [256] are
     # one 600-token prompt.

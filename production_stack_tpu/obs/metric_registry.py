@@ -299,7 +299,9 @@ REGISTRY = {
     "tpu:prefill_attn_tiles_total": {
         "kind": "counter", "layer": "engine", "labels": ("state",),
         "mirrors": ("fake_engine", "dashboard", "docs"),
-        "help": "Kv tiles of the flash prefill kernel's grid, per layer, "
+        "help": "Kv tiles of the prefill attention kernel's grid (the flash "
+                "prefill kernel's, or the module's own: the latent prefill "
+                "kernel's query-tile x key-stage pairs), per layer, "
                 "over dispatched prefill chunks (state: live — computed; "
                 "skipped — wholly masked, neither fetched nor computed: "
                 "gathered prefix slots past cached_len, new keys past "

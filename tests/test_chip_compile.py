@@ -5,8 +5,8 @@ a topology that is described (``v5e:2x2``), about two seconds a kernel.
 Interpret mode (every other kernel test) cannot see what Mosaic refuses —
 a slice not aligned to the tiling, too much VMEM — so these compiles guard
 each kernel variant the engine can dispatch for mistral-7b, at its
-published widths and at its tp=4 shard shapes, and the latent decode kernel
-at sarvam-105b's, against every later PR.
+published widths and at its tp=4 shard shapes, and the latent decode and
+prefill kernels at sarvam-105b's and xing4.0's, against every later PR.
 A compile that passes is not a chip run: nothing executes here.
 
 The topology is described inside a module-scoped fixture (never at import,
@@ -29,6 +29,7 @@ from production_stack_tpu.engine.ops.pallas.flash_prefill import (
 )
 from production_stack_tpu.engine.ops.pallas.latent_attention import (
     latent_decode_attention_pallas,
+    latent_prefill_attention_pallas,
 )
 from production_stack_tpu.engine.ops.pallas.paged_attention import (
     paged_decode_attention_pallas,
@@ -166,6 +167,35 @@ def test_latent_decode_kernel_compiles(sds, no_persistent_cache, S, preset):
         sds((34959, BS, lanes), jnp.bfloat16),
         sds((S, cfg.max_model_len // BS), jnp.int32), sds((S,), jnp.int32),
     )
+
+
+# The latent prefill kernel at the same two models' widths, both prefill
+# programs' slots (256: a round of sessions-20k; 2,048: a history's chunk,
+# whose own rows are four stages), the cell's pool and its 2,048-entry block
+# table in SMEM: a query tile of 1,024 rows x 640 lanes, a stage's fp32 scores
+# and probabilities, the accumulator and the ring have to fit the VMEM the
+# kernel asks for, and [slots, heads, lanes] has to read as rows where it lies.
+@pytest.mark.parametrize("preset", ["sarvam-105b-ep4",
+                                    "xing4.0-29b-a4b-stage"])
+@pytest.mark.parametrize("T", [256, 2048], ids=["T256", "T2048"])
+def test_latent_prefill_kernel_compiles(sds, no_persistent_cache, T, preset):
+    from production_stack_tpu.engine.models import sarvam_mla
+
+    cfg = PRESETS[preset]
+    lanes = sarvam_mla.cache_lanes(cfg)
+    compiled = _compile(
+        lambda q, rows, c, ids, cached, valid: latent_prefill_attention_pallas(
+            q, rows, c, ids, cached, valid, latent_rank=cfg.kv_lora_rank,
+            scale=sarvam_mla.softmax_scale(cfg),
+        ),
+        sds((T, cfg.num_heads, lanes), jnp.bfloat16),
+        sds((T, lanes), jnp.bfloat16),
+        sds((34959, BS, lanes), jnp.bfloat16),
+        sds((cfg.max_model_len // BS,), jnp.int32),
+        sds((), jnp.int32), sds((), jnp.int32),
+    )
+    # The name the trace and ``breakdown.device_ops`` show.
+    assert "latent_prefill_attention_pallas" in compiled.as_text()
 
 
 # The residual mappings' normalisation kernel at the shapes the

@@ -1,5 +1,7 @@
-"""Pallas paged latent (MLA) decode kernel vs the XLA walk it replaces on a
-TPU (``models/sarvam_mla.py: _latent_walk``), which stays the CPU path.
+"""Pallas paged latent (MLA) kernels vs the XLA paths they replace on a TPU,
+which stay the CPU paths: the decode kernel against
+``models/sarvam_mla.py: _latent_walk``, the prefill kernel against
+``_expanded_attention``.
 
 Runs the kernel in Pallas interpret mode on the CPU, on the tiny preset's
 shapes: 4 heads, a cache row of 48 values (latent 32, rotary key 16) padded
@@ -222,15 +224,24 @@ def test_the_kernel_serves_on_a_tpu_alone(monkeypatch):
     assert not sarvam_mla.use_pallas_latent_decode(640)   # the CPU
     assert sarvam_mla.attention_paths(cfg) == (
         "xla-absorbed-latent", "xla-expanded-latent")
+    assert not sarvam_mla.use_pallas_latent_prefill(640, 64)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert sarvam_mla.use_pallas_latent_decode(640)
+    assert sarvam_mla.use_pallas_latent_prefill(640, 64)
     # What the engine's boot line says (core/engine.py).
     assert sarvam_mla.attention_paths(cfg) == (
+        "pallas-latent", "pallas-latent")
+    assert sarvam_mla.attention_paths(PRESETS["xing4.0-29b-a4b-stage"]) == (
+        "pallas-latent", "pallas-latent")
+    # Four heads do not fill a sublane tile: the chunk stays on the walk.
+    assert sarvam_mla.attention_paths(PRESETS["tiny-sarvam"]) == (
         "pallas-latent", "xla-expanded-latent")
     assert sarvam_mla.use_pallas_latent_decode(128)
     assert not sarvam_mla.use_pallas_latent_decode(576)
+    assert not sarvam_mla.use_pallas_latent_prefill(576, 64)
     monkeypatch.setenv("PSTPU_DISABLE_PALLAS", "1")
     assert not sarvam_mla.use_pallas_latent_decode(640)
+    assert not sarvam_mla.use_pallas_latent_prefill(640, 64)
 
 
 def test_the_dense_model_does_not_import_the_kernel():

@@ -15,7 +15,8 @@ dispatched inside the measured window it tables, for each kind of dispatch
 - ``window_chained``  a decode window chained off an in-flight carry,
 
 the count, a second, and the mean / median / 95th percentile of its ``build``
-and ``launch`` spans in ms, how many dispatches a second were unchained
+and ``launch`` spans in ms, for prefills the records' ``kv_tiles_live`` /
+``kv_tiles_grid`` added up and the share skipped, how many dispatches a second were unchained
 (prefills + rebuilt windows), and for those two kinds **behind / device
 empty**: how many were launched while another program was in flight (the
 record's ``behind``, PR 51) and how many met an empty device, with the
@@ -80,6 +81,13 @@ def table(windows: list, lo: float, hi: float) -> dict:
         if kind in UNCHAINED:
             entry["behind"] = sum(bool(w.get("behind")) for w in rows)
             entry["device_empty"] = len(rows) - entry["behind"]
+        if kind == "prefill":
+            # Tiles of the prefill attention kernel's grid, a layer, by the
+            # kernel's own rule (PR 26; the latent module's since PR 57).
+            live = sum(w.get("kv_tiles_live", 0) for w in rows)
+            grid = sum(w.get("kv_tiles_grid", 0) for w in rows)
+            entry["kv_tiles"] = {"live": live, "grid": grid,
+                                 "skipped_share": 1 - live / max(grid, 1)}
         for phase in ("build", "launch"):
             ms = sorted(span_ms(w, phase) for w in rows)
             entry[phase + "_ms"] = {
