@@ -1,8 +1,11 @@
 """Per-layer metrics: each is a data file ``layer_metrics/<name>.json`` that
-names a reader module in ``readers/`` and its arguments (``<base>.<cells>``
-reads ``<base>.json`` where it has no file of its own).  A reader takes the
-run's :class:`Context` and returns a number, or None where it found nothing
-to read; the harness then leaves the metric out of the line.
+names a reader module in ``readers/`` and its arguments.  A configuration
+whose reader for a quantity is its own brings
+``layer_metrics/<config>/<name>.json``, which is looked for first; an entry
+split by what its cells report (``<base>.<suffix>``) reads ``<base>.json``
+where it has no file of its own.  A reader takes the run's :class:`Context`
+and returns a number, or None where it found nothing to read; the harness
+then leaves the metric out of the line.
 """
 
 from __future__ import annotations
@@ -61,30 +64,42 @@ def find(dirs: List[str], kind: str, name: str,
     raise SystemExit(f"bench: no {kind}/{name}.json under {dirs}")
 
 
-def spec_file(name: str, dirs: List[str],
+def spec_file(name: str, dirs: List[str], config: str,
               missing_ok: bool = False) -> Optional[str]:
-    """The metric's own file, or for a quantity split by cells because they
-    report different end-to-end metrics (``queue_wait_mean_ms.chat-steady``)
-    the file of its base name: the split copies read alike."""
-    return (find(dirs, "layer_metrics", name, missing_ok=True)
-            or find(dirs, "layer_metrics", name.split(".")[0], missing_ok))
+    """The configuration's own file for the metric
+    (``layer_metrics/<config>/<name>.json``: one quantity, another module's
+    program and bytes) before the metric's file; and each by the metric's
+    name before its base name, the file that the entries of one quantity
+    split by cells share (``queue_wait_mean_ms.chat-steady``: the cells
+    report different end-to-end metrics, the copies read alike)."""
+    base = name.split(".")[0]
+    own = os.path.join("layer_metrics", config)
+    for kind, stem in ((own, name), (own, base), ("layer_metrics", name)):
+        path = find(dirs, kind, stem, missing_ok=True)
+        if path:
+            return path
+    return find(dirs, "layer_metrics", base, missing_ok)
 
 
-def spec_of(name: str, dirs: List[str]) -> Dict:
-    with open(spec_file(name, dirs)) as f:
+def spec_of(name: str, dirs: List[str], config: str,
+            missing_ok: bool = False) -> Optional[Dict]:
+    path = spec_file(name, dirs, config, missing_ok)
+    if path is None:
+        return None
+    with open(path) as f:
         return json.load(f)
 
 
-def is_count(name: str, dirs: List[str]) -> bool:
+def is_count(name: str, dirs: List[str], config: str) -> bool:
     """A count may be printed by a CPU rehearsal; a time may not."""
-    path = spec_file(name, dirs, missing_ok=True)
-    return path is not None and bool(spec_of(name, dirs).get("count"))
+    spec = spec_of(name, dirs, config, missing_ok=True)
+    return spec is not None and bool(spec.get("count"))
 
 
 def read_all(ctx: Context, names: List[str]) -> Dict:
     out = {}
     for name in names:
-        spec = spec_of(name, ctx.dirs)
+        spec = spec_of(name, ctx.dirs, ctx.cell["config"])
         reader = importlib.import_module("readers." + spec["reader"])
         out[name] = reader.read(ctx, spec.get("args", {}))
     return out

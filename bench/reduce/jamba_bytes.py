@@ -1,6 +1,6 @@
 """Bytes and operations a ``jamba`` model's steps have to move, from shapes:
-the arithmetic behind ``decode_step_bw_share.jamba-20k``,
-``ssm_decode_bw_share.jamba-20k`` and ``ssm_prefill_roofline_share.jamba-20k``,
+the arithmetic behind this configuration's ``decode_step_bw_share``,
+``ssm_decode_bw_share`` and ``ssm_prefill_roofline_share``,
 kept with the benchmark so that no later PR can move it.  ``hp`` holds the
 sizes the chip holds (``harness/sizes.py: held``) under the keys of a ``jamba``
 configuration: layer ``i`` is attention where ``i % attn_layer_period ==

@@ -1,7 +1,7 @@
 """Bytes a ``laguna`` model's decode step has to move, from shapes: the
-arithmetic behind ``decode_step_bw_share.laguna-20k``,
-``paged_decode_bw_share.laguna-20k``, ``window_decode_bw_share.laguna-20k`` and
-``routed_decode_bw_share.laguna-20k``, kept with the benchmark so that no
+arithmetic behind this configuration's ``decode_step_bw_share``,
+``paged_decode_bw_share``, ``window_decode_bw_share`` and
+``routed_decode_bw_share``, kept with the benchmark so that no
 later PR can move it.  ``hp`` holds the sizes the chip holds
 (``harness/sizes.py: held``) under the keys of a ``laguna`` configuration:
 layer ``i`` is ``layer_types[i]`` (``full_attention``: keys in pages, every

@@ -1,6 +1,7 @@
 """Bytes of latent cache a decode step has to read, from shapes: the
-arithmetic behind ``routed_decode_bw_share``'s cache term, kept with the
-benchmark so that no later PR can move it."""
+arithmetic behind the cache term of the latent configurations'
+``decode_step_bw_share``, kept with the benchmark so that no later PR can
+move it."""
 
 from __future__ import annotations
 

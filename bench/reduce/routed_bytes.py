@@ -1,8 +1,8 @@
 """Bytes of weights a decode step of a routed model held by share has to
-read, from shapes: the arithmetic behind ``routed_decode_bw_share``, kept
-with the benchmark so that no later PR can move it.  ``hp`` holds the sizes
-the chip holds (``harness/sizes.py: held``), under the keys of a
-``sarvam_mla`` configuration; bf16 weights."""
+read, from shapes: the arithmetic behind this configuration's
+``decode_step_bw_share``, kept with the benchmark so that no later PR can
+move it.  ``hp`` holds the sizes the chip holds (``harness/sizes.py:
+held``), under the keys of a ``sarvam_mla`` configuration; bf16 weights."""
 
 from __future__ import annotations
 

@@ -1,6 +1,6 @@
 """Bytes and operations a ``solar_open2`` model's steps have to move, from
-shapes: the arithmetic behind ``routed_decode_bw_share.solar-20k``,
-``kda_decode_bw_share.solar-20k`` and ``kda_prefill_roofline_share.solar-20k``,
+shapes: the arithmetic behind this configuration's ``decode_step_bw_share``,
+``kda_decode_bw_share`` and ``kda_prefill_roofline_share``,
 kept with the benchmark so that no later PR can move it.  ``hp`` holds the
 sizes the chip holds (``harness/sizes.py: held``) under the keys of a
 ``solar_open2`` configuration: ``gqa_layers`` lists the held layers that are

@@ -1,7 +1,7 @@
 """Bytes of weights a decode step of a ``xing4_0`` model has to read, from
-shapes: the arithmetic behind ``routed_decode_bw_share.xing-20k``, kept with
-the benchmark so that no later PR can move it.  ``hp`` holds the sizes the
-chip holds (``harness/sizes.py: held``) under the keys of a ``xing4_0``
+shapes: the arithmetic behind this configuration's ``decode_step_bw_share``,
+kept with the benchmark so that no later PR can move it.  ``hp`` holds the
+sizes the chip holds (``harness/sizes.py: held``) under the keys of a ``xing4_0``
 configuration: every routed expert is held, the query goes through a latent
 of ``q_lora_rank``, and each sub-layer reads a float32 mapping of its
 ``hc_mult`` residual streams.  Weights bf16 unless said."""

@@ -402,7 +402,8 @@ def main() -> None:
         "attempted": summary["attempted"],
         "failed": summary["failed"],
         "metrics": {
-            k: {"value": v if (timed or layers.is_count(k, dirs)) else None,
+            k: {"value": v if (timed or layers.is_count(
+                k, dirs, cell["config"])) else None,
                 "unit": units[k]}
             for k, v in values.items() if v is not None
         },

@@ -5,7 +5,7 @@ import types
 
 import pytest
 
-from conftest import BENCH
+from conftest import BENCH, benchmark_file, hold_a_cell_to_the_rule
 from generators import open_loop, sessions
 from harness import layers
 from harness.client import Record
@@ -345,7 +345,7 @@ def test_a_dropped_in_config_traffic_generator_and_metric_are_found(tmp_path):
         # there; a reader that finds nothing gives None.
         got = layers.read_all(
             types.SimpleNamespace(late_ms=[], delta=lambda family: None,
-                                  dirs=dirs),
+                                  dirs=dirs, cell=cell),
             ["answer_42", "gen_late_p95_ms", "prefix_hit_share"])
         assert got == {"answer_42": 42.0, "gen_late_p95_ms": None,
                        "prefix_hit_share": None}
@@ -405,6 +405,11 @@ ROUTED_SIZES = {
 # Float32 against float32 on the CPU: a sound run reads at most 3.3e-7 and the
 # mildest fault (five of six) at least 1.4e-2, twelve seeds each.
 ROUTED_RTOL = 1e-3
+# What the dropped-in cell reports per layer: the dense readers' step (the
+# paged kernel is on its path) and what every model's engine counts.
+ROUTED_REPORTS = ("decode_step_dev_ms", "device_idle_share",
+                  "compiles_in_window", "seqs_per_window",
+                  "gen_late_p95_ms.chat-steady")
 
 
 @pytest.fixture(scope="module")
@@ -431,6 +436,11 @@ def routed_cell(tmp_path_factory):
     bench["workloads"].append({
         "name": "routed-tiny.chat-steady", "config": "routed-tiny",
         "traffic": "chat-steady", "chips": 1, "why": "new"})
+    # A later PR's cell joins the lists of the entries it reports: an
+    # append to each, no entry edited otherwise and none without a list.
+    for m in bench["per_layer"]:
+        if m["name"] in ROUTED_REPORTS:
+            m["workloads"].append("routed-tiny.chat-steady")
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
     root = tmp_path / "bench"
     for d in ("configs", "reference"):
@@ -498,7 +508,7 @@ def test_a_dropped_in_config_of_another_architecture_cut_in_depth(routed_cell):
     # calls over the 2 held layers are 8 steps of 10 ms; the dense-MLP
     # bandwidth share is not this cell's and is never read.
     names = run.metric_names(bench, cell["name"], traced=True)
-    assert "decode_step_dev_ms" in names
+    assert sorted(names) == sorted(ROUTED_REPORTS)
     assert "decode_step_bw_share" not in names
     assert "decode_step_bw_share" in run.metric_names(
         bench, "m7b-int8.chat-steady", traced=True)
@@ -734,30 +744,41 @@ def test_a_share_of_the_experts_and_a_one_array_cache_are_additions_only(
     assert max(c.max() for c in choice) > 8
 
 
-def test_every_name_in_the_benchmark_file_has_its_files():
+@pytest.mark.parametrize(
+    "cell_name", [w["name"] for w in benchmark_file()["workloads"]])
+def test_every_name_in_the_benchmark_file_has_its_files(cell_name):
+    """A cell's generator and reference are there, and every per-layer
+    entry that lists it finds a spec file and a reader for the cell's
+    configuration and moves what the cell reports."""
     import run
 
     path = os.path.join(BENCH, "..", "BENCHMARK.json")
-    with open(path) as f:
-        bench = json.load(f)
-    ends = {m["name"] for m in bench["end_to_end"]}
-    for cell in bench["workloads"]:
-        _b, _c, config, tr, _p, dirs = run.resolve(path, cell["name"])
-        assert os.path.exists(os.path.join(
-            BENCH, "generators", tr["generator"] + ".py"))
-        assert os.path.exists(os.path.join(
-            BENCH, "reference", config["compare"]["reference"] + ".py"))
+    _b, _c, config, tr, _p, _dirs = run.resolve(path, cell_name)
+    assert os.path.exists(os.path.join(
+        BENCH, "generators", tr["generator"] + ".py"))
+    assert os.path.exists(os.path.join(
+        BENCH, "reference", config["compare"]["reference"] + ".py"))
+    hold_a_cell_to_the_rule(cell_name)
+
+
+def test_no_spec_file_is_left_without_an_entry():
+    """Every file under ``layer_metrics/`` is read by some entry: a
+    quantity's file by its entry (or its split copies), a configuration's
+    own file by an entry that lists one of that configuration's cells."""
+    bench = benchmark_file()
+    bases = {m["name"].split(".")[0]: set() for m in bench["per_layer"]}
     for m in bench["per_layer"]:
-        spec = layers.spec_of(m["name"], [BENCH])
-        assert os.path.exists(os.path.join(
-            BENCH, "readers", spec["reader"] + ".py")), m["name"]
-        assert m["moves"] in ends
-    # What a per-layer metric moves is reported in every cell where it is.
-    cells = {w["name"] for w in bench["workloads"]}
-    where = {m["name"]: set(m.get("workloads", cells))
-             for m in bench["end_to_end"]}
-    for m in bench["per_layer"]:
-        assert set(m.get("workloads", cells)) <= where[m["moves"]], m["name"]
+        bases[m["name"].split(".")[0]] |= set(m["workloads"])
+    configs = {w["name"]: w["config"] for w in bench["workloads"]}
+    top = os.path.join(BENCH, "layer_metrics")
+    for where, _dirs, files in os.walk(top):
+        for file in files:
+            quantity = file[:-len(".json")].split(".")[0]
+            assert quantity in bases, file
+            config = os.path.relpath(where, top)
+            if config != ".":
+                assert config in {configs[c] for c in bases[quantity]}, (
+                    config, file)
 
 
 def test_the_benchmark_file_keeps_inside_the_contract_limits():
@@ -804,3 +825,10 @@ def test_the_benchmark_file_keeps_inside_the_contract_limits():
                                "program_counter", "host_clock")
     names = [m["name"] for m in bench["end_to_end"] + bench["per_layer"]]
     assert len(names) == len(set(names))
+    # Room for the next cell's entries, and a list on every entry, so that a
+    # PR that adds a cell never meets an entry that covers it unasked.
+    cells = {w["name"] for w in bench["workloads"]}
+    assert 1 <= len(bench["per_layer"]) <= 128
+    for m in bench["per_layer"]:
+        assert m.get("workloads") and set(m["workloads"]) <= cells, m["name"]
+        assert len(m["workloads"]) == len(set(m["workloads"])), m["name"]

@@ -10,7 +10,7 @@ import types
 
 import pytest
 
-from conftest import BENCH
+from conftest import BENCH, benchmark_file, hold_a_cell_to_the_rule
 from harness import layers
 from test_join import load
 
@@ -45,15 +45,13 @@ def test_the_state_space_rehearsal_runs_through_the_harness(tmp_path):
     metrics = {k: v["value"] for k, v in result["metrics"].items()}
     assert metrics["compiles_in_window"] == 0
     # A user new in the window shares the system prompt's keys and no state.
-    assert 50 < metrics["state_resume_share.jamba-20k"] <= 100
-    assert 0 <= metrics["state_recompute_share.jamba-20k"] < 25
+    assert 50 < metrics["state_resume_share"] <= 100
+    assert 0 <= metrics["state_recompute_share"] < 25
     assert metrics["prefix_hit_share"] > 50
     assert set(result["compared"]) >= {"decode_step_1", "served_path_faults"}
     # No timing leaves a CPU rehearsal.
-    for name in ("decode_step_bw_share.jamba-20k",
-                 "ssm_decode_bw_share.jamba-20k",
-                 "ssm_prefill_roofline_share.jamba-20k",
-                 "decode_step_dev_ms.jamba-20k"):
+    for name in ("decode_step_bw_share", "ssm_decode_bw_share",
+                 "ssm_prefill_roofline_share", "decode_step_dev_ms"):
         assert metrics.get(name) is None
 
 
@@ -120,7 +118,8 @@ def _trace():
 def _context(trace, prom=None, config=None):
     before, after = prom or ({}, {})
     return layers.Context(
-        cell={"name": CELL, "chips": 1}, config=config or _config(),
+        cell={"name": CELL, "config": "jamba2-3b", "chips": 1},
+        config=config or _config(),
         records=[], late_ms=[], got={
             "windows": load("join_small.windows.json"), "wall_t0": 0.0,
             "seconds": 4e9, "before": {"prom": before}, "after": {
@@ -129,7 +128,7 @@ def _context(trace, prom=None, config=None):
 
 
 def _read(ctx, name):
-    return layers.read_all(ctx, [name + ".jamba-20k"])[name + ".jamba-20k"]
+    return layers.read_all(ctx, [name])[name]
 
 
 def test_the_readers_on_a_sliced_trace():
@@ -158,7 +157,7 @@ def test_the_readers_on_a_sliced_trace():
         (16000 + 14700) / 4 / 1e6)
     # The paged kernel's reader, the file that is there: calls x positions
     # x one layer's K and V, whatever the depth.
-    assert layers.spec_of("paged_decode_bw_share.jamba-20k", [BENCH])[
+    assert layers.spec_of("paged_decode_bw_share", [BENCH], "jamba2-3b")[
         "reader"] == "paged_decode_bw"
 
 
@@ -229,22 +228,20 @@ def test_the_file_keeps_every_published_key_and_cuts_nothing():
         "--no-mixed-batch"]
 
 
-def test_the_entries_list_the_one_cell():
-    with open(os.path.join(BENCH, "..", "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    mine = [m for m in bench["per_layer"] if m["name"].endswith(".jamba-20k")]
-    assert len(mine) == 16
-    reports = {"tpot_p95_ms", "out_tok_s"}
-    for m in mine:
-        assert m["workloads"] == [CELL] and m["moves"] in reports
-        assert layers.spec_file(m["name"], [BENCH]) is not None
-    for name in ("prefix_chain_hashed_share", "build_transfers_per_dispatch",
-                 "dispatch_behind_share"):
-        entry = next(m for m in bench["per_layer"] if m["name"] == name)
-        assert CELL in entry["workloads"]
-    cell = next(w for w in bench["workloads"] if w["name"] == CELL)
+def test_the_entries_that_list_the_cell_hold_the_rule():
+    cell, names = hold_a_cell_to_the_rule(CELL, own=(
+        "decode_step_dev_ms", "decode_step_bw_share", "ssm_decode_bw_share",
+        "ssm_prefill_roofline_share", "paged_decode_bw_share",
+        "state_resume_share", "state_recompute_share",
+        "paged_coalesced_share", "prefix_chain_hashed_share",
+        "build_transfers_per_dispatch", "dispatch_behind_share"))
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "jamba2-3b", "sessions-20k", 1)
-    entry = next(c for c in bench["configs"] if c["name"] == "jamba2-3b")
+    # The step and its share by this module's bytes, not the dense reader's.
+    for name in ("decode_step_bw_share", "state_resume_share"):
+        assert layers.spec_of(name, [BENCH], "jamba2-3b")[
+            "reader"] == "jamba_decode", name
+    entry = next(c for c in benchmark_file()["configs"]
+                 if c["name"] == "jamba2-3b")
     assert entry["file"] == "bench/configs/jamba2-3b.json"
     assert entry["reduced"] == []

@@ -41,7 +41,7 @@ def load(name):
 def context(payload=None, trace=None, chips=1, engine_argv=(), dirs=None):
     payload = payload if payload is not None else load("join_small.windows.json")
     return layers.Context(
-        cell={"name": "c", "chips": chips},
+        cell={"name": "c", "config": "c", "chips": chips},
         config={"published": HP, "engine_argv": list(engine_argv)},
         records=[], late_ms=[],
         got={"windows": payload, "wall_t0": OFF / 1e9 - 1.0, "seconds": 45,
@@ -95,7 +95,7 @@ def test_prefill_padding_and_device_time():
     ctx = context()
     assert read("prefill_pad_share", ctx) == pytest.approx(
         100.0 * (1 - 100 / 256))
-    assert layers.is_count("prefill_pad_share.chat-steady", ctx.dirs)
+    assert layers.is_count("prefill_pad_share.chat-steady", ctx.dirs, "c")
     assert read("prefill_dev_ms", ctx) == pytest.approx(0.004)
     assert read("prefill_dev_ms.chat-steady", ctx) == pytest.approx(0.004)
 
@@ -175,15 +175,15 @@ def test_a_dropped_in_copy_of_the_new_files_is_found_by_name(tmp_path):
         shutil.copy(os.path.join(BENCH, rel), dest)
     with open(os.path.join(BENCH, "..", "BENCHMARK.json")) as f:
         per_layer = json.load(f)["per_layer"]
-    new = [m["name"] for m in per_layer
-           if m["name"].split(".")[0] + ".json" in
-           {os.path.basename(p) for p in NEW_FILES}]
-    assert sorted(new) == sorted(IDLE + (
-        "prefill_dev_ms", "prefill_dev_ms.chat-steady", "prefill_pad_share",
-        "prefill_pad_share.chat-steady", "paged_decode_bw_share"))
+    files = {os.path.basename(p)[:-len(".json")] for p in NEW_FILES
+             if p.startswith("layer_metrics/")}
+    new = [m["name"] for m in per_layer if m["name"].split(".")[0] in files]
+    # Every quantity of the files is an entry, split or not, and no count.
+    assert {name.split(".")[0] for name in new} == files
     dirs = [str(tmp_path), BENCH]
     for name in new:
-        assert layers.spec_file(name, dirs).startswith(str(tmp_path)), name
+        assert layers.spec_file(name, dirs, "c").startswith(
+            str(tmp_path)), name
     values = layers.read_all(context(dirs=dirs), new)
     assert all(v is not None for v in values.values()), values
     assert sum(values[n] for n in IDLE) == pytest.approx(100.0 * 3800 / 39000)

@@ -11,13 +11,13 @@ import types
 
 import pytest
 
-from conftest import BENCH
+from conftest import BENCH, benchmark_file, hold_a_cell_to_the_rule
 from harness import layers
 from test_join import load
 
 DATA = os.path.join(BENCH, "tests", "data")
 CELL = "laguna-xs.2-ep2.sessions-20k"
-SUFFIX = ".laguna-20k"
+CONFIG = "laguna-xs.2-ep2"
 PAGED, WINDOW = "paged_decode_attention_pallas", "window_decode_attention_pallas"
 
 
@@ -55,19 +55,19 @@ def test_the_window_rehearsal_runs_through_the_harness(tmp_path):
     metrics = {k: v["value"] for k, v in result["metrics"].items()}
     assert metrics["compiles_in_window"] == 0
     # A user new in the window shares the system prompt's keys and no state.
-    assert 50 < metrics["state_resume_share" + SUFFIX] <= 100
-    assert 0 <= metrics["state_recompute_share" + SUFFIX] < 25
-    assert metrics["prefix_hit_share" + SUFFIX] > 50
+    assert 50 < metrics["state_resume_share"] <= 100
+    assert 0 <= metrics["state_recompute_share"] < 25
+    assert metrics["prefix_hit_share"] > 50
     # A window of 24 in pages of 8 against contexts of hundreds.
-    assert 0 < metrics["window_positions_share" + SUFFIX] < 25
-    assert 0 < metrics["experts_touched_share" + SUFFIX] <= 100
+    assert 0 < metrics["window_positions_share"] < 25
+    assert 0 < metrics["experts_touched_share"] <= 100
     assert set(result["compared"]) >= {
         "decode_step_1", "choice_shortfall", "served_path_faults"}
     # No timing leaves a CPU rehearsal.
     for name in ("decode_step_bw_share", "paged_decode_bw_share",
                  "window_decode_bw_share", "routed_decode_bw_share",
                  "decode_step_dev_ms"):
-        assert metrics.get(name + SUFFIX) is None
+        assert metrics.get(name) is None
 
 
 def test_the_compare_runs_on_the_tiny_preset():
@@ -156,7 +156,8 @@ def _windows():
 def _context(trace, prom=None, config=None, windows=None):
     before, after = prom or ({}, {})
     return layers.Context(
-        cell={"name": CELL, "chips": 1}, config=config or _config(),
+        cell={"name": CELL, "config": CONFIG, "chips": 1},
+        config=config or _config(),
         records=[], late_ms=[], got={
             "windows": windows or _windows(), "wall_t0": 0.0,
             "seconds": 4e9, "before": {"prom": before}, "after": {
@@ -165,7 +166,7 @@ def _context(trace, prom=None, config=None, windows=None):
 
 
 def _read(ctx, name):
-    return layers.read_all(ctx, [name + SUFFIX])[name + SUFFIX]
+    return layers.read_all(ctx, [name])[name]
 
 
 def test_the_readers_on_a_sliced_trace():
@@ -290,37 +291,32 @@ def test_the_file_states_the_source_whole_and_every_cut():
         "--no-mixed-batch"]
 
 
-def test_the_entries_name_the_cell_and_every_metric_has_a_reader():
-    """The rule, not a count: whatever carries this cell's suffix lists this
-    cell and no other, moves a metric the cell reports and finds its file by
-    name; the three shared counters list the cell; the dropped-in reader and
-    bytes function are where the harness looks."""
-    with open(os.path.join(BENCH, "..", "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    mine = [m for m in bench["per_layer"] if m["name"].endswith(SUFFIX)]
-    assert mine
-    reports = {"tpot_p95_ms", "out_tok_s"}
-    for m in mine:
-        assert m["workloads"] == [CELL] and m["moves"] in reports
-        assert layers.spec_file(m["name"], [BENCH]) is not None
-        spec = layers.spec_of(m["name"], [BENCH])
-        assert os.path.exists(os.path.join(
-            BENCH, "readers", spec["reader"] + ".py")), m["name"]
-        if m["name"].endswith("_share" + SUFFIX):
-            assert m["unit"] == "%"
-    names = {m["name"][:-len(SUFFIX)] for m in mine}
-    assert {"window_decode_bw_share", "window_positions_share",
-            "paged_decode_bw_share", "decode_step_bw_share",
-            "state_resume_share"} <= names
-    for name in ("prefix_chain_hashed_share", "build_transfers_per_dispatch",
-                 "dispatch_behind_share"):
-        entry = next(m for m in bench["per_layer"] if m["name"] == name)
-        assert CELL in entry["workloads"]
-    cell = next(w for w in bench["workloads"] if w["name"] == CELL)
+def test_the_entries_that_list_the_cell_hold_the_rule():
+    """The rule, not a count (``conftest.hold_a_cell_to_the_rule``); the
+    dropped-in reader and bytes function are where the harness looks."""
+    cell, names = hold_a_cell_to_the_rule(CELL, own=(
+        "decode_step_dev_ms", "decode_step_bw_share", "paged_decode_bw_share",
+        "window_decode_bw_share", "routed_decode_bw_share",
+        "experts_touched_share", "window_positions_share",
+        "state_resume_share", "state_recompute_share",
+        "prefix_chain_hashed_share", "build_transfers_per_dispatch",
+        "dispatch_behind_share"))
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
-        "laguna-xs.2-ep2", "sessions-20k", 1)
+        CONFIG, "sessions-20k", 1)
     assert len(cell["why"]) <= 200
-    entry = next(c for c in bench["configs"] if c["name"] == "laguna-xs.2-ep2")
+    bench = benchmark_file()
+    # The routed layers' part of the step's share is this cell's alone; the
+    # whole step is the name every cell gives it.
+    part = next(m for m in bench["per_layer"]
+                if m["name"] == "routed_decode_bw_share")
+    assert part["workloads"] == [CELL]
+    for name, what in (("routed_decode_bw_share", "routed_bw_share"),
+                       ("decode_step_bw_share", "step_bw_share"),
+                       ("paged_decode_bw_share", "paged_bw_share")):
+        spec = layers.spec_of(name, [BENCH], CONFIG)
+        assert (spec["reader"], spec["args"]["what"]) == (
+            "laguna_decode", what)
+    entry = next(c for c in bench["configs"] if c["name"] == CONFIG)
     assert entry["file"] == "bench/configs/laguna-xs.2-ep2.json"
     assert entry["reduced"] == _config()["reduced"]
     for dropped in ("readers/laguna_decode.py", "reduce/laguna_bytes.py",

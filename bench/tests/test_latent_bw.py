@@ -35,7 +35,8 @@ def _trace(marker=MARKER):
 
 def _context(trace, payload=None):
     return layers.Context(
-        cell={"name": "sarvam-105b-ep4.sessions-20k", "chips": 1},
+        cell={"name": "sarvam-105b-ep4.sessions-20k",
+              "config": "sarvam-105b-ep4", "chips": 1},
         config=_config(), records=[], late_ms=[],
         got={"windows": payload or load("join_small.windows.json"),
              "wall_t0": 0.0, "seconds": 45,
@@ -72,11 +73,22 @@ def test_a_shifted_record_says_nothing():
     assert _read(_context(_trace(), payload)) is None
 
 
-def test_the_entry_lists_the_one_cell_that_runs_the_kernel():
+def test_the_entry_lists_the_cells_that_run_the_kernel():
+    """One entry, and on its list every cell whose configuration keeps a
+    latent cache (a ``kv_lora_rank`` in its file) and no other."""
     with open(os.path.join(BENCH, "..", "BENCHMARK.json")) as f:
-        entry = [m for m in json.load(f)["per_layer"]
-                 if m["name"] == "latent_decode_bw_share"]
+        bench = json.load(f)
+    entry = [m for m in bench["per_layer"]
+             if m["name"].split(".")[0] == "latent_decode_bw_share"]
+    latent = []
+    for cell in bench["workloads"]:
+        file = next(c["file"] for c in bench["configs"]
+                    if c["name"] == cell["config"])
+        with open(os.path.join(BENCH, "..", file)) as f:
+            if "kv_lora_rank" in json.load(f):
+                latent.append(cell["name"])
+    assert len(latent) >= 2
     assert entry == [{
         "name": "latent_decode_bw_share", "unit": "%", "better": "higher",
         "source": "device_trace", "layer": "Kernels", "moves": "tpot_p95_ms",
-        "workloads": ["sarvam-105b-ep4.sessions-20k"]}]
+        "workloads": latent}]

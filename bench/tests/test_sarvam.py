@@ -41,7 +41,7 @@ def test_the_latent_routed_rehearsal_runs_through_the_harness(tmp_path):
     assert set(result["compared"]) >= {
         "choice_shortfall", "return_choice_logits_differ", "decode_step_1"}
     # No timing leaves a CPU rehearsal.
-    assert "host_gap_share" not in metrics or metrics["host_gap_share"] is None
+    assert "host_gap_share" not in metrics
 
 
 def test_the_routed_readers_find_nothing_where_nothing_was_counted():
@@ -80,3 +80,17 @@ def test_the_routed_bytes_are_those_of_the_issues_table():
         + 4096 * 65536)
     assert latent_bytes.latent_bytes_per_token(hp) == 6912
     assert latent_bytes.decode_read_bytes(hp, 1000, 8) == 8 * 1000 * 6912
+
+
+def test_the_entries_that_list_the_cell_hold_the_rule():
+    from conftest import hold_a_cell_to_the_rule
+    from harness import layers
+
+    cell, _names = hold_a_cell_to_the_rule(
+        "sarvam-105b-ep4.sessions-20k", own=(
+            "decode_step_dev_ms", "decode_step_bw_share",
+            "latent_decode_bw_share", "experts_touched_share",
+            "routed_here_share"))
+    spec = layers.spec_of("decode_step_bw_share", [BENCH], cell["config"])
+    assert (spec["reader"], spec["args"]["what"]) == (
+        "routed_decode", "bw_share")

@@ -10,7 +10,7 @@ import types
 
 import pytest
 
-from conftest import BENCH
+from conftest import BENCH, benchmark_file, hold_a_cell_to_the_rule
 from harness import layers
 from test_join import load
 
@@ -46,16 +46,15 @@ def test_the_delta_rule_rehearsal_runs_through_the_harness(tmp_path):
     metrics = {k: v["value"] for k, v in result["metrics"].items()}
     assert metrics["compiles_in_window"] == 0
     # A user new in the window shares the system prompt's keys and no state.
-    assert 50 < metrics["state_resume_share.solar-20k"] <= 100
-    assert 0 <= metrics["state_recompute_share.solar-20k"] < 25
-    assert 0 < metrics["experts_touched_share.solar-20k"] <= 100
+    assert 50 < metrics["state_resume_share"] <= 100
+    assert 0 <= metrics["state_recompute_share"] < 25
+    assert 0 < metrics["experts_touched_share"] <= 100
     assert metrics["prefix_hit_share"] > 50
     assert set(result["compared"]) >= {
         "choice_shortfall", "return_choice_logits_differ", "decode_step_1"}
     # No timing leaves a CPU rehearsal.
-    for name in ("routed_decode_bw_share.solar-20k",
-                 "kda_decode_bw_share.solar-20k",
-                 "kda_prefill_roofline_share.solar-20k"):
+    for name in ("decode_step_bw_share", "kda_decode_bw_share",
+                 "kda_prefill_roofline_share"):
         assert metrics.get(name) is None
 
 
@@ -110,7 +109,8 @@ def _context(trace, payload=None, prom=None):
             w.update(experts_touched=2 * 4 * 11, moe_assigned=2 * 4 * 8 * 2)
     before, after = prom or ({}, {})
     return layers.Context(
-        cell={"name": CELL, "chips": 1}, config=_config(), records=[],
+        cell={"name": CELL, "config": "solar-open2-250b-ep8", "chips": 1},
+        config=_config(), records=[],
         late_ms=[], got={
             "windows": payload, "wall_t0": 0.0, "seconds": 4e9,
             "before": {"prom": before}, "after": {
@@ -119,7 +119,7 @@ def _context(trace, payload=None, prom=None):
 
 
 def _read(ctx, name):
-    return layers.read_all(ctx, [name + ".solar-20k"])[name + ".solar-20k"]
+    return layers.read_all(ctx, [name])[name]
 
 
 def test_the_readers_on_a_sliced_trace():
@@ -140,7 +140,7 @@ def test_the_readers_on_a_sliced_trace():
     # the softmax layer's keys and 2 rows' states in three layers.
     total = (4 * sb.non_expert_bytes(hp) + 2 * 88 * sb.expert_bytes(hp)
              + 2 * (992 + 1024) * 4096 + 2 * sb.decode_state_bytes(hp, 2, 2))
-    assert _read(ctx, "routed_decode_bw_share") == pytest.approx(
+    assert _read(ctx, "decode_step_bw_share") == pytest.approx(
         total / 819e9 / ((16000 + 14700) / 1e9) * 100.0)
     assert _read(ctx, "experts_touched_share") == pytest.approx(
         100.0 * 2 * 88 / (40 * 4 * 4))
@@ -171,10 +171,11 @@ def test_the_readers_find_nothing_where_nothing_was_counted():
     from readers import solar_decode
 
     names = ("kda_decode_bw_share", "kda_prefill_roofline_share",
-             "routed_decode_bw_share", "state_resume_share",
+             "decode_step_bw_share", "state_resume_share",
              "state_recompute_share")
     plain = layers.Context(
-        cell={"name": CELL, "chips": 1}, config=_config(), records=[],
+        cell={"name": CELL, "config": "solar-open2-250b-ep8", "chips": 1},
+        config=_config(), records=[],
         late_ms=[], got={
             "windows": load("join_small.windows.json"), "wall_t0": 0.0,
             "seconds": 4e9, "before": {"prom": {}}, "after": {
@@ -216,15 +217,21 @@ def test_the_file_keeps_every_published_width():
     assert (spec["logits_rtol"], spec["choice_shortfall"]) == (0.065, 0.2)
 
 
-def test_the_entries_list_the_one_cell():
-    with open(os.path.join(BENCH, "..", "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    mine = [m for m in bench["per_layer"] if m["name"].endswith(".solar-20k")]
-    assert len(mine) == 17
-    reports = {"tpot_p95_ms", "out_tok_s"}
-    for m in mine:
-        assert m["workloads"] == [CELL] and m["moves"] in reports
-        assert layers.spec_file(m["name"], [BENCH]) is not None
-    assert bench["workloads"][-1]["name"] == CELL
-    assert bench["configs"][-1]["file"] == (
-        "bench/configs/solar-open2-250b-ep8.json")
+def test_the_entries_that_list_the_cell_hold_the_rule():
+    cell, names = hold_a_cell_to_the_rule(CELL, own=(
+        "decode_step_dev_ms", "decode_step_bw_share", "kda_decode_bw_share",
+        "kda_prefill_roofline_share", "paged_decode_bw_share",
+        "experts_touched_share", "state_resume_share",
+        "state_recompute_share"))
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        "solar-open2-250b-ep8", "sessions-20k", 1)
+    # The whole step against this module's bytes under the name every cell
+    # gives it; the routed layers' part is another quantity (cell 7's).
+    assert "routed_decode_bw_share" not in names
+    for name in ("decode_step_bw_share", "experts_touched_share",
+                 "state_resume_share"):
+        assert layers.spec_of(name, [BENCH], cell["config"])[
+            "reader"] == "solar_decode", name
+    entry = next(c for c in benchmark_file()["configs"]
+                 if c["name"] == cell["config"])
+    assert entry["file"] == "bench/configs/solar-open2-250b-ep8.json"

@@ -51,7 +51,8 @@ AFTER = _prom(13, request_upstream=0.1 + 0.009, ttft=1.0 + 0.570,
 
 def _context(before=BEFORE, after=AFTER, records=RECORDS):
     return layers.Context(
-        cell={"name": "m7b-int8.chat-steady", "chips": 1}, config={},
+        cell={"name": "m7b-int8.chat-steady", "config": "mistral-7b-int8",
+              "chips": 1}, config={},
         records=records, late_ms=[],
         got={"t0": 9.0, "seconds": 45.0,
              "before": {"prom": before}, "after": {"prom": after}},

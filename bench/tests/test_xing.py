@@ -39,14 +39,14 @@ def test_the_several_streams_rehearsal_runs_through_the_harness(tmp_path):
     assert result["attempted"] > 0
     metrics = {k: v["value"] for k, v in result["metrics"].items()}
     assert metrics["compiles_in_window"] == 0
-    assert 0 < metrics["experts_touched_share.xing-20k"] <= 100
-    assert metrics["mhc_clamped_share.xing-20k"] == 0
-    assert 0 < metrics["mhc_sinkhorn_err.xing-20k"] <= 1e5
+    assert 0 < metrics["experts_touched_share"] <= 100
+    assert metrics["mhc_clamped_share"] == 0
+    assert 0 < metrics["mhc_sinkhorn_err"] <= 1e5
     assert metrics["prefix_hit_share"] > 50
     assert set(result["compared"]) >= {
         "choice_shortfall", "return_choice_logits_differ", "decode_step_1"}
     # No timing leaves a CPU rehearsal.
-    assert metrics.get("routed_decode_bw_share.xing-20k") is None
+    assert metrics.get("decode_step_bw_share") is None
 
 
 def test_the_readers_find_nothing_where_nothing_was_counted():
@@ -65,7 +65,7 @@ def test_the_readers_find_nothing_where_nothing_was_counted():
             "what": what, "program": "window_fn",
             "marker": "mhc_sinkhorn_pallas"}) is None
     with open(os.path.join(BENCH, "layer_metrics",
-                           "mhc_clamped_share.xing-20k.json")) as f:
+                           "mhc_clamped_share.json")) as f:
         assert prom_ratio.read(ctx, json.load(f)["args"]) is None
     windows[0].update(moe_assigned=3 * 8 * 5 * 4, moe_assigned_here=480,
                       experts_touched=8 * 5 * 12, mhc_err_e6=31000)
@@ -110,3 +110,17 @@ def test_the_file_keeps_every_published_width():
         assert config[key]
     for key in ("why_rtol", "why_shortfall"):
         assert "float8" in spec[key] and "three" in spec[key], key
+
+
+def test_the_entries_that_list_the_cell_hold_the_rule():
+    from conftest import hold_a_cell_to_the_rule
+    from harness import layers
+
+    cell, _names = hold_a_cell_to_the_rule(
+        "xing4.0-29b-a4b-stage.sessions-20k", own=(
+            "decode_step_dev_ms", "decode_step_bw_share",
+            "latent_decode_bw_share", "experts_touched_share",
+            "mhc_bw_share", "mhc_clamped_share", "mhc_sinkhorn_err"))
+    for name in ("decode_step_bw_share", "experts_touched_share"):
+        assert layers.spec_of(name, [BENCH], cell["config"])[
+            "reader"] == "xing_decode", name
