@@ -914,8 +914,9 @@ class SchedulerConfig:
     # running sequences exist AND a prompt waits, one step packs every
     # running sequence's decode token plus a bounded prefill chunk of the
     # head waiting sequence into ONE model invocation, so arriving prompts
-    # no longer stall all decoders for a full prefill bucket (the ITL
-    # spike the tpu:itl_seconds histogram shows under load).  None = auto
+    # no longer stall all decoders for a full prefill bucket (the share
+    # tpu:request_decode_behind_seconds takes of tpu:decode_time_seconds,
+    # and the tail of tpu:itl_seconds, under load).  None = auto
     # (ON whenever the classic single-step path is active and the mesh has
     # no dp/sp axis); False restores the alternating one-plan-per-step
     # scheduler exactly.

@@ -4312,9 +4312,9 @@ class LLMEngine:
                 seq.first_token_time = now
                 if self.obs.enabled:
                     self.obs.on_first_token(seq, now)
-            elif self.obs.enabled and seq.last_token_time is not None:
-                self.obs.on_token_gap(seq, now - seq.last_token_time)
             if self.obs.enabled:
+                # The gaps between tokens are taken where the record that
+                # carries them closes (obs: _on_record_close), not here.
                 seq.last_token_time = now
             if stop_hit:
                 finish = FinishReason.STOP

@@ -112,8 +112,9 @@ class Sequence:
     finish_reason: Optional[FinishReason] = None
     first_token_time: Optional[float] = None
     # Observability (obs/): first prefill-chunk launch (ends the queue-wait
-    # span) and the previous token's emit time (feeds the engine ITL
-    # histogram).  Maintained only when obs.tracing is on.
+    # span) and the newest token's emit time (with first_token_time, the
+    # stamps the decoder's span on the flight recorder's clock is held
+    # against).  Maintained only when obs.tracing is on.
     first_scheduled_time: Optional[float] = None
     last_token_time: Optional[float] = None
     # A request that came through the API server: ``arrival_time`` is the

@@ -79,7 +79,11 @@ _ENCLOSING = frozenset(STEP_PHASES) - frozenset(PHASES)
 
 # Request-level engine histograms -> ``tpu:*_seconds`` families; one
 # observation per request, EXCEPT itl which observes every token gap (its
-# _count is ~tokens, not requests).  detokenize_time is the request's
+# _count is ~tokens, not requests): a token's gap is its share of the
+# stretch that produced it, (this record's close - the close that gave the
+# row its tokens before) / the tokens the row took at this close, so that a
+# K-step window reads as K gaps of a step's length and not as K - 1 of
+# nothing and one of the window's.  detokenize_time is the request's
 # TOTAL host detokenize cost (accumulated across its tokens in the API
 # server) — a request-level quantity, which is why it lives here and not
 # in the per-step families above.
@@ -93,9 +97,20 @@ _ENCLOSING = frozenset(STEP_PHASES) - frozenset(PHASES)
 # prefill_time / first_token_write are the six gaps, in that order; ttft
 # and e2e_latency start at ``received``, so that ttft = admit + pending +
 # queue + prefill.
+#
+# Who waited for whom on the device, on the flight recorder's clock
+# (EngineObs._on_record_close).  request_prefill_behind: the part of
+# prefill_time in which the request's first prefill program, launched
+# behind the program in flight, waited for the device; observed with ttft's
+# parts.  request_decode_behind: the part of decode_time a request of two
+# tokens or more stood still behind other prompts' prefills, from the record
+# that gave its first token to the one that gave its last, observed where
+# that one closes; its _sum over decode_time's is the share of the decoders'
+# time that went to other requests' prompts.
 REQUEST_HISTS = ("ttft", "itl", "e2e_latency", "queue_time", "prefill_time",
                  "decode_time", "detokenize_time", "request_upstream",
-                 "request_admit", "request_pending", "first_token_write")
+                 "request_admit", "request_pending", "first_token_write",
+                 "request_prefill_behind", "request_decode_behind")
 
 # A step-thread phase longer than this is a stall: one WARNING line and
 # tpu:step_stall_total{phase}.  Every stream the engine serves stands still
@@ -196,6 +211,25 @@ class _PhaseSpan:
 _NULL_PHASE = contextlib.nullcontext()
 
 
+class _Decoder:
+    """One request between the record that gave its first token and the one
+    that gave its last: what EngineObs._on_record_close keeps of it."""
+
+    __slots__ = ("seq", "opened_at", "last_close", "tokens", "own_s",
+                 "own_prefill_s", "own_prefills", "prefill_s0", "prefills0")
+
+    def __init__(self, seq):
+        self.seq = seq
+        self.opened_at: Optional[float] = None  # None: its first record is open
+        self.last_close = 0.0   # the close that last gave it tokens
+        self.tokens = 0         # seq.num_generated as of that close
+        self.own_s = 0.0        # attributed_s of the records it rode
+        self.own_prefill_s = 0.0   # of those, the ``prefill`` records
+        self.own_prefills = 0      # (its own prompt, computed anew)
+        self.prefill_s0 = 0.0   # the recorder's prefill totals at the opening
+        self.prefills0 = 0
+
+
 class EngineObs:
     def __init__(
         self,
@@ -249,8 +283,21 @@ class EngineObs:
         self._profile: Dict[str, List[int]] = {}
         # Phases that lasted over STALL_S, by phase (step thread writes).
         self.step_stalls: Dict[str, int] = dict.fromkeys(PHASES, 0)
+        # Who waits for whom (_on_record_close).  The step thread writes;
+        # on_abort, which the event loop calls too, only pops, and no entry
+        # is put back after a read, so a pop in between leaves nothing
+        # behind.  A request's first prefill record: [None] from its first
+        # schedule to that record's close, then [the record's ``behind_s``]
+        # until its first token takes it.  The requests that are decoding,
+        # and those of them whose first / last token was taken inside the
+        # record that is being collected now.
+        self._first_prefill: Dict[str, List[Optional[float]]] = {}
+        self._decoders: Dict[str, _Decoder] = {}
+        self._opening: List[_Decoder] = []
+        self._closing: List[_Decoder] = []
         if self.enabled:
             self.compile_tracker.on_launch = self._on_launch
+            self.recorder.on_close = self._on_record_close
 
     # -- step phases (engine step thread) ----------------------------------
 
@@ -351,6 +398,7 @@ class EngineObs:
             return
         now = now if now is not None else time.time()
         arrival, submitted, admitted = self._hops(seq)
+        self._first_prefill[seq.seq_id] = [None]
         spans = [("engine.queue", admitted, now)]
         if seq.admitted_time is not None:
             spans = [("engine.admit", arrival, submitted),
@@ -361,9 +409,22 @@ class EngineObs:
     def on_first_token(self, seq, now: float) -> None:
         """The time to first token and its four parts, observed together:
         over any set of requests ttft = admit + pending + queue + prefill
-        exactly (a request that never gets here is in none of them)."""
+        exactly (a request that never gets here is in none of them); and of
+        the prefill part, what its first program waited behind the one in
+        flight.  Called inside the collect of the record that carries the
+        token: the request starts decoding where that record closes."""
         if not self.enabled:
             return
+        behind = self._first_prefill.pop(seq.seq_id, (0.0,))[0]
+        if behind is None:
+            # A prompt of one chunk: its first prefill record is the one
+            # being collected, and what it waited is known before it closes.
+            rec = self._open_rec
+            behind = 0.0
+            if rec is not None and seq.seq_id in rec.seq_ids[rec.rows:]:
+                behind = rec.behind_s = self.recorder.behind_of(rec)
+        decoder = self._decoders[seq.seq_id] = _Decoder(seq)
+        self._opening.append(decoder)
         hists = self.request_hists
         hists["ttft"].observe(now - seq.arrival_time)
         sched = seq.first_scheduled_time
@@ -374,7 +435,9 @@ class EngineObs:
                 hists["request_pending"].observe(admitted - submitted)
             hists["queue_time"].observe(sched - admitted)
             hists["prefill_time"].observe(now - sched)
-            self.tracer.add_span(seq.seq_id, "engine.prefill", sched, now)
+            hists["request_prefill_behind"].observe(behind)
+            self.tracer.add_span(seq.seq_id, "engine.prefill", sched, now,
+                                 behind_s=round(behind, 6))
 
     def on_first_written(self, request_id: str, now: float) -> None:
         """The stream's first write has returned (event loop): the first
@@ -399,10 +462,78 @@ class EngineObs:
         if seconds is not None:
             self.request_hists["first_token_write"].observe(seconds)
 
-    def on_token_gap(self, seq, gap: float) -> None:
-        if not self.enabled:
+    def _on_record_close(self, rec: WindowRecord) -> None:
+        """Every flight record as it closes (FlightRecorder.on_close, step
+        thread): the one account of what each request waited for on the
+        device.  ``attributed_s`` telescopes, so between two closes the
+        records' sum *is* the wall time, less the stretches with nothing in
+        flight; a decoder's span, from the close that gave its first token
+        to the close that gave its last, divides exactly into the records it
+        rode (``own_s``), the ``prefill`` records of other prompts
+        (``prefill_s``, ``prefills``) and the remainder (``rest_s``:
+        windows it did not ride, time nothing was in flight).  One addition
+        a row, no work a token; tpu:itl_seconds is fed here too, a row's
+        stretch shared among the tokens it brought."""
+        now, took = rec.collected_at, rec.attributed_s
+        decoders = self._decoders
+        if decoders:
+            itl = self.request_hists["itl"]
+            for seq_id in rec.seq_ids:
+                d = decoders.get(seq_id)
+                if d is None or d.opened_at is None:
+                    continue
+                d.own_s += took
+                if rec.kind == "prefill":
+                    d.own_prefill_s += took
+                    d.own_prefills += 1
+                n = d.seq.num_generated - d.tokens
+                if n > 0:
+                    itl.observe((now - d.last_close) / n, n)
+                    d.tokens += n
+                    d.last_close = now
+        if self._first_prefill and rec.chunk_prompts:
+            for seq_id in rec.seq_ids[rec.rows:]:
+                first = self._first_prefill.get(seq_id)
+                if first is not None and first[0] is None:
+                    if rec.behind_s is None:
+                        rec.behind_s = self.recorder.behind_of(rec)
+                    first[0] = rec.behind_s
+        if not (self._opening or self._closing):
             return
-        self.request_hists["itl"].observe(gap)
+        prefill_s, prefills = self.recorder.prefill_s, self.recorder.prefills
+        for d in self._opening:
+            # The record that carried the first token, the request's own
+            # prefill, lies before the opening and is in no part.
+            d.opened_at = d.last_close = now
+            d.tokens = d.seq.num_generated
+            d.prefill_s0, d.prefills0 = prefill_s, prefills
+        self._opening.clear()
+        hists = self.request_hists
+        for d in self._closing:
+            seq_id, tokens = d.seq.seq_id, d.seq.num_generated
+            if decoders.pop(seq_id, None) is not d or tokens < 2:
+                continue  # aborted since, or no gap between tokens
+            span_s = now - d.opened_at
+            behind_s = prefill_s - d.prefill_s0 - d.own_prefill_s
+            behind_n = prefills - d.prefills0 - d.own_prefills
+            rest_s = span_s - d.own_s - behind_s
+            hists["request_decode_behind"].observe(max(0.0, behind_s))
+            rec.finished.append([
+                seq_id, tokens, round(span_s, 9), round(d.own_s, 9),
+                round(behind_s, 9), behind_n, round(rest_s, 9)])
+            self._tag_decode_span(
+                seq_id, own_s=round(d.own_s, 6), prefill_s=round(behind_s, 6),
+                prefills=behind_n, rest_s=round(rest_s, 6))
+        self._closing.clear()
+
+    def _tag_decode_span(self, request_id: str, **attrs) -> None:
+        """Attributes onto a (just finished) request's engine.decode span."""
+        def tag(trace):
+            for span in trace.spans:
+                if span.name == "engine.decode":
+                    span.attrs.update(attrs)
+
+        self.tracer.with_trace(request_id, tag)
 
     # stackcheck: allow=SC201 reason=observability timeline math; the whole obs layer is plan-inert by contract (tracing=False removes it entirely and greedy parity is asserted in tests)
     def on_finish(self, seq, now: Optional[float] = None) -> None:
@@ -411,6 +542,12 @@ class EngineObs:
         if not self.enabled:
             return
         now = now if now is not None else time.time()
+        self._first_prefill.pop(seq.seq_id, None)
+        decoder = self._decoders.get(seq.seq_id)
+        if decoder is not None:
+            # Its parts are known where the record that carries its last
+            # token closes (_on_record_close); this runs inside its collect.
+            self._closing.append(decoder)
         self.request_hists["e2e_latency"].observe(now - seq.arrival_time)
         first = seq.first_token_time
         if first is not None:
@@ -437,6 +574,8 @@ class EngineObs:
     def on_abort(self, request_id: str) -> None:
         if not self.enabled:
             return
+        self._first_prefill.pop(request_id, None)
+        self._decoders.pop(request_id, None)
         self.tracer.finish(request_id, aborted=True)
 
     # -- compile taint (engine step thread writes, server reads) -----------

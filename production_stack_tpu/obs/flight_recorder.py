@@ -24,6 +24,12 @@ telescopes — summing a request's windows recovers its decode-phase wall
 time even under the depth-2 lookahead pipeline, where raw
 (collect - dispatch) intervals overlap and would double-count.
 
+The same clock is the account of who waited for whom on the device: the
+recorder keeps the running seconds and count of the ``prefill`` records, and
+``on_close`` hands every record, as it closes, to the one who divides each
+request's time among the records it rode and the prefills it stood behind
+(obs/engine.py: ``EngineObs._on_record_close``).
+
 Disabled (``obs.tracing=False``) the recorder is never consulted: the
 engine gates every call on ``obs.enabled`` and ``on_dispatch`` returns
 None, so the fast path carries zero recorder state.
@@ -35,7 +41,7 @@ import dataclasses
 import threading
 import time
 from collections import deque
-from typing import Deque, Dict, List, Optional, Tuple
+from typing import Callable, Deque, Dict, List, Optional, Tuple
 
 # The closed set of dispatch kinds.  Single-step paths record too —
 # without them the ring has holes and per-request attribution cannot sum
@@ -146,6 +152,18 @@ class WindowRecord:
     # zeros, or a later chunk of its prompt); None without a state pool.
     state_rows: int = 0
     state_resumed: Optional[bool] = None
+    # Who waited for whom, on ``attributed_s``' clock (obs/engine.py).
+    # ``behind_s``, on the record that carried a request's first prefill
+    # chunk: how long after ``dispatched_at`` the record before it was still
+    # being collected -- the program was launched behind one in flight and
+    # waited that long for the device.  ``finished``: one row for each
+    # request of two tokens or more whose last token this record gave,
+    # [seq_id, tokens, span_s, own_s, prefill_s, prefills, rest_s]: from the
+    # close of the record that gave its first token to this close, divided
+    # into the records it rode, the ``prefill`` records of other prompts
+    # that closed meanwhile (and how many) and the remainder.
+    behind_s: Optional[float] = None
+    finished: List[list] = dataclasses.field(default_factory=list)
 
     @property
     def launch_ns(self) -> Optional[int]:
@@ -197,6 +215,10 @@ class WindowRecord:
             d["state_rows"] = self.state_rows
         if self.state_resumed is not None:
             d["state_resumed"] = self.state_resumed
+        if self.behind_s is not None:
+            d["behind_s"] = round(self.behind_s, 6)
+        if self.finished:
+            d["finished"] = [list(row) for row in self.finished]
         if self.spec_width:
             d["spec_width"] = self.spec_width
             d["drafter"] = self.drafter
@@ -232,6 +254,14 @@ class FlightRecorder:
         self._last_collected_at: Optional[float] = None
         self.dropped = 0          # records evicted from a full ring
         self.windows_recorded = 0  # completed records since boot
+        # Running totals of the telescoped clock over the ``prefill``
+        # records (step thread writes): a decoder's wait behind other
+        # prompts is their difference between two closes.
+        self.prefill_s = 0.0
+        self.prefills = 0
+        # Called with every record as it closes, its ``attributed_s`` set
+        # and the totals holding it, before a reader can see it.
+        self.on_close: Optional[Callable[[WindowRecord], None]] = None
 
     # -- step-thread write path -------------------------------------------
 
@@ -329,16 +359,29 @@ class FlightRecorder:
         rec.chunk_tokens_delivered = int(chunk_tokens_delivered)
         rec.drafted = int(drafted)
         rec.accepted = int(accepted)
+        # The clock is the step thread's own (readers see it on the records).
+        prev = self._last_collected_at
+        floor = rec.dispatched_at if prev is None else max(
+            rec.dispatched_at, prev)
+        rec.attributed_s = max(0.0, now - floor)
+        if rec.kind == "prefill":
+            self.prefill_s += rec.attributed_s
+            self.prefills += 1
+        if self.on_close is not None:
+            self.on_close(rec)
+        self._last_collected_at = now
         with self._lock:
-            prev = self._last_collected_at
-            floor = rec.dispatched_at if prev is None else max(
-                rec.dispatched_at, prev)
-            rec.attributed_s = max(0.0, now - floor)
-            self._last_collected_at = now
             if len(self._completed) >= self.ring_size:
                 self.dropped += 1
             self._completed.appendleft(rec)
             self.windows_recorded += 1
+
+    def behind_of(self, rec: WindowRecord) -> float:
+        """How long after its dispatch the record before ``rec`` closed
+        (``on_collect``'s floor less ``dispatched_at``); 0 where nothing was
+        in flight.  Step thread, while ``rec`` is still open."""
+        prev = self._last_collected_at
+        return 0.0 if prev is None else max(0.0, prev - rec.dispatched_at)
 
     def note_compile(self, rec: Optional[WindowRecord], seconds: float) -> None:
         """Mark a record compile-tainted (an XLA compile fired inside its

@@ -42,7 +42,8 @@ class Histogram:
         self.count: int = 0
         self._lock = threading.Lock()
 
-    def observe(self, value: float) -> None:
+    def observe(self, value: float, n: int = 1) -> None:
+        """``n`` observations of ``value`` (one bucket search, one lock)."""
         value = float(value)
         idx = len(self.bounds)
         for i, bound in enumerate(self.bounds):
@@ -50,9 +51,9 @@ class Histogram:
                 idx = i
                 break
         with self._lock:
-            self.counts[idx] += 1
-            self.sum += value
-            self.count += 1
+            self.counts[idx] += n
+            self.sum += value * n
+            self.count += n
 
     def quantile(self, q: float) -> float:
         """Bucket-interpolated quantile estimate (what PromQL's

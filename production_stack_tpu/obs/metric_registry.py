@@ -561,7 +561,9 @@ REGISTRY = {
     "tpu:itl_seconds": {
         "kind": "histogram", "layer": "engine",
         "mirrors": ("fake_engine", "dashboard", "docs"),
-        "help": "Inter-token latency (one observation per token gap)",
+        "help": "Inter-token latency, one observation per token gap: the "
+                "stretch between the closes of the flight records that "
+                "gave a row tokens, shared among the tokens it brought",
     },
     "tpu:e2e_latency_seconds": {
         "kind": "histogram", "layer": "engine",
@@ -600,6 +602,21 @@ REGISTRY = {
         "kind": "histogram", "layer": "engine",
         "mirrors": ("fake_engine", "dashboard", "docs"),
         "help": "Prefill phase per request",
+    },
+    "tpu:request_prefill_behind_seconds": {
+        "kind": "histogram", "layer": "engine",
+        "mirrors": ("fake_engine", "docs"),
+        "help": "Of the prefill phase, how long the request's first prefill "
+                "program, launched behind the program in flight, waited "
+                "for the device (the flight record's behind_s)",
+    },
+    "tpu:request_decode_behind_seconds": {
+        "kind": "histogram", "layer": "engine",
+        "mirrors": ("fake_engine", "docs"),
+        "help": "Of the decode phase of a request of two tokens or more, "
+                "how long it stood still behind other requests' prefill "
+                "records (the flight record's finished row, prefill_s); "
+                "its _sum over tpu:decode_time_seconds_sum is the share",
     },
     "tpu:decode_time_seconds": {
         "kind": "histogram", "layer": "engine",

@@ -424,8 +424,9 @@ TPU_COUNTERS = frozenset({
 
 # Engine request-level families, keyed by obs.EngineObs.REQUEST_HISTS names
 # (one observation per request — except itl, observed per token GAP, so
-# its _count is ~tokens not requests; detokenize_time is the request's
-# total accumulated host detokenize cost).
+# its _count is ~tokens not requests, a token's gap being its share of the
+# stretch between the record closes that produced it; detokenize_time is
+# the request's total accumulated host detokenize cost).
 TPU_REQUEST_HISTOGRAMS = {
     "ttft": "tpu:ttft_seconds",
     "itl": "tpu:itl_seconds",
@@ -445,6 +446,13 @@ TPU_REQUEST_HISTOGRAMS = {
     "request_admit": "tpu:request_admit_seconds",
     "request_pending": "tpu:request_pending_seconds",
     "first_token_write": "tpu:first_token_write_seconds",
+    # Who waited for whom on the device, on the flight recorder's clock
+    # (obs/engine.py: _on_record_close).  Of prefill_time, what the request's
+    # first prefill program waited behind the program in flight; of
+    # decode_time, what the request stood still behind other prompts'
+    # prefills.
+    "request_prefill_behind": "tpu:request_prefill_behind_seconds",
+    "request_decode_behind": "tpu:request_decode_behind_seconds",
 }
 
 # Engine step-phase families, keyed by obs.EngineObs.STEP_PHASES names
