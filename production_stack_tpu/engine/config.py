@@ -947,9 +947,11 @@ class SchedulerConfig:
     # (greedy parity asserted in tests/test_multistep_window.py) and is
     # refused with either drafter: speculation runs inside the window.
     multi_step_window: Optional[bool] = None
-    # Window size K for multi_step_window (compiled-shape inventory grows
-    # by one scan executable per decode bucket; scan compile cost is
-    # ~independent of K).
+    # The most steps a window of multi_step_window runs (compiled-shape
+    # inventory grows by one executable per decode bucket; compile cost is
+    # ~independent of K).  A cap: the scheduler plans each window to the
+    # first row's last token and to what the step thread's pass needs
+    # (scheduler._plan_window), and the program runs what was planned.
     decode_window: int = 8
     # N-gram (prompt-lookup) speculative decoding: draft up to this many
     # tokens by matching the sequence's trailing bigram against its own

@@ -593,7 +593,8 @@ class LLMEngine:
                 "window_fn",
                 step_programs.window_program(
                     partial(model_decode, **counting),
-                    n_steps=self._window_steps, **dims
+                    n_steps=self._window_steps,
+                    n_counts=len(self._routing_names), **dims
                 ),
                 static_argnames=("use_penalties", "use_min_floor"),
                 donate_argnames=("kv_caches",),
@@ -746,6 +747,19 @@ class LLMEngine:
         self.mixed_window_prompts_hist = Histogram(
             bounds=(1.0, 2.0, 4.0, 8.0, 16.0, 32.0)
         )
+        # Steps each pure-decode window was planned to run
+        # (tpu:decode_window_steps; scheduler._plan_window): mass under the
+        # cap is windows that ended with a row's last token or as soon as
+        # the step thread's pass was covered.  Step thread writes.
+        self.window_steps_hist = Histogram(
+            bounds=(1.0, 2.0, 3.0, 4.0, 6.0, 8.0, 16.0)
+        )
+        # Whether this engine's own clocks may shape its plans (the window
+        # length: scheduler.WindowPace).  Cleared by whoever steps this
+        # engine in lockstep with others' (AsyncEngine under a lockstep
+        # channel, a follower's loop): their clocks differ, their plans may
+        # not.
+        self.plan_from_clocks = True
         # Seconds of host<->device transfer work issued WHILE the device
         # was busy with an in-flight window — H2D chunk staging for a
         # chained window plus D2H offload gathers dispatched under the
@@ -1560,13 +1574,15 @@ class LLMEngine:
             ))
             return True
         seqs = plan.decode.seqs
-        if plan.decode_window > 1 and self._can_window(seqs):
+        # A window of one step is still a window: the same program, the
+        # same carry for the next one to chain from.
+        if plan.window_cut is not None and self._can_window(seqs):
             self._pending.append(self._dispatch_window(plan, chain_from=None))
             return True
-        # A K>1 plan that fell out of the window path carries the decline
+        # A window plan that fell out of the window path carries the decline
         # reason onto the replacing K=1 dispatch's flight record.
         decline = plan.window_fallback or (
-            self._last_window_decline if plan.decode_window > 1 else None
+            self._last_window_decline if plan.window_cut is not None else None
         )
         if self._can_pipeline(seqs):
             p = self._dispatch_decode_async(seqs, False)
@@ -2393,8 +2409,9 @@ class LLMEngine:
     def _dispatch_window(self, plan, chain_from: Optional[_PendingStep] = None,
                          behind: Optional[_PendingStep] = None,
                          ) -> _PendingStep:
-        """Enqueue one K-step decode window on the device and return
-        without any host round-trip.  ``chain_from=None`` (re)builds the
+        """Enqueue one decode window of ``plan.decode_window`` steps (the
+        program runs as many as the plan's longest row was budgeted) on the
+        device and return without any host round-trip.  ``chain_from=None`` (re)builds the
         device-resident window state from host bookkeeping;  otherwise
         the state chains from the previous window's in-flight carry
         (pipelined windows — the device never drains between them).
@@ -2404,6 +2421,8 @@ class LLMEngine:
         t0 = time.time()
         decode = plan.decode
         seqs = decode.seqs
+        k = plan.decode_window
+        self.window_steps_hist.observe(k)
         depth = 0
         if chain_from is not None and chain_from.rec is not None:
             depth = chain_from.rec.chain_depth + 1
@@ -2415,7 +2434,7 @@ class LLMEngine:
         rec = self._open_record(
             "decode", seqs=seqs,
             ahead={first[0].seq_id: 1} if first else chain_from or 0,
-            k=self._window_steps, chain_depth=depth,
+            k=k, cut=plan.window_cut, chain_depth=depth,
             provisional=chain_from is not None,
             fallback=plan.window_fallback, behind=behind is not None,
         )
@@ -2525,7 +2544,7 @@ class LLMEngine:
             # advances one per iteration (deterministic on every
             # lockstep replica — acceptance is a pure function of the
             # shared weights and carried state, never of wall clock).
-            self._step_counter += self._window_steps
+            self._step_counter += k
         else:
             # Any non-model-spec dispatch advances positions without
             # extending the draft KV: the chain is broken and the next
@@ -2569,10 +2588,10 @@ class LLMEngine:
                     **state["state_kwargs"],
                 )
             self._count_sample_dispatch(state["sample_sorts"])
-            # One key ordinal per iteration: single-token stepping would
-            # have burned exactly these counter values for the same
-            # tokens.
-            self._step_counter += self._window_steps
+            # One key ordinal per iteration the plan runs: single-token
+            # stepping would have burned exactly these counter values for
+            # the same tokens.
+            self._step_counter += k
         state.update(out_state)
         if rec is not None:
             if spec_stats is not None:
@@ -2827,7 +2846,27 @@ class LLMEngine:
             # compiles onto this record, then complete it.
             self._note_compiles(p.rec, [s.seq_id for s in p.seqs])
             self.obs.recorder.on_collect(p.rec, host_s=p.host_s, **counts)
+            self._note_window_pace(p.rec)
         return outputs
+
+    def _note_window_pace(self, rec) -> None:
+        """Feed the planner's two means (scheduler.WindowPace) from a decode
+        window that has just closed, where it was chained from the window
+        in flight: the telescoped clock then gives its device time
+        (``attributed_s``: the device was never empty), and that less the
+        time the step thread stood blocked in its read-back is what the
+        thread was busy with since the close before -- the whole of its
+        pass, whoever's work it was.  A window that met an empty device, or
+        a compile, times something else."""
+        if not (self.plan_from_clocks and rec.provisional) or rec.compile:
+            return
+        blocked = sum(
+            (t1 - t0) / 1e9 for name, t0, t1 in rec.phases
+            if name == "collect")
+        # A clock reaches a plan here, and only here: ``plan_from_clocks``
+        # is cleared wherever replicas must plan alike.
+        self.scheduler.pace.note(
+            rec.attributed_s / rec.k, max(0.0, rec.attributed_s - blocked))
 
     def _count_routing(self, rec, counts) -> None:
         """Fold a window's routing counts (``[K, n]``, a row a step) into

@@ -101,6 +101,50 @@ class PrefillPlan:
     resumed: bool = False
 
 
+# What ended a pure-decode window (StepPlan.window_cut, a flight record's
+# ``cut``): the configured cap, the first row's last token, or the fewest
+# steps that cover the step thread's own pass.
+WINDOW_CUTS = ("cap", "finish", "host")
+
+
+class WindowPace:
+    """How many steps a decode window needs for the device to outlast the
+    step thread's pass over it: ``ceil(COVER x pass_s / step_s)``, never
+    under ``FLOOR``, from two running means the engine feeds as its chained
+    decode windows close (engine.py: ``_note_window_pace``) -- a step's
+    device time, and the thread's busy time a window.  None until both have
+    ``MIN_SAMPLES``; the planner then keeps the cap.
+
+    A wall-clock quantity in a plan: the engine feeds it on a single host
+    only.  Replicas in lockstep must plan alike and clocks differ, so their
+    engines never call ``note`` and their windows end by cap and by budget."""
+
+    # The margin: a window lasts this many passes of the step thread.  Fixed
+    # by a sweep on the chip (PERF.md section 5, PR 60).
+    COVER = 3.0
+    FLOOR = 2
+    MIN_SAMPLES = 4
+    # The means follow the last ~32 windows: the batch grows and shrinks.
+    HORIZON = 32
+
+    def __init__(self):
+        self.samples = 0
+        self.step_s = 0.0
+        self.pass_s = 0.0
+
+    def note(self, step_s: float, pass_s: float) -> None:
+        self.samples += 1
+        weight = 1.0 / min(self.samples, self.HORIZON)
+        self.step_s += (step_s - self.step_s) * weight
+        self.pass_s += (pass_s - self.pass_s) * weight
+
+    def cover(self) -> Optional[int]:
+        if self.samples < self.MIN_SAMPLES or self.step_s <= 0.0:
+            return None
+        return max(
+            self.FLOOR, math.ceil(self.COVER * self.pass_s / self.step_s))
+
+
 @dataclasses.dataclass
 class DecodePlan:
     seqs: List[Sequence]  # <= max_num_seqs running sequences
@@ -122,8 +166,11 @@ class StepPlan:
 
       decode only                     pure decode — ``decode_window`` (K)
                                       iterations per row budgeted in
-                                      ``decode.steps`` (K > 1 only when
-                                      no prompt is waiting)
+                                      ``decode.steps``; a window where
+                                      ``window_cut`` says what ended it
+                                      (``Scheduler._plan_window``), a
+                                      single step (K = 1, no cut) where a
+                                      prompt waits for an open slot
       prefill_chunk only              one prefill step (bucketed, maybe
                                       chunked)
       decode + prefill_chunk          fused mixed step (always K=1: the
@@ -155,6 +202,10 @@ class StepPlan:
     decode: Optional[DecodePlan] = None
     prefill_chunk: Optional[PrefillPlan] = None
     decode_window: int = 1
+    # What set a pure-decode window's length, one of WINDOW_CUTS; None on
+    # every plan that is not a window (a K=1 window is one: the program's
+    # trip count is a value).
+    window_cut: Optional[str] = None
     provisional: bool = False
     # Mixed K-step window: one PrefillPlan per scan iteration, all at
     # ONE chunk bucket (static scan shape).  The schedule may carry
@@ -239,6 +290,8 @@ class Scheduler:
         # window planning over N waiters must not recompute it per
         # chunk.
         self.budget_computations = 0
+        # The host's side of the window-length rule (_plan_window).
+        self.pace = WindowPace()
 
     # -- admission ---------------------------------------------------------
 
@@ -301,40 +354,67 @@ class Scheduler:
 
     # -- planning ----------------------------------------------------------
 
-    def _window_for_pass(self) -> int:
-        """Window-selection rule: K > 1 pure-decode windows only when no
-        prompt is waiting to prefill.  A waiting head is first offered a
-        MIXED K-step window (its chunks ride the decode scan — see
-        ``_try_schedule_mixed_window``); only when that declines does
-        the pass drop to K=1 steps so admission — mixed chunk or
-        dedicated prefill — is re-evaluated every token, not every K
-        tokens (counted as ``window_fallback="waiting_head"``).
+    def _plan_window(self, budgets) -> Tuple[int, str]:
+        """THE window-length rule, for every planner of a pure-decode window:
+        ``(k, cut)`` with ``k = min(window_steps, first_finish, host_cover)``
+        and ``cut`` the term that decided (WINDOW_CUTS).  ``budgets``: the
+        steps each row could still run when the window starts (its room
+        under ``max_tokens`` and ``max_model_len``, past what is in flight);
+        rows with none left are not in it.
 
-        Packed-window exception (mixed windows on): when every batch
-        slot is occupied, NO admission is possible this pass no matter
-        how often it is re-evaluated — dropping to K=1 would burn K
-        host round-trips purely on ceremony.  Run a pure-decode window
-        clamped to the first step a slot could FREE (the smallest
-        remaining output budget across the batch): windows never
-        retire rows mid-scan — finish/abort land at collect — so
-        iterations past the first exhausted row's budget would decode
-        dead rows while admissible prompts wait, and the boundary is
-        exactly where packing becomes possible again."""
-        window = self.config.window_steps
-        if window > 1 and self.num_waiting:
-            if (
-                self.config.mixed_window_enabled
-                and len(self.running) >= self.config.max_num_seqs
-            ):
-                # Floor 2: still a window (a K=1 pass here would be
-                # miscounted as a waiting_head forfeit — it isn't one,
-                # no admission fits a full batch either way).
-                return min(window, max(
-                    2,
-                    min(s.remaining_budget for s in self.running),
-                ))
-            return 1
-        return window
+        ``first_finish``, the smallest of them: the window that holds a
+        row's last token ends with it.  Windows never retire rows mid-loop
+        (finish and abort land at collect), so steps past it would hold a
+        finished request's last token back, and its slot, and decode a dead
+        row.  Host state alone: lockstep replicas plan alike.
+
+        ``host_cover`` (``WindowPace.cover``): shorter than that and the
+        step thread's pass no longer hides behind the window in flight.
+
+        The fused speculative window scans a static count and lands several
+        tokens a step: it keeps the cap."""
+        cap = self.config.window_steps
+        if self.config.spec_window_enabled:
+            return cap, "cap"
+        k, cut = cap, "cap"
+        cover = self.pace.cover()
+        if cover is not None and cover < k:
+            k, cut = cover, "host"
+        first_finish = min(budgets, default=cap)
+        if first_finish <= k and first_finish < cap:
+            k, cut = max(1, first_finish), "finish"
+        return k, cut
+
+    def _room(self, seq: Sequence, ahead: int = 0) -> int:
+        """Steps ``seq`` can still run once ``ahead`` tokens in flight have
+        landed: its room under max_model_len and its max_tokens."""
+        return min(
+            self.config.max_model_len - seq.num_tokens,
+            seq.sampling_params.max_tokens - seq.num_generated,
+        ) - ahead
+
+    def _window_for_pass(self) -> Tuple[int, Optional[str]]:
+        """``(K, cut)`` of this pass's decode plan.  A window by the one rule
+        (``_plan_window``) when no prompt waits.  A waiting head is first
+        offered a MIXED K-step window (its chunks ride the decode scan — see
+        ``_try_schedule_mixed_window``); only when that declines does
+        the pass drop to K=1 steps (no cut: not a window) so admission —
+        mixed chunk or dedicated prefill — is re-evaluated every token, not
+        every K tokens (counted as ``window_fallback="waiting_head"``).
+
+        Packed-window exception: when every batch slot is occupied, NO
+        admission is possible this pass no matter how often it is
+        re-evaluated — dropping to K=1 would burn K host round-trips purely
+        on ceremony.  The rule's window runs: it ends no later than the
+        first step a slot could FREE, which is exactly where admission
+        becomes possible again."""
+        if self.config.window_steps <= 1:
+            return 1, None
+        if self.num_waiting and (
+            len(self.running) < self.config.max_num_seqs
+        ):
+            return 1, None
+        return self._plan_window(self._room(s) for s in self.running)
 
     # stackcheck: root=step-thread
     def schedule(self) -> StepPlan:
@@ -347,16 +427,16 @@ class Scheduler:
         slot is open, else decode every running sequence — as a K-step
         window when no prompt waits (the device-resident fast path),
         single-token steps otherwise."""
-        window = self._window_for_pass()
+        window, cut = self._window_for_pass()
         if self.config.mixed_enabled and self.running:
             plan = self._try_schedule_mixed_window()
             if plan is not None:
                 return plan
-            plan = self._try_schedule_mixed(window)
+            plan = self._try_schedule_mixed(window, cut)
             if plan is not None:
                 if (
                     self.config.window_steps > 1
-                    and window == 1
+                    and cut is None
                     and not (
                         plan.prefill_chunk is not None
                         and plan.prefill_chunk.is_final
@@ -373,7 +453,8 @@ class Scheduler:
             return StepPlan(prefill_chunk=plan)
         decode = self._try_schedule_decode(window)
         if decode is not None:
-            return StepPlan(decode=decode, decode_window=window)
+            return StepPlan(
+                decode=decode, decode_window=window, window_cut=cut)
         # No step possible.  Two partially-prefilled sequences can coexist
         # (one per queue, or via offload restore) and deadlock each other
         # by jointly holding the pool; roll back the youngest — freeing its
@@ -431,7 +512,9 @@ class Scheduler:
             return self.waiting
         return self.preempted
 
-    def _try_schedule_mixed(self, window: int = 1) -> Optional[StepPlan]:
+    def _try_schedule_mixed(
+        self, window: int = 1, cut: Optional[str] = None,
+    ) -> Optional[StepPlan]:
         """Fused step: decode every running sequence AND, when the token
         budget and a batch slot allow, a bounded prefill chunk of the
         admission head.  Returns None to fall back to the classic
@@ -459,7 +542,8 @@ class Scheduler:
             budget = self._chunk_token_budget(len(decode.seqs))
             chunk = self._try_schedule_prefill(chunk_budget=budget)
         if chunk is None:
-            return StepPlan(decode=decode, decode_window=window)
+            return StepPlan(
+                decode=decode, decode_window=window, window_cut=cut)
         return StepPlan(decode=decode, prefill_chunk=chunk)
 
     # -- mixed K-step windows ----------------------------------------------
@@ -927,9 +1011,9 @@ class Scheduler:
         A waiting head whose chunks CAN ride the scan chains a MIXED
         window off the in-flight carry instead of breaking the pipeline
         — the sustained-arrival case that used to serialize every
-        window boundary into K=1 host round-trips."""
-        window = self.config.window_steps
-        if window <= 1:
+        window boundary into K=1 host round-trips.  The window's length is
+        ``_plan_window``'s, as the synchronous planner's."""
+        if self.config.window_steps <= 1:
             return None
         if len(self.running) < len(inflight_seqs) or any(
             a is not b for a, b in zip(self.running, inflight_seqs)
@@ -958,10 +1042,7 @@ class Scheduler:
             plan = self._provisional_mixed_window(inflight_steps)
             if plan is not None:
                 return plan
-            if parked or not (
-                self.config.mixed_window_enabled
-                and len(self.running) >= self.config.max_num_seqs
-            ):
+            if parked or len(self.running) < self.config.max_num_seqs:
                 return None
             if any(
                 seq.remaining_budget <= prev_k
@@ -969,51 +1050,63 @@ class Scheduler:
             ):
                 # A row exhausts its output budget INSIDE the in-flight
                 # window: its slot frees at collect, so a chained pure
-                # window would decode a dead row for K steps while this
-                # waiting prompt could pack.  Break the pipeline; the
-                # synchronous replan sees the freed slot.
+                # window would decode a dead row while this waiting prompt
+                # could be admitted.  Break the pipeline; the synchronous
+                # replan sees the freed slot.
                 return None
-            # Mixed windows on and a slot-full batch: no admission is
-            # possible at this boundary no matter how it replans, so
-            # chain a full pure-decode window off the carry instead of
-            # breaking the pipeline into K=1 waiting_head steps
-            # (mirrors _window_for_pass's slot-full rule).
+            # A slot-full batch: no admission is possible at this boundary
+            # no matter how it replans, so chain a pure-decode window off
+            # the carry instead of breaking the pipeline into K=1
+            # waiting_head steps (mirrors _window_for_pass's slot-full rule).
         elif parked:
             return None  # nothing left to pack: rebuild with the rows
-        bs = self.block_pool.block_size
+        # The in-flight window will (optimistically) land its whole prev_k
+        # token budget before this one runs (full acceptance under
+        # speculation; the device carry keeps the real count and the engine
+        # discards overrun on readback).
+        plan, _ = self._window_ahead(
+            self.running[: len(inflight_seqs)], inflight_steps)
+        if plan is not None:
+            plan.provisional = True
+        return plan
+
+    def _window_ahead(
+        self, rows: List[Sequence], aheads: List[int],
+    ) -> Tuple[Optional[StepPlan], Optional[str]]:
+        """The pure-decode window for ``rows`` planned while work is in
+        flight: row ``i`` stands ``aheads[i]`` tokens further along than the
+        host's bookkeeping says, its room is reckoned past them, and a row
+        with none left rides dead (no step).  (plan, None); (None, None)
+        where no row has a step to run; (None, "no_free_blocks") where
+        backing the window would take a preemption (never planned here: the
+        victim choice must see collected state)."""
+        rooms = [
+            max(0, self._room(seq, ahead)) for seq, ahead in zip(rows, aheads)
+        ]
+        if not any(rooms):
+            return None, None
+        window, cut = self._plan_window(r for r in rooms if r)
         # Per-window per-row token ceiling: K x (ngram + 1) under the
         # fused speculative window at max acceptance (all-greedy batch),
         # K otherwise.
         max_tok = self._window_token_cap(window)
-        rows = self.running[: len(inflight_seqs)]
-        steps: List[int] = []
-        needs: List[int] = []
-        for seq, prev_k in zip(rows, inflight_steps):
-            # The in-flight window will (optimistically) land its whole
-            # prev_k token budget before this one runs (full acceptance
-            # under speculation; the device carry keeps the real count
-            # and the engine discards overrun on readback).
-            base_tokens = seq.num_tokens + prev_k
-            base_gen = seq.num_generated + prev_k
-            room_len = self.config.max_model_len - base_tokens
-            room_out = seq.sampling_params.max_tokens - base_gen
-            k = max(0, min(max_tok, room_len, room_out))
-            steps.append(k)
-            slots = base_tokens + k - 1
-            needs.append(max(0, -(-slots // bs) - len(seq.block_table)))
-        if not any(steps):
-            return None
+        bs = self.block_pool.block_size
+        steps = [min(max_tok, room) for room in rooms]
+        needs = [
+            max(0, -(-(seq.num_tokens + ahead + k - 1) // bs)
+                - len(seq.block_table))
+            for seq, ahead, k in zip(rows, aheads, steps)
+        ]
         total = sum(needs)
         if total and not self.block_pool.can_allocate(total):
-            return None
+            return None, "no_free_blocks"
         for seq, need in zip(rows, needs):
             if need:
                 self._grow(seq, need)
         return StepPlan(
             decode=DecodePlan(seqs=list(rows), steps=steps),
-            decode_window=window,
-            provisional=True,
-        )
+            decode_window=window, window_cut=cut,
+        ), None
 
     def _provisional_mixed_window(
         self, inflight_steps: List[int]
@@ -1190,36 +1283,10 @@ class Scheduler:
         (plan, None); (None, None) where no row has a step to run (a window
         of nothing is not launched); (None, why) where backing the window
         would take a preemption."""
-        window = self.config.window_steps
-        if window <= 1:
+        if self.config.window_steps <= 1:
             return None, None
-        bs = self.block_pool.block_size
-        max_tok = self._window_token_cap(window)
-        steps: List[int] = []
-        needs: List[int] = []
-        for seq in self.running:
-            ahead = 1 if seq is first else 0
-            base_tokens = seq.num_tokens + ahead
-            room_len = self.config.max_model_len - base_tokens
-            room_out = (
-                seq.sampling_params.max_tokens - seq.num_generated - ahead
-            )
-            k = max(1 - ahead, min(max_tok, room_len, room_out))
-            steps.append(k)
-            slots = base_tokens + k - 1
-            needs.append(max(0, -(-slots // bs) - len(seq.block_table)))
-        if not any(steps):
-            return None, None
-        total = sum(needs)
-        if total and not self.block_pool.can_allocate(total):
-            return None, "no_free_blocks"
-        for seq, need in zip(self.running, needs):
-            if need:
-                self._grow(seq, need)
-        return StepPlan(
-            decode=DecodePlan(seqs=list(self.running), steps=steps),
-            decode_window=window,
-        ), None
+        return self._window_ahead(
+            self.running, [1 if seq is first else 0 for seq in self.running])
 
     # -- preemption / release ---------------------------------------------
 

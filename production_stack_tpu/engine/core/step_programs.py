@@ -7,15 +7,17 @@ Each builder takes what the program needs of the engine as arguments — the
 model arrives as a callable — and imports nothing of the engine, the
 scheduler or obs, so a program can be lowered, timed and tested alone.
 
-Every window program scans decode+sample iterations on the device and returns
-all emitted tokens in one host round trip.  Slot targeting is on the device
-(one block-table lookup per iteration); penalties and the ``min_tokens`` floor
-run inside the scan from carried occurrence state; a stop-token match freezes
-the row (no further KV writes, position and context frozen, -1 emitted), so a
-stop wastes no token of the window.  The final carry is returned so the next
-window can chain from this one's still-in-flight state.  The carry is a tuple
-inside the scan and a dict with fixed keys outside it: its order is the order
-of the compiled program's parameters.
+Every window program loops decode+sample iterations on the device and returns
+all emitted tokens in one host round trip.  ``window_fn`` runs as many as its
+longest row was budgeted (``max_steps``), up to the ``n_steps`` its outputs are
+sized for; the speculative and the mixed window scan a static count.  Slot
+targeting is on the device (one block-table lookup per iteration); penalties
+and the ``min_tokens`` floor run inside the scan from carried occurrence state;
+a stop-token match freezes the row (no further KV writes, position and context
+frozen, -1 emitted), so a stop wastes no token of the window.  The final carry
+is returned so the next window can chain from this one's still-in-flight
+state.  The carry is a tuple inside the scan and a dict with fixed keys outside
+it: its order is the order of the compiled program's parameters.
 
 The pieces the programs share are written once: ``stop_mask``,
 ``shape_logits``, ``commit_token`` / ``advance_rows`` and ``table_scatter``.
@@ -158,8 +160,17 @@ def _state_extra(state_slots):
 # -- the K-step decode window ------------------------------------------------
 
 
-def window_program(model_decode, *, block_size, n_steps, vocab):
-    """``window_fn``: ``n_steps`` decode+sample iterations in one scan."""
+def window_program(model_decode, *, block_size, n_steps, vocab, n_counts=0):
+    """``window_fn``: up to ``n_steps`` decode+sample iterations in one loop.
+
+    The trip count is a value: ``max(max_steps)``, the longest row's budget
+    for this window, clamped to ``n_steps``, which stays the static size of
+    the emitted ``[n_steps, S]`` and of a counting model's ``[n_steps,
+    n_counts]`` (``n_counts``: how many int32 counts ``model_decode`` hands
+    back a step beside its two results; 0: none).  Iteration ``t`` writes row
+    ``t`` of the outputs; the rows past the last iteration hold what a frozen
+    row emits (-1, and no counts), so the host reads a short window as it
+    reads one whose rows all stopped."""
     bs = block_size
 
     def multi_window(
@@ -173,7 +184,7 @@ def window_program(model_decode, *, block_size, n_steps, vocab):
     ):
         stop_valid, banned = _stops(stop_ids, vocab, use_min_floor)
 
-        def body(carry, t):
+        def step(t, carry):
             (tokens, positions, ctx_lens, done, min_left,
              counts, seen, kv_caches) = carry
             active = jnp.logical_and(~done, t < max_steps)  # [S]
@@ -220,11 +231,25 @@ def window_program(model_decode, *, block_size, n_steps, vocab):
                 tokens, positions, ctx_lens, done, min_left,
             ) + (counts, seen, kv_caches), (emitted, *counted)
 
-        carry, (emitted, *counted) = jax.lax.scan(
-            body,
-            (tokens, positions, ctx_lens, done, min_left,
-             counts, seen, kv_caches),
-            jnp.arange(n_steps),
+        def body(t, state):
+            carry, outs = state
+            carry, rows = step(t, carry)
+            if len(rows) != len(outs):
+                raise ValueError(
+                    f"window_program: the model hands back {len(rows) - 1} "
+                    f"array(s) of counts a step, n_counts={n_counts}")
+            return carry, tuple(
+                jax.lax.dynamic_update_index_in_dim(out, row, t, 0)
+                for out, row in zip(outs, rows)
+            )
+
+        outs = (jnp.full((n_steps,) + tokens.shape, -1, jnp.int32),)
+        if n_counts:
+            outs += (jnp.zeros((n_steps, n_counts), jnp.int32),)
+        carry, (emitted, *counted) = jax.lax.fori_loop(
+            0, jnp.minimum(jnp.max(max_steps), n_steps), body,
+            ((tokens, positions, ctx_lens, done, min_left,
+              counts, seen, kv_caches), outs),
         )
         *row, kv_caches = carry
         # No all-finished reduction on the device: every stop is visible in

@@ -550,6 +550,11 @@ def build_engine_app(
                 vocab.TPU_MIXED_WINDOW_PROMPTS,
                 engine.engine.mixed_window_prompts_hist,
             )
+            # How long the decode windows were planned (scheduler._plan_window).
+            + render_histogram(
+                vocab.TPU_DECODE_WINDOW_STEPS,
+                engine.engine.window_steps_hist,
+            )
             # Encode lane: batched embed/rerank/score texts, the queue
             # the batcher is carrying, and per-batch size/latency
             # (docs/engine.md "The encode lane").
@@ -2104,6 +2109,7 @@ def _run_follower(config, denv, args) -> None:
 
     health_app = web.Application()
     engine = LLMEngine(config)
+    engine.plan_from_clocks = False  # as the leader's (AsyncEngine)
     channel = distributed.LockstepChannel(
         denv, member_timeout_s=args.slice_member_timeout_s
     )
@@ -2303,10 +2309,11 @@ def main(argv=None) -> None:
         "--decode-window",
         type=int,
         default=8,
-        help="window size K for the K-step decode fast path (iterations "
-        "fused per pure-decode dispatch; the per-token host round-trip "
-        "is amortized K-fold and the device stop-mask keeps stop "
-        "conditions from wasting the tail of the window)",
+        help="the most iterations one pure-decode dispatch runs (the "
+        "K-step decode fast path).  The scheduler plans each window no "
+        "longer than the first row's last token and than the step "
+        "thread's own pass needs to hide behind it; the device "
+        "stop-mask keeps stop conditions from wasting a window's tail",
     )
     parser.add_argument(
         "--no-pipeline-decode",

@@ -76,6 +76,10 @@ class AsyncEngine:
         # all replicas' jitted launches identical (SPMD requirement).
         self.engine = LLMEngine(config)
         self._lockstep = lockstep
+        if lockstep is not None:
+            # The followers replay this engine's plans from its events
+            # alone: no plan may read a clock (scheduler.WindowPace).
+            self.engine.plan_from_clocks = False
         # Group liveness (docs/robustness.md "Slice lifecycle contract"):
         # a real lockstep channel with a control-plane side channel gets
         # a member-liveness monitor — the slice's health becomes the

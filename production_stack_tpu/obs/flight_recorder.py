@@ -60,7 +60,7 @@ class WindowRecord:
 
     window_id: int
     kind: str                      # one of WINDOW_KINDS
-    k: int                         # planned scan iterations (1 = single step)
+    k: int                         # planned iterations (1 = single step, or a window of one)
     rows: int                      # decode rows in the batch
     seq_ids: Tuple[str, ...]       # sequences riding this dispatch
     chain_depth: int = 0           # 0 = cold dispatch; n = nth chained window
@@ -72,6 +72,10 @@ class WindowRecord:
     chunk_tokens_planned: int = 0  # prompt tokens scheduled into the window
     chunk_tokens_delivered: int = 0
     fallback: Optional[str] = None  # planner decline reason, if it declined
+    # What set a pure-decode window's ``k`` (scheduler.WINDOW_CUTS: "cap",
+    # "finish" -- the first row's last token, "host" -- the fewest steps that
+    # cover the step thread's pass); None on every other dispatch.
+    cut: Optional[str] = None
     host_gap_s: float = 0.0        # host gap inherited from previous window
     transfer_overlap_s: float = 0.0  # H2D/D2H issued under in-flight window
     host_s: float = 0.0            # host-side dispatch cost
@@ -194,6 +198,8 @@ class WindowRecord:
             "collected_ns": self.collected_ns,
             "phases": [list(p) for p in self.phases],
         }
+        if self.cut is not None:
+            d["cut"] = self.cut
         if self.rows:
             d["kv_tokens"] = self.kv_tokens
         if self.kv_tokens_slots:
@@ -281,6 +287,7 @@ class FlightRecorder:
         chunk_prompts: int = 0,
         chunk_tokens_planned: int = 0,
         fallback: Optional[str] = None,
+        cut: Optional[str] = None,
         host_gap_s: float = 0.0,
         transfer_overlap_s: float = 0.0,
         kv_tokens: int = 0,
@@ -316,6 +323,7 @@ class FlightRecorder:
             chunk_prompts=int(chunk_prompts),
             chunk_tokens_planned=int(chunk_tokens_planned),
             fallback=fallback,
+            cut=cut,
             host_gap_s=float(host_gap_s),
             transfer_overlap_s=float(transfer_overlap_s),
             kv_tokens=int(kv_tokens),

@@ -231,6 +231,14 @@ REGISTRY = {
                 "bucket 1 is queue depth converted into device "
                 "utilization",
     },
+    "tpu:decode_window_steps": {
+        "kind": "histogram", "layer": "engine",
+        "mirrors": ("fake_engine", "dashboard", "docs"),
+        "help": "Steps each pure-decode window was planned to run: the "
+                "configured window (--decode-window), or fewer where the "
+                "window ended with a row's last token or as soon as it "
+                "covered the step thread's own pass",
+    },
     "tpu:encode_texts_total": {
         "kind": "counter", "layer": "engine",
         "mirrors": ("fake_engine", "dashboard", "docs"),

@@ -303,6 +303,12 @@ TPU_MIXED_WINDOW_CHUNK_TOKENS = "tpu:mixed_window_chunk_tokens_total"
 # admission declining); mass in the >1 buckets is queue depth being converted
 # into device utilization.
 TPU_MIXED_WINDOW_PROMPTS = "tpu:mixed_window_prompts_per_window"
+# Steps each pure-decode window was planned to run, as a histogram
+# (scheduler._plan_window): the configured window is its ceiling; mass
+# below it is windows that ended with a row's last token, or as soon as
+# the device time they held covered the step thread's own pass.  Mass at
+# the ceiling on a single host means the pass is long beside a step.
+TPU_DECODE_WINDOW_STEPS = "tpu:decode_window_steps"
 # Batched encode lane (scheduler encode_lane; docs/engine.md "The encode
 # lane"): texts embedded via the step thread's [B, T]-bucketed encode
 # batches (counter), the queue of texts the batcher is carrying (gauge —

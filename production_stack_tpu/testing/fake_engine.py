@@ -621,6 +621,10 @@ def build_fake_engine_app(state: FakeEngineState | None = None) -> web.Applicati
             # family must exist for the scrape contract (SC303).
             vocab.TPU_MIXED_WINDOW_PROMPTS,
             Histogram(bounds=(1.0, 2.0, 4.0, 8.0, 16.0, 32.0)),
+        ) + render_histogram(
+            # The fake plans no window: the family, empty (SC303).
+            vocab.TPU_DECODE_WINDOW_STEPS,
+            Histogram(bounds=(1.0, 2.0, 3.0, 4.0, 6.0, 8.0, 16.0)),
         ) + vocab.render_prometheus([
             # Slice-group lifecycle: live values in slice mode so the
             # whole group-liveness contract (epoch steps on restart,

@@ -65,3 +65,23 @@ def registry():
     from production_stack_tpu.utils.registry import ServiceRegistry
 
     return ServiceRegistry()
+
+
+@pytest.fixture(autouse=True, scope="session")
+def plans_from_host_state():
+    """A served engine ends a decode window as soon as its step thread's pass
+    is covered, by its own two clocks (scheduler.WindowPace).  Under test a
+    plan is a function of host state alone, so that streams and records are
+    those of the run before: the pace never has its samples.  For the whole
+    session, so that a module's fixture that drives an engine is held to it
+    too.  A test of the pace gives it ``MIN_SAMPLES`` back through its own
+    ``monkeypatch`` (tests/test_window_plan.py)."""
+    if jax is None:
+        yield
+        return
+    from production_stack_tpu.engine.core.scheduler import WindowPace
+
+    patch = pytest.MonkeyPatch()
+    patch.setattr(WindowPace, "MIN_SAMPLES", float("inf"))
+    yield
+    patch.undo()

@@ -422,7 +422,7 @@ def test_the_dense_kernels_compile_at_lagunas_geometries(
 # A module that owns a state pool reads and writes slots where they lie
 # (models/registry.py): the programs the engine serves it with -- the packed
 # ``prefill_fn`` at 256 slots with a snapshot slot named, the module's
-# ``decode`` at 16 rows and the K = 8 ``window_fn`` that scans it -- compiled at
+# ``decode`` at 16 rows and the ``window_fn`` (up to 8 steps) that loops it -- compiled at
 # the cells' pool sizes (59 slots; the K/V pools the engine's log gives) with
 # the cache tree donated, hold NO synchronous ``copy`` of an array the size of
 # a cache leaf (a state pool, a convolution rows' pool, a K/V pool).  What the
@@ -494,7 +494,8 @@ def test_a_state_models_served_programs_copy_no_pool(
     else:
         lowered = jax.jit(step_programs.window_program(
             functools.partial(model.decode, cfg=cfg, return_stats=True),
-            block_size=BS, n_steps=8, vocab=cfg.vocab_size),
+            block_size=BS, n_steps=8, vocab=cfg.vocab_size,
+            n_counts=len(model.stats_names(cfg))),
             static_argnames=("use_penalties", "use_min_floor"),
             donate_argnames=("kv_caches",),
         ).lower(

@@ -418,7 +418,9 @@ def test_the_engine_declines_on_what_it_serves(dense, reason, monkeypatch):
 
 def test_the_window_behind_budgets_rows_as_the_boundary_would(dense):
     """``schedule_window_behind`` before the first token is appended gives
-    every row what ``_try_schedule_decode`` gives after."""
+    every row what the boundary's planner gives after: the window the one
+    rule plans (it ends with the first row's last token), budgeted by
+    ``_try_schedule_decode``."""
     behind, _ = dense
     sched = Scheduler(config("dense").scheduler, behind.block_pool)
 
@@ -431,20 +433,23 @@ def test_the_window_behind_budgets_rows_as_the_boundary_would(dense):
             s.block_table = behind.block_pool.allocate(-(-s.num_tokens // 16))
         return [a, b, c]
 
-    sched.running = rows()
-    plans = []
-    for first in sched.running[1:]:
-        plans.append(sched.schedule_window_behind(first)[0].decode.steps)
-    tables = [len(s.block_table) for s in sched.running]
-    for s in sched.running:
-        behind.block_pool.free(s.block_table)
-    assert plans == [[8, 4, 1], [8, 5, 0]]
+    plans, tables = [], []
+    for first in (1, 2):
+        sched.running = rows()
+        plans.append(sched.schedule_window_behind(
+            sched.running[first])[0].decode.steps)
+        tables.append([len(s.block_table) for s in sched.running])
+        for s in sched.running:
+            behind.block_pool.free(s.block_table)
+    # Behind b's prefill, c has one token left; behind c's, c ends with its
+    # first token (an overrun row) and b's five are the first to run out.
+    assert plans == [[1, 1, 1], [5, 5, 0]]
 
     sched.running = rows()
     sched.running[1].output_token_ids = [9]
-    want = sched._try_schedule_decode(8)
-    assert want.steps == [8, 4, 1]
-    assert [len(s.block_table) for s in sched.running][:2] == tables[:2]
+    assert sched._window_for_pass() == (1, "finish")
+    assert sched._try_schedule_decode(1).steps == plans[0]
+    assert [len(s.block_table) for s in sched.running] == tables[0]
     for s in sched.running:
         behind.block_pool.free(s.block_table)
 

@@ -502,21 +502,20 @@ def test_k1_fallback_respects_spec_budget_block_invariant():
 # -- packed multi-prompt windows --------------------------------------------
 
 
-@pytest.mark.parametrize("gate, packs", [
-    ({}, True),
-    ({"mixed_window": False}, False),
-    # What the benchmark's cells run (--no-mixed-batch): a slot-full batch
-    # with a prompt waiting drops to K=1, as it did before the single-head
-    # planner went.  Un-gating the exception is a scheduling change with a
-    # claim of its own (ROADMAP S6).
-    ({"mixed_batch": False}, False),
-    ({"multi_step_window": False}, False),
+@pytest.mark.parametrize("gate, packs, window", [
+    ({}, True, 4),
+    ({"mixed_window": False}, False, 4),
+    # What the benchmark's cells run (--no-mixed-batch): the exception uses
+    # nothing of the mixed machinery and no longer hangs on it (ROADMAP S6).
+    ({"mixed_batch": False}, False, 4),
+    ({"multi_step_window": False}, False, 1),
 ])
-def test_multi_prompt_window_default_on_and_gate(gate, packs):
+def test_multi_prompt_window_default_on_and_gate(gate, packs, window):
     """Packing is what a mixed window does: it follows
-    mixed_window_enabled, and so does the packed-window exception — a
-    slot-full batch runs a pure-decode window past a waiting prompt (no
-    admission fits either way), clamped to the first step a slot could
+    mixed_window_enabled.  The packed-window exception does not (PR 60: the
+    one window-length rule, Scheduler._plan_window) — a slot-full batch
+    runs a pure-decode window past a waiting prompt whatever the gate (no
+    admission fits either way), ending with the first step a slot could
     free."""
     assert SchedulerConfig(**gate).mixed_window_enabled == packs
     sched, _ = _scheduler(max_num_seqs=2, **gate)
@@ -532,12 +531,11 @@ def test_multi_prompt_window_default_on_and_gate(gate, packs):
     plan = sched.schedule()
     assert plan.prefill_chunk is None and plan.chunk_schedule is None
     assert [s.seq_id for s in plan.decode.seqs] == ["run0", "run1"]
-    if packs:
-        # min(window 8, run1's 4 tokens left): the boundary is where a
-        # slot frees and packing becomes possible again.
-        assert plan.decode_window == 4 and plan.window_fallback is None
-    else:
-        assert plan.decode_window == 1
+    # min(window 8, run1's 4 tokens left): the boundary is where a slot
+    # frees and admission becomes possible again.  Without windows: a step.
+    assert plan.decode_window == window and plan.window_fallback is None
+    assert plan.window_cut == ("finish" if window > 1 else None)
+    assert plan.decode.steps == [window, window]
 
 
 def test_packed_window_plans_multiple_prompts():

@@ -527,8 +527,12 @@ def test_a_dense_models_step_programs_lower_to_the_text_they_had():
     tokens are the same, tests/test_sampler_paths.py).  PR 49 meant to:
     ``prefill_fn`` takes what the host builds for a chunk as one int32 vector
     and slices it (``step_programs.prefill_program``; 6992c098e4286882 before
-    it; the model is handed the same values, tests/test_dispatch_build.py)."""
+    it; the model is handed the same values, tests/test_dispatch_build.py).
+    PR 60 meant to: ``window_fn`` loops as many steps as its longest row was
+    budgeted, up to the eight its outputs hold, where it scanned eight
+    (caf2df83d2edb4da before it; the tokens are the same on the steps that
+    run, tests/test_window_plan.py)."""
     assert _lowered_hashes() == {
-        "prefill_fn": "7b371a1a2d3fe956", "window_fn": "caf2df83d2edb4da",
+        "prefill_fn": "7b371a1a2d3fe956", "window_fn": "e286ea5021c96819",
         "win_advance_fn": "325e8149c1081481",
         "sample_fn": "18cb405d3f877b3e"}

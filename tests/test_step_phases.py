@@ -187,7 +187,9 @@ def test_loose_phases_are_the_spans_that_belong_to_no_dispatch(runs):
 def test_the_records_older_fields_read_as_before_on_a_fixed_plan(runs, setup):
     """Same requests, same plan: what the recorder gave before the records
     were opened ahead of the work (``fixtures/flight_records_before_pr25
-    .json``, written by the parent commit) is what it gives now."""
+    .json``, written by the parent commit) is what it gives now.  (PR 60
+    changed the plan of the ``window`` set-up's end, and those two records
+    with it: the file's note.)"""
     with open(os.path.join(os.path.dirname(__file__), "fixtures",
                            "flight_records_before_pr25.json")) as f:
         before = json.load(f)[setup]
