@@ -108,6 +108,23 @@ class ModelConfig:
     # (``hc_eps`` in each divisor) of ``exp`` of its entries clamped to
     # +-``hc_res_clamp`` (models/sarvam_mla.py: the residual path).
     q_lora_rank: int = 0
+    # models/longcat.py (all decided in Python at trace time; the defaults
+    # are what every other preset traces).  ``attn_per_layer``: latent
+    # attentions a layer, each with a cache array and a dense FFN of its own
+    # (2: the shortcut-connected layer, one routed FFN across both).
+    # ``mla_scale_q_lora`` / ``mla_scale_kv_lora``: the query times
+    # (hidden_size / q_lora_rank)^1/2, the normed latent times (hidden_size /
+    # kv_lora_rank)^1/2.  ``router_scoring``: "sigmoid" | "softmax" over the
+    # router's whole width; ``norm_topk_prob`` False: the chosen scores weigh
+    # as they are.  ``zero_expert_num``: router outputs past
+    # ``router_experts`` that name an identity (an expert that returns its
+    # input and holds no weights).
+    attn_per_layer: int = 1
+    mla_scale_q_lora: bool = False
+    mla_scale_kv_lora: bool = False
+    router_scoring: str = "sigmoid"
+    norm_topk_prob: bool = True
+    zero_expert_num: int = 0
     hc_mult: int = 0
     hc_sinkhorn_iters: int = 20
     hc_eps: float = 1e-6
@@ -167,6 +184,10 @@ class ModelConfig:
             raise ValueError(
                 f"Unknown quantization {self.quantization!r} (None | int8)"
             )
+        if self.router_scoring not in ("sigmoid", "softmax"):
+            raise ValueError(
+                f"Unknown router_scoring {self.router_scoring!r} "
+                f"(sigmoid | softmax)")
         if self.hidden_act not in ("silu", "gelu_tanh"):
             # A typo (or HF's own string, "gelu_pytorch_tanh") silently
             # falling back to silu would serve wrong logits forever.
@@ -177,6 +198,18 @@ class ModelConfig:
     @property
     def q_per_kv(self) -> int:
         return self.num_heads // self.num_kv_heads
+
+    @property
+    def cache_layers(self) -> int:
+        """Cache arrays a position is kept in: one an attention, so
+        ``num_layers`` unless a layer holds several (``attn_per_layer``).
+        What sizes, allocates or counts the cache asks this."""
+        return self.num_layers * self.attn_per_layer
+
+    @property
+    def router_width(self) -> int:
+        """The router's outputs: the published experts and the identities."""
+        return self.router_experts + self.zero_expert_num
 
     def layer_kind(self, layer_idx: int) -> str:
         """The mix of held layer ``layer_idx``: ``layer_kinds`` tiled over the
@@ -652,6 +685,81 @@ PRESETS = {
         first_k_dense_replace=1,
         routed_scaling_factor=2.0,
         hc_mult=4,
+    ),
+    # LongCat-Flash-Omni's language model (https://huggingface.co/
+    # meituan-longcat/LongCat-Flash-Omni, 560B-A27B; text in, text out: its
+    # audio and vision encoders and codec decoder are not built) AS ONE OF 32
+    # CHIPS THAT SHARE EVERY LAYER, not the whole model: every width as
+    # published, and of the published 28 layers, 512 experts a layer and
+    # 131,072 vocabulary rows this preset holds 4 layers (each two latent
+    # attentions, two dense FFNs and one routed FFN: 8 cache arrays), experts
+    # 0-15 behind a router that stays 768 wide (512 + 256 identities, 12 a
+    # token) and 16,384 rows: 5.17 B parameters, 10.34 GB of bf16
+    # (bench/configs/longcat-flash-omni-ep32.json states the deployment;
+    # PERF.md section 4 the arithmetic).  No rope_scaling is published
+    # (theta 1e7).  The published max is 131,072 positions; 32,768 is the
+    # serving limit the cache is sized for.
+    "longcat-flash-omni-ep32": ModelConfig(
+        name="longcat-flash-omni-ep32",
+        vocab_size=16384,
+        published_vocab_size=131072,
+        hidden_size=6144,
+        intermediate_size=12288,
+        num_layers=4,
+        num_heads=64,
+        num_kv_heads=1,
+        head_dim=576,
+        max_model_len=32768,
+        rope_theta=10000000.0,
+        rms_norm_eps=1e-5,
+        kv_lora_rank=512,
+        qk_nope_head_dim=128,
+        qk_rope_head_dim=64,
+        v_head_dim=128,
+        q_lora_rank=1536,
+        attn_per_layer=2,
+        mla_scale_q_lora=True,
+        mla_scale_kv_lora=True,
+        num_experts=16,
+        router_experts=512,
+        zero_expert_num=256,
+        num_experts_per_tok=12,
+        moe_intermediate_size=2048,
+        routed_scaling_factor=6.0,
+        router_scoring="softmax",
+        norm_topk_prob=False,
+    ),
+    # The same module at a size the CPU tests run: two layers (four
+    # attentions, two routed FFNs), 4 of a router's 8 experts held beside 4
+    # identities (12 outputs), 3 a token.
+    "tiny-longcat": ModelConfig(
+        name="tiny-longcat",
+        vocab_size=384,
+        hidden_size=64,
+        intermediate_size=128,
+        num_layers=2,
+        num_heads=4,
+        num_kv_heads=1,
+        head_dim=48,
+        max_model_len=2048,
+        rope_theta=10000000.0,
+        rms_norm_eps=1e-5,
+        kv_lora_rank=32,
+        qk_nope_head_dim=16,
+        qk_rope_head_dim=16,
+        v_head_dim=16,
+        q_lora_rank=24,
+        attn_per_layer=2,
+        mla_scale_q_lora=True,
+        mla_scale_kv_lora=True,
+        num_experts=4,
+        router_experts=8,
+        zero_expert_num=4,
+        num_experts_per_tok=3,
+        moe_intermediate_size=32,
+        routed_scaling_factor=6.0,
+        router_scoring="softmax",
+        norm_topk_prob=False,
     ),
     # Laguna-XS.2 (https://huggingface.co/poolside/Laguna-XS.2, model_type
     # laguna) AS ONE OF TWO CHIPS THAT SHARE EVERY LAYER, not the whole model:

@@ -504,9 +504,12 @@ class LLMEngine:
         counting = {"return_stats": True} if self._routing_names else {}
         # Prefill dispatches' counts still on the device: (record, [n]).
         self._routing_pending: Deque[tuple] = deque()
-        # tpu:moe_assignments_total{where} / tpu:moe_experts_touched_total.
+        # tpu:moe_assignments_total{where} / tpu:moe_experts_touched_total /
+        # tpu:moe_zero_assigned_total (picks that named an identity expert:
+        # models/longcat.py).
         self.moe_assignments: Dict[str, int] = {"held": 0, "away": 0}
         self.moe_experts_touched = 0
+        self.moe_zero_assigned = 0
         # tpu:mhc_clamped_total / tpu:mhc_entries_total /
         # tpu:mhc_sinkhorn_err: a residual path of several streams' mixing
         # matrices (models/sarvam_mla.py: RESIDUAL_STATS), entries the clamp
@@ -1001,6 +1004,8 @@ class LLMEngine:
                 if residual:
                     logger.info(
                         "Residual: streams=%d sinkhorn=%d (%s)", *residual)
+            if hasattr(self.model, "layer_form"):
+                logger.info("Layer: %s", self.model.layer_form(cfg))
             return
         decode_kernel = attn_ops.use_pallas_decode(
             cfg.num_kv_heads // par.tensor_parallel, cfg.head_dim
@@ -1062,8 +1067,8 @@ class LLMEngine:
         refused = [what for what, on in asked.items() if on]
         if refused:
             raise ValueError(
-                f"{module} keeps a cache of its own (one array a layer) and "
-                f"cannot serve with: {'; '.join(refused)}"
+                f"{module} keeps a cache of its own (one array an attention) "
+                f"and cannot serve with: {'; '.join(refused)}"
             )
 
     def _decide_attn_kinds(self):
@@ -1072,9 +1077,10 @@ class LLMEngine:
             return [(kind, spec.window, cfg.layers_of(kind),
                      kind not in PAGED_KINDS)
                     for kind, spec in cfg.attention_specs.items()]
+        # A cache array an attention: a layer may hold several.
         keyed = (sum(cfg.layer_kind(i) in PAGED_KINDS
                      for i in range(cfg.num_layers))
-                 if cfg.layer_kinds else cfg.num_layers)
+                 if cfg.layer_kinds else cfg.cache_layers)
         window = cfg.sliding_window
         return [("full" if window is None else "window", window, keyed,
                  False)]
@@ -2887,6 +2893,7 @@ class LLMEngine:
                 self.moe_assignments["held"] += here
                 self.moe_assignments["away"] += folded["moe_assigned"] - here
                 self.moe_experts_touched += folded["experts_touched"]
+                self.moe_zero_assigned += folded.get("moe_zero_assigned", 0)
             if "ssm_dt_max_e3" in folded:
                 self.ssm_state_absmax = max(
                     self.ssm_state_absmax,
@@ -4828,6 +4835,7 @@ class LLMEngine:
             # and step (zero for a model that routes nothing).
             "moe_assignments": dict(self.moe_assignments),
             "moe_experts_touched": self.moe_experts_touched,
+            "moe_zero_assigned": self.moe_zero_assigned,
             # Several residual streams' mixing matrices (zero for a model
             # with one stream): entries the clamp changed, entries seen,
             # the largest |row sum - 1| after the last normalisation.

@@ -4,7 +4,9 @@
 architectures (RMSNorm + rotate-half RoPE + GQA + gated MLP, optional sliding
 window, softmax-routed experts).  ``sarvam_mla.py`` is latent attention (MLA,
 a cache of one array a layer) over experts behind a biased sigmoid router,
-held by share.  ``registry.py`` maps a preset's name to its module and says
+held by share; ``longcat.py`` two of those attentions a layer around one
+routed FFN whose softmax router also names identity experts.  ``registry.py``
+maps a preset's name to its module and says
 what a module must and may offer the engine.
 """
 

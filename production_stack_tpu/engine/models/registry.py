@@ -51,6 +51,9 @@ the step programs (``core/step_programs.py``) carry without looking inside.
 - with ``init_cache``, ``residual_path(cfg) -> None | (streams,
   normalisations, path)``: the boot line ``Residual: ...`` of a module whose
   tokens carry several residual streams.
+- with ``init_cache``, ``layer_form(cfg) -> str``: the boot line ``Layer:
+  ...`` of a module whose layer is not one attention and one FFN (how many
+  attentions, FFNs and cache arrays, the router's width and what is held).
 - with ``init_cache``, **a state pool**: ``state_bytes_per_slot(cfg)`` and
   ``snapshot_stride(cfg)`` say that some layers keep, in place of keys, a
   state that does not grow with the context (three modules do:
@@ -141,7 +144,22 @@ as its cached prefix through the flash kernel under the window mask; a sigmoid
 gate a head (``use_head_gate``); a leading dense layer and ``sarvam_mla``'s
 routed experts held by share with no selection bias, imported; counter
 ``tpu:attn_positions_total{kind}`` and the records' ``kv_tokens_slots``
-(``config.PAGED_KINDS`` says which kinds keep pages).
+(``config.PAGED_KINDS`` says which kinds keep pages); and in ``longcat.py`` a
+layer of two latent attentions and two dense FFNs with one routed FFN across
+them (shortcut-connected: the routed FFN reads what the first dense FFN reads
+and is added after the second; ``attn_per_layer`` 2, so two cache arrays a
+layer, ``ModelConfig.cache_layers`` in all, which everything that sizes,
+allocates or counts the cache asks), ``sarvam_mla``'s latent attention with
+the low-rank query path and the two latent scale factors
+(``mla_scale_q_lora``, ``mla_scale_kv_lora``: the cache keeps the scaled
+latent, the kernels are unchanged), plain rotary frequencies, and a router
+scored by softmax over its whole width whose chosen shares are not
+renormalised (``router_scoring``, ``norm_topk_prob``) and whose outputs past
+``router_experts`` name identity experts (``zero_expert_num``: they return
+their input, hold no weights and are computed whole on every chip of the
+deployment, counted once), behind ``sarvam_mla``'s grouped dispatch held by
+share, imported; boot line ``Layer: ...`` (``layer_form``), counter
+``moe_zero_assigned`` / ``tpu:moe_zero_assigned_total``.
 """
 
 from __future__ import annotations
@@ -149,7 +167,7 @@ from __future__ import annotations
 from types import ModuleType
 
 from production_stack_tpu.engine.models import (
-    jamba, laguna, llama, sarvam_mla, solar_kda,
+    jamba, laguna, llama, longcat, sarvam_mla, solar_kda,
 )
 
 MODEL_REGISTRY = {
@@ -184,6 +202,12 @@ MODEL_REGISTRY = {
     # and the slot addressing are solar_kda's, the routed FFN sarvam_mla's,
     # imported).
     "laguna": laguna,
+    # A layer of two latent attentions and two dense FFNs with one routed
+    # FFN across them (shortcut-connected), a softmax router some of whose
+    # outputs are identity experts: two cache arrays a layer (the latent
+    # attention with its kernels, the cache layout, the router and the
+    # grouped dispatch are sarvam_mla's, imported).
+    "longcat": longcat,
 }
 
 

@@ -72,6 +72,14 @@ are sorted by expert and run as one ``jax.lax.ragged_dot`` a projection, so a
 row costs the experts it chose here and a decode step streams only the
 experts its rows touch.
 
+``models/longcat.py`` imports the latent attention (through
+:func:`prefill_attention` / :func:`decode_attention`, once an attention of its
+two a layer), the cache, :func:`route` and :func:`held_experts`, and sets what
+is decided here in Python at trace time for its family alone:
+``cfg.mla_scale_q_lora`` / ``cfg.mla_scale_kv_lora`` (:func:`_project`),
+``cfg.router_scoring`` / ``cfg.norm_topk_prob`` (:func:`route`) and
+``cfg.cache_layers`` arrays (:func:`init_cache`).
+
 What a module must offer the engine, and what it may, is in
 ``models/registry.py``.  This one offers ``init_params``, ``quantize_params``
 (identity: bf16 throughout), ``prefill``, ``decode``, ``init_cache``,
@@ -139,19 +147,22 @@ def cache_lanes(cfg: ModelConfig) -> int:
 
 
 def cache_bytes_per_token(cfg: ModelConfig) -> int:
-    """Bytes of cache one position takes on the device over all layers."""
-    return cache_lanes(cfg) * jnp.dtype(cfg.dtype).itemsize * cfg.num_layers
+    """Bytes of cache one position takes on the device over every cache
+    array (one an attention: ``cfg.cache_layers``)."""
+    return cache_lanes(cfg) * jnp.dtype(cfg.dtype).itemsize * cfg.cache_layers
 
 
 def init_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
                sharding=None) -> LatentCaches:
-    """One array a layer, ``[c ; r]`` and the pad lanes wide.  A latent has
-    no head axis to split, so ``sharding`` can only replicate it."""
+    """One array an attention (a layer, unless ``cfg.attn_per_layer`` says
+    more: then a layer's arrays lie one after another), ``[c ; r]`` and the
+    pad lanes wide.  A latent has no head axis to split, so ``sharding`` can
+    only replicate it."""
     zeros = jax.jit(
         lambda: jnp.zeros((num_blocks, block_size, cache_lanes(cfg)),
                           jnp.dtype(cfg.dtype)),
         out_shardings=sharding)
-    return [zeros() for _ in range(cfg.num_layers)]
+    return [zeros() for _ in range(cfg.cache_layers)]
 
 
 def _is_routed(cfg: ModelConfig, layer_idx: int) -> bool:
@@ -358,7 +369,10 @@ def _dot(x, w):
 
 def _project(layer: Params, cfg: ModelConfig, x: jax.Array, cos, sin):
     """x [T, h] -> (q_nope [T, H, nope], q_rope [T, H, rope] rotated, the
-    cache's rows [T, lanes]: the normed latent, the rotated key, zeros)."""
+    cache's rows [T, lanes]: the normed latent, the rotated key, zeros).
+    ``cfg.mla_scale_q_lora`` / ``cfg.mla_scale_kv_lora`` (``models/
+    longcat.py``'s family) scale the query and the normed latent by the
+    square root of hidden size over the rank each came through."""
     T, H = x.shape[0], cfg.num_heads
     nope, eps = cfg.qk_nope_head_dim, cfg.rms_norm_eps
     if cfg.q_lora_rank:
@@ -367,9 +381,14 @@ def _project(layer: Params, cfg: ModelConfig, x: jax.Array, cos, sin):
         q = _dot(c_q, layer["q_b_proj"])
     else:
         q = _dot(x, layer["q_proj"])
+    if cfg.mla_scale_q_lora:     # both parts of every head alike
+        q = q * (cfg.hidden_size / cfg.q_lora_rank) ** 0.5
     q = q.astype(x.dtype).reshape(T, H, -1)
     kv = _dot(x, layer["kv_a_proj"]).astype(x.dtype)
     c = rms_norm(kv[:, :cfg.kv_lora_rank], layer["kv_a_layernorm"], eps)
+    if cfg.mla_scale_kv_lora:    # the cache keeps the scaled latent
+        c = (c.astype(jnp.float32)
+             * (cfg.hidden_size / cfg.kv_lora_rank) ** 0.5).astype(x.dtype)
     r = kv[:, cfg.kv_lora_rank:]
     if cfg.use_qk_norm:
         q = rms_norm(q, layer["q_norm"], eps)
@@ -660,12 +679,19 @@ def _swiglu(x, gate, up, down):
 def route(layer: Params, cfg: ModelConfig, x: jax.Array):
     """x [T, h] -> (chosen experts [T, k] int32 over the router's whole
     width, their shares g [T, k] float32).  A layer without a
-    ``router_bias`` (``models/laguna.py``) chooses by the scores alone."""
-    s = jax.nn.sigmoid(_dot(x, layer["router"]))
+    ``router_bias`` (``models/laguna.py``) chooses by the scores alone.
+    ``cfg.router_scoring`` "softmax" scores by a softmax over that width and
+    ``cfg.norm_topk_prob`` False leaves the chosen scores as they are
+    (``models/longcat.py``)."""
+    logits = _dot(x, layer["router"])
+    s = (jax.nn.softmax(logits, axis=-1) if cfg.router_scoring == "softmax"
+         else jax.nn.sigmoid(logits))
     _best, who = jax.lax.top_k(s + layer.get("router_bias", 0.0),
                                cfg.num_experts_per_tok)
     chosen = jnp.take_along_axis(s, who, axis=-1)
-    g = cfg.routed_scaling_factor * chosen / chosen.sum(-1, keepdims=True)
+    g = cfg.routed_scaling_factor * chosen
+    if cfg.norm_topk_prob:
+        g = g / chosen.sum(-1, keepdims=True)
     return who.astype(jnp.int32), g
 
 
@@ -858,6 +884,49 @@ def _result(logits, caches, choice, stats, residual, return_choice,
 # -- the two steps -----------------------------------------------------------
 
 
+def prefill_attention(cfg, T, cached_len, prefix_block_ids, new_block_ids,
+                      valid_len):
+    """One chunk's ``attention(layer, cache, normed h) -> (the heads' outputs
+    [T, H, v], the layer's new cache)``: project, attend the cached prefix
+    and the chunk's own rows, write the rows into ``new_block_ids``.  A
+    module whose layer holds several attentions (``models/longcat.py``)
+    calls it once an attention, each with its own weights and array."""
+    cos, sin = _rope_tables(cfg, cached_len + jnp.arange(T))
+
+    def attention(layer, cache, h):
+        q_nope, q_rope, rows = _project(layer, cfg, h, cos, sin)
+        out = _prefill_attention(
+            layer, cfg, q_nope, q_rope, rows, cache, prefix_block_ids,
+            cached_len, valid_len)
+        bs = cache.shape[1]
+        return out, cache.at[new_block_ids].set(
+            rows.reshape(T // bs, bs, -1).astype(cache.dtype))
+
+    return attention
+
+
+def decode_attention(cfg, positions, block_tables, ctx_lens, slot_block_ids,
+                     slot_offsets):
+    """A decode batch's ``attention(layer, cache, normed h)``, as
+    :func:`prefill_attention`'s: the new row written where its slot says,
+    then the absorbed walk over each row's pages."""
+    cos, sin = _rope_tables(cfg, positions)
+
+    def attention(layer, cache, h):
+        q_nope, q_rope, rows = _project(layer, cfg, h, cos, sin)
+        # Write, then attend: ctx_lens counts the new token.
+        bs = cache.shape[1]
+        cache = cache.reshape(-1, cache.shape[-1]).at[
+            slot_block_ids * bs + slot_offsets].set(
+                rows.astype(cache.dtype)).reshape(cache.shape)
+        with jax.named_scope("latent_attention_absorbed"):
+            out = _absorbed_attention(
+                layer, cfg, q_nope, q_rope, cache, block_tables, ctx_lens)
+        return out, cache
+
+    return attention
+
+
 def prefill(
     params: Params,
     cfg: ModelConfig,
@@ -882,18 +951,9 @@ def prefill(
     ``models/llama.py: prefill``'s (target_logprob [T], top_ids [T, k],
     top_logps [T, k]), the head swept in row chunks."""
     T = tokens.shape[0]
-    cos, sin = _rope_tables(cfg, cached_len + jnp.arange(T))
+    attention = prefill_attention(
+        cfg, T, cached_len, prefix_block_ids, new_block_ids, valid_len)
     live = jnp.arange(T) < valid_len
-
-    def attention(layer, cache, h):
-        q_nope, q_rope, rows = _project(layer, cfg, h, cos, sin)
-        out = _prefill_attention(
-            layer, cfg, q_nope, q_rope, rows, cache, prefix_block_ids,
-            cached_len, valid_len)
-        bs = cache.shape[1]
-        return out, cache.at[new_block_ids].set(
-            rows.reshape(T // bs, bs, -1).astype(cache.dtype))
-
     x, caches, *counted = _blocks(
         params, cfg, kv_caches, params["embed_tokens"][tokens], live,
         attention)
@@ -935,21 +995,9 @@ def decode(
     :func:`prefill`.  A row whose write is parked on the null block 0 (a
     padding row, a row the window froze) is not live: it is routed nowhere,
     touches no expert and is not counted."""
-    cos, sin = _rope_tables(cfg, positions)
+    attention = decode_attention(
+        cfg, positions, block_tables, ctx_lens, slot_block_ids, slot_offsets)
     live = slot_block_ids != 0
-
-    def attention(layer, cache, h):
-        q_nope, q_rope, rows = _project(layer, cfg, h, cos, sin)
-        # Write, then attend: ctx_lens counts the new token.
-        bs = cache.shape[1]
-        cache = cache.reshape(-1, cache.shape[-1]).at[
-            slot_block_ids * bs + slot_offsets].set(
-                rows.astype(cache.dtype)).reshape(cache.shape)
-        with jax.named_scope("latent_attention_absorbed"):
-            out = _absorbed_attention(
-                layer, cfg, q_nope, q_rope, cache, block_tables, ctx_lens)
-        return out, cache
-
     x, caches, *counted = _blocks(
         params, cfg, kv_caches, params["embed_tokens"][tokens], live,
         attention)

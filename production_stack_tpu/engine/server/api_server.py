@@ -397,6 +397,7 @@ def build_engine_app(
             # routed layers and decode steps (the labeled family, pairs
             # by where they fell, renders below).
             (vocab.TPU_MOE_EXPERTS_TOUCHED, s["moe_experts_touched"]),
+            (vocab.TPU_MOE_ZERO_ASSIGNED, s["moe_zero_assigned"]),
             # Several residual streams' mixing matrices: clamped entries
             # of entries seen, and the worst row sum's distance from 1.
             (vocab.TPU_MHC_CLAMPED, s["mhc_clamped"]),

@@ -141,6 +141,9 @@ class WindowRecord:
     # steps; ``moe_assigned_here``: those that fell on experts held here;
     # ``experts_touched``: held experts with at least one row, summed over
     # routed layers and steps; ``expert_rows_max``: the fullest one's rows.
+    # A router some of whose outputs are identity experts adds
+    # (models/longcat.py: ZERO_STATS) ``moe_zero_assigned``: picks of live
+    # rows that named one, and so computed nothing, of ``moe_assigned``.
     # A model with several residual streams adds (RESIDUAL_STATS):
     # ``mhc_clamped`` / ``mhc_entries``: entries of its mixing matrices'
     # exponents the clamp changed / seen; ``mhc_err_e6``: the largest

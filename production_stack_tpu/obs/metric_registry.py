@@ -452,6 +452,15 @@ REGISTRY = {
                 "layers and decode steps: what a decode step streams of "
                 "the expert stacks",
     },
+    "tpu:moe_zero_assigned_total": {
+        "kind": "counter", "layer": "engine",
+        "mirrors": ("docs",),
+        "help": "Picks of live rows that named an identity (zero-compute) "
+                "expert, over routed layers and steps: of "
+                "tpu:moe_assignments_total's pairs those that computed "
+                "nothing anywhere; counted on the device, read back with the "
+                "tokens; zero for a router without identity experts",
+    },
     "tpu:mhc_clamped_total": {
         "kind": "counter", "layer": "engine",
         "mirrors": ("fake_engine", "dashboard", "docs"),

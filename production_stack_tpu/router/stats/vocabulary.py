@@ -213,6 +213,9 @@ TPU_PREFILL_ATTN_TILE_STATES = ("live", "skipped")
 TPU_MOE_ASSIGNMENTS = "tpu:moe_assignments_total"
 TPU_MOE_ASSIGNMENT_WHERE = ("held", "away")
 TPU_MOE_EXPERTS_TOUCHED = "tpu:moe_experts_touched_total"
+# ... and, of those pairs, the picks that named an identity (zero-compute)
+# expert (engine/models/longcat.py): they compute nothing on any chip.
+TPU_MOE_ZERO_ASSIGNED = "tpu:moe_zero_assigned_total"
 # A residual path of several streams (engine/models/sarvam_mla.py:
 # RESIDUAL_STATS): entries of the mixing matrices' exponents the clamp
 # changed, entries seen, and (a gauge) the largest |row sum - 1| any dispatch

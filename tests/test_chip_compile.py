@@ -6,7 +6,8 @@ Interpret mode (every other kernel test) cannot see what Mosaic refuses —
 a slice not aligned to the tiling, too much VMEM — so these compiles guard
 each kernel variant the engine can dispatch for mistral-7b, at its
 published widths and at its tp=4 shard shapes, and the latent decode and
-prefill kernels at sarvam-105b's and xing4.0's, against every later PR.
+prefill kernels at sarvam-105b's, xing4.0's and longcat-flash-omni's, against
+every later PR.
 A compile that passes is not a chip run: nothing executes here.
 
 The topology is described inside a module-scoped fixture (never at import,
@@ -150,7 +151,8 @@ def test_flash_prefill_kernel_compiles(sds, no_persistent_cache, heads, T, C):
 # the ring's three slots and the [16, 2048] table in SMEM have to fit.  And at
 # Xing4.0-29B-A4B's: 32 heads a row, the same cache row.
 @pytest.mark.parametrize("preset", ["sarvam-105b-ep4",
-                                    "xing4.0-29b-a4b-stage"])
+                                    "xing4.0-29b-a4b-stage",
+                                    "longcat-flash-omni-ep32"])
 @pytest.mark.parametrize("S", [8, 16], ids=["S8", "S16"])
 def test_latent_decode_kernel_compiles(sds, no_persistent_cache, S, preset):
     from production_stack_tpu.engine.models import sarvam_mla
@@ -176,7 +178,8 @@ def test_latent_decode_kernel_compiles(sds, no_persistent_cache, S, preset):
 # and probabilities, the accumulator and the ring have to fit the VMEM the
 # kernel asks for, and [slots, heads, lanes] has to read as rows where it lies.
 @pytest.mark.parametrize("preset", ["sarvam-105b-ep4",
-                                    "xing4.0-29b-a4b-stage"])
+                                    "xing4.0-29b-a4b-stage",
+                                    "longcat-flash-omni-ep32"])
 @pytest.mark.parametrize("T", [256, 2048], ids=["T256", "T2048"])
 def test_latent_prefill_kernel_compiles(sds, no_persistent_cache, T, preset):
     from production_stack_tpu.engine.models import sarvam_mla
@@ -528,6 +531,65 @@ def test_a_state_models_served_programs_copy_no_pool(
     assert not {k: n for k, n in found.items() if k[0] == "copy"}
     for (_, name), n in found.items():
         assert n <= staged[program].get(name, 0), (name, n)
+
+
+# longcat-flash-omni-ep32 (models/longcat.py): the programs the engine serves
+# it with -- the packed ``prefill_fn`` at both buckets and the ``window_fn`` (up
+# to 8 steps) at 16 rows -- at the published widths and the cell's pool (eight
+# cache arrays of ~29,700 blocks beside 10.34 GB of weights), the cache tree
+# donated.  Each holds both latent kernels' custom calls, once a cache array;
+# and what a program needs beside its arguments fits in what the engine leaves
+# of the device (it fills ~15.2 of 16.9 GB): 0.81 GB for the 2,048-slot chunk,
+# whose 24,576 (row, pick) pairs are gathered for the grouped products.
+@pytest.mark.parametrize("program", ["prefill-256", "prefill-2048", "window"])
+def test_longcats_served_programs_compile(
+        one_chip, no_persistent_cache, monkeypatch, program):
+    from production_stack_tpu.engine.core import step_programs
+    from production_stack_tpu.engine.models import longcat as model
+
+    cfg = PRESETS["longcat-flash-omni-ep32"]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    on_chip = lambda tree: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    params = on_chip(jax.eval_shape(
+        lambda: model.init_params(cfg, jax.random.PRNGKey(0))))
+    cache = on_chip(jax.eval_shape(lambda: model.init_cache(cfg, 29_700, BS)))
+    assert len(cache) == 8
+    arg = lambda dtype, *shape: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+    i32, f32 = (functools.partial(arg, dt) for dt in (jnp.int32, jnp.float32))
+    S, bmax = 16, cfg.max_model_len // BS
+    if program == "window":
+        kernel = "latent_decode_attention_pallas"
+        lowered = jax.jit(step_programs.window_program(
+            functools.partial(model.decode, cfg=cfg, return_stats=True),
+            block_size=BS, n_steps=8, vocab=cfg.vocab_size,
+            n_counts=len(model.stats_names(cfg))),
+            static_argnames=("use_penalties", "use_min_floor"),
+            donate_argnames=("kv_caches",),
+        ).lower(
+            params, tokens=i32(S), positions=i32(S), ctx_lens=i32(S),
+            done=arg(jnp.bool_, S), min_left=i32(S), block_tables=i32(S, bmax),
+            max_steps=i32(S), kv_caches=cache, temps=f32(S), top_ps=f32(S),
+            top_ks=i32(S), min_ps=f32(S), seq_seeds=i32(S), stop_ids=i32(S, 4),
+            key_base=i32(), counts=arg(jnp.int16, S, 1),
+            seen=arg(jnp.bool_, S, 1), presence=f32(S), frequency=f32(S),
+            repetition=f32(S), use_penalties=False, use_min_floor=False)
+    else:
+        kernel = "latent_prefill_attention_pallas"
+        T, scalars = int(program.split("-")[1]), ("cached_len", "valid_len")
+        lowered = jax.jit(step_programs.prefill_program(
+            functools.partial(model.prefill, cfg=cfg, return_stats=True),
+            scalars, BS, bmax), donate_argnames=("kv_caches",),
+            static_argnames=("prompt_topk",),
+        ).lower(params, i32(T + T // BS + bmax + len(scalars)),
+                kv_caches=cache)
+    compiled = lowered.compile()
+    assert compiled.as_text().count(kernel) >= 8
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 1.2e9
+    assert memory.alias_size_in_bytes >= 8 * 29_700 * BS * 640 * 2   # donated
 
 
 def test_int8_kv_decode_kernel_is_still_refused(sds, no_persistent_cache):
