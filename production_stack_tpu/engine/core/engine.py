@@ -1881,7 +1881,7 @@ class LLMEngine:
         if bucket_tokens is None:
             bucket_tokens = sum(cp.bucket_len for cp in chunks)
         # Counted with tracing off too: /metrics carries the totals.
-        kv_tiles_live, kv_tiles_grid = self._count_kv_tiles(
+        kv_tiles_live, kv_tiles_grid, prefix_pages = self._count_kv_tiles(
             chunks, bucket_tokens
         )
         bs = self.block_pool.block_size
@@ -1931,47 +1931,55 @@ class LLMEngine:
             new_tokens=new_tokens, bucket_tokens=bucket_tokens,
             cached_tokens=sum(first.values()),
             kv_tiles_live=kv_tiles_live, kv_tiles_grid=kv_tiles_grid,
-            **fields,
+            prefix_pages=prefix_pages, **fields,
         )
 
     def _count_kv_tiles(self, chunks, bucket_tokens: int):
         """(kv tiles the prefill attention kernel computes, kv tiles in its
-        grid) per layer for the PrefillPlans of one dispatch, by the
-        kernel's own liveness rule — host arithmetic, no device read; also
-        feeds ``tpu:prefill_attn_tiles_total``.  The kernel is the module's
-        own where it says so (``prefill_attn_tiles``: the latent prefill
-        kernel's (query tile, key stage) pairs), else the flash prefill
-        kernel.  The counts describe the kernel's grid whether or not it is
-        the path that runs (under a tp mesh, and off the TPU, prefill takes
-        the XLA path)."""
+        grid), per layer, and the pages of the prefix it fetches over all its
+        layers, for the PrefillPlans of one dispatch, by the kernel's own
+        liveness rule — host arithmetic, no device read; also feeds
+        ``tpu:prefill_attn_tiles_total``.  The kernel is the module's own
+        where it says so (``prefill_attn_tiles``: the latent prefill kernel's
+        (query tile, key stage) pairs; no pages are counted for it), else the
+        flash prefill kernel, whose grid is the plan's block table
+        (``max_model_len`` positions of pages) and the chunk.  The tiles
+        describe the kernel's grid whether or not it is the path that runs
+        (under a tp mesh, and off the TPU, prefill takes the XLA path); pages
+        are counted only where it runs (``_flash_prefill_serves``): the dense
+        form fetches none."""
         if not chunks:
-            return 0, 0
+            return 0, 0, 0
         from production_stack_tpu.engine.ops.pallas.flash_prefill import (
             count_kv_tiles,
         )
 
         bmax, bs = max(self._bmax, 1), self.block_pool.block_size
-        C = bmax * bs
         own_rule = getattr(self.model, "prefill_attn_tiles", None)
-        live = grid = slots = 0
+        live = grid = pages = slots = 0
         for cp in chunks:
             n_grid = 0
             # A layer of each kind: a kind whose keys lie in slots of the
-            # state pool has one window of them as its gathered prefix.
-            for _label, window, _layers, in_slots in self._attn_kinds:
+            # state pool hands one window of them as a pool of one page.
+            for label, window, layers, in_slots in self._attn_kinds:
                 if own_rule is not None:
                     n_live, n = own_rule(
                         self.config.model, cp.bucket_len, bmax, bs,
                         cp.cached_len, cp.num_new_tokens)
+                    n_pages = 0
                 else:
-                    n_live, n = count_kv_tiles(
-                        cp.bucket_len, window if in_slots else C,
+                    n_live, n, n_pages = count_kv_tiles(
+                        cp.bucket_len, *((1, window) if in_slots
+                                         else (bmax, bs)),
                         min(cp.cached_len, window) if in_slots
                         else cp.cached_len,
                         cp.num_new_tokens, window,
                     )
                 live += n_live
                 n_grid += n
+                if n_pages and self._flash_prefill_serves(
+                        label, cp.bucket_len):
+                    pages += n_pages * layers
             grid += n_grid
             slots += cp.bucket_len
         # A mixed window's scan pads its schedule (one bucket) to a power
@@ -1980,7 +1988,17 @@ class LLMEngine:
         grid += (bucket_tokens - slots) // cp.bucket_len * n_grid
         self.prefill_attn_tiles["live"] += live
         self.prefill_attn_tiles["skipped"] += grid - live
-        return live, grid
+        return live, grid, pages
+
+    def _flash_prefill_serves(self, kind: str, bucket_len: int) -> bool:
+        """Whether a chunk of ``bucket_len`` slots through a layer of
+        ``kind`` (an ``_attn_kinds`` label) takes the flash prefill kernel
+        here: ``ops/attention.py: prefill_attention``'s own selector."""
+        cfg = self.config.model
+        heads = (cfg.attention_specs[kind].num_heads if cfg.attention_specs
+                 else cfg.num_heads)
+        return self.mesh.size == 1 and attn_ops.use_pallas_prefill(
+            heads, cfg.num_kv_heads, cfg.head_dim, bucket_len)
 
     def _note_compiles(self, rec, seq_ids=None) -> None:
         """Drain XLA compile events fired inside the jit calls this

@@ -38,15 +38,24 @@ from production_stack_tpu.engine.kv.block_pool import BlockPool
 logger = logging.getLogger(__name__)
 
 # What one prefill dispatch costs besides its slots, in slots.  Measured on
-# a v5e under int8 mistral-7b (PERF.md section 6, PR 32): a ``prefill_fn``
-# program takes 0.086 ms a slot plus 5.2 ms that do not scale with its slots
-# (the gather of the prefix positions), a later chunk of a run 0.85 ms more
-# for each 256 tokens written before it, and the step thread spends 4-5 ms
-# building and launching each dispatch: ~11 ms, 128 slots.  Inside a run the
-# host's part hides behind the device, so six 256-slot chunks (177 ms) beat
-# the 2,048-slot program (184 ms) by a hair where the constant says they
-# lose; at five chunks and at seven (146 against 182 ms, 210 against 187)
-# the constant and the device agree.
+# a v5e under int8 mistral-7b (PERF.md section 5, "One prefill program"): a
+# ``prefill_fn`` program takes 0.086 ms a slot and, since PR 62, next to
+# nothing that does not scale with its slots (22.1 ms at 256 slots, 175.9 at
+# 2,048 with 1,500 valid: an intercept of 0.2 ms; it was 5.2-5.6 ms at PR 32,
+# the gather of ``max_model_len`` prefix positions in every layer, which the
+# kernel's walk through the block table replaced), a later chunk of a run
+# 0.36 ms more for each 256 tokens written before it, and the step thread
+# spends 4-5 ms building and launching each dispatch, which hides behind the
+# device inside a run: what is left is ~5 ms, 56-64 slots, and six 256-slot
+# chunks (138 ms on the device) beat the 2,048-slot program (176 ms) where
+# this constant still sends 1,281-1,536 tokens to the latter.  It stays at
+# PR 32's 128 (11 ms then; under ``256,2048`` every value from 103 to 191
+# plans alike) until the engine warms every configured prefill bucket in its
+# set-up: a bucket's program is compiled at its first use, and a smaller
+# value moves the 2,048-slot program's first use from a prompt of 1,281
+# tokens to one of 1,537, past what a warm-up of mid-length prompts reaches,
+# so its compile would land on live traffic (the follow-up: ROADMAP S1 b;
+# PERF.md section 7, PR 62).
 PREFILL_DISPATCH_SLOTS = 128
 
 

@@ -325,8 +325,10 @@ def _window_prefill(layer, cfg, spec, cache, x, cached_len, valid_len, slots):
     # differences of positions alone, and the keys are rotated already).
     have = jnp.minimum(cached_len, W)
     order = (cached_len - have + jnp.arange(W)) % W
+    # They go in as a pool of one W-token page with the table [0].
     out = attn_ops.prefill_attention(
-        q, k, v, old[0][order], old[1][order], have, valid_len,
+        q, k, v, old[0][order][None], old[1][order][None],
+        jnp.zeros((1,), jnp.int32), have, valid_len,
         scale=cfg.head_dim ** -0.5, sliding_window=W)
     put = jax.lax.dynamic_update_index_in_dim
     buffers = []

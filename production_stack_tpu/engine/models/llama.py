@@ -374,10 +374,10 @@ def prefill(
         )
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
-        k_prefix, v_prefix = attn_ops.gather_prefix_kv(
-            k_cache, v_cache, prefix_block_ids, dtype=k.dtype
-        )
         if use_ring:
+            k_prefix, v_prefix = attn_ops.gather_prefix_kv(
+                k_cache, v_cache, prefix_block_ids, dtype=k.dtype
+            )
             if sp_mode == "ulysses":
                 from production_stack_tpu.engine.parallel.ulysses import (
                     ulysses_prefill_with_prefix,
@@ -414,7 +414,8 @@ def prefill(
             )(q, k, v, k_prefix, v_prefix, cached_len, valid_len)
         else:
             out = attn_ops.prefill_attention(
-                q, k, v, k_prefix, v_prefix, cached_len, valid_len,
+                q, k, v, k_cache, v_cache, prefix_block_ids,
+                cached_len, valid_len,
                 scale=scale, sliding_window=cfg.sliding_window, mesh=mesh,
             )
         k_cache, v_cache = attn_ops.write_prefill_kv(
@@ -477,8 +478,7 @@ def encode(
     positions = jnp.arange(T)
     cos, sin = rope_cos_sin(positions, cfg.head_dim, cfg.rope_theta,
                             cfg.rope_scaling)
-    empty_k = jnp.zeros((0, cfg.num_kv_heads, cfg.head_dim), cfg.dtype)
-    empty_v = empty_k
+    no_prefix = jnp.zeros((0,), jnp.int32)  # and no cache behind it
 
     x = _embed(params, cfg, tokens)  # [T, h]
     for layer in params["layers"]:
@@ -488,7 +488,7 @@ def encode(
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
         out = attn_ops.prefill_attention(
-            q, k, v, empty_k, empty_v, jnp.int32(0), valid_len,
+            q, k, v, None, None, no_prefix, jnp.int32(0), valid_len,
             scale=scale, sliding_window=cfg.sliding_window, mesh=mesh,
         )
         out = out.reshape(T, cfg.num_heads * cfg.head_dim)
@@ -601,11 +601,8 @@ def mixed_step(
         )
         # Prefill segment: attend over prefix + chunk, then scatter the
         # chunk's KV into its new blocks.
-        k_prefix, v_prefix = attn_ops.gather_prefix_kv(
-            k_cache, v_cache, pf_prefix_block_ids, dtype=k.dtype
-        )
         out_pf = attn_ops.prefill_attention(
-            q[S:], k[S:], v[S:], k_prefix, v_prefix,
+            q[S:], k[S:], v[S:], k_cache, v_cache, pf_prefix_block_ids,
             pf_cached_len, pf_valid_len,
             scale=scale, sliding_window=cfg.sliding_window, mesh=mesh,
         )

@@ -514,10 +514,8 @@ def _gqa_prefill(layer, cfg, cache, h, cached_len, prefix_block_ids,
     pages).  ``project`` and ``out`` are this module's; another module hands
     its own (``models/laguna.py``: rotary by layer kind, a gate a head)."""
     q, k, v = project(layer, cfg, h)
-    k_prefix, v_prefix = attn_ops.gather_prefix_kv(
-        *cache, prefix_block_ids, dtype=k.dtype)
     attended = attn_ops.prefill_attention(
-        q, k, v, k_prefix, v_prefix, cached_len, valid_len,
+        q, k, v, *cache, prefix_block_ids, cached_len, valid_len,
         scale=cfg.head_dim ** -0.5)
     return out(layer, cfg, h, attended), attn_ops.write_prefill_kv(
         *cache, k, v, new_block_ids)

@@ -25,6 +25,7 @@ kernel and host offload (kv/offload.py).
 
 from __future__ import annotations
 
+import math
 import os
 from functools import partial
 from typing import Optional, Tuple
@@ -138,6 +139,74 @@ def prefill_attention(
     q: jax.Array,  # [T, H, D]
     k_new: jax.Array,  # [T, K, D]
     v_new: jax.Array,  # [T, K, D]
+    k_cache,  # [N, bs, K, D], or (data, scale) when int8, or None at P == 0
+    v_cache,
+    prefix_block_ids: jax.Array,  # [P] int32 (0-padded; P may be 0)
+    cached_len: jax.Array,  # scalar int: valid prefix tokens (<= P * bs)
+    valid_len: jax.Array,  # scalar int: valid new tokens (<= T)
+    *,
+    scale: float,
+    sliding_window: Optional[int] = None,
+    mesh: Optional[Mesh] = None,
+) -> jax.Array:
+    """Causal attention for one sequence's prefill chunk over its cached
+    prefix -- the first ``cached_len`` positions of the pages
+    ``prefix_block_ids`` names -- and the new tokens themselves, with backend
+    dispatch.  ``k_cache`` / ``v_cache`` may be None behind an empty table.
+
+    On a single TPU device the Pallas flash kernel reads the prefix's pages
+    where they lie in the pool (pallas/flash_prefill.py): nothing is gathered.
+    A quantized (data, scale) cache keeps the kernel too: its prefix is
+    gathered and dequantized, as it always was, and that copy goes in as a
+    pool of its own (:func:`prefix_as_pool`).  The dense statement below,
+    over a gathered ``[P * bs, K, D]`` copy of the prefix, stays where the
+    kernel cannot serve, by what is seen at trace time: a multi-device mesh
+    (GSPMD partitions its einsums across tp automatically, while a bare
+    pallas_call cannot be auto-partitioned; the sp>1 case never reaches here
+    -- llama.prefill routes it to ring attention) and off a TPU."""
+    T, H, D = q.shape
+    single_device = mesh is None or mesh.size == 1
+    kernel = single_device and use_pallas_prefill(H, k_new.shape[1], D, T)
+    if kernel and not kv_quant.is_quantized(k_cache):
+        pool = k_cache, v_cache, prefix_block_ids
+    else:
+        dense = (k_new[:0], v_new[:0]) if k_cache is None else (
+            gather_prefix_kv(
+                k_cache, v_cache, prefix_block_ids, dtype=k_new.dtype))
+        if not kernel:
+            return dense_prefill_attention(
+                q, k_new, v_new, *dense, cached_len, valid_len,
+                scale=scale, sliding_window=sliding_window,
+            )
+        pool = prefix_as_pool(*dense)
+    from production_stack_tpu.engine.ops.pallas.flash_prefill import (
+        flash_prefill_attention,
+    )
+
+    return flash_prefill_attention(
+        q, k_new, v_new, *pool, cached_len, valid_len,
+        scale=scale, sliding_window=sliding_window,
+    )
+
+
+def prefix_as_pool(
+    k_prefix: jax.Array,  # [C, K, D]: a prefix's positions in order
+    v_prefix: jax.Array,
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """A dense copy of a prefix as ``prefill_attention`` takes one: a pool of
+    its own, ``(k_pool, v_pool, prefix_block_ids)`` with pages of up to 512
+    positions in order (a dequantized copy; Ulysses' redistributed prefix)."""
+    C = k_prefix.shape[0]
+    page = math.gcd(C, 512)
+    as_pool = lambda x: x.reshape(C // page, page, *x.shape[1:])  # noqa: E731
+    return (as_pool(k_prefix), as_pool(v_prefix),
+            jnp.arange(C // page, dtype=jnp.int32))
+
+
+def dense_prefill_attention(
+    q: jax.Array,  # [T, H, D]
+    k_new: jax.Array,  # [T, K, D]
+    v_new: jax.Array,  # [T, K, D]
     k_prefix: jax.Array,  # [C_max, K, D] gathered cached prefix (may be empty)
     v_prefix: jax.Array,  # [C_max, K, D]
     cached_len: jax.Array,  # scalar int: valid prefix tokens (< C_max)
@@ -145,29 +214,13 @@ def prefill_attention(
     *,
     scale: float,
     sliding_window: Optional[int] = None,
-    mesh: Optional[Mesh] = None,
 ) -> jax.Array:
-    """Causal attention for one sequence's prefill, attending to an optional
-    cached prefix (prefix-cache hit) plus the new tokens themselves.
-
-    Dispatches to the Pallas flash kernel on single-device TPU (the dense
-    path below materializes [K, G, T, C+T] fp32 scores, which spills to
-    HBM for long prompts — see pallas/flash_prefill.py).  Under a
-    multi-device mesh the dense path stays: GSPMD partitions its einsums
-    across tp automatically, while a bare pallas_call cannot be
-    auto-partitioned (the sp>1 case never reaches here — llama.prefill
-    routes it to ring attention)."""
+    """The plain statement of prefill attention: one sequence's new tokens
+    attend a gathered cached prefix plus themselves, causally.  It
+    materializes [K, G, T, C_max+T] fp32 scores (which spill to HBM for long
+    prompts -- see pallas/flash_prefill.py): what the kernel is held against,
+    and the path of :func:`prefill_attention`'s fallbacks."""
     T, H, D = q.shape
-    single_device = mesh is None or mesh.size == 1
-    if single_device and use_pallas_prefill(H, k_new.shape[1], D, T):
-        from production_stack_tpu.engine.ops.pallas.flash_prefill import (
-            flash_prefill_attention,
-        )
-
-        return flash_prefill_attention(
-            q, k_new, v_new, k_prefix, v_prefix, cached_len, valid_len,
-            scale=scale, sliding_window=sliding_window,
-        )
     C_max = k_prefix.shape[0]
     K = k_new.shape[1]
     G = H // K
@@ -314,10 +367,12 @@ def gather_prefix_kv(
     prefix_block_ids: jax.Array,  # [P] int32 (0-padded)
     dtype=None,  # dequantization target for quantized caches (fp32 default)
 ) -> Tuple[jax.Array, jax.Array]:
-    """Gather a cached prefix as [P*bs, K, D] for prefill attention.
+    """Gather a cached prefix as [P*bs, K, D] for the dense forms of
+    prefill attention (``prefill_attention``'s fallbacks, ring, ulysses);
+    the flash kernel reads the pages itself.
 
-    Quantized caches dequantize here — downstream prefill attention
-    (dense, flash kernel, ring, ulysses) is precision-agnostic.
+    Quantized caches dequantize here — the dense forms are
+    precision-agnostic.
     """
     N, bs, K, D = kv_quant.cache_shape(k_cache)
     P = prefix_block_ids.shape[0]

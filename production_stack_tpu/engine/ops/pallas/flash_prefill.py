@@ -1,45 +1,70 @@
 """Pallas TPU flash-attention kernel for prefill (causal + cached prefix).
 
-Why the dense path stalls at ~0.44 MFU: ops/attention.py:prefill_attention
-materializes the full fp32 score/prob tensors — [K, G, T, C+T] is ~430 MB
-for a 2k-token llama-3.2-3b prefill, far beyond VMEM, so XLA spills them
-to HBM and the MXU waits on bandwidth.  This kernel never materializes
+Why the dense path stalls at ~0.44 MFU: ``dense_prefill_attention``
+(ops/attention.py) materializes the full fp32 score/prob tensors —
+[K, G, T, C+T] is ~430 MB for a 2k-token llama-3.2-3b prefill, far beyond
+VMEM, so XLA spills them to HBM and the MXU waits on bandwidth.  This kernel never materializes
 scores in HBM: a 2-D grid (query tile x kv tile) streams keys/values
 through VMEM in [Tk, K, D] slices while the online-softmax state
 (running max, normalizer, and fp32 accumulator) lives in VMEM scratch
 that persists across the kv dimension of the grid.  Nothing resident
-scales with sequence length, so VMEM stays ~12 MB at any context
-(a previous revision kept the whole [S_k, K, D] KV row resident, which
-blew the 16 MB scoped-VMEM limit at 2k context on a 3B model).
+scales with sequence length, so VMEM stays ~16 MB at any context.
 
-Tile skipping: the grid is static — (T/Tq, ceil((C+T)/Tk)), and the
-engine always gathers max_model_len prefix slots, so C is 8,192 whatever
+**The cached prefix is read where it lies** (PR 62): the kernel takes the
+layer's K and V pools ``[N, bs, K, D]``, left in HBM, and the prefix's block
+ids by scalar prefetch, and a prefix kv tile is ``Tk / bs`` pages copied
+HBM -> VMEM into one slot of a ring of two, as ``latent_attention.py:
+_walk_prefix`` and the paged decode walk do: the next live prefix tile --
+the same query tile's, or the next query tile's first -- is in flight while
+this one is computed on, every copy that is started is waited for, every id
+is clipped into the pool and the copies' bounds checks are off.  A tile's
+pages past the table's end repeat its last entry: the mask drops their
+positions, and all of a tile's pages are always fetched, so no score meets
+what an earlier call left in VMEM.  The chunk's own ``k_new`` / ``v_new`` stay
+a ``[T, K, D]`` operand, tiled by a BlockSpec, and are the grid's last tiles.
+No gathered copy of the prefix, no ``concatenate`` and no ``pad`` exist
+(before: ``max_model_len`` positions a side a layer, whatever ``cached_len``
+was: 5.6 ms of mistral-7b's 27.8 ms program at 256 slots).  One key head (a
+page is 4 kB) goes page by page like eight: the prefix there is 6 MB a layer,
+the copies hide behind the dots, and the decode walk's grouping would be more
+code.  A pool may have pages of any size: ``models/laguna.py`` hands a window
+layer's 512-row rolling buffer as a pool of one 512-token page with the table
+``[0]``, and a dense copy of a prefix -- a quantized cache's, dequantized;
+Ulysses' redistributed one -- is a pool of 512-position pages in order
+(``ops/attention.py: prefix_as_pool``).  Behind an empty table (the encode
+lane) no pool is asked for.
+
+Tile skipping: the grid is static — (T/Tq, prefix steps + new-key steps),
+``ceil(len(prefix_block_ids) * bs / Tk)`` prefix steps whatever
 ``cached_len`` is — but a kv tile is *visited* only if one of its scores
 survives the mask for one query of the query tile.  ``live_kv_tiles``
-states that rule once, per query tile, as two ranges of kv tiles:
+states that rule once, per query tile, as two ranges of grid steps:
 
 * the query tile has a valid row (``i*Tq < valid_len``), else nothing;
-* prefix tiles holding a slot below ``min(C, cached_len)``;
+* prefix tiles holding a position below ``cached_len``;
 * new-key tiles holding a key below ``valid_len`` and not above the
   tile's causal frontier (its last query);
 * with a sliding window, only tiles whose newest valid key is still
   inside the window of the tile's oldest query.
 
-A tile that straddles prefix and new keys (C need not be a multiple of
-Tk) is live if either part is.  The rule is used three times: the
-compute fence (``pl.when``), the kv index map — every dead step maps to
-the block of the nearest live tile already fetched (dead query tiles to
-the one tile the last live query tile ended on), so the block index
-repeats and Mosaic's revisit elision issues no DMA — and, on the host,
-``count_kv_tiles`` for the flight records' ``kv_tiles_live`` /
-``kv_tiles_grid``.  Skipping is exact: a fully masked tile's
-contribution is wiped by ``alpha = exp(NEG_INF - m) = 0`` at the first
-live tile, and a query tile that saw no live tile ends with ``l == 0``
-and emits zeros.
+The rule is used four times: the compute fence (``pl.when``: a dead step
+issues no DMA and no compute), the walk's lookahead (which prefix tile to
+start next), the new keys' index map — every dead step maps to the block of
+the nearest live new-key tile (dead query tiles to the one tile the last
+live query tile ended on), so the block index repeats and Mosaic's revisit
+elision issues no DMA — and, on the host, ``count_kv_tiles`` for the flight
+records' ``kv_tiles_live`` / ``kv_tiles_grid`` / ``prefix_pages``.  Skipping
+is exact: a fully masked tile's contribution is wiped by ``alpha =
+exp(NEG_INF - m) = 0`` at the first live tile, and a query tile that saw no
+live tile ends with ``l == 0`` and emits zeros.
 
 Layout notes (Mosaic): blocks keep the (head, lane) dims whole — q tiles
 are [Tq, H, D], kv tiles [Tk, K, D] — because Mosaic requires the last
-two block dims divisible by (8, 128) or equal to the array's.  GQA
+two block dims divisible by (8, 128) or equal to the array's.  A prefix
+tile's pages ``[Tk/bs, bs, K, D]`` read as ``[Tk, K, D]`` where they lie
+(merging leading dims is layout-free); at one key head a page goes in as
+``[bs, D]``, the layout XLA keeps ``[N, bs, 1, D]`` in anyway (a second-minor
+dim of 1 is padded to a tile a one-head DMA slice is not aligned to).  GQA
 regrouping happens in-register via swapaxes/reshape moves (the decode
 kernel spares them: it has G rows a head, not Tq*G); both matmuls are
 K-batched dot_generals contracting the lane dim, so no transposes are
@@ -48,9 +73,10 @@ across the 128-lane dim (scratch must be lane-tiled anyway) and read
 back with a lane-reduce.
 
 Position/validity semantics match the dense path exactly
-(ops/attention.py:128-143): key j < C is prefix slot j (valid while
-j < cached_len), key j >= C is new token j-C at position cached_len+(j-C)
-(valid while j-C < valid_len); query row t sits at cached_len + t.
+(ops/attention.py: dense_prefill_attention): prefix position j is slot
+``j % bs`` of page ``prefix_block_ids[j // bs]`` (valid while
+j < cached_len), new token t sits at position cached_len + t (valid while
+t < valid_len), as query row t does.
 
 Replaces the role of FlashAttention prefill kernels inside the reference's
 external vLLM engine (the reference ships no kernels — SURVEY.md preamble).
@@ -59,6 +85,7 @@ external vLLM engine (the reference ships no kernels — SURVEY.md preamble).
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -67,31 +94,38 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from production_stack_tpu.engine.ops.pallas import each
+
 NEG_INF = -1e30
 LANES = 128  # scratch lane width: fp32 scratch must tile to (8, 128)
 Q_TILE = 128
 KV_TILE = 512
+SLOTS = 2  # the ring: one prefix tile computed on, the next in flight
 
 
-def _tiling(T: int, C: int, q_tile: int, kv_tile: int):
-    """(Tq, Tk, query tiles, kv tiles) of the grid for T new tokens behind
-    C gathered prefix slots."""
+def _tiling(T: int, P: int, bs: int, q_tile: int, kv_tile: int):
+    """(Tq, pages a prefix tile, Tn, prefix steps, new-key steps) of the grid
+    for T new tokens behind a table of P pages of bs positions: a prefix tile
+    is whole pages, about ``kv_tile`` positions of them, a new-key tile
+    divides T."""
     Tq = min(q_tile, T)
-    Tk = min(kv_tile, C + T)
-    return Tq, Tk, T // Tq, -(-(C + T) // Tk)
+    Cp = max(1, min(kv_tile // bs, P))
+    Tn = T if T <= kv_tile else math.gcd(T, kv_tile)
+    return Tq, Cp, Tn, -(-P // Cp), T // Tn
 
 
-def live_kv_tiles(i, cached_len, valid_len, *, Tq, Tk, C, sliding_window,
-                  xp=jnp):
-    """The liveness rule (module docstring): which kv tiles query tile
+def live_kv_tiles(i, cached_len, valid_len, *, Tq, Tp, Tn, NP,
+                  sliding_window, xp=jnp):
+    """The liveness rule (module docstring): which grid steps query tile
     ``i`` must visit, as ``(q_live, prefix_live, p_lo, p_hi, n_lo, n_hi)``
-    — kv tiles ``p_lo..p_hi`` hold its visible prefix slots (only if
-    ``prefix_live``), ``n_lo..n_hi`` its visible new keys, and nothing is
-    live unless ``q_live``.  Pure integer arithmetic on ``xp`` scalars or
-    arrays: jnp inside the kernel and its index map, numpy on the host."""
+    — steps ``p_lo..p_hi`` (below ``NP``, ``Tp`` positions each) hold its
+    visible prefix positions (only if ``prefix_live``), ``n_lo..n_hi`` (from
+    ``NP`` on, ``Tn`` keys each) its visible new keys, and nothing is live
+    unless ``q_live``.  Pure integer arithmetic on ``xp`` scalars or arrays:
+    jnp inside the kernel and its index map, numpy on the host."""
     q0 = i * Tq  # the tile's oldest query, as a new-token index
     q_live = q0 < valid_len
-    p_end = xp.minimum(cached_len, C)  # valid prefix slots [p_start, p_end)
+    p_end = xp.minimum(cached_len, NP * Tp)  # valid prefix [p_start, p_end)
     n_end = xp.minimum(valid_len, q0 + Tq)  # valid causal keys [n_start, n_end)
     if sliding_window is None:
         p_start = n_start = 0
@@ -104,8 +138,8 @@ def live_kv_tiles(i, cached_len, valid_len, *, Tq, Tk, C, sliding_window,
     prefix_live = q_live & (p_start < p_end)
     return (
         q_live, prefix_live,
-        p_start // Tk, (xp.maximum(p_end, 1) - 1) // Tk,
-        (C + n_start) // Tk, (C + xp.maximum(n_end, 1) - 1) // Tk,
+        p_start // Tp, (xp.maximum(p_end, 1) - 1) // Tp,
+        NP + n_start // Tn, NP + (xp.maximum(n_end, 1) - 1) // Tn,
     )
 
 
@@ -116,56 +150,63 @@ def _tile_is_live(j, ranges):
     )
 
 
-def kv_block_index(i, j, cached_len, valid_len, *, Tq, Tk, C,
-                   sliding_window, xp=jnp):
-    """The kv block that grid step ``(i, j)`` holds.  A live step holds its
-    own tile ``j``; a dead step repeats the block of the nearest live tile
-    already fetched, so Mosaic's revisit elision skips its DMA: clamp into
-    [first live, last live], and park the gap between the prefix range and
-    the new-key range on the prefix range's end.  A dead query tile stays
-    where the last live query tile ended."""
-    q_live, prefix_live, p_lo, p_hi, n_lo, n_hi = live_kv_tiles(
+def new_block_index(i, j, cached_len, valid_len, *, Tq, Tp, Tn, NP,
+                    sliding_window, xp=jnp):
+    """The block of ``k_new`` / ``v_new`` that grid step ``(i, j)`` holds.  A
+    live new-key step holds its own tile; any other step of a live query tile
+    — its prefix steps first — the nearest live one, so the tile after the
+    prefix is there when the walk ends and Mosaic's revisit elision skips
+    every repeat.  A dead query tile stays where the last live one ended."""
+    q_live, _, _, _, n_lo, n_hi = live_kv_tiles(
         i, cached_len, valid_len,
-        Tq=Tq, Tk=Tk, C=C, sliding_window=sliding_window, xp=xp,
+        Tq=Tq, Tp=Tp, Tn=Tn, NP=NP, sliding_window=sliding_window, xp=xp,
     )
-    idx = xp.clip(j, xp.where(prefix_live, p_lo, n_lo), n_hi)
-    idx = xp.where(prefix_live & (idx > p_hi) & (idx < n_lo), p_hi, idx)
-    parked = (C + xp.maximum(valid_len, 1) - 1) // Tk
-    return xp.where(q_live, idx, parked)
+    parked = (xp.maximum(valid_len, 1) - 1) // Tn
+    return xp.where(q_live, xp.clip(j, n_lo, n_hi) - NP, parked)
 
 
-def count_kv_tiles(T: int, C: int, cached_len: int, valid_len: int,
+def count_kv_tiles(T: int, P: int, bs: int, cached_len: int, valid_len: int,
                    sliding_window: Optional[int] = None, *,
                    q_tile: int = Q_TILE, kv_tile: int = KV_TILE):
-    """(kv tiles the kernel computes, kv tiles in its grid) for one call,
-    per layer — the same rule on the host, arithmetic only."""
-    Tq, Tk, NQ, NKV = _tiling(T, C, q_tile, kv_tile)
+    """(kv tiles the kernel computes, kv tiles in its grid, pages of the
+    prefix it fetches a side) for one call, per layer — the same rule on the
+    host, arithmetic only.  A live prefix tile fetches all its pages."""
+    Tq, Cp, Tn, NP, NN = _tiling(T, P, bs, q_tile, kv_tile)
+    NQ = T // Tq
     ranges = live_kv_tiles(
-        np.arange(NQ), cached_len, valid_len,
-        Tq=Tq, Tk=Tk, C=C, sliding_window=sliding_window, xp=np,
+        np.arange(NQ), cached_len, valid_len, Tq=Tq, Tp=Cp * bs, Tn=Tn,
+        NP=NP, sliding_window=sliding_window, xp=np,
     )
-    live = _tile_is_live(np.arange(NKV)[:, None], ranges)
-    return int(live.sum()), NQ * NKV
+    live = _tile_is_live(np.arange(NP + NN)[:, None], ranges)
+    return int(live.sum()), NQ * (NP + NN), int(live[:NP].sum()) * Cp
 
 
 def _flash_prefill_kernel(
     # scalar prefetch (SMEM)
-    cached_len_ref,  # [1] int32
-    valid_len_ref,  # [1] int32
-    # inputs (VMEM blocks)
-    q_ref,  # [Tq, H, D] this query tile, all heads
-    k_ref,  # [Tk, K, D] this kv tile
-    v_ref,  # [Tk, K, D]
+    ids_ref,  # [max(P, 1)] int32: the prefix's pages
+    lens_ref,  # [2] int32: cached_len, valid_len
+    # inputs
+    q_ref,  # [Tq, H, D] VMEM: this query tile, all heads
+    kn_ref,  # [Tn, K, D] VMEM: this new-key tile
+    vn_ref,  # [Tn, K, D]
+    k_hbm,  # [N, bs, K, D] HBM ([N, bs, D] at one key head): the pools
+    v_hbm,
     # outputs
     o_ref,  # [Tq, H, D]
-    # scratch (VMEM, persists across the kv grid dim)
+    # scratch (persists across the grid)
     m_ref,  # [K, R, LANES] fp32 running max (lane-broadcast)
     l_ref,  # [K, R, LANES] fp32 running normalizer (lane-broadcast)
     acc_ref,  # [K, R, D] fp32 output accumulator
+    k_buf,  # [SLOTS, Cp, *page] VMEM: the ring of prefix tiles
+    v_buf,
+    sems,  # DMA semaphores [2 sides, SLOTS, Cp]
+    walked_ref,  # [1] int32 SMEM: prefix tiles this call has computed on
     *,
     Tq: int,
-    Tk: int,
-    C: int,
+    Tn: int,
+    P: int,
+    NP: int,
+    NQ: int,
     NKV: int,
     K: int,
     G: int,
@@ -175,13 +216,17 @@ def _flash_prefill_kernel(
 ):
     i = pl.program_id(0)
     j = pl.program_id(1)
-    cached = cached_len_ref[0]
-    valid = valid_len_ref[0]
+    cached, valid = lens_ref[0], lens_ref[1]
     R = Tq * G  # query rows per kv head after GQA regrouping
-
-    live = _tile_is_live(j, live_kv_tiles(
-        i, cached, valid, Tq=Tq, Tk=Tk, C=C, sliding_window=sliding_window,
-    ))
+    Cp, bs = k_buf.shape[1], k_buf.shape[2]
+    Tp = Cp * bs
+    num_blocks = k_hbm.shape[0]
+    rule = functools.partial(
+        live_kv_tiles, cached_len=cached, valid_len=valid,
+        Tq=Tq, Tp=Tp, Tn=Tn, NP=NP, sliding_window=sliding_window,
+    )
+    ranges = rule(i)
+    live = _tile_is_live(j, ranges)
 
     @pl.when(j == 0)
     def _init():
@@ -189,13 +234,18 @@ def _flash_prefill_kernel(
         l_ref[...] = jnp.zeros((K, R, LANES), jnp.float32)
         acc_ref[...] = jnp.zeros((K, R, D), jnp.float32)
 
-    @pl.when(live)
-    def _compute():
+    def heads_first(x):
+        """A kv tile [Tk, K, D] (at one key head a prefix tile is [Tk, D])
+        -> [K, Tk, D] fp32."""
+        x = x.astype(jnp.float32)
+        return x[None] if x.ndim == 2 else x.swapaxes(0, 1)
+
+    def fold(k, v, key_pos, key_valid):
+        """One kv tile under the running softmax: ``k``, ``v`` [K, Tk, D],
+        its keys' positions and validity [1, Tk]."""
         # [Tq, H, D] -> [K, Tq*G, D]: head h = k*G + g attends kv head k.
         q = q_ref[...].astype(jnp.float32) * scale
         q = q.reshape(Tq, K, G, D).swapaxes(0, 1).reshape(K, R, D)
-        k = k_ref[...].astype(jnp.float32).swapaxes(0, 1)  # [K, Tk, D]
-        v = v_ref[...].astype(jnp.float32).swapaxes(0, 1)
 
         # [K, R, D] x [K, Tk, D] -> [K, R, Tk] (batch over kv heads).
         s = jax.lax.dot_general(
@@ -209,12 +259,6 @@ def _flash_prefill_kernel(
         # row r = t*G + g is query token t = r // G.
         row_t = jax.lax.broadcasted_iota(jnp.int32, (R, 1), 0) // G
         q_pos = cached + i * Tq + row_t  # [R, 1]
-        flat = j * Tk + jax.lax.broadcasted_iota(jnp.int32, (1, Tk), 1)
-        is_prefix = flat < C
-        key_pos = jnp.where(is_prefix, flat, cached + flat - C)  # int select
-        key_valid = (is_prefix & (flat < cached)) | (
-            ~is_prefix & (flat - C < valid)
-        )
         mask = key_valid & (key_pos <= q_pos)  # [R, Tk]
         if sliding_window is not None:
             mask &= key_pos > q_pos - sliding_window
@@ -235,6 +279,69 @@ def _flash_prefill_kernel(
         m_ref[...] = jnp.broadcast_to(m_new, (K, R, LANES))
         l_ref[...] = jnp.broadcast_to(l_new, (K, R, LANES))
 
+    if NP:
+        sides = ((k_hbm, k_buf, 0), (v_hbm, v_buf, 1))
+
+        def start(tile, slot, unrolled: bool = True):
+            """Start the copies of prefix tile ``tile`` into ring slot
+            ``slot``: Cp pages a side, pages past the table its last."""
+            def page(c):
+                block = ids_ref[jnp.minimum(tile * Cp + c, P - 1)]
+                block = jax.lax.clamp(0, block, num_blocks - 1)
+                for hbm, buf, side in sides:
+                    pltpu.make_async_copy(
+                        hbm.at[block], buf.at[slot, c], sems.at[side, slot, c]
+                    ).start()
+
+            each(Cp, page, unrolled)
+
+        @pl.when((i == 0) & (j == 0))
+        def _():
+            walked_ref[0] = 0
+
+        @pl.when(live & (j < NP))
+        def _prefix():
+            walked = walked_ref[0]
+            slot = jax.lax.rem(walked, SLOTS)
+
+            # The walk's first tile starts itself; every other was started
+            # by the live prefix step before it.
+            @pl.when(walked == 0)
+            def _():
+                start(j, slot, unrolled=False)
+
+            # The next live prefix step: this query tile's next, or the
+            # next query tile's first.
+            _, _, _, p_hi, _, _ = ranges
+            q_next, prefix_next, p_lo_next, _, _, _ = rule(
+                jnp.minimum(i + 1, NQ - 1))
+            more = j < p_hi
+            ahead = more | ((i + 1 < NQ) & q_next & prefix_next)
+
+            @pl.when(ahead)
+            def _():
+                start(jnp.where(more, j + 1, p_lo_next), 1 - slot)
+
+            for c in range(Cp):
+                for hbm, buf, side in sides:
+                    # A wait counts the destination's bytes; its source is
+                    # only a shape.
+                    pltpu.make_async_copy(
+                        hbm.at[0], buf.at[slot, c], sems.at[side, slot, c]
+                    ).wait()
+            walked_ref[0] = walked + 1
+            pos = j * Tp + jax.lax.broadcasted_iota(jnp.int32, (1, Tp), 1)
+            # [Cp, bs, K, D] -> [Tp, K, D]: merging leading dims is free.
+            tile = lambda buf: heads_first(
+                buf[slot].reshape(Tp, *buf.shape[3:]))
+            fold(tile(k_buf), tile(v_buf), pos, pos < cached)
+
+    @pl.when(live & (j >= NP))
+    def _new():
+        t = (j - NP) * Tn + jax.lax.broadcasted_iota(jnp.int32, (1, Tn), 1)
+        fold(heads_first(kn_ref[...]), heads_first(vn_ref[...]),
+             cached + t, t < valid)
+
     @pl.when(j == NKV - 1)
     def _final():
         # A query tile wholly past valid_len visited no kv tile -> l == 0;
@@ -253,8 +360,9 @@ def flash_prefill_attention(
     q: jax.Array,  # [T, H, D]
     k_new: jax.Array,  # [T, K, D]
     v_new: jax.Array,  # [T, K, D]
-    k_prefix: jax.Array,  # [C, K, D] gathered cached prefix (may be C=0)
-    v_prefix: jax.Array,  # [C, K, D]
+    k_pool: Optional[jax.Array],  # [N, bs, K, D]: the layer's pages, read
+    v_pool: Optional[jax.Array],  # where they lie; unread behind no table
+    prefix_block_ids: jax.Array,  # [P] int32 (0-padded; P may be 0)
     cached_len: jax.Array,  # scalar int32
     valid_len: jax.Array,  # scalar int32
     *,
@@ -264,74 +372,87 @@ def flash_prefill_attention(
     kv_tile: int = KV_TILE,
     interpret: bool = False,
 ) -> jax.Array:
-    """Flash causal prefill attention with prefix (Pallas TPU)."""
+    """Flash causal prefill attention over the first ``cached_len`` positions
+    of the pages ``prefix_block_ids`` names and the chunk's own keys (Pallas
+    TPU)."""
     T, H, D = q.shape
     K = k_new.shape[1]
-    C = k_prefix.shape[0]
+    P = prefix_block_ids.shape[0]
+    if P == 0:
+        # No prefix steps in the grid (the encode lane): the walk's operands
+        # and its ring are there for the form alone, a row each.
+        k_pool = v_pool = jnp.zeros((1, 1, K, D), k_new.dtype)
+    N, bs = k_pool.shape[:2]
     if H % K:
         raise ValueError(f"H={H} not divisible by num_kv_heads={K}")
     G = H // K
     if D % 128 and not interpret:
         raise ValueError(f"flash prefill requires head_dim%128==0, got {D}")
 
-    Tq, Tk, NQ, NKV = _tiling(T, C, q_tile, kv_tile)
+    Tq, Cp, Tn, NP, NN = _tiling(T, P, bs, q_tile, kv_tile)
     if T % Tq:
         raise ValueError(f"T={T} not a multiple of q_tile={Tq}")
+    NQ, NKV = T // Tq, NP + NN
 
-    keys = jnp.concatenate([k_prefix, k_new], axis=0)  # [C+T, K, D]
-    values = jnp.concatenate([v_prefix, v_new], axis=0)
-    if NKV * Tk != C + T:
-        pad = [(0, NKV * Tk - (C + T)), (0, 0), (0, 0)]
-        keys = jnp.pad(keys, pad)  # padded keys are masked (j-C >= valid)
-        values = jnp.pad(values, pad)
-
+    tiles = dict(
+        Tq=Tq, Tp=Cp * bs, Tn=Tn, NP=NP, sliding_window=sliding_window)
     kernel = functools.partial(
         _flash_prefill_kernel,
-        Tq=Tq, Tk=Tk, C=C, NKV=NKV, K=K, G=G, D=D,
-        scale=scale, sliding_window=sliding_window,
+        Tq=Tq, Tn=Tn, P=P, NP=NP, NQ=NQ, NKV=NKV, K=K, G=G, D=D, scale=scale,
+        sliding_window=sliding_window,
     )
 
-    def kv_index(i, j, cached_ref, valid_ref):
-        return (
-            kv_block_index(
-                i, j, cached_ref[0], valid_ref[0],
-                Tq=Tq, Tk=Tk, C=C, sliding_window=sliding_window,
-            ), 0, 0,
-        )
+    def new_index(i, j, ids_ref, lens_ref):
+        return new_block_index(i, j, lens_ref[0], lens_ref[1], **tiles), 0, 0
 
     R = Tq * G
+    # One key head: a page goes in as [bs, D] (module docstring).
+    page = (bs, D) if K == 1 else (bs, K, D)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(NQ, NKV),
         in_specs=[
             pl.BlockSpec((Tq, H, D), lambda i, j, *_: (i, 0, 0)),
-            pl.BlockSpec((Tk, K, D), kv_index),
-            pl.BlockSpec((Tk, K, D), kv_index),
+            pl.BlockSpec((Tn, K, D), new_index),
+            pl.BlockSpec((Tn, K, D), new_index),
+            pl.BlockSpec(memory_space=pl.ANY),  # the pools stay in HBM
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((Tq, H, D), lambda i, j, *_: (i, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((K, R, LANES), jnp.float32),
             pltpu.VMEM((K, R, LANES), jnp.float32),
             pltpu.VMEM((K, R, D), jnp.float32),
+            pltpu.VMEM((SLOTS, Cp, *page), k_pool.dtype),
+            pltpu.VMEM((SLOTS, Cp, *page), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, SLOTS, Cp)),
+            pltpu.SMEM((1,), jnp.int32),
         ],
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, H, D), q.dtype),
-        # The fp32 score/prob intermediates ([K, R, Tk] each) plus the
-        # online-softmax scratch exceed the compiler's default 16 MB scoped
-        # VMEM at serving tile sizes; v5e/v6e have 128 MB, so raise the cap
-        # rather than shrink tiles below MXU-efficient shapes.
         compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=96 * 1024 * 1024
+            # The walk's order is the grid's: a prefix tile is started a
+            # live step before it is computed on.
+            dimension_semantics=("arbitrary", "arbitrary"),
+            disable_bounds_checks=True,
+            # The fp32 score/prob intermediates ([K, R, Tk] each) plus the
+            # online-softmax scratch exceed the compiler's default 16 MB
+            # scoped VMEM at serving tile sizes; v5e/v6e have 128 MB, so
+            # raise the cap rather than shrink tiles below MXU-efficient
+            # shapes.
+            vmem_limit_bytes=96 * 1024 * 1024,
         ),
         interpret=interpret,
         # What the device trace calls the kernel (%<name>.N on XLA Ops):
         # the benchmark's readers find it by this name.
         name="flash_prefill_attention",
     )(
-        jnp.asarray(cached_len, jnp.int32).reshape(1),
-        jnp.asarray(valid_len, jnp.int32).reshape(1),
-        q, keys, values,
+        # An empty table (the encode lane) still needs an SMEM word.
+        jnp.pad(prefix_block_ids.astype(jnp.int32), (0, int(P == 0))),
+        jnp.stack([jnp.asarray(cached_len, jnp.int32),
+                   jnp.asarray(valid_len, jnp.int32)]),
+        q, k_new, v_new, k_pool.reshape(N, *page), v_pool.reshape(N, *page),
     )

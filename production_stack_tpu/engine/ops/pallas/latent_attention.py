@@ -86,6 +86,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from production_stack_tpu.engine.ops.pallas import each
+
 NEG_INF = -1e30
 # Blocks a stage fetches and slots in the ring, settled on the chip at the
 # served shape, 16 rows x 24,000 positions (the microbenchmark's docstring
@@ -106,27 +108,10 @@ def _values(tile, latent_rank: int):
     return tile[:, :latent_rank]
 
 
-def _each(n: int, body, unrolled: bool):
-    """``body(c)`` for every copy ``c`` of a stage.  Unrolled, the copies'
-    scalar work is straight-line code the scheduler runs beside the stage's
-    dots; as a loop it is a fraction of the text to trace and lower at every
-    process start (the kernel's prologue and its end: once a call)."""
-    if unrolled:
-        for c in range(n):
-            body(c)
-        return
-
-    def step(c, carry):
-        body(c)
-        return carry
-
-    jax.lax.fori_loop(0, n, step, 0)
-
-
 def _wait_stage(cache_hbm, buf, sems, slot: int, unrolled: bool = True):
     """Wait for every copy of the stage fetched into ring slot ``slot``."""
     # A wait counts the destination's bytes; its source is only a shape.
-    _each(buf.shape[0], lambda c: pltpu.make_async_copy(
+    each(buf.shape[0], lambda c: pltpu.make_async_copy(
         cache_hbm.at[0], buf.at[c], sems.at[slot, c]).wait(), unrolled)
 
 
@@ -180,7 +165,7 @@ def _latent_decode_kernel(
                 cache_hbm.at[block], bufs[slot].at[c], sems.at[slot, c]
             ).start()
 
-        _each(C, start, unrolled)
+        each(C, start, unrolled)
         more = (stage + 1) * T < ctx
         return (jnp.where(more, s, jnp.where(inside, next_ref[r], S)),
                 jnp.where(more, stage + 1, 0))

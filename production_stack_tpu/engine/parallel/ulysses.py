@@ -30,7 +30,10 @@ from typing import Optional
 import jax
 from jax import lax
 
-from production_stack_tpu.engine.ops.attention import prefill_attention
+from production_stack_tpu.engine.ops.attention import (
+    prefill_attention,
+    prefix_as_pool,
+)
 
 
 def _seq_to_heads(x: jax.Array, axis_name: str) -> jax.Array:
@@ -75,9 +78,10 @@ def ulysses_prefill_with_prefix(
     vp_full = _seq_to_heads(v_prefix, axis_name)
 
     # Full-sequence attention on the local head subset; single-device
-    # dispatch applies (Pallas flash kernel on TPU, dense elsewhere).
+    # dispatch applies (Pallas flash kernel on TPU, dense elsewhere).  The
+    # redistributed prefix is a pool of its own: pages in order.
     out_full = prefill_attention(
-        q_full, k_full, v_full, kp_full, vp_full, cached_len, valid_len,
-        scale=scale, sliding_window=sliding_window,
+        q_full, k_full, v_full, *prefix_as_pool(kp_full, vp_full),
+        cached_len, valid_len, scale=scale, sliding_window=sliding_window,
     )
     return _heads_to_seq(out_full, axis_name)  # [Tl, H, D]

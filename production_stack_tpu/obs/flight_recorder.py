@@ -120,7 +120,13 @@ class WindowRecord:
     # computes / its grid holds for those chunks (the flash prefill kernel's
     # liveness rule, or the module's own -- the latent prefill kernel's
     # (query tile, key stage) pairs -- evaluated on the host; the rest is
-    # skipped).  ``cover``: a dedicated
+    # skipped; the flash kernel's grid is the chunk's block table, prefix
+    # tiles of whole pages, and the chunk's own keys).  ``prefix_pages``:
+    # pages of the cached prefix (a page's K and its V) the flash prefill
+    # kernel copies out of the pools, summed over its query tiles and the
+    # model's attention layers (every page of every live prefix tile: the
+    # same rule on the host; 0 where the dense form runs instead, under a
+    # mesh and off a TPU).  ``cover``: a dedicated
     # prefill's bucket and those of the chunks its prompt still has to run
     # (scheduler.cover_prefill): [256, 256, 256], [256, 256], [256] are
     # one 600-token prompt.
@@ -133,6 +139,7 @@ class WindowRecord:
     cached_tokens: int = 0
     kv_tiles_live: int = 0
     kv_tiles_grid: int = 0
+    prefix_pages: int = 0
     cover: Tuple[int, ...] = ()
     # What routing did in this dispatch, counted on the device by a model
     # that routes (models/sarvam_mla.py: ROUTING_STATS) and read back with
@@ -216,6 +223,7 @@ class WindowRecord:
             d["cached_tokens"] = self.cached_tokens
             d["kv_tiles_live"] = self.kv_tiles_live
             d["kv_tiles_grid"] = self.kv_tiles_grid
+            d["prefix_pages"] = self.prefix_pages
         if self.cover:
             d["cover"] = list(self.cover)
         if self.routing:
@@ -300,6 +308,7 @@ class FlightRecorder:
         cached_tokens: int = 0,
         kv_tiles_live: int = 0,
         kv_tiles_grid: int = 0,
+        prefix_pages: int = 0,
         cover: Tuple[int, ...] = (),
         state_rows: int = 0,
         state_resumed: Optional[bool] = None,
@@ -336,6 +345,7 @@ class FlightRecorder:
             cached_tokens=int(cached_tokens),
             kv_tiles_live=int(kv_tiles_live),
             kv_tiles_grid=int(kv_tiles_grid),
+            prefix_pages=int(prefix_pages),
             cover=tuple(cover),
             state_rows=int(state_rows),
             state_resumed=state_resumed,

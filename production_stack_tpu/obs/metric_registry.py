@@ -312,7 +312,7 @@ REGISTRY = {
                 "kernel's query-tile x key-stage pairs), per layer, "
                 "over dispatched prefill chunks (state: live — computed; "
                 "skipped — wholly masked, neither fetched nor computed: "
-                "gathered prefix slots past cached_len, new keys past "
+                "the block table's positions past cached_len, new keys past "
                 "valid_len, padded query tiles); host arithmetic from "
                 "each plan, no device read",
     },

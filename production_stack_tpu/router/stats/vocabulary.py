@@ -198,7 +198,7 @@ TPU_MULTISTEP_WASTED_TOKENS = "tpu:multistep_wasted_tokens_total"
 # The flash prefill kernel's kv tiles (ops/pallas/flash_prefill.py), per
 # layer, over dispatched prefill chunks: live — tiles with a score that
 # survives the mask, computed; skipped — tiles of the static grid (the
-# gathered max_model_len prefix slots past cached_len, new keys past
+# block table's max_model_len prefix positions past cached_len, new keys past
 # valid_len or the causal frontier or outside the sliding window, padded
 # query tiles) the kernel neither fetched nor computed.  Counted on the
 # host from each plan's (bucket, cached_len, new tokens).

@@ -18,6 +18,8 @@ one file for the same reason.
 
 import collections
 import functools
+import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -113,36 +115,90 @@ def test_decode_kernel_bf16_compiles(sds, no_persistent_cache, heads, S):
     )
 
 
-# The engine always gathers max_model_len of prefix slots, so C = MAX_LEN
-# is the shape every served prefill compiles; C = 0 is the embeddings path
-# (models/llama.py encode).  The grid is static, what it visits is not:
-# the kernel's fence and its kv index map (which reads the prefetched
-# cached_len / valid_len) skip every kv tile that holds no visible key --
-# prefix slots past cached_len, new keys past valid_len -- and every
-# query tile past valid_len (flash_prefill.py: live_kv_tiles).
-@pytest.mark.parametrize(
-    "heads,T,C",
-    [
-        (ONE_CHIP, 256, MAX_LEN),
-        (ONE_CHIP, 2048, MAX_LEN),
-        (ONE_CHIP, 2048, 0),
-        (TP4_SHARD, 2048, MAX_LEN),
-    ],
-    ids=["H32K8-T256-C8192", "H32K8-T2048-C8192", "H32K8-T2048-C0",
-         "tp4-H8K2-T2048-C8192"],
-)
-def test_flash_prefill_kernel_compiles(sds, no_persistent_cache, heads, T, C):
-    H, K = heads
+def _prefix_copies(text, elements):
+    """The ``gather`` / ``concatenate`` / ``pad`` instructions of a compiled
+    program whose result holds at least ``elements`` values of bf16: what a
+    gathered ``[C, K, D]`` prefix, its concatenation with the chunk's keys and
+    the pad to whole tiles were."""
+    found = []
+    for line in text.splitlines():
+        m = re.search(
+            r"= bf16\[([0-9,]+)\]\S* (gather|concatenate|pad)\(", line)
+        if m and math.prod(map(int, m.group(1).split(","))) >= elements:
+            found.append(line.strip()[:120])
+    return found
 
-    new, prefix = sds((T, K, D), jnp.bfloat16), sds((C, K, D), jnp.bfloat16)
-    _compile(
-        lambda q, k, v, kp, vp, cached, valid: flash_prefill_attention(
-            q, k, v, kp, vp, cached, valid,
-            scale=SCALE, sliding_window=WINDOW,
+
+def _flash(sds, H, K, hd, T, pool, P, window):
+    """``flash_prefill_attention`` compiled for T slots of H heads over K,
+    behind a table of P pages of the K/V pools ``pool`` ([N, bs]); behind an
+    empty table it is handed no pools."""
+    new = sds((T, K, hd), jnp.bfloat16)
+    pages = sds((*pool, K, hd), jnp.bfloat16) if P else None
+    return _compile(
+        lambda q, k, v, kp, vp, ids, cached, valid: flash_prefill_attention(
+            q, k, v, kp, vp, ids, cached, valid,
+            scale=hd ** -0.5, sliding_window=window,
         ),
-        sds((T, H, D), jnp.bfloat16), new, new, prefix, prefix,
-        sds((), jnp.int32), sds((), jnp.int32),
+        sds((T, H, hd), jnp.bfloat16), new, new, pages, pages,
+        sds((P,), jnp.int32), sds((), jnp.int32), sds((), jnp.int32),
     )
+
+
+# The engine always hands max_model_len of block ids, so P = MAX_LEN / BS
+# is the table every served prefill compiles; P = 0 is the embeddings path
+# (models/llama.py encode).  The grid is static, what it visits is not:
+# the kernel's fence, its walk over the table's pages and its new keys' index
+# map (which read the prefetched cached_len / valid_len) skip every kv tile
+# that holds no visible key -- prefix positions past cached_len, new keys
+# past valid_len -- and every query tile past valid_len (flash_prefill.py:
+# live_kv_tiles).  The pools stay in HBM: the kernel copies the pages itself.
+@pytest.mark.parametrize(
+    "heads,T,P",
+    [
+        (ONE_CHIP, 256, MAX_LEN // BS),
+        (ONE_CHIP, 2048, MAX_LEN // BS),
+        (ONE_CHIP, 2048, 0),
+        (TP4_SHARD, 2048, MAX_LEN // BS),
+    ],
+    ids=["H32K8-T256-P512", "H32K8-T2048-P512", "H32K8-T2048-P0",
+         "tp4-H8K2-T2048-P512"],
+)
+def test_flash_prefill_kernel_compiles(sds, no_persistent_cache, heads, T, P):
+    H, K = heads
+    text = _flash(sds, H, K, D, T, (1024, BS), P, WINDOW).as_text()
+    # Nothing of the prefix is copied beside the kernel: no gather of the
+    # table's pages, no concatenate with the chunk's keys, no pad.
+    assert not _prefix_copies(text, MAX_LEN * K * D)
+
+
+# A quantized (data, scale) cache keeps the kernel (ops/attention.py:
+# prefill_attention): its prefix is gathered and dequantized to bf16, as at
+# every commit before, and that copy is the kernel's pool, 16 pages of 512
+# positions in order -- nothing is concatenated or padded after it.  The
+# engine still refuses int8 KV on a TPU at boot, for the decode kernel's sake
+# (test_engine_refuses_int8_kv_on_tpu_at_boot); the day that lifts, prefill
+# is served.
+@pytest.mark.parametrize("T", [256, 2048], ids=["T256", "T2048"])
+def test_flash_prefill_serves_a_quantized_cache(
+        sds, no_persistent_cache, monkeypatch, T):
+    from production_stack_tpu.engine.ops.attention import prefill_attention
+
+    H, K = ONE_CHIP
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    new = sds((T, K, D), jnp.bfloat16)
+    cache = (sds((1024, BS, K, D), jnp.int8), sds((1024, BS, K), jnp.float32))
+    text = _compile(
+        lambda q, k, v, kc, vc, ids, cached, valid: prefill_attention(
+            q, k, v, kc, vc, ids, cached, valid,
+            scale=SCALE, sliding_window=WINDOW),
+        sds((T, H, D), jnp.bfloat16), new, new, cache, cache,
+        sds((MAX_LEN // BS,), jnp.int32), sds((), jnp.int32),
+        sds((), jnp.int32),
+    ).as_text()
+    assert text.count("flash_prefill_attention")
+    copies = _prefix_copies(text, MAX_LEN * K * D)
+    assert not [line for line in copies if " gather(" not in line], copies
 
 
 # The latent (MLA) decode kernel at sarvam-105b's published widths: 64 heads,
@@ -277,15 +333,7 @@ def test_the_dense_kernels_compile_at_solars_geometry(sds, no_persistent_cache):
         sds((16, H, hd), jnp.bfloat16), cache, cache,
         sds((16, cfg.max_model_len // BS), jnp.int32), sds((16,), jnp.int32),
     )
-    new = sds((256, K, hd), jnp.bfloat16)
-    prefix = sds((cfg.max_model_len, K, hd), jnp.bfloat16)
-    _compile(
-        lambda q, k, v, kp, vp, cached, valid: flash_prefill_attention(
-            q, k, v, kp, vp, cached, valid, scale=hd ** -0.5,
-            sliding_window=None),
-        sds((256, H, hd), jnp.bfloat16), new, new, prefix, prefix,
-        sds((), jnp.int32), sds((), jnp.int32),
-    )
+    _flash(sds, H, K, hd, 256, (20000, BS), cfg.max_model_len // BS, None)
 
 
 # jamba2-3b (models/jamba.py): the two state-space kernels at the published
@@ -367,23 +415,16 @@ def test_the_dense_kernels_compile_at_jambas_geometry(sds, no_persistent_cache):
                 if "tpu_custom_call" in line and "custom-call(" in line)
     assert f"s32[16,{bmax // R}]" in call
     assert f"s32[16,{bmax // (R * CHUNK_BLOCKS)}]" in call
+    # The prefill kernel walks the same pool page by page (4 kB a copy).
     for T in (256, 2048):
-        new = sds((T, K, hd), jnp.bfloat16)
-        prefix = sds((cfg.max_model_len, K, hd), jnp.bfloat16)
-        _compile(
-            lambda q, k, v, kp, vp, cached, valid: flash_prefill_attention(
-                q, k, v, kp, vp, cached, valid, scale=hd ** -0.5,
-                sliding_window=None),
-            sds((T, H, hd), jnp.bfloat16), new, new, prefix, prefix,
-            sds((), jnp.int32), sds((), jnp.int32),
-        )
+        _flash(sds, H, K, hd, T, (500000, BS), bmax, None)
 
 
 # laguna-xs.2-ep2 (models/laguna.py): the two dense kernels at its full
 # layers' geometry, 48 query heads over 8 key heads -- 6 a key head, no power
 # of two and no multiple of the sublane tile -- and at its window layers' (64
 # over 8, the cell's 59 slots of 512 rows read as 32 pages of 16 each, under
-# the call's own name; the flash kernel behind a prefix of one window).
+# the call's own name; the flash kernel behind one window's buffer).
 def test_the_dense_kernels_compile_at_lagunas_geometries(
         sds, no_persistent_cache):
     from production_stack_tpu.engine.models.laguna import WINDOW_DECODE_KERNEL
@@ -409,17 +450,13 @@ def test_the_dense_kernels_compile_at_lagunas_geometries(
         sds((16, 512 // BS), jnp.int32), sds((16,), jnp.int32),
     ).as_text()
     assert WINDOW_DECODE_KERNEL in text
+    # The flash kernel behind the block pool's pages, and behind a window
+    # layer's buffer: a pool of one 512-token page with the table [0].
     for T in (256, 2048):
-        new = sds((T, K, hd), jnp.bfloat16)
-        for spec, C in ((full, cfg.max_model_len), (window, window.window)):
-            prefix = sds((C, K, hd), jnp.bfloat16)
-            _compile(
-                lambda q, k, v, kp, vp, cached, valid: flash_prefill_attention(
-                    q, k, v, kp, vp, cached, valid, scale=hd ** -0.5,
-                    sliding_window=spec.window),
-                sds((T, spec.num_heads, hd), jnp.bfloat16), new, new, prefix,
-                prefix, sds((), jnp.int32), sds((), jnp.int32),
-            )
+        _flash(sds, full.num_heads, K, hd, T, (58_768, BS),
+               cfg.max_model_len // BS, full.window)
+        _flash(sds, window.num_heads, K, hd, T, (1, window.window), 1,
+               window.window)
 
 
 # A module that owns a state pool reads and writes slots where they lie
@@ -512,6 +549,12 @@ def test_a_state_models_served_programs_copy_no_pool(
             state_slots=i32(S))
     text = lowered.compile().as_text()
     assert "tpu_custom_call" in text
+    if program == "prefill":
+        # Nor a copy of the cached prefix: the kernel walks the pages of the
+        # block table itself (PR 62; before, each layer that keeps pages
+        # gathered, concatenated and padded max_model_len positions a side).
+        assert not _prefix_copies(
+            text, cfg.max_model_len * cfg.num_kv_heads * cfg.head_dim)
     # The counter is the microbenchmark's (it counts the program that ran).
     from tools.state_pool_microbench import pool_copies
 
@@ -531,6 +574,49 @@ def test_a_state_models_served_programs_copy_no_pool(
     assert not {k: n for k, n in found.items() if k[0] == "copy"}
     for (_, name), n in found.items():
         assert n <= staged[program].get(name, 0), (name, n)
+
+
+# mistral-7b with int8 weights (cells 1-2): the packed ``prefill_fn`` as the
+# engine builds it, 256 slots behind a table of 8,192 positions, the cache tree
+# donated.  Its 32 layers hold 32 flash kernels and nothing that copies a
+# prefix: the parent of PR 62 compiled to 64 gathers of bf16[512,16,8,128], as
+# many concatenates to [8448,8,128] and pads to [8704,8,128] -- 5.6 ms of a 27.8 ms
+# program, whatever was cached.
+def test_mistrals_served_prefill_copies_no_prefix(
+        one_chip, no_persistent_cache, monkeypatch):
+    import dataclasses
+
+    from production_stack_tpu.engine.core import step_programs
+    from production_stack_tpu.engine.models import llama
+
+    cfg = dataclasses.replace(MISTRAL, quantization="int8")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    on_chip = lambda tree: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    params = on_chip(jax.eval_shape(lambda: llama.quantize_params(
+        llama.init_params(cfg, jax.random.PRNGKey(0)), cfg)))
+    pool = jax.ShapeDtypeStruct(
+        (3_000, BS, cfg.num_kv_heads, D), jnp.bfloat16, sharding=one_chip)
+    T, bmax, scalars = 256, MAX_LEN // BS, ("cached_len", "valid_len")
+    text = jax.jit(step_programs.prefill_program(
+        functools.partial(llama.prefill, cfg=cfg), scalars, BS, bmax),
+        donate_argnames=("kv_caches",), static_argnames=("prompt_topk",),
+    ).lower(
+        params, jax.ShapeDtypeStruct(
+            (T + T // BS + bmax + len(scalars),), jnp.int32,
+            sharding=one_chip),
+        kv_caches=[(pool, pool)] * cfg.num_layers,
+    ).compile().as_text()
+    assert text.count("flash_prefill_attention") >= cfg.num_layers
+    assert not _prefix_copies(text, MAX_LEN * cfg.num_kv_heads * D)
+    # What the check is made of: it finds the gather where there is one.
+    from production_stack_tpu.engine.ops.attention import gather_prefix_kv
+
+    gathered = jax.jit(gather_prefix_kv).lower(
+        pool, pool, jax.ShapeDtypeStruct((bmax,), jnp.int32, sharding=one_chip)
+    ).compile().as_text()
+    assert len(_prefix_copies(gathered, MAX_LEN * cfg.num_kv_heads * D)) == 2
 
 
 # longcat-flash-omni-ep32 (models/longcat.py): the programs the engine serves

@@ -5,6 +5,7 @@ compiled path is exercised on real TPU by tools/paged_decode_microbench.py
 and by the engine on TPU backends (ops/attention.py:decode_attention dispatch).
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -463,59 +464,88 @@ def test_the_engine_counts_groups_by_the_kernels_rule():
 
 # -- flash prefill kernel ---------------------------------------------------
 
-from production_stack_tpu.engine.ops.attention import prefill_attention
+from production_stack_tpu.engine.ops.attention import (
+    dense_prefill_attention,
+    gather_prefix_kv,
+)
 from production_stack_tpu.engine.ops.pallas import flash_prefill as fp
 from production_stack_tpu.engine.ops.pallas.flash_prefill import (
     flash_prefill_attention,
 )
 
 
-def _prefill_case(seed, T, H, K, D, C, dtype=jnp.float32):
+def _prefill_case(seed, T, H, K, D, P, bs=16, dtype=jnp.float32):
+    """Queries, the chunk's keys and values, K and V pools of ``2 P + 1``
+    pages of ``bs`` positions, and a table of ``P`` of them in no order
+    (never the null block 0, which holds NaNs here: nothing may read it)."""
     rng = np.random.default_rng(seed)
+    N = 2 * P + 1
     q = jnp.asarray(rng.standard_normal((T, H, D)), dtype)
     k_new = jnp.asarray(rng.standard_normal((T, K, D)), dtype)
     v_new = jnp.asarray(rng.standard_normal((T, K, D)), dtype)
-    k_prefix = jnp.asarray(rng.standard_normal((C, K, D)), dtype)
-    v_prefix = jnp.asarray(rng.standard_normal((C, K, D)), dtype)
-    return q, k_new, v_new, k_prefix, v_prefix
+    k_pool = rng.standard_normal((N, bs, K, D))
+    v_pool = rng.standard_normal((N, bs, K, D))
+    k_pool[0] = v_pool[0] = np.nan
+    ids = jnp.asarray(rng.permutation(N - 1)[:P] + 1, jnp.int32)
+    return (q, k_new, v_new, jnp.asarray(k_pool, dtype),
+            jnp.asarray(v_pool, dtype), ids)
 
 
+def _dense(q, k_new, v_new, k_pool, v_pool, ids, cached, valid, **kw):
+    """ops/attention.py's dense statement over the gathered table."""
+    k_prefix, v_prefix = gather_prefix_kv(k_pool, v_pool, ids)
+    return dense_prefill_attention(
+        q, k_new, v_new, k_prefix, v_prefix, jnp.int32(cached),
+        jnp.int32(valid), **kw)
+
+
+# bs 16 and kv tiles of 64: a prefix tile is four pages.
 @pytest.mark.parametrize(
-    "T,H,K,D,C,cached,valid,window",
+    "T,H,K,D,P,cached,valid,window",
     [
-        (64, 4, 2, 32, 0, 0, 64, None),      # no prefix, full tile
-        (64, 4, 2, 32, 32, 20, 50, None),    # prefix hit + padded tail
+        (64, 4, 2, 32, 0, 0, 64, None),      # an empty table (the encode lane)
+        (64, 4, 2, 32, 2, 20, 50, None),     # prefix hit + padded tail
         (128, 8, 8, 32, 0, 0, 128, None),    # MHA (G=1)
-        (64, 6, 2, 32, 16, 16, 64, None),    # G=3 (llama-3.2-3b shape)
-        (64, 4, 2, 32, 32, 32, 64, 24),      # sliding window
-        (512, 4, 2, 32, 64, 48, 500, None),  # multi q-tile + multi kv-tile
-        # The served pattern, small: the engine gathers max_model_len
-        # prefix slots whatever cached_len is, and pads T to a bucket.
-        (64, 4, 2, 32, 512, 0, 64, None),     # C >> T, nothing cached
-        (64, 4, 2, 32, 256, 100, 64, None),   # cached_len inside a kv tile
-        (64, 4, 2, 32, 256, 128, 64, None),   # cached_len on a tile edge
-        (64, 4, 2, 32, 256, 256, 40, None),   # cached_len == C
-        (128, 4, 2, 32, 128, 70, 10, None),   # valid_len < Tq: a dead q tile
-        (128, 4, 2, 32, 256, 0, 128, None),   # valid_len == T, dead prefix
-        (128, 4, 2, 32, 200, 150, 100, None),  # C not a multiple of kv_tile
-        (64, 4, 2, 32, 256, 256, 64, 100),    # window cuts inside the prefix
-        (128, 4, 2, 32, 320, 200, 128, 150),  # window + dead prefix tiles
-        (128, 4, 2, 32, 64, 64, 128, 40),     # window cuts inside new keys
-        (64, 4, 2, 32, 128, 64, 0, None),     # valid_len 0: nothing live
+        (64, 6, 2, 32, 1, 16, 64, None),     # G=3 (llama-3.2-3b shape)
+        (64, 4, 2, 32, 2, 32, 64, 24),       # sliding window
+        (512, 4, 2, 32, 4, 48, 500, None),   # multi q-tile + multi kv-tile
+        # The served pattern, small: the engine hands max_model_len of
+        # block ids whatever cached_len is, and pads T to a bucket.
+        (64, 4, 2, 32, 32, 0, 64, None),      # P*bs >> T, nothing cached
+        (64, 4, 2, 32, 16, 100, 64, None),    # cached_len inside a kv tile
+        (64, 4, 2, 32, 16, 128, 64, None),    # cached_len on a tile edge
+        (64, 4, 2, 32, 16, 256, 40, None),    # cached_len == P*bs
+        (128, 4, 2, 32, 8, 70, 10, None),     # valid_len < Tq: a dead q tile
+        (128, 4, 2, 32, 16, 0, 128, None),    # valid_len == T, dead prefix
+        (128, 4, 2, 32, 13, 150, 100, None),  # P no multiple of a tile's pages
+        (64, 4, 2, 32, 16, 256, 64, 100),     # window cuts inside the prefix
+        (128, 4, 2, 32, 20, 200, 128, 150),   # window + dead prefix tiles
+        (128, 4, 2, 32, 4, 64, 128, 40),      # window cuts inside new keys
+        (64, 4, 2, 32, 8, 64, 0, None),       # valid_len 0: nothing live
+        # cached_len: one position, a block less one, one tile, several
+        # tiles with a ragged end.
+        (64, 4, 2, 32, 16, 1, 64, None),
+        (64, 4, 2, 32, 16, 15, 64, None),
+        (64, 4, 2, 32, 16, 64, 50, None),
+        (128, 4, 2, 32, 16, 203, 100, None),
+        # a window longer than the prefix, and shorter than a block
+        (128, 4, 2, 32, 16, 203, 100, 4096),
+        (128, 4, 2, 32, 16, 203, 100, 7),
+        # query heads a key head: 4, 6 (laguna), 20 over one (jamba)
+        (64, 16, 4, 32, 8, 100, 60, None),
+        (64, 12, 2, 32, 8, 100, 60, None),
+        (64, 20, 1, 32, 8, 100, 60, None),
+        (128, 20, 1, 32, 16, 250, 128, 90),
     ],
 )
-def test_flash_prefill_matches_dense(T, H, K, D, C, cached, valid, window):
-    q, k_new, v_new, k_prefix, v_prefix = _prefill_case(3, T, H, K, D, C)
-    scale = D**-0.5
-    cached_len = jnp.int32(cached)
-    valid_len = jnp.int32(valid)
-    want = prefill_attention(
-        q, k_new, v_new, k_prefix, v_prefix, cached_len, valid_len,
-        scale=scale, sliding_window=window,
-    )
+def test_flash_prefill_matches_dense(T, H, K, D, P, cached, valid, window):
+    """The kernel walking the pool's pages through a table in no order,
+    against the dense statement over the gathered copy."""
+    case = _prefill_case(3, T, H, K, D, P)
+    kw = dict(scale=D**-0.5, sliding_window=window)
+    want = _dense(*case, cached, valid, **kw)
     got = flash_prefill_attention(
-        q, k_new, v_new, k_prefix, v_prefix, cached_len, valid_len,
-        scale=scale, sliding_window=window,
+        *case, jnp.int32(cached), jnp.int32(valid), **kw,
         q_tile=64, kv_tile=64, interpret=True,
     )
     # Rows past valid_len are padding garbage on both paths; compare live.
@@ -526,10 +556,91 @@ def test_flash_prefill_matches_dense(T, H, K, D, C, cached, valid, window):
     assert np.all(np.isfinite(np.asarray(got)))
 
 
-def _brute_force_live_tiles(T, C, cached, valid, window, Tq, Tk):
-    """[query tiles, kv tiles] bool: does any score of the tile survive
-    the dense path's mask (ops/attention.py: prefill_attention) on a row
-    below valid_len?  Rows past it are padding nobody reads."""
+@pytest.mark.parametrize("cached,valid", [(512, 200), (300, 256), (0, 256)])
+def test_flash_prefill_takes_a_pool_of_one_window_page(cached, valid):
+    """``models/laguna.py``'s window layers: the slot's 512-row rolling
+    buffer, oldest first, is a pool of one 512-token page with the table
+    [0], under a window of as many positions."""
+    T, H, K, D, W = 256, 8, 2, 32, 512
+    rng = np.random.default_rng(11)
+    q, k_new, v_new = (jnp.asarray(rng.standard_normal((T, h, D)), jnp.float32)
+                       for h in (H, K, K))
+    k_buf, v_buf = (jnp.asarray(rng.standard_normal((W, K, D)), jnp.float32)
+                    for _ in range(2))
+    kw = dict(scale=D**-0.5, sliding_window=W)
+    want = dense_prefill_attention(
+        q, k_new, v_new, k_buf, v_buf, jnp.int32(cached), jnp.int32(valid),
+        **kw)
+    got = flash_prefill_attention(
+        q, k_new, v_new, k_buf[None], v_buf[None], jnp.zeros((1,), jnp.int32),
+        jnp.int32(cached), jnp.int32(valid), **kw, interpret=True)
+    np.testing.assert_allclose(
+        np.asarray(got)[:valid], np.asarray(want)[:valid], rtol=2e-5,
+        atol=2e-5)
+    assert fp._tiling(T, 1, W, fp.Q_TILE, fp.KV_TILE) == (128, 1, 256, 1, 1)
+
+
+def test_prefill_attention_gathers_where_the_kernel_cannot_serve(monkeypatch):
+    """``ops/attention.py: prefill_attention`` picks by what it sees at trace
+    time: on a single TPU device the kernel, over the pools as they lie or,
+    for a quantized (data, scale) cache, over the dequantized copy of the
+    prefix as a pool of its own; the dense statement over the gathered
+    prefix under a mesh and off a TPU -- and they agree."""
+    import production_stack_tpu.engine.ops.attention as attn_ops
+    from production_stack_tpu.engine.kv import quant as kv_quant
+
+    T, H, K, D, P = 64, 4, 2, 128, 8
+    q, k_new, v_new, k_pool, v_pool, ids = _prefill_case(7, T, H, K, D, P)
+    # A served pool's null block holds finite rows (padded slots' writes).
+    k_pool, v_pool = k_pool.at[0].set(0.0), v_pool.at[0].set(0.0)
+    quantized = tuple(kv_quant.quantize_vectors(pool)
+                      for pool in (k_pool, v_pool))
+    args = (ids, jnp.int32(70), jnp.int32(50))
+    kw = dict(scale=D**-0.5, sliding_window=40)
+    calls = []
+
+    def kernel(*a, **k):
+        calls.append(a)
+        return flash_prefill_attention(
+            *a, **k, q_tile=32, kv_tile=32, interpret=True)
+
+    def close(got, want):
+        np.testing.assert_allclose(
+            np.asarray(got)[:50], np.asarray(want)[:50], rtol=2e-5, atol=2e-5)
+
+    monkeypatch.setattr(fp, "flash_prefill_attention", kernel)
+    dense = attn_ops.prefill_attention(q, k_new, v_new, k_pool, v_pool, *args, **kw)
+    dense_q = attn_ops.prefill_attention(q, k_new, v_new, *quantized, *args, **kw)
+    assert not calls  # off a TPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    close(attn_ops.prefill_attention(
+        q, k_new, v_new, k_pool, v_pool, *args, **kw), dense)
+    assert len(calls) == 1 and calls[0][3] is k_pool  # the pool as it lies
+    # The quantized cache's prefix: gathered and dequantized, its P * bs
+    # positions one page of a pool of its own, in order.
+    close(attn_ops.prefill_attention(
+        q, k_new, v_new, *quantized, *args, **kw), dense_q)
+    assert len(calls) == 2 and calls[1][3].shape == (1, P * 16, K, D)
+    np.testing.assert_array_equal(calls[1][5], [0])
+    # No cache behind an empty table (the encode lane).
+    none = (None, None, ids[:0], jnp.int32(0), jnp.int32(50))
+    close(attn_ops.prefill_attention(q, k_new, v_new, *none, **kw),
+          dense_prefill_attention(
+              q, k_new, v_new, k_new[:0], v_new[:0], *none[3:], **kw))
+    assert len(calls) == 3
+    mesh = jax.sharding.Mesh(np.array(jax.devices()[:2]), ("tp",))
+    attn_ops.prefill_attention(
+        q, k_new, v_new, k_pool, v_pool, *args, **kw, mesh=mesh)
+    assert len(calls) == 3
+
+
+def _brute_force_live_tiles(T, P, bs, cached, valid, window, Tq, Tp, Tn):
+    """[query tiles, grid steps] bool: does any score of the tile survive
+    the dense path's mask (ops/attention.py: dense_prefill_attention) on a
+    row below valid_len?  Rows past it are padding nobody reads.  The grid's
+    steps: the table's positions in tiles of ``Tp``, then the chunk's keys in
+    tiles of ``Tn``."""
+    C = P * bs
     key_pos = np.concatenate([np.arange(C), cached + np.arange(T)])
     key_valid = np.concatenate([np.arange(C) < cached, np.arange(T) < valid])
     q_pos = cached + np.arange(T)
@@ -537,66 +648,105 @@ def _brute_force_live_tiles(T, C, cached, valid, window, Tq, Tk):
     if window is not None:
         mask &= key_pos[None, :] > q_pos[:, None] - window
     mask &= (np.arange(T) < valid)[:, None]
-    nkv = -(-(C + T) // Tk)
-    mask = np.pad(mask, [(0, 0), (0, nkv * Tk - (C + T))])
-    return mask.reshape(T // Tq, Tq, nkv, Tk).any(axis=(1, 3))
+    NP = -(-C // Tp) if C else 0
+    prefix = np.pad(mask[:, :C], [(0, 0), (0, NP * Tp - C)])
+    return np.concatenate([
+        prefix.reshape(T // Tq, Tq, NP, Tp).any(axis=(1, 3)),
+        mask[:, C:].reshape(T // Tq, Tq, T // Tn, Tn).any(axis=(1, 3)),
+    ], axis=1)
 
 
 @pytest.mark.parametrize(
-    "T,C,q_tile,kv_tile",
+    "T,P,bs,q_tile,kv_tile",
     [
-        (64, 0, 16, 32),      # the encode lane: no prefix
-        (64, 128, 16, 32),    # C a multiple of the kv tile
-        (64, 100, 16, 32),    # a tile straddles prefix and new keys
-        (32, 96, 32, 48),     # one query tile
-        (256, 8192, 128, 512),   # the served buckets behind the 8,192
-        (2048, 8192, 128, 512),  # gathered prefix slots
+        (64, 0, 16, 16, 32),      # the encode lane: no prefix
+        (64, 8, 16, 16, 32),      # the table a whole number of tiles
+        (64, 7, 16, 16, 32),      # the last prefix tile holds one page
+        (32, 12, 8, 32, 48),      # one query tile
+        (256, 512, 16, 128, 512),   # the served buckets behind the 8,192
+        (2048, 512, 16, 128, 512),  # positions of a block table
+        (64, 1, 24, 16, 32),      # a pool of one page (a window's buffer)
     ],
 )
-def test_liveness_rule_matches_the_mask_tile_by_tile(T, C, q_tile, kv_tile):
-    """The one rule behind the compute fence, the kv index map and the
-    host's counter, against brute force over (cached_len, valid_len,
-    window); and the index map fetches each live tile once and nothing
-    else."""
-    Tq, Tk, NQ, NKV = fp._tiling(T, C, q_tile, kv_tile)
-    i, j = np.arange(NQ)[:, None], np.arange(NKV)[None, :]
+def test_liveness_rule_matches_the_mask_tile_by_tile(T, P, bs, q_tile, kv_tile):
+    """The one rule behind the compute fence, the walk's lookahead, the new
+    keys' index map and the host's counters, against brute force over
+    (cached_len, valid_len, window); and the index map fetches each live
+    new-key tile once and nothing else."""
+    Tq, Cp, Tn, NP, NN = fp._tiling(T, P, bs, q_tile, kv_tile)
+    Tp, NQ, C = Cp * bs, T // Tq, P * bs
+    i, j = np.arange(NQ)[:, None], np.arange(NP + NN)[None, :]
     small = C + T <= 1024
-    for cached in sorted({0, 1, C // 3, Tk, C - 1, C} & set(range(C + 1))):
+    for cached in sorted({0, 1, bs - 1, C // 3, Tp, C - 1, C} & set(range(C + 1))):
         for valid in sorted({0, 1, Tq - 1, Tq, T // 2 + 3, T}):
             for window in (None, 1, Tq + 5, C // 2 + 7, 4096):
                 if window == 4096 and small:
                     continue
-                kw = dict(Tq=Tq, Tk=Tk, C=C, sliding_window=window, xp=np)
+                kw = dict(Tq=Tq, Tp=Tp, Tn=Tn, NP=NP, sliding_window=window,
+                          xp=np)
                 what = f"cached={cached} valid={valid} window={window}"
                 want = _brute_force_live_tiles(
-                    T, C, cached, valid, window, Tq, Tk)
+                    T, P, bs, cached, valid, window, Tq, Tp, Tn)
                 got = fp._tile_is_live(
                     j, fp.live_kv_tiles(i, cached, valid, **kw))
                 np.testing.assert_array_equal(got, want, err_msg=what)
+                # kv_tiles_live, kv_tiles_grid and prefix_pages: every page
+                # of every live prefix tile is fetched.
                 assert fp.count_kv_tiles(
-                    T, C, cached, valid, window,
+                    T, P, bs, cached, valid, window,
                     q_tile=q_tile, kv_tile=kv_tile,
-                ) == (want.sum(), NQ * NKV), what
-                # A live step holds its own tile, a dead step of a live
-                # query tile one of that query tile's live tiles, a dead
-                # query tile whatever the step before it held: walking
-                # the grid in order fetches no more blocks than are live.
-                idx = fp.kv_block_index(i, j, cached, valid, **kw)
+                ) == (want.sum(), NQ * (NP + NN), want[:, :NP].sum() * Cp), what
+                # A live new-key step holds its own tile, any other step of
+                # a live query tile one of that query tile's live new-key
+                # tiles, a dead query tile whatever the step before it held:
+                # walking the grid in order fetches no more blocks than are
+                # live.
+                idx = fp.new_block_index(i, j, cached, valid, **kw)
+                new = want[:, NP:]
                 np.testing.assert_array_equal(
-                    idx[want], np.broadcast_to(j, idx.shape)[want], what)
-                assert idx.min() >= 0 and idx.max() < NKV, what
+                    idx[:, NP:][new],
+                    np.broadcast_to(j[:, NP:] - NP, new.shape)[new], what)
+                assert idx.min() >= 0 and idx.max() < NN, what
                 q_live = want.any(axis=1)
-                assert np.take_along_axis(want, idx, 1)[q_live].all(), what
+                assert np.take_along_axis(new, idx, 1)[q_live].all(), what
                 flat = idx.ravel()
                 moved = np.flatnonzero(flat[1:] != flat[:-1]) + 1
-                assert q_live[moved // NKV].all(), what
-                assert len(moved) + 1 <= max(want.sum(), 1), what
+                assert q_live[moved // (NP + NN)].all(), what
+                assert len(moved) + 1 <= max(new.sum(), 1), what
 
 
-def test_flight_record_counts_the_tiles_the_kernel_visits():
-    """``kv_tiles_live`` / ``kv_tiles_grid`` on a prefill's flight record
-    are the rule's count for that plan, at the kernel's own tile sizes,
-    and /metrics' counter is their sum."""
+@pytest.mark.parametrize("preset, kind, bucket", [
+    ("mistral-7b", "window", 256), ("mistral-7b", "window", 2048),
+    ("jamba2-3b", "full", 256), ("laguna-xs.2-ep2", "full", 256),
+    ("laguna-xs.2-ep2", "window", 2048),
+])
+def test_engine_counts_pages_where_the_kernel_is_the_path(
+        monkeypatch, preset, kind, bucket):
+    """``LLMEngine._flash_prefill_serves`` is ``prefill_attention``'s own
+    selector, by the heads of the layer's kind: a single TPU device, and
+    neither a mesh nor another backend."""
+    import types
+
+    from production_stack_tpu.engine.config import PRESETS
+    from production_stack_tpu.engine.core.engine import LLMEngine
+
+    boot = types.SimpleNamespace(
+        config=types.SimpleNamespace(model=PRESETS[preset]),
+        mesh=types.SimpleNamespace(size=1))
+    assert not LLMEngine._flash_prefill_serves(boot, kind, bucket)  # a CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert LLMEngine._flash_prefill_serves(boot, kind, bucket)
+    boot.mesh.size = 4
+    assert not LLMEngine._flash_prefill_serves(boot, kind, bucket)
+
+
+def test_flight_record_counts_the_tiles_the_kernel_visits(monkeypatch):
+    """``kv_tiles_live`` / ``kv_tiles_grid`` / ``prefix_pages`` on a
+    prefill's flight record are the rule's count for that plan, at the
+    kernel's own tile sizes over the plan's block table, and /metrics'
+    counter is the tiles' sum.  Pages are counted where the kernel is the
+    path taken (steered here, as a test steers code that asks JAX for its
+    backend), and none where the dense form ran."""
     from production_stack_tpu.engine.config import config_from_preset
     from production_stack_tpu.engine.core.engine import LLMEngine
     from production_stack_tpu.engine.core.sequence import SamplingParams
@@ -609,26 +759,47 @@ def test_flight_record_counts_the_tiles_the_kernel_visits():
     )
     eng = LLMEngine(config)
     shared = list(range(3, 35))  # two whole 16-token blocks
-    for rid, ids in (("a", shared + [40, 41, 42]), ("b", shared + [50])):
+
+    def serve(rid, ids):
         eng.add_request(rid, prompt_token_ids=ids,
                         sampling_params=SamplingParams(
                             max_tokens=2, ignore_eos=True))
         while eng.has_unfinished():
             eng.step()
-    C = config.scheduler.max_model_len
+
+    served = lambda: [  # noqa: E731
+        w for w in eng.obs.windows_payload()["windows"][::-1]  # oldest first
+        if w.get("bucket_tokens")]
+    serve("dense", shared + [60, 61])
+    serve("dense-behind", shared + [62])
+    assert {w["cached_tokens"] for w in served()} >= {0, 32}
+    assert not any(w["prefix_pages"] for w in served())  # off a TPU
+    dense = len(served())
+    monkeypatch.setattr(
+        LLMEngine, "_flash_prefill_serves", lambda self, kind, T: True)
+    for rid, ids in (("a", shared[:16] + [40, 41, 42] + shared[16:]),
+                     ("b", shared[:16] + [40, 41, 42] + shared[16:] + [50])):
+        serve(rid, ids)
+    bs = config.cache.block_size
+    P = config.scheduler.max_model_len // bs
     window = config.model.sliding_window
-    prefills = [w for w in eng.obs.windows_payload()["windows"]
-                if w.get("bucket_tokens")]
-    assert {w["cached_tokens"] for w in prefills} >= {0, 32}
+    prefills = served()
+    assert {w["cached_tokens"] for w in prefills[dense:]} >= {16, 32}
     live = grid = 0
-    for w in prefills:
+    for n, w in enumerate(prefills):
         T = w["bucket_tokens"]
-        Tq, Tk, NQ, NKV = fp._tiling(T, C, fp.Q_TILE, fp.KV_TILE)
+        Tq, Cp, Tn, NP, NN = fp._tiling(T, P, bs, fp.Q_TILE, fp.KV_TILE)
         want = _brute_force_live_tiles(
-            T, C, w["cached_tokens"], w["new_tokens"], window, Tq, Tk)
+            T, P, bs, w["cached_tokens"], w["new_tokens"], window, Tq,
+            Cp * bs, Tn)
         assert (w["kv_tiles_live"], w["kv_tiles_grid"]) == (
-            want.sum(), NQ * NKV)
+            want.sum(), (T // Tq) * (NP + NN))
         assert 0 < w["kv_tiles_live"] < w["kv_tiles_grid"]
+        # Every page of a live prefix tile, in each of the model's layers.
+        assert w["prefix_pages"] == (n >= dense) * (
+            want[:, :NP].sum() * Cp * config.model.num_layers)
+        assert (w["prefix_pages"] > 0) == (
+            n >= dense and w["cached_tokens"] > 0)
         live += w["kv_tiles_live"]
         grid += w["kv_tiles_grid"]
     assert eng.stats()["prefill_attn_tiles"] == {
@@ -639,16 +810,16 @@ def test_flash_prefill_causality():
     """Future tokens must not leak: perturbing token t+1 cannot change
     output row t."""
     T, H, K, D = 64, 4, 2, 32
-    q, k_new, v_new, k_prefix, v_prefix = _prefill_case(5, T, H, K, D, 0)
+    q, k_new, v_new, k_pool, v_pool, ids = _prefill_case(5, T, H, K, D, 0)
     scale = D**-0.5
     base = flash_prefill_attention(
-        q, k_new, v_new, k_prefix, v_prefix, jnp.int32(0), jnp.int32(T),
+        q, k_new, v_new, k_pool, v_pool, ids, jnp.int32(0), jnp.int32(T),
         scale=scale, q_tile=32, kv_tile=32, interpret=True,
     )
     k_mut = k_new.at[40].add(100.0)
     v_mut = v_new.at[40].add(100.0)
     mut = flash_prefill_attention(
-        q, k_mut, v_mut, k_prefix, v_prefix, jnp.int32(0), jnp.int32(T),
+        q, k_mut, v_mut, k_pool, v_pool, ids, jnp.int32(0), jnp.int32(T),
         scale=scale, q_tile=32, kv_tile=32, interpret=True,
     )
     np.testing.assert_allclose(

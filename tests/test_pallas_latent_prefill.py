@@ -304,10 +304,13 @@ def test_a_round_of_sessions_20k_skips_a_third_and_the_dead_stages():
     ("tiny-llama", "flash"), ("tiny-solar", "flash"),
     ("tiny-jamba", "flash"), ("tiny-laguna", "flash"),
 ])
-def test_the_engine_counts_tiles_by_the_modules_own_rule(preset, rule):
+def test_the_engine_counts_tiles_by_the_modules_own_rule(
+        preset, rule, monkeypatch):
     """``LLMEngine._count_kv_tiles`` takes the latent prefill kernel's rule
     for a module that names it (``prefill_attn_tiles``) and the flash
-    prefill kernel's for every other, a layer of each kind."""
+    prefill kernel's for every other, a layer of each kind; the flash
+    kernel's pages where it is the path taken, none where the dense form
+    runs, as here off a TPU."""
     import types
 
     from production_stack_tpu.engine.config import config_from_preset
@@ -322,6 +325,11 @@ def test_the_engine_counts_tiles_by_the_modules_own_rule(preset, rule):
     bmax = eng.config.scheduler.max_model_len // BS
     plan = types.SimpleNamespace(bucket_len=64, cached_len=48,
                                  num_new_tokens=20)
+    if rule == "flash":
+        assert eng._count_kv_tiles([plan], 64)[2] == 0
+        # tiny models' heads are no 128 lanes: say the kernel serves.
+        monkeypatch.setattr(
+            LLMEngine, "_flash_prefill_serves", lambda self, kind, T: True)
     before = dict(eng.prefill_attn_tiles)
     got = eng._count_kv_tiles([plan], 64)
     if rule == "latent":
@@ -330,13 +338,15 @@ def test_the_engine_counts_tiles_by_the_modules_own_rule(preset, rule):
         assert hasattr(eng.model, "prefill_attn_tiles")
     else:
         assert not hasattr(eng.model, "prefill_attn_tiles")
-        want = (0, 0)
-        for _label, window, _layers, in_slots in eng._attn_kinds:
-            n_live, n = fp.count_kv_tiles(
-                64, window if in_slots else bmax * BS,
+        want = (0, 0, 0)
+        for _label, window, layers, in_slots in eng._attn_kinds:
+            # A window layer's buffer in a slot: a pool of one window page.
+            n_live, n, pages = fp.count_kv_tiles(
+                64, *((1, window) if in_slots else (bmax, BS)),
                 min(48, window) if in_slots else 48, 20, window)
-            want = (want[0] + n_live, want[1] + n)
-    assert got == want and 0 < got[0] < got[1]
+            want = (want[0] + n_live, want[1] + n, want[2] + pages * layers)
+        assert got[2] > 0
+    assert got == (*want, 0)[:3] and 0 < got[0] < got[1]
     assert eng.prefill_attn_tiles == {
         "live": before["live"] + got[0],
         "skipped": before["skipped"] + got[1] - got[0]}

@@ -145,7 +145,7 @@ def test_ring_prefill_with_prefix_matches_gather_path(cached_len, valid_len):
     )
     got = np.asarray(jax.jit(ring)(q, k, v, k_pre, v_pre, cl, vl))
     want = np.asarray(
-        attn_ops.prefill_attention(q, k, v, k_pre, v_pre, cl, vl, scale=scale)
+        attn_ops.dense_prefill_attention(q, k, v, k_pre, v_pre, cl, vl, scale=scale)
     )
     np.testing.assert_allclose(
         got[:valid_len], want[:valid_len], rtol=2e-5, atol=2e-5
@@ -299,7 +299,7 @@ def test_ulysses_prefill_with_prefix_matches_gather_path(cached_len, valid_len):
     )
     got = np.asarray(jax.jit(ulysses)(q, k, v, k_pre, v_pre, cl, vl))
     want = np.asarray(
-        attn_ops.prefill_attention(q, k, v, k_pre, v_pre, cl, vl, scale=scale)
+        attn_ops.dense_prefill_attention(q, k, v, k_pre, v_pre, cl, vl, scale=scale)
     )
     np.testing.assert_allclose(
         got[:valid_len], want[:valid_len], rtol=2e-5, atol=2e-5
@@ -386,7 +386,7 @@ def test_ulysses_sliding_window_matches_dense():
         check_vma=False,
     )
     got = np.asarray(jax.jit(fn)(q, k, v, k_pre, v_pre, jnp.int32(0), jnp.int32(T)))
-    want = np.asarray(attn_ops.prefill_attention(
+    want = np.asarray(attn_ops.dense_prefill_attention(
         q, k, v, k_pre, v_pre, jnp.int32(0), jnp.int32(T),
         scale=scale, sliding_window=window,
     ))
