@@ -142,7 +142,11 @@ class ModelConfig:
     # encoding at all.  ``use_gqa_gate``: the softmax heads' output times
     # sigmoid(x W_gate), a column an output channel.  ``linear_gate_rank``:
     # the rank of the decay's and the output gate's projections.
-    # ``kda_allow_neg_eigval``: beta = 2 sigmoid, not sigmoid.
+    # ``kda_allow_neg_eigval``: beta = 2 sigmoid, not sigmoid.  "gdn"
+    # (models/olmo_hybrid.py, the gated delta rule with a decay a head): a
+    # [linear_head_dim, linear_value_head_dim] float32 state a head (keys by
+    # values; ``linear_value_head_dim`` 0: square), full-rank decay and gate
+    # projections (``linear_gate_rank`` 0), beside "full" softmax layers.
     layer_kinds: Tuple[str, ...] = ()
     use_rope: bool = True
     use_gqa_gate: bool = False
@@ -160,6 +164,7 @@ class ModelConfig:
     use_head_gate: bool = False
     linear_num_heads: int = 0
     linear_head_dim: int = 0
+    linear_value_head_dim: int = 0
     linear_conv_kernel: int = 0
     linear_gate_rank: int = 0
     kda_allow_neg_eigval: bool = False
@@ -549,6 +554,61 @@ PRESETS = {
         linear_head_dim=16,
         linear_conv_kernel=4,
         linear_gate_rank=8,
+        kda_allow_neg_eigval=True,
+    ),
+    # Olmo-Hybrid-7B (https://huggingface.co/allenai/Olmo-Hybrid-7B,
+    # model_type olmo_hybrid) AS ONE PIPELINE STAGE, not the whole model:
+    # every width, every head and the whole 100,352-row vocabulary as
+    # published, and of the published 32 layers (three gated delta-rule
+    # layers with a decay a head, then one multi-head softmax layer, eight
+    # times) one whole period, layers 0-3: 0.832 B parameters in the period
+    # and 0.771 B in the embedding and the untied head, 3.21 GB of bf16, what
+    # the first of eight v5e chips of a pipeline holds plus the head
+    # (bench/configs/olmo-hybrid-7b-stage.json states the deployment; PERF.md
+    # section 4 the arithmetic).  30 key heads for 30 query heads of 128
+    # (head_dim is not in the source: 3840 / 30); 30 delta-rule heads of 96
+    # key and 192 value channels.  rope_theta null in the source: read as no
+    # position encoding.  The published max is 65,536 positions; 32,768 is the
+    # serving limit the caches are sized for.
+    "olmo-hybrid-7b-stage": ModelConfig(
+        name="olmo-hybrid-7b-stage",
+        vocab_size=100352,
+        hidden_size=3840,
+        intermediate_size=11008,
+        num_layers=4,
+        num_heads=30,
+        num_kv_heads=30,
+        head_dim=128,
+        max_model_len=32768,
+        rms_norm_eps=1e-6,
+        layer_kinds=("gdn", "gdn", "gdn", "full"),
+        use_rope=False,
+        linear_num_heads=30,
+        linear_head_dim=96,
+        linear_value_head_dim=192,
+        linear_conv_kernel=4,
+        kda_allow_neg_eigval=True,
+    ),
+    # The same module at a size the CPU tests run: one period, 10 softmax
+    # heads (a page of 10 key heads pads to 16, as 30 does to 32) and 6
+    # delta-rule heads (16 does not divide them) of 8 key by 16 value channels.
+    "tiny-olmo": ModelConfig(
+        name="tiny-olmo-hybrid",
+        vocab_size=384,
+        hidden_size=80,
+        intermediate_size=128,
+        num_layers=4,
+        num_heads=10,
+        num_kv_heads=10,
+        head_dim=8,
+        max_model_len=2048,
+        rms_norm_eps=1e-6,
+        layer_kinds=("gdn", "gdn", "gdn", "full"),
+        use_rope=False,
+        linear_num_heads=6,
+        linear_head_dim=8,
+        linear_value_head_dim=16,
+        linear_conv_kernel=4,
         kda_allow_neg_eigval=True,
     ),
     # AI21-Jamba2-3B (https://huggingface.co/ai21labs/AI21-Jamba2-3B,

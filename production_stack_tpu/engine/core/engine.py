@@ -523,6 +523,11 @@ class LLMEngine:
         # any dispatch has left in a slot and the largest step size.
         self.ssm_state_absmax = 0.0
         self.ssm_dt_max = 0.0
+        # tpu:gdn_state_absmax / tpu:gdn_beta_max: a module with delta-rule
+        # layers under a decay a head (models/olmo_hybrid.py: GDN_STATS), the
+        # largest |S| any dispatch has left in a slot and the largest beta.
+        self.gdn_state_absmax = 0.0
+        self.gdn_beta_max = 0.0
         # tpu:sample_dispatch_total / tpu:sample_sorted_dispatch_total:
         # dispatched programs that sample, and those whose rows make the
         # sampler sort the vocabulary (sampling.needs_sort).
@@ -2918,6 +2923,12 @@ class LLMEngine:
                     folded["ssm_state_absmax_e3"] / 1e3)
                 self.ssm_dt_max = max(
                     self.ssm_dt_max, folded["ssm_dt_max_e3"] / 1e3)
+            if "gdn_beta_max_e3" in folded:
+                self.gdn_state_absmax = max(
+                    self.gdn_state_absmax,
+                    folded["gdn_state_absmax_e3"] / 1e3)
+                self.gdn_beta_max = max(
+                    self.gdn_beta_max, folded["gdn_beta_max_e3"] / 1e3)
             if "mhc_entries" in folded:
                 self.mhc_clamped += folded["mhc_clamped"]
                 self.mhc_entries += folded["mhc_entries"]
@@ -4864,6 +4875,10 @@ class LLMEngine:
             # any dispatch left in a slot, the largest step size.
             "ssm_state_absmax": self.ssm_state_absmax,
             "ssm_dt_max": self.ssm_dt_max,
+            # Delta-rule layers under a decay a head (zero without): the
+            # largest |S| any dispatch left in a slot, the largest beta.
+            "gdn_state_absmax": self.gdn_state_absmax,
+            "gdn_beta_max": self.gdn_beta_max,
             # The state pool of a model with recurrent state (zero without).
             **self._state_stats(),
             # Dispatched programs that sample, and those among them whose

@@ -56,9 +56,9 @@ the step programs (``core/step_programs.py``) carry without looking inside.
   attentions, FFNs and cache arrays, the router's width and what is held).
 - with ``init_cache``, **a state pool**: ``state_bytes_per_slot(cfg)`` and
   ``snapshot_stride(cfg)`` say that some layers keep, in place of keys, a
-  state that does not grow with the context (three modules do:
-  ``solar_kda.py`` and ``jamba.py`` a recurrent state, ``laguna.py`` a window
-  layer's last keys and values in a rolling buffer).  The engine then makes
+  state that does not grow with the context (four modules do:
+  ``solar_kda.py``, ``jamba.py`` and ``olmo_hybrid.py`` a recurrent state,
+  ``laguna.py`` a window layer's last keys and values in a rolling buffer).  The engine then makes
   a ``kv/state_pool.py: StatePool`` beside the block pool (sized by rule from
   ``max_num_seqs``; its bytes come off what the block pool is sized from),
   calls ``init_cache(..., state_slots=n)`` so that the one cache tree holds
@@ -85,7 +85,7 @@ the step programs (``core/step_programs.py``) carry without looking inside.
   pool a layer a dispatch; in ``[slots, 3 x width]`` a slot is a sub-tile line
   and every scatter stages the pool or rewrites tiles row by row).
   ``tests/test_chip_compile.py: test_a_state_models_served_programs_copy_no_pool``
-  compiles the three modules' served programs for a described v5e and is what
+  compiles the four modules' served programs for a described v5e and is what
   holds a fourth such module to it: add the preset to its ``STATE_MODELS``.
   (A value written into a slot that is computed from a slot of the same pool
   -- a buffer carried over from the slot a chunk starts from -- is taken out
@@ -159,7 +159,20 @@ renormalised (``router_scoring``, ``norm_topk_prob``) and whose outputs past
 their input, hold no weights and are computed whole on every chip of the
 deployment, counted once), behind ``sarvam_mla``'s grouped dispatch held by
 share, imported; boot line ``Layer: ...`` (``layer_form``), counter
-``moe_zero_assigned`` / ``tpu:moe_zero_assigned_total``.
+``moe_zero_assigned`` / ``tpu:moe_zero_assigned_total``; and in
+``olmo_hybrid.py`` blocks that norm what a sub-layer returns inside its
+residual branch (no norm before it), ``"gdn"`` layers -- the gated delta rule
+with a decay that is one number a head, a state of ``linear_head_dim`` key by
+``linear_value_head_dim`` value channels (96 x 192: not square), 30 heads (a
+count 16 does not divide: ``ops/pallas/kda.py: head_block``), a SiLU output
+gate and full-rank decay and gate projections; prefill through
+``gdn_prefill_pallas`` (the chunkwise form with one decay ratio a pair of
+tokens), decode through ``kda_decode_pallas`` as ``solar_kda.py`` calls it --
+beside ``"full"`` multi-head layers (30 key heads for 30 query heads, an
+RMSNorm over the whole width of q and of k, no position encoding) through
+``solar_kda``'s paged path with pages of ``olmo_hybrid.page_heads`` = 32 key
+heads (whole bf16 tiles); ``llama.py``'s dense SwiGLU and untied head;
+counters ``gdn_state_absmax_e3`` / ``gdn_beta_max_e3``.
 """
 
 from __future__ import annotations
@@ -167,7 +180,7 @@ from __future__ import annotations
 from types import ModuleType
 
 from production_stack_tpu.engine.models import (
-    jamba, laguna, llama, longcat, sarvam_mla, solar_kda,
+    jamba, laguna, llama, longcat, olmo_hybrid, sarvam_mla, solar_kda,
 )
 
 MODEL_REGISTRY = {
@@ -208,6 +221,13 @@ MODEL_REGISTRY = {
     # attention with its kernels, the cache layout, the router and the
     # grouped dispatch are sarvam_mla's, imported).
     "longcat": longcat,
+    # The gated delta rule with a decay a head and a state that is not square
+    # (96 x 192) beside multi-head softmax layers without position encoding
+    # whose pages keep 32 key heads for 30, a dense MLP, the norm after each
+    # sub-layer: the state pool's fourth user (the paged softmax path, the
+    # slot addressing and the plain recurrence are solar_kda's, the MLP and
+    # the head llama's, imported).
+    "olmo": olmo_hybrid,
 }
 
 

@@ -320,10 +320,12 @@ def _hi(a, b, dims):
 
 
 def kda_chunk_plain(q, k, v, g, beta, s0, snapshot_len=None, chunk=CHUNK):
-    """The chunkwise form in plain ``jax.numpy``.  ``q, k, v, g`` [T, H, D]
-    float32 (``q`` scaled, ``g`` the log decay <= 0), ``beta`` [T, H], ``s0``
-    [H, D, D] -> (o [T, H, D], the state after T tokens, the state after
-    ``snapshot_len`` tokens, a multiple of ``chunk`` below T, or None).
+    """The chunkwise form in plain ``jax.numpy``.  ``q, k, g`` [T, H, Dk] and
+    ``v`` [T, H, Dv] float32 (``q`` scaled, ``g`` the log decay <= 0; ``g``
+    [T, H], a decay a head, is spread over the head's key channels), ``beta``
+    [T, H], ``s0`` [H, Dk, Dv] -> (o [T, H, Dv], the state after T tokens, the
+    state after ``snapshot_len`` tokens, a multiple of ``chunk`` below T, or
+    None).
 
     Within a chunk, with ``G_t`` the running sum of ``g`` from its start,
     ``S_t = Diag(e^{G_t}) S_0 + sum_{s<=t} Diag(e^{G_t-G_s}) k_s u_s^T`` where
@@ -333,6 +335,8 @@ def kda_chunk_plain(q, k, v, g, beta, s0, snapshot_len=None, chunk=CHUNK):
     L^2)(I + L^4) ...``; ``O = (Q e^G) S_0 + tril(B) U`` with ``B`` as ``A``
     from ``q``; ``S_C = Diag(e^{G_C}) S_0 + (K e^{G_C - G})^T U``."""
     T, H, D = q.shape
+    if g.ndim == 2:
+        g = jnp.broadcast_to(g[..., None], k.shape)
     C = chunk
     n = T // C
     to = lambda a: a.reshape(n, C, H, -1).transpose(0, 2, 1, 3)  # [n,H,C,.]
@@ -368,13 +372,16 @@ def kda_chunk_plain(q, k, v, g, beta, s0, snapshot_len=None, chunk=CHUNK):
 
     (S, snap), o = jax.lax.scan(
         step, (s0, s0), (jnp.arange(n), qs, ks, vs, gs, bs))
-    o = o.transpose(0, 2, 1, 3).reshape(T, H, D)
+    o = o.transpose(0, 2, 1, 3).reshape(T, H, v.shape[-1])
     return o, S, (snap if snapshot_len is not None else None)
 
 
 def kda_step_plain(q, k, v, g, beta, S):
-    """One token a row: ``q, k, v, g`` [R, H, D] float32, ``beta`` [R, H],
-    ``S`` [R, H, D, D] -> (o [R, H, D], the new states)."""
+    """One token a row: ``q, k`` [R, H, Dk] and ``v`` [R, H, Dv] float32, ``g``
+    [R, H, Dk] or (a decay a head) [R, H], ``beta`` [R, H], ``S`` [R, H, Dk,
+    Dv] -> (o [R, H, Dv], the new states)."""
+    if g.ndim == 2:
+        g = g[..., None]
     S1 = jnp.exp(g)[..., None] * S
     u = beta[..., None] * (v - jnp.sum(k[..., None] * S1, axis=-2))
     S2 = S1 + k[..., None] * u[..., None, :]

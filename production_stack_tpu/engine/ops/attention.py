@@ -54,10 +54,15 @@ def use_pallas_decode(num_kv_heads: int = 128, head_dim: int = 128) -> bool:
     the DMA'd KV row back into heads in VMEM, and Mosaic only lowers that
     shape cast when head_dim is a multiple of the 128-lane tile.  Covers
     llama-3-8b / llama-3.2-3b / mistral-7b (D=128); head_dim-64 models
-    (llama-3.2-1b) and the tiny test models use the gather path."""
+    (llama-3.2-1b) and the tiny test models use the gather path.  A page of
+    more than eight key heads has to be whole bf16 tiles of 16 rows: the
+    device keeps 30 heads in 32 and Mosaic refuses a DMA of 30 of them
+    (``models/olmo_hybrid.py`` makes its pages with 32)."""
     if pallas_disabled():
         return False
     if num_kv_heads < 1 or head_dim % 128:
+        return False
+    if num_kv_heads > 8 and num_kv_heads % 16:
         return False
     return jax.default_backend() == "tpu"
 

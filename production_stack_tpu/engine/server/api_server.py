@@ -407,6 +407,10 @@ def build_engine_app(
             # and the largest step size since boot.
             (vocab.TPU_SSM_STATE_ABSMAX, s["ssm_state_absmax"]),
             (vocab.TPU_SSM_DT_MAX, s["ssm_dt_max"]),
+            # Delta-rule layers under a decay a head: the largest |S| left in
+            # a slot and the largest beta since boot.
+            (vocab.TPU_GDN_STATE_ABSMAX, s["gdn_state_absmax"]),
+            (vocab.TPU_GDN_BETA_MAX, s["gdn_beta_max"]),
             # Programs that sample, and those that sort the vocabulary for
             # it: both from boot, so that their ratio reads 0, not nothing.
             (vocab.TPU_SAMPLE_DISPATCH, s["sample_dispatches"]),

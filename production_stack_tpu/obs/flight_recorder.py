@@ -157,7 +157,10 @@ class WindowRecord:
     # |row sum - 1| after the last normalisation, x 1e6.  A model with
     # selective state-space layers hands (models/jamba.py: SSM_STATS)
     # ``ssm_state_absmax_e3`` / ``ssm_dt_max_e3``: the largest |h| the
-    # dispatch left in a slot and its largest step size, x 1000.
+    # dispatch left in a slot and its largest step size, x 1000.  A model
+    # with delta-rule layers under a decay a head (models/olmo_hybrid.py:
+    # GDN_STATS) ``gdn_state_absmax_e3`` / ``gdn_beta_max_e3``: the largest
+    # |S| the dispatch left in a slot and its largest beta, x 1000.
     routing: Optional[Dict[str, int]] = None
     # Under a model with a state pool (kv/state_pool.py).  ``state_rows``:
     # decode rows that read and write a slot of recurrent state each step.

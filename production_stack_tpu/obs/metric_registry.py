@@ -497,6 +497,21 @@ REGISTRY = {
                 "its bias) of a live token that any dispatch since boot "
                 "has read in a selective state-space layer",
     },
+    "tpu:gdn_state_absmax": {
+        "kind": "gauge", "layer": "engine",
+        "mirrors": ("docs",),
+        "help": "Largest |S| that any dispatch since boot has left in a "
+                "slot of a delta-rule layer's recurrent state under a decay "
+                "a head (counted on the device, read back with the tokens); "
+                "zero for a model without such layers",
+    },
+    "tpu:gdn_beta_max": {
+        "kind": "gauge", "layer": "engine",
+        "mirrors": ("docs",),
+        "help": "Largest beta (2 sigmoid of the projected write strength) of "
+                "a live token that any dispatch since boot has read in a "
+                "delta-rule layer under a decay a head; at most 2",
+    },
     "tpu:kv_wire_bytes_total": {
         "kind": "counter", "layer": "engine", "labels": ("tier", "format"),
         "mirrors": ("fake_engine", "dashboard", "docs"),

@@ -231,6 +231,13 @@ TPU_MHC_SINKHORN_ERR = "tpu:mhc_sinkhorn_err"
 # the device, read back with the tokens; zero for a model without such layers.
 TPU_SSM_STATE_ABSMAX = "tpu:ssm_state_absmax"
 TPU_SSM_DT_MAX = "tpu:ssm_dt_max"
+# Delta-rule layers under a decay a head (engine/models/olmo_hybrid.py:
+# GDN_STATS), two gauges: the largest |S| any dispatch has left in a slot of
+# recurrent state (with beta up to 2 a state can grow where a decay a channel
+# damped it) and the largest beta of a live token (at most 2).  Counted on the
+# device, read back with the tokens; zero for a model without such layers.
+TPU_GDN_STATE_ABSMAX = "tpu:gdn_state_absmax"
+TPU_GDN_BETA_MAX = "tpu:gdn_beta_max"
 # The sampler does what its rows ask for (engine/sampling.py): dispatched
 # programs that sample (decode window, mixed window, single step, prefill
 # tail), and those among them in which a sampling row set top-k or top-p,

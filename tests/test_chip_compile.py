@@ -488,6 +488,9 @@ STATE_MODELS = {
     # "state": a window layer's rolling buffers, K and V (bf16, as pages are).
     "laguna-xs.2-ep2": ("laguna", 58_768, {
         "prefill": {}, "decode": {}, "window": {}}),
+    # Pages of 32 key heads for 30 (whole bf16 tiles), a 96 x 192 state.
+    "olmo-hybrid-7b-stage": ("olmo_hybrid", 42_000, {
+        "prefill": {}, "decode": {"rows": 1}, "window": {"rows": 1}}),
 }
 @pytest.mark.parametrize("program", ["prefill", "decode", "window"])
 @pytest.mark.parametrize("preset", list(STATE_MODELS))
