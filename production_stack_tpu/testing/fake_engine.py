@@ -210,6 +210,10 @@ class FakeEngineState:
         self.capacity = capacity
         self.max_queued = max_queued
         self.admission_control = admission_control
+        # What a completion's service times (TTFT, token intervals) are
+        # slept on.  A test that measures them gives one that moves its
+        # own clock, so that a busy machine's sleeps are not in the gaps.
+        self.sleep = asyncio.sleep
         self.admission_rejected = 0  # tpu:admission_rejected_total
         self.deadline_expired = 0  # tpu:deadline_expired_total
         # Deterministic fault-injection surface (FakeEngineState.inject):
@@ -469,13 +473,20 @@ def build_fake_engine_app(state: FakeEngineState | None = None) -> web.Applicati
 
     def _render_metrics_pairs(state: FakeEngineState) -> str:
         # With a capacity model, "waiting" is the oversubscription beyond
-        # capacity (queue-depth gauge the overload tests assert on).
+        # capacity (queue-depth gauge the overload tests assert on), and
+        # "running" the rest: disjoint, as the real engine's scheduler
+        # reports them.  The router adds the two into one load
+        # (routing/base.py: effective_load) and takes the larger of that
+        # and its own count of the same requests: counted in both gauges,
+        # a stale scrape would outweigh the count and steer every request
+        # onto the other replica, past its bound.
         waiting = (
             max(0, state.num_running - state.capacity)
             if state.capacity else state.num_waiting
         )
+        running = state.num_running - (waiting if state.capacity else 0)
         return vocab.render_prometheus([
-            (vocab.TPU_NUM_REQUESTS_RUNNING, state.num_running),
+            (vocab.TPU_NUM_REQUESTS_RUNNING, running),
             (vocab.TPU_NUM_REQUESTS_WAITING, waiting),
             (vocab.TPU_HBM_KV_USAGE_PERC, state.kv_usage),
             (vocab.TPU_PREFIX_CACHE_HIT_RATE, state.prefix_hit_rate),
@@ -958,7 +969,7 @@ def build_fake_engine_app(state: FakeEngineState | None = None) -> web.Applicati
                 # server: the router's backend_connect span must end at
                 # connect, not absorb prefill time.
                 await response.prepare(request)
-                await asyncio.sleep(ttft_s)
+                await state.sleep(ttft_s)
                 t_first = time.time()
                 t_last = t_first
                 for i in range(max_tokens):
@@ -988,7 +999,7 @@ def build_fake_engine_app(state: FakeEngineState | None = None) -> web.Applicati
                         # disconnect) tears it down — the CancelledError
                         # lands in the abort tracking below.
                         await asyncio.Event().wait()
-                    await asyncio.sleep(state.token_interval())
+                    await state.sleep(state.token_interval())
                     now = time.time()
                     if state.obs.enabled and i > 0:
                         state.obs.request_hists["itl"].observe(now - t_last)
@@ -1023,10 +1034,10 @@ def build_fake_engine_app(state: FakeEngineState | None = None) -> web.Applicati
                 await response.write(b"data: [DONE]\n\n")
                 await response.write_eof()
                 return response
-            await asyncio.sleep(ttft_s)
+            await state.sleep(ttft_s)
             t_first = time.time()
             interval = state.token_interval()
-            await asyncio.sleep(max_tokens * interval)
+            await state.sleep(max_tokens * interval)
             text = " ".join(_word(state._rng) for _ in range(max_tokens))
             state.total_generated_tokens += max_tokens
             state.total_finished += 1

@@ -17,7 +17,9 @@ What it measures (per request, classified at response time):
   capacity model shed at the router; docs/robustness.md)
 * ``shed_engine`` — 429 with any other error type (the engine's own
   bounded admission tripped — in a healthy fleet these are strictly
-  RARER than and PRECEDED by router sheds)
+  RARER than and PRECEDED by router sheds; see
+  :meth:`FleetHarness.shed_ordering_violations` for what "preceded" is
+  held to)
 * ``error``       — 5xx / connect failure before any stream byte
 * ``dropped``     — the stream STARTED and then died before ``[DONE]``
   (the one class the scale-through-drain guarantee forbids entirely)
@@ -171,6 +173,10 @@ class FleetHarness:
         # the oracle (an omniscient admission schedule cannot serve work
         # on a killed/stalled/429-flapping replica either).
         self.fault_timeline: List[Tuple[float, int, bool]] = []
+        # How many outcomes were in when the harness last changed the fleet
+        # (a scale event, a fault armed or cleared): where an overload
+        # window starts, in the order things happened.
+        self.fleet_changes: List[int] = []
         self._discovery: Optional[MutableServiceDiscovery] = None
         self._client: Optional[TestClient] = None
         self._router_server: Optional[TestServer] = None
@@ -295,6 +301,7 @@ class FleetHarness:
         streams finish; the replica only counts as gone once idle."""
         assert self._discovery is not None
         n = max(0, min(n, self.num_engines))
+        self.fleet_changes.append(len(self.outcomes))
         current = [be for be in self.backends if be.active]
         if n > len(current):
             for be in self.backends:
@@ -341,13 +348,17 @@ class FleetHarness:
 
     # -- faults ------------------------------------------------------------
 
+    def _fault(self, index: int, armed: bool) -> None:
+        self.fault_timeline.append((self.now(), index, armed))
+        self.fleet_changes.append(len(self.outcomes))
+
     def inject(self, index: int, kind: str, **params) -> None:
         self.backends[index].state.inject(kind, **params)
-        self.fault_timeline.append((self.now(), index, True))
+        self._fault(index, True)
 
     def clear_injection(self, index: int, kind: str) -> None:
         self.backends[index].state.clear_injection(kind)
-        self.fault_timeline.append((self.now(), index, False))
+        self._fault(index, False)
 
     def kill_slice_member(self, ordinal: int) -> None:
         """Kill one follower of the fake slice group: its acks freeze,
@@ -357,7 +368,7 @@ class FleetHarness:
         0 — contributes zero oracle capacity while failed."""
         assert self.slice_group is not None, "harness has no slice group"
         self.slice_group.kill_member(ordinal)
-        self.fault_timeline.append((self.now(), 0, True))
+        self._fault(0, True)
 
     def restart_slice(self) -> None:
         """The parallel k8s group restart: members revive into one fresh
@@ -366,7 +377,7 @@ class FleetHarness:
         assert self.slice_group is not None, "harness has no slice group"
         self.slice_group.restart()
         self.backends[0].state.draining = False
-        self.fault_timeline.append((self.now(), 0, False))
+        self._fault(0, False)
 
     # -- traffic -----------------------------------------------------------
 
@@ -535,9 +546,9 @@ class FleetHarness:
         t_start = self.now()
         next_event = next_arrival = 0
         while True:
-            t = self.now() - t_start
-            if t >= duration_s:
-                break
+            # The end of the replay is an instant like any other the loop
+            # may wake behind: what was due before it is launched first.
+            t = min(self.now() - t_start, duration_s)
             while next_event < len(events) and events[next_event][0] <= t:
                 await events[next_event][1]()
                 next_event += 1
@@ -551,6 +562,8 @@ class FleetHarness:
                 else:
                     coro = self.one_request(phase=phase, priority=priority)
                 tasks.append(asyncio.ensure_future(coro))
+            if t >= duration_s:
+                break
             wake = min(
                 arrivals[next_arrival][0] if next_arrival < len(arrivals)
                 else duration_s,
@@ -651,25 +664,37 @@ class FleetHarness:
             t += bin_s
         return total
 
-    def shed_ordering_violations(
-        self, phase: str = "replay", window_s: float = 1.0
-    ) -> List[Outcome]:
-        """Engine-side 429s NOT preceded (within ``window_s``) by a
-        router-side fleet shed: the overload-firewall ordering guarantee
-        says this list is empty — the router always sheds first, the
-        engines' own bounds are the belt-and-braces layer behind it."""
-        outs = [o for o in self.outcomes if o.phase == phase]
-        router_shed_times = sorted(
-            o.done_t for o in outs if o.kind == "shed_router"
-        )
+    def shed_ordering_violations(self, phase: str = "replay") -> List[Outcome]:
+        """Engine-side 429s that no router-side fleet shed came before:
+        the overload-firewall ordering guarantee says this list is empty —
+        the router sheds first, the engines' own bounds are the
+        belt-and-braces layer behind it.
+
+        "Before" is the order the answers came in (``outcomes`` is in that
+        order), and the overload window an engine 429 belongs to starts
+        where the harness last changed the fleet (``fleet_changes``): no
+        span of the wall clock is measured, so a machine that stalls this
+        process moves nothing.
+        A request sent while one of the harness's own faults was armed is
+        not held to the order: a 429 storm's rejections are injected, and
+        until the breaker has counted a killed replica out, its slots are
+        headroom the gate admits on and the failover lands on the survivor
+        past the gate (the router cannot shed before what it has not been
+        told; ``oracle_admitted`` takes such a replica's capacity out the
+        same way)."""
         violations = []
-        for o in outs:
-            if o.kind != "shed_engine":
+        router_shed_in_window = False
+        for i, o in enumerate(self.outcomes):
+            if i in self.fleet_changes:
+                router_shed_in_window = False
+            if o.phase != phase:
                 continue
-            ok = any(
-                o.done_t - window_s <= t <= o.done_t
-                for t in router_shed_times
-            )
-            if not ok:
+            if o.kind == "shed_router":
+                router_shed_in_window = True
+            elif (
+                o.kind == "shed_engine"
+                and not router_shed_in_window
+                and not self._faulted_at(o.arrived_t)
+            ):
                 violations.append(o)
         return violations

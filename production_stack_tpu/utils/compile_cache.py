@@ -11,7 +11,11 @@ Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and no code
 sets another directory.  Where it is not, the cache lives in ``.jax_cache`` at
 the root of the checkout: a fixed path, because the path is part of what a
 cache entry is looked up by, so a directory made from a temporary name, a pid
-or the time never hits.  The in-process tests do not go through here.
+or the time never hits.  The tests come through here as well:
+``tests/conftest.py`` names, through that variable, one directory outside the
+checkout for the pytest workers and the children they start, with thresholds
+low enough for a small model's CPU programs, and calls
+:func:`enable_compile_cache` for the counts.
 """
 
 from __future__ import annotations

@@ -18,6 +18,8 @@ surface and the real CPU tiny-llama engine — no TPU, no flaky network:
 """
 
 import asyncio
+import heapq
+import itertools
 import json
 import time
 
@@ -47,7 +49,7 @@ def url_of(server) -> str:
     return str(server.make_url("")).rstrip("/")
 
 
-async def sse_events(resp):
+async def sse_events(resp, now=time.monotonic, on_event=None):
     """(timestamp, payload) for each SSE data event of a streamed body."""
     events = []
     buf = b""
@@ -56,7 +58,9 @@ async def sse_events(resp):
         while b"\n\n" in buf:
             frame, buf = buf.split(b"\n\n", 1)
             if frame.startswith(b"data: "):
-                events.append((time.monotonic(), frame[len(b"data: "):]))
+                events.append((now(), frame[len(b"data: "):]))
+                if on_event is not None:
+                    on_event(events[-1][1])
     return events
 
 
@@ -64,6 +68,63 @@ def itl_p95(token_times):
     gaps = sorted(b - a for a, b in zip(token_times, token_times[1:]))
     assert gaps, "need at least two tokens for an ITL sample"
     return gaps[int(0.95 * (len(gaps) - 1))]
+
+
+class StreamClock:
+    """The time one fake engine's service runs on, for a test that measures
+    token gaps: only the engine's own sleeps move it.  A sleeper wakes when
+    the clock reaches its hour, and the clock goes to the earliest sleeper's
+    hour once nothing else can happen before it: every request a client has
+    open sleeps in the engine, and every token the engine has written has
+    been read.  A chunk's stamp is then the hour it was written at, however
+    long the machine took to carry it, and a chunk the engine held back
+    (or a router buffered) stops the clock instead of passing unseen."""
+
+    def __init__(self, state):
+        self.state = state
+        self.state.sleep = self.sleep
+        self.time = 0.0
+        self.open = 0   # requests sent whose answer is not read to its end
+        self.read = 0   # token chunks the clients have read
+        self._sleepers = []  # heap of (hour, nth, future)
+        self._nth = itertools.count()
+
+    def now(self):
+        return self.time
+
+    async def sleep(self, delay):
+        woken = asyncio.get_running_loop().create_future()
+        heapq.heappush(
+            self._sleepers, (self.time + delay, next(self._nth), woken))
+        await woken
+
+    def on_event(self, payload):
+        if payload != b"[DONE]" and (
+            json.loads(payload)["choices"][0]["finish_reason"] is None
+        ):
+            self.read += 1
+
+    async def run(self, work, real_limit_s=60.0):
+        """``work``'s result, the clock moved for it as long as it runs."""
+        task = asyncio.ensure_future(work)
+        deadline = time.monotonic() + real_limit_s
+        while not task.done():
+            if (
+                self._sleepers
+                and len(self._sleepers) == self.open
+                and self.read == self.state.total_generated_tokens
+            ):
+                self.time, _, woken = heapq.heappop(self._sleepers)
+                woken.set_result(None)
+            elif time.monotonic() > deadline:
+                task.cancel()
+                raise AssertionError(
+                    f"the stream stopped at {self.time:.3f}: {self.open} open, "
+                    f"{len(self._sleepers)} asleep, {self.read} chunks read of "
+                    f"{self.state.total_generated_tokens} written"
+                )
+            await asyncio.sleep(0)
+        return task.result()
 
 
 # -- circuit breaker state machine ------------------------------------------
@@ -134,6 +195,16 @@ async def test_breaker_e2e_open_no_traffic_then_half_open_recovery():
                         "--breaker-open-s", "0.4"],
         )
         try:
+            from production_stack_tpu.router.services.request_service.request import (
+                CIRCUIT_BREAKER,
+            )
+
+            # The open window runs on a clock the test moves: on a busy
+            # machine four requests can take longer than 0.4 s of wall
+            # time, and the half-open probe then lands among them.
+            breaker = app["registry"].get(CIRCUIT_BREAKER)
+            now = [1000.0]
+            breaker._clock = lambda: now[0]
             s_bad.inject("refuse", count=-1)
             body = {"model": "fake/llama-3-8b", "prompt": "x",
                     "max_tokens": 2}
@@ -144,11 +215,6 @@ async def test_breaker_e2e_open_no_traffic_then_half_open_recovery():
             for _ in range(12):
                 resp = await client.post("/v1/completions", json=body)
                 assert resp.status == 200, await resp.text()
-            from production_stack_tpu.router.services.request_service.request import (
-                CIRCUIT_BREAKER,
-            )
-
-            breaker = app["registry"].get(CIRCUIT_BREAKER)
             assert breaker.state_value(url_of(e_bad)) == 2  # open
             # Open: the bad backend receives NO traffic at all.
             hits_while_open = s_bad.data_plane_hits
@@ -160,7 +226,7 @@ async def test_breaker_e2e_open_no_traffic_then_half_open_recovery():
             # requests include ONE half-open probe that closes the
             # breaker, after which traffic resumes.
             s_bad.clear_injection("refuse")
-            await asyncio.sleep(0.45)
+            now[0] += 0.45
             for _ in range(4):
                 resp = await client.post("/v1/completions", json=body)
                 assert resp.status == 200
@@ -272,7 +338,12 @@ async def test_oversubscription_shedding_bounds_itl():
     """2x oversubscription against a capacity-modeled fake engine: with
     bounded admission ON the excess sheds as structured 429s and the
     ADMITTED requests' p95 ITL stays within 1.5x the unloaded baseline;
-    with admission OFF everyone is admitted and everyone degrades."""
+    with admission OFF everyone is admitted and everyone degrades.
+
+    The gaps are those of the delivered stream, each chunk stamped as the
+    client reads it, on the engine's own clock (``StreamClock``): under six
+    busy workers a 10 ms sleep on the wall clock is as long as the machine
+    makes it, and the ratio read the machine."""
     capacity, n_load, n_tokens = 4, 8, 30
 
     async def run(admission: bool):
@@ -280,25 +351,31 @@ async def test_oversubscription_shedding_bounds_itl():
             capacity=capacity, max_queued=0, admission_control=admission,
             tokens_per_sec=100.0, ttft=0.005,
         )
+        clock = StreamClock(state)
         client = TestClient(server)
         await client.start_server()
         body = {"model": state.model, "prompt": "x", "stream": True,
                 "max_tokens": n_tokens}
 
         async def one():
-            resp = await client.post("/v1/completions", json=body)
-            if resp.status != 200:
-                detail = json.loads(await resp.text())
-                return ("rejected", resp, detail)
-            events = await sse_events(resp)
+            clock.open += 1
+            try:
+                resp = await client.post("/v1/completions", json=body)
+                if resp.status != 200:
+                    detail = json.loads(await resp.text())
+                    return ("rejected", resp, detail)
+                events = await sse_events(resp, clock.now, clock.on_event)
+            finally:
+                clock.open -= 1
             times = [t for t, payload in events if payload != b"[DONE]"]
             return ("admitted", resp, times)
 
         # Unloaded baseline: one stream alone.
-        _, _, baseline_times = await one()
+        _, _, baseline_times = await clock.run(one())
         baseline = itl_p95(baseline_times)
         # 2x capacity, simultaneously.
-        results = await asyncio.gather(*[one() for _ in range(n_load)])
+        results = await clock.run(
+            asyncio.gather(*[one() for _ in range(n_load)]))
         admitted = [r for r in results if r[0] == "admitted"]
         rejected = [r for r in results if r[0] == "rejected"]
         await client.close()
@@ -361,6 +438,27 @@ async def test_fake_engine_queue_depth_gauge_bounded_under_shed():
             resp = await t
             await resp.read()
     finally:
+        await client.close()
+
+
+async def test_fake_engine_gauges_count_a_request_once():
+    """Running and waiting are disjoint, as the real engine's scheduler
+    reports them: the router adds the two into one load, and a request
+    counted in both made a stale scrape outweigh the router's own count."""
+    from production_stack_tpu.router.stats.engine_stats import EngineStats
+
+    state, server = await start_fake(capacity=2, max_queued=8)
+    client = TestClient(server)
+    await client.start_server()
+    try:
+        for in_flight, (running, waiting) in {0: (0, 0), 2: (2, 0), 6: (2, 4)}.items():
+            state.num_running = in_flight
+            stats = EngineStats.from_prometheus_text(
+                await (await client.get("/metrics")).text())
+            assert (stats.num_running_requests, stats.num_queuing_requests) == (
+                running, waiting)
+    finally:
+        state.num_running = 0
         await client.close()
 
 

@@ -551,7 +551,7 @@ async def test_drain_relay_completes_stream_before_any_member_exits(
         stream = await client.post(
             "/v1/completions",
             json={"model": "tiny-llama", "prompt": "drain me gently",
-                  "max_tokens": 24, "ignore_eos": True, "stream": True},
+                  "max_tokens": 200, "ignore_eos": True, "stream": True},
         )
         assert stream.status == 200
         await stream.content.readany()
@@ -566,11 +566,18 @@ async def test_drain_relay_completes_stream_before_any_member_exits(
         fchan._epoch_adopted = True
         assert fchan.relay_drain()
 
-        # The monitor picks the relay up and begins the LEADER's drain;
-        # the in-flight stream still runs to [DONE].
+        # The monitor picks the relay up at its next poll (member timeout
+        # / 8) and begins the LEADER's drain: wait for that event, not for
+        # the stream, which a warm compile cache ends within one poll.
+        drain = app["drain"]
+        for _ in range(200):
+            if drain.draining:
+                break
+            await asyncio.sleep(0.05)
+        assert drain.draining
+        # The stream still runs to [DONE].
         body = await stream.content.read()
         assert b"[DONE]" in body
-        drain = app["drain"]
         assert await drain.wait(timeout=10.0) is True
 
         # New data-plane work is refused while the group exits.
